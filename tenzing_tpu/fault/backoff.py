@@ -5,7 +5,7 @@ THE one retry implementation for the whole runtime (ISSUE 3 satellite —
 describe *what* to retry (:class:`BackoffPolicy`, a ``retry_on`` predicate)
 and :func:`retry_call` handles the loop, the sleeps, and the telemetry —
 every retry lands as a ``fault.retry`` trace event (attempt count, error
-class, delay) and a ``fault.retries`` counter bump, so flaky-tunnel spells
+class, delay) and a ``fault.retries`` counter bump, so flaky-runtime spells
 are visible in the bundle instead of silently stretching the wall clock.
 
 Jitter is a +/- fraction of the exponential delay, drawn from the caller's
@@ -67,9 +67,8 @@ def retry_call(
 
     ``retry_on(exc) -> bool`` gates each retry (default: transient-class
     only).  ``on_retry(exc, attempt, delay)`` runs before each sleep — the
-    hook callers use for recovery work between attempts (e.g.
-    ``jax.extend.backend.clear_backends()`` before re-probing a failed
-    backend init).  The final failure re-raises the last exception."""
+    hook callers use for recovery work between attempts.  The final
+    failure re-raises the last exception."""
     policy = policy if policy is not None else BackoffPolicy()
     retry_on = retry_on if retry_on is not None else _default_retry_on
     rng = rng if rng is not None else _random.Random()

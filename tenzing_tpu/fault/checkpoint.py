@@ -1,7 +1,7 @@
 """Checkpoint/resume for long searches.
 
 A multi-hour empirical search must survive a kill — SIGINT, a SLURM wall
-clock, a crashed tunnel — without losing its corpus.  The checkpoint layout
+clock, a crashed runtime — without losing its corpus.  The checkpoint layout
 (one directory, ``bench.py --checkpoint DIR``):
 
 * ``measurements.jsonl`` — the **measurement journal**: one JSON line per

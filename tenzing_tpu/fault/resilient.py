@@ -6,8 +6,8 @@ Wraps any benchmarker (the Benchmarker protocol: ``benchmark(order, opts)
 fault policy of docs/robustness.md:
 
 * **watchdog** — each attempt runs on a daemon worker thread bounded by a
-  wall-clock ``timeout_secs``; a hung measurement (stuck collective, dead
-  tunnel that never errors) surfaces as
+  wall-clock ``timeout_secs``; a hung measurement (stuck collective, a
+  runtime that stalls without erroring) surfaces as
   :class:`~tenzing_tpu.fault.errors.MeasurementTimeout` instead of blocking
   the search forever.  The timed-out worker is *abandoned* (Python cannot
   interrupt a thread blocked in C) — safe for a dead RPC, and the retry

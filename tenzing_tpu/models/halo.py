@@ -315,6 +315,20 @@ def add_to_graph(
     return g
 
 
+def engine_overlap_order(graph: Graph, platform, engine: str):
+    """The post-all-before-await-any schedule of an ``xfer_choice`` mesh graph
+    (:func:`add_to_graph`) with EVERY exchange on one transfer ``engine``
+    ("xla" | "rdma") — the deterministic way to put a named engine on the
+    mesh, next to whatever a search happens to explore."""
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    seq, _ = drive(graph, platform, phase_policy(
+        platform, ("start", "pack", "exchange", "await", "unpack", "finish"),
+        prefer=lambda op, choices: next(
+            c for c in choices if c.endswith("." + engine))))
+    return seq
+
+
 def make_halo_buffers(
     mesh_shape: Tuple[int, int, int], args: HaloArgs, seed: int = 0,
     synth: bool = False

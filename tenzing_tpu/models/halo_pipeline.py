@@ -49,7 +49,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from tenzing_tpu.core.graph import Graph
-from tenzing_tpu.core.operation import ChoiceOp, CompoundOp, Finish, Start
+from tenzing_tpu.core.operation import ChoiceOp, CompoundOp
 from tenzing_tpu.core.sequence import Sequence
 from tenzing_tpu.models.halo import (
     DIRECTIONS,
@@ -270,14 +270,16 @@ HALO_PHASES = ("start", "pack", "spill", "fetch", "xfer", "await", "unpack",
 def naive_order(args: HaloArgs, platform) -> Sequence:
     """The naive sequential baseline: one lane, each direction's chain completed
     (post immediately awaited) before the next starts — the fully-synchronous
-    program the search must beat (BASELINE.md north star)."""
-    lane = platform.lanes[0]
-    ops: List = [Start()]
-    for d in DIRECTIONS:
-        pack, spill, fetch, await_, unpack = direction_ops(args, d)
-        ops += [pack.bind(lane), spill, fetch, await_, unpack.bind(lane)]
-    ops.append(Finish())
-    return Sequence(ops)
+    program the search must beat (BASELINE.md north star).  Derived through
+    the SDP machinery (solve/greedy.py) so the schedule carries the sync ops
+    the soundness verifier requires between a lane-bound pack and its
+    host-side spill."""
+    from tenzing_tpu.solve.greedy import serialized_chain_order
+
+    rank = {dir_name(d): i for i, d in enumerate(DIRECTIONS)}
+    return serialized_chain_order(
+        build_graph(args), platform,
+        lambda name: rank[name.split("_", 1)[1]])
 
 
 def greedy_overlap_order(args: HaloArgs, platform, engine: str = "host") -> Sequence:

@@ -48,9 +48,7 @@ from tenzing_tpu.core.operation import (
     ChoiceOp,
     CompoundOp,
     DeviceOp,
-    Finish,
     OpBase,
-    Start,
 )
 from tenzing_tpu.core.sequence import Sequence
 from tenzing_tpu.models.halo_pipeline import flatten_face, unflatten_face
@@ -476,15 +474,15 @@ def build_graph(args: MoEPipeArgs, cap: int, impl_choice: bool = False,
 
 def naive_order(args: MoEPipeArgs, cap: int, platform) -> Sequence:
     """The naive sequential baseline: one lane, each chunk's chain completed
-    (posts immediately awaited) before the next starts."""
-    lane = platform.lanes[0]
-    ops: List = [Start()]
-    for c in range(args.n_chunks):
-        for op in chunk_ops(args, c, cap):
-            ops.append(op.bind(lane) if isinstance(op, DeviceOp) else op)
-    cat = ConcatPipe("concat", args)
-    ops += [cat.bind(lane), Finish()]
-    return Sequence(ops)
+    (posts immediately awaited) before the next starts, then the concat.
+    Derived through the SDP machinery (solve/greedy.py) so the schedule
+    carries the sync ops the soundness verifier requires."""
+    from tenzing_tpu.solve.greedy import serialized_chain_order
+
+    return serialized_chain_order(
+        build_graph(args, cap), platform,
+        lambda name: (args.n_chunks if name == "concat"
+                      else int(name.rsplit("_", 1)[1])))
 
 
 def greedy_overlap_order(args: MoEPipeArgs, cap: int, platform,

@@ -87,7 +87,7 @@ def lane_label(lane: Optional[int]) -> str:
 
 def analyze(ops, timeline: OpTimeline, measured_us: Optional[float] = None,
             cost=None, per_op_costs: Optional[Dict[str, Any]] = None,
-            ) -> Attribution:
+            peaks=None) -> Attribution:
     """Fill the timeline's starts from the happens-before relation and
     compute the attribution verdict.
 
@@ -96,7 +96,9 @@ def analyze(ops, timeline: OpTimeline, measured_us: Optional[float] = None,
     whole-program measured iteration time (the driver's final pct50);
     ``cost`` an optional workload :class:`~tenzing_tpu.bench.roofline.Cost`
     for the fraction-of-peak join; ``per_op_costs`` an optional
-    ``unit name -> Cost`` map for per-unit utilization."""
+    ``unit name -> Cost`` map for per-unit utilization; ``peaks`` the
+    measuring device's :class:`~tenzing_tpu.bench.roofline.Peaks` — without
+    it the utilization blocks carry achieved rates and no fraction."""
     from tenzing_tpu.verify.soundness import happens_before_masks
 
     ops = list(ops)
@@ -158,7 +160,7 @@ def analyze(ops, timeline: OpTimeline, measured_us: Optional[float] = None,
         secs = (measured_us if measured_us is not None else makespan) * 1e-6
         if secs > 0:
             util = {k: (round(v, 6) if isinstance(v, float) else v)
-                    for k, v in cost.utilization(secs).items()}
+                    for k, v in cost.utilization(secs, peaks).items()}
     per_op_util = None
     if per_op_costs:
         per_op_util = {}
@@ -167,7 +169,7 @@ def analyze(ops, timeline: OpTimeline, measured_us: Optional[float] = None,
             if c is not None and rec.dur_us > 0:
                 per_op_util[rec.name] = {
                     k: (round(v, 6) if isinstance(v, float) else v)
-                    for k, v in c.utilization(rec.dur_us * 1e-6).items()}
+                    for k, v in c.utilization(rec.dur_us * 1e-6, peaks).items()}
 
     return Attribution(
         timeline=timeline,

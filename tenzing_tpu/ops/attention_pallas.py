@@ -208,8 +208,6 @@ def attn_fused_pallas(
     kernel = functools.partial(_attn_fused_kernel, float(scale), nkv_steps)
     from jax.experimental.pallas import tpu as pltpu
 
-    from tenzing_tpu.ops.pallas_compat import compiler_params
-
     outs = pl.pallas_call(
         kernel,
         # kv innermost and strictly sequential: the VMEM scratch state
@@ -227,7 +225,7 @@ def attn_fused_pallas(
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
         interpret=interpret,

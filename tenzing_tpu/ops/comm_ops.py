@@ -51,10 +51,16 @@ from tenzing_tpu.core.operation import CpuOp, register_kind
 
 
 def _to_memory_kind(x, kind: str):
+    """``x`` moved to the "pinned_host" or "device" memory of whatever device
+    the surrounding program runs on.  A memory-space transfer, not a sharding
+    on ``jax.devices()[0]``: it lowers to the same ``annotate_device_placement``
+    but names no device, so the program also traces for a device that is
+    described and not attached (tests/test_tpu_compile.py) and under a mesh."""
     import jax
 
-    dev = jax.devices()[0]
-    return jax.device_put(x, jax.sharding.SingleDeviceSharding(dev, memory_kind=kind))
+    space = {"pinned_host": jax.memory.Space.Host,
+             "device": jax.memory.Space.Device}[kind]
+    return jax.device_put(x, space)
 
 
 class CommStart(CpuOp):

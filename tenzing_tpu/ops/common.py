@@ -4,18 +4,11 @@ from __future__ import annotations
 
 import jax
 
-from tenzing_tpu.ops.pallas_compat import typeof
-
 
 def out_struct(shape, dtype, *like):
     """ShapeDtypeStruct carrying the union of the inputs' varying-across-mesh
-    (vma) annotation — required for pallas_call under shard_map.  ``typeof``
-    is the compat shim's: on jax without ``jax.typeof`` it degrades to an
-    eval_shape struct with no vma (matching the vma-less shard_map there)."""
+    (vma) annotation — required for pallas_call under shard_map."""
     vma = frozenset()
     for a in like:
-        vma = vma | getattr(typeof(a), "vma", frozenset())
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except TypeError:  # older jax without vma
-        return jax.ShapeDtypeStruct(shape, dtype)
+        vma = vma | jax.typeof(a).vma
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

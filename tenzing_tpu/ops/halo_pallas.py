@@ -49,7 +49,6 @@ from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 
 from tenzing_tpu.core.operation import ChoiceOp, OpBase
-from tenzing_tpu.ops.pallas_compat import compiler_params as _compiler_params
 from tenzing_tpu.models.halo import (
     HaloArgs,
     _face_slices,
@@ -73,7 +72,7 @@ def _interpret() -> bool:
 # today, but nothing else pins it — "arbitrary" makes the requirement
 # explicit so a future parallel/megacore grid default can't silently race
 # the rotating slots.
-_SEQUENTIAL_GRID = _compiler_params(
+_SEQUENTIAL_GRID = pltpu.CompilerParams(
     dimension_semantics=("arbitrary", "arbitrary")
 )
 

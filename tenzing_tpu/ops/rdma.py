@@ -58,7 +58,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tenzing_tpu.core.operation import register_kind
 from tenzing_tpu.ops.comm_ops import CommStart
-from tenzing_tpu.ops.pallas_compat import compiler_params as _compiler_params
 
 
 def _interpret() -> bool:
@@ -113,9 +112,9 @@ def rdma_shift_fused(
     kern = functools.partial(_shift_fused_kernel, tuple(axes), axis, shift)
     needs_barrier = axis is not None and axes and jax.lax.axis_size(axis) > 1
     params = (
-        _compiler_params(collective_id=collective_id, has_side_effects=True)
+        pltpu.CompilerParams(collective_id=collective_id, has_side_effects=True)
         if needs_barrier
-        else _compiler_params(has_side_effects=True)
+        else pltpu.CompilerParams(has_side_effects=True)
     )
     return pl.pallas_call(
         kern,
@@ -150,7 +149,7 @@ def rdma_copy_fused_local(x: jax.Array, interpret: Optional[bool] = None) -> jax
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA],
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
         name="rdma_copy_fused_local",
     )(x)
@@ -200,9 +199,9 @@ def rdma_shift_post(
     kern = functools.partial(_shift_post_kernel, tuple(axes), axis, shift)
     needs_barrier = axis is not None and axes and jax.lax.axis_size(axis) > 1
     params = (
-        _compiler_params(collective_id=collective_id, has_side_effects=True)
+        pltpu.CompilerParams(collective_id=collective_id, has_side_effects=True)
         if needs_barrier
-        else _compiler_params(has_side_effects=True)
+        else pltpu.CompilerParams(has_side_effects=True)
     )
     return pl.pallas_call(
         kern,
@@ -240,7 +239,7 @@ def rdma_shift_wait(
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
         input_output_aliases={3: 0},
-        compiler_params=_compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
         name="rdma_shift_wait",
     )(x, send, recv, y)
 

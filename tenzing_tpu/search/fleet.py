@@ -650,10 +650,15 @@ class MeasureOwner:
 
 
 def _spawn_worker(fleet_dir: str, rank: int) -> subprocess.Popen:
+    # one process per chip: the parent is the measurement owner and holds
+    # it.  Workers only build graphs and talk files, so they are pinned to
+    # the CPU backend — a worker that ever touched jax must not reach for
+    # the parent's chip
     return subprocess.Popen(
         [sys.executable, "-m", "tenzing_tpu.search.fleet",
          fleet_dir, str(rank)],
-        stdout=sys.stderr, stderr=sys.stderr)
+        stdout=sys.stderr, stderr=sys.stderr,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
 
 def _load_done(fleet_dir: str, graph, jobs: List[FleetJob]

@@ -13,7 +13,7 @@ from the domain heuristic rather than from naive.
 
 from __future__ import annotations
 
-from typing import Sequence as Seq
+from typing import Callable, Sequence as Seq
 
 from tenzing_tpu.core.graph import Graph
 from tenzing_tpu.core.sequence import Sequence
@@ -34,4 +34,29 @@ def greedy_phase_order(graph: Graph, platform, phases: Seq[str]) -> Sequence:
     from tenzing_tpu.solve.local import drive, phase_policy
 
     seq, _ = drive(graph, platform, phase_policy(platform, phases))
+    return seq
+
+
+def serialized_chain_order(graph: Graph, platform,
+                           chain_rank: Callable[[str], int]) -> Sequence:
+    """The fully-serialized baseline of a graph of independent chains: every
+    device op on ``platform.lanes[0]``, each chain completed before the next
+    starts.  ``chain_rank`` maps a work op's name to its chain's position
+    (lower first; "start"/"finish" are handled here).  Derived through the
+    same SDP drive as :func:`greedy_phase_order`, so the ``EventRecord``/
+    ``EventSync`` pairs a lane-bound op needs before a host-side consumer are
+    inserted exactly as a solver would insert them — a hand-listed op
+    sequence without them is unsound (verify/)."""
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    def priority(name: str) -> int:
+        if name == "start":
+            return -1
+        if name == "finish":
+            return 1 << 30
+        return chain_rank(name)
+
+    one_lane = Platform(platform.lanes[:1])
+    seq, _ = drive(graph, one_lane, phase_policy(one_lane, (), priority=priority))
     return seq

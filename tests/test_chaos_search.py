@@ -487,7 +487,7 @@ def test_chaos_with_prefetch_matches_prefetch_off(tmp_path, tracer,
 
     class CompileGate:
         """Foreground lazy-compile stand-in: the seeded subset fails before
-        any measurement — above the tunnel-fault injector (a compile never
+        any measurement — above the device-fault injector (a compile never
         reaches the device), below the counting layer."""
 
         def __init__(self, inner):
@@ -608,7 +608,7 @@ def test_device_lost_with_fallback_finishes_degraded(corpus, tracer):
 
             calls["n"] += 1
             if calls["n"] == 4:
-                raise DeviceLostError("tunnel torn down")
+                raise DeviceLostError("chip gone for good")
             return inner.benchmark(order, opts)
 
     rb = ResilientBenchmarker(LoseAfter(), policy=_fast_policy(),

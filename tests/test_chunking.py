@@ -334,7 +334,7 @@ class TestPartialFolds:
             for p in op.split(n):
                 acc.update(p.apply(acc, None))
             np.testing.assert_allclose(np.asarray(acc["ffn_out_0"]),
-                                       np.asarray(want), rtol=1e-6)
+                                       np.asarray(want), rtol=1e-5)
 
     def test_pipeline_stage_fold(self):
         from tenzing_tpu.models.pipeline import StageCompute
@@ -352,7 +352,7 @@ class TestPartialFolds:
             for p in op.split(n):
                 acc.update(p.apply(acc, None))
             np.testing.assert_allclose(np.asarray(acc["out_0"]),
-                                       np.asarray(want), rtol=1e-6)
+                                       np.asarray(want), rtol=1e-5)
 
     def test_tp_mlp_fold(self):
         from tenzing_tpu.models.tp_mlp import TpLayerPartial
@@ -371,7 +371,7 @@ class TestPartialFolds:
             for p in op.split(n):
                 acc.update(p.apply(acc, None))
             np.testing.assert_allclose(np.asarray(acc["part_0_0"]),
-                                       np.asarray(want), rtol=1e-6)
+                                       np.asarray(want), rtol=1e-5)
 
     def test_partials_reject_indivisible_runtime_rows(self):
         """Regression (review): chunk validity is checked against the
@@ -426,7 +426,7 @@ class TestPartialFolds:
             for p in op.split(n):
                 acc.update(p.apply(acc, None))
             np.testing.assert_allclose(np.asarray(acc["out_0"]),
-                                       np.asarray(want), rtol=1e-6)
+                                       np.asarray(want), rtol=1e-5)
 
 
 class TestVerifierFuzz:

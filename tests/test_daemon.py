@@ -151,7 +151,7 @@ def test_transient_failure_retries_then_leaves_item(tmp_path):
 
     def flaky(item_path, payload, timeout):
         calls.append(1)
-        raise TransientError("tunnel reset")
+        raise TransientError("connection reset")
 
     d = DrainDaemon(_opts(tmp_path, retries=2), runner=flaky,
                     log=lambda m: None)

@@ -167,3 +167,37 @@ def test_bench_shim_reexports_the_builders():
     assert bench.build_attn is driver.build_attn
     assert bench.metric_for is driver.metric_for
     assert bench.ALIAS_UNPACK is driver.ALIAS_UNPACK
+
+
+def test_non_smoke_run_is_refused_off_the_tpu():
+    """A run without --smoke measures on a TPU or not at all: on the CPU
+    backend (where this suite runs) the driver answers with the device
+    error before it builds anything, and stamps the device it found."""
+    res = driver.run(DriverRequest(workload="halo", halo_n=8, mcts_iters=1,
+                                   climb_budget=1))
+    v = res.verdict
+    assert v["error"].startswith("device refused")
+    assert v["device"]["platform"] == "cpu" and v["device"]["count"] >= 1
+    assert v["value"] == -1.0 and v["metric"] == "halo_iter_pct50_searched_n8"
+
+
+@pytest.mark.parametrize("verdict,rc", [
+    ({"metric": "m", "value": 1.0}, 0),
+    ({"metric": "m", "value": -1.0, "error": "device refused: ..."}, 1),
+    ({"metric": "m", "value": 1.0, "fault": {"degraded": True}}, 1),
+    ({"metric": "m", "value": 1.0, "fault": {"degraded": False}}, 0),
+])
+def test_bench_exit_code_follows_the_verdict(verdict, rc, monkeypatch,
+                                             capsys):
+    """bench.py keeps the parseable line and exits non-zero on an ``error``
+    verdict and on a degraded one."""
+    import json
+    import sys
+
+    import bench
+
+    monkeypatch.setattr(bench, "run_driver",
+                        lambda req: driver.DriverResult(verdict=verdict))
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--smoke"])
+    assert bench.main() == rc
+    assert json.loads(capsys.readouterr().out.strip()) == verdict

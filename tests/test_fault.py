@@ -87,7 +87,7 @@ class ScriptedBench:
     (TimeoutError("anything"), FaultClass.TRANSIENT),
     (ConnectionResetError("peer"), FaultClass.TRANSIENT),
     (RuntimeError("connection reset by peer"), FaultClass.TRANSIENT),
-    (RuntimeError("UNAVAILABLE: tunnel hiccup"), FaultClass.TRANSIENT),
+    (RuntimeError("UNAVAILABLE: runtime hiccup"), FaultClass.TRANSIENT),
     (RuntimeError("DEADLINE_EXCEEDED while fetching"), FaultClass.TRANSIENT),
     (RuntimeError("RESOURCE_EXHAUSTED: out of memory allocating"),
      FaultClass.DETERMINISTIC),
@@ -649,7 +649,7 @@ def test_exempt_ids_skip_identity_keyed_kinds_only():
     """bench.py registers its naive baseline here: identity-keyed
     candidate-fault kinds (deterministic/corrupt) skip exempt schedules —
     a seed deterministically breaking the BASELINE would kill every run —
-    while per-attempt tunnel-fault kinds still apply to them."""
+    while per-attempt device-fault kinds still apply to them."""
     det = InjectSpec("deterministic", 0.5, 123)
     # a schedule this seed deterministically fails
     fails = next(f"s{i}" for i in range(50)

@@ -143,3 +143,52 @@ class TestFfnPallas:
         got = ffn_pallas_batched(jnp.asarray(x), jnp.asarray(w1),
                                  jnp.asarray(w2), interpret=True)
         np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
+
+
+# -- moved from the deleted version-compat suite: these test the kernels and
+# the shared out_struct helper, now through the installed jax directly
+
+
+def test_out_struct_shapes_and_dtype():
+    from tenzing_tpu.ops.common import out_struct
+
+    s = out_struct((3, 5), jnp.float32, jnp.zeros((3, 5)))
+    assert tuple(s.shape) == (3, 5) and s.dtype == jnp.float32
+    assert s.vma == frozenset()
+
+
+def test_fused_attention_kernel_runs_with_compiler_params():
+    """A kernel that passes pltpu.CompilerParams compiles and runs in
+    interpret mode on the installed jax."""
+    from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+
+    b, n, d = 1, 8, 8
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.standard_normal((b, n, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, n, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, n, d)), jnp.float32)
+    acc = jnp.zeros((b, n, d))
+    m = jnp.full((b, n, d), -1e30)
+    l = jnp.zeros((b, n, d))
+    acc2, m2, l2 = attn_fused_pallas(q, k, v, acc, m, l, 1.0, bkv=n)
+    o = np.asarray(acc2 / l2)
+    s = np.asarray(q) @ np.asarray(k).transpose(0, 2, 1)
+    p = np.exp(s - s.max(axis=2, keepdims=True))
+    p /= p.sum(axis=2, keepdims=True)
+    np.testing.assert_allclose(o, p @ np.asarray(v), rtol=1e-5, atol=1e-5)
+
+
+def test_halo_and_rdma_modules_build_their_compiler_params():
+    """Module-level pltpu.CompilerParams construction: every field the
+    kernels pass is one the installed class knows (none is dropped)."""
+    import dataclasses
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    import tenzing_tpu.ops.halo_pallas as hp
+    import tenzing_tpu.ops.rdma  # noqa: F401
+
+    assert isinstance(hp._SEQUENTIAL_GRID, pltpu.CompilerParams)
+    known = {f.name for f in dataclasses.fields(pltpu.CompilerParams)}
+    assert {"dimension_semantics", "collective_id",
+            "has_side_effects"} <= known

@@ -8,10 +8,13 @@ from tenzing_tpu.bench.benchmarker import (
     EmpiricalBenchmarker,
 )
 from tenzing_tpu.bench.roofline import (
+    PEAKS,
+    UnknownDeviceError,
     V5E_PEAK_BF16_FLOPS,
     attention_cost,
     halo_cost,
     moe_cost,
+    peaks_for,
     spmv_cost,
 )
 
@@ -20,8 +23,21 @@ def test_attention_cost_counts_both_matmuls():
     c = attention_cost(batch=2, seq=1024, head_dim=128)
     assert c.flops == 4.0 * 2 * 1024 * 1024 * 128
     assert c.hbm_bytes == 4.0 * 2 * 1024 * 128 * 4
-    u = c.utilization(1e-3)
+    u = c.utilization(1e-3, peaks_for("TPU v5 lite"))
     assert abs(u["mxu_frac"] - c.flops / 1e-3 / V5E_PEAK_BF16_FLOPS) < 1e-12
+
+
+def test_no_fraction_of_peak_without_the_devices_row():
+    """Peaks are keyed by device_kind: a device without a row is an error,
+    and a utilization computed without peaks states rates only — never the
+    v5e's fractions under another device's name."""
+    import pytest
+
+    assert all(p.source for p in PEAKS.values())
+    with pytest.raises(UnknownDeviceError, match="cpu"):
+        peaks_for("cpu")
+    u = attention_cost(batch=2, seq=1024, head_dim=128).utilization(1e-3)
+    assert "mxu_frac" not in u and "hbm_frac" not in u and u["tflops"] > 0
 
 
 def test_moe_cost_staged_adds_transfer_bytes():

@@ -340,11 +340,11 @@ def test_owner_error_round_trip_preserves_fault_class(tmp_path, registry,
         proxy._await(rid)
     # a device loss is fatal on BOTH sides: the owner re-raises after
     # answering, and the worker reconstructs the DeviceLostError type
-    bench.fail = lambda o: DeviceLostError("tunnel collapsed")
+    bench.fail = lambda o: DeviceLostError("chip dropped off the bus")
     rid = proxy._submit("single", corpus[:1], BenchOpts(n_iters=1), 0)
     with pytest.raises(DeviceLostError):
         owner.drain(busy_workers=1)
-    with pytest.raises(DeviceLostError, match="tunnel collapsed"):
+    with pytest.raises(DeviceLostError, match="chip dropped off the bus"):
         proxy._await(rid)
 
 

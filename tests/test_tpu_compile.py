@@ -1,0 +1,444 @@
+"""Ahead-of-time compiles for a described (not attached) TPU v5e.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip that
+is only *described* (``jax.experimental.topologies``), so what Mosaic or XLA
+would refuse on the chip — an unaligned slice, too much VMEM, a program that
+does not fit 16 GB of HBM, a host memory kind it cannot place — is refused
+here, at no chip time.  Nothing runs: these tests say nothing about results
+or times, and a compile that passes is not a chip run.
+
+Covered, at the shapes ``python bench.py`` builds without ``--smoke``:
+
+* every halo kernel of ops/halo_pallas.py on every face it is on the menu
+  for (window 6+6, batched 4+4, flat 4+4), grid ``(3, 518, 520, 640)`` f32;
+* the fused and the split (semaphore-output) rdma copy of ops/rdma.py;
+* the moe/attention/spmv kernels;
+* two whole halo schedule programs (the naive baseline and the
+  ``greedy-alias-6l`` incumbent), both as the single-shot program the
+  integrity gate runs and as the repeat-n benchmark program, with the
+  pinned_host staging buffers and the 2 GB grid carried through ``fori_loop``;
+* the models/halo.py mesh exchange on the four described chips, both
+  transfer engines (XLA collective-permute, remote DMA with barriers).
+
+During such a compile ``jax.default_backend()`` is still ``cpu``, so the
+kernels' ``_interpret()`` would pick the interpreter: the tests (never an
+option of the program) monkeypatch it and assert ``tpu_custom_call`` in the
+compiled text.
+
+The topology is described inside a module-scoped fixture — never at import —
+because only one process may load libtpu, and every xdist worker imports every
+test file (on-chip-measurement guide, section 2).  Keep these in ONE file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tenzing_tpu.models.halo import DIRECTIONS, HaloArgs, _face_slices, dir_name
+
+# the north-star cell: python bench.py (halo, 512^3, nQ=3, radius 3)
+FLAGSHIP = HaloArgs(nq=3, lx=512, ly=512, lz=512, radius=3)
+DIR_IDS = [dir_name(d) for d in DIRECTIONS]
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described v5e 2x2 host, with the persistent compile cache off
+    around the module: an entry written for a described chip cannot be read
+    back without one, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip_kernels(monkeypatch):
+    """Route the op classes to the real kernels: ``_interpret()`` reads the
+    process's default backend, which stays ``cpu`` during an AOT compile."""
+    from tenzing_tpu.ops import halo_pallas, rdma
+
+    monkeypatch.setattr(halo_pallas, "_interpret", lambda: False)
+    monkeypatch.setattr(rdma, "_interpret", lambda: False)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+def _grid(one_chip):
+    from tenzing_tpu.models.halo_pipeline import _padded_shape
+
+    shape = _padded_shape(FLAGSHIP.local_shape(), 4)
+    assert shape == (3, 518, 520, 640)
+    return _sds(shape, jnp.float32, one_chip)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_described_device_has_published_peaks(topo):
+    """The device kind the chip reports is a row of the roofline table —
+    a non-smoke run on it gets its fractions of peak, not an error."""
+    from tenzing_tpu.bench.roofline import peaks_for
+
+    dev = topo.devices[0]
+    assert dev.platform == "tpu" and len(topo.devices) == 4
+    assert peaks_for(dev.device_kind).hbm_bytes == 819e9
+
+
+# -- halo kernels -------------------------------------------------------------
+
+
+def _face(d, which):
+    starts, _ = _face_slices(FLAGSHIP, d, which)
+    _, sizes = _face_slices(FLAGSHIP, d, "pack")
+    return tuple(starts), tuple(sizes)
+
+
+@pytest.mark.parametrize("d", DIRECTIONS, ids=DIR_IDS)
+def test_window_pack(one_chip, d):
+    from tenzing_tpu.ops.halo_pallas import pack_face_pallas
+
+    starts, sizes = _face(d, "pack")
+    _assert_kernel(pack_face_pallas.lower(
+        _grid(one_chip), starts, sizes, interpret=False).compile())
+
+
+@pytest.mark.parametrize("d", DIRECTIONS, ids=DIR_IDS)
+def test_window_unpack(one_chip, d):
+    from tenzing_tpu.ops.halo_pallas import unpack_face_pallas
+
+    starts, sizes = _face(d, "unpack")
+    _assert_kernel(unpack_face_pallas.lower(
+        _grid(one_chip), _sds(sizes, jnp.float32, one_chip), starts,
+        interpret=False).compile())
+
+
+# the batched kernels are on the menu where a DMA moves more than one face
+# row (the y/z faces); the flat kernels where the trailing dim is lane
+# -aligned (the x/y faces) — PackChoice/UnpackChoice gate on the same rules
+BATCHED = [d for d in DIRECTIONS if d[0] == 0]
+FLAT = [d for d in DIRECTIONS if d[2] == 0]
+
+
+@pytest.mark.parametrize("d", BATCHED, ids=[dir_name(d) for d in BATCHED])
+def test_batched_pack(one_chip, d):
+    from tenzing_tpu.ops.halo_pallas import _face_bx, pack_face_pallas_batched
+
+    assert _face_bx(FLAGSHIP, d) > 1
+    starts, sizes = _face(d, "pack")
+    _assert_kernel(pack_face_pallas_batched.lower(
+        _grid(one_chip), starts, sizes, interpret=False).compile())
+
+
+@pytest.mark.parametrize("d", BATCHED, ids=[dir_name(d) for d in BATCHED])
+def test_batched_unpack(one_chip, d):
+    from tenzing_tpu.ops.halo_pallas import (
+        _face_bx,
+        unpack_face_pallas_batched,
+    )
+
+    assert _face_bx(FLAGSHIP, d, which="unpack") > 1
+    starts, sizes = _face(d, "unpack")
+    _assert_kernel(unpack_face_pallas_batched.lower(
+        _grid(one_chip), _sds(sizes, jnp.float32, one_chip), starts,
+        interpret=False).compile())
+
+
+def _flat(sizes, one_chip):
+    from tenzing_tpu.models.halo_pipeline import _flat_rows
+
+    return _sds((_flat_rows(sizes), 128), jnp.float32, one_chip)
+
+
+@pytest.mark.parametrize("d", FLAT, ids=[dir_name(d) for d in FLAT])
+def test_flat_pack(one_chip, d):
+    from tenzing_tpu.ops.halo_pallas import _flat_ok, pack_face_flat_pallas
+
+    assert _flat_ok(FLAGSHIP, d)
+    starts, sizes = _face(d, "pack")
+    _assert_kernel(pack_face_flat_pallas.lower(
+        _grid(one_chip), starts, sizes, interpret=False).compile())
+
+
+@pytest.mark.parametrize("d", FLAT, ids=[dir_name(d) for d in FLAT])
+def test_flat_unpack(one_chip, d):
+    from tenzing_tpu.ops.halo_pallas import _flat_ok, unpack_face_flat_pallas
+
+    assert _flat_ok(FLAGSHIP, d)
+    starts, sizes = _face(d, "unpack")
+    _assert_kernel(unpack_face_flat_pallas.lower(
+        _grid(one_chip), _flat(sizes, one_chip), starts, sizes,
+        interpret=False).compile())
+
+
+def test_menus_offer_exactly_the_compiled_kernels():
+    """The cases above are the flagship menus: 6+6 window, 4+4 batched,
+    4+4 flat — a menu that grows a kernel must grow a compile case."""
+    from tenzing_tpu.ops.halo_pallas import PackChoice, UnpackChoice
+
+    def suffixes(choice_cls):
+        return sorted(c.name().split(".", 1)[1]
+                      for d in DIRECTIONS
+                      for c in choice_cls(FLAGSHIP, d).choices())
+
+    want = sorted(["xla"] * 6 + ["pallas"] * 6 + ["pallasb"] * 4
+                  + ["pallasf"] * 4)
+    assert suffixes(PackChoice) == want
+    assert suffixes(UnpackChoice) == want
+
+
+# -- rdma ---------------------------------------------------------------------
+
+
+def _face_buffer(one_chip):
+    _, sizes = _face(DIRECTIONS[0], "pack")
+    return _flat(sizes, one_chip)
+
+
+def test_rdma_fused_local_copy(one_chip):
+    from tenzing_tpu.ops.rdma import rdma_copy_fused_local
+
+    c = jax.jit(lambda x: rdma_copy_fused_local(x, interpret=False)).lower(
+        _face_buffer(one_chip)).compile()
+    _assert_kernel(c)
+
+
+def test_rdma_split_start_wait(one_chip):
+    """The post/wait pair passing DMA semaphores between two kernels — a path
+    no CPU test reaches (the interpreter has no semaphore outputs)."""
+    from tenzing_tpu.ops.rdma import rdma_start_loopback, rdma_wait_loopback
+
+    def copy(x):
+        send, recv, y = rdma_start_loopback(x)
+        return rdma_wait_loopback(x, send, recv, y)
+
+    c = jax.jit(copy).lower(_face_buffer(one_chip)).compile()
+    assert c.as_text().count("tpu_custom_call") >= 2
+
+
+# -- moe / attention / spmv kernels -------------------------------------------
+
+
+def test_ffn_batched(one_chip):
+    """moe: MoEPipeArgs() — 8 experts, d 512, d_ff 2048; capacity 304 is what
+    make_pipe_buffers(seed=0) routes at 8192 tokens in 4 chunks."""
+    from tenzing_tpu.ops.ffn_pallas import ffn_pallas_batched
+
+    f32 = jnp.float32
+    _assert_kernel(ffn_pallas_batched.lower(
+        _sds((8, 304, 512), f32, one_chip),
+        _sds((8, 512, 2048), f32, one_chip),
+        _sds((8, 2048, 512), f32, one_chip), interpret=False).compile())
+
+
+ATTN = dict(batch=4, seq=8 * 1024, block=1024, head_dim=128)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_attention_fused(one_chip, dtype):
+    """attn: 8k context in 8 blocks of 1024, head dim 128, batch 4."""
+    from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+
+    b, n, d = ATTN["batch"], ATTN["seq"], ATTN["head_dim"]
+    qkv = _sds((b, n, d), dtype, one_chip)
+    st = _sds((b, n, d), jnp.float32, one_chip)
+    _assert_kernel(attn_fused_pallas.lower(
+        qkv, qkv, qkv, st, st, st, d ** -0.5, bkv=ATTN["block"],
+        interpret=False).compile())
+
+
+def test_attention_block(one_chip):
+    from tenzing_tpu.ops.attention_pallas import attn_block_pallas
+
+    b, n, d = ATTN["batch"], ATTN["seq"], ATTN["head_dim"]
+    q = _sds((b, n, d), jnp.float32, one_chip)
+    kv = _sds((b, ATTN["block"], d), jnp.float32, one_chip)
+    _assert_kernel(attn_block_pallas.lower(
+        q, kv, kv, q, q, q, d ** -0.5, interpret=False).compile())
+
+
+def test_spmv_ell(one_chip):
+    """spmv: the 150000-row local ELL slab (width 26, make_spmv_buffers seed
+    0) against the largest x the kernel is offered for (``supports``: 4096 —
+    at the default m both x vectors are larger and the menu prunes it)."""
+    from tenzing_tpu.ops.spmv_pallas import (
+        LANES,
+        MAX_X_BLOCKS,
+        ell_spmv_pallas,
+        supports,
+    )
+
+    n = LANES * MAX_X_BLOCKS
+    assert supports(n) and not supports(n + 1)
+    _assert_kernel(ell_spmv_pallas.lower(
+        _sds((150_000, 26), jnp.float32, one_chip),
+        _sds((150_000, 26), jnp.int32, one_chip),
+        _sds((n,), jnp.float32, one_chip), interpret=False).compile())
+
+
+# -- whole halo schedule programs ---------------------------------------------
+
+
+def _pipeline_shapes(args):
+    """name -> (shape, is_host) of models/halo_pipeline.make_pipeline_buffers
+    without allocating the grid."""
+    from tenzing_tpu.models.halo_pipeline import _flat_rows, _padded_shape
+
+    out = {"U": (_padded_shape(args.local_shape(), 4), False)}
+    for d in DIRECTIONS:
+        _, sizes = _face_slices(args, d, "pack")
+        flat = (_flat_rows(sizes), 128)
+        for prefix in ("buf", "host", "recv"):
+            out[f"{prefix}_{dir_name(d)}"] = (flat, prefix == "host")
+    return out
+
+
+def test_pipeline_shapes_match_the_builder():
+    """The shape table the program compiles use is the builder's, checked
+    where allocating is cheap."""
+    from tenzing_tpu.models.halo_pipeline import (
+        host_buffer_names,
+        make_pipeline_buffers,
+    )
+
+    small = HaloArgs(nq=3, lx=16, ly=16, lz=16, radius=3)
+    bufs, _ = make_pipeline_buffers(small, with_expected=False)
+    want = _pipeline_shapes(small)
+    assert {k: v.shape for k, v in bufs.items()} == \
+        {k: s for k, (s, _) in want.items()}
+    assert sorted(k for k, (_, h) in want.items() if h) == \
+        sorted(host_buffer_names())
+
+
+def _halo_schedule(which):
+    from tenzing_tpu.bench.driver import halo_alias_prefer
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.halo_pipeline import (
+        HALO_PHASES,
+        build_graph,
+        naive_order,
+    )
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    if which == "naive":
+        plat = Platform.make_n_lanes(1)
+        return plat, naive_order(FLAGSHIP, plat)
+    # greedy-alias-6l, as bench/driver.py builds it: all-rdma transfers,
+    # aliased Pallas unpacks, on the kernel + engine choice graph
+    plat = Platform.make_n_lanes(6)
+    g = build_graph(FLAGSHIP, impl_choice=True, xfer_choice=True)
+    seq, _ = drive(g, plat, phase_policy(plat, HALO_PHASES,
+                                         halo_alias_prefer))
+    return plat, seq
+
+
+@pytest.mark.parametrize("which,n_kernels", [("naive", 0), ("alias", 18)])
+def test_whole_halo_program(topo, one_chip, on_chip_kernels, which,
+                            n_kernels):
+    """One whole schedule at 512^3: the single-shot program and the repeat-n
+    benchmark program compile, keep their kernels (alias: 6 aliased unpacks +
+    6 rdma posts + 6 rdma waits) and fit the chip's memory."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tenzing_tpu.runtime.executor import TraceExecutor
+
+    host = SingleDeviceSharding(topo.devices[0], memory_kind="pinned_host")
+    shapes = _pipeline_shapes(FLAGSHIP)
+    bufs = {k: _sds(s, jnp.float32, host if is_host else one_chip)
+            for k, (s, is_host) in shapes.items()}
+    host_names = {k for k, (_, is_host) in shapes.items() if is_host}
+    plat, seq = _halo_schedule(which)
+    ex = TraceExecutor(plat, bufs)
+
+    def host_typed(b):
+        # an array committed to pinned_host traces as float32<host>; a
+        # ShapeDtypeStruct drops the memory space from its type, so restate
+        # it (a no-op placement: the argument already arrives there)
+        return {k: jax.device_put(v, jax.memory.Space.Host)
+                if k in host_names else v for k, v in b.items()}
+
+    program = ex.program(seq)
+    stepped = ex._stepped_fn(seq.vector())
+    n = _sds((), jnp.int32, one_chip)
+    for compiled in (
+        jax.jit(lambda b: program(host_typed(b))).lower(bufs).compile(),
+        jax.jit(lambda b, n: stepped(host_typed(b), n)).lower(
+            bufs, n).compile(),
+    ):
+        assert compiled.as_text().count("tpu_custom_call") == n_kernels
+        m = compiled.memory_analysis()
+        assert (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes) < HBM_BYTES
+        assert m.host_output_size_in_bytes > 0  # the staging set is host's
+
+
+# -- the mesh exchange on four chips ------------------------------------------
+
+
+@pytest.mark.parametrize("engine,marker", [
+    ("xla", "collective-permute"),
+    ("rdma", "tpu_custom_call"),
+])
+def test_mesh_halo_exchange(topo, on_chip_kernels, engine, marker):
+    """models/halo.py on a 2x2x1 mesh of the four described chips, 256^3
+    cells per shard (what ``chip_smoke.py --chips 4`` runs): the XLA
+    collective-permute engine and the remote-DMA engine with its barrier
+    semaphore and collective_id."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tenzing_tpu.core.graph import Graph
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.halo import add_to_graph, engine_overlap_order
+    from tenzing_tpu.runtime.executor import TraceExecutor
+
+    args = HaloArgs(nq=3, lx=256, ly=256, lz=256, radius=3)
+    mx, my, mz = 2, 2, 1
+    mesh = Mesh(np.array(topo.devices).reshape(mx, my, mz), ("x", "y", "z"))
+    spec = P(None, "x", "y", "z")
+    sharded = NamedSharding(mesh, spec)
+
+    def tiled(local):
+        return _sds((local[0], mx * local[1], my * local[2], mz * local[3]),
+                    jnp.float32, sharded)
+
+    bufs = {"U": tiled(args.local_shape())}
+    for d in DIRECTIONS:
+        _, sizes = _face_slices(args, d, "pack")
+        bufs[f"buf_{dir_name(d)}"] = tiled(sizes)
+        bufs[f"recv_{dir_name(d)}"] = tiled(sizes)
+    plat = Platform.make_n_lanes(2, mesh=mesh, specs={k: spec for k in bufs})
+    seq = engine_overlap_order(
+        add_to_graph(Graph(), args, xfer_choice=True), plat, engine)
+    assert sum(op.name().endswith("." + engine) for op in seq.vector()) == 6
+    ex = TraceExecutor(plat, bufs)
+    compiled = jax.jit(ex.program(seq)).lower(bufs).compile()
+    assert marker in compiled.as_text()
+    m = compiled.memory_analysis()  # bytes on each device
+    assert (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes) < HBM_BYTES

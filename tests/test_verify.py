@@ -396,3 +396,47 @@ def test_solver_accept_points_reject_unsound(tmp_path):
         hill_climb(g, plat, inner, phases=("spmv",),
                    opts=LocalOpts(budget=2, verify=RejectAll()))
     assert inner.calls == 0
+
+
+@pytest.mark.parametrize("workload", ["halo", "moe", "spmv", "attn"])
+def test_driver_naive_baseline_verifies(workload):
+    """The naive baseline the driver measures first — the denominator of
+    every verdict — passes the verifier AS BUILT, for every workload, at
+    smoke size and device-free.  The hand-listed halo/moe baselines carried
+    no sync ops and killed ``bench.py`` at its first measurement (race:raw
+    between a lane-bound pack and its host-side spill); nothing fed them to
+    the verifier."""
+    from tenzing_tpu.bench.driver import (
+        BUILDERS,
+        DriverRequest,
+        graph_for,
+        naive_schedule,
+        workload_shape,
+    )
+
+    assert workload in BUILDERS
+    req = DriverRequest(workload=workload, smoke=True)
+    g, _ = graph_for(req)
+    wargs = None
+    if workload == "halo":
+        from tenzing_tpu.models.halo import HaloArgs
+
+        s = workload_shape(req)
+        wargs = HaloArgs(nq=s["nq"], lx=s["n"], ly=s["n"], lz=s["n"],
+                         radius=s["radius"])
+    elif workload == "moe":
+        from tenzing_tpu.models.moe_pipeline import (
+            MoEPipeArgs,
+            make_pipe_buffers,
+        )
+
+        margs = MoEPipeArgs(**workload_shape(req))
+        _, _, cap = make_pipe_buffers(margs, seed=0, with_expected=False)
+        wargs = (margs, cap)
+    seq = naive_schedule(workload, g, wargs)
+    v = ScheduleVerifier(g)(seq)
+    assert v.ok, f"unsound baseline: {v.witness()}\n{seq.desc()}"
+    # one lane, fully serialized
+    lanes = {l.id for op in seq.vector()
+             for l in getattr(op, "lanes", lambda: [])()}
+    assert lanes == {0}
