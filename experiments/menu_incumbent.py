@@ -40,7 +40,7 @@ MENU_BEST = {
 }
 # the flat twins where legal (x/y faces): staging emitted/consumed directly
 # in the kernel, no separate XLA flatten/unflatten relayout pass — the pass
-# profile_winner measured at ~10 ms/iter across the r4 winner's schedule
+# PROFILE_WINNER.json records at ~10 ms/iter across the r4 winner's schedule
 MENU_FLAT = dict(MENU_BEST)
 MENU_FLAT.update({
     "pack_px": ".pallasf", "pack_mx": ".pallasf",

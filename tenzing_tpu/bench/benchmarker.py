@@ -204,6 +204,18 @@ class EmpiricalBenchmarker:
 
         return run_n, 1  # 1: one fence per sample
 
+    def _dispatch(self, run_n: Callable[[int], None], n: int) -> float:
+        """One fenced ``run_n(n)`` and its wall seconds: a ``bench.dispatch``
+        span (the executor's ``executor.enqueue`` / ``executor.fence_wait``, or
+        a first call or first run, inside it) and one count of
+        ``bench.dispatches``."""
+        with get_tracer().span("bench.dispatch", n=n):
+            t0 = time.perf_counter()
+            run_n(n)
+            wall = time.perf_counter() - t0
+        get_metrics().counter("bench.dispatches").inc()
+        return wall
+
     # reference measure(), benchmarker.cpp:83-119
     def _measure(
         self,
@@ -217,9 +229,7 @@ class EmpiricalBenchmarker:
         overhead = self._fetch_overhead()
         while True:
             self.cp.barrier()
-            t0 = time.perf_counter()
-            run_n(n_samples)
-            wall = time.perf_counter() - t0
+            wall = self._dispatch(run_n, n_samples)
             cost = overhead * (fences_per_sample * n_samples if fences_per_sample else 1)
             elapsed = wall - cost
             elapsed = self.cp.allreduce_max(elapsed)
@@ -248,12 +258,13 @@ class EmpiricalBenchmarker:
     def benchmark(self, order: Sequence, opts: Optional[BenchOpts] = None) -> BenchResult:
         opts = opts if opts is not None else BenchOpts()
         tr = get_tracer()
-        sid = schedule_id(order) if tr.enabled else None
+        sid = schedule_id(order) if tr.recording else None
         with tr.span("bench.benchmark", schedule=sid, n_iters=opts.n_iters,
                      target_secs=opts.target_secs) as sp:
             run_n, fences = self._runner_for(order)
             with tr.span("bench.warm", schedule=sid):
-                run_n(1)  # warmup: compile + first dispatch excluded
+                # warmup: compile + first dispatch excluded
+                self._dispatch(run_n, 1)
             n_samples = 1
             for attempt in range(opts.max_retries):
                 times: List[float] = []
@@ -339,7 +350,7 @@ class EmpiricalBenchmarker:
             runners = [self._runner_for(o) for o in orders]
             with tr.span("bench.batch_warm", n_orders=len(orders)):
                 for r, _ in runners:
-                    r(1)  # warmup/compile all before timing any
+                    self._dispatch(r, 1)  # warmup/compile all before timing any
             n_samples = [1] * len(orders)
             times: List[List[float]] = (
                 times_out if times_out is not None else [[] for _ in orders]

@@ -37,10 +37,13 @@ Fault discipline — background threads NEVER touch the control plane:
 
 Observability (docs/performance.md): ``pipeline.prefetch.issued`` /
 ``hits`` / ``wasted`` / ``failed`` / ``surfaced`` / ``dropped`` counters, a
-``pipeline.queue_depth`` gauge, and a ``pipeline.precompile`` span per
-background compile (the executor's ``executor.compile`` spans — ``aot: true``
-for background ones — give the compile wall; overlap fraction falls out of
-comparing them against ``bench.benchmark`` spans on the main thread).
+``pipeline.queue_depth`` gauge, a ``pipeline.precompile`` span per
+background compile (the executor's ``executor.first_call`` spans — ``aot:
+true`` for background ones, with ``executor.lower`` and
+``executor.xla_compile`` inside — give the compile wall; overlap fraction
+falls out of comparing them against ``bench.benchmark`` spans on the main
+thread), and a ``pipeline.wait`` span for the time the foreground is blocked
+on a compile that is still running.
 
 Shutdown: ``close()`` cancels pending compiles and joins the workers (no
 leaked threads); a SIGINT/SIGABRT trap handler (utils/trap.py) only flips
@@ -271,7 +274,10 @@ class PrefetchingBenchmarker:
                 depth = len(self._inflight)
             get_metrics().gauge("pipeline.queue_depth").set(depth)
             return
-        wait([fut])
+        if not fut.done():
+            with get_tracer().span("pipeline.wait",
+                                   schedule=schedule_id(order)):
+                wait([fut])
 
     def _consume(self, order) -> None:
         """Account a prefetch hit and surface a stored background compile

@@ -14,9 +14,10 @@ decision**:
 * :mod:`~tenzing_tpu.obs.attrib.explain` — winner-vs-naive decision diff
   (lanes / reorder / sync removal / menu choices), the three-term timing
   decomposition, ``explain.json``, per-lane Perfetto tracks;
-* :mod:`~tenzing_tpu.obs.attrib.xplane` — the device-plane jax.profiler
-  capture + concurrency analysis (absorbed from ``utils/profiling.py``,
-  which remains as a deprecation shim), the multi-chip fallback.
+* :mod:`~tenzing_tpu.obs.attrib.xplane` — the reader of a jax.profiler
+  trace: device busy and idle, and each idle gap given to the program's
+  own span (``python -m tenzing_tpu.obs.attrib.xplane <trace dir>``; not
+  imported here, so that ``-m`` runs it as a script of its own).
 
 Driver surface: ``bench.py --profile-winner`` stamps the ``attrib`` block
 into the driver JSON; ``python -m tenzing_tpu.obs.report`` mines corpora
@@ -39,24 +40,16 @@ from tenzing_tpu.obs.attrib.timeline import (
     fetch_overhead_us,
     stepped_timeline,
 )
-from tenzing_tpu.obs.attrib.xplane import (
-    analyze_trace,
-    capture_trace,
-    merge_intervals,
-)
 
 __all__ = [
     "Attribution",
     "OpRecord",
     "OpTimeline",
     "analyze",
-    "analyze_trace",
-    "capture_trace",
     "diff_schedules",
     "explain",
     "fetch_overhead_us",
     "lane_label",
-    "merge_intervals",
     "stepped_timeline",
     "timeline_trace_events",
     "write_explain",

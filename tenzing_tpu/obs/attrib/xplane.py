@@ -1,72 +1,82 @@
-"""Device-side xplane profiling: jax.profiler traces + transfer/compute
-concurrency analysis — the attribution profiler's fallback timing source.
+"""Reader of a ``jax.profiler`` trace: the device's idle time, given to the
+program's own spans.
 
-The primary timing source for attribution is the per-op stepped mode
-(:mod:`tenzing_tpu.obs.attrib.timeline` over ``TraceExecutor.op_stepped``),
-which is single-chip only.  This module is the complement that works on any
-platform the profiler can attach to: capture an ``xplane`` trace of a
-schedule running under the executor, and parse it programmatically to
-measure how much wall time has a transfer (DMA/copy) event concurrent with
-device compute — the quantity a searched overlap schedule exists to create.
+While a profiler session is active the tracer (obs/tracer.py) enters every
+span it records as a ``jax.profiler.TraceAnnotation("tz:" + name)`` too, so
+the xplane holds the program's spans on the device's clock, each on the line
+of the thread that made it.  Profile a search (``jax.profiler.start_trace``
+round it, or ``jax.profiler.trace``), then::
 
-History: this code began life as ``utils/profiling.py`` (SURVEY.md §5 maps
-the reference's host-side phase counters — its ``counters.hpp``, whose
-in-repo analog is the ``utils/counters.py`` shim over ``obs/metrics`` — to
-JAX profiler traces on TPU).  ``utils/profiling.py`` is now a deprecation
-shim re-exporting this module.  The archived on-TPU evidence lives in
-``experiments/PROFILE_OVERLAP.json`` (driver:
-``experiments/profile_overlap.py``, which also documents the naive-vs-
-overlap halo comparison) and ``experiments/PROFILE_WINNER.json``
-(``experiments/profile_winner.py``, the winner's per-op-name breakdown).
+    python -m tenzing_tpu.obs.attrib.xplane <trace dir>
 
-The analysis is keyword-based over the device planes' event names: transfer
-events (copy/dma/transfer/send/recv/infeed/outfeed) vs compute events
-(fusion/slice/convert/...), with outer control events (while/loop) excluded —
-they span the whole program and would make every DMA look concurrent.
-Intervals are coalesced before intersection so each nanosecond counts once.
+prints, for the slice the foreground thread's spans cover: device busy and
+idle share; device seconds by operation kind; and **each idle gap of the
+device given to the innermost ``tz:`` span the foreground thread was in**,
+with, for every such span name, what the other threads' spans were in
+meanwhile (a foreground ``pipeline.wait`` against a prefetch worker's
+``executor.xla_compile``).  The foreground thread is the one that dispatches
+(``bench.dispatch``): the measurement owner.
+
+The reduction works on a neutral form, so it can be checked on a hand-made
+trace and on a small one recorded on a chip (tests/data)::
+
+    {"planes": [{"name": str, "lines": [{"name": str,
+                 "events": [[name, start_ns, end_ns], ...]}]}]}
+
+:func:`load_xplane` makes that form from the newest ``.xplane.pb`` under a
+directory.  Device planes are named ``/device:<KIND>:<i>``; on a TPU their
+line ``XLA Ops`` holds one event per executed operation (a ``while`` holds
+its body's operations nested inside it).  The benchmark's own reduction
+(``benchmarks/harness/trace.py``) is this algorithm over the harness's
+``tzb:`` proxies; the program may not import it, so the two are kept in
+step by ``benchmarks/tests/program_spans_on_chip.py``.
 """
 
 from __future__ import annotations
 
 import glob
+import os
+import sys
 from pathlib import Path
-from typing import Dict, List, Sequence as Seq, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence as Seq, Tuple
 
-TRANSFER_KEYWORDS = ("copy", "dma", "transfer", "infeed", "outfeed", "send",
-                     "recv", "all-reduce", "reduce-scatter", "all-gather",
-                     "all-to-all", "collective", "permute", "rdma")
-COMPUTE_KEYWORDS = ("fusion", "dynamic", "slice", "pad", "convert", "reshape",
-                    "add", "concatenate", "custom-call", "custom_call", "dot",
-                    "matmul", "gelu", "broadcast", "select", "iota",
-                    "transpose", "mosaic")
-# outer control events span the whole program and would make every DMA look
-# concurrent — they are neither transfer nor compute nor "unclassified"
-CONTROL_KEYWORDS = ("while", "loop", "condition", "body", "call", "region")
+from tenzing_tpu.obs.tracer import SESSION_PREFIX as SPAN_PREFIX
+
+OPS_LINE = "XLA Ops"
+DISPATCH = "bench.dispatch"  # marks the foreground thread
+UNATTRIBUTED = "unattributed"
+TOP = 12                   # entries a printed list may have
+
+Interval = Tuple[int, int, str]  # start_ns, end_ns, name
 
 
-def capture_trace(executor, order, out_dir, iters: int = 3) -> Tuple[Path, float]:
-    """Run ``order`` ``iters`` times under ``jax.profiler.trace`` and return
-    (trace directory, wall seconds).  The schedule is compiled and warmed
-    first so the trace holds steady-state execution, not compilation."""
-    import time
+def load_xplane(trace_dir) -> dict:
+    """The neutral form of the newest ``.xplane.pb`` under ``trace_dir``."""
+    from jax.profiler import ProfileData
 
-    import jax
+    paths = sorted(glob.glob(str(Path(trace_dir) / "**" / "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not paths:
+        raise FileNotFoundError(f"no xplane under {trace_dir}")
+    data = ProfileData.from_file(paths[-1])
+    planes = []
+    for plane in data.planes:
+        lines = []
+        for line in plane.lines:
+            evs = [[ev.name or "", int(ev.start_ns),
+                    int(ev.start_ns + ev.duration_ns)] for ev in line.events]
+            lines.append({"name": line.name or "", "events": evs})
+        planes.append({"name": plane.name or "", "lines": lines})
+    return {"planes": planes}
 
-    run_n = executor.prepare_n(order)
-    run_n(1)  # compile + warm outside the trace
-    out_dir = Path(out_dir)
-    out_dir.mkdir(parents=True, exist_ok=True)
-    t0 = time.perf_counter()
-    with jax.profiler.trace(str(out_dir)):
-        run_n(iters)
-    return out_dir, time.perf_counter() - t0
 
-
-def merge_intervals(ivs: Seq[Tuple[int, int]]) -> List[List[int]]:
+def merge_intervals(ivs: Iterable[Seq[int]]) -> List[List[int]]:
     """Coalesce intervals so busy time and intersections count each
     nanosecond once."""
     out: List[List[int]] = []
     for a, b in sorted(ivs):
+        if b <= a:
+            continue
         if out and a <= out[-1][1]:
             out[-1][1] = max(out[-1][1], b)
         else:
@@ -74,57 +84,177 @@ def merge_intervals(ivs: Seq[Tuple[int, int]]) -> List[List[int]]:
     return out
 
 
-def analyze_trace(trace_dir) -> Dict[str, float]:
-    """Transfer-vs-compute concurrency on the device planes of the newest
-    xplane file under ``trace_dir`` (see module docstring for the method)."""
-    from jax.profiler import ProfileData
+def device_planes(trace: dict) -> List[dict]:
+    return [p for p in trace["planes"] if p["name"].startswith("/device:")
+            and any(ln["name"] == OPS_LINE for ln in p["lines"])]
 
-    paths = sorted(glob.glob(str(Path(trace_dir) / "**" / "*.xplane.pb"),
-                             recursive=True))
-    if not paths:
-        return {"error": f"no xplane under {trace_dir}"}
-    data = ProfileData.from_file(paths[-1])
-    xfers: List[Tuple[int, int]] = []
-    computes: List[Tuple[int, int]] = []
-    unclassified: List[Tuple[int, int]] = []
-    for plane in data.planes:
-        pname = plane.name.lower()
-        if not ("tpu" in pname or "device" in pname or "xla" in pname):
+
+def innermost(events: Iterable[Seq]) -> List[Interval]:
+    """One line's nested events flattened to disjoint pieces, each named by
+    the innermost event that covers it (a parent keeps only its self time),
+    in order of start."""
+    out: List[Interval] = []
+    stack: List[list] = []  # [name, end, covered up to]
+
+    def emit(name: str, a: int, b: int) -> None:
+        if b > a:
+            out.append((a, b, name))
+
+    def close(upto: float) -> None:
+        while stack and stack[-1][1] <= upto:
+            name, end, at = stack.pop()
+            emit(name, at, end)
+            if stack:
+                stack[-1][2] = max(stack[-1][2], end)
+
+    for name, a, b in sorted(events, key=lambda e: (e[1], -e[2])):
+        close(a)
+        if stack:
+            b = min(b, stack[-1][1])  # a child cannot outlast its parent
+            emit(stack[-1][0], stack[-1][2], a)
+            stack[-1][2] = a
+        stack.append([name, b, a])
+    close(float("inf"))
+    return sorted(out)
+
+
+def op_kind(name: str) -> str:
+    """A device operation's name cut to what is stable from program to
+    program: ``%copy.106 = f32[...] copy(...)`` -> ``copy``."""
+    head = name.split(" = ", 1)[0].strip().lstrip("%")
+    return ".".join(p for p in head.split(".") if not p.isdigit()) or head
+
+
+def program_threads(trace: dict) -> Dict[Tuple[int, int], List[list]]:
+    """``{(plane, line): [[span name, start_ns, end_ns], ...]}`` of the
+    program's mirrored spans, one entry per host thread that made any (every
+    Python thread's line has the same name, so a line goes by position)."""
+    out: Dict[Tuple[int, int], List[list]] = {}
+    for pi, p in enumerate(trace["planes"]):
+        if p["name"].startswith("/device:"):
             continue
-        for line in plane.lines:
-            for ev in line.events:
-                nm = (ev.name or "").lower()
-                iv = (ev.start_ns, ev.end_ns)
-                if iv[1] <= iv[0]:
-                    continue
-                if any(k in nm for k in TRANSFER_KEYWORDS):
-                    xfers.append(iv)
-                elif any(k in nm for k in COMPUTE_KEYWORDS):
-                    computes.append(iv)
-                elif not any(k in nm for k in CONTROL_KEYWORDS):
-                    # neither transfer, compute, nor outer control: report it
-                    # so silent misclassification is visible (ADVICE r3)
-                    unclassified.append(iv)
+        for li, ln in enumerate(p["lines"]):
+            evs = [[n[len(SPAN_PREFIX):], a, b] for n, a, b in ln["events"]
+                   if n.startswith(SPAN_PREFIX) and b > a]
+            if evs:
+                out[(pi, li)] = evs
+    return out
 
-    def total(ivs):
-        return sum(b - a for a, b in merge_intervals(ivs))
 
-    overlap_ns = 0
-    computes_merged = merge_intervals(computes)
-    for a, b in merge_intervals(xfers):
-        for c, d in computes_merged:
-            if c >= b:
-                break
-            lo, hi = max(a, c), min(b, d)
+def overlaps(pieces: Seq[Interval], others: Seq[Interval]):
+    """``(start, end, piece name, other name)`` for every overlap of two
+    sorted lists of disjoint intervals."""
+    k = 0
+    for a, b, name in pieces:
+        while k < len(others) and others[k][1] <= a:
+            k += 1
+        j = k
+        while j < len(others) and others[j][0] < b:
+            lo, hi = max(a, others[j][0]), min(b, others[j][1])
             if hi > lo:
-                overlap_ns += hi - lo
-    return {
-        "xplane": paths[-1],
-        "n_transfer_events": len(xfers),
-        "n_compute_events": len(computes),
-        "n_unclassified_events": len(unclassified),
-        "transfer_busy_ms": total(xfers) / 1e6,
-        "compute_busy_ms": total(computes) / 1e6,
-        "unclassified_busy_ms": total(unclassified) / 1e6,
-        "transfer_concurrent_with_compute_ms": overlap_ns / 1e6,
-    }
+                yield lo, hi, name, others[j][2]
+            j += 1
+
+
+def _ranked(d: Dict[str, float]) -> List[list]:
+    return [[k, v / 1e9] for k, v in sorted(d.items(), key=lambda kv: -kv[1])]
+
+
+def reduce_trace(trace: dict) -> dict:
+    """Busy and idle time of the slice the foreground thread's spans cover
+    (first start to last end: the profiler's own start-up and shutdown lie
+    outside), device seconds by operation kind with nested operations taken
+    out of their parents, and the idle gaps of the first device by the
+    foreground's innermost span (module docstring).  ``{}`` where the trace
+    holds no span of the program."""
+    threads = program_threads(trace)
+    if not threads:
+        return {}
+    fg = max(threads, key=lambda k: (
+        sum(1 for e in threads[k] if e[0] == DISPATCH), len(threads[k])))
+    w0 = min(e[1] for e in threads[fg])
+    w1 = max(e[2] for e in threads[fg])
+    out = {"slice_s": (w1 - w0) / 1e9, "n_threads": len(threads)}
+    gaps: List[Interval] = [(w0, w1, "")]
+    planes = device_planes(trace)
+    if planes:
+        busy, ops = [], {}
+        for i, p in enumerate(planes):
+            line = next(ln for ln in p["lines"] if ln["name"] == OPS_LINE)
+            evs = [e for e in line["events"] if e[2] > w0 and e[1] < w1]
+            merged = merge_intervals(
+                [max(a, w0), min(b, w1)] for _, a, b in evs)
+            busy.append(sum(b - a for a, b in merged))
+            if i == 0:
+                for a, b, name in innermost(evs):
+                    kind = op_kind(name)
+                    ops[kind] = ops.get(kind, 0) + (b - a)
+                gaps, at = [], w0
+                for a, b in merged:
+                    if a > at:
+                        gaps.append((at, a, ""))
+                    at = max(at, b)
+                if w1 > at:
+                    gaps.append((at, w1, ""))
+        out.update(busy_s=sum(busy) / len(busy) / 1e9, n_devices=len(planes),
+                   device_ops=_ranked(ops))
+    else:
+        out.update(busy_s=0.0, n_devices=0, device_ops=[])
+    out["idle_s"] = out["slice_s"] - out["busy_s"]
+    # the gaps, cut by the foreground's innermost spans
+    idle: Dict[str, int] = {}
+    named: List[Interval] = []
+    for lo, hi, _, name in overlaps(gaps, innermost(threads[fg])):
+        named.append((lo, hi, name))
+        idle[name] = idle.get(name, 0) + (hi - lo)
+    covered = sum(idle.values())
+    total = sum(b - a for a, b, _ in gaps)
+    if total > covered:
+        idle[UNATTRIBUTED] = total - covered
+    # what the other threads were in during each name's gaps
+    meanwhile: Dict[str, Dict[str, int]] = {}
+    for key, evs in threads.items():
+        if key == fg:
+            continue
+        for lo, hi, name, other in overlaps(named, innermost(evs)):
+            m = meanwhile.setdefault(name, {})
+            m[other] = m.get(other, 0) + (hi - lo)
+    out["idle_by_span"] = [
+        {"span": name, "idle_s": s, "meanwhile": _ranked(
+            meanwhile.get(name, {}))} for name, s in _ranked(idle)]
+    return out
+
+
+def render(red: dict) -> str:
+    """The reduction as the text ``python -m`` prints."""
+    if not red:
+        return ("no tz: span in the trace: was the program run while the "
+                "profiler session was active?")
+    lines = [
+        f"slice {red['slice_s']:.3f} s over {red['n_devices']} device(s), "
+        f"{red['n_threads']} host thread(s) with spans: busy "
+        f"{red['busy_s']:.3f} s, idle {red['idle_s']:.3f} s "
+        f"({100 * red['idle_s'] / red['slice_s']:.1f}%)",
+        "device seconds by operation kind:"]
+    lines += [f"  {s:10.4f}  {kind}" for kind, s in red["device_ops"][:TOP]]
+    lines.append("idle seconds by the foreground thread's innermost span "
+                 "(other threads meanwhile, summed over threads):")
+    for row in red["idle_by_span"][:TOP]:
+        lines.append(f"  {row['idle_s']:10.4f}  {row['span']}")
+        lines += [f"  {'':10}    {s:10.4f}  {other}"
+                  for other, s in row["meanwhile"][:4]]
+    return "\n".join(lines)
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    argv = sys.argv[1:] if argv is None else argv
+    if len(argv) != 1:
+        sys.stderr.write(
+            "usage: python -m tenzing_tpu.obs.attrib.xplane <trace dir>\n")
+        return 2
+    sys.stdout.write(render(reduce_trace(load_xplane(argv[0]))) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -22,6 +22,22 @@ shared no-op immediately — no allocation, no locking, no timestamp (the
 contract tests/test_obs.py::test_disabled_tracer_is_noop relies on).  Enable
 it process-wide with :func:`configure` (what ``bench.py --trace-out`` does).
 
+**The tracer follows the profiler**: while a ``jax.profiler`` session is
+active (``jax.profiler.TraceAnnotation.is_enabled()``) a disabled tracer
+records all the same, and every span it records is also entered as
+``jax.profiler.TraceAnnotation("tz:" + name)``: the same span is then in the
+xplane, on the device's clock, on the line of the thread that made it
+(obs/attrib/xplane.py gives the device's idle gaps to these).  So profiling
+the process is all it takes to see the program's spans; a process that is
+not profiled pays the shared no-op plus that one check.  JAX is looked up
+lazily and never imported from here (``obs`` stays stdlib-only): no session
+can be active in a process that has not imported ``jax``.  A span opened
+before a session starts is not recorded; one that closes after it ended
+closes as usual.
+
+Every span also carries its start and end on ``time.perf_counter()``
+(``t0`` / ``t1``), the clock a host-side harness stamps its own windows on.
+
 While a cross-process trace context is ambient (obs/context.py — minted
 at serve-listen ingress, adopted by drain daemons and their children),
 every recorded span and event is additionally stamped with ``trace_id``
@@ -39,6 +55,7 @@ metric snapshots so silent loss is impossible.
 from __future__ import annotations
 
 import hashlib
+import sys
 import threading
 import time
 from collections import deque
@@ -54,9 +71,28 @@ MAX_SPANS = 200_000
 MAX_EVENTS = 200_000
 
 
+SESSION_PREFIX = "tz:"  # a mirrored span's name in the profiler's trace
+
+
+def _no_session() -> bool:
+    """Stand-in for ``TraceAnnotation.is_enabled`` until ``jax`` has been
+    imported by someone else; then it puts the real one in its place."""
+    global _annotation, _session_active
+    prof = sys.modules.get("jax.profiler")
+    ann = getattr(prof, "TraceAnnotation", None)
+    if ann is None or not hasattr(ann, "is_enabled"):
+        return False
+    _annotation, _session_active = ann, ann.is_enabled
+    return _session_active()
+
+
+_annotation = None            # jax.profiler.TraceAnnotation, once found
+_session_active = _no_session  # () -> bool: is a profiler session active?
+
+
 def short_digest(payload: str) -> str:
     """12-hex sha1 of a serialized payload — THE schedule-id convention
-    every telemetry emitter shares (bench.benchmark spans, executor.compile
+    every telemetry emitter shares (bench.benchmark spans, executor.first_call
     spans, bench.cache events), so trace records for the same schedule
     correlate byte-for-byte across subsystems and hosts."""
     return hashlib.sha1(payload.encode()).hexdigest()[:12]
@@ -64,17 +100,21 @@ def short_digest(payload: str) -> str:
 
 class Span:
     """One finished-or-open interval.  ``ts_us``/``dur_us`` are unix-epoch
-    microseconds; ``attrs`` is a plain JSON-safe dict."""
+    microseconds; ``t0``/``t1`` the same two instants on
+    ``time.perf_counter()`` (``t1`` is None while the span is open);
+    ``attrs`` is a plain JSON-safe dict."""
 
-    __slots__ = ("name", "ts_us", "dur_us", "pid", "tid", "span_id",
-                 "parent_id", "attrs")
+    __slots__ = ("name", "ts_us", "dur_us", "t0", "t1", "pid", "tid",
+                 "span_id", "parent_id", "attrs")
 
     def __init__(self, name: str, ts_us: float, pid: int, tid: int,
                  span_id: int, parent_id: Optional[int],
-                 attrs: Dict[str, Any]):
+                 attrs: Dict[str, Any], t0: float = 0.0):
         self.name = name
         self.ts_us = ts_us
         self.dur_us = 0.0
+        self.t0 = t0
+        self.t1: Optional[float] = None
         self.pid = pid
         self.tid = tid
         self.span_id = span_id
@@ -178,7 +218,16 @@ class Tracer:
 
     # -- plumbing ----------------------------------------------------------
     def _now_us(self) -> float:
-        return (self._t0_unix + (time.perf_counter() - self._t0_perf)) * 1e6
+        return self._to_us(time.perf_counter())
+
+    def _to_us(self, perf: float) -> float:
+        return (self._t0_unix + (perf - self._t0_perf)) * 1e6
+
+    @property
+    def recording(self) -> bool:
+        """Would a span opened now be recorded: enabled, or following an
+        active profiler session (module docstring)."""
+        return self.enabled or _session_active()
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -223,7 +272,7 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, name: str, **attrs: Any):
         """Context manager opening a nested span; yields the :class:`Span`."""
-        if not self.enabled:
+        if not self.enabled and not _session_active():
             return _NULL_CTX
         return self._span_ctx(name, attrs)
 
@@ -242,13 +291,24 @@ class Tracer:
         with self._lock:
             span_id = self._next_span_id
             self._next_span_id += 1
-        sp = Span(name, self._now_us(), self.rank, self._tid(), span_id,
-                  parent, attrs)
+        # mirrored into the profiler's trace while a session is active
+        # (module docstring); entered last and left first, so the ring's
+        # interval encloses the xplane's
+        mirror = (_annotation(SESSION_PREFIX + name)
+                  if _session_active() else None)
+        t0 = time.perf_counter()
+        sp = Span(name, self._to_us(t0), self.rank, self._tid(), span_id,
+                  parent, attrs, t0)
         stack.append(sp)
+        if mirror is not None:
+            mirror.__enter__()
         try:
             yield sp
         finally:
-            sp.dur_us = self._now_us() - sp.ts_us
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
+            sp.t1 = time.perf_counter()
+            sp.dur_us = (sp.t1 - t0) * 1e6
             stack.pop()
             with self._lock:
                 if len(self._spans) == self._spans.maxlen:
@@ -258,7 +318,7 @@ class Tracer:
 
     def event(self, name: str, **attrs: Any) -> None:
         """Record one instant event."""
-        if not self.enabled:
+        if not self.enabled and not _session_active():
             return
         trace = current_trace_attrs()
         if trace is not None:
@@ -327,8 +387,9 @@ class Tracer:
                     if sp.span_id in done_ids:
                         continue
                     cp = Span(sp.name, sp.ts_us, sp.pid, sp.tid, sp.span_id,
-                              sp.parent_id, dict(sp.attrs))
+                              sp.parent_id, dict(sp.attrs), sp.t0)
                     cp.dur_us = max(0.0, now - sp.ts_us)
+                    cp.t1 = sp.t0 + cp.dur_us / 1e6
                     cp.attrs["flushed"] = True
                     open_spans.append(cp)
         return spans, events, open_spans

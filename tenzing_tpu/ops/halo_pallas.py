@@ -390,7 +390,7 @@ def pack_face_flat_pallas(
     relaid to the flat layout with an in-kernel reshape (vreg shuffles at
     VMEM bandwidth), so the separate XLA flatten pass — measured at
     ~10 ms/iter of chunked HBM relayout copies across the winner's schedule
-    (experiments/profile_winner.py) — disappears, while the staging buffer
+    (experiments/PROFILE_WINNER.json) — disappears, while the staging buffer
     stays dense (the 4D-staging A/B showed tile-padded staging pays 2.7x+
     DMA bytes).  Requires sz % 128 == 0 (the ``_flat_ok`` gate): that keeps
     every (BX, sy, sz) block row-aligned in the flat buffer AND the relayout

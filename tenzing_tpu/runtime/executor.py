@@ -47,7 +47,8 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from contextlib import contextmanager
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +60,65 @@ from tenzing_tpu.core.resources import Event, Lane
 from tenzing_tpu.core.sequence import Sequence
 from tenzing_tpu.core.serdes import sequence_to_json_str
 from tenzing_tpu.obs.tracer import get_tracer, short_digest
+
+
+# -- the first call of a program, in its parts --------------------------------
+#
+# jax.jit traces, lowers, compiles and runs inside ONE call, and an AOT
+# ``.lower().compile()`` is two; both tell ``jax.monitoring`` when the
+# StableHLO module is built and when the backend's compile (and load)
+# returns, on the thread that did the work.  An open first call
+# (:meth:`TraceExecutor._first_call`) walks its three child spans on those
+# two signals, so the lazy path keeps its lazily jitted callable and the
+# spans are real-time intervals (mirrored into a profiler session).
+
+_LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILED = "/jax/core/compile/backend_compile_duration"
+_FIRST_CALL_PARTS = ("executor.lower", "executor.xla_compile",
+                     "executor.first_run")
+_first_call_open = threading.local()  # .parts: this thread's open first call
+
+
+class _FirstCallParts:
+    """The child spans of one ``executor.first_call``, entered in order and
+    back to back: ``executor.lower`` from the call until the module is
+    lowered, ``executor.xla_compile`` until the backend hands back a loaded
+    executable, ``executor.first_run`` (``n``; none for an AOT compile,
+    which runs nothing) until the fence is fetched."""
+
+    def __init__(self, run_n: Optional[int]):
+        self._attrs = ({}, {}, {"n": run_n})
+        self._n_parts = 2 if run_n is None else 3
+        self._at = -1
+        self._ctx = None
+        self.advance(0)
+
+    def advance(self, to: int) -> None:
+        if to != self._at + 1:
+            return  # a nested or repeated signal: parts only move forward
+        self.close()
+        self._at = to
+        if to < self._n_parts:
+            self._ctx = get_tracer().span(_FIRST_CALL_PARTS[to],
+                                          **self._attrs[to])
+            self._ctx.__enter__()
+
+    def close(self) -> None:
+        if self._ctx is not None:
+            self._ctx.__exit__(None, None, None)
+            self._ctx = None
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    parts = getattr(_first_call_open, "parts", None)
+    if parts is not None:
+        if event == _LOWERED:
+            parts.advance(1)
+        elif event == _COMPILED:
+            parts.advance(2)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def _scalarize(leaf) -> Any:
@@ -321,6 +381,8 @@ class TraceExecutor:
         self.platform = platform
         self.init_bufs = dict(init_bufs)
         self._cache: Dict[str, Callable] = {}
+        # "n:" keys that precompile() put there and nobody has run yet
+        self._unrun: set = set()
         # compile-provenance tallies (the driver's ``perf`` meta block):
         # programs actually traced+XLA-compiled by THIS process and the wall
         # seconds they took — cache hits (in-memory or the persistent
@@ -335,6 +397,31 @@ class TraceExecutor:
         with self._stats_lock:
             self.compile_count += 1
             self.compile_secs += secs
+
+    @contextmanager
+    def _first_call(self, sched_json: str,
+                    run_n: Optional[int]) -> Iterator[None]:
+        """Round the first call of a newly built program — where jax traces
+        and lowers, XLA compiles and loads, and (unless ahead of time:
+        ``run_n`` None, span attr ``aot``) the program runs ``run_n``
+        samples for the first time.  Always timed into the
+        ``compile_count``/``compile_secs`` tallies (the driver's ``perf``
+        provenance: seconds of first calls, not of XLA alone); when the
+        tracer records, one ``executor.first_call`` span with the parts of
+        :class:`_FirstCallParts` as children.  ``schedule`` hashes the
+        UNPREFIXED schedule JSON, so it matches the ``bench.benchmark``
+        span's id for the same schedule."""
+        attrs = {"aot": True} if run_n is None else {}
+        t0 = time.perf_counter()
+        with get_tracer().span("executor.first_call",
+                               schedule=short_digest(sched_json), **attrs):
+            parts = _first_call_open.parts = _FirstCallParts(run_n)
+            try:
+                yield
+            finally:
+                _first_call_open.parts = None
+                parts.close()
+        self._note_compile(time.perf_counter() - t0)
 
     @staticmethod
     def place_host_buffers(bufs: Dict[str, Any], host_names) -> Dict[str, Any]:
@@ -431,28 +518,21 @@ class TraceExecutor:
         """One jitted program per schedule, cached by schedule JSON.
 
         The FIRST invocation of the returned callable — where jax.jit
-        actually traces and XLA-compiles — is always timed into the
-        ``compile_count``/``compile_secs`` tallies (the driver's ``perf``
-        provenance), and additionally recorded as an ``executor.compile``
-        span when tracing is enabled; steady-state calls pay one branch."""
+        actually traces and XLA-compiles — goes through
+        :meth:`_first_call`; steady-state calls pay one branch."""
         key = sequence_to_json_str(order)
         if key in self._cache:
             return self._cache[key]
-        tr = get_tracer()
-        sid = short_digest(key)
-        with tr.span("executor.build", schedule=sid,
-                     n_ops=len(order.vector())):
+        with get_tracer().span("executor.build", schedule=short_digest(key),
+                               n_ops=len(order.vector())):
             jitted = jax.jit(self._build(order))
         state = {"cold": True}
 
         def wrapped(bufs: Dict[str, Any]) -> Dict[str, Any]:
             if state["cold"]:
                 state["cold"] = False
-                t0 = time.perf_counter()
-                with get_tracer().span("executor.compile", schedule=sid):
-                    out = jitted(bufs)
-                self._note_compile(time.perf_counter() - t0)
-                return out
+                with self._first_call(key, run_n=1):
+                    return jitted(bufs)  # its first run: to the call's return
             return jitted(bufs)
 
         self._cache[key] = wrapped
@@ -497,31 +577,36 @@ class TraceExecutor:
             f = jax.jit(self._stepped_fn(ops))
             self._cache[key] = f
         bufs = self.init_bufs
-        if not newly_built:
-            def run_n(n: int) -> None:
-                jax.device_get(f(bufs, jnp.int32(n))[0])
-
-            return run_n
         # the first invocation of a newly-built program is where jax traces
-        # and XLA compiles (device_get blocks through both) — time it into
-        # the compile tallies, and (tracing enabled) record it as an
-        # executor.compile span so trace bundles attribute compile wall
-        # separately from steady-state measurement.  The id hashes the
-        # UNPREFIXED schedule JSON so it matches the bench.benchmark span's
-        # schedule_id for the same schedule.
-        sid = short_digest(sched_json)
-        state = {"cold": True}
+        # and XLA compiles (device_get blocks through both): a first call,
+        # kept apart from steady-state measurement in tallies and spans.  A
+        # program a prefetch worker compiled ahead has only its first run
+        # left, which loads it onto the device: that too is kept apart.
+        state = {"first": "call" if newly_built
+                 else "run" if key in self._unrun else None}
 
         def run_n(n: int) -> None:
-            if state["cold"]:
-                state["cold"] = False
-                t0 = time.perf_counter()
-                with get_tracer().span("executor.compile", schedule=sid,
-                                       n_samples=n):
-                    jax.device_get(f(bufs, jnp.int32(n))[0])
-                self._note_compile(time.perf_counter() - t0)
+            first = state["first"]
+            if first is not None:
+                state["first"] = None
+                n_dev = jnp.int32(n)  # its own tiny program, the first time
+                if first == "call":
+                    with self._first_call(sched_json, run_n=n):
+                        jax.device_get(f(bufs, n_dev)[0])
+                else:
+                    self._unrun.discard(key)
+                    with get_tracer().span("executor.first_run", n=n):
+                        jax.device_get(f(bufs, n_dev)[0])
                 return
-            jax.device_get(f(bufs, jnp.int32(n))[0])
+            tr = get_tracer()
+            with tr.span("executor.enqueue"):
+                # only the fence is kept: the host-space outputs are dropped
+                # while the program runs.  Held until the fence is fetched,
+                # their release (an unmap) costs the flagship 43-51 ms a
+                # dispatch after the wait (PERF.md, PR 25)
+                fence = f(bufs, jnp.int32(n))[0]
+            with tr.span("executor.fence_wait"):
+                jax.device_get(fence)
 
         return run_n
 
@@ -627,15 +712,13 @@ class TraceExecutor:
         if key in self._cache:
             return False
         stepped = self._stepped_fn(order.vector())
-        t0 = time.perf_counter()
-        with get_tracer().span("executor.compile",
-                               schedule=short_digest(sched_json), aot=True):
-            compiled = jax.jit(stepped).lower(
-                self.init_bufs, jnp.int32(1)).compile()
-        self._note_compile(time.perf_counter() - t0)
+        one = jnp.int32(1)
+        with self._first_call(sched_json, run_n=None):
+            compiled = jax.jit(stepped).lower(self.init_bufs, one).compile()
         # first writer wins: a foreground prepare_n racing this insert keeps
         # its own (equivalent) program; both callables answer identically
-        self._cache.setdefault(key, compiled)
+        if self._cache.setdefault(key, compiled) is compiled:
+            self._unrun.add(key)  # compiled, never run (prepare_n)
         return True
 
     # -- timed execution mode (the attribution profiler's entry point) ------

@@ -431,15 +431,15 @@ def hill_climb(
                             tr.event("learn.prune", where="local",
                                      schedule=schedule_id(cand_seq))
                         continue
-                if use_paired:
-                    res, accept, charge = paired_step(seq, cand_seq)
-                    if charge:
-                        spent += 1
-                else:
-                    res, charge = measured(cand_seq)
-                    if charge:
-                        spent += 1  # cache hits are free: don't charge
-                    accept = res is not None and res.pct50 < cur.pct50
+                with get_tracer().span("climb.iter", it=len(result.sims),
+                                       pos=i):
+                    if use_paired:
+                        res, accept, charge = paired_step(seq, cand_seq)
+                    else:
+                        res, charge = measured(cand_seq)
+                        accept = res is not None and res.pct50 < cur.pct50
+                if charge:
+                    spent += 1  # cache and journal hits are free
                 if accept:  # first improvement: move
                     cur, seq, decisions = res, cand_seq, cand_dec
                     improved = True

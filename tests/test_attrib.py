@@ -437,12 +437,3 @@ def test_report_labels_truncated_histograms(tmp_path):
     assert "| short.series | 10 | 1 | 0.1 | 0.2 | full |" in text
 
 
-# -- utils/profiling back-compat shim ---------------------------------------
-
-def test_profiling_shim_reexports_xplane():
-    from tenzing_tpu.obs.attrib import xplane
-    from tenzing_tpu.utils import profiling
-
-    assert profiling.analyze_trace is xplane.analyze_trace
-    assert profiling.capture_trace is xplane.capture_trace
-    assert profiling.merge_intervals is xplane.merge_intervals

@@ -81,7 +81,7 @@ def test_cold_item_through_queue_wait_drain_merge_golden():
         span("serve.cache_probe", 60, 100),
         event("serve.enqueue", 250, exact="e1", reason="cold"),
         span("daemon.drain", 1000, 4500, exact="e1"),
-        span("executor.compile", 1100, 900),
+        span("executor.first_call", 1100, 900),
         span("bench.benchmark", 2100, 900),
         span("serve.store.flush", 4500, 400),
     ]
