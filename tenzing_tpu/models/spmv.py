@@ -12,11 +12,21 @@ the whole dataflow (ops_spmv.cuh:306-436).
 
 TPU-native design: the sparse kernel avoids cuSPARSE-style scalar gathers.  A CSR
 matrix is lowered once, host-side, to a dense **band/ELL slab**: values padded to
-a fixed row width with a companion column-index slab.  The SpMV is then
-``sum(vals * x[cols], axis=1)`` — a gather + VPU multiply-reduce over a static
-shape, which XLA vectorizes and tiles; for the band matrices of the reference's
-benchmark the slab is dense and this is bandwidth-optimal.  The remote half runs
-against the renumbered remote columns exactly like the reference's split SpMV.
+a fixed row width ``w`` with a companion column-index slab.  The workload's
+buffers hold the slab transposed, ``(w, m)`` and contiguous: the matrix's rows
+lie along the lanes, slab row ``j`` holds every matrix row's ``j``-th entry.
+The SpMV is a **column sweep**: a ``fori_loop`` over the ``w`` slab rows,
+``acc += vals_t[j] * x[cols_t[j]]`` — every operand 1-D, no reshape, the loop
+body compiled once whatever ``m`` is.  (The row-major form ``sum(vals *
+x[cols], axis=1)`` computes the same, but its minor dimension, ``w`` = 23..26,
+is no lane multiple: XLA flattens the slab for the gather and reshapes back,
+two physical relayouts whose code emission took 2.4 s of host time at
+m = 16 384 and 72 s at 150 000 — PERF.md, PR 26.)  The gather is where the time
+goes (7 ns an index on a v5e), so each half is swept only over the contiguous
+range of matrix rows that hold an entry of it (``A_*_rows``: the local half of
+a band matrix has none below the band, the remote half none above); y outside
+the range is 0.  The remote half runs against the renumbered remote columns
+exactly like the reference's split SpMV.
 
 The comm ops here are the single-device slice (device-local gather standing for
 the ICI exchange); the multi-chip exchange ops live in models/spmv_dist.py.
@@ -272,32 +282,62 @@ def split_local_remote(a: CsrMat, col_lo: int, col_hi: int) -> SplitMat:
 
 
 class SpMVOp(DeviceOp):
-    """ELL-slab SpMV: y = sum(vals * x[cols], axis=1) (reference SpMVKernel,
-    ops_spmv.cuh:61-163 — cuSPARSE there, gather+VPU-reduce here)."""
+    """ELL-slab SpMV over the transposed slab ``(w, m)``:
+    ``y = sum_j vals_t[j] * x[cols_t[j]]``, swept one slab row a loop step
+    (reference SpMVKernel, ops_spmv.cuh:61-163 — cuSPARSE there, a lane-dense
+    gather + VPU multiply-add here).
 
-    def __init__(self, name: str, x: str, y: str, vals: str, cols: str):
+    ``rows`` names an int32 buffer ``arange(lo, hi)``: the contiguous range of
+    matrix rows that hold an entry (``arange(m)`` for a matrix with entries in
+    every row).  Only that range is swept and y is 0 outside it.  The graph is
+    built before the matrix is known, so the range travels in a buffer: its
+    extent is the buffer's shape (static, as the loop's operands need it), its
+    start the buffer's first value.  (A slab cut to the range on the host,
+    with the start in a 1-element buffer, iterated 2% slower on the chip:
+    4.271 against 4.188 ms at 16 384 rows, PERF.md, PR 26.)"""
+
+    def __init__(self, name: str, x: str, y: str, vals: str, cols: str,
+                 rows: str):
         super().__init__(name)
         self._x, self._y, self._vals, self._cols = x, y, vals, cols
+        self._rows = rows
 
     def reads(self):
-        return [self._x, self._vals, self._cols]
+        return [self._x, self._vals, self._cols, self._rows]
 
     def writes(self):
         return [self._y]
 
+    def _product(self, vals_t, cols_t, x):
+        """``sum_j vals_t[j] * x[cols_t[j]]`` for a ``(w, r)`` slab."""
+        import jax.numpy as jnp
+        from jax import lax
+
+        w, r = vals_t.shape
+
+        def column(j, acc):
+            return acc + vals_t[j] * x[cols_t[j]]
+
+        return lax.fori_loop(0, w, column, jnp.zeros((r,), vals_t.dtype))
+
     def apply(self, bufs, ctx):
         import jax.numpy as jnp
+        from jax import lax
 
-        vals, cols, x = bufs[self._vals], bufs[self._cols], bufs[self._x]
-        return {self._y: jnp.sum(vals * x[cols], axis=1)}
+        vals_t, cols_t, x = bufs[self._vals], bufs[self._cols], bufs[self._x]
+        w, m = vals_t.shape
+        rows = bufs[self._rows]
+        lo, r = rows[0], rows.shape[0]
+        held = self._product(lax.dynamic_slice(vals_t, (0, lo), (w, r)),
+                             lax.dynamic_slice(cols_t, (0, lo), (w, r)), x)
+        return {self._y: lax.dynamic_update_slice(
+            jnp.zeros((m,), held.dtype), held, (lo,))}
 
-    # megakernel fusion (runtime/fused.py): rows are independent — the slab
-    # and output decompose along axis 0; the gathered x must stay whole
+    # megakernel fusion (runtime/fused.py): a pure buffer->buffer function,
+    # so it fuses; the row range counts from the whole slab's first row, so
+    # the op declares no tiling and its regions are single-tile kernels
     def fusible(self) -> bool:
         return True
-
-    def fuse_tiling(self):
-        return {self._vals: 0, self._cols: 0, self._y: 0, self._x: None}
 
 
 class SpMVPallasOp(SpMVOp):
@@ -307,13 +347,12 @@ class SpMVPallasOp(SpMVOp):
     hardware note) so the op is always valid; where both kernels apply, which
     is faster is the solver's ChoiceOp question."""
 
-    def apply(self, bufs, ctx):
+    def _product(self, vals_t, cols_t, x):
         from tenzing_tpu.ops.spmv_pallas import ell_spmv_pallas, supports
 
-        vals, cols, x = bufs[self._vals], bufs[self._cols], bufs[self._x]
         if not supports(x.shape[0]):
-            return super().apply(bufs, ctx)
-        return {self._y: ell_spmv_pallas(vals, cols, x)}
+            return super()._product(vals_t, cols_t, x)
+        return ell_spmv_pallas(vals_t, cols_t, x)
 
     def uses_pallas(self) -> bool:
         return True
@@ -330,18 +369,17 @@ class SpMVImplChoice(ChoiceOp):
     double the structural-variant space with duplicate candidates (ADVICE r1)."""
 
     def __init__(self, name: str, x: str, y: str, vals: str, cols: str,
-                 x_size: Optional[int] = None):
+                 rows: str, x_size: Optional[int] = None):
         super().__init__(name)
-        self._args = (x, y, vals, cols)
+        self._args = (x, y, vals, cols, rows)
         self._x_size = x_size
 
     def choices(self) -> List[OpBase]:
         from tenzing_tpu.ops.spmv_pallas import supports
 
-        x, y, vals, cols = self._args
-        out: List[OpBase] = [SpMVOp(self.name() + ".xla", x, y, vals, cols)]
+        out: List[OpBase] = [SpMVOp(self.name() + ".xla", *self._args)]
         if self._x_size is None or supports(self._x_size):
-            out.append(SpMVPallasOp(self.name() + ".pallas", x, y, vals, cols))
+            out.append(SpMVPallasOp(self.name() + ".pallas", *self._args))
         return out
 
 
@@ -500,14 +538,16 @@ class SpMVCompound(CompoundOp):
     def graph(self) -> Graph:
         g = Graph()
         if self._impl_choice:
-            def mk(name, x, y, vals, cols):
-                return SpMVImplChoice(name, x, y, vals, cols,
+            def mk(name, x, y, vals, cols, rows):
+                return SpMVImplChoice(name, x, y, vals, cols, rows,
                                       x_size=self._x_sizes.get(x))
         else:
             mk = SpMVOp
-        yl = mk("spmv_local", "x_local", "y_local", "A_loc_vals", "A_loc_cols")
+        yl = mk("spmv_local", "x_local", "y_local", "A_loc_vals", "A_loc_cols",
+                "A_loc_rows")
         scatter = Scatter("scatter", "x_local", "send_idx", "send_buf")
-        yr = mk("spmv_remote", "x_remote", "y_remote", "A_rem_vals", "A_rem_cols")
+        yr = mk("spmv_remote", "x_remote", "y_remote", "A_rem_vals",
+                "A_rem_cols", "A_rem_rows")
         add = VectorAdd("y_add", "y_local", "y_remote", "y")
         g.start_then(yl)
         g.start_then(scatter)
@@ -559,6 +599,14 @@ class SpMVCompound(CompoundOp):
         return g
 
 
+def _held_rows(a: CsrMat) -> np.ndarray:
+    """``arange(lo, hi)`` over the contiguous range of rows of ``a`` that hold
+    an entry (``SpMVOp``'s ``rows``); row 0 alone for an empty matrix."""
+    held = np.flatnonzero(a.row_widths())
+    lo, hi = (held[0], held[-1] + 1) if len(held) else (0, 1)
+    return np.arange(lo, hi, dtype=np.int32)
+
+
 def make_spmv_buffers(
     m: int = 4096,
     nnz_per_row: int = 10,
@@ -583,8 +631,9 @@ def make_spmv_buffers(
         a = random_band_matrix(m, bw, nnz_per_row * m, seed=seed)
     half = m // 2
     sp = split_local_remote(a, 0, half)
-    lv, lc = sp.local.to_slab(slab_width)
-    rv, rc = sp.remote.to_slab(slab_width)
+    # the slabs as SpMVOp sweeps them: (w, m), the matrix's rows contiguous
+    lv, lc = (np.ascontiguousarray(s.T) for s in sp.local.to_slab(slab_width))
+    rv, rc = (np.ascontiguousarray(s.T) for s in sp.remote.to_slab(slab_width))
     rng = np.random.default_rng(seed + 1)
     x = rng.random(m, dtype=np.float32)
     # remote x entries come from the "other rank"'s region via scatter+exchange
@@ -597,6 +646,8 @@ def make_spmv_buffers(
         "A_loc_cols": lc,
         "A_rem_vals": rv,
         "A_rem_cols": rc,
+        "A_loc_rows": _held_rows(sp.local),
+        "A_rem_rows": _held_rows(sp.remote),
         "send_idx": send_idx,
         "send_buf": np.zeros(len(send_idx), dtype=np.float32),
         # staging buffer for the exchange="host" round trip (place in

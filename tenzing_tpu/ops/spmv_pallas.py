@@ -1,8 +1,16 @@
-"""Pallas ELL-slab SpMV kernel: y = sum(vals * x[cols], axis=1).
+"""Pallas ELL-slab SpMV kernel: y[i] = sum_j vals_t[j, i] * x[cols_t[j, i]].
 
 TPU-native replacement for the reference's cuSPARSE ``cusparseSpMV`` call
 (ops_spmv.cuh:61-163) and hand-rolled CUDA ``spmv`` kernel (ops_spmv.cuh:25-39),
-operating on the ELL/band slab built by ``CsrMat.to_slab`` (models/spmv.py).
+operating on the ELL/band slab of the SpMV workload's buffers
+(models/spmv.py ``make_spmv_buffers``).
+
+Layout: the caller hands the slab as the workload stores it, transposed and
+contiguous, ``(w, m)`` — the matrix's rows along the lanes, which is what the
+XLA path (``SpMVOp``'s column sweep) wants.  The kernel itself works on
+row-major ``(block_m, 128)`` tiles, so ``ell_spmv_pallas`` pads the slab to
+``(w_pad, m_pad)`` (a lane multiple of slab rows, a block multiple of matrix
+rows) and transposes it once, tile-aligned, before the ``pallas_call``.
 
 Hardware note (probed on TPU v5e, jax 0.9 Mosaic): in-kernel dynamic gather
 (``tpu.dynamic_gather``) requires operand/indices/output to share one 2D shape
@@ -76,30 +84,29 @@ def _ell_kernel(vals_ref, cols_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
 def ell_spmv_pallas(
-    vals: jax.Array,
-    cols: jax.Array,
+    vals_t: jax.Array,
+    cols_t: jax.Array,
     x: jax.Array,
     *,
     block_m: int = 512,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """y[i] = sum_j vals[i, j] * x[cols[i, j]] via the masked vreg-gather kernel."""
+    """y[i] = sum_j vals_t[j, i] * x[cols_t[j, i]] via the masked vreg-gather
+    kernel, for the transposed ``(w, m)`` slab."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    m, w = vals.shape
+    w, m = vals_t.shape
     n = x.shape[0]
-    # pad the slab width to a lane multiple (cols 0 / vals 0: contributes 0)
+    # pad the slab width to a lane multiple and the rows to a block multiple
+    # (cols 0 / vals 0: contributes 0), then hand the kernel row-major tiles
     w_pad = -(-w // LANES) * LANES
-    if w_pad != w:
-        vals = jnp.pad(vals, ((0, 0), (0, w_pad - w)))
-        cols = jnp.pad(cols, ((0, 0), (0, w_pad - w)))
     n_pad = -(-n // LANES) * LANES
     xp = jnp.pad(x, (0, n_pad - n)) if n_pad != n else x
     block_m = min(block_m, max(8, m))
     m_pad = -(-m // block_m) * block_m
-    if m_pad != m:
-        vals = jnp.pad(vals, ((0, m_pad - m), (0, 0)))
-        cols = jnp.pad(cols, ((0, m_pad - m), (0, 0)))
+    pad = ((0, w_pad - w), (0, m_pad - m))
+    vals = jnp.pad(vals_t, pad).T
+    cols = jnp.pad(cols_t, pad).T
     y = pl.pallas_call(
         _ell_kernel,
         grid=(m_pad // block_m,),

@@ -404,8 +404,9 @@ class TestFusedVsSteppedAttn:
 
 
 class TestFusedVsSteppedSpmv:
-    """CPU equality on the spmv workload (local exchange, tiling collapses
-    to 1 because x_remote is written tiled but gathered whole)."""
+    """CPU equality on the spmv workload (local exchange; single-tile: an
+    ``SpMVOp`` sweeps a row range counted from the slab's first row and
+    declares no tiling)."""
 
     def _setup(self):
         from tenzing_tpu.models.spmv import SpMVCompound, make_spmv_buffers
@@ -425,9 +426,9 @@ class TestFusedVsSteppedSpmv:
         plan = fex.plan(seq)
         assert len(plan.regions) == 1
         assert plan.regions[0].n_ops == 5
-        # exchange writes x_remote tiled, spmv_remote gathers it whole:
-        # the region admits no common decomposition
+        # an untileable member (SpMVOp): the region is a single-tile kernel
         assert plan.tile_menu == [1]
+        assert plan.regions[0].valid_tiles == [1]
 
     def test_bit_identical_and_correct(self):
         g, seq, ex, want = self._setup()

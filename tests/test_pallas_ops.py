@@ -21,7 +21,7 @@ class TestEllSpmvPallas:
         a = _band(300, 40, 3000, seed=1)
         v, c = a.to_slab()
         x = np.random.default_rng(0).random(a.n, dtype=np.float32)
-        got = ell_spmv_pallas(jnp.asarray(v), jnp.asarray(c), jnp.asarray(x))
+        got = ell_spmv_pallas(jnp.asarray(v.T), jnp.asarray(c.T), jnp.asarray(x))
         np.testing.assert_allclose(np.asarray(got), a.matvec(x), rtol=2e-3)
 
     def test_wide_slab_and_row_padding(self):
@@ -32,7 +32,8 @@ class TestEllSpmvPallas:
         v, c = a.to_slab()
         assert v.shape[1] > 128
         x = np.random.default_rng(1).random(a.n, dtype=np.float32)
-        got = ell_spmv_pallas(jnp.asarray(v), jnp.asarray(c), jnp.asarray(x), block_m=32)
+        got = ell_spmv_pallas(jnp.asarray(v.T), jnp.asarray(c.T), jnp.asarray(x),
+                              block_m=32)
         np.testing.assert_allclose(np.asarray(got), a.matvec(x), rtol=2e-3)
 
     def test_supports_gate(self):
@@ -50,12 +51,14 @@ class TestEllSpmvPallas:
         rng = np.random.default_rng(0)
         bufs = {
             "x": jnp.asarray(rng.random(n, dtype=np.float32)),
-            "vals": jnp.asarray(rng.random((16, 3), dtype=np.float32)),
-            "cols": jnp.asarray(rng.integers(0, n, size=(16, 3)), jnp.int32),
+            # the workload's layout: (w, m), matrix rows along the lanes
+            "vals": jnp.asarray(rng.random((3, 16), dtype=np.float32)),
+            "cols": jnp.asarray(rng.integers(0, n, size=(3, 16)), jnp.int32),
+            "rows": jnp.arange(16, dtype=jnp.int32),  # every row holds entries
             "y": jnp.zeros(16, jnp.float32),
         }
-        out = SpMVPallasOp("k", "x", "y", "vals", "cols").apply(bufs, None)
-        want = np.sum(np.asarray(bufs["vals"]) * np.asarray(bufs["x"])[np.asarray(bufs["cols"])], axis=1)
+        out = SpMVPallasOp("k", "x", "y", "vals", "cols", "rows").apply(bufs, None)
+        want = np.sum(np.asarray(bufs["vals"]) * np.asarray(bufs["x"])[np.asarray(bufs["cols"])], axis=0)
         np.testing.assert_allclose(np.asarray(out["y"]), want, rtol=1e-5)
 
 

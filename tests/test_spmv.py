@@ -232,3 +232,136 @@ def test_spmv_host_exchange_schedules_correct():
                 and ns.index("fetch_x") < ns.index("spmv_local") < ns.index("await_x"))
 
     assert any(overlapped(st) for st in states)
+
+
+# -- the column sweep over the transposed slab (PR 26) ---------------------------
+
+_OPS = ["SpMVOp", "SpMVPallasOp"]  # the latter in the Pallas interpreter
+
+
+def _op(kind):
+    from tenzing_tpu.models import spmv
+
+    return getattr(spmv, kind)("k", "x", "y", "vals", "cols", "rows")
+
+
+def _rows_of_width(m, width, seed):
+    """An m x m matrix whose every row holds exactly ``width`` entries."""
+    rng = np.random.default_rng(seed)
+    rows = np.repeat(np.arange(m), width)
+    cols = rng.integers(0, m, size=m * width)
+    vals = rng.random(m * width, dtype=np.float32)
+    return CooMat(m, m, rows, cols, vals).to_csr()
+
+
+@pytest.mark.parametrize("kind", _OPS)
+@pytest.mark.parametrize("width", [1, 11])
+@pytest.mark.parametrize("m", [1, 8, 64, 513])
+def test_spmv_op_matches_matvec(kind, m, width):
+    """``SpMVOp`` (the column sweep) and ``SpMVPallasOp`` on the transposed
+    ``(w, m)`` slab against the host's ``CsrMat.matvec``: one row, one
+    sublane tile, sizes off every tile multiple, slab width 1 and > 8."""
+    import jax.numpy as jnp
+
+    a = _rows_of_width(m, width, seed=m + width)
+    vals, cols = a.to_slab()
+    assert vals.shape == (m, width)
+    x = np.random.default_rng(7).random(m, dtype=np.float32)
+    out = _op(kind).apply({"vals": jnp.asarray(vals.T),
+                           "cols": jnp.asarray(cols.T),
+                           "rows": jnp.arange(m, dtype=jnp.int32),
+                           "x": jnp.asarray(x)}, None)
+    assert out["y"].shape == (m,) and out["y"].dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out["y"]), a.matvec(x), rtol=1e-5)
+
+
+def _empty_ends(tmp_path):
+    """Rows 0-4 and 27-31 hold nothing, on both sides of the column split."""
+    rng = np.random.default_rng(11)
+    rows = rng.integers(5, 27, size=120)
+    cols = rng.integers(0, 32, size=120)
+    return CooMat(32, 32, rows, cols, rng.random(120, dtype=np.float32)).to_csr()
+
+
+def _empty_remote(tmp_path):
+    """Every entry in the local columns [0, m/2): the remote half is empty
+    and ``send_idx`` degenerates to one index."""
+    rng = np.random.default_rng(12)
+    rows = rng.integers(0, 24, size=90)
+    cols = rng.integers(0, 12, size=90)
+    return CooMat(24, 24, rows, cols, rng.random(90, dtype=np.float32)).to_csr()
+
+
+def _from_matrix_market(tmp_path):
+    from tenzing_tpu.models.spmv import read_matrix_market
+
+    path = tmp_path / "sym.mtx"
+    path.write_text(
+        "%%MatrixMarket matrix coordinate real symmetric\n6 6 7\n"
+        "1 1 1.0\n3 1 2.0\n3 2 3.0\n5 4 -1.5\n6 1 0.25\n6 6 4.0\n4 4 2.0\n")
+    return read_matrix_market(str(path))
+
+
+def _band(tmp_path):
+    return random_band_matrix(96, 12, 4 * 96, seed=3)
+
+
+_MATRICES = {"empty_ends": _empty_ends, "empty_remote": _empty_remote,
+             "matrix_market": _from_matrix_market, "band": _band}
+
+
+@pytest.mark.parametrize("case", list(_MATRICES))
+def test_spmv_buffers_are_transposed_slabs(case, tmp_path):
+    """``make_spmv_buffers`` holds each slab ``(w, m)`` and C-contiguous:
+    the matrix's rows along the lanes, slab row j every row's j-th entry;
+    beside it the contiguous range of rows that hold an entry."""
+    a = _MATRICES[case](tmp_path)
+    bufs, _ = make_spmv_buffers(matrix=a)
+    sp = split_local_remote(a, 0, a.m // 2)
+    for half, part in (("loc", sp.local), ("rem", sp.remote)):
+        vals, cols = bufs[f"A_{half}_vals"], bufs[f"A_{half}_cols"]
+        want_vals, want_cols = part.to_slab()
+        assert vals.shape == cols.shape == (want_vals.shape[1], a.m)
+        assert vals.flags["C_CONTIGUOUS"] and cols.flags["C_CONTIGUOUS"]
+        assert vals.dtype == np.float32 and cols.dtype == np.int32
+        np.testing.assert_array_equal(vals, want_vals.T)
+        np.testing.assert_array_equal(cols, want_cols.T)
+        rows = bufs[f"A_{half}_rows"]
+        held = np.flatnonzero(part.row_widths())
+        assert rows.dtype == np.int32
+        if len(held):
+            np.testing.assert_array_equal(
+                rows, np.arange(held[0], held[-1] + 1))
+    if case == "empty_ends":
+        assert bufs["A_loc_rows"][0] == 5 and bufs["A_rem_rows"][-1] == 26
+    if case == "empty_remote":
+        assert bufs["send_idx"].shape == (1,) and not sp.remote.nnz()
+        np.testing.assert_array_equal(bufs["A_rem_rows"], [0])
+
+
+@pytest.mark.parametrize("kind", _OPS)
+@pytest.mark.parametrize("case", ["empty_ends", "empty_remote",
+                                  "matrix_market"])
+def test_spmv_halves_match_matvec(kind, case, tmp_path):
+    """Both halves of the workload's product, on the buffers as
+    ``make_spmv_buffers`` lays them out and over the row ranges it names,
+    against ``CsrMat.matvec``."""
+    import jax.numpy as jnp
+
+    a = _MATRICES[case](tmp_path)
+    bufs, want = make_spmv_buffers(matrix=a)
+    x = jnp.asarray(bufs["x_local"])
+    op = _op(kind)
+    y_loc = op.apply({"vals": jnp.asarray(bufs["A_loc_vals"]),
+                      "cols": jnp.asarray(bufs["A_loc_cols"]),
+                      "rows": jnp.asarray(bufs["A_loc_rows"]), "x": x},
+                     None)["y"]
+    y_rem = op.apply({"vals": jnp.asarray(bufs["A_rem_vals"]),
+                      "cols": jnp.asarray(bufs["A_rem_cols"]),
+                      "rows": jnp.asarray(bufs["A_rem_rows"]),
+                      "x": x[jnp.asarray(bufs["send_idx"])]}, None)["y"]
+    assert y_loc.shape == y_rem.shape == (a.m,)
+    np.testing.assert_allclose(np.asarray(y_loc + y_rem), want, rtol=1e-5,
+                               atol=1e-7)
+    if case == "empty_ends":
+        assert not np.asarray(y_loc + y_rem)[[0, 4, 27, 31]].any()

@@ -13,6 +13,9 @@ Covered, at the shapes ``python bench.py`` builds without ``--smoke``:
   for (window 6+6, batched 4+4, flat 4+4), grid ``(3, 518, 520, 640)`` f32;
 * the fused and the split (semaphore-output) rdma copy of ops/rdma.py;
 * the moe/attention/spmv kernels;
+* the SpMV column sweep at the source's 150 000 rows and the whole naive
+  program of the benchmark's ``spmv16k`` configuration (one-shot and
+  repeat-n, ``host_x`` in pinned host memory);
 * two whole halo schedule programs (the naive baseline and the
   ``greedy-alias-6l`` incumbent), both as the single-shot program the
   integrity gate runs and as the repeat-n benchmark program, with the
@@ -94,6 +97,28 @@ def _grid(one_chip):
     shape = _padded_shape(FLAGSHIP.local_shape(), 4)
     assert shape == (3, 518, 520, 640)
     return _sds(shape, jnp.float32, one_chip)
+
+
+def _both_programs(plat, seq, bufs, host_names, one_chip):
+    """One schedule compiled both ways for the described chip: the one-shot
+    program the integrity gate runs, then the repeat-n benchmark program."""
+    from tenzing_tpu.runtime.executor import TraceExecutor
+
+    ex = TraceExecutor(plat, bufs)
+
+    def host_typed(b):
+        # an array committed to pinned_host traces as float32<host>; a
+        # ShapeDtypeStruct drops the memory space from its type, so restate
+        # it (a no-op placement: the argument already arrives there)
+        return {k: jax.device_put(v, jax.memory.Space.Host)
+                if k in host_names else v for k, v in b.items()}
+
+    program = ex.program(seq)
+    stepped = ex._stepped_fn(seq.vector())
+    n = _sds((), jnp.int32, one_chip)
+    yield jax.jit(lambda b: program(host_typed(b))).lower(bufs).compile()
+    yield jax.jit(lambda b, n: stepped(host_typed(b), n)).lower(
+        bufs, n).compile()
 
 
 def _assert_kernel(compiled):
@@ -285,7 +310,8 @@ def test_attention_block(one_chip):
 
 def test_spmv_ell(one_chip):
     """spmv: the 150000-row local ELL slab (width 26, make_spmv_buffers seed
-    0) against the largest x the kernel is offered for (``supports``: 4096 —
+    0; transposed ``(w, m)`` as the buffers hold it) against the largest x
+    the kernel is offered for (``supports``: 4096 —
     at the default m both x vectors are larger and the menu prunes it)."""
     from tenzing_tpu.ops.spmv_pallas import (
         LANES,
@@ -297,9 +323,97 @@ def test_spmv_ell(one_chip):
     n = LANES * MAX_X_BLOCKS
     assert supports(n) and not supports(n + 1)
     _assert_kernel(ell_spmv_pallas.lower(
-        _sds((150_000, 26), jnp.float32, one_chip),
-        _sds((150_000, 26), jnp.int32, one_chip),
+        _sds((26, 150_000), jnp.float32, one_chip),
+        _sds((26, 150_000), jnp.int32, one_chip),
         _sds((n,), jnp.float32, one_chip), interpret=False).compile())
+
+
+# -- the SpMV product and its whole naive program ------------------------------
+#
+# What these guard: for ``sum(vals * x[cols], axis=1)`` on a row-major
+# ``(m, w)`` slab XLA flattens the slab to ``[m*w]`` for the gather and
+# reshapes back, two physical relayouts (w = 23..26 is no lane multiple) whose
+# code emission takes 2.4 s of host time at 16 384 rows and 72 s at 150 000
+# (PERF.md, PR 26).  The column sweep has neither, and its loop body is
+# compiled once whatever m is.
+
+
+def _assert_swept(compiled, slabs):
+    """No flat ``[r*w]`` array for any ``(w, r)`` in ``slabs`` (slab width,
+    rows swept), and every gather of r entries of x sits in the body of a
+    ``while`` (the sweep over the slab's rows)."""
+    text = compiled.as_text()
+    assert " while(" in text
+    for w, r in slabs:
+        assert f"[{r * w}]" not in text, f"a flat [{r}*{w}] array"
+        gathers = [ln for ln in text.splitlines() if " gather(" in ln
+                   and f"f32[{r}]" in ln.split(" gather(")[0]]
+        assert gathers
+        assert all("/while/body/" in ln for ln in gathers)
+
+
+def test_spmv_sweep_150k(one_chip):
+    """The bare ``SpMVOp`` at the source's own size, m = 150 000, w = 26:
+    compiles inside 10 s here (0.7 s read; the row-major form: 72 s on the
+    chip's host, PERF.md PR 24 — a coarse guard with 15x of room)."""
+    import time
+
+    from tenzing_tpu.models.spmv import SpMVOp
+
+    m, w = 150_000, 26
+    op = SpMVOp("k", "x", "y", "vals", "cols", "rows")
+    t0 = time.perf_counter()
+    compiled = jax.jit(lambda vals, cols, rows, x: op.apply(
+        {"vals": vals, "cols": cols, "rows": rows, "x": x}, None)["y"]).lower(
+        _sds((w, m), jnp.float32, one_chip), _sds((w, m), jnp.int32, one_chip),
+        _sds((m,), jnp.int32, one_chip),
+        _sds((m,), jnp.float32, one_chip)).compile()
+    secs = time.perf_counter() - t0
+    _assert_swept(compiled, [(w, m)])
+    assert secs < 10.0, f"the 150000-row product took {secs:.1f} s to compile"
+
+
+def test_whole_spmv16k_program(topo, one_chip):
+    """The naive schedule of the benchmark's ``spmv16k`` configuration
+    (m = 16 384, nnz = 10 m, band m/4, host-staged x exchange, kernel menu):
+    the one-shot and the repeat-n program compile with ``host_x`` in pinned
+    host memory, sweep both products over their row ranges and hold no flat
+    slab."""
+    from jax.sharding import SingleDeviceSharding
+
+    from tenzing_tpu.bench.driver import naive_schedule
+    from tenzing_tpu.core.graph import Graph
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.spmv import (
+        SpMVCompound,
+        make_spmv_buffers,
+        spmv_host_buffer_names,
+    )
+
+    m = 16_384
+    np_bufs, _ = make_spmv_buffers(m=m, nnz_per_row=10, bw=m // 4, seed=0)
+    n_rem = int(np_bufs["x_remote"].shape[0])
+    slabs = [(np_bufs[f"A_{h}_vals"].shape[0], np_bufs[f"A_{h}_rows"].shape[0])
+             for h in ("loc", "rem")]
+    assert all(np_bufs[k].shape[1] == m for k in ("A_loc_vals", "A_rem_cols"))
+    assert all(r < m for _, r in slabs)  # a band: a quarter of each half empty
+    host_names = set(spmv_host_buffer_names(n_rem))
+    host = SingleDeviceSharding(topo.devices[0], memory_kind="pinned_host")
+    bufs = {k: _sds(v.shape, v.dtype, host if k in host_names else one_chip)
+            for k, v in np_bufs.items()}
+
+    def mk():
+        return SpMVCompound(impl_choice=True, exchange="host",
+                            x_sizes={"x_local": m, "x_remote": n_rem})
+
+    graph = Graph()
+    graph.start_then(mk())
+    graph.then_finish(mk())
+    seq = naive_schedule("spmv", graph, m)
+    for compiled in _both_programs(Platform.make_n_lanes(2), seq, bufs,
+                                   host_names, one_chip):
+        _assert_swept(compiled, slabs)
+        assert compiled.memory_analysis().host_output_size_in_bytes > 0
 
 
 # -- whole halo schedule programs ---------------------------------------------
@@ -366,31 +480,13 @@ def test_whole_halo_program(topo, one_chip, on_chip_kernels, which,
     6 rdma posts + 6 rdma waits) and fit the chip's memory."""
     from jax.sharding import SingleDeviceSharding
 
-    from tenzing_tpu.runtime.executor import TraceExecutor
-
     host = SingleDeviceSharding(topo.devices[0], memory_kind="pinned_host")
     shapes = _pipeline_shapes(FLAGSHIP)
     bufs = {k: _sds(s, jnp.float32, host if is_host else one_chip)
             for k, (s, is_host) in shapes.items()}
     host_names = {k for k, (_, is_host) in shapes.items() if is_host}
     plat, seq = _halo_schedule(which)
-    ex = TraceExecutor(plat, bufs)
-
-    def host_typed(b):
-        # an array committed to pinned_host traces as float32<host>; a
-        # ShapeDtypeStruct drops the memory space from its type, so restate
-        # it (a no-op placement: the argument already arrives there)
-        return {k: jax.device_put(v, jax.memory.Space.Host)
-                if k in host_names else v for k, v in b.items()}
-
-    program = ex.program(seq)
-    stepped = ex._stepped_fn(seq.vector())
-    n = _sds((), jnp.int32, one_chip)
-    for compiled in (
-        jax.jit(lambda b: program(host_typed(b))).lower(bufs).compile(),
-        jax.jit(lambda b, n: stepped(host_typed(b), n)).lower(
-            bufs, n).compile(),
-    ):
+    for compiled in _both_programs(plat, seq, bufs, host_names, one_chip):
         assert compiled.as_text().count("tpu_custom_call") == n_kernels
         m = compiled.memory_analysis()
         assert (m.argument_size_in_bytes + m.output_size_in_bytes
