@@ -8,6 +8,7 @@ of its own.  This module holds the name of none of them.
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import time
@@ -339,6 +340,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         device["busy_s"], device["window_s"] = w["busy_s"], w["window_s"]
         result["breakdown"] = {"device_ops": w["device_ops"],
                                "idle_gaps": w["idle_gaps"]}
+    # last in the line: every number compared, beside its limit
+    result["compared"] = {f"{c['schedule']}.{c['name']}":
+                          [c["value"], c["limit"]] for c in compared}
     out_dir.mkdir(parents=True, exist_ok=True)
     (out_dir / f"record.trace{int(trace)}.json").write_text(
         json.dumps({"record": record, "compared": compared,
@@ -404,6 +408,38 @@ def committed(bufs: dict) -> dict:
     return {k: jax.device_put(v, v.sharding) for k, v in bufs.items()}
 
 
+@functools.lru_cache(maxsize=None)
+def probe_fill(shape, dtype, low: int, sharding):
+    """The compiled program that draws one probe buffer: whole numbers
+    ``low`` to ``low + 4`` in ``dtype``, each device drawing its own shard of
+    ``sharding`` (the generator's values do not depend on the sharding), so
+    no device ever holds a sharded buffer's global shape."""
+    import jax
+
+    def fill(key):
+        return jax.random.randint(key, shape, low, low + 5).astype(dtype)
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return jax.jit(fill, out_shardings=sharding).lower(key).compile()
+
+
+def probe_fills(bufs: dict) -> dict:
+    """``{name: (position, program)}`` for the floating buffers of ``bufs``:
+    a buffer of up to 2**20 elements takes 0 to 4, a larger one -2 to 2,
+    drawn in device memory under the buffer's own sharding."""
+    import jax.numpy as jnp
+
+    fills = {}
+    for i, name in enumerate(sorted(bufs)):
+        v = bufs[name]
+        if jnp.issubdtype(v.dtype, jnp.floating):
+            device = next(iter(v.sharding.device_set)).default_memory().kind
+            fills[name] = (i, probe_fill(
+                v.shape, v.dtype, 0 if v.size <= 2 ** 20 else -2,
+                v.sharding.with_memory_kind(device)))
+    return fills
+
+
 def probe_buffers(bufs: dict, seed: int) -> dict:
     """Buffers shaped, typed and placed as ``bufs``, the floating ones filled
     with small whole numbers drawn from the seed (index buffers stay as they
@@ -414,18 +450,13 @@ def probe_buffers(bufs: dict, seed: int) -> dict:
     exact, so that a fault moves the fence by more than the rounding of what
     else the fence adds (an index buffer's sum can reach 2**31)."""
     import jax
-    import jax.numpy as jnp
 
     key = jax.random.key(seed & 0xFFFFFFFF)
-    out = {}
-    for i, name in enumerate(sorted(bufs)):
-        v = bufs[name]
-        if jnp.issubdtype(v.dtype, jnp.floating):
-            low = 0 if v.size <= 2 ** 20 else -2
-            fill = jax.random.randint(jax.random.fold_in(key, i), v.shape,
-                                      low, low + 5).astype(v.dtype)
-            v = jax.device_put(fill, v.sharding)
-        out[name] = v
+    out = dict(bufs)
+    for name, (i, fill) in probe_fills(bufs).items():
+        # placed as the buffer is (a pinned-host buffer moves there)
+        out[name] = jax.device_put(fill(jax.random.fold_in(key, i)),
+                                   bufs[name].sharding)
     return out
 
 
@@ -437,7 +468,10 @@ def timed_fence_gap(ex, order, n: int, probe: dict) -> float:
     states are the same and the gap is 0; a repeat-n program that skips an
     operation, loses its carry or never iterates leaves another state.  The
     fence is a sum, so it does not see a value that lands in the wrong cell:
-    that the one-shot comparison sees, on the same operations."""
+    that the one-shot comparison sees, on the same operations.  ``probe`` is
+    taken over and emptied once the one-shot program has run on it: the
+    zero-repeat call then finds two sets of buffers on a device beside the
+    program's own temporaries, not three (PERF.md, PR 27)."""
     import jax
     import jax.numpy as jnp
 
@@ -447,6 +481,7 @@ def timed_fence_gap(ex, order, n: int, probe: dict) -> float:
     after_n = float(jax.device_get(f(probe, jnp.int32(n))[0]))
     once = ex.compile(order)(probe)
     once = {k: jax.device_put(v, probe[k].sharding) for k, v in once.items()}
+    probe.clear()
     after_one = float(jax.device_get(f(once, jnp.int32(0))[0]))
     if variants() != before:
         raise RuntimeError("the probe made jax.jit compile the timed "
@@ -460,15 +495,23 @@ def compare(ex, check, verify, schedules, repeats: dict, seed: int) -> list:
     the plain reference on the run's data; the timed program itself, at the
     repeat count the clock timed it at, against that one-shot program on
     the probe; and the verifier's verdict on each finalist."""
-    probe = probe_buffers(ex.init_bufs, seed)
+    # the probe's programs go through the persistent cache (off since the
+    # window opened): only a checkout's first run compiles them, and none
+    # of it is set-up
+    persistent_cache(True)
+    try:
+        probe_fills(ex.init_bufs)
+    finally:
+        persistent_cache(False)
     compared = []
     for label, order in schedules:
         out = ex.run(order)
         compared += [{**c, "schedule": label} for c in check(out)]
         del out
         compared.append({"name": "timed_fence_gap", "schedule": label,
-                         "value": timed_fence_gap(ex, order, repeats[label],
-                                                  probe),
+                         "value": timed_fence_gap(
+                             ex, order, repeats[label],
+                             probe_buffers(ex.init_bufs, seed)),
                          "limit": 0})
         if label != "naive":
             compared.append({"name": "verifier_rejections", "schedule": label,

@@ -2,8 +2,10 @@
 
     python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The last line of standard output is the result object.  Everything else goes
-on earlier lines or under ``benchmarks/out/``.  ``--rehearse-cpu`` (private)
+The last line of standard output is the result object; its last key, and
+the last lines of standard error, hold every number compared for ``correct``
+beside its limit.  Everything else goes on earlier lines or under
+``benchmarks/out/``.  ``--rehearse-cpu`` (private)
 walks the same code at the configuration's toy shapes on ``JAX_PLATFORMS=cpu``
 and never prints a result line.
 """
@@ -43,6 +45,9 @@ def main(argv=None) -> int:
         print(f"refused: {e}", file=sys.stderr)
         return 2
     line = json.dumps(result)
+    for name, (value, limit) in result["compared"].items():
+        print(f"compared {name}: {value!r} (limit {limit!r})",
+              file=sys.stderr, flush=True)
     if args.rehearse_cpu:
         print(f"rehearsal on the CPU, not a result: {line}")
         print("rehearsal done")

@@ -12,12 +12,15 @@ import pytest
 from benchmarks.harness import cell as cell_mod
 
 HERE = Path(__file__).parent.parent
+BENCH = json.loads((HERE.parent / "BENCHMARK.json").read_text())
 
 
 def run(workload, seconds=4.0, trace=False, **kw):
     import jax
 
-    kw.setdefault("devices", jax.devices()[:1])
+    chips = next((w["chips"] for w in BENCH["workloads"]
+                  if w["name"] == workload), 1)
+    kw.setdefault("devices", jax.devices()[:chips])
     return cell_mod.run_cell(workload, 2**31 + 5, seconds, trace,
                              time.perf_counter(), rehearse=True, **kw)
 
@@ -47,7 +50,8 @@ def test_halo_run_with_a_cell_altered_where_it_is_produced_is_not_correct(
     assert r["correct"] is False
 
 
-@pytest.mark.parametrize("workload", ["halo512.climb", "spmv16k.dfs"])
+@pytest.mark.parametrize("workload", ["halo512.climb", "spmv16k.dfs",
+                                      "halo512-mesh4.mcts"])
 def test_run_whose_timed_program_returns_its_state_unchanged_is_not_correct(
         monkeypatch, workload):
     """Broken in the repeat-n program only (it never iterates): the
@@ -130,3 +134,15 @@ def test_harness_takes_a_four_device_configuration_as_data():
     assert r["correct"] is True
     assert r["device"]["count"] == 4
     assert r["attempted"] >= 2
+
+
+def test_sound_mesh_run_is_correct_and_measures_both_engines():
+    r = run("halo512-mesh4.mcts", seconds=8.0)
+    assert r["correct"] is True
+    assert r["device"]["count"] == 4 and r["failed"] == 0
+    assert set(r["metrics"]) == {"evals_per_s", "best_iter_ms", "setup_s"}
+    rec = json.loads((HERE / "out" / f"halo512-mesh4.mcts.seed{2**31 + 5}" /
+                      "record.trace0.json").read_text())["record"]
+    # the two engine-overlap schedules come first, then the tree search
+    assert rec["window"]["n_completed"] >= 3
+    assert not any("error" in c for c in rec["window"]["candidates"][:2])
