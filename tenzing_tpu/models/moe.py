@@ -10,27 +10,37 @@ other shards, so the layer is dispatch (all-to-all) -> expert FFN -> combine
 
 Design:
 
-* **Routing is host-side setup** (the analog of ``RowPartSpmv``'s send/recv
-  negotiation, row_part_spmv.cuh:259-423): top-1 gating over a fixed gate
-  matrix is evaluated on the host when buffers are built, producing static
-  per-(shard, expert) slot tables — ``disp_idx`` (which local token fills
-  each capacity slot) and ``disp_w`` (its gate weight; 0 marks padding).
-  Raggedness is handled by padding every (src, dst) pair to the common
-  capacity, exactly like the irregular SpMV's width-padded lists — there is
-  no ragged all-to-all on ICI.
+* **Which experts a token goes to is negotiated at set-up** (the analog of
+  ``RowPartSpmv``'s send/recv negotiation, row_part_spmv.cuh:259-423): the
+  ``top_k`` experts of every token are selected once, when buffers are
+  built, producing static per-(shard, expert) slot tables — ``disp_idx``
+  (which local token fills each capacity slot), ``comb_idx`` (which slots a
+  token's experts answer in) and ``disp_w`` (a slot's combine weight; 0
+  marks padding).  Raggedness is handled by padding every (src, expert)
+  pair to the common capacity, exactly like the irregular SpMV's
+  width-padded lists — there is no ragged all-to-all on ICI.  A shard holds
+  ``experts_per_shard`` experts (a grouped product over the experts held).
+* **Two scoring rules, one layer** (:class:`MoEArgs`).  ``"softmax"`` (the
+  default): ``disp_w`` is the selected experts' softmax probability, fixed
+  at set-up.  ``"sigmoid"`` (the deepseek_v3 rule, ``scoring_func: sigmoid``
+  / ``norm_topk_prob`` / ``routed_scaling_factor``): ``disp_w`` is computed
+  **in the iteration** by ``gate_c`` — scores ``sigmoid(x W_g)``, the
+  selected ones normalised to sum 1 and scaled — a chain that depends on
+  neither all-to-all, like the shared expert ``shared_c`` (``shared_ff``).
 * **The data plane is schedulable.**  Tokens are split into ``n_chunks``
   microbatch chunks; each chunk is an independent chain
 
       pack_c -> a2a_disp_c(post) -> await -> ffn_c -> a2a_comb_c(post)
-             -> await -> combine_c
+             -> await -> combine_c        (gate_c, shared_c -> combine_c)
 
   so the solver can pipeline chunks: expert compute of chunk 0 overlaps the
   dispatch of chunk 1 (the schedule MoE systems hand-tune; here it is
   *searched*).  The reference hard-codes its overlap discipline with
   post-all-before-wait-any edges (ops_halo_exchange.cu:249-256); this graph
   deliberately leaves that freedom to the search.
-* The expert FFN (gelu MLP, the MXU hot spot) has an implementation ChoiceOp:
-  XLA einsums vs the Pallas tiled-matmul kernel (ops/ffn_pallas.py).
+* The expert FFN (a gelu MLP, or the gated SwiGLU MLP with ``gated``; the
+  MXU hot spot) has an implementation ChoiceOp for the gelu form: XLA
+  einsums vs the Pallas tiled-matmul kernel (ops/ffn_pallas.py).
 
 Numerics are checked against a dense host evaluation of the routed layer
 (tests/test_moe.py; ``dryrun_multichip`` covers the full sharded path).
@@ -49,20 +59,66 @@ from tenzing_tpu.ops.comm_ops import AllToAllStart, AwaitTransfer
 
 AXIS = "ep"
 
+#: op-name prefixes of the post-all-before-await-any discipline (the
+#: reference's hard-coded overlap, ops_halo_exchange.cu:249-256), for
+#: ``solve/local.py`` ``phase_policy`` / ``greedy_phase_order``: every
+#: dispatch is posted before anything waits, the chains that cross no chip
+#: (gate, shared expert) run while it is in flight
+PHASES = ("start", "pack", "a2a_disp", "gate", "shared", "await_disp", "ffn",
+          "a2a_comb", "await_comb", "combine", "moe_concat", "finish")
+
 
 @dataclass(frozen=True)
 class MoEArgs:
-    n_ep: int  # expert-parallel shards == experts (one expert per shard)
+    """One expert-parallel layer.  The defaults are the one-expert-a-shard,
+    top-1 softmax, gelu layer; ``experts_per_shard=16, top_k=6, gated=True,
+    shared_ff=2816, scoring="sigmoid", routed_scale=2.446,
+    capacity_factor=1.5`` is Moonlight-16B-A3B's (deepseek_v3) over four
+    shards."""
+
+    n_ep: int  # expert-parallel shards
     tokens_per_shard: int = 16
     d_model: int = 8
     d_ff: int = 16
     n_chunks: int = 2  # microbatch chunks (the pipelining freedom)
     dtype: str = "float32"
+    experts_per_shard: int = 1  # resident experts a shard
+    top_k: int = 1  # experts a token is sent to
+    gated: bool = False  # (silu(x W1) * (x W3)) W2 instead of gelu(x W1) W2
+    shared_ff: int = 0  # width of the shared expert (0: none)
+    # slots per (source shard, expert, chunk) = ceil(factor * mean load);
+    # 0: the largest load routed (the data decide the shapes)
+    capacity_factor: float = 0.0
+    scoring: str = "softmax"  # "softmax": weights fixed at set-up;
+    # "sigmoid": normalised, scaled, computed in the iteration (gate_c)
+    routed_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring {self.scoring!r}")
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.n_experts} experts")
 
     @property
     def chunk_tokens(self) -> int:
         assert self.tokens_per_shard % self.n_chunks == 0
         return self.tokens_per_shard // self.n_chunks
+
+    @property
+    def n_experts(self) -> int:
+        return self.n_ep * self.experts_per_shard
+
+    @property
+    def gate_in_iteration(self) -> bool:
+        return self.scoring == "sigmoid"
+
+    def fixed_capacity(self):
+        """Slots per (source shard, expert, chunk) where the configuration
+        fixes them (``capacity_factor``), else ``None``."""
+        if not self.capacity_factor:
+            return None
+        mean = self.chunk_tokens * self.top_k / self.n_experts
+        return int(np.ceil(self.capacity_factor * mean))
 
 
 from tenzing_tpu.utils.numeric import gelu_tanh as _gelu
@@ -81,15 +137,137 @@ def top1_route(x: np.ndarray, wg: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     return expert, gate
 
 
-class DispatchPack(DeviceOp):
-    """Fill chunk ``c``'s capacity-padded send buffer from the local tokens the
-    router assigned to each expert (the gather the reference's Scatter op does
-    for the Ialltoallv send buffer, ops_spmv.cuh:194-215)."""
+def select_experts(args: MoEArgs, x, wg, bias):
+    """The set-up half of the router: ``(T, top_k)`` int32 expert ids, the
+    ``top_k`` largest of ``score(x wg) + bias`` in float32 at the highest
+    matmul precision (a rounding must not send a token elsewhere than a
+    reference does).  Softmax and sigmoid are monotone, so the selection
+    is the logits' except for the bias, which is added to the score."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.default_matmul_precision("highest"):
+        logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32))
+    score = (jax.nn.sigmoid(logits) if args.scoring == "sigmoid"
+             else jax.nn.softmax(logits, axis=1))
+    _, sel = jax.lax.top_k(score + bias.astype(jnp.float32)[None, :],
+                           args.top_k)
+    return sel.astype(jnp.int32)
+
+
+def gate_weights(args: MoEArgs, xc, wg, topk):
+    """The iteration half of the sigmoid router: ``(Tc, top_k)`` float32
+    combine weights of the selected experts ``topk`` — their scores
+    ``sigmoid(xc wg)``, normalised to sum 1 (``norm_topk_prob``; the
+    model's ``+ 1e-20``) and scaled (``routed_scaling_factor``)."""
+    import jax
+    import jax.numpy as jnp
+
+    s = jax.nn.sigmoid(jnp.dot(xc, wg, preferred_element_type=jnp.float32))
+    picked = jnp.take_along_axis(s, topk, axis=1)
+    return args.routed_scale * picked / (
+        picked.sum(axis=1, keepdims=True) + 1e-20)
+
+
+def expert_loads(sel, args: MoEArgs):
+    """``(n_chunks, n_experts)`` int32: tokens one shard's chunk sends to
+    each expert, from its ``(T, top_k)`` selection."""
+    import jax.numpy as jnp
+
+    e = sel.reshape(args.n_chunks, -1)
+    ids = jnp.arange(args.n_experts, dtype=sel.dtype)
+    return jnp.sum(e[:, :, None] == ids[None, None, :], axis=1,
+                   dtype=jnp.int32)
+
+
+def slot_tables(sel, args: MoEArgs, cap: int) -> Dict[str, object]:
+    """One shard's static slot tables from its ``(T, top_k)`` selection, as
+    the device ops index them (each with the shard's leading axis of 1
+    where the global buffer stacks shards):
+
+    * ``disp_idx_c`` (1, n_ep, E_l*cap): the chunk-local token in each slot
+      (0 in padding), slot ``e_l*cap + j`` of peer ``p`` the ``j``-th token,
+      in token order, selected for expert ``p*E_l + e_l``;
+    * ``slot_tk_c`` (1, n_ep, E_l*cap): the flat ``token*top_k + k`` whose
+      weight the slot carries, -1 in padding;
+    * ``comb_idx_c`` (Tc, top_k): the flat slot each selected expert of a
+      token answers in (the all-to-all back keeps the slot's place);
+    * ``topk_c`` (Tc, top_k): the selection itself.
+
+    A selection beyond ``cap`` finds no slot (counted by the caller from
+    :func:`expert_loads`; the builders refuse it)."""
+    import jax.numpy as jnp
+
+    k, tc, n_e = args.top_k, args.chunk_tokens, args.n_experts
+    a = jnp.arange(tc * k, dtype=jnp.int32)
+    out = {}
+    for c in range(args.n_chunks):
+        topk = sel[c * tc:(c + 1) * tc]
+        e = topk.reshape(-1)
+        onehot = (e[:, None] == jnp.arange(n_e, dtype=e.dtype)[None, :])
+        rank = jnp.take_along_axis(
+            jnp.cumsum(onehot.astype(jnp.int32), axis=0), e[:, None],
+            axis=1)[:, 0] - 1
+        # beyond capacity: an index past the table, which the scatter drops
+        slot = jnp.where(rank < cap, e * cap + rank, n_e * cap)
+        shape = (1, args.n_ep, args.experts_per_shard * cap)
+        out[f"disp_idx_{c}"] = jnp.zeros((n_e * cap,), jnp.int32).at[
+            slot].set(a // k, mode="drop").reshape(shape)
+        out[f"slot_tk_{c}"] = jnp.full((n_e * cap,), -1, jnp.int32).at[
+            slot].set(a, mode="drop").reshape(shape)
+        out[f"comb_idx_{c}"] = jnp.minimum(slot, n_e * cap - 1).reshape(tc, k)
+        out[f"topk_{c}"] = topk
+    return out
+
+
+def slot_weights(w_tk, slot_tk):
+    """Per-slot combine weights from per-(token, k) weights ``w_tk``
+    (Tc, top_k): the weight each slot's token gives the slot's expert, 0 in
+    padding."""
+    import jax.numpy as jnp
+
+    w = w_tk.reshape(-1)[jnp.maximum(slot_tk, 0)]
+    return jnp.where(slot_tk >= 0, w, jnp.zeros((), w.dtype))
+
+
+def _mlp(args: MoEArgs, x, w1, w3, w2, grouped: bool = False):
+    """The expert MLP in the layer's precision: inputs and weights in
+    ``args.dtype``, float32 accumulation, the hidden activation rounded to
+    ``args.dtype`` between the products.  Plain: ``x`` (t, d) through one
+    expert; ``grouped``: ``x`` (p, e, c, d) through expert ``e``'s weights
+    for each ``e`` (a grouped product over the experts held)."""
+    import jax
+    import jax.numpy as jnp
+
+    up, down = (("pecd,edf->pecf", "pecf,efd->pecd") if grouped
+                else ("td,df->tf", "tf,fd->td"))
+    f32 = jnp.float32
+    g = jnp.einsum(up, x, w1, preferred_element_type=f32)
+    if args.gated:
+        h = jax.nn.silu(g) * jnp.einsum(up, x, w3, preferred_element_type=f32)
+    else:
+        h = jax.nn.gelu(g)
+    return jnp.einsum(down, h.astype(x.dtype), w2, preferred_element_type=f32)
+
+
+class _ChunkOp(DeviceOp):
+    """A device op of chunk ``c``'s chain."""
 
     def __init__(self, name: str, c: int, args: MoEArgs):
         super().__init__(name)
         self._c = c
         self._args = args
+
+    def _tokens(self, bufs):
+        """The chunk's local tokens ``(Tc, d)``."""
+        tc_ = self._args.chunk_tokens
+        return bufs["X"][self._c * tc_ : (self._c + 1) * tc_]
+
+
+class DispatchPack(_ChunkOp):
+    """Fill chunk ``c``'s capacity-padded send buffer from the local tokens the
+    router assigned to each expert (the gather the reference's Scatter op does
+    for the Ialltoallv send buffer, ops_spmv.cuh:194-215)."""
 
     def reads(self):
         return ["X", f"disp_idx_{self._c}"]
@@ -98,41 +276,76 @@ class DispatchPack(DeviceOp):
         return [f"send_disp_{self._c}"]
 
     def apply(self, bufs, ctx):
-        tc_ = self._args.chunk_tokens
-        xc = bufs["X"][self._c * tc_ : (self._c + 1) * tc_]  # (Tc, d)
-        idx = bufs[f"disp_idx_{self._c}"][0]  # (n_ep, C)
-        return {f"send_disp_{self._c}": xc[idx]}  # (n_ep, C, d)
+        xc = self._tokens(bufs)  # (Tc, d)
+        idx = bufs[f"disp_idx_{self._c}"][0]  # (n_ep, E_l*C)
+        return {f"send_disp_{self._c}": xc[idx]}  # (n_ep, E_l*C, d)
 
 
-class ExpertFFN(DeviceOp):
-    """Run the resident expert's gelu MLP over every received token (the MXU
-    compute between the two exchanges).  Padding slots carry real numbers but
-    combine multiplies them by weight 0."""
-
-    def __init__(self, name: str, c: int, args: MoEArgs):
-        super().__init__(name)
-        self._c = c
-        self._args = args
+class GateWeights(_ChunkOp):
+    """Chunk ``c``'s combine weights, computed in the iteration (sigmoid
+    scoring): the score product ``x W_g`` over all experts, the selected
+    scores normalised and scaled, laid out slot by slot.  Depends on
+    neither all-to-all: the search may run it while a dispatch is in
+    flight."""
 
     def reads(self):
-        return [f"recv_disp_{self._c}", "W1", "W2"]
+        return ["X", "Wg", f"topk_{self._c}", f"slot_tk_{self._c}"]
+
+    def writes(self):
+        return [f"disp_w_{self._c}"]
+
+    def apply(self, bufs, ctx):
+        w_tk = gate_weights(self._args, self._tokens(bufs), bufs["Wg"],
+                            bufs[f"topk_{self._c}"])
+        return {f"disp_w_{self._c}": slot_weights(
+            w_tk, bufs[f"slot_tk_{self._c}"])}
+
+
+class SharedExpert(_ChunkOp):
+    """Chunk ``c`` through the shared expert (every token, no routing, no
+    exchange): the MLP of the experts' form at width ``shared_ff``."""
+
+    def reads(self):
+        return ["X", "Ws1", "Ws2"] + (["Ws3"] if self._args.gated else [])
+
+    def writes(self):
+        return [f"shared_out_{self._c}"]
+
+    def apply(self, bufs, ctx):
+        xc = self._tokens(bufs)
+        y = _mlp(self._args, xc, bufs["Ws1"], bufs.get("Ws3"), bufs["Ws2"])
+        return {f"shared_out_{self._c}": y.astype(xc.dtype)}
+
+
+class ExpertFFN(_ChunkOp):
+    """Run the resident experts' MLP over every received token (the MXU
+    compute between the two exchanges): a grouped product over the experts
+    held.  Padding slots carry real numbers but combine gives them weight
+    0."""
+
+    def reads(self):
+        return [f"recv_disp_{self._c}", "W1", "W2"] + (
+            ["W3"] if self._args.gated else [])
 
     def writes(self):
         return [f"ffn_out_{self._c}"]
 
-    def _mlp(self, x2d, w1, w2):
-        import jax
-        import jax.numpy as jnp
+    def _experts(self, x, w1, w3, w2):
+        """``x`` (rows, E_l, cap, d) through expert ``e``'s weights for each
+        ``e`` of the second axis."""
+        return _mlp(self._args, x, w1, w3, w2, grouped=True)
 
-        h = jax.nn.gelu(jnp.dot(x2d, w1, preferred_element_type=jnp.float32))
-        return jnp.dot(h.astype(x2d.dtype), w2, preferred_element_type=jnp.float32)
+    def _ffn(self, bufs, x):
+        """``x`` (rows, E_l*cap, d), rows by source shard, to the same shape."""
+        rows, slots, d = x.shape
+        e_l = bufs["W1"].shape[0]  # this shard's experts
+        y = self._experts(x.reshape(rows, e_l, slots // e_l, d), bufs["W1"],
+                          bufs.get("W3"), bufs["W2"])
+        return y.astype(x.dtype).reshape(rows, slots, d)
 
     def apply(self, bufs, ctx):
-        x = bufs[f"recv_disp_{self._c}"]  # (n_ep, C, d) rows by source shard
-        w1, w2 = bufs["W1"][0], bufs["W2"][0]  # this shard's expert
-        n, cap, d = x.shape
-        y = self._mlp(x.reshape(n * cap, d), w1, w2).astype(x.dtype)
-        return {f"ffn_out_{self._c}": y.reshape(n, cap, d)}
+        x = bufs[f"recv_disp_{self._c}"]  # (n_ep, E_l*C, d)
+        return {f"ffn_out_{self._c}": self._ffn(bufs, x)}
 
     # -- op-chunking protocol (core/chunking.py, T3): the expert MLP splits
     # over the source-shard rows of the received slot table (the token
@@ -177,9 +390,8 @@ class ExpertFFNPartial(ExpertFFN):
     def apply(self, bufs, ctx):
         from jax import lax
 
-        x = bufs[f"recv_disp_{self._c}"]  # (n_ep, C, d)
-        w1, w2 = bufs["W1"][0], bufs["W2"][0]
-        n, cap, d = x.shape
+        x = bufs[f"recv_disp_{self._c}"]  # (n_ep, E_l*C, d)
+        n = x.shape[0]
         if n % self._n_parts:
             # chunk validity was checked against the build-time n_ep —
             # fail at trace time rather than slice partial rows silently
@@ -187,20 +399,27 @@ class ExpertFFNPartial(ExpertFFN):
                 f"{self.name()}: {n} slot-table rows do not split "
                 f"{self._n_parts} ways")
         lo = self._part * (n // self._n_parts)
-        xs = x[lo : lo + n // self._n_parts]
-        y = self._mlp(xs.reshape(-1, d), w1, w2).astype(x.dtype)
-        y = y.reshape(n // self._n_parts, cap, d)
+        y = self._ffn(bufs, x[lo : lo + n // self._n_parts])
         return {f"ffn_out_{self._c}": lax.dynamic_update_slice_in_dim(
             bufs[f"ffn_out_{self._c}"], y, lo, 0)}
 
 
 class ExpertFFNPallas(ExpertFFN):
-    """Same MLP through the Pallas tiled-matmul kernel (ops/ffn_pallas.py)."""
+    """Same MLP through the Pallas tiled-matmul kernel (ops/ffn_pallas.py):
+    the gelu form, one expert at a time."""
 
-    def _mlp(self, x2d, w1, w2):
+    def _experts(self, x, w1, w3, w2):
+        import jax.numpy as jnp
+
         from tenzing_tpu.ops.ffn_pallas import ffn_pallas
 
-        return ffn_pallas(x2d, w1, w2)
+        if self._args.gated:
+            raise NotImplementedError("no gated Pallas expert kernel yet")
+        rows, e_l, cap, d = x.shape
+        return jnp.stack(
+            [ffn_pallas(x[:, e].reshape(rows * cap, d), w1[e],
+                        w2[e]).reshape(rows, cap, d) for e in range(e_l)],
+            axis=1)
 
     def uses_pallas(self) -> bool:
         return True
@@ -286,17 +505,16 @@ def moe_synth_plans(args: MoEArgs, c: int, site: str, cap: int = None):
         (cap, args.d_model), itemsize=np.dtype(args.dtype).itemsize)]
 
 
-class CombineScatter(DeviceOp):
-    """Scatter-add the returned expert outputs back into token order, scaled
-    by the gate weights (padding slots have weight 0)."""
-
-    def __init__(self, name: str, c: int, args: MoEArgs):
-        super().__init__(name)
-        self._c = c
-        self._args = args
+class CombineScatter(_ChunkOp):
+    """Bring the returned expert outputs back into token order: each token
+    gathers the slots its ``top_k`` experts answered in and sums them,
+    scaled by the slots' combine weights, in float32, on top of the shared
+    expert's output where the layer has one."""
 
     def reads(self):
-        return [f"recv_comb_{self._c}", f"disp_idx_{self._c}", f"disp_w_{self._c}"]
+        c = self._c
+        return [f"recv_comb_{c}", f"comb_idx_{c}", f"disp_w_{c}"] + (
+            [f"shared_out_{c}"] if self._args.shared_ff else [])
 
     def writes(self):
         return [f"Y_{self._c}"]
@@ -304,12 +522,19 @@ class CombineScatter(DeviceOp):
     def apply(self, bufs, ctx):
         import jax.numpy as jnp
 
-        vals = bufs[f"recv_comb_{self._c}"]  # (n_ep, C, d) rows by expert
-        idx = bufs[f"disp_idx_{self._c}"][0].reshape(-1)  # (n_ep*C,)
-        w = bufs[f"disp_w_{self._c}"][0].reshape(-1, 1)  # (n_ep*C, 1)
+        c = self._c
+        vals = bufs[f"recv_comb_{c}"]  # (n_ep, E_l*C, d) rows by expert shard
         d = vals.shape[-1]
-        y = jnp.zeros((self._args.chunk_tokens, d), vals.dtype)
-        return {f"Y_{self._c}": y.at[idx].add(w * vals.reshape(-1, d))}
+        vals = vals.reshape(-1, d)
+        idx = bufs[f"comb_idx_{c}"]  # (Tc, top_k) flat slots
+        w = bufs[f"disp_w_{c}"].reshape(-1)[idx].astype(jnp.float32)
+        if self._args.shared_ff:
+            y = bufs[f"shared_out_{c}"].astype(jnp.float32)
+        else:
+            y = jnp.zeros((self._args.chunk_tokens, d), jnp.float32)
+        for k in range(self._args.top_k):  # a fixed order of sums
+            y = y + w[:, k, None] * vals[idx[:, k]].astype(jnp.float32)
+        return {f"Y_{c}": y.astype(vals.dtype)}
 
 
 class ConcatChunks(DeviceOp):
@@ -337,7 +562,9 @@ class ConcatChunks(DeviceOp):
 
 class MoELayer(CompoundOp):
     """The whole EP layer as one compound: ``n_chunks`` independent
-    dispatch -> expert -> combine chains joined by the final concat.  With
+    dispatch -> expert -> combine chains joined by the final concat, each
+    combine also fed by the chunk's gate (sigmoid scoring) and shared
+    expert where the layer has them.  With
     ``impl_choice`` each chunk's FFN kernel is searched; ``chunk=True``
     adds T3-style chunked expert-FFN alternatives to the menus
     (core/chunking.py; :func:`ffn_chunk_menu` prunes the counts through
@@ -418,78 +645,214 @@ class MoELayer(CompoundOp):
                 pack, ffn)
             a2a(f"a2a_comb_{c}", f"ffn_out_{c}", f"recv_comb_{c}",
                 ffn, scat)
+            # the chains that cross no chip: free to run under either
+            # exchange of any chunk
+            if self._args.gate_in_iteration:
+                gate = GateWeights(f"gate_{c}", c, self._args)
+                g.start_then(gate)
+                g.then(gate, scat)
+            if self._args.shared_ff:
+                shared = SharedExpert(f"shared_{c}", c, self._args)
+                g.start_then(shared)
+                g.then(shared, scat)
             g.then(scat, cat)
         g.then_finish(cat)
         return g
+
+
+def buffer_layout(args: MoEArgs, cap: int = 1) -> Dict[str, tuple]:
+    """``{name: (global shape, dtype, partition spec)}`` of every buffer of
+    the layer on the ``("ep",)`` mesh at ``cap`` slots per (source shard,
+    expert, chunk): tokens, slot tables and exchange buffers by shard,
+    expert weights by expert, the router and the shared expert replicated.
+    Data and tables are made at set-up; what the iteration writes starts at
+    zero."""
+    from jax.sharding import PartitionSpec as P
+
+    n, d, t = args.n_ep, args.d_model, args.tokens_per_shard
+    tc, n_e, k, dt = args.chunk_tokens, args.n_experts, args.top_k, args.dtype
+    slots = args.experts_per_shard * cap
+    rows, stacked, everywhere = P(AXIS, None), P(AXIS, None, None), P()
+    out = {"X": ((n * t, d), dt, rows), "Y": ((n * t, d), dt, rows),
+           "W1": ((n_e, d, args.d_ff), dt, stacked),
+           "W2": ((n_e, args.d_ff, d), dt, stacked)}
+    if args.shared_ff:
+        out["Ws1"] = ((d, args.shared_ff), dt, everywhere)
+        out["Ws2"] = ((args.shared_ff, d), dt, everywhere)
+    if args.gated:
+        out["W3"] = out["W1"]
+        if args.shared_ff:
+            out["Ws3"] = out["Ws1"]
+    if args.gate_in_iteration:
+        out["Wg"] = ((d, n_e), dt, everywhere)
+    for c in range(args.n_chunks):
+        for nm in ("send_disp", "recv_disp", "ffn_out", "recv_comb"):
+            out[f"{nm}_{c}"] = ((n * n, slots, d), dt, stacked)
+        out[f"Y_{c}"] = ((n * tc, d), dt, rows)
+        out[f"disp_idx_{c}"] = ((n, n, slots), "int32", stacked)
+        out[f"comb_idx_{c}"] = ((n * tc, k), "int32", rows)
+        # in-iteration weights are float32; fixed ones the layer's dtype
+        out[f"disp_w_{c}"] = ((n, n, slots), "float32"
+                              if args.gate_in_iteration else dt, stacked)
+        if args.gate_in_iteration:
+            out[f"slot_tk_{c}"] = ((n, n, slots), "int32", stacked)
+            out[f"topk_{c}"] = ((n * tc, k), "int32", rows)
+        if args.shared_ff:
+            out[f"shared_out_{c}"] = ((n * tc, d), dt, rows)
+    return out
+
+
+def layer_specs(args: MoEArgs) -> Dict[str, object]:
+    """Partition spec of every buffer of the layer (:func:`buffer_layout`)."""
+    return {name: spec for name, (_, _, spec) in buffer_layout(args).items()}
+
+
+def note_routing(args: MoEArgs, cap: int, loads) -> int:
+    """Counters of what the set-up negotiation did, from the loads
+    ``(..., n_experts)`` of every (shard, chunk): ``moe.capacity_slots``
+    (slots the exchange carries), ``moe.routed_slots`` (slots that hold a
+    token), ``moe.max_expert_load`` (largest load of one (shard, expert,
+    chunk)), ``moe.dropped_slots`` (selections beyond capacity).  Returns
+    the last, and refuses a layer that drops: no token may be."""
+    from tenzing_tpu.obs.metrics import get_metrics
+
+    loads = np.asarray(loads).reshape(-1, args.n_experts)
+    dropped = int(np.maximum(loads - cap, 0).sum())
+    reg = get_metrics()
+    for name, n in (("capacity_slots", loads.size * cap),
+                    ("routed_slots", int(loads.sum()) - dropped),
+                    ("max_expert_load", int(loads.max())),
+                    ("dropped_slots", dropped)):
+        reg.counter(f"moe.{name}").inc(n)
+    if dropped:
+        raise ValueError(
+            f"{dropped} selection(s) beyond the capacity of {cap} slots per "
+            f"(shard, expert, chunk), largest load {int(loads.max())}: the "
+            "layer drops no token; raise capacity_factor")
+    return dropped
+
+
+def mesh_moe_buffers(args: MoEArgs, mesh, data: Dict[str, object]):
+    """``(buffers, specs)`` for the sigmoid-scored layer on ``mesh``
+    (``("ep",)``) from ``data`` that already lie there under
+    :func:`layer_specs` (``X``, the expert weights, ``Wg``, the shared
+    expert; ``gate_bias`` replicated, zeros if absent): the set-up
+    negotiation and the zeroed work buffers, every shard's made on its own
+    device — nothing of the global size passes through the host.  Needs a
+    fixed capacity (``capacity_factor``): the shapes are then the same for
+    every seed.  Raises where a selection finds no slot."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tenzing_tpu.obs.tracer import get_tracer
+
+    cap = args.fixed_capacity()
+    if cap is None or not args.gate_in_iteration:
+        raise ValueError("a layer made on the mesh needs capacity_factor and "
+                         "sigmoid scoring (make_moe_buffers makes the rest)")
+    specs = layer_specs(args)
+    bias = data.get("gate_bias")
+    if bias is None:
+        bias = jnp.zeros((args.n_experts,), jnp.float32)
+
+    def route(x, wg, b):
+        sel = select_experts(args, x, wg, b)
+        return slot_tables(sel, args, cap), expert_loads(sel, args)[None]
+
+    with get_tracer().span("moe.route", n_ep=args.n_ep, capacity=cap):
+        table_specs = {f"{nm}_{c}": specs[f"{nm}_{c}"]
+                       for c in range(args.n_chunks)
+                       for nm in ("disp_idx", "slot_tk", "comb_idx", "topk")}
+        tables, loads = jax.jit(jax.shard_map(
+            route, mesh=mesh, in_specs=(specs["X"], P(), P()),
+            out_specs=(table_specs, P(AXIS, None, None))))(
+                data["X"], data["Wg"], bias)
+        note_routing(args, cap, jax.device_get(loads))
+    bufs = {k: data[k] for k in specs if k in data}
+    bufs.update(tables)
+    for name, (shape, dtype, spec) in buffer_layout(args, cap).items():
+        if name not in bufs:  # what the iteration writes
+            bufs[name] = jnp.zeros(shape, dtype,
+                                   device=NamedSharding(mesh, spec))
+    return bufs, specs
 
 
 def make_moe_buffers(
     args: MoEArgs, seed: int = 0, synth: bool = False
 ) -> Tuple[Dict[str, np.ndarray], Dict[str, object], np.ndarray]:
     """(buffers, partition specs, expected Y) for the EP layer on a 1-D
-    ``("ep",)`` mesh.  Routing (top-1 gating) runs here, on the host, against
-    a fixed random gate matrix — the setup-negotiation analog; its product is
-    the static slot tables the device ops consume."""
-    from jax.sharding import PartitionSpec as P
+    ``("ep",)`` mesh, as host arrays (tests and small sizes;
+    :func:`mesh_moe_buffers` makes a layer of real size on its devices).
+    The selection runs here against a fixed random gate matrix — the
+    setup-negotiation analog; its product is the static slot tables the
+    device ops consume.  Expected Y: a dense float64 evaluation of the
+    routed softmax layer, the plain reference
+    (models/moe_reference.py) for the sigmoid one."""
+    import jax.numpy as jnp
 
     rng = np.random.default_rng(seed)
     n, t, d, dff = args.n_ep, args.tokens_per_shard, args.d_model, args.d_ff
-    tc_ = args.chunk_tokens
-    dt = np.dtype(args.dtype)
-    x = rng.standard_normal((n * t, d)).astype(dt)
-    wg = rng.standard_normal((d, n)).astype(dt)
-    w1 = rng.standard_normal((n, d, dff)).astype(dt) / np.sqrt(d)
-    w2 = rng.standard_normal((n, dff, d)).astype(dt) / np.sqrt(dff)
+    n_e, k = args.n_experts, args.top_k
+    dt = jnp.dtype(args.dtype)
+    if not args.gate_in_iteration and (k != 1 or args.gated
+                                       or args.shared_ff):
+        raise ValueError("softmax scoring is the top-1 gelu layer")
 
-    # host routing: top-1 expert + softmax gate weight per token
-    expert, gate = top1_route(x, wg)
+    def draw(*shape, fan_in=None):
+        a = rng.standard_normal(shape)
+        return (a / np.sqrt(fan_in) if fan_in else a).astype(dt)
 
-    # capacity: max tokens any (shard, chunk) sends to any expert
-    cap = 1
-    for s in range(n):
-        for c in range(args.n_chunks):
-            lo = s * t + c * tc_
-            e_blk = expert[lo : lo + tc_]
-            if len(e_blk):
-                cap = max(cap, int(np.bincount(e_blk, minlength=n).max()))
-
+    x = draw(n * t, d)
+    wg = draw(d, n_e)
     bufs: Dict[str, np.ndarray] = {
-        "X": x,
-        "W1": w1,
-        "W2": w2,
-        "Y": np.zeros((n * t, d), dt),
-    }
-    specs: Dict[str, object] = {
-        "X": P(AXIS, None),
-        "W1": P(AXIS, None, None),
-        "W2": P(AXIS, None, None),
-        "Y": P(AXIS, None),
-    }
-    for c in range(args.n_chunks):
-        idx = np.zeros((n, n, cap), dtype=np.int32)
-        w = np.zeros((n, n, cap), dtype=dt)
-        for s in range(n):
-            lo = s * t + c * tc_
-            fill = [0] * n
-            for j in range(tc_):
-                e = int(expert[lo + j])
-                idx[s, e, fill[e]] = j
-                w[s, e, fill[e]] = gate[lo + j]
-                fill[e] += 1
-        bufs[f"disp_idx_{c}"] = idx
-        bufs[f"disp_w_{c}"] = w
-        specs[f"disp_idx_{c}"] = P(AXIS, None, None)
-        specs[f"disp_w_{c}"] = P(AXIS, None, None)
-        for nm in (f"send_disp_{c}", f"recv_disp_{c}", f"ffn_out_{c}",
-                   f"recv_comb_{c}"):
-            bufs[nm] = np.zeros((n * n, cap, d), dt)
-            specs[nm] = P(AXIS, None, None)
-        bufs[f"Y_{c}"] = np.zeros((n * tc_, d), dt)
-        specs[f"Y_{c}"] = P(AXIS, None)
-        if synth:
-            # staging buffers for the synthesized ring all-to-all: plans
-            # price against the chunk_tokens upper bound, but allocation
-            # uses the routed capacity so runtime shapes line up
+        "X": x, "W1": draw(n_e, d, dff, fan_in=d),
+        "W2": draw(n_e, dff, d, fan_in=dff)}
+    if args.gated:
+        bufs["W3"] = draw(n_e, d, dff, fan_in=d)
+    if args.shared_ff:
+        bufs["Ws1"] = draw(d, args.shared_ff, fan_in=d)
+        bufs["Ws2"] = draw(args.shared_ff, d, fan_in=args.shared_ff)
+        if args.gated:
+            bufs["Ws3"] = draw(d, args.shared_ff, fan_in=d)
+
+    # the selection, and for softmax scoring the fixed weights
+    if args.gate_in_iteration:
+        bufs["Wg"] = wg / np.sqrt(d).astype(dt)
+        sel = np.asarray(select_experts(args, x, bufs["Wg"],
+                                        np.zeros(n_e, np.float32)))
+        gate = None
+    else:
+        expert, gate = top1_route(x, wg)
+        sel = expert[:, None].astype(np.int32)
+    per_shard = sel.reshape(n, t, k)
+    loads = np.stack([np.asarray(expert_loads(s, args)) for s in per_shard])
+    cap = args.fixed_capacity() or max(1, int(loads.max()))
+    note_routing(args, cap, loads)
+
+    specs = layer_specs(args)
+    tables = [slot_tables(s, args, cap) for s in per_shard]
+    for name in tables[0]:
+        if name in specs:
+            bufs[name] = np.concatenate([np.asarray(tb[name])
+                                         for tb in tables])
+    if gate is not None:
+        tc_ = args.chunk_tokens
+        for c in range(args.n_chunks):
+            bufs[f"disp_w_{c}"] = np.concatenate([
+                np.asarray(slot_weights(
+                    gate[s * t + c * tc_:s * t + (c + 1) * tc_, None].astype(dt),
+                    tables[s][f"slot_tk_{c}"])) for s in range(n)]).astype(dt)
+    for name, (shape, dtype, _) in buffer_layout(args, cap).items():
+        if name not in bufs:  # what the iteration writes
+            bufs[name] = np.zeros(shape, jnp.dtype(dtype))
+    if synth:
+        # staging buffers for the synthesized ring all-to-all: plans
+        # price against the chunk_tokens upper bound, but allocation
+        # uses the routed capacity so runtime shapes line up
+        from jax.sharding import PartitionSpec as P
+
+        for c in range(args.n_chunks):
             for site in ("disp", "comb"):
                 for plan in moe_synth_plans(args, c, site, cap=cap):
                     for decl in plan.buffers:
@@ -501,13 +864,24 @@ def make_moe_buffers(
                         specs[decl.name] = P(
                             AXIS, *([None] * (len(gshape) - 1)))
 
+    if args.gate_in_iteration:
+        from tenzing_tpu.models.moe_reference import moe_layer
+
+        shared = ((bufs["Ws1"], bufs["Ws3"], bufs["Ws2"])
+                  if args.shared_ff else None)
+        want = np.asarray(moe_layer(
+            jnp.asarray(x), bufs["Wg"], np.zeros(n_e, np.float32),
+            bufs["W1"], bufs["W3"], bufs["W2"], shared, k,
+            args.routed_scale))
+        return bufs, specs, want.astype(dt)
     # dense host reference: y[t] = gate * expert_e(x[t]) in float64
     x64 = x.astype(np.float64)
     want = np.zeros((n * t, d), np.float64)
-    for e in range(n):
-        sel = expert == e
-        h = _gelu(x64[sel] @ w1[e].astype(np.float64))
-        want[sel] = gate[sel, None] * (h @ w2[e].astype(np.float64))
+    for e in range(n_e):
+        sel_e = expert == e
+        h = _gelu(x64[sel_e] @ bufs["W1"][e].astype(np.float64))
+        want[sel_e] = gate[sel_e, None] * (
+            h @ bufs["W2"][e].astype(np.float64))
     # expected cast to the workload dtype (ADVICE r2) so a bf16 config
     # compares bf16-vs-bf16; callers comparing a non-f32 config must
     # choose tolerances to match (~0.4% relative at bf16)

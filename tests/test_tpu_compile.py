@@ -538,3 +538,51 @@ def test_mesh_halo_exchange(topo, on_chip_kernels, engine, marker):
     m = compiled.memory_analysis()  # bytes on each device
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes) < HBM_BYTES
+
+
+# -- the expert layer on four chips --------------------------------------------
+
+
+def test_moonlight_expert_layer(topo):
+    """models/moe.py at Moonlight-16B-A3B's published widths, expert-parallel
+    over the four described chips (what ``moonlight-ep4.mcts`` runs): the
+    repeat-n program of the post-all-before-await-any schedule compiles,
+    holds its eight all-to-alls, and leaves room for the three sets of
+    buffers ``correct`` keeps beside it."""
+    from jax.sharding import Mesh, NamedSharding
+
+    from tenzing_tpu.core.graph import Graph
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.moe import (
+        PHASES,
+        MoEArgs,
+        MoELayer,
+        buffer_layout,
+    )
+    from tenzing_tpu.runtime.executor import TraceExecutor
+    from tenzing_tpu.solve.greedy import greedy_phase_order
+
+    args = MoEArgs(n_ep=4, tokens_per_shard=8192, d_model=2048, d_ff=1408,
+                   n_chunks=4, dtype="bfloat16", experts_per_shard=16,
+                   top_k=6, gated=True, shared_ff=2816, capacity_factor=1.5,
+                   scoring="sigmoid", routed_scale=2.446)
+    assert args.fixed_capacity() == 288
+    mesh = Mesh(np.array(topo.devices), ("ep",))
+    layout = buffer_layout(args, 288)
+    specs = {name: spec for name, (_, _, spec) in layout.items()}
+    bufs = {name: _sds(shape, jnp.dtype(dt), NamedSharding(mesh, spec))
+            for name, (shape, dt, spec) in layout.items()}
+    plat = Platform.make_n_lanes(2, mesh=mesh, specs=specs)
+    layer = MoELayer(args)
+    g = Graph()
+    g.start_then(layer)
+    g.then_finish(layer)
+    seq = greedy_phase_order(g, plat, PHASES)
+    stepped = TraceExecutor(plat, bufs)._stepped_fn(seq.vector())
+    compiled = jax.jit(stepped).lower(
+        bufs, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    assert compiled.as_text().count(" all-to-all(") == 8
+    m = compiled.memory_analysis()  # bytes on each device
+    assert 1.5e9 < m.argument_size_in_bytes < 1.8e9
+    assert 3 * m.argument_size_in_bytes + m.temp_size_in_bytes < (
+        HBM_BYTES - 3e9)
