@@ -1,0 +1,238 @@
+"""ISSUE 29's go/no-go on the chip: what the mesh exchange's iteration costs
+once its packs take their ordering token by index.
+
+    chiprun --chips 4 -- python experiments/halo_mesh_tie_on_chip.py [--cells N] [--ranks 1]
+
+Builds ``halo512-mesh4.mcts``'s stack as a run builds it
+(``benchmarks/builders/halo_mesh.py``) and, for naive and both
+engine-overlap schedules (``engine_overlap_order``, ``xla`` and ``rdma``):
+
+* the iteration time by the benchmark's two-point clock (``prepare_n`` at n
+  and 4n, the slope);
+* ``timed_fence_gap`` and the one-shot program against the plain reference
+  (``halo_mismatched_cells``, ``chips_without_a_shard``), as the harness
+  takes them;
+* the compiled repeat-n program's temporaries a chip (``memory_analysis``)
+  and every operation inside its ``while`` body whose result is a shard's
+  whole grid (``obs/attrib/hlo.py``);
+* the program's counters ``executor.index_ties`` and
+  ``executor.value_tied_bytes`` for one traced body;
+* a profiled dispatch: the first device's milliseconds an iteration by
+  operation.
+
+``--ranks 1`` puts one rank on one chip (a 1x1x1 grid: every exchange wraps
+onto its own shard), the same slices, updates and tokens a chip at a quarter
+of the chip time.  ``--compile-only N`` compiles the first engine-overlap
+schedule's repeat-n program at N cells a shard for the attached chips from
+shapes alone and reports its temporaries (what decides whether the source's
+512^3 fits).  One process; not part of a benchmark run.  Writes
+``chiprun_out/halo_mesh_tie.json``.
+"""
+
+import argparse
+import json
+import os
+import sys
+import tempfile
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def grid_ops(compiled, local_shape) -> list:
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+
+    shape = "f32[" + ",".join(str(int(x)) for x in local_shape) + "]"
+    return [[o.name, o.opcode, list(o.fused)]
+            for o in loop_ops_of_shape(compiled.as_text(), shape)]
+
+
+def device_ms_by_op(run_n, n: int, top: int = 24) -> list:
+    """One profiled dispatch of ``n`` iterations: the first device's
+    milliseconds an iteration by operation, nested operations taken out of
+    their parents (a ``while`` keeps its own time only)."""
+    import jax
+
+    from tenzing_tpu.obs.attrib import xplane
+
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        try:
+            run_n(n)
+        finally:
+            jax.profiler.stop_trace()
+        trace = xplane.load_xplane(d)
+    planes = xplane.device_planes(trace)
+    if not planes:
+        return []
+    line = next(ln for ln in planes[0]["lines"]
+                if ln["name"] == xplane.OPS_LINE)
+    ops = {}
+    for a, b, name in xplane.innermost(line["events"]):
+        head = name.split(" = ", 1)[0].strip().lstrip("%")
+        ops[head] = ops.get(head, 0) + (b - a)
+    ranked = sorted(ops.items(), key=lambda kv: -kv[1])
+    return [[k, v / 1e6 / n] for k, v in ranked[:top]]
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", default="halo512-mesh4.mcts")
+    ap.add_argument("--cells", type=int, default=None)
+    ap.add_argument("--ranks", type=int, default=None)
+    ap.add_argument("--seed", type=int, default=2147484101)
+    ap.add_argument("--compile-only", type=int, action="append", default=[])
+    ap.add_argument("--schedules", default="naive,xla,rdma")
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    args = ap.parse_args()
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.harness import cell as cell_mod
+    from benchmarks.harness import clock as clock_mod
+    from tenzing_tpu.models.halo import engine_overlap_order
+    from tenzing_tpu.obs.metrics import get_metrics
+
+    cell = cell_mod.load_cell(args.workload)
+    config = cell.config
+    if args.rehearse_cpu:
+        config = cell_mod.toy_shapes(config)
+    shapes = dict(config["shapes"])
+    chips = cell.chips
+    if args.ranks == 1:
+        shapes.update(ranks=1, mesh=[1, 1, 1])
+        chips = 1
+    if args.cells:
+        shapes["cells_per_shard"] = args.cells
+    config = {**config, "shapes": shapes}
+    devices = cell_mod.find_devices(chips, args.rehearse_cpu)
+    ref = cell_mod.load_module("references", config["reference"])
+    builder = cell_mod.load_module("builders", config["builder"])
+    t0 = time.perf_counter()
+    built = builder.build(config, args.seed, devices, ref)
+    ex = built.executor
+    ex.init_bufs = cell_mod.committed(ex.init_bufs)
+    jax.block_until_ready(ex.init_bufs)
+    n_cells = int(shapes["cells_per_shard"])
+    local = (int(shapes["nq"]),) + (n_cells + 2 * int(shapes["radius"]),) * 3
+    report = {"device": devices[0].device_kind, "chips": len(devices),
+              "cells_per_shard": n_cells, "seed": args.seed, "schedules": {}}
+    print(f"{len(devices)} x {devices[0].device_kind}, {n_cells}^3 a shard, "
+          f"built in {time.perf_counter() - t0:.1f} s", flush=True)
+    plat = built.hints["platform"]
+    orders = {"naive": built.naive}
+    for engine in built.hints["engines"]:
+        orders[engine] = engine_overlap_order(built.graph, plat, engine)
+    reg = get_metrics()
+
+    def counters():
+        return (reg.counter("executor.index_ties").value,
+                reg.counter("executor.value_tied_bytes").value)
+
+    for label in [s for s in args.schedules.split(",") if s]:
+        order = orders[label]
+        t0 = time.perf_counter()
+        before = counters()
+        stepped = jax.jit(ex._stepped_fn(order.vector()))
+        compiled = stepped.lower(ex.init_bufs, jnp.int32(1)).compile()
+        ties = [b - a for a, b in zip(before, counters())]
+        mem = compiled.memory_analysis()
+        row = report["schedules"][label] = {
+            "index_ties": ties[0], "value_tied_bytes": ties[1],
+            "temp_gb": mem.temp_size_in_bytes / 1e9,
+            "argument_gb": mem.argument_size_in_bytes / 1e9,
+            "grid_ops_in_loop": grid_ops(compiled, local)}
+        del compiled, stepped
+        run_n = ex.prepare_n(order)
+        c = clock_mod.two_point(run_n)
+        out = ex.run(order)
+        compared = built.check(out)
+        del out
+        gap = cell_mod.timed_fence_gap(
+            ex, order, c["n"], cell_mod.probe_buffers(ex.init_bufs, args.seed))
+        row.update(iter_ms=c["iter_s"] * 1e3, fixed_ms=c["fixed_s"] * 1e3,
+                   n=c["n"], slopes_ms=[s * 1e3 for s in c["slopes"]],
+                   timed_fence_gap=gap,
+                   compared={x["name"]: [x["value"], x["limit"]]
+                             for x in compared},
+                   peak_gb=cell_mod.memory_peak(devices[:1]) / 1e9,
+                   seconds=time.perf_counter() - t0)
+        row["device_ms_per_iter"] = device_ms_by_op(run_n, c["n"])
+        print(f"{label}: {json.dumps(row)}", flush=True)
+    del built, ex
+    for cells in args.compile_only:
+        report.setdefault("compile_only", {})[str(cells)] = compile_only(
+            config, cells, devices)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", "halo_mesh_tie.json"),
+              "w") as f:
+        json.dump(report, f, indent=1)
+    bad = [k for k, r in report["schedules"].items()
+           if r["timed_fence_gap"] != 0.0
+           or any(v > lim for v, lim in r["compared"].values())]
+    print(json.dumps({"not_correct": bad}))
+    return 1 if bad else 0
+
+
+def compile_only(config: dict, cells: int, devices) -> dict:
+    """The first engine-overlap schedule's repeat-n program at ``cells`` a
+    shard, compiled for ``devices`` from shapes: nothing is allocated."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tenzing_tpu.core.graph import Graph
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.halo import (
+        DIRECTIONS,
+        HaloArgs,
+        _face_slices,
+        add_to_graph,
+        dir_name,
+        engine_overlap_order,
+    )
+    from tenzing_tpu.runtime.executor import TraceExecutor
+
+    s = config["shapes"]
+    grid = tuple(int(m) for m in s["mesh"])
+    mesh = Mesh(np.array(devices).reshape(grid), ("x", "y", "z"))
+    spec = P(None, "x", "y", "z")
+    sharded = NamedSharding(mesh, spec)
+    hargs = HaloArgs(nq=int(s["nq"]), lx=cells, ly=cells, lz=cells,
+                     radius=int(s["radius"]), dtype=s["dtype"])
+
+    def tiled(local):
+        return jax.ShapeDtypeStruct(
+            (local[0],) + tuple(m * e for m, e in zip(grid, local[1:])),
+            jnp.dtype(hargs.dtype), sharding=sharded)
+
+    bufs = {"U": tiled(hargs.local_shape())}
+    for d in DIRECTIONS:
+        _, sz = _face_slices(hargs, d, "pack")
+        bufs[f"buf_{dir_name(d)}"] = tiled(sz)
+        bufs[f"recv_{dir_name(d)}"] = tiled(sz)
+    plat = Platform.make_n_lanes(int(config["lanes"]["executor"]), mesh=mesh,
+                                 specs={k: spec for k in bufs})
+    order = engine_overlap_order(
+        add_to_graph(Graph(), hargs, xfer_choice=True), plat, s["engines"][0])
+    ex = TraceExecutor(plat, bufs)
+    n = jax.ShapeDtypeStruct((), jnp.int32,
+                             sharding=NamedSharding(mesh, P()))
+    out = {}
+    try:
+        compiled = jax.jit(ex._stepped_fn(order.vector())).lower(
+            bufs, n).compile()
+        mem = compiled.memory_analysis()
+        out = {"temp_gb": mem.temp_size_in_bytes / 1e9,
+               "argument_gb": mem.argument_size_in_bytes / 1e9,
+               "grid_ops_in_loop": grid_ops(compiled, hargs.local_shape())}
+    except Exception as e:  # the chip's own message is the finding
+        out = {"failed": f"{type(e).__name__}: {str(e)[:400]}"}
+    print(f"compile only, {cells}^3 a shard: {json.dumps(out)}", flush=True)
+    return out
+
+
+if __name__ == "__main__":
+    sys.exit(main())

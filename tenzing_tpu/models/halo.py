@@ -113,7 +113,28 @@ def _face_slices(args: HaloArgs, d: Tuple[int, int, int], which: str):
 
 class Pack(DeviceOp):
     """Slice the interior edge for one direction (reference Pack,
-    ops_halo_exchange.hpp:97-141, kernels ops_halo_exchange.cu:519-573)."""
+    ops_halo_exchange.hpp:97-141, kernels ops_halo_exchange.cu:519-573).
+
+    INDEX_TIE: the op takes its ordering token into the slice's START index
+    (``ctx.tok_index_zero``, an int32 zero that depends on the token), not
+    as a value-preserving add on its read.  The happens-before is the same
+    (the slice cannot start before the token) and the face bit-identical,
+    but the read is the whole grid and six packs read it: a value-add makes
+    six live versions of the grid, each a full pass over it.  Measured on a
+    v5e: the one-chip flagship (2.07 GB grid) paid 21 ms an iteration in
+    fused full-grid adds and 13 ms in dynamic-update-slices that could no
+    longer be done in place; the mesh cell ``halo512-mesh4.mcts`` (1.27 GB a
+    shard), value-tied until PR 29, read 36.5 ms an iteration and 10.2 GB of
+    temporaries for its overlap schedule, 20.8 ms and 5.1 GB since (its
+    updates were in place before and after: PERF.md, PR 29).  The zero
+    goes onto the DIRECTION axis, where ``start < dim - size`` keeps the
+    dynamic-slice clamp non-degenerate: on a full-extent axis the clamp is
+    provably 0 and XLA folds the edge away (probed: the compiled program had
+    static slices and no token edge).  Subclasses that need static starts
+    (the Pallas kernels of ops/halo_pallas.py) set ``INDEX_TIE = False`` and
+    get the executor's value-tied read."""
+
+    INDEX_TIE = True
 
     def __init__(self, args: HaloArgs, d: Tuple[int, int, int]):
         super().__init__(f"pack_{dir_name(d)}")
@@ -129,6 +150,19 @@ class Pack(DeviceOp):
         import jax.lax as lax
 
         starts, sizes = _face_slices(self._args, self._d, "pack")
+        # MUST come from the executor contract — a missing/None value means
+        # the op would trace with no ordering edge at all, so fail loudly
+        z = ctx.tok_index_zero
+        if z is None:
+            raise RuntimeError(
+                f"{self.desc()}: INDEX_TIE op traced without tok_index_zero "
+                "(executor contract violated — the pack would have no "
+                "happens-before edge)"
+            )
+        axis = 1 + [i for i, v in enumerate(self._d) if v != 0][0]
+        starts = tuple(
+            s + z if i == axis else s for i, s in enumerate(starts)
+        )
         sl = lax.dynamic_slice(bufs["U"], starts, sizes)
         return {f"buf_{dir_name(self._d)}": sl}
 

@@ -94,37 +94,12 @@ class PackFlat(Pack):
     every staged transfer uses the 2D tiled layout the host-offload path is
     reliable for — which is also what the reference does with its staging
     buffers (contiguous pack buffers, ops_halo_exchange.hpp:97-186).
-
-    INDEX_TIE: the op's token dependence rides the slice START index (an
-    int32 zero derived from the token, ``ctx.tok_index_zero``) rather than a
-    value-add on the 2 GB grid — six packs value-tying the same grid version
-    forked it into full-grid add fusions (measured: 21 ms/iter on v5e).  The
-    zero is added on the DIRECTION axis, where ``start < dim - size`` keeps
-    the dynamic-slice clamp range non-degenerate: on a full-extent axis the
-    clamp is provably 0 and XLA folds the tie away (probed — the compiled
-    program had static slices and no token edge)."""
-
-    INDEX_TIE = True
+    The slice itself, and how it takes its ordering token (INDEX_TIE), are
+    the base class's: see :class:`tenzing_tpu.models.halo.Pack`."""
 
     def apply(self, bufs, ctx):
-        import jax.lax as lax
-
-        starts, sizes = _face_slices(self._args, self._d, "pack")
-        # MUST come from the executor contract — a missing/None value means
-        # the op would trace with no ordering edge at all, so fail loudly
-        z = ctx.tok_index_zero
-        if z is None:
-            raise RuntimeError(
-                f"{self.desc()}: INDEX_TIE op traced without tok_index_zero "
-                "(executor contract violated — the pack would have no "
-                "happens-before edge)"
-            )
-        axis = 1 + [i for i, v in enumerate(self._d) if v != 0][0]
-        starts = tuple(
-            s + z if i == axis else s for i, s in enumerate(starts)
-        )
-        sl = lax.dynamic_slice(bufs["U"], starts, sizes)
-        return {f"buf_{dir_name(self._d)}": flatten_face(sl, sizes)}
+        ((name, face),) = super().apply(bufs, ctx).items()
+        return {name: flatten_face(face, face.shape)}
 
 
 class UnpackRecv(Unpack):

@@ -59,6 +59,7 @@ from tenzing_tpu.core.platform import Platform
 from tenzing_tpu.core.resources import Event, Lane
 from tenzing_tpu.core.sequence import Sequence
 from tenzing_tpu.core.serdes import sequence_to_json_str
+from tenzing_tpu.obs.metrics import get_metrics
 from tenzing_tpu.obs.tracer import get_tracer, short_digest
 
 
@@ -273,32 +274,38 @@ class TraceContext:
 
         One tied read is sufficient for the happens-before semantics — an op
         cannot start until EVERY input is ready, so making any one input
-        depend on the token delays the whole op — and the SMALLEST read is
-        tied so the value-preserving add never materializes on a huge buffer
-        whose consumer XLA cannot slice-fuse (measured on the halo flagship:
-        tying the 2 GB grid U on every unpack added a full grid read+write
-        per direction — ~30 ms/iter of pure tie overhead)."""
-        view = self.bufs
-        # index-tie contract: an op declaring INDEX_TIE consumes
-        # ``ctx.tok_index_zero`` (an int32 0 data-dependent on its token) in
-        # its slice/update indices instead of receiving a value-tied read.
-        # Same happens-before — the op cannot start before the token — but
-        # the tie costs nothing: a value-add on a multi-GB grid read by six
-        # ops forks the grid (measured on the halo flagship: 21 ms/iter of
-        # fused full-grid adds + 13 ms of consequent non-in-place
-        # dynamic-update-slices).
-        from tenzing_tpu.core.operation import unbound
+        depend on the token delays the whole op.  How the op takes the
+        token is the op's to declare:
 
+        * by value (the default): a value-preserving add of the token's zero
+          onto the op's SMALLEST read — a full pass over that buffer, and a
+          new version of it that whoever else reads it does not share;
+        * by index (``INDEX_TIE = True``): the op consumes
+          ``ctx.tok_index_zero`` (an int32 0 that depends on the token) in
+          its slice/update indices and gets its reads untouched.  For an op
+          whose only read is large and shared — the halo packs, which is
+          where the cost was measured: models/halo.py ``Pack``.
+
+        The program's counters ``executor.index_ties`` and
+        ``executor.value_tied_bytes`` say, per traced program body, how many
+        ops took the token by index and how many bytes got a value-add: a
+        tie that lands on a large buffer reads there, not only as a
+        ``broadcast_add_fusion`` in a device trace."""
+        view = self.bufs
+        reg = get_metrics()
         if getattr(unbound(op), "INDEX_TIE", False):
             self.tok_index_zero = jnp.where(tok_in != tok_in, 1, 0).astype(
                 jnp.int32
             )
+            reg.counter("executor.index_ties").inc()
         else:
             self.tok_index_zero = None  # stale-consumption guard
             reads = [n for n in op.reads() if n not in self.host_space]
             if reads:
                 view = dict(self.bufs)
                 name = min(reads, key=lambda n: (self._approx_nbytes(view[n]), n))
+                reg.counter("executor.value_tied_bytes").inc(
+                    self._approx_nbytes(view[name]))
                 view[name] = datatie(view[name], tok_in)
         out = op.apply(view, self)
         for name, val in out.items():

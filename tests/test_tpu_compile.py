@@ -497,23 +497,17 @@ def test_whole_halo_program(topo, one_chip, on_chip_kernels, which,
 # -- the mesh exchange on four chips ------------------------------------------
 
 
-@pytest.mark.parametrize("engine,marker", [
-    ("xla", "collective-permute"),
-    ("rdma", "tpu_custom_call"),
-])
-def test_mesh_halo_exchange(topo, on_chip_kernels, engine, marker):
-    """models/halo.py on a 2x2x1 mesh of the four described chips, 256^3
-    cells per shard (what ``chip_smoke.py --chips 4`` runs): the XLA
-    collective-permute engine and the remote-DMA engine with its barrier
-    semaphore and collective_id."""
+def _mesh_halo(topo, cells):
+    """``(executor, shapes, graph, platform, args)`` of models/halo.py on a
+    2x2x1 mesh of the four described chips, ``cells``^3 a shard."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from tenzing_tpu.core.graph import Graph
     from tenzing_tpu.core.platform import Platform
-    from tenzing_tpu.models.halo import add_to_graph, engine_overlap_order
+    from tenzing_tpu.models.halo import add_to_graph
     from tenzing_tpu.runtime.executor import TraceExecutor
 
-    args = HaloArgs(nq=3, lx=256, ly=256, lz=256, radius=3)
+    args = HaloArgs(nq=3, lx=cells, ly=cells, lz=cells, radius=3)
     mx, my, mz = 2, 2, 1
     mesh = Mesh(np.array(topo.devices).reshape(mx, my, mz), ("x", "y", "z"))
     spec = P(None, "x", "y", "z")
@@ -529,15 +523,59 @@ def test_mesh_halo_exchange(topo, on_chip_kernels, engine, marker):
         bufs[f"buf_{dir_name(d)}"] = tiled(sizes)
         bufs[f"recv_{dir_name(d)}"] = tiled(sizes)
     plat = Platform.make_n_lanes(2, mesh=mesh, specs={k: spec for k in bufs})
-    seq = engine_overlap_order(
-        add_to_graph(Graph(), args, xfer_choice=True), plat, engine)
+    graph = add_to_graph(Graph(), args, xfer_choice=True)
+    return TraceExecutor(plat, bufs), bufs, graph, plat, args
+
+
+@pytest.mark.parametrize("engine,marker", [
+    ("xla", "collective-permute"),
+    ("rdma", "tpu_custom_call"),
+])
+def test_mesh_halo_exchange(topo, on_chip_kernels, engine, marker):
+    """models/halo.py on a 2x2x1 mesh of the four described chips, 256^3
+    cells per shard (what ``chip_smoke.py --chips 4`` runs): the XLA
+    collective-permute engine and the remote-DMA engine with its barrier
+    semaphore and collective_id."""
+    from tenzing_tpu.models.halo import engine_overlap_order
+
+    ex, bufs, graph, plat, _ = _mesh_halo(topo, 256)
+    seq = engine_overlap_order(graph, plat, engine)
     assert sum(op.name().endswith("." + engine) for op in seq.vector()) == 6
-    ex = TraceExecutor(plat, bufs)
     compiled = jax.jit(ex.program(seq)).lower(bufs).compile()
     assert marker in compiled.as_text()
     m = compiled.memory_analysis()  # bytes on each device
     assert (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("which", ["naive", "xla", "rdma"])
+def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
+                                                   which):
+    """The repeat-n program of ``halo512-mesh4.mcts`` (448^3 a shard) as the
+    TPU compiler leaves it: no operation inside the ``while`` body adds onto
+    a shard's whole grid (up to PR 28 the six packs' ordering tokens did, a
+    pass over 1.27 GB each), and its temporaries are under five grids
+    (10.18 GB up to PR 28 for the ``xla`` schedule, eight grids; 5.09 since:
+    the entry's copy of the grid into the loop's carry, the carry, and two
+    relayout copies XLA makes for the thin y and z faces' slices, which are
+    the compiler's and not an ordering edge: PERF.md, PR 29)."""
+    from tenzing_tpu.bench.driver import naive_schedule
+    from tenzing_tpu.models.halo import engine_overlap_order
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+
+    ex, bufs, graph, plat, args = _mesh_halo(topo, 448)
+    seq = (naive_schedule("halo_mesh", graph, None) if which == "naive"
+           else engine_overlap_order(graph, plat, which))
+    n = _sds((), jnp.int32, jax.sharding.NamedSharding(
+        plat.mesh, jax.sharding.PartitionSpec()))
+    compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(bufs, n).compile()
+    grid = "f32[" + ",".join(str(e) for e in args.local_shape()) + "]"
+    ops = loop_ops_of_shape(compiled.as_text(), grid)
+    assert sum(o.opcode == "dynamic-update-slice" for o in ops) == 6
+    assert not [o for o in ops if "add" in o.fused or o.opcode == "add"], ops
+    grid_bytes = 4 * np.prod([-(-e // t) * t for e, t in zip(
+        args.local_shape(), (1, 1, 8, 128))])
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * grid_bytes
 
 
 # -- the expert layer on four chips --------------------------------------------
