@@ -1,45 +1,24 @@
-"""The library driver: workload builders + the search→gate→JSON loop.
+"""The library driver: the search→gate→JSON loop as a callable.
 
-``bench.py`` used to be a 1,678-line monolith: four workload builders, the
-anytime search (greedy incumbents → recorded warm starts → MCTS →
-hill-climbs), the paired screen/final verdict, the result-integrity gate,
-attribution profiling, and the driver-JSON assembly — all inside one
-``main()`` reachable only through argparse.  The schedule-serving
-subsystem (``tenzing_tpu/serve/``, docs/serving.md) needs exactly that
-loop as a *callable*: a cold request enqueues a work item a driver drains,
-and the warm path needs the workload graphs without a CLI in the way.
-
-This module is that API:
+The schedule-serving subsystem (``tenzing_tpu/serve/``, docs/serving.md)
+drains its queued work items through this loop, and ``bench.py`` is a thin
+argparse shim over it:
 
 * :class:`DriverRequest` — the typed request, field-for-field the CLI's
   argparse namespace (defaults asserted equal by tests/test_driver.py, so
   the two can never drift);
-* :func:`run` — the whole search→gate→JSON loop; returns a
-  :class:`DriverResult` whose ``verdict`` dict, serialized, is
-  byte-identical to the JSON line ``bench.py`` prints;
-* :func:`build_workload` / :func:`graph_for` / :func:`workload_shape` —
-  the workload builders, with a device-free graph/shape path for serving
-  (fingerprints and corpus deserialization must not touch a backend);
+* :func:`run` — the whole loop; returns a :class:`DriverResult` whose
+  ``verdict`` dict, serialized, is the JSON line ``bench.py`` prints.
+  :func:`_run` calls one function per phase, in order: open (device check,
+  build), learn, tile planting, :func:`assemble_stack`, naive, incumbents
+  and recorded warm start, tree search, climbs, paired screen and final
+  batch, integrity gate, the winner's provenance reports
+  (:func:`winner_report`), dump and stamp;
+* what a workload is (builders, ``graph_for``, ``workload_shape``, naive
+  schedule, incumbents, climb policies) is one row of
+  ``bench/workloads.py``; its names are re-exported here;
 * :exc:`DriverConfigError` — an invalid request (the shim maps it to
-  ``argparse.error``, keeping CLI behavior identical).
-
-``bench.py`` is now a thin argparse shim over this module.
-
-Workloads (``DriverRequest.workload`` / the CLI's ``--workload``):
-* ``halo`` (default, the north-star metric — BASELINE.md): the 3D
-  halo-exchange pipeline (nQ=3, 512^3 cells, radius 3, the reference config
-  halo_run_strategy.hpp:42-49) as six pack -> post -> await -> unpack chains
-  whose transfers are async host round-trip DMAs; MCTS searches order x lane x
-  kernel (XLA slice vs Pallas plane-DMA) against the fully-synchronous naive
-  serialization.
-* ``spmv``: distributed-SpMV iteration (reference config: m=150000 rows,
-  nnz=10*m, band matrix, 2 lanes — spmv_run_strategy.cuh:44-47).
-* ``attn``: single-chip blockwise (flash) attention over a long context —
-  the kernel menu (XLA vs Pallas MXU) plus order x lane space.
-* ``moe``: single-chip MoE dispatch/combine pipeline — routed tokens staged
-  through async host round-trip DMAs to the resident experts (the
-  expert-parallel network-hop analog), searched over order x lane x
-  expert-kernel (XLA vs Pallas) across independent microbatch chunk chains.
+  ``argparse.error``).
 
 The search is anytime: greedy domain incumbents (for halo, an engine x
 lane-count grid), the best recorded schedules from previous runs' databases
@@ -47,17 +26,12 @@ lane-count grid), the best recorded schedules from previous runs' databases
 in-file paired ratio), and a FastMin MCTS that explores at CHEAP measurement
 cost — search-time numbers only steer the tree — followed by drift-immune
 hill-climbs seeded from the best recorded schedule's menu choices and from
-the strongest hand disciplines.  Candidate selection and the
-verdict are both *paired decorrelated batches* (reference batch benchmark,
-benchmarker.cpp:21-76): a moderate-cost screen ranks the distinct candidates
-by paired per-iteration speedup vs naive and drops anything below 1.0, then
-the final batch (3x iterations, 20x adaptive measurement floor,
-benchmarker.cpp:83-119) re-measures naive + the top 3 survivors together,
-visited in a fresh random order per iteration.  ``vs_baseline`` is the best
-finalist's **paired speedup** (median of naive[k]/cand[k] with a bootstrap
-CI, utils.numeric.paired_speedup) — drift common to both schedules cancels
-instead of masquerading as, or drowning, a schedule difference; a win
-additionally requires the CI to exclude 1.0.
+the strongest hand disciplines.  Candidate selection and the verdict are
+both *paired decorrelated batches* (:func:`_screen_and_final`).
+``vs_baseline`` is the best finalist's **paired speedup** (median of
+naive[k]/cand[k] with a bootstrap CI, utils.numeric.paired_speedup) — drift
+common to both schedules cancels instead of masquerading as, or drowning, a
+schedule difference; a win additionally requires the CI to exclude 1.0.
 
 Prints ONE JSON line:
   {"metric": ..., "value": <best pct50, us>, "unit": "us",
@@ -83,18 +57,21 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+# what a workload is lives one layer down; the names it had here stay
+# importable from here (bench.py, the benchmark's builders, examples)
+from tenzing_tpu.bench.workloads import (  # noqa: F401
+    ALIAS_UNPACK, BUILDERS, WORKLOADS, DriverConfigError, Workload,
+    alias_unpack_choice, build_attn, build_halo, build_moe, build_spmv,
+    first_decision_schedule, generic_xla_prefer, graph_for,
+    halo_alias_prefer, metric_for, moe_bf16_prefer, naive_schedule,
+    nbytes_of, recorded_prefer, search_lanes, workload_cost, workload_shape,
+)
+
 # the CLI's relative default globs (--seed-csv) resolve against the repo
 # root, where bench.py lives — anchored here so the extracted driver keeps
 # resolving the same files the monolith did
 REPO_ROOT = _os_mod.path.dirname(_os_mod.path.dirname(
     _os_mod.path.dirname(_os_mod.path.abspath(__file__))))
-
-
-class DriverConfigError(ValueError):
-    """An invalid :class:`DriverRequest` — the library analog of
-    ``argparse.ArgumentParser.error`` (the CLI shim catches it and calls
-    exactly that, so bad flag combinations fail identically to the
-    monolith)."""
 
 
 @dataclass
@@ -166,396 +143,6 @@ def device_stamp(devs) -> Dict[str, Any]:
     it came from."""
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}
-
-
-# the measured per-face aliased-unpack recipe (the r5 discovery, see
-# experiments/MENU_INCUMBENT2.json / MENU_INCUMBENT3.json): the ghost-shell
-# write must lower IN PLACE (a non-aliased write copies the 2.07 GB grid,
-# ~5 ms) and these are the aliased Pallas kernels per face axis.  ONE
-# definition — the greedy incumbents and the climb seeds must refine the
-# same recipe.
-ALIAS_UNPACK = {"x": ".pallas", "y": ".pallasf", "z": ".pallasb"}
-
-
-def alias_unpack_choice(op_name, choices):
-    """The aliased kernel for an ``unpack_*`` op from the menu, or None when
-    it is off-menu — the one lookup both the greedy seeding and the climb
-    disciplines share."""
-    want = ALIAS_UNPACK[op_name[-1]]
-    return next((c for c in choices if c.endswith(want)), None)
-
-
-def generic_xla_prefer(op_name, choices):
-    """Workload-agnostic default policy: the plain XLA lowering when the
-    menu has one — the fleet's smoke-job prefer (safe on any workload)."""
-    return next((c for c in choices if c.endswith(".xla")), None)
-
-
-def halo_alias_prefer(op_name, choices):
-    """The halo climb policy: all-rdma + the aliased-unpack kernel map (the
-    measured r5 recipe — in-place ghost-shell writes per face,
-    MENU_INCUMBENT2/3).  Module-level so a fleet worker process can rebuild
-    it by name from the job spec (search/fleet.py resolve_prefer)."""
-    if op_name.startswith("xfer_"):
-        return next((c for c in choices if c.endswith(".rdma")), None)
-    if op_name.startswith("unpack_"):
-        hit = alias_unpack_choice(op_name, choices)
-        if hit is not None:
-            return hit
-    return next((c for c in choices if c.endswith(".xla")), None)
-
-
-def moe_bf16_prefer(op_name, choices):
-    """The moe climb policy: whole-chain staging choice — device-resident
-    bf16 transfers (the measured 10.97x winner); kernel choices default to
-    XLA."""
-    return next(
-        (c for c in choices if c.endswith(".bf16-rdma")),
-        next((c for c in choices if c.endswith(".xla")), None),
-    )
-
-
-def recorded_prefer(chosen: Dict[str, str]):
-    """The climb policy replicating a recorded winner's menu choices
-    (``chosen``: base op name -> ``".suffix"``) — the factory form of the
-    legacy closure, so a fleet worker can rebuild it from the job spec's
-    serialized ``chosen`` map."""
-
-    def prefer(op_name, choices):
-        want = chosen.get(op_name)
-        if want is not None:
-            c = next((c for c in choices if c.endswith(want)), None)
-            if c is not None:
-                return c
-        if op_name.startswith("xfer_"):
-            # a recorded host-staged transfer leaves no "xfer_*" vertex
-            # (the HostRoundTrip compound expands into spill/fetch)
-            return next((c for c in choices if c.endswith(".host")), None)
-        return next((c for c in choices if c.endswith(".xla")), None)
-
-    return prefer
-
-
-def metric_for(workload: str, args) -> str:
-    """The metric name for a workload config — the single source both the
-    success path (build_* return) and the backend-init-failure path use, so
-    the two always land in the same metric series."""
-    if workload == "halo":
-        return f"halo_iter_pct50_searched_n{4 if args.smoke else args.halo_n}"
-    if workload == "spmv":
-        m = args.m if args.m is not None else (512 if args.smoke else 150_000)
-        sfx = f"_bw{args.spmv_bw}" if args.spmv_bw is not None else ""
-        return f"spmv_iter_pct50_searched_m{m}{sfx}"
-    if workload == "moe":
-        t = 32 if args.smoke else args.moe_tokens
-        return f"moe_pipe_pct50_searched_t{t}"
-    n_ctx = 4 * 16 if args.smoke else 8 * 1024
-    return f"attn_blockwise_pct50_searched_n{n_ctx}"
-
-
-def workload_cost(workload: str, built):
-    """The workload's roofline :class:`~tenzing_tpu.bench.roofline.Cost`
-    for the attribution profiler's fraction-of-peak join (``built`` is the
-    matching ``build_*`` return).  One iteration's arithmetic + traffic —
-    the same accounting experiments/halo_roofline.py reports against."""
-    from tenzing_tpu.bench import roofline
-
-    if workload == "halo":
-        h = built[3]
-        return roofline.halo_cost(h.nq, h.lx, h.ly, h.lz, h.radius)
-    if workload == "spmv":
-        m = built[3]
-        return roofline.spmv_cost(m, nnz=10 * m)
-    if workload == "moe":
-        margs = built[3][0]
-        return roofline.moe_cost(margs.tokens, margs.d_model, margs.d_ff,
-                                 staged=True, n_experts=margs.n_experts)
-    a = built[3]  # attn
-    return roofline.attention_cost(a.batch, a.n_devices * a.seq_local,
-                                   a.head_dim)
-
-
-def build_halo(args):
-    from tenzing_tpu.models.halo import HaloArgs
-    from tenzing_tpu.models.halo_pipeline import (
-        build_graph,
-        host_buffer_names,
-        make_pipeline_buffers,
-    )
-    from tenzing_tpu.runtime.executor import TraceExecutor
-
-    if args.smoke:
-        hargs = HaloArgs(nq=2, lx=4, ly=4, lz=4, radius=1)
-    else:
-        n = args.halo_n
-        hargs = HaloArgs(nq=3, lx=n, ly=n, lz=n, radius=3)
-    bufs, _ = make_pipeline_buffers(hargs, seed=0, with_expected=False)
-    jbufs = TraceExecutor.place_host_buffers(bufs, host_buffer_names())
-    # kernel + transfer-engine menus only where a real TPU compiles them;
-    # interpret-mode Pallas would dominate a CPU smoke timing
-    impl_choice = not args.smoke
-    g = build_graph(hargs, impl_choice=impl_choice, xfer_choice=impl_choice)
-    return g, jbufs, metric_for("halo", args), hargs
-
-
-def build_spmv(args):
-    from tenzing_tpu.core.graph import Graph
-    from tenzing_tpu.models.spmv import (
-        SpMVCompound,
-        make_spmv_buffers,
-        spmv_host_buffer_names,
-    )
-    from tenzing_tpu.runtime.executor import TraceExecutor
-
-    m = args.m if args.m is not None else (512 if args.smoke else 150_000)
-    # --spmv-bw widens the band, growing the remote-column exchange relative
-    # to the local compute: the transfer-bound sweep of VERDICT r2 item 7
-    synth = bool(args.synth_collectives)
-    bufs, _ = make_spmv_buffers(m=m, nnz_per_row=10, bw=args.spmv_bw, seed=0,
-                                synth=synth)
-    n_rem = int(bufs["x_remote"].shape[0])
-    jbufs = TraceExecutor.place_host_buffers(
-        bufs, spmv_host_buffer_names(n_rem, synth=synth))
-    # impl_choice: the kernel menu (XLA gather vs Pallas vreg-gather) is part
-    # of the searched space alongside order and lane assignment; known x sizes
-    # prune Pallas choices that would only alias the XLA path (ADVICE r1).
-    # exchange="host": the x exchange is a posted async host round-trip DMA
-    # (the reference's MPI hop), so the post/wait split gives the search a
-    # real transfer to hide behind the local SpMV
-    x_sizes = {"x_local": int(jbufs["x_local"].shape[0]),
-               "x_remote": int(jbufs["x_remote"].shape[0])}
-    mk = lambda: SpMVCompound(impl_choice=True, x_sizes=x_sizes,
-                              exchange="host", synth=synth,
-                              synth_relax=args.smoke)
-    g = Graph()
-    g.start_then(mk())
-    g.then_finish(mk())
-    return g, jbufs, metric_for("spmv", args), m
-
-
-def build_moe(args):
-    from tenzing_tpu.models.moe_pipeline import (
-        MoEPipeArgs,
-        build_graph,
-        host_buffer_names,
-        make_pipe_buffers,
-    )
-    from tenzing_tpu.runtime.executor import TraceExecutor
-
-    if args.smoke:
-        margs = MoEPipeArgs(n_experts=4, tokens=32, d_model=8, d_ff=16,
-                            n_chunks=2)
-    else:
-        margs = MoEPipeArgs(tokens=args.moe_tokens)
-    # the searched space includes the staging-precision menu (f32 vs
-    # half-width bf16 transfers) on the real chip
-    staging = "f32" if args.smoke else "choice"
-    bufs, _, cap = make_pipe_buffers(margs, seed=0, with_expected=False,
-                                     staging=staging)
-    jbufs = TraceExecutor.place_host_buffers(
-        bufs, host_buffer_names(margs, staging=staging))
-    impl_choice = not args.smoke  # same rationale as build_halo
-    g = build_graph(margs, cap, impl_choice=impl_choice, staging=staging,
-                    chunk=args.chunk, chunk_relax=args.smoke)
-    return g, jbufs, metric_for("moe", args), (margs, cap)
-
-
-def build_attn(args):
-    import jax.numpy as jnp
-
-    from tenzing_tpu.core.graph import Graph
-    from tenzing_tpu.models.ring_attention import (
-        BlockedAttention,
-        RingAttnArgs,
-        make_blocked_buffers,
-    )
-
-    if args.smoke:
-        aargs = RingAttnArgs(n_devices=4, batch=1, seq_local=16, head_dim=8)
-    else:
-        # 8k context in 8 blocks of 1024, head dim 128
-        aargs = RingAttnArgs(n_devices=8, batch=4, seq_local=1024, head_dim=128)
-    bufs, _ = make_blocked_buffers(aargs, seed=0)
-    bufs = {k: jnp.asarray(v) for k, v in bufs.items()}
-    g = Graph()
-    op = BlockedAttention(aargs, impl_choice=True, fused_choice=True,
-                          chunk=args.chunk, chunk_relax=args.smoke)
-    g.start_then(op)
-    g.then_finish(op)
-    return g, bufs, metric_for("attn", args), aargs
-
-
-# workload name -> device builder (graph + device-placed buffers + metric +
-# workload args) — the search path's entry; serving uses graph_for below
-BUILDERS = {"halo": build_halo, "spmv": build_spmv, "attn": build_attn,
-            "moe": build_moe}
-
-
-def build_workload(req: DriverRequest):
-    """``(graph, buffers, metric, workload-args)`` for ``req`` — the
-    device-placing builder dispatch :func:`run` uses (buffers land in
-    pinned host / device memory; needs an initialized backend)."""
-    return BUILDERS[req.workload](req)
-
-
-def workload_shape(req: DriverRequest) -> Dict[str, int]:
-    """The request's exact shape parameters, as the builders resolve them
-    — THE single source the serving fingerprint keys on (serve/
-    fingerprint.py), kept next to the builders so a new shape knob cannot
-    silently stay out of the fingerprint.  Pure request arithmetic: no
-    jax, no buffers, no backend."""
-    w = req.workload
-    if w == "halo":
-        if req.smoke:
-            return {"nq": 2, "n": 4, "radius": 1}
-        return {"nq": 3, "n": req.halo_n, "radius": 3}
-    if w == "spmv":
-        m = req.m if req.m is not None else (512 if req.smoke else 150_000)
-        # bw resolves exactly as models/spmv.py make_spmv_buffers does
-        # (None -> max(1, m // 8)): a default request and an explicit
-        # --spmv-bw of the same value build the SAME matrix and must
-        # fingerprint identically, or independently-warmed stores
-        # fragment and exact hits are missed
-        bw = req.spmv_bw if req.spmv_bw is not None else max(1, m // 8)
-        return {"m": m, "nnz_per_row": 10, "bw": bw}
-    if w == "moe":
-        if req.smoke:
-            return {"n_experts": 4, "tokens": 32, "d_model": 8, "d_ff": 16,
-                    "n_chunks": 2}
-        return {"tokens": req.moe_tokens}
-    if w == "attn":
-        if req.smoke:
-            return {"n_devices": 4, "batch": 1, "seq_local": 16,
-                    "head_dim": 8}
-        return {"n_devices": 8, "batch": 4, "seq_local": 1024,
-                "head_dim": 128}
-    raise DriverConfigError(f"unknown workload {w!r}")
-
-
-def search_lanes(req: DriverRequest) -> int:
-    """The search platform's lane count for ``req`` — the same default
-    rule :func:`run` applies (8 for full-size halo, else 2, unless
-    overridden), exposed so the serving fingerprint's mesh signature and
-    the search agree by construction."""
-    if req.lanes:
-        return req.lanes
-    return 8 if req.workload == "halo" and not req.smoke else 2
-
-
-def graph_for(req: DriverRequest):
-    """``(graph, nbytes)`` for ``req`` **without touching a backend**: the
-    choice graph recorded schedules deserialize/verify against, plus a
-    buffer-size map for the surrogate featurizer.  The serving path's
-    builder (docs/serving.md): resolution and corpus warm-up must work on
-    a host with no accelerator at all.
-
-    ``nbytes`` is ``{}`` for the full-size halo config — materializing its
-    2 GB grid just to read ``.nbytes`` is not a serving-path cost; the
-    featurizer degrades to zero comm-bytes features, consistently at train
-    and predict time because both sides use this same map.
-
-    The other workloads DO build their (tens-of-MB) host buffers once per
-    fingerprint, deliberately: spmv's choice graph depends on the
-    constructed buffers (``x_sizes`` comes from the random band matrix's
-    actual remote-column split), so deriving sizes analytically here
-    would risk a serving-side graph that silently diverges from the one
-    the driver searches — a correctness risk worth more than a transient
-    allocation that the resolver's per-fingerprint cache amortizes."""
-    w = req.workload
-    impl_choice = not req.smoke
-    if w == "halo":
-        from tenzing_tpu.models.halo import HaloArgs
-        from tenzing_tpu.models.halo_pipeline import build_graph
-
-        s = workload_shape(req)
-        hargs = HaloArgs(nq=s["nq"], lx=s["n"], ly=s["n"], lz=s["n"],
-                         radius=s["radius"])
-        g = build_graph(hargs, impl_choice=impl_choice,
-                        xfer_choice=impl_choice)
-        nbytes: Dict[str, int] = {}
-        if req.smoke:
-            from tenzing_tpu.models.halo_pipeline import make_pipeline_buffers
-
-            bufs, _ = make_pipeline_buffers(hargs, seed=0,
-                                            with_expected=False)
-            nbytes = {k: int(getattr(v, "nbytes", 0))
-                      for k, v in bufs.items()}
-        return g, nbytes
-    if w == "spmv":
-        from tenzing_tpu.core.graph import Graph
-        from tenzing_tpu.models.spmv import SpMVCompound, make_spmv_buffers
-
-        s = workload_shape(req)
-        synth = bool(req.synth_collectives)
-        bufs, _ = make_spmv_buffers(m=s["m"], nnz_per_row=s["nnz_per_row"],
-                                    bw=req.spmv_bw, seed=0, synth=synth)
-        x_sizes = {"x_local": int(bufs["x_local"].shape[0]),
-                   "x_remote": int(bufs["x_remote"].shape[0])}
-        mk = lambda: SpMVCompound(impl_choice=True, x_sizes=x_sizes,
-                                  exchange="host", synth=synth,
-                                  synth_relax=req.smoke)
-        g = Graph()
-        g.start_then(mk())
-        g.then_finish(mk())
-        return g, {k: int(getattr(v, "nbytes", 0)) for k, v in bufs.items()}
-    if w == "moe":
-        from tenzing_tpu.models.moe_pipeline import (
-            MoEPipeArgs,
-            build_graph,
-            make_pipe_buffers,
-        )
-
-        margs = MoEPipeArgs(**workload_shape(req))
-        staging = "f32" if req.smoke else "choice"
-        bufs, _, cap = make_pipe_buffers(margs, seed=0, with_expected=False,
-                                         staging=staging)
-        g = build_graph(margs, cap, impl_choice=impl_choice, staging=staging,
-                        chunk=req.chunk, chunk_relax=req.smoke)
-        return g, {k: int(getattr(v, "nbytes", 0)) for k, v in bufs.items()}
-    if w == "attn":
-        from tenzing_tpu.core.graph import Graph
-        from tenzing_tpu.models.ring_attention import (
-            BlockedAttention,
-            RingAttnArgs,
-            make_blocked_buffers,
-        )
-
-        aargs = RingAttnArgs(**workload_shape(req))
-        bufs, _ = make_blocked_buffers(aargs, seed=0)
-        g = Graph()
-        op = BlockedAttention(aargs, impl_choice=True, fused_choice=True,
-                              chunk=req.chunk, chunk_relax=req.smoke)
-        g.start_then(op)
-        g.then_finish(op)
-        return g, {k: int(getattr(v, "nbytes", 0)) for k, v in bufs.items()}
-    raise DriverConfigError(f"unknown workload {w!r}")
-
-
-def naive_schedule(workload: str, graph, wargs):
-    """The naive incumbent every verdict is a ratio against: the fully
-    -synchronous serialization on one lane (the reference's "sequential
-    ordering on one stream" baseline, BASELINE.json).  ``wargs`` is the
-    workload builder's fourth return.  halo and moe serialize chain by chain
-    (models/*_pipeline.naive_order); spmv and attn take the first decision
-    the SDP offers.  Either way the schedule comes out of the SDP machinery,
-    sync ops included, and is held to the soundness verifier like any
-    candidate."""
-    from tenzing_tpu.core.platform import Platform
-    from tenzing_tpu.core.state import State
-
-    naive_plat = Platform.make_n_lanes(1)
-    if workload == "halo":
-        from tenzing_tpu.models.halo_pipeline import naive_order
-
-        return naive_order(wargs, naive_plat)
-    if workload == "moe":
-        from tenzing_tpu.models.moe_pipeline import naive_order
-
-        return naive_order(wargs[0], wargs[1], naive_plat)
-    st = State(graph)
-    while not st.is_terminal():
-        st = st.apply(st.get_decisions(naive_plat)[0])
-    return st.sequence
 
 
 def _written_buffers(seq) -> set:
@@ -660,7 +247,7 @@ def run(req: DriverRequest) -> DriverResult:
     # smoke iteration caps) exactly like the monolith mutated its argparse
     # namespace, without surprising a caller who reuses the request
     args = dataclasses.replace(req)
-    if args.workload not in BUILDERS:
+    if args.workload not in WORKLOADS:
         # validate BEFORE the backend probe: argparse choices protect
         # the CLI, but a library caller (a drainer on a hand-edited work
         # item) must get the API's config error, not a KeyError after a
@@ -695,8 +282,198 @@ def run(req: DriverRequest) -> DriverResult:
             _obs_context.set_process_default(prev_ctx)
 
 
-def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
+@dataclass
+class Stack:
+    """The measurement stack's handles, inside-out (:func:`assemble_stack`)."""
 
+    emp: Any
+    injector: Any
+    prefetcher: Any
+    resilient: Any
+    corrupt_injector: Any
+    quarantine: Any
+    checkpoint: Any
+    bench: Any
+    verifier: Any  # the soundness gate the resilient layer holds, or None
+
+
+@dataclass
+class _Run:
+    """What lives through one :func:`run`: the request, what the opening
+    phases built, and the measurement stack.  A phase takes it (and what the
+    phase before returned) and returns what the next needs."""
+
+    args: DriverRequest
+    row: Workload
+    compile_cache_dir: Optional[str]
+    # _open: the device's stamp and peaks, the builder's return, its graph
+    device: Optional[Dict[str, Any]] = None
+    peaks: Any = None
+    built: Any = None
+    g: Any = None  # with the tile menu planted, once _plant_tiles has run
+    surrogate: Any = None
+    # _plant_tiles: search platform, executor, --fuse-search-tiles' menu
+    plat: Any = None
+    ex: Any = None
+    tile_menu: Any = None
+    tile_planted: bool = False
+    stack: Optional[Stack] = None
+    # _measure_naive: the verdict's and the search's floors, the baseline
+    opts: Any = None
+    search_opts: Any = None
+    naive_seq: Any = None
+    naive: Any = None
+    labels: Dict[int, str] = field(default_factory=dict)  # id(sim) -> label
+    # _tree_search, _climb: what the dump and the stamp read back
+    mcts_screen: Any = None
+    search_bench: Any = None
+    distributed_stats: Any = None
+    # --profile-winner's analysis of the reported schedule, for the reports
+    # after it: re-stepping a multi-GB workload per op is minutes of waste
+    profiled_attrib: Any = None
+    # per-lane Gantt tracks from --profile-winner (chrome trace-event
+    # dicts, obs/attrib/explain.py): filled late in the run, exported by
+    # write_telemetry into the same Perfetto bundle as the PR-1 spans
+    attrib_extra: list = field(default_factory=list)
+    _telemetry_done: bool = False
+
+    def write_telemetry(self):
+        """Archive the telemetry bundle once.  Registered with atexit (for
+        crashes: the interpreter still exits normally after an unhandled
+        exception) AND with utils.trap (for SIGINT/SIGABRT: the trap handler
+        re-raises via SIG_DFL, which kills the process without running
+        atexit) so an interrupted search — the run where the trace matters
+        most — still archives everything recorded so far.  The explicit call
+        on the success path just makes the files land before the final JSON
+        line.  Filenames are rank-qualified past rank 0 so multi-host runs
+        writing to a shared directory do not clobber each other's bundles."""
+        import os
+
+        from tenzing_tpu import obs
+
+        args = self.args
+        if self._telemetry_done:
+            return
+        self._telemetry_done = True
+        rank = obs.get_tracer().rank
+        sfx = "" if rank == 0 else f".rank{rank}"
+        if args.trace_out:
+            os.makedirs(args.trace_out, exist_ok=True)
+            obs.write_jsonl(obs.get_tracer(),
+                            os.path.join(args.trace_out, f"trace{sfx}.jsonl"))
+            obs.write_chrome_trace(
+                obs.get_tracer(),
+                os.path.join(args.trace_out, f"trace{sfx}.json"),
+                extra_events=self.attrib_extra or None)
+            sys.stderr.write(f"trace bundle: {args.trace_out}\n")
+        if args.metrics_json:
+            # block=False: this runs from the signal trap, where the
+            # interrupted thread may hold an instrument lock — the
+            # non-blocking read falls back to GIL-atomic copies instead of
+            # deadlocking the Ctrl-C path (the exporters above are
+            # non-blocking by construction, obs/export.py)
+            with open(args.metrics_json + sfx, "w") as f:
+                json.dump(obs.get_metrics().to_json(block=False), f,
+                          indent=2, sort_keys=True)
+            sys.stderr.write(f"metrics: {args.metrics_json}{sfx}\n")
+
+    def error_verdict(self, msg: str, **extra) -> DriverResult:
+        """No measurement was made: still one parseable line, carrying
+        ``error`` (the CLI exits non-zero on it)."""
+        self.write_telemetry()
+        return DriverResult(verdict={
+            "metric": self.row.metric(self.args),
+            "value": -1.0,
+            "unit": "us",
+            "vs_baseline": 0.0,
+            "error": msg,
+            **extra,
+        })
+
+    def with_tile1(self, seq):
+        """An out-of-graph sequence (naive_order/greedy helpers, recorded
+        rows predating the tile node) completed with the ``fuse_tile.t1``
+        directive the planted choice requires — without it the verifier
+        would reject the schedule as an unresolved choice.  The directive
+        goes AFTER the leading start sentinel: the planted choice is a
+        successor of Start, so a directive at position 0 would violate
+        the projected start->directive edge and fail verification."""
+        if not self.tile_planted:
+            return seq
+        from tenzing_tpu.core.sequence import Sequence as _Seq
+        from tenzing_tpu.runtime.fused import FuseTile, TILE_PREFIX
+
+        ops_ = list(seq.vector())
+        if any(op.name().startswith(TILE_PREFIX) for op in ops_):
+            return seq
+        at = 1 if ops_ and ops_[0].name() == "start" else 0
+        return _Seq(ops_[:at] + [FuseTile(1)] + ops_[at:])
+
+    def label_of(self, s) -> str:
+        """'greedy-host-8l' for a labeled incumbent, 'climb/<engine>' for a
+        hill-climb candidate, 'mcts/<engine>' for an MCTS rollout — the
+        screen/final printouts must distinguish the entries they compare."""
+        base = self.labels.get(id(s), "mcts")
+        if base in ("mcts", "climb", "climb-tip"):
+            names = [op.desc() for op in s.order.vector()]
+            engine = "rdma" if any(".rdma" in n for n in names) else "host"
+            return f"{base}/{engine}"
+        return base
+
+
+@dataclass
+class _Pick:
+    """The paired screen's and final batch's answer, then the integrity
+    gate's: the number the verdict reports and the schedule it belongs to."""
+
+    vs: float
+    value_us: float
+    screen_opts: Any
+    fin_opts: Any
+    finals: list = field(default_factory=list)
+    top: list = field(default_factory=list)
+    best_i: int = 0
+    integrity: Optional[Dict[str, Any]] = None
+    # gate outputs stashed for reuse: the fused phase compares against the
+    # stepped program's outputs, which the gate just computed — re-running
+    # a multi-GB workload's program for the same answer is pure waste
+    gate_outs: Dict[int, Dict[str, Any]] = field(default_factory=dict)
+    reported_seq: Any = None
+
+    def winner(self):
+        """The finalist the verdict reports, or None when it is naive's."""
+        if self.top and self.finals and self.vs > 1.0:
+            return self.top[self.best_i]
+        return None
+
+
+def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
+    """The phases of a run, in order."""
+    run = _open(args, scope)
+    if isinstance(run, DriverResult):
+        return run
+    if args.learn_train:
+        return _learn_train(run)
+    run.surrogate = _load_surrogate(run)
+    measure_ex = _plant_tiles(run)
+    run.stack = assemble_stack(measure_ex, run.g, args, run.surrogate, scope)
+    _measure_naive(run)
+    # anytime search: heuristic incumbents first, then the directed search
+    incumbents, seed_paths, rollout_policy = _measure_incumbents(run)
+    recorded = _recorded_warm_start(run, incumbents)
+    res = _tree_search(run, incumbents, seed_paths, rollout_policy)
+    _climb(run, res, incumbents, recorded)
+    pick = _screen_and_final(run, res, incumbents)
+    _integrity_gate(run, pick)
+    reports = _winner_reports(run, res, pick)
+    if args.dump_csv:
+        _dump_csv(run, res, pick)
+    return _stamp(run, pick, reports, len(recorded))
+
+
+def _open(args: DriverRequest, scope: _RunScope):
+    """Smoke defaults, telemetry, the device check and the build: a
+    :class:`_Run`, or the error verdict of a run that may not measure."""
     if args.smoke:
         import jax
 
@@ -711,65 +488,11 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     if args.trace_out:
         obs.configure(enabled=True)
 
-    _telemetry_done = {"v": False}
-    # per-lane Gantt tracks from --profile-winner (chrome trace-event
-    # dicts, obs/attrib/explain.py): filled late in the run, exported by
-    # write_telemetry into the same Perfetto bundle as the PR-1 spans
-    attrib_extra: list = []
-
-    def write_telemetry():
-        """Archive the telemetry bundle once.  Registered with atexit (for
-        crashes: the interpreter still exits normally after an unhandled
-        exception) AND with utils.trap (for SIGINT/SIGABRT: the trap handler
-        re-raises via SIG_DFL, which kills the process without running
-        atexit) so an interrupted search — the run where the trace matters
-        most — still archives everything recorded so far.  The explicit call
-        on the success path just makes the files land before the final JSON
-        line.  Filenames are rank-qualified past rank 0 so multi-host runs
-        writing to a shared directory do not clobber each other's bundles."""
-        import os
-
-        if _telemetry_done["v"]:
-            return
-        _telemetry_done["v"] = True
-        rank = obs.get_tracer().rank
-        sfx = "" if rank == 0 else f".rank{rank}"
-        if args.trace_out:
-            os.makedirs(args.trace_out, exist_ok=True)
-            obs.write_jsonl(obs.get_tracer(),
-                            os.path.join(args.trace_out, f"trace{sfx}.jsonl"))
-            obs.write_chrome_trace(
-                obs.get_tracer(),
-                os.path.join(args.trace_out, f"trace{sfx}.json"),
-                extra_events=attrib_extra or None)
-            sys.stderr.write(f"trace bundle: {args.trace_out}\n")
-        if args.metrics_json:
-            # block=False: this runs from the signal trap, where the
-            # interrupted thread may hold an instrument lock — the
-            # non-blocking read falls back to GIL-atomic copies instead of
-            # deadlocking the Ctrl-C path (the exporters above are
-            # non-blocking by construction, obs/export.py)
-            with open(args.metrics_json + sfx, "w") as f:
-                json.dump(obs.get_metrics().to_json(block=False), f,
-                          indent=2, sort_keys=True)
-            sys.stderr.write(f"metrics: {args.metrics_json}{sfx}\n")
-
+    run = _Run(args=args, row=WORKLOADS[args.workload],
+               compile_cache_dir=compile_cache_dir)
     if args.trace_out or args.metrics_json:
-        scope.on_exit(write_telemetry)
-        scope.on_trap(write_telemetry)
-
-    def error_verdict(msg: str, **extra) -> DriverResult:
-        """No measurement was made: still one parseable line, carrying
-        ``error`` (the CLI exits non-zero on it)."""
-        write_telemetry()
-        return DriverResult(verdict={
-            "metric": metric_for(args.workload, args),
-            "value": -1.0,
-            "unit": "us",
-            "vs_baseline": 0.0,
-            "error": msg,
-            **extra,
-        })
+        scope.on_exit(run.write_telemetry)
+        scope.on_trap(run.write_telemetry)
 
     try:
         # one attempt: the chip is attached to this host, so an init failure
@@ -778,14 +501,14 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
 
         devs = jax.devices()
     except Exception as e:
-        return error_verdict(f"backend init failed: {e}")
+        return run.error_verdict(f"backend init failed: {e}")
     sys.stderr.write(f"backend: {devs}\n")
-    device = device_stamp(devs)
+    run.device = device = device_stamp(devs)
     if not args.smoke and device["platform"] != "tpu":
         # the numbers this run writes are device metrics: searching 512^3
         # on whatever jax.devices() happens to return would file CPU
         # timings under their name.  --smoke is the one named CPU mode.
-        return error_verdict(
+        return run.error_verdict(
             f"device refused: a run without --smoke measures on a TPU, but "
             f"jax.devices()[0].platform is {device['platform']!r} "
             f"({device['kind']}); use --smoke for the CPU rehearsal",
@@ -793,66 +516,62 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     # fraction-of-peak denominators of the device this run measures on
     # (bench/roofline.py PEAKS).  Smoke states no fraction; a chip without
     # a row is an error, never the v5e's numbers under another name.
-    peaks = None
     if not args.smoke:
         from tenzing_tpu.bench import roofline
 
         try:
-            peaks = roofline.peaks_for(device["kind"])
+            run.peaks = roofline.peaks_for(device["kind"])
         except roofline.UnknownDeviceError as e:
-            return error_verdict(str(e), device=device)
+            return run.error_verdict(str(e), device=device)
 
-    from tenzing_tpu.bench.benchmarker import (
-        BenchOpts,
-        CachingBenchmarker,
-        EmpiricalBenchmarker,
-        result_row,
-    )
-    from tenzing_tpu.core.platform import Platform
-    from tenzing_tpu.core.state import State
-    from tenzing_tpu.runtime.executor import TraceExecutor
-    from tenzing_tpu.solve.mcts import MctsOpts, explore
-    from tenzing_tpu.solve.mcts.strategies import FastMin
+    run.built = run.row.build(args)
+    run.g = run.built[0]
+    return run
 
-    built = BUILDERS[args.workload](args)
-    g, bufs, metric = built[0], built[1], built[2]
-    # buffer byte sizes feed the surrogate's comm-bytes + analytic-makespan
-    # features (learn/features.py) — the same map for train and screen, so
-    # the feature contract holds across the two phases
-    learn_nbytes = {k: int(getattr(v, "nbytes", 0)) for k, v in bufs.items()}
 
-    if args.learn_train:
-        # corpus -> features -> ridge ensemble -> model JSON, then exit:
-        # training is offline (no device measurement), it only needs the
-        # workload graph to deserialize the recorded schedules against
-        import glob as _glob
+def _learn_nbytes(run: _Run) -> Dict[str, int]:
+    """Buffer byte sizes feed the surrogate's comm-bytes + analytic-makespan
+    features (learn/features.py) — the same map for train and screen, so
+    the feature contract holds across the two phases."""
+    return nbytes_of(run.built[1])
 
-        from tenzing_tpu import obs as _obs
-        from tenzing_tpu.learn import train_from_corpus
 
-        log = lambda m: sys.stderr.write(m + "\n")
-        paths = sorted(p for pat in args.learn_train
-                       for p in _glob.glob(pat))
-        with _obs.get_tracer().span("learn.train", n_files=len(paths)):
-            tpaths = (sorted(p for pat in args.learn_trace
-                             for p in _glob.glob(pat))
-                      if args.learn_trace else None)
-            # THE shared training recipe (learn/train.py) — the serving
-            # warm path trains through the same call
-            model, info = train_from_corpus(
-                paths, g, nbytes=learn_nbytes, trace_paths=tpaths, log=log)
-            out = {"metric": f"learn_train_{args.workload}",
-                   "device": device, **info}
-            if model is not None and args.learn_model:
-                model.save(args.learn_model)
-                out["model"] = args.learn_model
-                log(f"learn model: {args.learn_model} "
-                    f"({info['rows']} rows, train spearman "
-                    f"{out['train_spearman']})")
-        write_telemetry()
-        return DriverResult(verdict=out)
+def _learn_train(run: _Run) -> DriverResult:
+    """``--learn-train``: corpus -> features -> ridge ensemble -> model
+    JSON, then exit: training is offline (no device measurement), it only
+    needs the workload graph to deserialize the recorded schedules against."""
+    import glob as _glob
 
-    surrogate = None
+    from tenzing_tpu import obs as _obs
+    from tenzing_tpu.learn import train_from_corpus
+
+    args = run.args
+    log = lambda m: sys.stderr.write(m + "\n")
+    paths = sorted(p for pat in args.learn_train for p in _glob.glob(pat))
+    with _obs.get_tracer().span("learn.train", n_files=len(paths)):
+        tpaths = (sorted(p for pat in args.learn_trace
+                         for p in _glob.glob(pat))
+                  if args.learn_trace else None)
+        # THE shared training recipe (learn/train.py) — the serving
+        # warm path trains through the same call
+        model, info = train_from_corpus(
+            paths, run.g, nbytes=_learn_nbytes(run), trace_paths=tpaths,
+            log=log)
+        out = {"metric": f"learn_train_{args.workload}",
+               "device": run.device, **info}
+        if model is not None and args.learn_model:
+            model.save(args.learn_model)
+            out["model"] = args.learn_model
+            log(f"learn model: {args.learn_model} "
+                f"({info['rows']} rows, train spearman "
+                f"{out['train_spearman']})")
+    run.write_telemetry()
+    return DriverResult(verdict=out)
+
+
+def _load_surrogate(run: _Run):
+    """``--learn-screen``: the surrogate benchmarker, or None."""
+    args = run.args
     if args.learn_screen and args.learn_model:
         from tenzing_tpu.learn import (
             FEATURE_NAMES,
@@ -862,25 +581,30 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
 
         model = RidgeEnsemble.load(args.learn_model,
                                    expect_features=list(FEATURE_NAMES))
-        surrogate = SurrogateBenchmarker(model, nbytes=learn_nbytes)
         sys.stderr.write(
             f"learn screen: {args.learn_model} "
             f"({model.n_train} training rows)\n")
-    elif args.learn_screen:
+        return SurrogateBenchmarker(model, nbytes=_learn_nbytes(run))
+    if args.learn_screen:
         sys.stderr.write("learn screen: no --learn-model given — "
                          "screening disabled\n")
-    # 8 lanes for halo: the probed greedy lane-count curve peaks at 6-8 lanes
-    # (paired 1.38-1.42 vs 1.18-1.23 at 2) and the repeat driver winner is the
-    # mixed-engine 8-lane incumbent — searching on 8 lanes puts the hill-climb
-    # and MCTS in the same neighborhood instead of a 6-lane one.  Smoke stays
-    # at 2 lanes and a small tree (the CPU path exists to be cheap).
+    return None
+
+
+def _plant_tiles(run: _Run):
+    """The search platform and executor, and ``--fuse-search-tiles``' menu
+    planted in the graph; returns the executor measurements lower through."""
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.runtime.executor import TraceExecutor
+
+    args = run.args
     # THE default rule lives in search_lanes() — the serving fingerprint's
     # mesh signature keys on the same call, so the two cannot drift
-    n_lanes = search_lanes(args)
-    plat = Platform.make_n_lanes(n_lanes)
+    run.plat = Platform.make_n_lanes(search_lanes(args))
     if args.smoke:
+        # a small tree (the CPU path exists to be cheap)
         args.mcts_iters = min(args.mcts_iters, 12)
-    ex = TraceExecutor(plat, bufs)
+    run.ex = ex = TraceExecutor(run.plat, run.built[1])
     # --fuse-search-tiles (ISSUE 10 satellite of the PR-8 backend): plant
     # the megakernel tile menu as a decision node in the choice graph BEFORE
     # the verifier/search are built, so MCTS/DFS/hill-climb search tile
@@ -888,78 +612,62 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     # workloads) instead of only sweeping the menu post-verdict.  Every
     # measurement then lowers through the schedule's ``fuse_tile.tN``
     # directive (FusedExecutor reads it back; tiles=None).
-    measure_ex = ex
-    tile_menu = None
-    tile_planted = False
-    if args.fuse_search_tiles:
-        from tenzing_tpu.runtime.fused import FusedExecutor, with_tile_menu
+    if not args.fuse_search_tiles:
+        return ex
+    from tenzing_tpu.runtime.fused import FusedExecutor, with_tile_menu
 
-        # the menu needs a complete schedule to partition: the cheap
-        # first-decision serialization on one lane (host-side only)
-        probe_state = State(g)
-        probe_plat = Platform.make_n_lanes(1)
-        while not probe_state.is_terminal():
-            probe_state = probe_state.apply(
-                probe_state.get_decisions(probe_plat)[0])
-        # smoke relaxes the traffic floor like tests/test_fused.py
-        # (min_tile_bytes=0): toy buffers would prune every count and CI
-        # could never exercise the searched tile nodes
-        fuse_kw = {"min_tile_bytes": 0} if args.smoke else {}
-        tile_menu = FusedExecutor(ex, **fuse_kw).plan(
-            probe_state.sequence).tile_menu
-        if len(tile_menu) > 1:
-            g = with_tile_menu(g, tile_menu)
-            measure_ex = FusedExecutor(ex, **fuse_kw)
-            tile_planted = True
-            sys.stderr.write(
-                f"fuse-search-tiles: menu {tile_menu} planted in the "
-                "choice graph; measurements lower through the searched "
-                "directive\n")
-        else:
-            sys.stderr.write(
-                "fuse-search-tiles: tile menu is [1] (no fusible "
-                "decomposition survived pruning) — nothing to search\n")
+    # the menu needs a complete schedule to partition: the cheap
+    # first-decision serialization on one lane (host-side only)
+    probe = first_decision_schedule(run.g, None, Platform.make_n_lanes(1))
+    # smoke relaxes the traffic floor like tests/test_fused.py
+    # (min_tile_bytes=0): toy buffers would prune every count and CI
+    # could never exercise the searched tile nodes
+    fuse_kw = {"min_tile_bytes": 0} if args.smoke else {}
+    run.tile_menu = tile_menu = FusedExecutor(ex, **fuse_kw).plan(
+        probe).tile_menu
+    if len(tile_menu) > 1:
+        run.g = with_tile_menu(run.g, tile_menu)
+        run.tile_planted = True
+        sys.stderr.write(
+            f"fuse-search-tiles: menu {tile_menu} planted in the "
+            "choice graph; measurements lower through the searched "
+            "directive\n")
+        return FusedExecutor(ex, **fuse_kw)
+    sys.stderr.write(
+        "fuse-search-tiles: tile menu is [1] (no fusible "
+        "decomposition survived pruning) — nothing to search\n")
+    return ex
 
-    def with_tile1(seq):
-        """An out-of-graph sequence (naive_order/greedy helpers, recorded
-        rows predating the tile node) completed with the ``fuse_tile.t1``
-        directive the planted choice requires — without it the verifier
-        would reject the schedule as an unresolved choice.  The directive
-        goes AFTER the leading start sentinel: the planted choice is a
-        successor of Start, so a directive at position 0 would violate
-        the projected start->directive edge and fail verification."""
-        if not tile_planted:
-            return seq
-        from tenzing_tpu.core.sequence import Sequence as _Seq
-        from tenzing_tpu.runtime.fused import FuseTile, TILE_PREFIX
 
-        ops_ = list(seq.vector())
-        if any(op.name().startswith(TILE_PREFIX) for op in ops_):
-            return seq
-        at = 1 if ops_ and ops_[0].name() == "start" else 0
-        return _Seq(ops_[:at] + [FuseTile(1)] + ops_[at:])
+def assemble_stack(executor, graph, req: DriverRequest, surrogate,
+                   scope: _RunScope) -> Stack:
+    """The fault-tolerance stack (docs/robustness.md) over ``executor``,
+    inside-out — the one place that orders it:
 
-    emp = EmpiricalBenchmarker(measure_ex)
-    # fault-tolerance stack (docs/robustness.md), inside-out:
-    #   EmpiricalBenchmarker            device measurement
-    #   [FaultInjectingBenchmarker]     --inject-faults seeded chaos
-    #                                   (measurement-fault kinds)
-    #   [PrefetchingBenchmarker]        --prefetch-compiles async compile
-    #                                   pipeline: solver hints AOT-compile
-    #                                   in the background, failures surface
-    #                                   on the foreground call so the
-    #                                   resilient layer above classifies /
-    #                                   agrees / quarantines as usual
-    #   ResilientBenchmarker            soundness gate / watchdog /
-    #                                   classified retry / quarantine /
-    #                                   degradation
-    #   [FaultInjectingBenchmarker]     --inject-faults corrupt: schedule
-    #                                   corruption — ABOVE the resilient
-    #                                   layer so its verifier gate sees
-    #                                   (and quarantines) the mutation
-    #   [JournalingBenchmarker]         --checkpoint measurement journal
-    #   CachingBenchmarker              equivalence-keyed cache (also the
-    #                                   --resume restore target)
+      EmpiricalBenchmarker            device measurement
+      [FaultInjectingBenchmarker]     --inject-faults seeded chaos
+                                      (measurement-fault kinds)
+      [PrefetchingBenchmarker]        --prefetch-compiles async compile
+                                      pipeline: solver hints AOT-compile
+                                      in the background, failures surface
+                                      on the foreground call so the
+                                      resilient layer above classifies /
+                                      agrees / quarantines as usual
+      ResilientBenchmarker            soundness gate / watchdog /
+                                      classified retry / quarantine /
+                                      degradation
+      [FaultInjectingBenchmarker]     --inject-faults corrupt: schedule
+                                      corruption — ABOVE the resilient
+                                      layer so its verifier gate sees
+                                      (and quarantines) the mutation
+      [JournalingBenchmarker]         --checkpoint measurement journal
+      CachingBenchmarker              equivalence-keyed cache (also the
+                                      --resume restore target)
+    """
+    from tenzing_tpu.bench.benchmarker import (
+        CachingBenchmarker,
+        EmpiricalBenchmarker,
+    )
     from tenzing_tpu.fault import (
         JournalingBenchmarker,
         Quarantine,
@@ -968,12 +676,13 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     )
     from tenzing_tpu.verify import ScheduleVerifier
 
-    verifier = None if args.no_verify else ScheduleVerifier(g)
+    emp = EmpiricalBenchmarker(executor)
+    verifier = None if req.no_verify else ScheduleVerifier(graph)
     inner_specs, corrupt_specs = [], []
-    if args.inject_faults:
+    if req.inject_faults:
         from tenzing_tpu.fault import parse_inject_specs
 
-        specs = parse_inject_specs(args.inject_faults)
+        specs = parse_inject_specs(req.inject_faults)
         inner_specs = [s for s in specs if s.kind != "corrupt"]
         corrupt_specs = [s for s in specs if s.kind == "corrupt"]
         if corrupt_specs and verifier is None:
@@ -982,24 +691,24 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
             raise DriverConfigError(
                 "--inject-faults corrupt: requires the soundness "
                 "verifier (drop --no-verify)")
-        sys.stderr.write(f"chaos: injecting {args.inject_faults}\n")
+        sys.stderr.write(f"chaos: injecting {req.inject_faults}\n")
     measured_stack = emp
     injector = None
     if inner_specs:
         from tenzing_tpu.fault import FaultInjectingBenchmarker
 
         injector = FaultInjectingBenchmarker(
-            emp, inner_specs, hang_secs=args.inject_hang_secs)
+            emp, inner_specs, hang_secs=req.inject_hang_secs)
         measured_stack = injector
     prefetcher = None
-    if args.prefetch_compiles > 0 and args.resume:
+    if req.prefetch_compiles > 0 and req.resume:
         # a resumed run answers journaled measurements without touching the
         # executor (the PR 3 "0 compiles" provenance); background hints
         # would compile programs the journal already answers — keep the
         # resume contract and skip the pipeline
         sys.stderr.write("prefetch: disabled under --resume (journaled "
                          "answers never compile)\n")
-    elif args.prefetch_compiles > 0:
+    elif req.prefetch_compiles > 0:
         from tenzing_tpu.bench.pipeline import PrefetchingBenchmarker
 
         # ABOVE injection (background compiles are not chaos targets — the
@@ -1007,15 +716,15 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
         # only) and BELOW the resilient layer (surfaced compile failures
         # ride the normal classify/agree/quarantine path)
         measured_stack = prefetcher = PrefetchingBenchmarker(
-            measured_stack, executor=measure_ex,
-            workers=args.prefetch_compiles, rank=surrogate)
+            measured_stack, executor=executor,
+            workers=req.prefetch_compiles, rank=surrogate)
         # exception paths too (not only the happy-path close below): a
         # fatal mid-search error must not leave queued background compiles
         # draining at interpreter exit — the pool's own shutdown hook joins
         # only AFTER the queue empties (~3.4 s per pending compile), while
         # close() cancels pending first.  Idempotent; SIGINT has the trap.
         scope.on_exit(prefetcher.close)
-    ckpt = SearchCheckpoint(args.checkpoint) if args.checkpoint else None
+    ckpt = SearchCheckpoint(req.checkpoint) if req.checkpoint else None
     quar = Quarantine(ckpt.quarantine_path if ckpt else None,
                       log=lambda m: sys.stderr.write(m + "\n"))
     if len(quar):
@@ -1023,7 +732,7 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
             f"quarantine: {len(quar)} schedule(s) carried from previous "
             "runs will not be re-measured\n")
     resilient = ResilientBenchmarker(
-        measured_stack, timeout_secs=args.measure_timeout, quarantine=quar,
+        measured_stack, timeout_secs=req.measure_timeout, quarantine=quar,
         fallback=surrogate, verifier=verifier)
     guarded = resilient
     corrupt_injector = None
@@ -1037,399 +746,261 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     bench = CachingBenchmarker(
         JournalingBenchmarker(guarded, ckpt) if ckpt else guarded)
     if ckpt is not None:
-        config = {"workload": args.workload, "metric": metric,
-                  "smoke": bool(args.smoke), "seed_topk": args.seed_topk}
-        prior = None
-        try:
-            prior = ckpt.load_state()
-        except Exception as e:  # corrupt snapshot: resume from journal only
-            sys.stderr.write(f"checkpoint: state unreadable ({e}); "
-                             "journal + quarantine still apply\n")
-        if prior is not None and prior.get("config") not in (None, config):
-            sys.stderr.write(
-                "checkpoint: recorded config differs from this run "
-                f"({prior.get('config')} vs {config}); journal rows that "
-                "do not resolve against this workload are skipped\n")
-        want_inject = args.inject_faults or None
-        if args.resume and prior is not None and \
-                prior.get("inject") != want_inject:
-            # a resumed chaos run whose injection spec disagrees with the
-            # one the checkpoint was written under would replay journaled
-            # answers from a DIFFERENT fault universe and silently diverge
-            # from both the original run and a clean rerun — refuse loudly
-            raise DriverConfigError(
-                "--resume: this run's --inject-faults "
-                f"({want_inject!r}) disagrees with the checkpoint's "
-                f"recorded injection spec ({prior.get('inject')!r}); "
-                "use the same spec (including seeds) or start a fresh "
-                "checkpoint directory")
-        if args.resume:
-            restored = ckpt.restore_into(
-                bench, g, log=lambda m: sys.stderr.write(m + "\n"))
-            sys.stderr.write(
-                f"resume: {restored} recorded measurement(s) restored — "
-                "already-measured schedules will not touch the device\n")
-        ckpt.save_state(config=config, inject=want_inject)
+        _open_checkpoint(ckpt, bench, graph, req, scope)
+    return Stack(emp=emp, injector=injector, prefetcher=prefetcher,
+                 resilient=resilient, corrupt_injector=corrupt_injector,
+                 quarantine=quar, checkpoint=ckpt, bench=bench,
+                 verifier=verifier)
 
-        # final snapshots: the journal and quarantine are already on disk
-        # (appended/rewritten as each measurement landed), so these only
-        # stamp the cursor document.  The trap path marks the interrupt
-        # (SIG_DFL then kills without running the exit finalizers); a
-        # normal return (or crash) marks completion at scope close.
-        scope.on_exit(lambda: ckpt.save_state(done=True))
-        scope.on_trap(lambda: ckpt.save_state(interrupted=True))
+
+def _open_checkpoint(ckpt, bench, graph, args: DriverRequest,
+                     scope: _RunScope) -> None:
+    """Check ``--checkpoint``'s recorded config against this run's, restore
+    its journal under ``--resume``, and register its final snapshots."""
+    config = {"workload": args.workload,
+              "metric": metric_for(args.workload, args),
+              "smoke": bool(args.smoke), "seed_topk": args.seed_topk}
+    prior = None
+    try:
+        prior = ckpt.load_state()
+    except Exception as e:  # corrupt snapshot: resume from journal only
+        sys.stderr.write(f"checkpoint: state unreadable ({e}); "
+                         "journal + quarantine still apply\n")
+    if prior is not None and prior.get("config") not in (None, config):
+        sys.stderr.write(
+            "checkpoint: recorded config differs from this run "
+            f"({prior.get('config')} vs {config}); journal rows that "
+            "do not resolve against this workload are skipped\n")
+    want_inject = args.inject_faults or None
+    if args.resume and prior is not None and \
+            prior.get("inject") != want_inject:
+        # a resumed chaos run whose injection spec disagrees with the
+        # one the checkpoint was written under would replay journaled
+        # answers from a DIFFERENT fault universe and silently diverge
+        # from both the original run and a clean rerun — refuse loudly
+        raise DriverConfigError(
+            "--resume: this run's --inject-faults "
+            f"({want_inject!r}) disagrees with the checkpoint's "
+            f"recorded injection spec ({prior.get('inject')!r}); "
+            "use the same spec (including seeds) or start a fresh "
+            "checkpoint directory")
+    if args.resume:
+        restored = ckpt.restore_into(
+            bench, graph, log=lambda m: sys.stderr.write(m + "\n"))
+        sys.stderr.write(
+            f"resume: {restored} recorded measurement(s) restored — "
+            "already-measured schedules will not touch the device\n")
+    ckpt.save_state(config=config, inject=want_inject)
+
+    # final snapshots: the journal and quarantine are already on disk
+    # (appended/rewritten as each measurement landed), so these only
+    # stamp the cursor document.  The trap path marks the interrupt
+    # (SIG_DFL then kills without running the exit finalizers); a
+    # normal return (or crash) marks completion at scope close.
+    scope.on_exit(lambda: ckpt.save_state(done=True))
+    scope.on_trap(lambda: ckpt.save_state(interrupted=True))
+
+
+def _measure_naive(run: _Run) -> None:
+    """The measurement floors, and the naive incumbent: the
+    fully-synchronous serialization on one lane (the reference's
+    "sequential ordering on one stream" baseline, BASELINE.json)."""
+    from tenzing_tpu.bench.benchmarker import BenchOpts
+
+    args, stack = run.args, run.stack
     # max_retries=2 (library default 10): the runs-test retry loop re-measures
     # the whole series on rejection, and in a slow chip regime that blew
     # a single naive benchmark to 558 s of wall; the verdict comes from the
     # paired batches (which have no retry loop), so the search-phase numbers
     # only need to be cheap, not certified-stationary
-    opts = BenchOpts(n_iters=max(5, args.iters), max_retries=2,
-                     target_secs=0.002 if args.smoke else 0.02)
+    run.opts = BenchOpts(n_iters=max(5, args.iters), max_retries=2,
+                         target_secs=0.002 if args.smoke else 0.02)
     # the search phase buys BREADTH with cheap measurements (VERDICT r2 weak
     # #2: 24 iters at full measurement cost explored a 109-node tree of a far
     # larger space); ranking candidates is the paired screening batch's job,
     # so search-time numbers only need to steer the tree
-    search_opts = BenchOpts(
+    run.search_opts = BenchOpts(
         n_iters=max(3, args.search_iters),
         max_retries=2,
         target_secs=0.002 if args.smoke else 0.01,
     )
-
-    # naive incumbent: the fully-synchronous serialization on one lane (the
-    # reference's "sequential ordering on one stream" baseline, BASELINE.json)
-    naive_plat = Platform.make_n_lanes(1)
-    naive_seq = naive_schedule(args.workload, g, built[3])
     # a planted tile menu makes the directive part of every complete
     # schedule; the out-of-graph naive builders predate it
-    naive_seq = with_tile1(naive_seq)
+    run.naive_seq = naive_seq = run.with_tile1(
+        naive_schedule(args.workload, run.g, run.built[3]))
     # the baseline is not a search candidate: exempt it from the
     # identity-keyed candidate-fault kinds (deterministic/corrupt), which
     # would otherwise deterministically kill the run under ~rate of the
     # seeds before the search starts.  Device-fault kinds still apply.
-    for inj in (injector, corrupt_injector):
+    for inj in (stack.injector, stack.corrupt_injector):
         if inj is not None:
             from tenzing_tpu.bench.benchmarker import schedule_id as _sid
 
             inj.exempt_ids.add(_sid(naive_seq))
-    if prefetcher is not None:
+    if stack.prefetcher is not None:
         # hint the baseline itself: its compile starts on a worker while
         # argument/driver setup finishes, the foreground join consumes it,
         # and every run deterministically exercises the AOT-program /
         # prepare_n cache-key agreement on the real executor (the CI smoke
         # asserts prefetch hits > 0 on exactly this)
-        prefetcher.prefetch([naive_seq])
+        stack.prefetcher.prefetch([naive_seq])
     t0 = time.time()
-    naive = bench.benchmark(naive_seq, opts)
-    sys.stderr.write(f"naive: pct50={naive.pct50*1e6:.1f}us (wall {time.time()-t0:.0f}s)\n")
+    run.naive = stack.bench.benchmark(naive_seq, run.opts)
+    sys.stderr.write(f"naive: pct50={run.naive.pct50*1e6:.1f}us (wall {time.time()-t0:.0f}s)\n")
 
-    # anytime search: heuristic incumbents first, then the directed search.
-    # For halo the domain heuristic is the post-all-before-await-any overlap
-    # discipline — the one the reference's graph hard-codes via its
-    # every-post-before-any-wait edges (ops_halo_exchange.cu:249-256)
-    incumbents = []
-    incumbent_labels: dict = {}
-    # MCTS warm-start seeds: incumbent disciplines as DECISION PATHS on the
-    # search platform over the choice graph (filled alongside the incumbents;
-    # VERDICT r3 item 1)
-    seed_paths = []
-    # informed MCTS playouts: rollouts complete with the workload's best
-    # hand discipline (epsilon-noised) instead of uniform random — a
-    # ~100-decision halo schedule essentially never assembles a coherent
-    # discipline by chance, which is why random-playout MCTS lagged the
-    # climbs for four rounds (VERDICT r4 item 2)
-    mcts_rollout_policy = None
-    if args.workload == "attn" and not args.smoke:
-        # kernel incumbents: (a) the per-block chain with every block on the
-        # bf16 Pallas kernel (the r2-r4 winner), (b) the fused single-kernel
-        # flash with VMEM-resident state (the r5 HBM-state-traffic fix) —
-        # the directed search starts from both, the final batch must include
-        # whichever survives the screen
-        from tenzing_tpu.core.state import ChooseOp
-        from tenzing_tpu.solve.mcts.mcts import SimResult
 
-        def attn_incumbent(label, engine_suffix, kernel_suffix):
-            st = State(g)
-            while not st.is_terminal():
-                ds = st.get_decisions(naive_plat)
-                pick = next(
-                    (d for d in ds if isinstance(d, ChooseOp)
-                     and d.choice.name().endswith(engine_suffix)),
-                    None,
-                ) or next(
-                    (d for d in ds if isinstance(d, ChooseOp)
-                     and d.choice.name().endswith(kernel_suffix)),
-                    ds[0],
-                )
-                st = st.apply(pick)
-            t0 = time.time()
-            try:
-                res_i = bench.benchmark(st.sequence, search_opts)
-            except Exception as e:
-                sys.stderr.write(
-                    f"{label} incumbent rejected ({type(e).__name__}: "
-                    f"{str(e)[:160]})\n")
-                return
-            sys.stderr.write(
-                f"{label} incumbent: pct50={res_i.pct50*1e6:.1f}us "
-                f"(wall {time.time()-t0:.0f}s)\n"
-            )
-            sim = SimResult(order=st.sequence, result=res_i)
-            incumbent_labels[id(sim)] = label
-            incumbents.append(sim)
+def _measure_incumbents(run: _Run):
+    """The row's hand incumbents, measured: ``(incumbents, seed_paths,
+    rollout_policy)``."""
+    from tenzing_tpu.solve.mcts.mcts import SimResult
 
-        attn_incumbent("bf16-kernel", ".chain", ".pallas_bf16")
-        attn_incumbent("fused-bf16", ".fused_bf16", ".pallas_bf16")
-    if args.workload in ("halo", "moe"):
-        from tenzing_tpu.solve.mcts.mcts import SimResult
-
-        if args.workload == "halo":
-            from tenzing_tpu.models.halo_pipeline import (
-                greedy_overlap_order,
-                paired_overlap_order,
-            )
-
-            greedy_seqs = []
-            if args.smoke:
-                greedy_seqs.append(
-                    ("greedy-overlap", greedy_overlap_order(built[3], plat)))
-            else:
-                from tenzing_tpu.models.halo import (
-                    DIRECTIONS as _DIRS,
-                    dir_name as _dn,
-                )
-                from tenzing_tpu.models.halo_pipeline import (
-                    HALO_PHASES as _PH,
-                    paired_priority,
-                )
-                from tenzing_tpu.solve.local import drive, phase_policy
-
-                _dirs = [_dn(d) for d in _DIRS]
-
-                def mk_prefer(engine):
-                    def prefer(op_name, choices):
-                        if op_name.startswith("xfer_"):
-                            i = _dirs.index(op_name.split("_", 1)[1])
-                            want = {"host": ".host", "rdma": ".rdma",
-                                    "alias": ".rdma"}.get(
-                                engine, ".rdma" if i % 2 == 0 else ".host")
-                            return next(
-                                (c for c in choices if c.endswith(want)), None)
-                        if engine == "alias" and op_name.startswith("unpack_"):
-                            hit = alias_unpack_choice(op_name, choices)
-                            if hit is not None:
-                                return hit
-                        return next(
-                            (c for c in choices if c.endswith(".xla")), None)
-
-                    return prefer
-
-                # rollouts complete with the measured r5 alias discipline
-                # (phase_policy is stateful via its lane round-robin, which
-                # adds completion diversity on top of rollout_eps)
-                mcts_rollout_policy = phase_policy(
-                    plat, _PH, mk_prefer("alias"))
-
-                # search-platform (8-lane) incumbents are driven on the
-                # CHOICE graph itself, and their decision paths double as the
-                # MCTS warm-start seeds (re-measured at the cheap screen
-                # floor — a few ms of device time — since the multi-fidelity
-                # split keys the cache per-floor)
-                for label, engine, pri in (
-                    ("greedy-host-8l", "host", None),
-                    ("greedy-rdma-8l", "rdma", None),
-                    ("greedy-mixed-8l", "mixed", None),
-                    ("greedy-paired-8l", "mixed", paired_priority("mixed")),
-                    ("greedy-alias-8l", "alias", None),
-                ):
-                    seq, decs = drive(g, plat, phase_policy(
-                        plat, _PH, mk_prefer(engine), priority=pri))
-                    greedy_seqs.append((label, seq))
-                    seed_paths.append(decs)
-                # other lane counts: engine-fixed graphs (probed on v5e:
-                # rdma peaks at 2-3 lanes, mixed also strong at 6)
-                for label, engine, nl in (
-                    ("greedy-rdma-2l", "rdma", 2),
-                    ("greedy-rdma-3l", "rdma", 3),
-                    ("greedy-mixed-6l", "mixed", 6),
-                ):
-                    greedy_seqs.append((label, greedy_overlap_order(
-                        built[3], Platform.make_n_lanes(nl), engine=engine)))
-                greedy_seqs.append(("greedy-paired-6l", paired_overlap_order(
-                    built[3], Platform.make_n_lanes(6), engine="mixed")))
-                # the aliased-unpack recipe at the probed lane counts
-                # (experiments/MENU_INCUMBENT3.json: 3.2-3.4x paired at
-                # 2/3/6 lanes, best at 6) — driven on the choice graph so
-                # their decision paths also seed the tree
-                for label, nl in (("greedy-alias-3l", 3),
-                                  ("greedy-alias-6l", 6)):
-                    plat_a = Platform.make_n_lanes(nl)
-                    seq, decs = drive(g, plat_a, phase_policy(
-                        plat_a, _PH, mk_prefer("alias")))
-                    greedy_seqs.append((label, seq))
-                    seed_paths.append(decs)
-        else:
-            from tenzing_tpu.models.moe_pipeline import greedy_overlap_order
-
-            margs_, cap_ = built[3]
-            greedy_seqs = [
-                ("greedy-overlap", greedy_overlap_order(margs_, cap_, plat))
-            ]
-            if not args.smoke:
-                # the half-width-transfer incumbent (bf16 staging) and the
-                # device-resident-transfer incumbents (rdma engine): the
-                # likely winners the search should start from
-                greedy_seqs.append((
-                    "greedy-overlap-bf16",
-                    greedy_overlap_order(margs_, cap_, plat, staging="bf16"),
-                ))
-                greedy_seqs.append((
-                    "greedy-bf16-rdma",
-                    greedy_overlap_order(margs_, cap_, plat, staging="bf16",
-                                         engine="rdma"),
-                ))
-                greedy_seqs.append((
-                    "greedy-f32-rdma",
-                    greedy_overlap_order(margs_, cap_, plat, engine="rdma"),
-                ))
-        greedy_seqs = [(label, with_tile1(s)) for label, s in greedy_seqs]
-        if prefetcher is not None:
+    stack = run.stack
+    hand = run.row.incumbents(run.args, run.g, run.built[3], run.plat)
+    seqs = hand.seqs
+    if seqs and not hand.tolerant:
+        seqs = [(label, run.with_tile1(s)) for label, s in seqs]
+        if stack.prefetcher is not None:
             # the incumbent grid is known up front: incumbent k+1 compiles
             # in the background while incumbent k measures
-            prefetcher.prefetch([s for _, s in greedy_seqs])
-        for label, greedy_seq in greedy_seqs:
-            t0 = time.time()
-            # search-phase cost: incumbents are re-ranked by the paired
-            # screen anyway, this number only seeds the tree
-            greedy = bench.benchmark(greedy_seq, search_opts)
+            stack.prefetcher.prefetch([s for _, s in seqs])
+    incumbents = []
+    for label, seq in seqs:
+        t0 = time.time()
+        # search-phase cost: incumbents are re-ranked by the paired
+        # screen anyway, this number only seeds the tree
+        try:
+            meas = stack.bench.benchmark(seq, run.search_opts)
+        except Exception as e:
+            if not hand.tolerant:
+                raise
             sys.stderr.write(
-                f"{label} incumbent: pct50={greedy.pct50*1e6:.1f}us "
-                f"(wall {time.time()-t0:.0f}s)\n"
-            )
-            sim = SimResult(order=greedy_seq, result=greedy)
-            incumbent_labels[id(sim)] = label
-            incumbents.append(sim)
-
-    # recorded-best warm start: the best distinct schedules from previous
-    # runs' search databases are first-class candidates (the search
-    # remembers its own discoveries across runs — CSV checkpoint/resume, the
-    # reference's mcts_csv workflow) and, below, a hill-climb seed
-    # discipline.  r4l motivated this: r4k's climb discovered the
-    # batched-z-unpack combination at paired 2.48, and the next run's climbs
-    # wandered to 1.42 local optima instead of starting from it.
-    recorded = []  # best-first sequences, filled below
-    if args.seed_csv is None:
-        args.seed_csv = {
-            "halo": "experiments/halo_search_tpu_r[45]*.csv",
-            "moe": "experiments/moe_search_tpu_r[45]*.csv",
-            "attn": "experiments/attn_search_tpu_r[45]*.csv",
-        }.get(args.workload, "")
-    if args.seed_csv and args.seed_topk > 0 and not args.smoke:
-        import glob as _glob
-        import os.path as _osp
-
-        from tenzing_tpu.bench.recorded import rank_recorded
-        from tenzing_tpu.solve.mcts.mcts import SimResult
-
-        pat = args.seed_csv
-        if not _osp.isabs(pat):
-            pat = _osp.join(REPO_ROOT, pat)
-        paths = sorted(_glob.glob(pat))
-        if not paths:
-            sys.stderr.write(f"recorded db: no files match {pat!r}\n")
-        picked = rank_recorded(
-            paths, g, args.seed_topk,
-            log=lambda m: sys.stderr.write(m + "\n"),
+                f"{label} incumbent rejected ({type(e).__name__}: "
+                f"{str(e)[:160]})\n")
+            continue
+        sys.stderr.write(
+            f"{label} incumbent: pct50={meas.pct50*1e6:.1f}us "
+            f"(wall {time.time()-t0:.0f}s)\n"
         )
-        # recorded rows predating a planted tile menu carry no directive
-        picked = [(with_tile1(s), r) for s, r in picked]
-        recorded_ok = []
-        if prefetcher is not None:
-            prefetcher.prefetch([s for s, _ in picked])
-        from tenzing_tpu.fault.backoff import BackoffPolicy as _BP, retry_call
+        sim = SimResult(order=seq, result=meas)
+        run.labels[id(sim)] = label
+        incumbents.append(sim)
+    return incumbents, hand.seed_paths, hand.rollout_policy
 
-        for ri, (seq_r, ratio) in enumerate(picked):
-            t0 = time.time()
-            # transient-classified retry via the shared backoff helper (the
-            # device runtime can have flaky spells); a deterministic failure — a recorded
-            # schedule this chip genuinely cannot run — drops immediately
-            try:
-                meas = retry_call(
-                    lambda seq_r=seq_r: bench.benchmark(seq_r, search_opts),
-                    policy=_BP(retries=1, base_secs=2.0),
-                    where="recorded.warmstart",
-                )
-            except Exception as err:
-                sys.stderr.write(
-                    f"recorded[{ri}] dropped "
-                    f"({type(err).__name__}: {str(err)[:200]})\n"
-                )
-                continue
+
+def _recorded_warm_start(run: _Run, incumbents: list) -> list:
+    """Recorded-best warm start: the best distinct schedules from previous
+    runs' search databases are first-class candidates (the search remembers
+    its own discoveries across runs — CSV checkpoint/resume, the reference's
+    mcts_csv workflow) and a hill-climb seed discipline.  r4l motivated
+    this: r4k's climb discovered the batched-z-unpack combination at paired
+    2.48, and the next run's climbs wandered to 1.42 local optima instead of
+    starting from it.  Appends to ``incumbents``; returns the recorded
+    sequences that measured, best first."""
+    args, stack = run.args, run.stack
+    if args.seed_csv is None:
+        args.seed_csv = run.row.seed_csv
+    if not (args.seed_csv and args.seed_topk > 0 and not args.smoke):
+        return []
+    import glob as _glob
+    import os.path as _osp
+
+    from tenzing_tpu.bench.recorded import rank_recorded
+    from tenzing_tpu.solve.mcts.mcts import SimResult
+
+    pat = args.seed_csv
+    if not _osp.isabs(pat):
+        pat = _osp.join(REPO_ROOT, pat)
+    paths = sorted(_glob.glob(pat))
+    if not paths:
+        sys.stderr.write(f"recorded db: no files match {pat!r}\n")
+    picked = rank_recorded(
+        paths, run.g, args.seed_topk,
+        log=lambda m: sys.stderr.write(m + "\n"),
+    )
+    # recorded rows predating a planted tile menu carry no directive
+    picked = [(run.with_tile1(s), r) for s, r in picked]
+    recorded_ok = []
+    if stack.prefetcher is not None:
+        stack.prefetcher.prefetch([s for s, _ in picked])
+    from tenzing_tpu.fault.backoff import BackoffPolicy as _BP, retry_call
+
+    for ri, (seq_r, ratio) in enumerate(picked):
+        t0 = time.time()
+        # transient-classified retry via the shared backoff helper (the
+        # device runtime can have flaky spells); a deterministic failure — a recorded
+        # schedule this chip genuinely cannot run — drops immediately
+        try:
+            meas = retry_call(
+                lambda seq_r=seq_r: stack.bench.benchmark(
+                    seq_r, run.search_opts),
+                policy=_BP(retries=1, base_secs=2.0),
+                where="recorded.warmstart",
+            )
+        except Exception as err:
             sys.stderr.write(
-                f"recorded[{ri}] candidate: pct50={meas.pct50*1e6:.1f}us "
-                f"(recorded ratio {ratio:.3f}, wall {time.time()-t0:.0f}s)\n"
+                f"recorded[{ri}] dropped "
+                f"({type(err).__name__}: {str(err)[:200]})\n"
             )
-            sim = SimResult(order=seq_r, result=meas)
-            incumbent_labels[id(sim)] = f"recorded[{ri}]"
-            incumbents.append(sim)
-            recorded_ok.append((seq_r, meas.pct50))
-        # best by RE-MEASURED time first for the climb seed (this run's
-        # regime, same fidelity across the three)
-        recorded = [s for s, _ in sorted(recorded_ok, key=lambda e: e[1])]
+            continue
+        sys.stderr.write(
+            f"recorded[{ri}] candidate: pct50={meas.pct50*1e6:.1f}us "
+            f"(recorded ratio {ratio:.3f}, wall {time.time()-t0:.0f}s)\n"
+        )
+        sim = SimResult(order=seq_r, result=meas)
+        run.labels[id(sim)] = f"recorded[{ri}]"
+        incumbents.append(sim)
+        recorded_ok.append((seq_r, meas.pct50))
+    # best by RE-MEASURED time first for the climb seed (this run's
+    # regime, same fidelity across the three)
+    return [s for s, _ in sorted(recorded_ok, key=lambda e: e[1])]
 
-    # moe warm-start seed (halo's were recorded with its incumbents above)
-    if not args.smoke and args.workload == "moe":
-        from tenzing_tpu.models.moe_pipeline import PHASES as _MOE_PH
-        from tenzing_tpu.solve.local import drive, phase_policy
 
-        def moe_seed_prefer(op_name, choices):
-            return next(
-                (c for c in choices if c.endswith(".bf16-rdma")),
-                next((c for c in choices if c.endswith(".xla")), None),
-            )
+def _tree_search(run: _Run, incumbents, seed_paths, rollout_policy):
+    """Directed search over the order x lane x kernel x engine space, at the
+    cheap search-phase measurement cost.  Multi-fidelity (VERDICT r4 item
+    2): rollouts are measured at a ~1 ms screen floor — search-time numbers
+    only steer the tree — and the top-k distinct schedules are re-measured
+    at the climb floor before the dump, so MCTS's official candidates carry
+    comparable-fidelity numbers into the paired screen."""
+    from tenzing_tpu.bench.benchmarker import BenchOpts
+    from tenzing_tpu.solve.mcts import MctsOpts, explore
+    from tenzing_tpu.solve.mcts.strategies import FastMin
 
-        _, decs = drive(g, plat, phase_policy(plat, _MOE_PH, moe_seed_prefer))
-        seed_paths.append(decs)
-        mcts_rollout_policy = phase_policy(plat, _MOE_PH, moe_seed_prefer)
-
-    # directed search over the order x lane x kernel x engine space, at the
-    # cheap search-phase measurement cost.  Multi-fidelity (VERDICT r4 item
-    # 2): rollouts are measured at a ~1 ms screen floor — search-time numbers
-    # only steer the tree — and the top-k distinct schedules are re-measured
-    # at the climb floor before the dump, so MCTS's official candidates carry
-    # comparable-fidelity numbers into the paired screen
+    args, stack, bench = run.args, run.stack, run.stack.bench
     t0 = time.time()
-    mcts_screen = BenchOpts(
+    run.mcts_screen = mcts_screen = BenchOpts(
         n_iters=2, max_retries=2,
         target_secs=0.0005 if args.smoke else 0.001,
     )
     mcts_confirm = BenchOpts(
         n_iters=max(5, args.iters), max_retries=2,
-        target_secs=search_opts.target_secs * 10,
+        target_secs=run.search_opts.target_secs * 10,
     )
-    search_bench = bench
-    if surrogate is not None:
+    run.search_bench = search_bench = bench
+    if run.surrogate is not None:
         # the learned screen slots into the existing screen/confirm split:
         # rollout queries (mcts_screen opts) may be answered by the model,
         # while the confirm pass and everything at any other fidelity
         # always reaches the device (screen_only_opts)
         from tenzing_tpu.learn import ScreeningBenchmarker
 
-        search_bench = ScreeningBenchmarker(
-            surrogate, bench, escalate_topk=max(4, args.seed_topk + 1),
+        run.search_bench = search_bench = ScreeningBenchmarker(
+            run.surrogate, bench, escalate_topk=max(4, args.seed_topk + 1),
             screen_only_opts=mcts_screen,
         )
     res = explore(
-        g,
-        plat,
+        run.g,
+        run.plat,
         search_bench,
         MctsOpts(n_iters=args.mcts_iters, bench_opts=mcts_confirm,
                  screen_opts=mcts_screen, confirm_topk=4, seed=0,
-                 rollout_policy=mcts_rollout_policy,
-                 checkpoint=ckpt, verify=verifier, prefetch=prefetcher),
+                 rollout_policy=rollout_policy,
+                 checkpoint=stack.checkpoint, verify=stack.verifier,
+                 prefetch=stack.prefetcher),
         strategy=FastMin,
         seeds=seed_paths,
     )
-    if surrogate is not None:
+    if run.surrogate is not None:
         sys.stderr.write(
             f"learn screen: {search_bench.hits} surrogate answers / "
             f"{search_bench.escalations} escalations\n")
@@ -1450,249 +1021,170 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
         sys.stderr.write(res.counters.report() + "\n")
     sys.stderr.write(
         f"bench cache: {bench.hits} hits / {bench.misses} misses; "
-        f"compiled programs: {ex.compile_count} "
-        f"({ex.compile_secs:.1f}s compile wall)\n"
+        f"compiled programs: {run.ex.compile_count} "
+        f"({run.ex.compile_secs:.1f}s compile wall)\n"
     )
-    if prefetcher is not None:
-        pst = prefetcher.stats()
+    if stack.prefetcher is not None:
+        pst = stack.prefetcher.stats()
         sys.stderr.write(
             "prefetch: %(issued)d issued / %(hits)d hits / %(wasted)d "
             "wasted / %(failed)d failed / %(dropped)d dropped\n" % pst)
     res.sims = incumbents + res.sims
+    return res
 
-    # neighborhood search from the best-known heuristic: hill-climb in
-    # decision space (solve/local.py) refines it with measured
-    # single-substitution moves — the local complement to MCTS's global
-    # exploration, at the same cheap search cost
-    climb_cfg = []
 
-    def recorded_prefer_and_lanes():
-        """(prefer, n_lanes, chosen) replicating the best recorded
-        schedule's menu choices — the climb starts in the recorded winner's
-        kernel/engine configuration and searches order/lane/flip moves from
-        there.  ``chosen`` rides along so a fleet job spec can serialize
-        the policy for a worker process (recorded_prefer rebuilds it)."""
-        from tenzing_tpu.core.serdes import sequence_to_json
+def _climb(run: _Run, res, incumbents: list, recorded: list) -> None:
+    """Neighborhood search from the best-known heuristic: hill-climb in
+    decision space (solve/local.py) refines it with measured
+    single-substitution moves — the local complement to MCTS's global
+    exploration, at the same cheap search cost.  The climbs' candidates
+    join ``res.sims``, their tips ``incumbents``.
 
-        js = sequence_to_json(recorded[0])
-        chosen: dict = {}
-        for j in js:
-            n = j.get("name", "")
-            if "." in n:
-                base, suffix = n.rsplit(".", 1)
-                chosen.setdefault(base, "." + suffix)
-
-        lanes_used = [j.get("lane") for j in js if j.get("lane") is not None]
-        return (recorded_prefer(chosen),
-                (max(lanes_used) + 1 if lanes_used else 2), chosen)
-
-    # each climb config carries its prefer SPEC (name + serialized chosen
-    # map) beside the callable, so the fleet can ship the policy to a
-    # worker process (search/fleet.py resolve_prefer rebuilds the same
-    # module-level functions — inline and worker execution agree
-    # decision-for-decision)
-    if args.workload == "halo" and not args.smoke:
-        from tenzing_tpu.models.halo_pipeline import HALO_PHASES
-
-        # climbs: one seeded from the best RECORDED schedule's menu choices
-        # (when a database is present — the cross-run memory), then the two
-        # strongest measured disciplines, split 4:3: the aliased-unpack
-        # all-rdma recipe at its two best probed lane counts
-        # (MENU_INCUMBENT3.json: 3.2-3.4x paired at 3 and 6 lanes) — the
-        # climb refines order/lane/kernel-flip moves from there
-        b_rec = (args.climb_budget // 3) if recorded else 0
-        rest = args.climb_budget - b_rec
-        b1 = (rest * 4) // 7
-        plat3 = Platform.make_n_lanes(3)
-        climb_cfg = [
-            (plat3, HALO_PHASES, halo_alias_prefer, None, b1,
-             "halo_alias", None),
-            (Platform.make_n_lanes(6), HALO_PHASES, halo_alias_prefer, None,
-             rest - b1, "halo_alias", None),
-        ]
-        if b_rec:
-            rec_prefer, n_rec, rec_chosen = recorded_prefer_and_lanes()
-            climb_cfg.insert(
-                0,
-                (Platform.make_n_lanes(n_rec), HALO_PHASES, rec_prefer, None,
-                 b_rec, "recorded", rec_chosen),
-            )
-    elif args.workload == "moe" and not args.smoke:
-        from tenzing_tpu.models.moe_pipeline import PHASES as MOE_PHASES
-
-        b_rec = (args.climb_budget // 2) if recorded else 0
-        climb_cfg = [(plat, MOE_PHASES, moe_bf16_prefer, None,
-                      args.climb_budget - b_rec, "moe_bf16", None)]
-        if b_rec:
-            rec_prefer, n_rec, rec_chosen = recorded_prefer_and_lanes()
-            climb_cfg.insert(
-                0,
-                (Platform.make_n_lanes(n_rec), MOE_PHASES, rec_prefer, None,
-                 b_rec, "recorded", rec_chosen),
-            )
-    # distributed search fleet (docs/performance.md, "Distributed search"):
-    # --search-workers N / --measure-batch K route the SAME climb jobs
-    # through search/fleet.py — (1,1) is the serialized inline baseline
-    # (bit-identical to the legacy loop below), N>=2 spawns worker
-    # processes measuring through fused K-candidate rounds.  0/0 keeps the
-    # legacy loop byte-for-byte.
+    Distributed search fleet (docs/performance.md, "Distributed search"):
+    --search-workers N / --measure-batch K route the SAME climb jobs
+    through search/fleet.py — (1,1) is the serialized inline baseline
+    (bit-identical to the legacy loop below), N>=2 spawns worker
+    processes measuring through fused K-candidate rounds.  0/0 keeps the
+    legacy loop byte-for-byte."""
+    args, stack, bench = run.args, run.stack, run.stack.bench
+    climb_cfg = run.row.climb_config(args, run.plat, recorded)
     fleet_n = max(0, int(args.search_workers or 0))
     fleet_k = max(0, int(args.measure_batch or 0))
     fleet_engaged = fleet_n > 0 or fleet_k > 0
-    distributed_stats = None
     if fleet_engaged and not climb_cfg and args.climb_budget > 0:
         # --smoke builds no climb configs; synthesize a deterministic 2-job
         # split of the climb budget — the job list depends only on the
         # request (never on N or K), so the (1,1) serialized baseline and
         # the fused fleet spend the same candidate budget
-        if args.workload == "halo":
-            from tenzing_tpu.models.halo_pipeline import HALO_PHASES as _FPH
-        elif args.workload == "moe":
-            from tenzing_tpu.models.moe_pipeline import PHASES as _FPH
-        else:
-            _FPH = ("",)
         _half = max(1, args.climb_budget // 2)
-        climb_cfg = [
-            (plat, _FPH, generic_xla_prefer, None, _half,
-             "generic_xla", None),
-            (plat, _FPH, generic_xla_prefer, None, _half,
-             "generic_xla", None),
+        climb_cfg = [(run.plat, run.row.phases(), generic_xla_prefer, None,
+                      _half, "generic_xla", None)] * 2
+    if not (climb_cfg and args.climb_budget > 0):
+        return
+    from dataclasses import replace as _replace
+
+    from tenzing_tpu.solve.local import LocalOpts, hill_climb
+
+    # paired=True: accept moves only on a back-to-back paired comparison
+    # with the incumbent — the r4a run showed unpaired first-improvement
+    # climbing chases chip drift (climb "best" 96 ms that the paired
+    # screen ranked below its own seed).  Accepts run at SCREEN fidelity
+    # (r4c: accepts at the cheap 0.01s floor did not replicate under the
+    # screen's 0.1s floor — measurement-regime-dependent overlap), which
+    # costs ~1.6s of measurement per neighbor on top of the ~3s compile.
+    climb_opts = _replace(run.search_opts, n_iters=8,
+                          target_secs=10 * run.search_opts.target_secs)
+
+    def adopt(sims, final):
+        for s in sims:
+            run.labels[id(s)] = "climb"
+        res.sims = res.sims + sims
+        if final is not None:
+            # the accepted chain tip is the climb's official output: it
+            # always advances to the paired screen, like the incumbents
+            run.labels[id(final)] = "climb-tip"
+            incumbents.append(final)
+            res.sims = res.sims + [final]
+
+    if fleet_engaged:
+        from tenzing_tpu.search.fleet import (
+            FleetJob,
+            run_fleet,
+            run_serialized,
+        )
+
+        jobs = [
+            FleetJob(index=ci, budget=cbudget, seed=2 + ci,
+                     lanes=len(cplat.lanes), phases=tuple(cphases),
+                     prefer=pname, chosen=chosen)
+            for ci, (cplat, cphases, _cpf, _cpri, cbudget, pname,
+                     chosen) in enumerate(climb_cfg)
         ]
-    if climb_cfg and args.climb_budget > 0:
-        from dataclasses import replace as _replace
-
-        from tenzing_tpu.solve.local import LocalOpts, hill_climb
-
-        # paired=True: accept moves only on a back-to-back paired comparison
-        # with the incumbent — the r4a run showed unpaired first-improvement
-        # climbing chases chip drift (climb "best" 96 ms that the paired
-        # screen ranked below its own seed).  Accepts run at SCREEN fidelity
-        # (r4c: accepts at the cheap 0.01s floor did not replicate under the
-        # screen's 0.1s floor — measurement-regime-dependent overlap), which
-        # costs ~1.6s of measurement per neighbor on top of the ~3s compile.
-        climb_opts = _replace(search_opts, n_iters=8,
-                              target_secs=10 * search_opts.target_secs)
-        if fleet_engaged:
-            from tenzing_tpu.search.fleet import (
-                FleetJob,
-                run_fleet,
-                run_serialized,
-            )
-
-            jobs = [
-                FleetJob(index=ci, budget=cbudget, seed=2 + ci,
-                         lanes=len(cplat.lanes), phases=tuple(cphases),
-                         prefer=pname, chosen=chosen)
-                for ci, (cplat, cphases, _cpf, _cpri, cbudget, pname,
-                         chosen) in enumerate(climb_cfg)
-            ]
-            n_w, k_fuse = max(1, fleet_n), max(1, fleet_k)
-            t0 = time.time()
-            if n_w == 1 and k_fuse == 1:
-                fres = run_serialized(
-                    g, jobs, bench, climb_opts, surrogate=surrogate,
-                    ckpt=ckpt, verifier=verifier, prefetcher=prefetcher)
-            else:
-                fres = run_fleet(
-                    g, args.to_json(), jobs, bench, climb_opts, n_w, k_fuse,
-                    prefetcher=prefetcher, verify=not args.no_verify)
-            distributed_stats = fres.stats
-            for jr in fres.jobs:
-                if jr.failed:
-                    sys.stderr.write(
-                        f"fleet job {jr.index}: FAILED ({jr.failed})\n")
-                    continue
-                for s in jr.sims:
-                    incumbent_labels[id(s)] = "climb"
-                res.sims = res.sims + jr.sims
-                if jr.final is not None:
-                    # the accepted chain tip always advances to the paired
-                    # screen, exactly like the legacy climb loop's
-                    incumbent_labels[id(jr.final)] = "climb-tip"
-                    incumbents.append(jr.final)
-                    res.sims = res.sims + [jr.final]
-            st = distributed_stats
-            sys.stderr.write(
-                f"fleet: {st['workers']}w K={st['measure_batch']}: "
-                f"{st['candidates']} candidates / {st['jobs']} jobs in "
-                f"{st['wall_s']}s ({st['rounds']} fused rounds, occupancy "
-                f"{st['batch_occupancy']}, {st['singles']} singles, "
-                f"{st['reclaimed_subtrees']} reclaimed, scaling "
-                f"{st['scaling_factor']}x, wall {time.time()-t0:.0f}s)\n")
+        n_w, k_fuse = max(1, fleet_n), max(1, fleet_k)
+        t0 = time.time()
+        if n_w == 1 and k_fuse == 1:
+            fres = run_serialized(
+                run.g, jobs, bench, climb_opts, surrogate=run.surrogate,
+                ckpt=stack.checkpoint, verifier=stack.verifier,
+                prefetcher=stack.prefetcher)
         else:
-            for ci, (cplat, cphases, cprefer, cpriority, cbudget, _pname,
-                     _chosen) in enumerate(climb_cfg):
-                t0 = time.time()
-                lres = hill_climb(
-                    g, cplat, bench, cphases, prefer=cprefer,
-                    priority=cpriority,
-                    opts=LocalOpts(budget=cbudget, bench_opts=climb_opts,
-                                   seed=2 + ci, paired=True,
-                                   prescreen=surrogate, checkpoint=ckpt,
-                                   verify=verifier, prefetch=prefetcher),
-                )
-                lbest = lres.best()
+            fres = run_fleet(
+                run.g, args.to_json(), jobs, bench, climb_opts, n_w, k_fuse,
+                prefetcher=stack.prefetcher, verify=not args.no_verify)
+        run.distributed_stats = st = fres.stats
+        for jr in fres.jobs:
+            if jr.failed:
                 sys.stderr.write(
-                    f"hill-climb[{ci}] ({len(cplat.lanes)} lanes): "
-                    f"{len(lres.sims)} candidates, best "
-                    f"pct50={lbest.result.pct50*1e6:.1f}us "
-                    f"(wall {time.time()-t0:.0f}s)\n"
-                )
-                for s in lres.sims:
-                    incumbent_labels[id(s)] = "climb"
-                res.sims = res.sims + lres.sims
-                if lres.final is not None:
-                    # the accepted chain tip is the climb's official output:
-                    # it always advances to the paired screen, like the
-                    # incumbents
-                    incumbent_labels[id(lres.final)] = "climb-tip"
-                    incumbents.append(lres.final)
-                    res.sims = res.sims + [lres.final]
+                    f"fleet job {jr.index}: FAILED ({jr.failed})\n")
+                continue
+            adopt(jr.sims, jr.final)
+        sys.stderr.write(
+            f"fleet: {st['workers']}w K={st['measure_batch']}: "
+            f"{st['candidates']} candidates / {st['jobs']} jobs in "
+            f"{st['wall_s']}s ({st['rounds']} fused rounds, occupancy "
+            f"{st['batch_occupancy']}, {st['singles']} singles, "
+            f"{st['reclaimed_subtrees']} reclaimed, scaling "
+            f"{st['scaling_factor']}x, wall {time.time()-t0:.0f}s)\n")
+        return
+    for ci, (cplat, cphases, cprefer, cpriority, cbudget, _pname,
+             _chosen) in enumerate(climb_cfg):
+        t0 = time.time()
+        lres = hill_climb(
+            run.g, cplat, bench, cphases, prefer=cprefer,
+            priority=cpriority,
+            opts=LocalOpts(budget=cbudget, bench_opts=climb_opts,
+                           seed=2 + ci, paired=True,
+                           prescreen=run.surrogate,
+                           checkpoint=stack.checkpoint,
+                           verify=stack.verifier,
+                           prefetch=stack.prefetcher),
+        )
+        lbest = lres.best()
+        sys.stderr.write(
+            f"hill-climb[{ci}] ({len(cplat.lanes)} lanes): "
+            f"{len(lres.sims)} candidates, best "
+            f"pct50={lbest.result.pct50*1e6:.1f}us "
+            f"(wall {time.time()-t0:.0f}s)\n"
+        )
+        adopt(lres.sims, lres.final)
 
-    # Candidate selection is DRIFT-IMMUNE (VERDICT r2 weak #1: raw search-
-    # phase pct50s picked final candidates while naive drifted 254ms -> 129ms
-    # within one run, and 2 of 4 finalists lost to naive).  Two paired
-    # decorrelated batches (reference batch benchmark, benchmarker.cpp:21-76):
-    #
-    #   screen: naive + the distinct candidates (incumbent grid + top
-    #           searched), moderate cost; paired
-    #           per-iteration speedups rank them, dropping everything whose
-    #           paired median is < 1.0 — search-time drift cancels because
-    #           iteration k visits every schedule back-to-back;
-    #   final:  naive + the top 3 screened, 3x iterations and a 20x adaptive
-    #           measurement floor (the reference's >=10ms floor scaled up,
-    #           benchmarker.cpp:83-119) so single-execution jitter cannot
-    #           widen the bootstrap CI across 1.0 when the margin is real.
-    #
-    # All programs are already compiled (executor cache) — pure measurement.
+
+def _screen_and_final(run: _Run, res, incumbents: list) -> _Pick:
+    """Candidate selection is DRIFT-IMMUNE (VERDICT r2 weak #1: raw search-
+    phase pct50s picked final candidates while naive drifted 254ms -> 129ms
+    within one run, and 2 of 4 finalists lost to naive).  Two paired
+    decorrelated batches (reference batch benchmark, benchmarker.cpp:21-76):
+
+      screen: naive + the distinct candidates (incumbent grid + top
+              searched), moderate cost; paired
+              per-iteration speedups rank them, dropping everything whose
+              paired median is < 1.0 — search-time drift cancels because
+              iteration k visits every schedule back-to-back;
+      final:  naive + the top 3 screened, 3x iterations and a 20x adaptive
+              measurement floor (the reference's >=10ms floor scaled up,
+              benchmarker.cpp:83-119) so single-execution jitter cannot
+              widen the bootstrap CI across 1.0 when the margin is real.
+
+    All programs are already compiled (executor cache) — pure measurement."""
     from dataclasses import replace
+    from itertools import chain, zip_longest
 
     from tenzing_tpu.bench.benchmarker import BenchResult
     from tenzing_tpu.core.sequence import canonical_key
     from tenzing_tpu.utils.numeric import paired_speedup
+
+    args, naive, opts = run.args, run.naive, run.opts
+    resilient, label_of = run.stack.resilient, run.label_of
 
     def batch_paired(seqs, bopts, seed):
         """(results, paired-vs-naive) for [naive] + candidates run as one
         decorrelated batch — through the resilient wrapper, so a transient
         flake mid-verdict retries the batch instead of killing the run."""
         times = resilient.benchmark_batch_times(
-            [naive_seq] + list(seqs), bopts, seed=seed)
+            [run.naive_seq] + list(seqs), bopts, seed=seed)
         results = [BenchResult.from_times(ts) for ts in times]
         paired = [paired_speedup(times[0], ts, seed=seed + 1) for ts in times[1:]]
         return results, paired
-
-    def engine_of(seq) -> str:
-        names = [op.desc() for op in seq.vector()]
-        return "rdma" if any(".rdma" in n for n in names) else "host"
-
-    def label_of(s) -> str:
-        """'greedy-host-8l' for a labeled incumbent, 'climb/<engine>' for a
-        hill-climb candidate, 'mcts/<engine>' for an MCTS rollout — the
-        screen/final printouts must distinguish the entries they compare."""
-        base = incumbent_labels.get(id(s), "mcts")
-        if base in ("mcts", "climb", "climb-tip"):
-            return f"{base}/{engine_of(s.order)}"
-        return base
 
     # distinct candidates by canonical key; heuristic incumbents always
     # advance to screening (search-time noise must not knock them out).
@@ -1700,8 +1192,6 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     # floor the climbs use), but each pool is still sorted within itself and
     # the screen slots interleave the pools: measurements taken minutes
     # apart on a drifting chip are safer ranked per-pool than jointly.
-    from itertools import chain, zip_longest
-
     seen = set()
     cands = []
     inc_ids = {id(s) for s in incumbents}
@@ -1713,7 +1203,7 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
               and getattr(s, "fidelity", "full") == "full"]
     pools = {
         label: sorted(
-            (s for s in others if incumbent_labels.get(id(s), "mcts") == label),
+            (s for s in others if run.labels.get(id(s), "mcts") == label),
             key=lambda s: s.result.pct50,
         )
         for label in ("climb", "mcts")
@@ -1734,10 +1224,6 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     # workloads with few incumbents
     cands = cands[: max(8, len(incumbents) + 4) if not args.smoke else 4]
 
-    vs = 1.0
-    value_us = naive.pct50 * 1e6
-    finals = []
-    top = []
     if resilient.degraded:
         # graceful degradation (docs/robustness.md): the device was lost
         # mid-search and the run finished against cache + surrogate.  The
@@ -1752,15 +1238,18 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     # constructed unconditionally: the regime metadata in the final JSON
     # reads the ACTUAL floors these carry, so tuning a multiplier at one
     # site cannot silently desynchronize the reported metadata
-    screen_opts = replace(opts, target_secs=5 * opts.target_secs)
-    fin_opts = replace(
-        opts, n_iters=3 * opts.n_iters, target_secs=20 * opts.target_secs
-    )
+    pick = _Pick(
+        vs=1.0, value_us=naive.pct50 * 1e6,
+        screen_opts=replace(opts, target_secs=5 * opts.target_secs),
+        fin_opts=replace(
+            opts, n_iters=3 * opts.n_iters, target_secs=20 * opts.target_secs
+        ))
     if cands:
         for attempt in range(2):
             t0 = time.time()
             _, screen = batch_paired(
-                [s.order for s in cands], screen_opts, seed=1 + 10 * attempt
+                [s.order for s in cands], pick.screen_opts,
+                seed=1 + 10 * attempt
             )
             sys.stderr.write(
                 "screen (paired vs naive, wall %.0fs): %s\n"
@@ -1802,11 +1291,12 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
         )
         # only candidates that beat naive under the paired screen advance —
         # the final batch reports no sub-1.0 losers
-        top = [s for s, p in ranked if p[0] > 1.0][:3]
-    if top:
+        pick.top = top = [s for s, p in ranked if p[0] > 1.0][:3]
+    if pick.top:
         t0 = time.time()
-        finals, paired = batch_paired([s.order for s in top], fin_opts, seed=3)
-        fin_naive, fin_cands = finals[0], finals[1:]
+        pick.finals, paired = batch_paired(
+            [s.order for s in top], pick.fin_opts, seed=3)
+        fin_naive, fin_cands = pick.finals[0], pick.finals[1:]
         sys.stderr.write(
             "final batch (wall %.0fs): naive=%.1fus candidates=[%s]us\n"
             % (
@@ -1815,8 +1305,8 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
                 ", ".join("%.1f" % (r.pct50 * 1e6) for r in fin_cands),
             )
         )
-        best_i = max(range(len(paired)), key=lambda i: paired[i][0])
-        m, lo, hi = paired[best_i]
+        pick.best_i = max(range(len(paired)), key=lambda i: paired[i][0])
+        m, lo, hi = paired[pick.best_i]
         sys.stderr.write(
             "paired speedup vs naive: best=%.4f [%.4f, %.4f] 95%% CI "
             "(all: %s)\n"
@@ -1832,27 +1322,27 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
         # median — otherwise sampling noise reports a spurious speedup on
         # roughly half of no-difference runs
         if m > 1.0 and lo > 1.0:
-            value_us = fin_cands[best_i].pct50 * 1e6
-            vs = m
+            pick.value_us = fin_cands[pick.best_i].pct50 * 1e6
+            pick.vs = m
         else:
-            value_us = fin_naive.pct50 * 1e6
-            vs = 1.0
+            pick.value_us = fin_naive.pct50 * 1e6
+            pick.vs = 1.0
+    return pick
 
-    # result-integrity gate (docs/robustness.md, "Schedule soundness"): the
-    # schedule whose number the JSON is about to report re-executes on the
-    # device next to naive, and their outputs must numerically agree — plus
-    # the independent verifier must pass it.  A fast-but-WRONG schedule
-    # (an under-synchronized winner whose race made it fast) can therefore
-    # never be the answer: a failed gate demotes the run to no-win and
-    # stamps ``verified: false`` with the verdict into the fault meta.
-    integrity = None
-    # gate outputs stashed for reuse: the fused phase compares against the
-    # stepped program's outputs, which the gate just computed — re-running
-    # a multi-GB workload's program for the same answer is pure waste
-    gate_outs: Dict[int, Dict[str, Any]] = {}
-    if verifier is not None and not resilient.degraded:
-        winner_seq = (top[best_i].order if top and finals and vs > 1.0
-                      else naive_seq)
+
+def _integrity_gate(run: _Run, pick: _Pick) -> None:
+    """Result-integrity gate (docs/robustness.md, "Schedule soundness"): the
+    schedule whose number the JSON is about to report re-executes on the
+    device next to naive, and their outputs must numerically agree — plus
+    the independent verifier must pass it.  A fast-but-WRONG schedule
+    (an under-synchronized winner whose race made it fast) can therefore
+    never be the answer: a failed gate demotes the run to no-win and
+    stamps ``verified: false`` with the verdict into the fault meta.
+    Sets ``pick.integrity``, ``pick.gate_outs`` and ``pick.reported_seq``."""
+    ex, naive_seq, verifier = run.ex, run.naive_seq, run.stack.verifier
+    if verifier is not None and not run.stack.resilient.degraded:
+        win = pick.winner()
+        winner_seq = win.order if win is not None else naive_seq
         verdict = verifier(winner_seq)
         num_ok = False
         gate_err = None
@@ -1866,15 +1356,13 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
             # transient-classified retry (default retry_on), like every
             # other device interaction: one transient flake must not demote a
             # multi-hour search's legitimate winner to verified: false
-            out_w = _gate_retry(lambda: ex.run(winner_seq),
-                                policy=_GP(retries=2, base_secs=2.0),
-                                where="verify.gate")
-            out_n = (out_w if winner_seq is naive_seq
-                     else _gate_retry(lambda: ex.run(naive_seq),
-                                      policy=_GP(retries=2, base_secs=2.0),
-                                      where="verify.gate"))
-            gate_outs[id(winner_seq)] = out_w
-            gate_outs[id(naive_seq)] = out_n
+            rerun = lambda seq: _gate_retry(
+                lambda: ex.run(seq), policy=_GP(retries=2, base_secs=2.0),
+                where="verify.gate")
+            out_w = rerun(winner_seq)
+            out_n = out_w if winner_seq is naive_seq else rerun(naive_seq)
+            pick.gate_outs[id(winner_seq)] = out_w
+            pick.gate_outs[id(naive_seq)] = out_n
             # compared: every buffer BOTH schedules define.  A staging
             # buffer only one of them writes (naive's host_* under an
             # all-rdma winner, moe's bf16 set) is that menu choice's
@@ -1882,7 +1370,7 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
             # initial zeros, so comparing it fails every winner that
             # picked another engine than naive's
             mismatched = _mismatched_outputs(
-                out_n, out_w, args.verify_tol,
+                out_n, out_w, run.args.verify_tol,
                 names=_written_buffers(naive_seq)
                 & _written_buffers(winner_seq))
             num_ok = not mismatched
@@ -1897,472 +1385,464 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
             gate_err = f"{type(e).__name__}: {str(e)[:200]}"
             sys.stderr.write(
                 f"integrity gate: winner re-execution failed ({gate_err})\n")
-        integrity = {"verified": bool(verdict.ok and num_ok)}
+        pick.integrity = integrity = {"verified": bool(verdict.ok and num_ok)}
         if not verdict.ok:
             integrity["verdict"] = verdict.witness()
         if gate_err is not None:
             integrity["error"] = gate_err
-        if not integrity["verified"] and vs > 1.0:
+        if not integrity["verified"] and pick.vs > 1.0:
             sys.stderr.write(
                 "integrity gate FAILED — demoting the winner to no-win\n")
-            value_us = (finals[0].pct50 if finals else naive.pct50) * 1e6
-            vs = 1.0
+            pick.value_us = (pick.finals[0].pct50 if pick.finals
+                             else run.naive.pct50) * 1e6
+            pick.vs = 1.0
     elif verifier is not None:
         # degraded: no device to re-execute on — the answer is explicitly
         # NOT verified (and already demoted to the pre-loss naive number)
-        integrity = {"verified": False, "error": "degraded: no device"}
+        pick.integrity = {"verified": False, "error": "degraded: no device"}
 
     # the schedule whose number the JSON reports, AFTER any gate demotion —
     # the one object the profiling and fusion phases both operate on
-    reported_seq = (top[best_i].order if top and finals and vs > 1.0
-                    else naive_seq)
+    win = pick.winner()
+    pick.reported_seq = win.order if win is not None else naive_seq
 
-    # attribution profiling (docs/observability.md, "Attribution"): per-op
-    # stepped timing of the schedule whose number the JSON reports, plus
-    # naive for the decision diff — the attrib block is the measurement
-    # substrate the mega-kernel and chunking work will be judged with
-    # (dispatch overhead removed, which ops fail to overlap).
-    attrib_block = None
-    profiled_attrib = None
-    if args.profile_winner and resilient.degraded:
-        sys.stderr.write("profile-winner: skipped (device lost — no "
-                         "hardware to step ops on)\n")
-    elif args.profile_winner:
-        import os as _os
 
-        t0 = time.time()
-        try:
-            from tenzing_tpu.obs import attrib as _attrib
+def winner_report(name: str, enabled, needs_device: Optional[str], body,
+                  degraded: bool = False, skipped=None):
+    """The frame of a post-search provenance report on the reported
+    schedule: its block, or None when it is not ``enabled``.  A report that
+    ``needs_device`` (says what for) is skipped on a degraded run and leaves
+    ``skipped``; ``body(t0)`` fills the block.  Provenance is observability,
+    never a verdict gate: a body that raises (a stepped program that cannot
+    compile, a mesh platform) degrades to an error-carrying block instead of
+    killing a finished search."""
+    if not enabled:
+        return None
+    if needs_device and degraded:
+        sys.stderr.write(f"{name}: skipped (device lost — no hardware to "
+                         f"{needs_device})\n")
+        return skipped
+    try:
+        return body(time.time())
+    except Exception as e:
+        sys.stderr.write(
+            f"{name} failed ({type(e).__name__}: {str(e)[:200]})\n")
+        return {"error": f"{type(e).__name__}: {str(e)[:200]}"}
 
-            winner_seq_p = reported_seq
-            cost = workload_cost(args.workload, built)
-            naive_meas_us = (finals[0].pct50 if finals else naive.pct50) * 1e6
-            w_tl = _attrib.stepped_timeline(ex, winner_seq_p,
-                                            repeats=args.profile_repeats)
-            w_at = _attrib.analyze(winner_seq_p.vector(), w_tl,
-                                   measured_us=value_us, cost=cost,
-                                   peaks=peaks)
-            # stash for the fusion phase: its "before" timeline is this
-            # exact (sequence, repeats, measured_us) analysis — with both
-            # --profile-winner and --fuse-winner set, re-stepping a
-            # multi-GB workload per op twice is minutes of pure waste
-            profiled_attrib = w_at
-            attrib_block = w_at.to_json()
-            expl = None
-            if winner_seq_p is not naive_seq:
-                n_tl = _attrib.stepped_timeline(ex, naive_seq,
-                                                repeats=args.profile_repeats)
-                n_at = _attrib.analyze(naive_seq.vector(), n_tl,
-                                       measured_us=naive_meas_us, cost=cost,
-                                       peaks=peaks)
-                expl = _attrib.explain(naive_seq.vector(),
-                                       winner_seq_p.vector(),
-                                       naive_attrib=n_at,
-                                       winner_attrib=w_at)
-                attrib_block["explain"] = expl.get("timing", {})
-            # the winner's raw measurement series rides along for the
-            # report CLI's noise-aware regression check (obs/report.py)
-            fin_res = (finals[1 + best_i] if top and finals and vs > 1.0
-                       else (finals[0] if finals else naive))
-            if fin_res.times:
-                attrib_block["measured_times"] = [
-                    round(t, 9) for t in fin_res.times]
-            if args.trace_out:
-                _os.makedirs(args.trace_out, exist_ok=True)
-                doc = dict(expl) if expl is not None else {}
-                doc["attrib"] = attrib_block
-                _attrib.write_explain(
-                    _os.path.join(args.trace_out, "explain.json"), doc)
-                rank = obs.get_tracer().rank
-                # anchor the Gantt at the current unix-us instant so the
-                # per-lane tracks render next to the span timeline (span
-                # timestamps are unix-anchored, obs/tracer.py)
-                t0_us = time.time() * 1e6
-                attrib_extra.extend(_attrib.timeline_trace_events(
-                    w_at, pid=rank, t0_us=t0_us, label="attrib/winner"))
-                if expl is not None:
-                    attrib_extra.extend(_attrib.timeline_trace_events(
-                        n_at, pid=rank, t0_us=t0_us, label="attrib/naive",
-                        tid_base=2000))
-                sys.stderr.write(
-                    f"explain: {_os.path.join(args.trace_out, 'explain.json')}\n")
-            eff = attrib_block.get("overlap_efficiency")
-            sys.stderr.write(
-                "profile-winner: %d ops stepped, sum-of-parts %.1fus, "
-                "critical path %.1fus, dispatch overhead %.1fus, overlap "
-                "efficiency %s (wall %.0fs)\n"
-                % (attrib_block["n_timed"],
-                   attrib_block["sum_of_parts_us"],
-                   attrib_block["critical_path_us"],
-                   attrib_block["dispatch_overhead_us"],
-                   f"{eff:.3f}" if eff is not None else "n/a",
-                   time.time() - t0))
-        except Exception as e:
-            # profiling is observability, never a verdict gate: a stepped
-            # program that cannot compile (or a mesh platform) degrades to
-            # an error-carrying block instead of killing a finished search
-            sys.stderr.write(
-                f"profile-winner failed ({type(e).__name__}: "
-                f"{str(e)[:200]})\n")
-            attrib_block = {"error": f"{type(e).__name__}: {str(e)[:200]}"}
 
-    # megakernel fusion (docs/performance.md, "Megakernel fusion"): lower
-    # the reported schedule into fused Pallas regions (runtime/fused.py),
-    # sweep the roofline-pruned tile menu, gate the best fused program
-    # through the result-integrity machinery (allclose vs the stepped
-    # program + re-verified), and stamp the ``perf.fused`` provenance
-    # block with the dispatch overhead before/after (obs/attrib) — the
-    # measured answer to "what did fusing the dispatches buy".
-    fused_block = None
-    if args.fuse_winner and resilient.degraded:
-        sys.stderr.write("fuse-winner: skipped (device lost — no hardware "
-                         "to run fused programs on)\n")
-        fused_block = {"error": "degraded: no device"}
-    elif args.fuse_winner:
-        t0 = time.time()
-        try:
-            from tenzing_tpu.obs import attrib as _attrib
-            from tenzing_tpu.runtime.fused import FusedExecutor, fused_summary
+def stepped_analysis(run: _Run, seq, measured_us, cost=None):
+    """obs/attrib's analysis of ``seq`` stepped op by op on the run's
+    executor; ``cost`` joins the roofline's fractions of peak."""
+    from tenzing_tpu.obs import attrib as _attrib
 
-            winner_seq_f = reported_seq
-            cost = workload_cost(args.workload, built)
-            # "before": the unfused program's dispatch overhead — per-op
-            # stepped sum-of-parts minus the reported whole-program pct50.
-            # --profile-winner already produced this exact analysis of the
-            # same sequence/repeats/measured_us: reuse it instead of
-            # re-stepping every op
-            if profiled_attrib is not None:
-                at_b = profiled_attrib
-            else:
-                tl_b = _attrib.stepped_timeline(ex, winner_seq_f,
-                                                repeats=args.profile_repeats)
-                at_b = _attrib.analyze(winner_seq_f.vector(), tl_b,
-                                       measured_us=value_us, cost=cost,
-                                       peaks=peaks)
-            # compile tallies snapshot AFTER the stepped timeline: the
-            # per-op sub-program compiles above are attribution cost, not
-            # fusion cost — the stamped delta covers plan + tile variants
-            # + the gate's executions only
-            compile0, csecs0 = ex.compile_count, ex.compile_secs
-            plan0 = FusedExecutor(ex).plan(winner_seq_f)
-            menu = plan0.tile_menu
-            by_tiles: Dict[str, float] = {}
-            best_t, best_us, best_fex = 1, None, None
-            for t in menu:
-                # fresh benchmarker per variant: the shared CachingBenchmarker
-                # keys by canonical schedule, which would collide the fused
-                # variants with the stepped measurement of the same order
-                fex_t = FusedExecutor(ex, tiles=t)
-                res_t = EmpiricalBenchmarker(fex_t).benchmark(
-                    winner_seq_f, opts)
-                us = res_t.pct50 * 1e6
-                by_tiles[str(t)] = round(us, 2)
-                if best_us is None or us < best_us:
-                    best_t, best_us, best_fex = t, us, fex_t
-            plan = best_fex.plan(winner_seq_f)
-            # result-integrity gate on the fused outputs: allclose vs the
-            # stepped program, and the schedule re-verified (PR 4 gate)
-            out_f = best_fex.run(winner_seq_f)
-            # the PR-4 gate already executed this exact sequence — reuse
-            # its outputs instead of re-running a potentially multi-GB
-            # program (gate skipped/failed -> fresh execution)
-            out_s = gate_outs.get(id(winner_seq_f))
-            if out_s is None:
-                out_s = ex.run(winner_seq_f)
-            mismatched = _mismatched_outputs(out_s, out_f, args.verify_tol)
-            num_ok = not mismatched
-            re_verdict = verifier(winner_seq_f) if verifier is not None \
-                else None
-            fused_verified = bool(
-                num_ok and (re_verdict.ok if re_verdict is not None
-                            else True))
-            # "after": the FUSED program's remaining dispatch overhead —
-            # one stepped unit per region instead of per op
-            fseq = best_fex.fused_order(winner_seq_f)
-            tl_a = _attrib.stepped_timeline(ex, fseq,
-                                            repeats=args.profile_repeats)
-            at_a = _attrib.analyze(fseq.vector(), tl_a,
-                                   measured_us=best_us, cost=cost,
-                                   peaks=peaks)
-            fused_block = {
-                "regions": len(plan.regions),
-                "region_sizes": [r.n_ops for r in plan.regions],
-                "fused_ops": plan.n_ops_fused,
-                "n_ops_total": plan.n_ops_total,
-                "tiles": {"chosen": best_t, "menu": menu,
-                          "per_region": [r.tiles for r in plan.regions],
-                          "by_tiles_us": by_tiles},
-                "measured_us": {"stepped": round(value_us, 2),
-                                "fused": round(best_us, 2)},
-                "compile_secs": round(ex.compile_secs - csecs0, 3),
-                "compiled_programs": ex.compile_count - compile0,
-                "verified": fused_verified,
-                "dispatch_overhead_us": {
-                    "before": round(at_b.dispatch_overhead_us, 3),
-                    "after": round(at_a.dispatch_overhead_us, 3)},
-                "sum_of_parts_us": {
-                    "before": round(at_b.sum_of_parts_us, 3),
-                    "after": round(at_a.sum_of_parts_us, 3)},
-            }
-            if mismatched:
-                fused_block["error"] = \
-                    f"fused outputs diverge on {mismatched[:4]}"
-            if re_verdict is not None and not re_verdict.ok:
-                fused_block["verdict"] = re_verdict.witness()
-            sys.stderr.write(
-                "fuse-winner: %s; tiles %s -> best t=%d %.1fus (stepped "
-                "%.1fus); dispatch overhead %.1f -> %.1fus; %s (wall "
-                "%.0fs)\n" % (
-                    fused_summary(plan), by_tiles, best_t, best_us,
-                    value_us,
-                    fused_block["dispatch_overhead_us"]["before"],
-                    fused_block["dispatch_overhead_us"]["after"],
-                    "verified" if fused_verified else "GATE FAILED",
-                    time.time() - t0))
-        except Exception as e:
-            # like profiling, fusion provenance must never kill a finished
-            # search — an error-carrying block instead
-            sys.stderr.write(
-                f"fuse-winner failed ({type(e).__name__}: "
-                f"{str(e)[:200]})\n")
-            fused_block = {"error": f"{type(e).__name__}: {str(e)[:200]}"}
+    tl = _attrib.stepped_timeline(run.ex, seq,
+                                  repeats=run.args.profile_repeats)
+    return _attrib.analyze(seq.vector(), tl, measured_us=measured_us,
+                           cost=cost, peaks=run.peaks)
 
-    # op-chunking provenance (ISSUE 10, docs/performance.md "Chunked
-    # overlap"): the roofline-pruned chunk menus the models offered, what
-    # the search visited and chose, and the hidden comm the chunking bought
-    # — estimated (the roofline upper bound carried on the menu) vs
-    # measured (transfer-unit overlap with the chunk partials on the
-    # obs/attrib stepped timeline).  Like profiling/fusion, provenance
-    # only: a failure degrades to an error-carrying block.
-    chunked_block = None
-    if args.chunk:
-        try:
-            from tenzing_tpu.core.chunking import chunk_menus, chunks_of
 
-            menus = chunk_menus(g)
-            chosen = chunks_of(reported_seq)
-            searched_counts: set = set()
-            n_cand_chunked = 0
-            for s in res.sims:
-                cm = chunks_of(s.order)
-                if cm:
-                    n_cand_chunked += 1
-                    searched_counts.update(cm.values())
-            est_total = 0.0
-            for base, n in chosen.items():
-                m = menus.get(base)
-                if m:
-                    est_total += float(
-                        m.get("est_hidden_us", {}).get(n, 0.0))
-            chunked_block = {
-                "menus": {
-                    b: {"counts": list(m["counts"]),
-                        "est_hidden_us": {
-                            str(k): round(float(v), 2)
-                            for k, v in m.get("est_hidden_us", {}).items()}}
-                    for b, m in sorted(menus.items())},
-                "searched_counts": sorted(int(c) for c in searched_counts),
-                "n_candidates_chunked": n_cand_chunked,
-                "chosen": {b: int(n) for b, n in sorted(chosen.items())},
-                "hidden_comm_us": {"estimated": round(est_total, 2),
-                                   "measured": None},
-            }
-            if menus and all(
-                    not [c for c in m["counts"] if c > 1]
-                    for m in menus.values()):
-                chunked_block["note"] = (
-                    "roofline pruned every chunking: no transfer whose "
-                    "hidden-comm bound beats the dispatch+combine cost on "
-                    "this workload/hardware (bench/roofline.py::"
-                    "prune_chunkings)")
-            elif not menus:
-                chunked_block["note"] = (
-                    "workload offers no chunkable-op menus (--chunk is a "
-                    "no-op for it)")
-            if chosen and not resilient.degraded:
-                from tenzing_tpu.core.chunking import hidden_comm_measured_us
-                from tenzing_tpu.obs import attrib as _attrib
+def _reported_analysis(run: _Run, pick: _Pick, cost=None):
+    """The reported schedule's analysis: ``--profile-winner``'s when it ran
+    (this exact sequence, repeats and measured_us), else stepped now."""
+    if run.profiled_attrib is not None:
+        return run.profiled_attrib
+    return stepped_analysis(run, pick.reported_seq, pick.value_us, cost)
 
-                t0 = time.time()
-                if profiled_attrib is not None:
-                    at_c = profiled_attrib
-                else:
-                    tl_c = _attrib.stepped_timeline(
-                        ex, reported_seq, repeats=args.profile_repeats)
-                    at_c = _attrib.analyze(reported_seq.vector(), tl_c,
-                                           measured_us=value_us)
-                measured = hidden_comm_measured_us(reported_seq.vector(),
-                                                   at_c)
-                chunked_block["hidden_comm_us"]["measured"] = round(
-                    measured, 2)
-                sys.stderr.write(
-                    "chunked: winner uses %s; hidden comm est %.1fus / "
-                    "measured %.1fus (wall %.0fs)\n"
-                    % (chunked_block["chosen"], est_total, measured,
-                       time.time() - t0))
-            else:
-                sys.stderr.write(
-                    "chunked: %d menu(s), %d chunked candidate(s) "
-                    "searched, winner unchunked\n"
-                    % (len(menus), n_cand_chunked))
-        except Exception as e:
-            sys.stderr.write(
-                f"chunked provenance failed ({type(e).__name__}: "
-                f"{str(e)[:200]})\n")
-            chunked_block = {
-                "error": f"{type(e).__name__}: {str(e)[:200]}"}
 
-    # synthesized-collective provenance (ISSUE 17, docs/performance.md
-    # "Synthesized collectives"): the priced-and-pruned sketch menus each
-    # exchange site offered, what the search visited and chose, analytic
-    # est vs measured hidden comm of the chosen decomposition, and the
-    # result-integrity verdict on the reported projection.  Provenance
-    # only: a failure degrades to an error-carrying block.
-    synth_block = None
-    if args.synth_collectives:
-        try:
-            from tenzing_tpu.collectives.synth import (
-                synth_hidden_comm_measured_us,
-                synth_menus,
-                synths_of,
-            )
+def _winner_reports(run: _Run, res, pick: _Pick) -> Dict[str, Any]:
+    """The four post-search provenance reports, each through
+    :func:`winner_report`: the verdict's blocks by where they are stamped."""
+    args, degraded = run.args, run.stack.resilient.degraded
+    return {
+        "attrib": winner_report(
+            "profile-winner", args.profile_winner, "step ops on",
+            lambda t0: _profile_winner(run, pick, t0), degraded),
+        "fused": winner_report(
+            "fuse-winner", args.fuse_winner, "run fused programs on",
+            lambda t0: _fuse_winner(run, pick, t0), degraded,
+            skipped={"error": "degraded: no device"}),
+        "chunked": winner_report(
+            "chunked provenance", args.chunk, None,
+            lambda t0: _chunk_report(run, res, pick, t0)),
+        "synth": winner_report(
+            "synth provenance", args.synth_collectives, None,
+            lambda t0: _synth_report(run, res, pick, t0)),
+    }
 
-            smenus = synth_menus(g)
-            schosen = synths_of(reported_seq)
-            searched_sketches: set = set()
-            n_cand_synth = 0
-            for s in res.sims:
-                sm = synths_of(s.order)
-                if sm:
-                    n_cand_synth += 1
-                    searched_sketches.update(
-                        f"{v['sketch']}.c{v['chunks']}" for v in sm.values())
-            sest_total = 0.0
-            for base, v in schosen.items():
-                m = smenus.get(base)
-                if m:
-                    sest_total += float(m.get("est_us", {}).get(
-                        f"{v['sketch']}.c{v['chunks']}", 0.0))
-            synth_block = {
-                "menus": {
-                    b: {"menu": list(m["menu"]),
-                        "est_us": {k: round(float(v2), 3)
-                                   for k, v2 in m.get("est_us", {}).items()},
-                        "pruned": dict(m.get("pruned", {})),
-                        "note": m.get("note", "")}
-                    for b, m in sorted(smenus.items())},
-                "searched_sketches": sorted(searched_sketches),
-                "n_candidates_synth": n_cand_synth,
-                "chosen": {b: f"{v['sketch']}.c{v['chunks']}"
-                           for b, v in sorted(schosen.items())},
-                "est_comm_us": round(sest_total, 3),
-                "measured_hidden_us": None,
-                "verified": bool(integrity and integrity.get("verified")),
-            }
-            if not smenus:
-                synth_block["note"] = (
-                    "workload offers no synthesized-collective menus "
-                    "(--synth-collectives is a no-op for it)")
-            elif all(len(m.get("menu", [])) <= 1 for m in smenus.values()):
-                synth_block["note"] = (
-                    "roofline pruned every sketch instantiation: no "
-                    "decomposition whose alpha-beta estimate beats the "
-                    "fixed engine's one-post floor on this "
-                    "workload/hardware (bench/roofline.py::prune_sketches)")
-            else:
-                synth_block["note"] = "; ".join(
-                    f"{b}: {m.get('note', '')}"
-                    for b, m in sorted(smenus.items()))
-            if schosen and not resilient.degraded:
-                from tenzing_tpu.obs import attrib as _attrib
 
-                t0 = time.time()
-                if profiled_attrib is not None:
-                    at_s = profiled_attrib
-                else:
-                    tl_s = _attrib.stepped_timeline(
-                        ex, reported_seq, repeats=args.profile_repeats)
-                    at_s = _attrib.analyze(reported_seq.vector(), tl_s,
-                                           measured_us=value_us)
-                smeasured = synth_hidden_comm_measured_us(
-                    reported_seq.vector(), at_s)
-                synth_block["measured_hidden_us"] = round(smeasured, 2)
-                sys.stderr.write(
-                    "synth: winner uses %s; est comm %.1fus / hidden "
-                    "measured %.1fus (wall %.0fs)\n"
-                    % (synth_block["chosen"], sest_total, smeasured,
-                       time.time() - t0))
-            else:
-                sys.stderr.write(
-                    "synth: %d menu(s), %d synthesized candidate(s) "
-                    "searched, winner fixed-engine\n"
-                    % (len(smenus), n_cand_synth))
-        except Exception as e:
-            sys.stderr.write(
-                f"synth provenance failed ({type(e).__name__}: "
-                f"{str(e)[:200]})\n")
-            synth_block = {
-                "error": f"{type(e).__name__}: {str(e)[:200]}"}
+def _profile_winner(run: _Run, pick: _Pick, t0) -> Dict[str, Any]:
+    """Attribution profiling (docs/observability.md, "Attribution"): per-op
+    stepped timing of the schedule whose number the JSON reports, plus
+    naive for the decision diff — the attrib block is the measurement
+    substrate the mega-kernel and chunking work will be judged with
+    (dispatch overhead removed, which ops fail to overlap)."""
+    import os as _os
 
-    if args.dump_csv:
-        # One row per distinct schedule.  The decorrelated final-batch results
-        # *supersede* the search-time measurements for naive and the finalists
-        # (CsvBenchmarker returns the first equivalence match, so appending
-        # duplicate rows would leave the finals unreachable) — the headline
-        # verdict is replayable from the recorded database.
-        results = [naive] + [s.result for s in res.sims]
-        if finals:
-            results[0] = finals[0]
-            for r, s in zip(finals[1:], top):
-                # identity, not ==: sync ops compare kind-only, so two distinct
-                # schedules can be ==-equal and .index() would mis-attribute
-                idx = next(i for i, s2 in enumerate(res.sims) if s2 is s)
-                results[1 + idx] = r
-        orders = [naive_seq] + [s.order for s in res.sims]
-        # fidelity tags keep the DB honest: MCTS screen rows were measured at
-        # a ~1 ms floor and must not be ranked against full-floor rows by the
-        # warm-start loader (bench/recorded.py skips non-"full" rows)
-        fids = ["full"] + [getattr(s, "fidelity", "full") for s in res.sims]
-        if finals:
-            for s in top:
-                idx = next(i for i, s2 in enumerate(res.sims) if s2 is s)
-                fids[1 + idx] = "full"  # superseded by the final batch
-        # rows the learned screen answered from the MODEL carry no device
-        # measurement at all — tag them fid=model (inert to every reader,
-        # like screen rows) so the archive never passes predictions off as
-        # measurements
-        if surrogate is not None:
-            for i, s in enumerate(res.sims):
-                if fids[1 + i] == "screen" and search_bench.was_predicted(
-                        s.order):
-                    fids[1 + i] = "model"
-        # rows answered after device loss carry degraded provenance — like
-        # fid=model they are inert to every reader (CsvBenchmarker admits
-        # only "full" rows, recorded.py skips non-"full"), so a degraded
-        # run's archive can never pass predictions off as measurements
-        if resilient.degraded:
-            for i, s in enumerate(res.sims):
-                if resilient.was_degraded(s.order):
-                    fids[1 + i] = "degraded"
-        # screen rows cannot shadow full-fidelity twins on replay:
-        # CsvBenchmarker only admits "full" rows into its equivalence cache
-        rows = [
-            result_row(i, r, o, fidelity=None if f == "full" else f)
-            for i, (r, o, f) in enumerate(zip(results, orders, fids))
-        ]
-        # THE dump invariant every downstream reader trusts (recorded.py
-        # naive_anchor_of, learn/dataset.py): row 0 is the naive schedule at
-        # FINAL fidelity — checked at dump time (a real exception, not an
-        # assert: it must hold under python -O too) so a future reshuffle of
-        # the results list cannot silently poison every in-file ratio
-        # computed against this file's anchor
-        if orders[0] is not naive_seq or fids[0] != "full":
-            raise RuntimeError(
-                "dump-csv invariant violated: row 0 must be the naive "
-                "schedule at full fidelity")
-        with open(args.dump_csv, "w") as f:
-            f.write("\n".join(rows) + "\n")
-        sys.stderr.write(f"csv: {args.dump_csv} ({len(rows)} rows)\n")
+    from tenzing_tpu import obs
+    from tenzing_tpu.obs import attrib as _attrib
+
+    args, naive_seq, finals = run.args, run.naive_seq, pick.finals
+    winner_seq_p = pick.reported_seq
+    cost = workload_cost(args.workload, run.built)
+    naive_meas_us = (finals[0].pct50 if finals else run.naive.pct50) * 1e6
+    # stashed for the reports after this one (_reported_analysis)
+    run.profiled_attrib = w_at = stepped_analysis(
+        run, winner_seq_p, pick.value_us, cost)
+    attrib_block = w_at.to_json()
+    expl = None
+    if winner_seq_p is not naive_seq:
+        n_at = stepped_analysis(run, naive_seq, naive_meas_us, cost)
+        expl = _attrib.explain(naive_seq.vector(),
+                               winner_seq_p.vector(),
+                               naive_attrib=n_at,
+                               winner_attrib=w_at)
+        attrib_block["explain"] = expl.get("timing", {})
+    # the winner's raw measurement series rides along for the
+    # report CLI's noise-aware regression check (obs/report.py)
+    fin_res = (finals[1 + pick.best_i] if pick.winner() is not None
+               else (finals[0] if finals else run.naive))
+    if fin_res.times:
+        attrib_block["measured_times"] = [round(t, 9) for t in fin_res.times]
+    if args.trace_out:
+        _os.makedirs(args.trace_out, exist_ok=True)
+        doc = dict(expl) if expl is not None else {}
+        doc["attrib"] = attrib_block
+        _attrib.write_explain(
+            _os.path.join(args.trace_out, "explain.json"), doc)
+        rank = obs.get_tracer().rank
+        # anchor the Gantt at the current unix-us instant so the
+        # per-lane tracks render next to the span timeline (span
+        # timestamps are unix-anchored, obs/tracer.py)
+        t0_us = time.time() * 1e6
+        run.attrib_extra.extend(_attrib.timeline_trace_events(
+            w_at, pid=rank, t0_us=t0_us, label="attrib/winner"))
+        if expl is not None:
+            run.attrib_extra.extend(_attrib.timeline_trace_events(
+                n_at, pid=rank, t0_us=t0_us, label="attrib/naive",
+                tid_base=2000))
+        sys.stderr.write(
+            f"explain: {_os.path.join(args.trace_out, 'explain.json')}\n")
+    eff = attrib_block.get("overlap_efficiency")
+    sys.stderr.write(
+        "profile-winner: %d ops stepped, sum-of-parts %.1fus, "
+        "critical path %.1fus, dispatch overhead %.1fus, overlap "
+        "efficiency %s (wall %.0fs)\n"
+        % (attrib_block["n_timed"],
+           attrib_block["sum_of_parts_us"],
+           attrib_block["critical_path_us"],
+           attrib_block["dispatch_overhead_us"],
+           f"{eff:.3f}" if eff is not None else "n/a",
+           time.time() - t0))
+    return attrib_block
+
+
+def _fuse_winner(run: _Run, pick: _Pick, t0) -> Dict[str, Any]:
+    """Megakernel fusion (docs/performance.md, "Megakernel fusion"): lower
+    the reported schedule into fused Pallas regions (runtime/fused.py),
+    sweep the roofline-pruned tile menu, gate the best fused program
+    through the result-integrity machinery (allclose vs the stepped
+    program + re-verified), and stamp the ``perf.fused`` provenance
+    block with the dispatch overhead before/after (obs/attrib) — the
+    measured answer to "what did fusing the dispatches buy"."""
+    from tenzing_tpu.bench.benchmarker import EmpiricalBenchmarker
+    from tenzing_tpu.runtime.fused import FusedExecutor, fused_summary
+
+    ex, verifier, value_us = run.ex, run.stack.verifier, pick.value_us
+    winner_seq_f = pick.reported_seq
+    cost = workload_cost(run.args.workload, run.built)
+    # "before": the unfused program's dispatch overhead — per-op
+    # stepped sum-of-parts minus the reported whole-program pct50
+    at_b = _reported_analysis(run, pick, cost)
+    # compile tallies snapshot AFTER the stepped timeline: the
+    # per-op sub-program compiles above are attribution cost, not
+    # fusion cost — the stamped delta covers plan + tile variants
+    # + the gate's executions only
+    compile0, csecs0 = ex.compile_count, ex.compile_secs
+    plan0 = FusedExecutor(ex).plan(winner_seq_f)
+    menu = plan0.tile_menu
+    by_tiles: Dict[str, float] = {}
+    best_t, best_us, best_fex = 1, None, None
+    for t in menu:
+        # fresh benchmarker per variant: the shared CachingBenchmarker
+        # keys by canonical schedule, which would collide the fused
+        # variants with the stepped measurement of the same order
+        fex_t = FusedExecutor(ex, tiles=t)
+        res_t = EmpiricalBenchmarker(fex_t).benchmark(winner_seq_f, run.opts)
+        us = res_t.pct50 * 1e6
+        by_tiles[str(t)] = round(us, 2)
+        if best_us is None or us < best_us:
+            best_t, best_us, best_fex = t, us, fex_t
+    plan = best_fex.plan(winner_seq_f)
+    # result-integrity gate on the fused outputs: allclose vs the
+    # stepped program, and the schedule re-verified (PR 4 gate)
+    out_f = best_fex.run(winner_seq_f)
+    # the PR-4 gate already executed this exact sequence — reuse
+    # its outputs instead of re-running a potentially multi-GB
+    # program (gate skipped/failed -> fresh execution)
+    out_s = pick.gate_outs.get(id(winner_seq_f))
+    if out_s is None:
+        out_s = ex.run(winner_seq_f)
+    mismatched = _mismatched_outputs(out_s, out_f, run.args.verify_tol)
+    num_ok = not mismatched
+    re_verdict = verifier(winner_seq_f) if verifier is not None \
+        else None
+    fused_verified = bool(
+        num_ok and (re_verdict.ok if re_verdict is not None
+                    else True))
+    # "after": the FUSED program's remaining dispatch overhead —
+    # one stepped unit per region instead of per op
+    at_a = stepped_analysis(run, best_fex.fused_order(winner_seq_f),
+                            best_us, cost)
+    fused_block = {
+        "regions": len(plan.regions),
+        "region_sizes": [r.n_ops for r in plan.regions],
+        "fused_ops": plan.n_ops_fused,
+        "n_ops_total": plan.n_ops_total,
+        "tiles": {"chosen": best_t, "menu": menu,
+                  "per_region": [r.tiles for r in plan.regions],
+                  "by_tiles_us": by_tiles},
+        "measured_us": {"stepped": round(value_us, 2),
+                        "fused": round(best_us, 2)},
+        "compile_secs": round(ex.compile_secs - csecs0, 3),
+        "compiled_programs": ex.compile_count - compile0,
+        "verified": fused_verified,
+        "dispatch_overhead_us": {
+            "before": round(at_b.dispatch_overhead_us, 3),
+            "after": round(at_a.dispatch_overhead_us, 3)},
+        "sum_of_parts_us": {
+            "before": round(at_b.sum_of_parts_us, 3),
+            "after": round(at_a.sum_of_parts_us, 3)},
+    }
+    if mismatched:
+        fused_block["error"] = \
+            f"fused outputs diverge on {mismatched[:4]}"
+    if re_verdict is not None and not re_verdict.ok:
+        fused_block["verdict"] = re_verdict.witness()
+    sys.stderr.write(
+        "fuse-winner: %s; tiles %s -> best t=%d %.1fus (stepped "
+        "%.1fus); dispatch overhead %.1f -> %.1fus; %s (wall "
+        "%.0fs)\n" % (
+            fused_summary(plan), by_tiles, best_t, best_us,
+            value_us,
+            fused_block["dispatch_overhead_us"]["before"],
+            fused_block["dispatch_overhead_us"]["after"],
+            "verified" if fused_verified else "GATE FAILED",
+            time.time() - t0))
+    return fused_block
+
+
+def _chunk_report(run: _Run, res, pick: _Pick, t0) -> Dict[str, Any]:
+    """Op-chunking provenance (ISSUE 10, docs/performance.md "Chunked
+    overlap"): the roofline-pruned chunk menus the models offered, what
+    the search visited and chose, and the hidden comm the chunking bought
+    — estimated (the roofline upper bound carried on the menu) vs
+    measured (transfer-unit overlap with the chunk partials on the
+    obs/attrib stepped timeline)."""
+    from tenzing_tpu.core.chunking import chunk_menus, chunks_of
+
+    reported_seq = pick.reported_seq
+    menus = chunk_menus(run.g)
+    chosen = chunks_of(reported_seq)
+    searched_counts: set = set()
+    n_cand_chunked = 0
+    for s in res.sims:
+        cm = chunks_of(s.order)
+        if cm:
+            n_cand_chunked += 1
+            searched_counts.update(cm.values())
+    est_total = 0.0
+    for base, n in chosen.items():
+        m = menus.get(base)
+        if m:
+            est_total += float(m.get("est_hidden_us", {}).get(n, 0.0))
+    chunked_block = {
+        "menus": {
+            b: {"counts": list(m["counts"]),
+                "est_hidden_us": {
+                    str(k): round(float(v), 2)
+                    for k, v in m.get("est_hidden_us", {}).items()}}
+            for b, m in sorted(menus.items())},
+        "searched_counts": sorted(int(c) for c in searched_counts),
+        "n_candidates_chunked": n_cand_chunked,
+        "chosen": {b: int(n) for b, n in sorted(chosen.items())},
+        "hidden_comm_us": {"estimated": round(est_total, 2),
+                           "measured": None},
+    }
+    if menus and all(
+            not [c for c in m["counts"] if c > 1]
+            for m in menus.values()):
+        chunked_block["note"] = (
+            "roofline pruned every chunking: no transfer whose "
+            "hidden-comm bound beats the dispatch+combine cost on "
+            "this workload/hardware (bench/roofline.py::"
+            "prune_chunkings)")
+    elif not menus:
+        chunked_block["note"] = (
+            "workload offers no chunkable-op menus (--chunk is a "
+            "no-op for it)")
+    if chosen and not run.stack.resilient.degraded:
+        from tenzing_tpu.core.chunking import hidden_comm_measured_us
+
+        measured = hidden_comm_measured_us(
+            reported_seq.vector(), _reported_analysis(run, pick))
+        chunked_block["hidden_comm_us"]["measured"] = round(measured, 2)
+        sys.stderr.write(
+            "chunked: winner uses %s; hidden comm est %.1fus / "
+            "measured %.1fus (wall %.0fs)\n"
+            % (chunked_block["chosen"], est_total, measured,
+               time.time() - t0))
+    else:
+        sys.stderr.write(
+            "chunked: %d menu(s), %d chunked candidate(s) "
+            "searched, winner unchunked\n"
+            % (len(menus), n_cand_chunked))
+    return chunked_block
+
+
+def _synth_report(run: _Run, res, pick: _Pick, t0) -> Dict[str, Any]:
+    """Synthesized-collective provenance (ISSUE 17, docs/performance.md
+    "Synthesized collectives"): the priced-and-pruned sketch menus each
+    exchange site offered, what the search visited and chose, analytic
+    est vs measured hidden comm of the chosen decomposition, and the
+    result-integrity verdict on the reported projection."""
+    from tenzing_tpu.collectives.synth import (
+        synth_hidden_comm_measured_us,
+        synth_menus,
+        synths_of,
+    )
+
+    reported_seq, integrity = pick.reported_seq, pick.integrity
+    smenus = synth_menus(run.g)
+    schosen = synths_of(reported_seq)
+    searched_sketches: set = set()
+    n_cand_synth = 0
+    for s in res.sims:
+        sm = synths_of(s.order)
+        if sm:
+            n_cand_synth += 1
+            searched_sketches.update(
+                f"{v['sketch']}.c{v['chunks']}" for v in sm.values())
+    sest_total = 0.0
+    for base, v in schosen.items():
+        m = smenus.get(base)
+        if m:
+            sest_total += float(m.get("est_us", {}).get(
+                f"{v['sketch']}.c{v['chunks']}", 0.0))
+    synth_block = {
+        "menus": {
+            b: {"menu": list(m["menu"]),
+                "est_us": {k: round(float(v2), 3)
+                           for k, v2 in m.get("est_us", {}).items()},
+                "pruned": dict(m.get("pruned", {})),
+                "note": m.get("note", "")}
+            for b, m in sorted(smenus.items())},
+        "searched_sketches": sorted(searched_sketches),
+        "n_candidates_synth": n_cand_synth,
+        "chosen": {b: f"{v['sketch']}.c{v['chunks']}"
+                   for b, v in sorted(schosen.items())},
+        "est_comm_us": round(sest_total, 3),
+        "measured_hidden_us": None,
+        "verified": bool(integrity and integrity.get("verified")),
+    }
+    if not smenus:
+        synth_block["note"] = (
+            "workload offers no synthesized-collective menus "
+            "(--synth-collectives is a no-op for it)")
+    elif all(len(m.get("menu", [])) <= 1 for m in smenus.values()):
+        synth_block["note"] = (
+            "roofline pruned every sketch instantiation: no "
+            "decomposition whose alpha-beta estimate beats the "
+            "fixed engine's one-post floor on this "
+            "workload/hardware (bench/roofline.py::prune_sketches)")
+    else:
+        synth_block["note"] = "; ".join(
+            f"{b}: {m.get('note', '')}"
+            for b, m in sorted(smenus.items()))
+    if schosen and not run.stack.resilient.degraded:
+        smeasured = synth_hidden_comm_measured_us(
+            reported_seq.vector(), _reported_analysis(run, pick))
+        synth_block["measured_hidden_us"] = round(smeasured, 2)
+        sys.stderr.write(
+            "synth: winner uses %s; est comm %.1fus / hidden "
+            "measured %.1fus (wall %.0fs)\n"
+            % (synth_block["chosen"], sest_total, smeasured,
+               time.time() - t0))
+    else:
+        sys.stderr.write(
+            "synth: %d menu(s), %d synthesized candidate(s) "
+            "searched, winner fixed-engine\n"
+            % (len(smenus), n_cand_synth))
+    return synth_block
+
+
+def _dump_csv(run: _Run, res, pick: _Pick) -> None:
+    """``--dump-csv``: one row per distinct schedule.  The decorrelated
+    final-batch results *supersede* the search-time measurements for naive
+    and the finalists (CsvBenchmarker returns the first equivalence match,
+    so appending duplicate rows would leave the finals unreachable) — the
+    headline verdict is replayable from the recorded database."""
+    from tenzing_tpu.bench.benchmarker import result_row
+
+    args, naive_seq, resilient = run.args, run.naive_seq, run.stack.resilient
+    finals, top = pick.finals, pick.top
+    results = [run.naive] + [s.result for s in res.sims]
+    orders = [naive_seq] + [s.order for s in res.sims]
+    # fidelity tags keep the DB honest: MCTS screen rows were measured at
+    # a ~1 ms floor and must not be ranked against full-floor rows by the
+    # warm-start loader (bench/recorded.py skips non-"full" rows)
+    fids = ["full"] + [getattr(s, "fidelity", "full") for s in res.sims]
+    if finals:
+        results[0] = finals[0]
+        for r, s in zip(finals[1:], top):
+            # identity, not ==: sync ops compare kind-only, so two distinct
+            # schedules can be ==-equal and .index() would mis-attribute
+            idx = next(i for i, s2 in enumerate(res.sims) if s2 is s)
+            results[1 + idx] = r
+            fids[1 + idx] = "full"  # superseded by the final batch
+    # rows the learned screen answered from the MODEL carry no device
+    # measurement at all — tag them fid=model (inert to every reader,
+    # like screen rows) so the archive never passes predictions off as
+    # measurements
+    if run.surrogate is not None:
+        for i, s in enumerate(res.sims):
+            if fids[1 + i] == "screen" and run.search_bench.was_predicted(
+                    s.order):
+                fids[1 + i] = "model"
+    # rows answered after device loss carry degraded provenance — like
+    # fid=model they are inert to every reader (CsvBenchmarker admits
+    # only "full" rows, recorded.py skips non-"full"), so a degraded
+    # run's archive can never pass predictions off as measurements
+    if resilient.degraded:
+        for i, s in enumerate(res.sims):
+            if resilient.was_degraded(s.order):
+                fids[1 + i] = "degraded"
+    # screen rows cannot shadow full-fidelity twins on replay:
+    # CsvBenchmarker only admits "full" rows into its equivalence cache
+    rows = [
+        result_row(i, r, o, fidelity=None if f == "full" else f)
+        for i, (r, o, f) in enumerate(zip(results, orders, fids))
+    ]
+    # THE dump invariant every downstream reader trusts (recorded.py
+    # naive_anchor_of, learn/dataset.py): row 0 is the naive schedule at
+    # FINAL fidelity — checked at dump time (a real exception, not an
+    # assert: it must hold under python -O too) so a future reshuffle of
+    # the results list cannot silently poison every in-file ratio
+    # computed against this file's anchor
+    if orders[0] is not naive_seq or fids[0] != "full":
+        raise RuntimeError(
+            "dump-csv invariant violated: row 0 must be the naive "
+            "schedule at full fidelity")
+    with open(args.dump_csv, "w") as f:
+        f.write("\n".join(rows) + "\n")
+    sys.stderr.write(f"csv: {args.dump_csv} ({len(rows)} rows)\n")
+
+
+def _stamp(run: _Run, pick: _Pick, reports: Dict[str, Any],
+           n_recorded: int) -> DriverResult:
+    """The verdict: the number, then the provenance blocks, each present
+    iff its mechanism ran."""
+    ex, stack, finals = run.ex, run.stack, pick.finals
+    prefetcher, resilient = stack.prefetcher, stack.resilient
     # compile/perf provenance (ISSUE 5): "compiled programs: N" used to be
     # a stderr-only note, so a compile-wall regression was invisible to the
     # parsed BENCH_*.json series.  Close the prefetcher first (joins the
@@ -2373,58 +1853,55 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     perf = {
         "compiled_programs": ex.compile_count,
         "compile_secs": round(ex.compile_secs, 3),
-        "compile_cache_dir": compile_cache_dir,
+        "compile_cache_dir": run.compile_cache_dir,
         "prefetch": (prefetcher.stats() if prefetcher is not None else
                      {"workers": 0, "issued": 0, "hits": 0, "wasted": 0,
                       "failed": 0, "surfaced": 0, "dropped": 0}),
     }
-    # megakernel-fusion provenance (ISSUE 8): regions, tiles chosen, gate
-    # verdict, dispatch overhead before/after — present iff --fuse-winner
-    if fused_block is not None:
-        perf["fused"] = fused_block
-    # in-driver tile search provenance — present iff --fuse-search-tiles
-    if tile_menu is not None:
+    tiles_block = None
+    if run.tile_menu is not None:
         from tenzing_tpu.runtime.fused import tiles_of as _tiles_of
 
-        perf["fuse_search_tiles"] = {
-            "menu": list(tile_menu),
-            "planted": tile_planted,
-            "chosen": _tiles_of(reported_seq),
+        tiles_block = {
+            "menu": list(run.tile_menu),
+            "planted": run.tile_planted,
+            "chosen": _tiles_of(pick.reported_seq),
         }
-    # op-chunking provenance (ISSUE 10) — present iff --chunk
-    if chunked_block is not None:
-        perf["chunked"] = chunked_block
-    # synthesized-collective provenance (ISSUE 17) — present iff
-    # --synth-collectives
-    if synth_block is not None:
-        perf["synth"] = synth_block
-    # distributed-search provenance (ISSUE 20) — present iff the fleet ran
-    # (--search-workers / --measure-batch): wall-clock, candidates/sec,
-    # fused-round batch occupancy and the worker scaling factor, parsed by
-    # the CI distributed-search gate
-    if distributed_stats is not None:
-        perf["distributed"] = distributed_stats
+    # in stamping order: megakernel fusion (ISSUE 8: regions, tiles chosen,
+    # gate verdict, dispatch overhead before/after — iff --fuse-winner); the
+    # in-driver tile search (iff --fuse-search-tiles); op chunking (ISSUE
+    # 10, iff --chunk); synthesized collectives (ISSUE 17, iff
+    # --synth-collectives); the distributed search (ISSUE 20, iff the fleet
+    # ran: wall-clock, candidates/sec, fused-round batch occupancy and the
+    # worker scaling factor, parsed by the CI distributed-search gate)
+    for key, block in (("fused", reports["fused"]),
+                       ("fuse_search_tiles", tiles_block),
+                       ("chunked", reports["chunked"]),
+                       ("synth", reports["synth"]),
+                       ("distributed", run.distributed_stats)):
+        if block is not None:
+            perf[key] = block
     # regime metadata (VERDICT r4 item 6): cross-round vs_baseline
     # comparisons need the chip regime (naive_us), the measurement floors
     # that produced the verdict, and the warm-start provenance — without
     # them the parsed series quietly compares different machines
+    win = pick.winner()
     meta = {
         "perf": perf,
         "naive_us": round(
-            (finals[0].pct50 if finals else naive.pct50) * 1e6, 2),
-        "search_floor_s": search_opts.target_secs,
-        "screen_floor_s": screen_opts.target_secs,
-        "final_floor_s": fin_opts.target_secs,
-        "mcts_screen_floor_s": mcts_screen.target_secs,
-        "winner_label": (label_of(top[best_i])
-                         if top and finals and vs > 1.0 else None),
-        "recorded_seeds": len(recorded),
+            (finals[0].pct50 if finals else run.naive.pct50) * 1e6, 2),
+        "search_floor_s": run.search_opts.target_secs,
+        "screen_floor_s": pick.screen_opts.target_secs,
+        "final_floor_s": pick.fin_opts.target_secs,
+        "mcts_screen_floor_s": run.mcts_screen.target_secs,
+        "winner_label": run.label_of(win) if win is not None else None,
+        "recorded_seeds": n_recorded,
     }
     # attribution provenance (ISSUE 6): per-op timeline, critical path,
     # dispatch overhead and overlap efficiency of the reported schedule —
     # next to the fault/perf blocks, parsed by the report CLI
-    if attrib_block is not None:
-        meta["attrib"] = attrib_block
+    if reports["attrib"] is not None:
+        meta["attrib"] = reports["attrib"]
     # fault-layer provenance (ISSUE 3): a degraded verdict or a quarantine
     # -heavy run must be visible in the parsed metric series, not only in
     # stderr.  ``resumed`` distinguishes a continued run's numbers (its
@@ -2433,26 +1910,27 @@ def _run(args: DriverRequest, scope: _RunScope) -> DriverResult:
     # reported answer re-executed on device with outputs matching naive AND
     # passed the independent soundness verifier.
     injected: dict = {}
-    for inj in (injector, corrupt_injector):
+    for inj in (stack.injector, stack.corrupt_injector):
         if inj is not None:
             for k, v in inj.injected.items():
                 if v:
                     injected[k] = injected.get(k, 0) + v
-    if (resilient.degraded or len(quar) or args.resume or injected
-            or integrity is not None):
+    integrity = pick.integrity
+    if (resilient.degraded or len(stack.quarantine) or run.args.resume
+            or injected or integrity is not None):
         meta["fault"] = {
             "degraded": resilient.degraded,
-            "quarantined": len(quar),
-            "resumed": bool(args.resume),
+            "quarantined": len(stack.quarantine),
+            "resumed": bool(run.args.resume),
             **({"injected": injected} if injected else {}),
             **(integrity if integrity is not None else {}),
         }
-    write_telemetry()
+    run.write_telemetry()
     return DriverResult(verdict={
-        "metric": metric,
-        "value": round(value_us, 2),
+        "metric": run.built[2],
+        "value": round(pick.value_us, 2),
         "unit": "us",
-        "vs_baseline": round(vs, 4),
-        "device": device,
+        "vs_baseline": round(pick.vs, 4),
+        "device": run.device,
         **meta,
     })

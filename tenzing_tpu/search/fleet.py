@@ -143,7 +143,7 @@ def resolve_prefer(job: FleetJob):
     """The job's choice-preference policy, reconstructed from its name —
     the same module-level functions the serialized path uses, so worker
     and inline execution agree decision-for-decision."""
-    from tenzing_tpu.bench import driver as _driver
+    from tenzing_tpu.bench import workloads as _driver
 
     if job.prefer == "halo_alias":
         return _driver.halo_alias_prefer
@@ -379,7 +379,8 @@ def worker_main(fleet_dir: str, rank: int) -> int:
     from tenzing_tpu.core.serdes import sequence_to_json
 
     spec = read_json(os.path.join(fleet_dir, "spec.json"))
-    from tenzing_tpu.bench.driver import DriverRequest, graph_for
+    from tenzing_tpu.bench.driver import DriverRequest
+    from tenzing_tpu.bench.workloads import graph_for
 
     graph, _ = graph_for(DriverRequest(**spec["request"]))
     opts = _opts_from_json(spec["bench_opts"])

@@ -142,7 +142,7 @@ def fingerprint_of(req) -> WorkloadFingerprint:
     DriverRequest` — pure request arithmetic (no jax, no buffers, no
     backend): the serving front door must fingerprint a request on a host
     with no accelerator."""
-    from tenzing_tpu.bench.driver import search_lanes, workload_shape
+    from tenzing_tpu.bench.workloads import search_lanes, workload_shape
 
     shape = workload_shape(req)
     return WorkloadFingerprint(

@@ -428,7 +428,7 @@ class Resolver:
         if got is None:
             builder = self._graph_builder
             if builder is None:
-                from tenzing_tpu.bench.driver import graph_for as builder
+                from tenzing_tpu.bench.workloads import graph_for as builder
             got = builder(req)
             self._cache_put(self._graphs, fp.exact_digest, got)
         return got

@@ -30,7 +30,8 @@ import glob as _glob
 import os
 from typing import Any, Callable, Dict, List, Optional
 
-from tenzing_tpu.bench.driver import DriverRequest, graph_for, metric_for
+from tenzing_tpu.bench.driver import DriverRequest
+from tenzing_tpu.bench.workloads import graph_for, metric_for
 from tenzing_tpu.obs.metrics import get_metrics
 from tenzing_tpu.obs.tracer import get_tracer
 from tenzing_tpu.serve.fingerprint import fingerprint_of, schedule_key
