@@ -1,7 +1,19 @@
-"""ISSUE 29's go/no-go on the chip: what the mesh exchange's iteration costs
-once its packs take their ordering token by index.
+"""ISSUE 29's go/no-go on the chip, and ISSUE 32's step 0: what the mesh
+exchange's iteration costs, operation by operation, and which operations
+write the ghost shells.
 
     chiprun --chips 4 -- python experiments/halo_mesh_tie_on_chip.py [--cells N] [--ranks 1]
+
+Parent against change in one call (ISSUE 32): unpack the parent into a
+directory ``.gitignore`` lists and measure its program with this script,
+then this checkout's, then compare the two reports (no chip in that step):
+
+    git archive <parent> | tar -x -C .bench_checkout/parent
+    chiprun --chips 4 -- sh -c 'python experiments/halo_mesh_tie_on_chip.py \
+        --root .bench_checkout/parent --label parent && \
+      python experiments/halo_mesh_tie_on_chip.py --label change && \
+      python experiments/halo_mesh_tie_on_chip.py --compare \
+        chiprun_out/halo_mesh_tie.parent.json chiprun_out/halo_mesh_tie.change.json'
 
 Builds ``halo512-mesh4.mcts``'s stack as a run builds it
 (``benchmarks/builders/halo_mesh.py``) and, for naive and both
@@ -15,10 +27,13 @@ engine-overlap schedules (``engine_overlap_order``, ``xla`` and ``rdma``):
 * the compiled repeat-n program's temporaries a chip (``memory_analysis``)
   and every operation inside its ``while`` body whose result is a shard's
   whole grid (``obs/attrib/hlo.py``);
-* the program's counters ``executor.index_ties`` and
-  ``executor.value_tied_bytes`` for one traced body;
+* the program's counters ``executor.index_ties``,
+  ``executor.value_tied_bytes`` and ``halo.window_unpacks`` for one traced
+  body;
 * a profiled dispatch: the first device's milliseconds an iteration by
-  operation.
+  operation, and of them the loop's writes into the grid by the face each
+  writes (``writes_ms``: x, y, z; a ``dynamic-update-slice`` or the window
+  kernel of ``ops/halo_pallas.py``).
 
 ``--ranks 1`` puts one rank on one chip (a 1x1x1 grid: every exchange wraps
 onto its own shard), the same slices, updates and tokens a chip at a quarter
@@ -26,7 +41,7 @@ of the chip time.  ``--compile-only N`` compiles the first engine-overlap
 schedule's repeat-n program at N cells a shard for the attached chips from
 shapes alone and reports its temporaries (what decides whether the source's
 512^3 fits).  One process; not part of a benchmark run.  Writes
-``chiprun_out/halo_mesh_tie.json``.
+``chiprun_out/halo_mesh_tie[.<label>].json``.
 """
 
 import argparse
@@ -36,8 +51,9 @@ import sys
 import tempfile
 import time
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# z writes together, ms an iteration (ISSUE 32, step 0)
+GO_Z_WRITES_MS = 4.0
 
 
 def grid_ops(compiled, local_shape) -> list:
@@ -46,6 +62,34 @@ def grid_ops(compiled, local_shape) -> list:
     shape = "f32[" + ",".join(str(int(x)) for x in local_shape) + "]"
     return [[o.name, o.opcode, list(o.fused)]
             for o in loop_ops_of_shape(compiled.as_text(), shape)]
+
+
+def grid_writes(compiled, local_shape) -> dict:
+    """{operation: thin axis of the face it writes} for the writes into a
+    shard's grid inside the loop: a ``dynamic-update-slice``'s update or a
+    kernel's last operand, looked up by name for its shape."""
+    import re
+
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+
+    text = compiled.as_text()
+    types = dict(re.findall(
+        r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\w+\[[\d,]*\])", text, re.M))
+    shape = "f32[" + ",".join(str(int(x)) for x in local_shape) + "]"
+    out = {}
+    for o in loop_ops_of_shape(text, shape):
+        if o.opcode not in ("dynamic-update-slice", "custom-call"):
+            continue
+        operands = re.search(
+            re.escape(o.name) + r" = .*? " + o.opcode + r"\(([^)]*)\)", text)
+        names = re.findall(r"%([\w.\-]+)", operands.group(1))
+        update = names[1] if o.opcode == "dynamic-update-slice" else names[-1]
+        dims = [int(x) for x in
+                types.get(update, "[]").split("[")[1].rstrip("]").split(",")
+                if x]
+        if len(dims) == 4:
+            out[o.name] = "xyz"[min(range(3), key=lambda i: dims[1 + i])]
+    return out
 
 
 def device_ms_by_op(run_n, n: int, top: int = 24) -> list:
@@ -85,7 +129,14 @@ def main() -> int:
     ap.add_argument("--compile-only", type=int, action="append", default=[])
     ap.add_argument("--schedules", default="naive,xla,rdma")
     ap.add_argument("--rehearse-cpu", action="store_true")
+    ap.add_argument("--root", default=HERE,
+                    help="the checkout whose program is measured")
+    ap.add_argument("--label", default=None)
+    ap.add_argument("--compare", nargs=2, metavar=("PARENT", "CHANGE"))
     args = ap.parse_args()
+    if args.compare:
+        return compare(*args.compare)
+    sys.path.insert(0, os.path.abspath(args.root))
     import jax
     import jax.numpy as jnp
 
@@ -127,8 +178,9 @@ def main() -> int:
     reg = get_metrics()
 
     def counters():
-        return (reg.counter("executor.index_ties").value,
-                reg.counter("executor.value_tied_bytes").value)
+        return tuple(reg.counter(name).value for name in (
+            "executor.index_ties", "executor.value_tied_bytes",
+            "halo.window_unpacks"))
 
     for label in [s for s in args.schedules.split(",") if s]:
         order = orders[label]
@@ -140,9 +192,11 @@ def main() -> int:
         mem = compiled.memory_analysis()
         row = report["schedules"][label] = {
             "index_ties": ties[0], "value_tied_bytes": ties[1],
+            "window_unpacks": ties[2],
             "temp_gb": mem.temp_size_in_bytes / 1e9,
             "argument_gb": mem.argument_size_in_bytes / 1e9,
             "grid_ops_in_loop": grid_ops(compiled, local)}
+        writes = grid_writes(compiled, local)
         del compiled, stepped
         run_n = ex.prepare_n(order)
         c = clock_mod.two_point(run_n)
@@ -158,21 +212,62 @@ def main() -> int:
                              for x in compared},
                    peak_gb=cell_mod.memory_peak(devices[:1]) / 1e9,
                    seconds=time.perf_counter() - t0)
-        row["device_ms_per_iter"] = device_ms_by_op(run_n, c["n"])
+        row["device_ms_per_iter"] = device_ms_by_op(run_n, c["n"], top=40)
+        ms = dict(row["device_ms_per_iter"])
+        row["writes_ms"] = {axis: sorted(
+            (ms.get(name, 0.0) for name, a in writes.items() if a == axis),
+            reverse=True) for axis in "xyz"}
         print(f"{label}: {json.dumps(row)}", flush=True)
     del built, ex
     for cells in args.compile_only:
         report.setdefault("compile_only", {})[str(cells)] = compile_only(
             config, cells, devices)
-    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "halo_mesh_tie.json"),
-              "w") as f:
+    os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+    name = ".".join(x for x in ("halo_mesh_tie", args.label, "json") if x)
+    with open(os.path.join(HERE, "chiprun_out", name), "w") as f:
         json.dump(report, f, indent=1)
     bad = [k for k, r in report["schedules"].items()
            if r["timed_fence_gap"] != 0.0
            or any(v > lim for v, lim in r["compared"].values())]
     print(json.dumps({"not_correct": bad}))
     return 1 if bad else 0
+
+
+def compare(parent_json: str, change_json: str) -> int:
+    """ISSUE 32's go/no-go from two reports of this script: the two z
+    writes together within ``GO_Z_WRITES_MS``, no whole-grid ``copy`` or
+    ``add`` in the loop that the parent's program of the same schedule did
+    not have, temporaries not above the parent's.  Exit 1 on a no-go."""
+    with open(parent_json) as f:
+        parent = json.load(f)["schedules"]
+    with open(change_json) as f:
+        change = json.load(f)["schedules"]
+
+    def passes(row):
+        ops = row["grid_ops_in_loop"]
+        return (sum(op == "copy" for _, op, _ in ops),
+                sum(op == "add" or "add" in fused for _, op, fused in ops))
+
+    no_go = []
+    for label, c in change.items():
+        p = parent[label]
+        writes = {axis: [sum(p["writes_ms"][axis]), sum(c["writes_ms"][axis])]
+                  for axis in "xyz"}
+        line = {"schedule": label,
+                "iter_ms": [p["iter_ms"], c["iter_ms"]],
+                "writes_ms": writes,
+                "grid_copies_adds": [passes(p), passes(c)],
+                "temp_gb": [p["temp_gb"], c["temp_gb"]],
+                "timed_fence_gap": [p["timed_fence_gap"],
+                                    c["timed_fence_gap"]],
+                "compared": c["compared"]}
+        print(json.dumps(line), flush=True)
+        if (writes["z"][1] > GO_Z_WRITES_MS
+                or any(a > b for a, b in zip(passes(c), passes(p)))
+                or c["temp_gb"] > p["temp_gb"]):
+            no_go.append(label)
+    print(json.dumps({"no_go": no_go}))
+    return 1 if no_go else 0
 
 
 def compile_only(config: dict, cells: int, devices) -> dict:

@@ -13,9 +13,11 @@ mesh ``("x", "y", "z")``; per direction the DAG is
 Pack(slice of the interior edge) -> post (host-posted transfer along the
 face's mesh axis, periodic: ``PermuteStart`` ICI collective-permute or
 ``RdmaShiftStart`` per-neighbor remote DMA) -> AwaitTransfer (the reference's
-Wait) -> Unpack(``dynamic_update_slice`` into the ghost shell).  Pack/unpack
+Wait) -> Unpack(the ghost-shell write).  The packs and the x faces' unpacks
 are XLA slice ops (contiguous copies the compiler fuses; the reference needs
-hand-written CUDA kernels for exactly this).  The six directions are
+hand-written CUDA kernels for exactly this); the y and z faces' unpacks, thin
+along the grid's sublane and lane axes, are an aliased window kernel
+(:class:`Unpack`).  The six directions are
 independent in the graph and the post and wait are separate vertices, so the
 solver searches how exchanges overlap each other and how much work hides
 between each post and its wait — the reference's post-all-before-wait-any
@@ -80,6 +82,12 @@ class HaloArgs:
 
     def itemsize(self) -> int:
         return np.dtype(self.dtype).itemsize
+
+
+def _face_axis(d: Tuple[int, int, int]) -> int:
+    """The grid axis (of ``(q, x, y, z)``) along which direction ``d``'s face
+    is thin: 1, 2 or 3."""
+    return 1 + [i for i, v in enumerate(d) if v != 0][0]
 
 
 def sublane_tile(itemsize: int) -> int:
@@ -159,7 +167,7 @@ class Pack(DeviceOp):
                 "(executor contract violated — the pack would have no "
                 "happens-before edge)"
             )
-        axis = 1 + [i for i, v in enumerate(self._d) if v != 0][0]
+        axis = _face_axis(self._d)
         starts = tuple(
             s + z if i == axis else s for i, s in enumerate(starts)
         )
@@ -169,8 +177,7 @@ class Pack(DeviceOp):
 
 def _dir_axis_sign(d: Tuple[int, int, int]) -> Tuple[str, int]:
     """(mesh axis name, ±1) of a face direction."""
-    i = [j for j, v in enumerate(d) if v != 0][0]
-    return _AXIS_NAMES[i], (1 if sum(d) > 0 else -1)
+    return _AXIS_NAMES[_face_axis(d) - 1], (1 if sum(d) > 0 else -1)
 
 
 def exchange_post(d: Tuple[int, int, int], engine: str = "xla"):
@@ -272,7 +279,25 @@ class ExchangeChoice(ChoiceOp):
 class Unpack(DeviceOp):
     """Write the received face into the ghost shell (reference Unpack,
     ops_halo_exchange.hpp:143-186, kernels ops_halo_exchange.cu:611-699 — and
-    without the stray device-sync defect noted in SURVEY.md §7.3)."""
+    without the stray device-sync defect noted in SURVEY.md §7.3).
+
+    Which write depends on what the op can see, the face's thin axis in the
+    grid ``(q, x, y, z)``:
+
+    * x (axis 1, a leading dimension): ``lax.dynamic_update_slice``, whole
+      tiles, 0.086 ms a face at 448^3 a shard (PERF.md, PR 29); the op takes
+      its ordering token by value, an add on the received face (7 MB);
+    * y or z (axis 2 or 3, the grid's sublane or lane axis): the aliased
+      window kernel ``ops/halo_pallas.py`` ``unpack_face_window``, which
+      also says why and how it takes the token (``INDEX_TIE``, into a block
+      index by scalar prefetch).  The op then counts as a Pallas op
+      (``uses_pallas``: ``shard_map`` without ``check_vma``, left out of
+      fused regions), and the program's counter ``halo.window_unpacks``
+      says, per traced body, how many faces went this way.
+
+    Subclasses with a write of their own (``halo_pipeline.UnpackRecv`` and
+    the kernel menu of ops/halo_pallas.py) override ``apply`` and declare
+    ``INDEX_TIE = False``: they keep the executor's value-tied read."""
 
     def __init__(self, args: HaloArgs, d: Tuple[int, int, int]):
         super().__init__(f"unpack_{dir_name(d)}")
@@ -284,11 +309,33 @@ class Unpack(DeviceOp):
     def writes(self):
         return ["U"]
 
+    @property
+    def INDEX_TIE(self) -> bool:
+        return _face_axis(self._d) >= 2
+
+    def uses_pallas(self) -> bool:
+        return self.INDEX_TIE
+
     def apply(self, bufs, ctx):
         import jax.lax as lax
 
         starts, _ = _face_slices(self._args, self._d, "unpack")
-        return {"U": lax.dynamic_update_slice(bufs["U"], bufs[f"recv_{dir_name(self._d)}"], starts)}
+        face = bufs[f"recv_{dir_name(self._d)}"]
+        if not self.INDEX_TIE:
+            return {"U": lax.dynamic_update_slice(bufs["U"], face, starts)}
+        from tenzing_tpu.obs.metrics import get_metrics
+        from tenzing_tpu.ops.halo_pallas import _interpret, unpack_face_window
+
+        z = ctx.tok_index_zero
+        if z is None:  # as Pack: no zero, no happens-before edge
+            raise RuntimeError(
+                f"{self.desc()}: INDEX_TIE op traced without tok_index_zero "
+                "(executor contract violated — the unpack would have no "
+                "happens-before edge)"
+            )
+        get_metrics().counter("halo.window_unpacks").inc()
+        return {"U": unpack_face_window(
+            bufs["U"], face, tuple(starts), z, interpret=_interpret())}
 
 
 class HaloExchange(CompoundOp):

@@ -104,8 +104,15 @@ class PackFlat(Pack):
 
 class UnpackRecv(Unpack):
     """Unpack reading the fetched (round-tripped) flat staging buffer: reshape
-    back to the face extents, then the same ghost-shell write as
-    models/halo.Unpack."""
+    back to the face extents, then one ``dynamic_update_slice`` into the
+    ghost shell whatever the face (the grid is tile-padded here, and the
+    kernels are the menu's: ops/halo_pallas.py ``UnpackChoice``), on the
+    executor's value-tied read."""
+
+    INDEX_TIE = False
+
+    def uses_pallas(self) -> bool:
+        return False
 
     def apply(self, bufs, ctx):
         import jax.lax as lax

@@ -32,6 +32,16 @@ exactly the aliased kernels per face (x .pallas, y .pallasf, z .pallasb —
 experiments/MENU_INCUMBENT2.json: 2.94x vs the XLA-unpack recipe's 2.51x in
 the same paired batch).
 
+The kernels above want a TILE-PADDED grid (``halo_pipeline._padded_shape``:
+the one-chip flagship's ``(3, 518, 520, 640)``).  The mesh exchange
+(``models/halo.py``, the cell ``halo512-mesh4.mcts``) keeps each shard at its
+own ``(3, 454, 454, 454)``, where Mosaic refuses every manual window; its
+``Unpack`` writes the y and z ghost shells with a fifth kernel,
+:func:`unpack_face_window`, which pipelines by ``BlockSpec`` and takes its
+ordering token as a scalar-prefetch operand.  That function's docstring is
+the one account of both (the unpadded-grid window and the scalar-prefetch
+tie); it is no menu entry: ``Unpack`` picks it by the face's thin axis.
+
 Off-TPU the kernels run in the Pallas interpreter (``interpret=True``), same
 code path as the repo's other Pallas kernels.
 """
@@ -503,6 +513,90 @@ def unpack_face_flat_pallas(
         compiler_params=_SEQUENTIAL_GRID,
         interpret=interpret,
     )(u, flat)
+
+
+# -- the window write on an unpadded grid (models/halo.py ``Unpack``) ----------
+
+
+def _shell_block(a0: int, n: int, extent: int, tile: int) -> Tuple[int, int, int]:
+    """(block extent, block index, offset of the cut in the block) of the
+    smallest tile-aligned BLOCK of one axis that holds the cut
+    ``[a0, a0 + n)``: blocks of ``tile``, doubled while the cut straddles two
+    of them, the whole axis where nothing smaller holds it (a cut over most
+    of the axis, as a face's long sides).  A block, not ``_tile_window``'s
+    window: a ``BlockSpec`` addresses whole blocks, and a block that runs
+    past the axis's end (lanes ``[384, 512)`` of 454) is legal there."""
+    w = tile
+    while w < extent and a0 // w != (a0 + n - 1) // w:
+        w *= 2
+    return (extent, 0, a0) if w >= extent else (w, a0 // w, a0 % w)
+
+
+@functools.partial(jax.jit, static_argnames=("starts", "interpret"))
+def unpack_face_window(
+    u: jax.Array, face: jax.Array, starts: Tuple[int, ...],
+    tok_zero: jax.Array, interpret: bool = False
+) -> jax.Array:
+    """u[:, x0+i, y0:y0+sy, z0:z0+sz] = face[:, i], in place, on a grid that
+    is NOT tile-padded (the mesh cell's ``(3, 454, 454, 454)`` a shard): the
+    ghost-shell write of a face whose thin axis is the grid's sublane (y) or
+    lane (z) axis.
+
+    Why a kernel: XLA's ``dynamic-update-slice`` does this write in place
+    but slowly, 3 cells of 128 lanes at a time from an update it first
+    relayouts (measured on four v5e chips at 448^3 a shard: 5.11 + 3.93 ms
+    the two z faces, 0.84 + 0.84 the y faces, of a 20.8 ms iteration:
+    PERF.md, PR 29).  What such a write has to touch is the tile column (z:
+    every y, one 128-lane tile) or tile row (y: one sublane tile, every z)
+    that holds the shell, once in and once out.
+
+    Why not the window kernels above: they DMA ``_tile_window``'s window by
+    hand, and on an unpadded grid Mosaic refuses every such slice, a whole
+    axis included ("Slice shape along dimension 2 must be aligned to tiling
+    (8), but is 454": it sees the buffer at its physical 456 x 512).  So
+    this one pipelines by ``BlockSpec``: per grid step (one x row, every q)
+    Pallas brings the block of :func:`_shell_block` in, the body copies it
+    and merges the face row, Pallas writes it back.  The block at the high
+    end runs past the axis (sublanes ``[448, 456)``, lanes ``[384, 512)`` of
+    454) and Pallas masks what is not there.  Input/output-aliased, so what
+    no block visits is never touched and every other cell of a visited
+    block, ghost edges and corners included, goes back as it came.
+
+    The ordering token: ``tok_zero`` (``ctx.tok_index_zero``, an int32 zero
+    that depends on the op's token) is a scalar-prefetch operand that the
+    index map adds to the x block index.  The kernel cannot start before it
+    is there, and no buffer gets a value-preserving add: on the received
+    face that add would be a Pallas consumer's operand, materialised in the
+    padded default layout (0.6 GB of traffic for a 7 MB z face)."""
+    nq, sx, sy, sz = face.shape
+    _, x0, y0, z0 = starts
+    _, _, Y, Z = u.shape
+    WH, by, yl = _shell_block(y0, sy, Y, sublane_tile(u.dtype.itemsize))
+    WW, bz, zl = _shell_block(z0, sz, Z, 128)
+
+    def kernel(tok_ref, u_ref, f_ref, o_ref):
+        o_ref[...] = u_ref[...]
+        o_ref[:, yl : yl + sy, zl : zl + sz] = f_ref[...]
+
+    def window(i, tok_ref):
+        return (0, x0 + i + tok_ref[0], by, bz)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(sx,),
+            in_specs=[
+                pl.BlockSpec((nq, None, WH, WW), window),
+                pl.BlockSpec((nq, None, sy, sz), lambda i, tok_ref: (0, i, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((nq, None, WH, WW), window),
+        ),
+        out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
+        input_output_aliases={1: 0},  # operand 0 is the token's zero
+        name="halo_window_unpack",
+        interpret=interpret,
+    )(tok_zero.reshape(1), u, face)
 
 
 # -- ops + choice menu ------------------------------------------------------------

@@ -35,8 +35,11 @@ from tenzing_tpu.runtime.executor import TraceExecutor
 ARGS = HaloArgs(nq=2, lx=8, ly=6, lz=4, radius=2)
 MESH = (2, 2, 1)
 GRID_TYPE = "tensor<" + "x".join(str(n) for n in ARGS.local_shape()) + "xf32>"
-FACE_BYTES = sum(int(np.prod(_face_slices(ARGS, d, "pack")[1])) * 4
-                 for d in DIRECTIONS)
+# the reads that still get a value-preserving add: the two received x faces
+# (the y and z unpacks take their token by index since PR 32:
+# tests/test_halo_window_unpack.py)
+X_FACE_BYTES = sum(int(np.prod(_face_slices(ARGS, d, "pack")[1])) * 4
+                   for d in DIRECTIONS if d[0] != 0)
 SCHEDULES = ["naive", "xla", "rdma"]
 DIR_IDS = [dir_name(d) for d in DIRECTIONS]
 
@@ -54,7 +57,7 @@ class ValueTiedPack(Pack):
 
 
 def _setup(which: str):
-    from jax.sharding import Mesh
+    from jax.sharding import Mesh, NamedSharding
 
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(MESH), ("x", "y", "z"))
     bufs, specs, want = make_halo_buffers(MESH, ARGS, seed=3)
@@ -62,7 +65,10 @@ def _setup(which: str):
     g = add_to_graph(Graph(), ARGS, xfer_choice=True)
     seq = (naive_schedule("halo_mesh", g, None) if which == "naive"
            else engine_overlap_order(g, plat, which))
-    ex = TraceExecutor(plat, {k: jnp.asarray(v) for k, v in bufs.items()})
+    # each buffer sharded as the benchmark's builder shards it
+    ex = TraceExecutor(plat, {
+        k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+        for k, v in bufs.items()})
     return ex, seq, want
 
 
@@ -78,8 +84,9 @@ def _grid_adds(text: str) -> int:
 
 def _counters():
     reg = get_metrics()
-    return (reg.counter("executor.index_ties").value,
-            reg.counter("executor.value_tied_bytes").value)
+    return tuple(reg.counter(name).value for name in (
+        "executor.index_ties", "executor.value_tied_bytes",
+        "halo.window_unpacks"))
 
 
 @pytest.mark.needs_shard_map
@@ -95,16 +102,19 @@ def test_repeat_n_program_adds_nothing_onto_the_grid(which):
 
 @pytest.mark.needs_shard_map
 @pytest.mark.parametrize("which", SCHEDULES)
-def test_counters_read_six_index_ties_and_the_faces(which):
-    """(e) one traced body of the mesh halo: six ops take their token by
-    index, and the value-tied reads are the six received faces, none of
-    them the grid."""
+def test_counters_read_ten_index_ties_and_the_x_faces(which):
+    """(e) one traced body of the mesh halo: ten ops take their token by
+    index (six packs; since PR 32 the four unpacks of y and z faces, which
+    the window kernel writes), and the value-tied reads are the two
+    received x faces, neither of them the grid."""
     ex, seq, _ = _setup(which)
     before = _counters()
     _lowered_repeat_n(ex, seq)
-    ties, tied_bytes = (b - a for a, b in zip(before, _counters()))
-    assert ties == 6
-    assert tied_bytes == FACE_BYTES
+    ties, tied_bytes, window_unpacks = (
+        b - a for a, b in zip(before, _counters()))
+    assert ties == 10
+    assert tied_bytes == X_FACE_BYTES
+    assert window_unpacks == 4
 
 
 @pytest.mark.needs_shard_map
@@ -118,11 +128,11 @@ def test_a_value_tied_pack_shows_in_lowering_and_counter(monkeypatch):
     ex, seq, want = _setup("xla")
     before = _counters()
     text = _lowered_repeat_n(ex, seq)
-    ties, tied_bytes = (b - a for a, b in zip(before, _counters()))
+    ties, tied_bytes, _ = (b - a for a, b in zip(before, _counters()))
     grid_bytes = int(np.prod(ARGS.local_shape())) * 4
     assert _grid_adds(text) == 6
-    assert ties == 0
-    assert tied_bytes == FACE_BYTES + 6 * grid_bytes
+    assert ties == 4  # the y and z unpacks
+    assert tied_bytes == X_FACE_BYTES + 6 * grid_bytes
     np.testing.assert_array_equal(np.asarray(ex.run(seq)["U"]), want)
 
 
@@ -147,6 +157,66 @@ def test_pack_token_edge_survives_compilation_under_shard_map():
     # a lane's first pack has no token yet (its start is a constant);
     # every later pack of the six starts where a token says
     assert tied >= 4, "the packs' token edges folded to static slices"
+
+
+@pytest.mark.needs_shard_map
+@pytest.mark.parametrize("which", SCHEDULES)
+def test_mesh_halo_is_the_expected_grid_and_the_timed_fence_agrees(which):
+    """Under ``shard_map`` on four virtual devices: the one-shot program
+    leaves ``make_halo_buffers``' expected grid cell for cell, and the
+    repeat-n program's fence after three iterations is, to the last bit,
+    its fence for the one-shot program's outputs after none (the
+    benchmark's ``timed_fence_gap``: the exchange is idempotent)."""
+    ex, seq, want = _setup(which)
+    once = ex.run(seq)
+    np.testing.assert_array_equal(np.asarray(once["U"]), want)
+    stepped = jax.jit(ex._stepped_fn(seq.vector()))
+
+    def fence(bufs, n):
+        # fetched before the next dispatch, as the harness does: two runs
+        # of a program with collectives are never in flight together
+        return float(jax.device_get(stepped(bufs, jnp.int32(n))[0]))
+
+    after_n = fence(ex.init_bufs, 3)
+    assert after_n == fence(once, 0)
+    # and the fence sees the exchange: the untouched buffers sum to less
+    assert fence(ex.init_bufs, 0) != after_n
+
+
+@pytest.mark.needs_shard_map
+def test_unpack_token_edge_is_the_kernels_first_operand():
+    """The traced repeat-n program hands every window unpack a scalar
+    prefetch operand that is a value of the program (the token's zero),
+    never a literal: were it one, every order of the unpacks would trace
+    to the same unordered kernel calls.  (That the compiled TPU program
+    keeps it: tests/test_tpu_compile.py, ``mesh_halo_loop``.)"""
+    from jax.extend import core as jcore
+
+    def subjaxprs(params):
+        for v in params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(x, jcore.ClosedJaxpr):
+                    yield x.jaxpr
+                elif isinstance(x, jcore.Jaxpr):
+                    yield x
+
+    ex, seq, _ = _setup("xla")
+    closed = jax.make_jaxpr(ex._stepped_fn(seq.vector()))(
+        ex.init_bufs, jnp.int32(1))
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call" and "halo_window_unpack" \
+                    in str(eqn.params.get("name", "")) + str(
+                        eqn.params.get("name_and_src_info", "")):
+                found.append(eqn.invars[0])
+            for sub in subjaxprs(eqn.params):
+                walk(sub)
+
+    walk(closed.jaxpr)
+    assert len(found) == 4
+    assert not any(isinstance(v, jcore.Literal) for v in found)
 
 
 @pytest.mark.parametrize("cls", [Pack, PackFlat])

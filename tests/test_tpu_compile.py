@@ -21,7 +21,8 @@ Covered, at the shapes ``python bench.py`` builds without ``--smoke``:
   integrity gate runs and as the repeat-n benchmark program, with the
   pinned_host staging buffers and the 2 GB grid carried through ``fori_loop``;
 * the models/halo.py mesh exchange on the four described chips, both
-  transfer engines (XLA collective-permute, remote DMA with barriers).
+  transfer engines (XLA collective-permute, remote DMA with barriers), and
+  its window unpack on the cell's unpadded ``(3, 454, 454, 454)`` shard.
 
 During such a compile ``jax.default_backend()`` is still ``cpu``, so the
 kernels' ``_interpret()`` would pick the interpreter: the tests (never an
@@ -548,17 +549,65 @@ def test_mesh_halo_exchange(topo, on_chip_kernels, engine, marker):
             + m.temp_size_in_bytes) < HBM_BYTES
 
 
+MESH_CELL = HaloArgs(nq=3, lx=448, ly=448, lz=448, radius=3)
+THIN = [d for d in DIRECTIONS if d[0] == 0]
+
+
+@pytest.mark.parametrize("d", THIN, ids=[dir_name(d) for d in THIN])
+def test_window_unpack_on_the_unpadded_grid(one_chip, d):
+    """``unpack_face_window`` on ``halo512-mesh4``'s shard, ``(3, 454, 454,
+    454)`` as the cell allocates it: Mosaic takes the blocks that run past
+    the grid's end (sublanes [448, 456), lanes [384, 512) of 454) ..."""
+    from tenzing_tpu.ops.halo_pallas import unpack_face_window
+
+    starts, sizes = _face_slices(MESH_CELL, d, "unpack")
+    compiled = jax.jit(
+        lambda u, f, z: unpack_face_window(u, f, tuple(starts), z)
+    ).lower(_sds(MESH_CELL.local_shape(), jnp.float32, one_chip),
+            _sds(sizes, jnp.float32, one_chip),
+            _sds((), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("d", THIN, ids=[dir_name(d) for d in THIN])
+def test_manual_window_dma_is_refused_on_the_unpadded_grid(one_chip, d):
+    """... and refuses the one-chip twin's kernel there: every slice of a
+    manual DMA window, a whole axis included, has to be a multiple of the
+    tile, and Mosaic sees the buffer at its physical 456 x 512.  Why the
+    mesh halo's kernel pipelines by ``BlockSpec`` (ops/halo_pallas.py)."""
+    from tenzing_tpu.ops.halo_pallas import unpack_face_pallas_batched
+
+    starts, sizes = _face_slices(MESH_CELL, d, "unpack")
+    with pytest.raises(Exception, match="must be aligned to tiling"):
+        jax.jit(
+            lambda u, f: unpack_face_pallas_batched(u, f, tuple(starts))
+        ).lower(_sds(MESH_CELL.local_shape(), jnp.float32, one_chip),
+                _sds(sizes, jnp.float32, one_chip)).compile()
+
+
+# the parent's (89ae733) repeat-n programs at 448^3, compiled for the same
+# described chips before the edit: whole-grid ``copy`` operations in the
+# ``while`` body, and temporaries a chip in bytes
+PARENT_LOOP = {"naive": (1, 3_131_737_088), "xla": (2, 5_091_660_800),
+               "rdma": (0, 3_219_259_392)}
+
+
 @pytest.mark.parametrize("which", ["naive", "xla", "rdma"])
 def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
                                                    which):
     """The repeat-n program of ``halo512-mesh4.mcts`` (448^3 a shard) as the
-    TPU compiler leaves it: no operation inside the ``while`` body adds onto
-    a shard's whole grid (up to PR 28 the six packs' ordering tokens did, a
-    pass over 1.27 GB each), and its temporaries are under five grids
-    (10.18 GB up to PR 28 for the ``xla`` schedule, eight grids; 5.09 since:
-    the entry's copy of the grid into the loop's carry, the carry, and two
-    relayout copies XLA makes for the thin y and z faces' slices, which are
-    the compiler's and not an ordering edge: PERF.md, PR 29)."""
+    TPU compiler leaves it.  No operation inside the ``while`` body adds
+    onto a shard's whole grid (up to PR 28 the six packs' ordering tokens
+    did, a pass over 1.27 GB each).  Since PR 32 the only
+    ``dynamic-update-slice`` of the grid are the two x faces': the y and z
+    shells are written by four ``halo_window_unpack`` kernels, each handed
+    its token's zero as an operand that is not a constant.  The body copies
+    the whole grid no more often than the parent's program of the same
+    schedule did (layout assignment's relayouts for the thin y and z
+    *packs*, which are the compiler's and no ordering edge: PERF.md, PR 29),
+    and the temporaries are not above the parent's."""
+    import re
+
     from tenzing_tpu.bench.driver import naive_schedule
     from tenzing_tpu.models.halo import engine_overlap_order
     from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
@@ -569,13 +618,26 @@ def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
     n = _sds((), jnp.int32, jax.sharding.NamedSharding(
         plat.mesh, jax.sharding.PartitionSpec()))
     compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(bufs, n).compile()
+    text = compiled.as_text()
     grid = "f32[" + ",".join(str(e) for e in args.local_shape()) + "]"
-    ops = loop_ops_of_shape(compiled.as_text(), grid)
-    assert sum(o.opcode == "dynamic-update-slice" for o in ops) == 6
+    ops = loop_ops_of_shape(text, grid)
+    assert sum(o.opcode == "dynamic-update-slice" for o in ops) == 2
+    for d in THIN:  # no update of a y- or z-face shape is left anywhere
+        face = "f32[" + ",".join(
+            str(e) for e in _face_slices(args, d, "unpack")[1]) + "]"
+        assert not re.search(
+            r"dynamic-update-slice\([^)]*" + re.escape(face), text), face
+    kernels = [o for o in ops if o.name.startswith("halo_window_unpack")]
+    assert len(kernels) == 4 and all(o.opcode == "custom-call"
+                                     for o in kernels)
+    for o in kernels:
+        first = re.search(re.escape(o.name) + r" = .*? custom-call\(%?([\w.\-]+)",
+                          text).group(1)
+        assert not first.startswith("constant"), (o.name, first)
     assert not [o for o in ops if "add" in o.fused or o.opcode == "add"], ops
-    grid_bytes = 4 * np.prod([-(-e // t) * t for e, t in zip(
-        args.local_shape(), (1, 1, 8, 128))])
-    assert compiled.memory_analysis().temp_size_in_bytes < 5 * grid_bytes
+    copies, temp_bytes = PARENT_LOOP[which]
+    assert sum(o.opcode == "copy" for o in ops) <= copies
+    assert compiled.memory_analysis().temp_size_in_bytes <= temp_bytes
 
 
 # -- the expert layer on four chips --------------------------------------------
