@@ -118,13 +118,31 @@ class Cost:
         return out
 
 
-def attention_cost(batch: int, seq: int, head_dim: int, bytes_per_el: int = 4) -> Cost:
-    """Dense softmax attention, one head group: QK^T and PV are each
-    2*b*n^2*d FLOPs (softmax's exp/sum is O(b*n^2), negligible).  HBM traffic
-    = read Q,K,V + write O (the n^2 score matrix stays blocked in VMEM in
-    every implementation compared)."""
-    flops = 4.0 * batch * seq * seq * head_dim
-    hbm = 4.0 * batch * seq * head_dim * bytes_per_el
+def attention_pairs(seq: int, causal: bool = False,
+                    window: Optional[int] = None) -> int:
+    """(query, key) pairs of one head's ``seq`` x ``seq`` scores that the
+    mask lets through: all of them; under ``causal`` the triangle with its
+    diagonal; under a ``window`` each query's own position and the
+    ``window - 1`` before it."""
+    if not causal:
+        return seq * seq
+    if window is None or window >= seq:
+        return seq * (seq + 1) // 2
+    return seq * window - window * (window - 1) // 2
+
+
+def attention_cost(batch: int, seq: int, head_dim: int, bytes_per_el: int = 4,
+                   heads: int = 1, kv_heads: int = 1, causal: bool = False,
+                   window: Optional[int] = None) -> Cost:
+    """Softmax attention of ``batch * heads`` query heads over ``batch *
+    kv_heads`` key/value heads: QK^T and PV are each 2*d FLOPs a (query,
+    key) pair, and only pairs under the mask are counted (softmax's exp/sum
+    is O(pairs), negligible): ``4*b*n^2*d`` for one unmasked head.  HBM
+    traffic = read Q,K,V + write O (the score matrix stays blocked in VMEM
+    in every implementation compared)."""
+    pairs = attention_pairs(seq, causal, window)
+    flops = 4.0 * batch * heads * pairs * head_dim
+    hbm = 2.0 * batch * (heads + kv_heads) * seq * head_dim * bytes_per_el
     return Cost(flops=flops, hbm_bytes=hbm)
 
 

@@ -23,7 +23,14 @@ The workloads (``DriverRequest.workload`` / the CLI's ``--workload``):
 * ``spmv``: distributed-SpMV iteration (reference config: m=150000 rows,
   nnz=10*m, band matrix, 2 lanes — spmv_run_strategy.cuh:44-47).
 * ``attn``: single-chip blockwise (flash) attention over a long context —
-  the kernel menu (XLA vs Pallas MXU) plus order x lane space.
+  the engine menu (per-block chain vs one fused kernel), the kernel menu
+  (XLA vs Pallas MXU) plus order x lane space.  The model
+  (``models/ring_attention.py`` ``BlockedAttention``) takes query heads
+  grouped over key/value heads, a causal mask, a sliding window and query
+  blocks (one chain per query block over the K/V blocks it can see, the
+  first fold writing the softmax state, so that an iteration is
+  idempotent); this row runs its unmasked single-group shape (8k context,
+  8 blocks of 1024), the benchmark's ``trinity-attn32k`` a model's layers.
 * ``moe``: single-chip MoE dispatch/combine pipeline — routed tokens staged
   through async host round-trip DMAs to the resident experts (the
   expert-parallel network-hop analog), searched over order x lane x
@@ -91,6 +98,17 @@ def moe_bf16_prefer(op_name, choices):
         (c for c in choices if c.endswith(".bf16-rdma")),
         next((c for c in choices if c.endswith(".xla")), None),
     )
+
+
+def attn_fused_prefer(op_name, choices):
+    """The attn climb policy: every query block's folds in one fused kernel
+    over its visible range (state in VMEM), the Pallas kernel where a chain
+    is unrolled: the start point a long prompt's search climbs from."""
+    for want in (".fused", ".pallas", ".xla"):
+        hit = next((c for c in choices if c.endswith(want)), None)
+        if hit is not None:
+            return hit
+    return None
 
 
 def recorded_prefer(chosen: Dict[str, str]):
@@ -485,8 +503,9 @@ def build_attn(args):
 
 def _attn_cost(built):
     a = built[3]
-    return roofline.attention_cost(a.batch, a.n_devices * a.seq_local,
-                                   a.head_dim)
+    return roofline.attention_cost(
+        a.batch, a.seq, a.head_dim, heads=a.heads, kv_heads=a.kv_heads,
+        causal=a.causal, window=a.window)
 
 
 def _attn_incumbents(req, g, wargs, plat):

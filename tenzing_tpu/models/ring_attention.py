@@ -27,12 +27,27 @@ Design (blockwise ring attention, double-buffered):
 
 The per-step block update has an implementation ChoiceOp: plain XLA einsums vs
 the Pallas MXU kernel.
+
+:class:`BlockedAttention` is the one-chip workload (``bench.py --workload
+attn``, and the benchmark's ``trinity-attn32k``): blockwise attention over
+K/V resident in HBM, with query heads grouped over key/value heads
+(``heads``, ``kv_heads``), a causal mask and a sliding window (``causal``,
+``window``), and query blocking (``q_block``).  With query blocks a layer is
+one chain per query block over the K/V blocks that block can see
+(:func:`tile_plan`): blocks no query of the block sees are not in the graph.
+Each chain competes with one fused kernel over the visible range
+(:class:`AttnEngineChoice`), and its first fold writes the softmax state
+instead of reading it, so an iteration is idempotent: n repeats leave every
+buffer as one leaves it.  The defaults of :class:`RingAttnArgs` (one head
+group, no mask, ``q_block=None``: every query in one chain whose state comes
+from the buffers, as the ring's does) are the shape this module had before
+it met a model, on the same code path.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
-from typing import Dict, List, Tuple
+from dataclasses import dataclass, replace
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -49,10 +64,42 @@ class RingAttnArgs:
     seq_local: int = 128  # queries per device
     head_dim: int = 128
     dtype: str = "float32"
+    heads: int = 1  # query heads; K/V head g serves heads g*group..(g+1)*group-1
+    kv_heads: int = 1
+    causal: bool = False  # key position <= query position
+    window: Optional[int] = None  # keys back from the query's own, it included
+    # rows of a query block (BlockedAttention); None: every query in one chain
+    # whose state is read from the buffers
+    q_block: Optional[int] = None
+
+    def __post_init__(self):
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} query heads do not group over "
+                             f"{self.kv_heads} key/value heads")
+        if self.window is not None and not self.causal:
+            raise ValueError("a window is counted back from the query's own "
+                             "position: it needs causal=True")
 
     @property
     def scale(self) -> float:
         return 1.0 / float(np.sqrt(self.head_dim))
+
+    @property
+    def seq(self) -> int:
+        """Positions of a sequence (BlockedAttention: all resident)."""
+        return self.n_devices * self.seq_local
+
+    @property
+    def low_precision_menu(self) -> bool:
+        """Whether casting Q/K/V to bfloat16 is another computation than the
+        stated one: with bfloat16 operands the ``*_bf16`` menu entries
+        coincide with their plain twins and the menus drop them."""
+        import jax.numpy as jnp
+
+        return jnp.dtype(self.dtype) != jnp.bfloat16
+
+
+NEG = -1e30  # the empty row maximum (ops/attention_pallas.py)
 
 
 def _kv(s: int) -> Tuple[str, str]:
@@ -76,27 +123,50 @@ class AttnStep(DeviceOp):
     def writes(self):
         return ["acc", "m_run", "l_run"]
 
-    def _update(self, q, k, v, acc, m, l):
+    def _update(self, q, k, v, state, q_pos=0, k_pos=0):
+        """``(acc', m', l')`` of folding ``k``/``v`` (first row at position
+        ``k_pos``) into ``state`` (``None``: the empty state) for the queries
+        ``q`` (first row at ``q_pos``).  A K/V head's group of query heads
+        is folded into the row axis, which is no operation for one group."""
         import jax.numpy as jnp
 
-        s_ = jnp.einsum("bqd,bkd->bqk", q, k, preferred_element_type=jnp.float32)
-        s_ = s_ * self._args.scale
+        a = self._args
+        h, n, d = q.shape
+        rows = (k.shape[0], (h // k.shape[0]) * n, d)
+        if state is None:
+            acc, m, l = (jnp.full(rows, c, jnp.float32) for c in (0., NEG, 0.))
+        else:
+            acc, m, l = (t.reshape(rows) for t in state)
+        s_ = jnp.einsum("bqd,bkd->bqk", q.reshape(rows), k,
+                        preferred_element_type=jnp.float32)
+        s_ = s_ * a.scale
+        edge = a.causal and mask_crosses(a, q_pos, n, k_pos, k.shape[1])
+        if edge:
+            qpos = q_pos + jnp.arange(rows[1])[:, None] % n
+            kpos = k_pos + jnp.arange(k.shape[1])[None, :]
+            seen = kpos <= qpos
+            if a.window is not None:
+                seen = seen & (kpos > qpos - a.window)
+            s_ = jnp.where(seen, s_, NEG)
         m_blk = jnp.max(s_, axis=2, keepdims=True)  # (b, n, 1)
         m_new = jnp.maximum(m, jnp.broadcast_to(m_blk, m.shape))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s_ - m_new[..., :1])
+        if edge:
+            p = jnp.where(seen, p, 0.0)
         l_new = l * alpha + jnp.broadcast_to(
             jnp.sum(p, axis=2, keepdims=True), l.shape
         )
         acc_new = acc * alpha + jnp.einsum(
             "bqk,bkd->bqd", p.astype(v.dtype), v, preferred_element_type=jnp.float32
         ).astype(acc.dtype)
-        return acc_new, m_new, l_new
+        return tuple(t.reshape(h, n, d) for t in (acc_new, m_new, l_new))
 
     def apply(self, bufs, ctx):
         k, v = _kv(self._s)
         acc, m, l = self._update(
-            bufs["Q"], bufs[k], bufs[v], bufs["acc"], bufs["m_run"], bufs["l_run"]
+            bufs["Q"], bufs[k], bufs[v],
+            (bufs["acc"], bufs["m_run"], bufs["l_run"])
         )
         return {"acc": acc, "m_run": m, "l_run": l}
 
@@ -118,10 +188,21 @@ class AttnStep(DeviceOp):
 class AttnStepPallas(AttnStep):
     """Same update via the Pallas MXU kernel (ops/attention_pallas.py)."""
 
-    def _update(self, q, k, v, acc, m, l):
+    def _update(self, q, k, v, state, q_pos=0, k_pos=0):
         from tenzing_tpu.ops.attention_pallas import attn_block_pallas
 
-        return attn_block_pallas(q, k, v, acc, m, l, self._args.scale)
+        a = self._args
+        # the kernel's static arguments say how the block sits under the
+        # mask and no more: the positions' difference, and no mask at all
+        # where no edge crosses the block.  Folds that sit alike are then
+        # one traced call, however many a chain has (a 16k prompt's chains
+        # have 53 folds of 9 kinds: PERF.md, PR 33)
+        masked = a.causal and mask_crosses(a, q_pos, q.shape[1], k_pos,
+                                           k.shape[1])
+        return attn_block_pallas(
+            q, k, v, *(state or (None,) * 3), a.scale,
+            q_pos=q_pos - k_pos if masked else 0, causal=masked,
+            window=a.window if masked else None)
 
     def uses_pallas(self) -> bool:
         return True
@@ -132,16 +213,13 @@ class AttnStepPallasBf16(AttnStep):
     the systolic-array throughput; softmax state and accumulation stay
     float32 via preferred_element_type inside the kernel)."""
 
-    def _update(self, q, k, v, acc, m, l):
+    def _update(self, q, k, v, state, q_pos=0, k_pos=0):
         import jax.numpy as jnp
 
-        from tenzing_tpu.ops.attention_pallas import attn_block_pallas
-
         bf = jnp.bfloat16
-        return attn_block_pallas(
-            q.astype(bf), k.astype(bf), v.astype(bf), acc, m, l,
-            self._args.scale,
-        )
+        return AttnStepPallas._update(
+            self, q.astype(bf), k.astype(bf), v.astype(bf), state, q_pos,
+            k_pos)
 
     def uses_pallas(self) -> bool:
         return True
@@ -157,11 +235,14 @@ class AttnStepChoice(ChoiceOp):
         self._args = args
 
     def choices(self) -> List[OpBase]:
-        return [
+        out = [
             AttnStep(self.name() + ".xla", self._s, self._args),
             AttnStepPallas(self.name() + ".pallas", self._s, self._args),
-            AttnStepPallasBf16(self.name() + ".pallas_bf16", self._s, self._args),
         ]
+        if self._args.low_precision_menu:
+            out.append(AttnStepPallasBf16(self.name() + ".pallas_bf16",
+                                          self._s, self._args))
+        return out
 
 
 class RotateKV(DeviceOp):
@@ -193,29 +274,6 @@ class RotateKV(DeviceOp):
         }
 
 
-class FinalizeAttn(DeviceOp):
-    """O = acc / l (the denominator division deferred past the ring)."""
-
-    def __init__(self, name: str = "attn_finalize"):
-        super().__init__(name)
-
-    def reads(self):
-        return ["acc", "l_run"]
-
-    def writes(self):
-        return ["O"]
-
-    def apply(self, bufs, ctx):
-        return {"O": bufs["acc"] / bufs["l_run"]}
-
-    # fusion: elementwise over the (b, n, d) state
-    def fusible(self) -> bool:
-        return True
-
-    def fuse_tiling(self):
-        return {"acc": 1, "l_run": 1, "O": 1}
-
-
 class RingAttention(CompoundOp):
     """The whole ring as one compound op: n_devices attn steps chained through
     the softmax state, n_devices-1 rotates chained through the kv buffers, WAR
@@ -224,6 +282,9 @@ class RingAttention(CompoundOp):
     def __init__(self, args: RingAttnArgs, name: str = "ring_attention",
                  impl_choice: bool = False):
         super().__init__(name)
+        if args.causal or args.heads != args.kv_heads:
+            raise ValueError("the ring folds one head group with no mask: a "
+                             "shard does not know its positions")
         self._args = args
         self._impl_choice = impl_choice
 
@@ -253,24 +314,176 @@ class RingAttention(CompoundOp):
         return g
 
 
+# -- the tile plan of a blocked layer -------------------------------------------
+
+
+@dataclass(frozen=True)
+class QBlock:
+    """One query block of a layer and the K/V blocks it can see: a
+    contiguous run, since the visible keys of a row are."""
+
+    index: Optional[int]  # None: the one block of a layer without q_block
+    q0: int
+    rows: int
+    blocks: Tuple[int, ...]  # K/V blocks that hold a key some row sees
+    skipped: int             # K/V blocks no row of the block can see
+
+
+def mask_crosses(args: RingAttnArgs, q0: int, rows: int, k0: int,
+                 keys: int) -> bool:
+    """Whether some (query, key) pair of the rectangle is masked: the
+    diagonal or the window's far edge crosses it."""
+    if not args.causal:
+        return False
+    if k0 + keys - 1 > q0:
+        return True
+    return args.window is not None and k0 <= q0 + rows - 1 - args.window
+
+
+def visible_pairs(args: RingAttnArgs, q0: int, rows: int, k0: int,
+                  keys: int) -> int:
+    """(query, key) pairs of the rectangle the mask lets through, one head."""
+    i = np.arange(q0, q0 + rows, dtype=np.int64)
+    hi = np.minimum(i, k0 + keys - 1) if args.causal else k0 + keys - 1
+    lo = k0 if args.window is None else np.maximum(i - args.window + 1, k0)
+    return int(np.maximum(hi - lo + 1, 0).sum())
+
+
+def tile_plan(args: RingAttnArgs) -> List[QBlock]:
+    """The query blocks of a layer, each with the K/V blocks in its graph."""
+    from tenzing_tpu.obs.tracer import get_tracer
+
+    blk, n = args.seq_local, args.seq
+    qb = args.q_block or n
+    with get_tracer().span("attn.plan", q_block=qb, kv_block=blk,
+                           causal=args.causal, window=args.window):
+        plan = []
+        for i, q0 in enumerate(range(0, n, qb)):
+            rows = min(qb, n - q0)
+            seen = tuple(s for s in range(args.n_devices)
+                         if visible_pairs(args, q0, rows, s * blk, blk))
+            plan.append(QBlock(
+                index=i if args.q_block else None, q0=q0, rows=rows,
+                blocks=seen, skipped=args.n_devices - len(seen)))
+    return plan
+
+
+def note_tiles(args: RingAttnArgs, qb: QBlock, blocks, computed: int,
+               opens: bool) -> None:
+    """The program's counters for one traced fold or fused vertex of
+    ``qb`` over the K/V ``blocks`` (at trace time, once per traced body, as
+    ``halo.window_unpacks``): ``attn.tiles`` (folds in the graph, a fused
+    vertex counting the blocks it covers), ``attn.tiles_edge`` (those a mask
+    edge crosses), ``attn.tiles_skipped`` (blocks no query of the block
+    sees, counted where its chain opens), ``attn.pairs_useful`` (pairs under
+    the mask) and ``attn.pairs_computed`` (pairs the implementation
+    computes, masked ones included), over all heads."""
+    from tenzing_tpu.obs.metrics import get_metrics
+
+    reg, blk = get_metrics(), args.seq_local
+    hb = args.batch * args.heads
+    reg.counter("attn.tiles").inc(len(blocks))
+    reg.counter("attn.tiles_edge").inc(sum(
+        mask_crosses(args, qb.q0, qb.rows, s * blk, blk) for s in blocks))
+    if opens:
+        reg.counter("attn.tiles_skipped").inc(qb.skipped)
+    reg.counter("attn.pairs_useful").inc(hb * sum(
+        visible_pairs(args, qb.q0, qb.rows, s * blk, blk) for s in blocks))
+    reg.counter("attn.pairs_computed").inc(hb * computed)
+
+
+def _all_rows(args: RingAttnArgs) -> QBlock:
+    """The one query block of a layer without ``q_block``: every row."""
+    return tile_plan(replace(args, q_block=None))[0]
+
+
+def _names(layer: str, qb: "QBlock") -> Dict[str, str]:
+    """Buffer names of a vertex: Q/K/V/O carry the layer's tag, the state
+    the query block's (the layers of a period run one after another and
+    share the state buffers)."""
+    tag = f".{layer}" if layer else ""
+    q = "" if qb.index is None else f".q{qb.index}"
+    return {"Q": "Q" + tag, "K": "K" + tag, "V": "V" + tag, "O": "O" + tag,
+            "acc": "acc" + q, "m_run": "m_run" + q, "l_run": "l_run" + q}
+
+
+def _prefix(layer: str, qb: Optional["QBlock"] = None) -> str:
+    """Op-name prefix of a layer's (query block's) vertices."""
+    out = f"{layer}." if layer else ""
+    if qb is not None and qb.index is not None:
+        out += f"q{qb.index}."
+    return out
+
+
+STATE = ("acc", "m_run", "l_run")
+
+
+def _rows_of(x, q0: int, rows: int):
+    """Rows ``q0 .. q0+rows`` of axis 1, or ``x`` where that is all of it."""
+    import jax.lax as lax
+
+    if rows == x.shape[1]:
+        return x
+    return lax.dynamic_slice_in_dim(x, q0, rows, 1)
+
+
 class BlockAttnStep(AttnStep):
     """Single-device variant: fold K/V block ``s`` *sliced from the resident
     K/V* into the state (blockwise/flash attention without the ring — the
-    1-device degenerate case of sequence parallelism, long context in HBM)."""
+    1-device degenerate case of sequence parallelism, long context in HBM).
+
+    ``qb`` names the query block whose rows fold (default: every row, the
+    state under its plain names); ``first`` makes the fold write the state
+    from the empty one instead of reading it."""
+
+    def __init__(self, name: str, s: int, args: RingAttnArgs,
+                 qb: Optional[QBlock] = None, layer: str = "",
+                 first: bool = False):
+        super().__init__(name, s, args)
+        self._qb = qb if qb is not None else _all_rows(args)
+        self._layer = layer
+        self._first = first
+        self._n = _names(layer, self._qb)
 
     def reads(self):
-        return ["Q", "K", "V", "acc", "m_run", "l_run"]
+        n = self._n
+        return [n["Q"], n["K"], n["V"]] + (
+            [] if self._first else [n[t] for t in STATE])
+
+    def writes(self):
+        return [self._n[t] for t in STATE]
+
+    def _computed_pairs(self, k0: int, keys: int) -> int:
+        return self._qb.rows * keys  # the einsum is dense; the mask comes after
 
     def apply(self, bufs, ctx):
         import jax.lax as lax
 
-        blk = self._args.seq_local
-        k = lax.dynamic_slice_in_dim(bufs["K"], self._s * blk, blk, 1)
-        v = lax.dynamic_slice_in_dim(bufs["V"], self._s * blk, blk, 1)
-        acc, m, l = self._update(
-            bufs["Q"], k, v, bufs["acc"], bufs["m_run"], bufs["l_run"]
-        )
-        return {"acc": acc, "m_run": m, "l_run": l}
+        a, n, qb = self._args, self._n, self._qb
+        blk = a.seq_local
+        k = lax.dynamic_slice_in_dim(bufs[n["K"]], self._s * blk, blk, 1)
+        v = lax.dynamic_slice_in_dim(bufs[n["V"]], self._s * blk, blk, 1)
+        # without query blocks every row the op is handed folds (a fused
+        # region hands it a tile of them)
+        q = bufs[n["Q"]] if qb.index is None else _rows_of(
+            bufs[n["Q"]], qb.q0, qb.rows)
+        state = None if self._first else tuple(bufs[n[t]] for t in STATE)
+        out = self._update(q, k, v, state, qb.q0, self._s * blk)
+        note_tiles(a, qb, [self._s], self._computed_pairs(self._s * blk, blk),
+                   self._first)
+        return dict(zip((n[t] for t in STATE), out))
+
+    # fusion (runtime/fused.py) tiles the query rows: a tile no longer knows
+    # its positions, so a masked fold stays out, as does a grouped one (the
+    # group is folded into the rows)
+    def fusible(self) -> bool:
+        a = self._args
+        return not a.causal and a.heads == a.kv_heads
+
+    def fuse_tiling(self):
+        n = self._n
+        return {n["Q"]: 1, n["K"]: None, n["V"]: None,
+                **{n[t]: 1 for t in STATE}}
 
     # -- op-chunking protocol (core/chunking.py, T3): the fold splits over
     # the K/V block axis into n sub-folds of seq_local/n columns each —
@@ -288,16 +501,16 @@ class BlockAttnStep(AttnStep):
         return pow2_counts(self._args.seq_local)
 
     def split(self, n: int) -> List["BlockAttnStep"]:
-        from dataclasses import replace
-
         blk = self._args.seq_local
         if n < 1 or blk % n:
             raise ValueError(f"{blk} K/V columns do not split {n} ways")
-        sub = replace(self._args, seq_local=blk // n)
+        sub = replace(self._args, seq_local=blk // n,
+                      n_devices=self._args.n_devices * n)
         # sub-fold j of block s slices K/V at s*blk + j*(blk//n): the same
         # dynamic_slice arithmetic, one power of two finer
         return [BlockAttnSubFold(f"{self.name()}.c{n}p{j}", self._s * n + j,
-                                 sub)
+                                 sub, self._qb, self._layer,
+                                 self._first and j == 0)
                 for j in range(n)]
 
 
@@ -315,6 +528,12 @@ class BlockAttnStepPallas(BlockAttnStep):
 
     _update = AttnStepPallas._update
 
+    def _computed_pairs(self, k0: int, keys: int) -> int:
+        from tenzing_tpu.ops.attention_pallas import computed_pairs
+
+        a, qb = self._args, self._qb
+        return computed_pairs(qb.rows, keys, qb.q0, k0, a.causal, a.window)
+
     def uses_pallas(self) -> bool:
         return True
 
@@ -322,16 +541,10 @@ class BlockAttnStepPallas(BlockAttnStep):
         return False  # the kernel owns its internal blocking
 
 
-class BlockAttnStepPallasBf16(BlockAttnStep):
+class BlockAttnStepPallasBf16(BlockAttnStepPallas):
     """Blocked step with the bfloat16-input Pallas kernel update."""
 
     _update = AttnStepPallasBf16._update
-
-    def uses_pallas(self) -> bool:
-        return True
-
-    def chunkable(self) -> bool:
-        return False
 
 
 def fold_chunk_menu(args: RingAttnArgs, relax: bool = False):
@@ -343,14 +556,25 @@ def fold_chunk_menu(args: RingAttnArgs, relax: bool = False):
     and the library tests — the ``min_tile_bytes=0`` convention of
     tests/test_fused.py) keeps every structurally-valid count so the
     machinery is searchable on toy shapes."""
+    import jax.numpy as jnp
+
     from tenzing_tpu.bench import roofline
 
-    bpe = np.dtype(args.dtype).itemsize
+    bpe = jnp.dtype(args.dtype).itemsize
     b, d, blk = args.batch, args.head_dim, args.seq_local
-    nq = args.n_devices * blk  # all queries fold against each block
-    state = 6.0 * b * nq * d * bpe  # read+write acc/m_run/l_run
-    cost = roofline.Cost(flops=4.0 * b * nq * blk * d,
-                         hbm_bytes=state + 2.0 * b * blk * d * bpe)
+    plan = tile_plan(args)
+    folds = sum(len(qb.blocks) for qb in plan)
+    # a fold's queries: a query block's rows, every head (without q_block
+    # all queries fold against each block)
+    nq = args.heads * max(qb.rows for qb in plan)
+    state = 6.0 * b * nq * d * 4  # read+write acc/m_run/l_run, float32
+    # the layer's operations under the mask, shared evenly among its folds
+    whole = roofline.attention_cost(
+        b, args.seq, d, bpe, heads=args.heads, kv_heads=args.kv_heads,
+        causal=args.causal, window=args.window)
+    cost = roofline.Cost(flops=whole.flops / folds,
+                         hbm_bytes=state + 2.0 * b * args.kv_heads * blk * d
+                         * bpe)
     # combine cost: every extra sub-fold re-presents the full softmax
     # state (the accumulating RMW is the combine)
     return roofline.chunk_menu(
@@ -360,10 +584,13 @@ def fold_chunk_menu(args: RingAttnArgs, relax: bool = False):
 
 class BlockAttnChoice(ChoiceOp):
     def __init__(self, name: str, s: int, args: RingAttnArgs,
-                 chunk_counts=(), chunk_est=None):
+                 chunk_counts=(), chunk_est=None,
+                 qb: Optional[QBlock] = None, layer: str = "",
+                 first: bool = False):
         super().__init__(name)
         self._s = s
         self._args = args
+        self._where = (qb, layer, first)
         self._chunks = tuple(int(c) for c in chunk_counts if int(c) > 1)
         self._chunk_est = dict(chunk_est or {})
         if chunk_counts:
@@ -375,59 +602,79 @@ class BlockAttnChoice(ChoiceOp):
     def choices(self) -> List[OpBase]:
         from tenzing_tpu.core.chunking import ChunkedOp
 
-        out: List[OpBase] = [
-            BlockAttnStep(self.name() + ".xla", self._s, self._args),
-            BlockAttnStepPallas(self.name() + ".pallas", self._s, self._args),
-            BlockAttnStepPallasBf16(
-                self.name() + ".pallas_bf16", self._s, self._args
-            ),
-        ]
+        def mk(cls, suffix):
+            return cls(self.name() + suffix, self._s, self._args,
+                       *self._where)
+
+        out: List[OpBase] = [mk(BlockAttnStep, ".xla"),
+                             mk(BlockAttnStepPallas, ".pallas")]
+        if self._args.low_precision_menu:
+            out.append(mk(BlockAttnStepPallasBf16, ".pallas_bf16"))
         # chunked alternatives of the XLA fold: ordinary menu entries the
         # solvers pick like any kernel (core/chunking.py)
         out += [
-            ChunkedOp(BlockAttnStep(self.name() + ".xla", self._s,
-                                    self._args),
-                      n, est_hidden_us=self._chunk_est.get(n))
+            ChunkedOp(mk(BlockAttnStep, ".xla"), n,
+                      est_hidden_us=self._chunk_est.get(n))
             for n in self._chunks
         ]
         return out
 
 
 class FusedBlockAttn(DeviceOp):
-    """ALL K/V blocks folded in one fused Pallas flash kernel
-    (ops/attention_pallas.attn_fused_pallas): the online-softmax state lives
-    in VMEM scratch across the kv grid dimension instead of round-tripping
-    HBM between per-block ops.  Measured motivation (r5): the chained
-    variant moves ~0.8 GB of acc/m/l state per iteration at the bench config
-    (b=4, n=8k, d=128) — HBM-state-bound at 66.5% MFU; fusing removes
-    6 x 16.8 MB of traffic per block."""
+    """ALL K/V blocks a query block sees folded in one fused Pallas flash
+    kernel (ops/attention_pallas.attn_fused_pallas): the online-softmax
+    state lives in VMEM scratch across the kv grid dimension instead of
+    round-tripping HBM between per-block ops.  Measured motivation (r5): the
+    chained variant moves ~0.8 GB of acc/m/l state per iteration at the
+    bench config (b=4, n=8k, d=128) — HBM-state-bound at 66.5% MFU; fusing
+    removes 6 x 16.8 MB of traffic per block."""
 
     BF16 = False
 
-    def __init__(self, name: str, args: RingAttnArgs):
+    def __init__(self, name: str, args: RingAttnArgs,
+                 qb: Optional[QBlock] = None, layer: str = "",
+                 first: bool = False):
         super().__init__(name)
         self._args = args
+        self._qb = qb if qb is not None else _all_rows(args)
+        self._first = first
+        self._n = _names(layer, self._qb)
 
-    def reads(self):
-        return ["Q", "K", "V", "acc", "m_run", "l_run"]
-
-    def writes(self):
-        return ["acc", "m_run", "l_run"]
+    reads = BlockAttnStep.reads
+    writes = BlockAttnStep.writes
 
     def apply(self, bufs, ctx):
         import jax.numpy as jnp
 
-        from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+        from tenzing_tpu.ops.attention_pallas import (
+            KV_TILE,
+            attn_fused_pallas,
+            computed_pairs,
+        )
 
-        q, k, v = bufs["Q"], bufs["K"], bufs["V"]
+        a, n, qb = self._args, self._n, self._qb
+        blk = a.seq_local
+        k0, keys = qb.blocks[0] * blk, len(qb.blocks) * blk
+        q = _rows_of(bufs[n["Q"]], qb.q0, qb.rows)
+        k = _rows_of(bufs[n["K"]], k0, keys)
+        v = _rows_of(bufs[n["V"]], k0, keys)
         if self.BF16:
             bf = jnp.bfloat16
             q, k, v = q.astype(bf), k.astype(bf), v.astype(bf)
-        acc, m, l = attn_fused_pallas(
-            q, k, v, bufs["acc"], bufs["m_run"], bufs["l_run"],
-            self._args.scale, bkv=self._args.seq_local,
-        )
-        return {"acc": acc, "m_run": m, "l_run": l}
+        state = (None,) * 3 if self._first else tuple(
+            bufs[n[t]] for t in STATE)
+        # one K/V block a grid step where the mask skips nothing; under a
+        # mask the kernel's own tile, so that less of an edge is computed
+        bkv = min(blk, KV_TILE) if a.causal else blk
+        # the positions enter as their difference (AttnStepPallas._update)
+        out = attn_fused_pallas(q, k, v, *state, a.scale, bkv=bkv,
+                                q_pos=qb.q0 - k0, causal=a.causal,
+                                window=a.window)
+        note_tiles(a, qb, qb.blocks,
+                   computed_pairs(qb.rows, keys, qb.q0, k0, a.causal,
+                                  a.window, bkv=bkv),
+                   self._first)
+        return dict(zip((n[t] for t in STATE), out))
 
     def uses_pallas(self) -> bool:
         return True
@@ -438,21 +685,35 @@ class FusedBlockAttnBf16(FusedBlockAttn):
 
 
 def _mk_block_step(name: str, s: int, args: RingAttnArgs, impl_choice: bool,
-                   chunk_counts, chunk_est) -> OpBase:
+                   chunk_counts, chunk_est, *where) -> OpBase:
     """One block fold vertex: the kernel ChoiceOp (optionally extended
     with chunked alternatives), a bare step wrapped in a
     :class:`~tenzing_tpu.core.chunking.ChunkChoice` when only chunking is
-    searched, or the plain step."""
+    searched, or the plain step.  ``where``: query block, layer, first."""
     if impl_choice:
-        return BlockAttnChoice(name, s, args, chunk_counts=chunk_counts,
-                               chunk_est=chunk_est)
-    step = BlockAttnStep(name, s, args)
+        return BlockAttnChoice(name, s, args, chunk_counts, chunk_est, *where)
+    step = BlockAttnStep(name, s, args, *where)
     counts = [c for c in (chunk_counts or ()) if int(c) > 1]
     if counts:
         from tenzing_tpu.core.chunking import ChunkChoice, chunk_variants
 
         return ChunkChoice(step, chunk_variants(step, counts, chunk_est))
     return step
+
+
+def _chain(g: Graph, args: RingAttnArgs, impl_choice: bool, chunk_counts,
+           chunk_est, qb: QBlock, layer: str) -> Tuple[OpBase, OpBase]:
+    """The per-block folds of ``qb`` linked through the state into ``g``;
+    returns the chain's two ends.  With query blocks the first fold opens
+    the state."""
+    pre = _prefix(layer, qb)
+    attns = [_mk_block_step(f"{pre}attn_{s}", s, args, impl_choice,
+                            chunk_counts, chunk_est, qb, layer,
+                            args.q_block is not None and s == qb.blocks[0])
+             for s in qb.blocks]
+    for a, b in zip(attns, attns[1:]):
+        g.then(a, b)
+    return attns[0], attns[-1]
 
 
 class BlockChain(CompoundOp):
@@ -462,57 +723,107 @@ class BlockChain(CompoundOp):
     precedent, models/halo_pipeline.py)."""
 
     def __init__(self, name: str, args: RingAttnArgs, impl_choice: bool,
-                 chunk_counts=(), chunk_est=None):
+                 chunk_counts=(), chunk_est=None,
+                 qb: Optional[QBlock] = None, layer: str = ""):
         super().__init__(name)
         self._args = args
         self._impl_choice = impl_choice
         self._chunk_counts = tuple(chunk_counts)
         self._chunk_est = dict(chunk_est or {})
+        self._qb = qb if qb is not None else _all_rows(args)
+        self._layer = layer
 
     def graph(self) -> Graph:
         g = Graph()
-        n = self._args.n_devices
-        attns = [_mk_block_step(f"attn_{s}", s, self._args,
-                                self._impl_choice, self._chunk_counts,
-                                self._chunk_est)
-                 for s in range(n)]
-        g.start_then(attns[0])
-        for s in range(1, n):
-            g.then(attns[s - 1], attns[s])
-        g.then_finish(attns[-1])
+        head, tail = _chain(g, self._args, self._impl_choice,
+                            self._chunk_counts, self._chunk_est, self._qb,
+                            self._layer)
+        g.start_then(head)
+        g.then_finish(tail)
         return g
 
 
 class AttnEngineChoice(ChoiceOp):
-    """Granularity menu for the whole blocked fold: the per-block chain
-    (searchable order x lane x per-block kernel) vs the fused single-kernel
-    flash (f32 or bf16 MXU inputs) — kernel granularity is itself a
-    scheduling decision the solver owns."""
+    """Granularity menu for the blocked fold of one query block: the
+    per-block chain (searchable order x lane x per-block kernel) vs the
+    fused single-kernel flash over the visible range (f32 or bf16 MXU
+    inputs) — kernel granularity is itself a scheduling decision the solver
+    owns.  Chains of unequal length (2 to 16 folds in a causal layer) sit
+    beside each other in one graph."""
 
     def __init__(self, args: RingAttnArgs, impl_choice: bool,
-                 chunk_counts=(), chunk_est=None):
-        super().__init__("attn_blocks")
+                 chunk_counts=(), chunk_est=None,
+                 qb: Optional[QBlock] = None, layer: str = ""):
+        super().__init__(_prefix(layer, qb) + "attn_blocks")
         self._args = args
         self._impl_choice = impl_choice
         self._chunk_counts = tuple(chunk_counts)
         self._chunk_est = dict(chunk_est or {})
+        self._qb = qb
+        self._layer = layer
 
     def choices(self) -> List[OpBase]:
-        return [
-            BlockChain("attn_blocks.chain", self._args, self._impl_choice,
-                       self._chunk_counts, self._chunk_est),
-            FusedBlockAttn("attn_blocks.fused", self._args),
-            FusedBlockAttnBf16("attn_blocks.fused_bf16", self._args),
+        first = self._args.q_block is not None
+        name = self.name()
+        out = [
+            BlockChain(name + ".chain", self._args, self._impl_choice,
+                       self._chunk_counts, self._chunk_est, self._qb,
+                       self._layer),
+            FusedBlockAttn(name + ".fused", self._args, self._qb,
+                           self._layer, first),
         ]
+        if self._args.low_precision_menu:
+            out.append(FusedBlockAttnBf16(name + ".fused_bf16", self._args,
+                                          self._qb, self._layer, first))
+        return out
+
+
+class FinalizeAttn(DeviceOp):
+    """O = acc / l (the denominator division deferred past the folds), the
+    query blocks of ``plan`` side by side, in the layer's ``dtype``."""
+
+    def __init__(self, name: str = "attn_finalize",
+                 plan: Optional[List[QBlock]] = None, layer: str = "",
+                 dtype: str = "float32"):
+        super().__init__(name)
+        self._dtype = dtype
+        self._parts = [_names(layer, qb) for qb in plan] if plan else [
+            {t: t for t in STATE + ("O",)}]
+
+    def reads(self):
+        return [n[t] for n in self._parts for t in ("acc", "l_run")]
+
+    def writes(self):
+        return [self._parts[0]["O"]]
+
+    def apply(self, bufs, ctx):
+        import jax.numpy as jnp
+
+        o = self._parts[0]["O"]
+        rows = [bufs[n["acc"]] / bufs[n["l_run"]] for n in self._parts]
+        whole = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
+        return {o: whole.astype(self._dtype)}
+
+    # fusion: elementwise over the (b, n, d) state
+    def fusible(self) -> bool:
+        return len(self._parts) == 1
+
+    def fuse_tiling(self):
+        n = self._parts[0]
+        return {n["acc"]: 1, n["l_run"]: 1, n["O"]: 1}
 
 
 class BlockedAttention(CompoundOp):
-    """Single-device blockwise attention over ``n_blocks`` K/V blocks: the attn
-    steps chain through the softmax state; block loads overlap on lanes; the
-    per-step kernel is a ChoiceOp when ``impl_choice``; with ``fused_choice``
-    the whole chain additionally competes with the fused single-kernel flash
-    (:class:`AttnEngineChoice`).  ``args.n_devices`` is reused as the block
-    count (no mesh involved).
+    """Single-device blockwise attention of one layer over ``n_blocks`` K/V
+    blocks: per query block (``args.q_block`` rows; one block of every row
+    without it) the folds of the K/V blocks it can see chain through the
+    softmax state; block loads overlap on lanes; the per-step kernel is a
+    ChoiceOp when ``impl_choice``; with ``fused_choice`` each chain
+    additionally competes with the fused single-kernel flash over its
+    visible range (:class:`AttnEngineChoice`).  ``args.n_devices`` is reused
+    as the block count (no mesh involved).  ``layer`` tags the op names and
+    the Q/K/V/O buffers, so that the layers of a model sit in one graph
+    (:func:`period_graph`).
 
     ``chunk=True`` adds chunked sub-fold alternatives of each block's XLA
     fold to the menus (core/chunking.py; :func:`fold_chunk_menu` prunes the
@@ -521,58 +832,106 @@ class BlockedAttention(CompoundOp):
 
     def __init__(self, args: RingAttnArgs, name: str = "blocked_attention",
                  impl_choice: bool = False, fused_choice: bool = False,
-                 chunk: bool = False, chunk_relax: bool = False):
+                 chunk: bool = False, chunk_relax: bool = False,
+                 layer: str = ""):
         super().__init__(name)
         self._args = args
         self._impl_choice = impl_choice
         self._fused_choice = fused_choice
         self._chunk = chunk
         self._chunk_relax = chunk_relax
+        self._layer = layer
 
     def args(self) -> RingAttnArgs:
         return self._args
 
     def graph(self) -> Graph:
         g = Graph()
-        n = self._args.n_devices
         counts, est = ((), None)
         if self._chunk:
             counts, est = fold_chunk_menu(self._args,
                                           relax=self._chunk_relax)
-        fin = FinalizeAttn()
-        if self._fused_choice:
-            eng = AttnEngineChoice(self._args, self._impl_choice,
-                                   counts, est)
-            g.start_then(eng)
-            g.then(eng, fin)
-        else:
-            attns = [_mk_block_step(f"attn_{s}", s, self._args,
-                                    self._impl_choice, counts, est)
-                     for s in range(n)]
-            g.start_then(attns[0])
-            for s in range(1, n):
-                g.then(attns[s - 1], attns[s])
-            g.then(attns[-1], fin)
+        plan = tile_plan(self._args)
+        fin = FinalizeAttn(_prefix(self._layer) + "attn_finalize",
+                           plan, self._layer, self._args.dtype)
+        for qb in plan:
+            if self._fused_choice:
+                head = tail = AttnEngineChoice(
+                    self._args, self._impl_choice, counts, est, qb,
+                    self._layer)
+            else:
+                head, tail = _chain(g, self._args, self._impl_choice, counts,
+                                    est, qb, self._layer)
+            g.start_then(head)
+            g.then(tail, fin)
         g.then_finish(fin)
         return g
 
 
+def period_graph(layers, **menus) -> Graph:
+    """The layers of a model one after another, as the residual stream
+    orders them (layer l+1 starts when layer l's O is final): ``layers`` is
+    ``[(tag, RingAttnArgs)]``, ``menus`` the switches of
+    :class:`BlockedAttention`.  The search's freedom is inside a layer."""
+    g = Graph()
+    ops = [BlockedAttention(a, name=f"{tag}.blocked_attention", layer=tag,
+                            **menus) for tag, a in layers]
+    g.start_then(ops[0])
+    for a, b in zip(ops, ops[1:]):
+        g.then(a, b)
+    g.then_finish(ops[-1])
+    return g
+
+
+def blocked_buffer_shapes(args: RingAttnArgs, layer: str = ""):
+    """``{name: (shape, dtype)}`` of one layer's buffers: Q and O
+    ``(batch*heads, n, d)``, K and V ``(batch*kv_heads, n, d)`` in
+    ``args.dtype`` (O float32 for a float32 layer), and per query block the
+    float32 state."""
+    hq, hk = args.batch * args.heads, args.batch * args.kv_heads
+    n, d = args.seq, args.head_dim
+    out = {}
+    for qb in tile_plan(args):
+        names = _names(layer, qb)
+        out.update({names[t]: ((hq, qb.rows, d), "float32") for t in STATE})
+    out.update({names["Q"]: ((hq, n, d), args.dtype),
+                names["K"]: ((hk, n, d), args.dtype),
+                names["V"]: ((hk, n, d), args.dtype),
+                names["O"]: ((hq, n, d), args.dtype)})
+    return out
+
+
 def make_blocked_buffers(
-    args: RingAttnArgs, seed: int = 0
+    args: RingAttnArgs, seed: int = 0, layer: str = ""
 ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
-    """(buffers, expected O) for single-device blockwise attention;
-    ``args.n_devices`` K/V blocks of ``seq_local`` each, resident in HBM."""
-    bufs, _specs, want = make_ring_buffers(args, seed=seed)
-    out = {
-        "Q": bufs["Q"],
-        "K": bufs["K0"],
-        "V": bufs["V0"],
-        "acc": bufs["acc"],
-        "m_run": bufs["m_run"],
-        "l_run": bufs["l_run"],
-        "O": bufs["O"],
-    }
-    return out, want
+    """(buffers, expected O) of one layer of single-device blockwise
+    attention; ``args.n_devices`` K/V blocks of ``seq_local`` each, resident
+    in HBM.  Expected O is dense softmax attention under the layer's mask,
+    computed in float64 on the host (small shapes: tests and smoke)."""
+    rng = np.random.default_rng(seed)
+    shapes = blocked_buffer_shapes(args, layer)
+    names = _names(layer, tile_plan(args)[0])
+    dt = np.dtype(args.dtype)
+    q, k, v = (rng.standard_normal(shapes[names[t]][0]).astype(dt)
+               for t in ("Q", "K", "V"))
+    group = args.heads // args.kv_heads
+    k64, v64 = (np.repeat(t.astype(np.float64), group, axis=0)
+                for t in (k, v))
+    s_ = np.einsum("bqd,bkd->bqk", q.astype(np.float64), k64) * args.scale
+    if args.causal:
+        i, j = np.arange(args.seq)[:, None], np.arange(args.seq)[None, :]
+        seen = j <= i
+        if args.window is not None:
+            seen &= j > i - args.window
+        s_ = np.where(seen, s_, -np.inf)
+    p = np.exp(s_ - s_.max(axis=2, keepdims=True))
+    p /= p.sum(axis=2, keepdims=True)
+    want = np.einsum("bqk,bkd->bqd", p, v64).astype(np.float32)
+    fill = {"m_run": NEG}
+    bufs = {name: np.full(shape, fill.get(name.split(".")[0], 0.0), dtype)
+            for name, (shape, dtype) in shapes.items()}
+    bufs.update({names["Q"]: q, names["K"]: k, names["V"]: v})
+    return bufs, want
 
 
 def make_ring_buffers(

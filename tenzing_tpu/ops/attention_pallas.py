@@ -1,8 +1,8 @@
-"""Pallas flash-attention block kernel: one online-softmax update step.
+"""Pallas flash-attention kernels: online-softmax folds on the MXU.
 
-The MXU workhorse of the ring-attention workload (models/ring_attention.py):
-given local queries Q and one K/V block of the ring, fold the block into the
-running (acc, m, l) online-softmax state:
+The MXU workhorse of the attention workloads (models/ring_attention.py):
+given queries Q and a stretch of K/V, fold it into the running (acc, m, l)
+online-softmax state:
 
     s     = Q K^T * scale          (MXU)
     m'    = max(m, rowmax(s))
@@ -11,14 +11,40 @@ running (acc, m, l) online-softmax state:
     l'    = l * alpha + rowsum(p)
     acc'  = acc * alpha + p V      (MXU)
 
-State tensors m and l are carried broadcast to (b, n, d) — same shape/layout as
-acc — so every in-kernel operand is a clean 2D (n, d) or (n, nkv) tile (no
-lane<->sublane transposes, no last-dim-1 blocks; see ops/spmv_pallas.py for the
-Mosaic layout constraints that motivate this).
+One kernel body, two entry points that differ in what they are handed and in
+the name the device trace shows:
 
-The kernel grid runs over the batch dimension; one program folds one batch
-element's whole block — Q/K/V blocks of ring attention are already VMEM-sized
-by construction (n_local x d per step).
+* :func:`attn_block_pallas` (``attn_fold``): one K/V block, the state read
+  from HBM and written back — one link of a per-block chain;
+* :func:`attn_fused_pallas` (``attn_fused``): a whole K/V range, the state
+  in VMEM scratch across it.
+
+State tensors m and l are carried broadcast to (h, n, d) — same shape/layout
+as acc — so every in-kernel operand is a clean 2D (n, d) or (n, nkv) tile (no
+lane<->sublane transposes, no last-dim-1 blocks; see ops/spmv_pallas.py for
+the Mosaic layout constraints that motivate this).  They stay float32
+whatever Q/K/V are.
+
+Heads: the leading axis of Q and the state is ``batch * heads``, that of K/V
+``batch * kv_heads``; query head ``i`` reads K/V head ``i // (heads //
+kv_heads)`` (the K/V index map), so grouped-query attention costs no copy.
+
+Mask: ``causal`` (key position <= query position) and ``window`` (key
+position > query position - window) are decided from positions.  The
+positions of the operands' first rows (``q_pos``, ``k_pos``) reach the
+kernel as one scalar-prefetch operand, so one kernel body serves every
+block of a layer.  Only their difference matters; the callers
+(models/ring_attention.py) hand ``q_pos - k_pos`` and 0, so that blocks
+that sit alike under the mask are one traced call.  Per query tile only the K/V tiles that hold a visible
+key are visited (the K/V index map walks that range, the steps left over
+skip their compute and fetch nothing new); of those, only the tiles the
+diagonal or the window's edge crosses build the iota comparison.  A row
+whose every key in a tile is masked stays finite: ``m`` starts at -1e30,
+not -inf, and a masked ``p`` is set to zero, not to ``exp(0)``.
+
+``acc``/``m``/``l`` handed as ``None`` starts from the empty
+state instead of reading one: the first fold of a chain, which makes an
+iteration leave the state one iteration leaves.
 
 ``interpret=True`` (automatic off-TPU) runs the same kernel in the Pallas
 interpreter for CPU tests.
@@ -27,156 +53,254 @@ interpreter for CPU tests.
 from __future__ import annotations
 
 import functools
+from dataclasses import dataclass, replace
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from tenzing_tpu.ops.common import out_struct
 
-
-def _attn_block_kernel(scale, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
-                       acc_out, m_out, l_out):
-    q = q_ref[0]  # (n, d)
-    k = k_ref[0]  # (nkv, d)
-    v = v_ref[0]
-    m_old = m_ref[0]  # (n, d) broadcast copies of the running row max
-    l_old = l_ref[0]
-    acc_old = acc_ref[0]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (n, nkv)
-    m_blk = jnp.max(s, axis=1, keepdims=True)  # (n, 1)
-    m_new = jnp.maximum(m_old, jnp.broadcast_to(m_blk, m_old.shape))
-    alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new[:, :1])  # (n, nkv)
-    l_new = l_old * alpha + jnp.broadcast_to(
-        jnp.sum(p, axis=1, keepdims=True), l_old.shape
-    )
-    acc_new = acc_old * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    acc_out[0] = acc_new.astype(acc_out.dtype)
-    m_out[0] = m_new
-    l_out[0] = l_new
+NEG = -1e30  # the empty row maximum: finite, so exp(NEG - NEG) is no NaN
+Q_TILE = 512
+KV_TILE = 1024
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def attn_block_pallas(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    acc: jax.Array,
-    m: jax.Array,
-    l: jax.Array,
-    scale: float,
-    *,
-    interpret: Optional[bool] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fold one K/V block into the online-softmax state; returns (acc', m', l').
+@dataclass(frozen=True)
+class _Plan:
+    """What the kernel body is specialised on (all static)."""
 
-    Shapes: q (b, n, d); k/v (b, nkv, d); acc/m/l (b, n, d) with m/l broadcast
-    along the last axis.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, n, d = q.shape
-    nkv = k.shape[1]
-    # tile the (row-independent) update over query blocks so VMEM holds one
-    # q/state tile + the whole K/V block, never all n queries at once; ragged n
-    # is padded up to the tile (rows are independent, pad rows stay finite:
-    # zero q/m give s=0, alpha=1 — no NaN/inf to leak) and sliced back off
-    bq = min(n, 512)
-    pad = (-n) % bq
-    np_ = n + pad
-    if pad:
-        padw = ((0, 0), (0, pad), (0, 0))
-        q, acc, m, l = (jnp.pad(t, padw) for t in (q, acc, m, l))
-    qblk = pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0))
-    kvblk = pl.BlockSpec((1, nkv, d), lambda i, j: (i, 0, 0))
-    specs_in = [qblk, kvblk, kvblk, qblk, qblk, qblk]
-    operands = (q, k, v, acc, m, l)
-    out_shape = [
-        out_struct((b, np_, d), acc.dtype, *operands),
-        out_struct((b, np_, d), m.dtype, *operands),
-        out_struct((b, np_, d), l.dtype, *operands),
-    ]
-    specs_out = [qblk, qblk, qblk]
-    kernel = functools.partial(_attn_block_kernel, float(scale))
-    outs = pl.pallas_call(
-        kernel,
-        grid=(b, np_ // bq),
-        in_specs=specs_in,
-        out_specs=specs_out,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(q, k, v, acc, m, l)
-    if pad:
-        outs = [o[:, :n] for o in outs]
-    return tuple(outs)
+    scale: float
+    bq: int
+    bkv: int
+    kv_tiles: int  # K/V tiles in the operand
+    steps: int     # grid extent over K/V: the most tiles a query tile sees
+    causal: bool   # the mask: a window implies it
+    window: Optional[int]
+    init: bool
 
 
-def _attn_fused_kernel(scale, nkv_steps, q_ref, k_ref, v_ref, acc_in, m_in,
-                       l_in, acc_out, m_out, l_out, acc_s, m_s, l_s):
-    """One (batch, q-tile, kv-block) grid step of the fused flash kernel:
-    state lives in VMEM scratch across the kv dimension (innermost, strictly
-    sequential), so acc/m/l touch HBM exactly twice per q-tile (initial read,
-    final write) instead of twice per kv block."""
-    kv = pl.program_id(2)
+def visible_tiles(plan: _Plan, q_lo, k_pos, larger=jnp.maximum,
+                  smaller=jnp.minimum):
+    """``(first, last)`` K/V tile of the operand that holds a key visible to
+    the query tile whose first row is at position ``q_lo`` (``last < first``
+    where there is none).  On traced scalars inside the kernel and its index
+    maps; on Python ints (``larger=max, smaller=min``) where the wrapper
+    sizes the grid."""
+    first, last = 0, plan.kv_tiles - 1
+    if plan.window is not None:
+        first = larger(q_lo - plan.window + 1 - k_pos, 0) // plan.bkv
+    if plan.causal:
+        last = smaller((q_lo + plan.bq - 1 - k_pos) // plan.bkv, last)
+    return first, last
 
-    @pl.when(kv == 0)
+
+def computed_pairs(rows: int, keys: int, q_pos: int, k_pos: int,
+                   causal: bool, window: Optional[int], bq: int = Q_TILE,
+                   bkv: int = KV_TILE) -> int:
+    """(query, key) pairs a call of the kernel computes for one head, masked
+    ones included: its (query tile, K/V tile) steps that hold a visible key,
+    whole (the program's ``attn.pairs_computed``)."""
+    bq, bkv = min(rows, bq), min(bkv, keys)
+    plan = _Plan(0.0, bq, bkv, keys // bkv, 0, causal, window, False)
+    tiles = 0
+    for j in range(-(-rows // bq)):
+        first, last = visible_tiles(plan, q_pos + j * bq, k_pos, max, min)
+        tiles += max(0, last - first + 1)
+    return tiles * bq * bkv
+
+
+def _flash_kernel(plan: _Plan, offs, q_ref, k_ref, v_ref, *refs):
+    """One (head, q-tile, kv-step) grid step: state lives in VMEM scratch
+    across the kv dimension (innermost, strictly sequential), so acc/m/l
+    touch HBM once in (not at all with ``init``) and once out per q-tile."""
+    if plan.init:
+        acc_out, m_out, l_out, acc_s, m_s, l_s = refs
+    else:
+        acc_in, m_in, l_in, acc_out, m_out, l_out, acc_s, m_s, l_s = refs
+    j, t = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(t == 0)
     def _():
-        acc_s[...] = acc_in[0]
-        m_s[...] = m_in[0]
-        l_s[...] = l_in[0]
+        if plan.init:
+            acc_s[...] = jnp.zeros_like(acc_s)
+            m_s[...] = jnp.full_like(m_s, NEG)
+            l_s[...] = jnp.zeros_like(l_s)
+        else:
+            acc_s[...] = acc_in[0]
+            m_s[...] = m_in[0]
+            l_s[...] = l_in[0]
 
-    q = q_ref[0]
-    k = k_ref[0]
-    v = v_ref[0]
-    m_old = m_s[...]
-    l_old = l_s[...]
-    acc_old = acc_s[...]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    m_blk = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_old, jnp.broadcast_to(m_blk, m_old.shape))
-    alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(s - m_new[:, :1])
-    l_new = l_old * alpha + jnp.broadcast_to(
-        jnp.sum(p, axis=1, keepdims=True), l_old.shape
-    )
-    acc_new = acc_old * alpha + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )
-    acc_s[...] = acc_new
-    m_s[...] = m_new
-    l_s[...] = l_new
+    q_lo = offs[0] + j * plan.bq
+    first, last = visible_tiles(plan, q_lo, offs[1])
+    k_lo = offs[1] + (first + t) * plan.bkv
 
-    @pl.when(kv == nkv_steps - 1)
+    def fold(edge: bool):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        m_old, l_old, acc_old = m_s[...], l_s[...], acc_s[...]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * plan.scale  # (bq, bkv)
+        if edge:
+            qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            seen = kpos <= qpos
+            if plan.window is not None:
+                seen = seen & (kpos > qpos - plan.window)
+            s = jnp.where(seen, s, NEG)
+        m_blk = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_old, jnp.broadcast_to(m_blk, m_old.shape))
+        alpha = jnp.exp(m_old - m_new)
+        p = jnp.exp(s - m_new[:, :1])
+        if edge:
+            p = jnp.where(seen, p, 0.0)
+        l_s[...] = l_old * alpha + jnp.broadcast_to(
+            jnp.sum(p, axis=1, keepdims=True), l_old.shape
+        )
+        acc_s[...] = acc_old * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
+        )
+        m_s[...] = m_new
+
+    if plan.causal:
+        live = first + t <= last
+        edge = k_lo + plan.bkv - 1 > q_lo
+        if plan.window is not None:
+            edge = edge | (k_lo <= q_lo + plan.bq - 1 - plan.window)
+        pl.when(live & edge)(lambda: fold(True))
+        pl.when(live & jnp.logical_not(edge))(lambda: fold(False))
+    else:
+        fold(False)
+
+    @pl.when(t == plan.steps - 1)
     def _():
         acc_out[0] = acc_s[...].astype(acc_out.dtype)
         m_out[0] = m_s[...]
         l_out[0] = l_s[...]
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bkv", "interpret"))
+def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
+           window, interpret):
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if window is not None and not causal:
+        raise ValueError("a window is counted back from the query's own "
+                         "position: it needs causal=True")
+    h, n, d = q.shape
+    hkv, nkv = k.shape[0], k.shape[1]
+    if h % hkv:
+        raise ValueError(f"{h} query heads over {hkv} key/value heads")
+    group = h // hkv
+    bkv = min(bkv, nkv)
+    if nkv % bkv:
+        raise ValueError(f"{nkv} K/V rows in tiles of {bkv}")
+    init = acc is None
+    # tile the (row-independent) update over query tiles so VMEM holds one
+    # q/state tile + one K/V tile, never all n queries at once; ragged n is
+    # padded up to the tile (rows are independent, pad rows stay finite:
+    # zero q/m give s=0, alpha=1 — no NaN/inf to leak) and sliced back off
+    bq = min(n, bq)
+    pad = (-n) % bq
+    np_ = n + pad
+    padw = ((0, 0), (0, pad), (0, 0))
+    state = () if init else (acc, m, l)
+    if pad:
+        q = jnp.pad(q, padw)
+        state = tuple(jnp.pad(t, padw) for t in state)
+    plan = _Plan(float(scale), bq, bkv, nkv // bkv, nkv // bkv, bool(causal),
+                 None if window is None else int(window), init)
+    if plan.causal:
+        spans = [visible_tiles(plan, q_pos + j * bq, k_pos, max, min)
+                 for j in range(np_ // bq)]
+        plan = replace(plan, steps=max(1, max(b - a + 1 for a, b in spans)))
+
+    def kv_tile(j, t, offs):
+        if not plan.causal:
+            return t
+        first, last = visible_tiles(plan, offs[0] + j * bq, offs[1])
+        return jnp.clip(jnp.minimum(first + t, last), 0, plan.kv_tiles - 1)
+
+    qblk = pl.BlockSpec((1, bq, d), lambda i, j, t, offs: (i, j, 0))
+    kvblk = pl.BlockSpec(
+        (1, bkv, d), lambda i, j, t, offs: (i // group, kv_tile(j, t, offs), 0))
+    operands = (q, k, v) + state
+    st = out_struct((h, np_, d), jnp.float32, *operands)
+    outs = pl.pallas_call(
+        functools.partial(_flash_kernel, plan),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            # kv innermost and strictly sequential: the VMEM scratch state
+            # carries across the kv steps of one (head, q-tile)
+            grid=(h, np_ // bq, plan.steps),
+            in_specs=[qblk, kvblk, kvblk] + [qblk] * len(state),
+            out_specs=[qblk, qblk, qblk],
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)] * 3,
+        ),
+        out_shape=[st, st, st],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
+        ),
+        name=name,
+        interpret=interpret,
+    )(jnp.asarray([q_pos, k_pos], jnp.int32), *operands)
+    if pad:
+        outs = [o[:, :n] for o in outs]
+    return tuple(outs)
+
+
+_STATIC = ("scale", "bkv", "q_pos", "k_pos", "causal", "window", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def attn_block_pallas(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    acc: Optional[jax.Array],
+    m: Optional[jax.Array],
+    l: Optional[jax.Array],
+    scale: float,
+    *,
+    bkv: int = KV_TILE,
+    q_pos: int = 0,
+    k_pos: int = 0,
+    causal: bool = False,
+    window: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Fold one K/V block into the online-softmax state; returns (acc', m', l').
+
+    Shapes: q (h, n, d); k/v (hkv, nkv, d), h a multiple of hkv; acc/m/l
+    (h, n, d) float32 with m/l broadcast along the last axis, or all three
+    ``None`` to start from the empty state.  ``q_pos``/``k_pos``: the
+    positions of q's and k's first rows, for the mask.  A block of more than
+    ``bkv`` rows is walked ``bkv`` at a time.
+    """
+    return _flash("attn_fold", q, k, v, acc, m, l, scale, Q_TILE, bkv, q_pos,
+                  k_pos, causal, window, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def attn_fused_pallas(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
-    acc: jax.Array,
-    m: jax.Array,
-    l: jax.Array,
+    acc: Optional[jax.Array],
+    m: Optional[jax.Array],
+    l: Optional[jax.Array],
     scale: float,
-    bkv: int = 1024,
+    bkv: int = KV_TILE,
     *,
+    q_pos: int = 0,
+    k_pos: int = 0,
+    causal: bool = False,
+    window: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Fold the ENTIRE resident K/V into the online-softmax state in ONE
-    kernel — the fused alternative to chaining :func:`attn_block_pallas`
-    per block.
+    """Fold a whole K/V range into the online-softmax state in ONE kernel —
+    the fused alternative to chaining :func:`attn_block_pallas` per block.
 
     Why it exists (measured, r5): at b=4, n=8k, d=128 the chained version
     moves the (b, n, d) f32 state acc/m/l through HBM twice per block —
@@ -184,52 +308,10 @@ def attn_fused_pallas(
     so the chain is HBM-state-bound at 66.5% MFU while the roofline says
     compute-bound.  Keeping the state in VMEM scratch across the kv grid
     dimension (strictly sequential, pinned "arbitrary") cuts state traffic
-    to one read + one write per q-tile.
+    to one read + one write per q-tile (one write with ``acc=None``).
 
-    Shapes: q (b, n, d); k/v (b, nkv, d) with nkv % bkv == 0; acc/m/l
-    (b, n, d) broadcast state as in :func:`attn_block_pallas`.
+    Shapes as :func:`attn_block_pallas`, with nkv % bkv == 0.  Under a mask
+    each q-tile walks only the K/V tiles that hold a key it can see.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    b, n, d = q.shape
-    nkv = k.shape[1]
-    bkv = min(bkv, nkv)
-    assert nkv % bkv == 0, (nkv, bkv)
-    nkv_steps = nkv // bkv
-    bq = min(n, 512)
-    pad = (-n) % bq
-    np_ = n + pad
-    if pad:
-        padw = ((0, 0), (0, pad), (0, 0))
-        q, acc, m, l = (jnp.pad(t, padw) for t in (q, acc, m, l))
-    qblk = pl.BlockSpec((1, bq, d), lambda i, j, kv: (i, j, 0))
-    kvblk = pl.BlockSpec((1, bkv, d), lambda i, j, kv: (i, kv, 0))
-    operands = (q, k, v, acc, m, l)
-    kernel = functools.partial(_attn_fused_kernel, float(scale), nkv_steps)
-    from jax.experimental.pallas import tpu as pltpu
-
-    outs = pl.pallas_call(
-        kernel,
-        # kv innermost and strictly sequential: the VMEM scratch state
-        # carries across kv steps of one (batch, q-tile)
-        grid=(b, np_ // bq, nkv_steps),
-        in_specs=[qblk, kvblk, kvblk, qblk, qblk, qblk],
-        out_specs=[qblk, qblk, qblk],
-        out_shape=[
-            out_struct((b, np_, d), acc.dtype, *operands),
-            out_struct((b, np_, d), m.dtype, *operands),
-            out_struct((b, np_, d), l.dtype, *operands),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
-        ),
-        interpret=interpret,
-    )(q, k, v, acc, m, l)
-    if pad:
-        outs = [o[:, :n] for o in outs]
-    return tuple(outs)
+    return _flash("attn_fused", q, k, v, acc, m, l, scale, Q_TILE, bkv, q_pos,
+                  k_pos, causal, window, interpret)
