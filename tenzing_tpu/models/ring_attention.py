@@ -38,10 +38,15 @@ one chain per query block over the K/V blocks that block can see
 Each chain competes with one fused kernel over the visible range
 (:class:`AttnEngineChoice`), and its first fold writes the softmax state
 instead of reading it, so an iteration is idempotent: n repeats leave every
-buffer as one leaves it.  The defaults of :class:`RingAttnArgs` (one head
+buffer as one leaves it.  Every query block finishes its own rows of the
+layer's O: the fused kernel divides in VMEM and writes them in place, with
+no state through HBM; a chain ends in a finaliser of its block.  A layer
+has no finaliser of its own then, and ends when its last block has.  The
+defaults of :class:`RingAttnArgs` (one head
 group, no mask, ``q_block=None``: every query in one chain whose state comes
 from the buffers, as the ring's does) are the shape this module had before
-it met a model, on the same code path.
+it met a model, on the same code path: there the state is handed on, and
+one :class:`FinalizeAttn` ends the layer.
 """
 
 from __future__ import annotations
@@ -392,6 +397,14 @@ def note_tiles(args: RingAttnArgs, qb: QBlock, blocks, computed: int,
     reg.counter("attn.pairs_computed").inc(hb * computed)
 
 
+def note_finish() -> None:
+    """``attn.fused_finishes``: one fused vertex wrote its rows of O itself
+    (at trace time, once per traced body, as :func:`note_tiles`)."""
+    from tenzing_tpu.obs.metrics import get_metrics
+
+    get_metrics().counter("attn.fused_finishes").inc()
+
+
 def _all_rows(args: RingAttnArgs) -> QBlock:
     """The one query block of a layer without ``q_block``: every row."""
     return tile_plan(replace(args, q_block=None))[0]
@@ -624,10 +637,28 @@ class FusedBlockAttn(DeviceOp):
     """ALL K/V blocks a query block sees folded in one fused Pallas flash
     kernel (ops/attention_pallas.attn_fused_pallas): the online-softmax
     state lives in VMEM scratch across the kv grid dimension instead of
-    round-tripping HBM between per-block ops.  Measured motivation (r5): the
-    chained variant moves ~0.8 GB of acc/m/l state per iteration at the
-    bench config (b=4, n=8k, d=128) — HBM-state-bound at 66.5% MFU; fusing
-    removes 6 x 16.8 MB of traffic per block."""
+    round-tripping HBM between per-block ops.
+
+    With query blocks (``first``: the vertex opens its own state and covers
+    the whole visible range of its rows) the kernel also finishes them: it
+    divides ``acc`` by ``l`` at a query tile's last step and writes rows
+    ``q0 .. q0+rows`` of the layer's O in place.  The vertex then reads Q,
+    K, V and O, writes O, and touches no state buffer.  What that saves, at
+    the benchmark's ``trinity-attn32k`` (16 384 tokens, 32 heads of 128,
+    query blocks of 4096; PERF.md, PR 34): a vertex that hands on its state
+    writes three float32 ``(32, 4096, 128)`` tensors, 201 MB, and the
+    layer's finaliser reads 537 MB of them and writes 67 MB of O: 3.2 GB
+    of state written and 2.4 GB moved by four XLA fusions an iteration,
+    beside the 0.6 GB of Q, K, V and O a layer has to move.
+
+    The four vertices of a layer write one buffer, O, in disjoint rows.
+    The graph has no edge between them and needs none: the executor's trace
+    is SSA (each vertex takes the O the vertex traced before it left and
+    returns it with its own rows written), so every order the search tries
+    gives the same O (tests/test_attn_window_gqa.py pins it).
+
+    Without query blocks (the state is handed on, as the ring's) nothing
+    finishes here: state in, state out, the layer's one finaliser."""
 
     BF16 = False
 
@@ -640,8 +671,15 @@ class FusedBlockAttn(DeviceOp):
         self._first = first
         self._n = _names(layer, self._qb)
 
-    reads = BlockAttnStep.reads
-    writes = BlockAttnStep.writes
+    def reads(self):
+        if self._first:
+            return [self._n[t] for t in ("Q", "K", "V", "O")]
+        return BlockAttnStep.reads(self)
+
+    def writes(self):
+        if self._first:
+            return [self._n["O"]]
+        return BlockAttnStep.writes(self)
 
     def apply(self, bufs, ctx):
         import jax.numpy as jnp
@@ -661,19 +699,23 @@ class FusedBlockAttn(DeviceOp):
         if self.BF16:
             bf = jnp.bfloat16
             q, k, v = q.astype(bf), k.astype(bf), v.astype(bf)
-        state = (None,) * 3 if self._first else tuple(
-            bufs[n[t]] for t in STATE)
         # one K/V block a grid step where the mask skips nothing; under a
         # mask the kernel's own tile, so that less of an edge is computed
         bkv = min(blk, KV_TILE) if a.causal else blk
         # the positions enter as their difference (AttnStepPallas._update)
-        out = attn_fused_pallas(q, k, v, *state, a.scale, bkv=bkv,
-                                q_pos=qb.q0 - k0, causal=a.causal,
-                                window=a.window)
+        mask = dict(bkv=bkv, q_pos=qb.q0 - k0, causal=a.causal,
+                    window=a.window)
         note_tiles(a, qb, qb.blocks,
                    computed_pairs(qb.rows, keys, qb.q0, k0, a.causal,
                                   a.window, bkv=bkv),
                    self._first)
+        if self._first:
+            note_finish()
+            return {n["O"]: attn_fused_pallas(
+                q, k, v, None, None, None, a.scale, finish=True,
+                o=bufs[n["O"]], o_row0=qb.q0, **mask)}
+        out = attn_fused_pallas(q, k, v, *(bufs[n[t]] for t in STATE),
+                                a.scale, **mask)
         return dict(zip((n[t] for t in STATE), out))
 
     def uses_pallas(self) -> bool:
@@ -705,12 +747,16 @@ def _chain(g: Graph, args: RingAttnArgs, impl_choice: bool, chunk_counts,
            chunk_est, qb: QBlock, layer: str) -> Tuple[OpBase, OpBase]:
     """The per-block folds of ``qb`` linked through the state into ``g``;
     returns the chain's two ends.  With query blocks the first fold opens
-    the state."""
+    the state and the chain ends in the finaliser of its own rows of O (as
+    a fused vertex finishes its own: :class:`FusedBlockAttn`)."""
     pre = _prefix(layer, qb)
     attns = [_mk_block_step(f"{pre}attn_{s}", s, args, impl_choice,
                             chunk_counts, chunk_est, qb, layer,
                             args.q_block is not None and s == qb.blocks[0])
              for s in qb.blocks]
+    if args.q_block is not None:
+        attns.append(FinalizeAttn(pre + "attn_finalize", [qb], layer,
+                                  args.dtype, q0=qb.q0))
     for a, b in zip(attns, attns[1:]):
         g.then(a, b)
     return attns[0], attns[-1]
@@ -780,33 +826,47 @@ class AttnEngineChoice(ChoiceOp):
 
 class FinalizeAttn(DeviceOp):
     """O = acc / l (the denominator division deferred past the folds), the
-    query blocks of ``plan`` side by side, in the layer's ``dtype``."""
+    query blocks of ``plan`` side by side, in the layer's ``dtype``.
+
+    ``q0``: the finaliser of one query block's chain in a layer with query
+    blocks.  It writes that block's rows of the layer's O, from row ``q0``,
+    and leaves the others as they are, as a fused vertex does
+    (:class:`FusedBlockAttn`, where the order of a layer's writers is
+    accounted for)."""
 
     def __init__(self, name: str = "attn_finalize",
                  plan: Optional[List[QBlock]] = None, layer: str = "",
-                 dtype: str = "float32"):
+                 dtype: str = "float32", q0: Optional[int] = None):
         super().__init__(name)
         self._dtype = dtype
         self._parts = [_names(layer, qb) for qb in plan] if plan else [
             {t: t for t in STATE + ("O",)}]
+        self._q0 = q0
 
     def reads(self):
-        return [n[t] for n in self._parts for t in ("acc", "l_run")]
+        return [n[t] for n in self._parts for t in ("acc", "l_run")] + (
+            [] if self._q0 is None else [self._parts[0]["O"]])
 
     def writes(self):
         return [self._parts[0]["O"]]
 
     def apply(self, bufs, ctx):
+        import jax.lax as lax
         import jax.numpy as jnp
 
         o = self._parts[0]["O"]
         rows = [bufs[n["acc"]] / bufs[n["l_run"]] for n in self._parts]
         whole = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
-        return {o: whole.astype(self._dtype)}
+        whole = whole.astype(self._dtype)
+        if self._q0 is not None:
+            whole = lax.dynamic_update_slice_in_dim(bufs[o], whole,
+                                                    self._q0, 1)
+        return {o: whole}
 
-    # fusion: elementwise over the (b, n, d) state
+    # fusion: elementwise over the (b, n, d) state; a finaliser of some rows
+    # of O stays out (a fused region would tile O whole)
     def fusible(self) -> bool:
-        return len(self._parts) == 1
+        return len(self._parts) == 1 and self._q0 is None
 
     def fuse_tiling(self):
         n = self._parts[0]
@@ -852,8 +912,12 @@ class BlockedAttention(CompoundOp):
             counts, est = fold_chunk_menu(self._args,
                                           relax=self._chunk_relax)
         plan = tile_plan(self._args)
-        fin = FinalizeAttn(_prefix(self._layer) + "attn_finalize",
-                           plan, self._layer, self._args.dtype)
+        # with query blocks every block finishes its own rows of O (the
+        # fused kernel, or its chain's finaliser): the layer ends when the
+        # last of them has.  Without, one finaliser over the handed-on state
+        fin = None if self._args.q_block is not None else FinalizeAttn(
+            _prefix(self._layer) + "attn_finalize", plan, self._layer,
+            self._args.dtype)
         for qb in plan:
             if self._fused_choice:
                 head = tail = AttnEngineChoice(
@@ -863,8 +927,9 @@ class BlockedAttention(CompoundOp):
                 head, tail = _chain(g, self._args, self._impl_choice, counts,
                                     est, qb, self._layer)
             g.start_then(head)
-            g.then(tail, fin)
-        g.then_finish(fin)
+            g.then(tail, g.finish() if fin is None else fin)
+        if fin is not None:
+            g.then_finish(fin)
         return g
 
 

@@ -19,6 +19,23 @@ the name the device trace shows:
 * :func:`attn_fused_pallas` (``attn_fused``): a whole K/V range, the state
   in VMEM scratch across it.
 
+and three shapes of output:
+
+* state in, state out (``acc``, ``m``, ``l`` handed): three float32
+  ``(h, n, d)`` tensors read and three written;
+* state out only (the three handed as ``None``): the first fold of a chain;
+* O out (``attn_fused_pallas(..., finish=True)``, legal only with no state
+  handed): a call that opens its rows' state and sees their whole visible
+  range holds the finished ``acc`` and ``l`` in VMEM at a query tile's last
+  K/V step, so it divides there (the float32 division a finaliser would
+  do, rounded once) and writes O alone: no state leaves the chip.  Handed
+  the layer's O (``o``, ``o_row0``) it writes its rows of it in place:
+  O is an aliased operand that is never fetched, the output's index map
+  walks tiles ``o_row0 // bq + j``, and every other row stays as it was.
+  Rows that are not whole tiles of O (a ragged count, a block that starts
+  inside a tile) come out fresh and are put in by
+  ``dynamic_update_slice_in_dim``.
+
 State tensors m and l are carried broadcast to (h, n, d) — same shape/layout
 as acc — so every in-kernel operand is a clean 2D (n, d) or (n, nkv) tile (no
 lane<->sublane transposes, no last-dim-1 blocks; see ops/spmv_pallas.py for
@@ -80,6 +97,7 @@ class _Plan:
     causal: bool   # the mask: a window implies it
     window: Optional[int]
     init: bool
+    finish: bool = False  # write O = acc / l and no state (needs init)
 
 
 def visible_tiles(plan: _Plan, q_lo, k_pos, larger=jnp.maximum,
@@ -115,8 +133,13 @@ def computed_pairs(rows: int, keys: int, q_pos: int, k_pos: int,
 def _flash_kernel(plan: _Plan, offs, q_ref, k_ref, v_ref, *refs):
     """One (head, q-tile, kv-step) grid step: state lives in VMEM scratch
     across the kv dimension (innermost, strictly sequential), so acc/m/l
-    touch HBM once in (not at all with ``init``) and once out per q-tile."""
-    if plan.init:
+    touch HBM once in (not at all with ``init``) and once out per q-tile
+    (not at all with ``finish``: the tile's last step divides and writes its
+    rows of O)."""
+    if plan.finish:
+        # an aliased O comes first among the refs, unfetched: never read
+        o_out, acc_s, m_s, l_s = refs[-4:]
+    elif plan.init:
         acc_out, m_out, l_out, acc_s, m_s, l_s = refs
     else:
         acc_in, m_in, l_in, acc_out, m_out, l_out, acc_s, m_s, l_s = refs
@@ -176,13 +199,17 @@ def _flash_kernel(plan: _Plan, offs, q_ref, k_ref, v_ref, *refs):
 
     @pl.when(t == plan.steps - 1)
     def _():
-        acc_out[0] = acc_s[...].astype(acc_out.dtype)
-        m_out[0] = m_s[...]
-        l_out[0] = l_s[...]
+        if plan.finish:
+            # FinalizeAttn's own float32 division, rounded once
+            o_out[0] = (acc_s[...] / l_s[...]).astype(o_out.dtype)
+        else:
+            acc_out[0] = acc_s[...].astype(acc_out.dtype)
+            m_out[0] = m_s[...]
+            l_out[0] = l_s[...]
 
 
 def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
-           window, interpret):
+           window, interpret, finish=False, o=None, o_row0=0):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if window is not None and not causal:
@@ -197,6 +224,11 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
     if nkv % bkv:
         raise ValueError(f"{nkv} K/V rows in tiles of {bkv}")
     init = acc is None
+    if finish and not init:
+        raise ValueError("a call that finishes its rows opens their state: "
+                         "it is handed no acc, m and l")
+    if o is not None and not finish:
+        raise ValueError("O is written by a call that finishes its rows")
     # tile the (row-independent) update over query tiles so VMEM holds one
     # q/state tile + one K/V tile, never all n queries at once; ragged n is
     # padded up to the tile (rows are independent, pad rows stay finite:
@@ -210,7 +242,7 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
         q = jnp.pad(q, padw)
         state = tuple(jnp.pad(t, padw) for t in state)
     plan = _Plan(float(scale), bq, bkv, nkv // bkv, nkv // bkv, bool(causal),
-                 None if window is None else int(window), init)
+                 None if window is None else int(window), init, bool(finish))
     if plan.causal:
         spans = [visible_tiles(plan, q_pos + j * bq, k_pos, max, min)
                  for j in range(np_ // bq)]
@@ -226,7 +258,28 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
     kvblk = pl.BlockSpec(
         (1, bkv, d), lambda i, j, t, offs: (i // group, kv_tile(j, t, offs), 0))
     operands = (q, k, v) + state
-    st = out_struct((h, np_, d), jnp.float32, *operands)
+    in_specs = [qblk, kvblk, kvblk] + [qblk] * len(state)
+    aliases = {}
+    # O in place where this call's query tiles are tiles of O: O is an
+    # aliased operand nobody fetches (every tile written is written whole),
+    # the output's index map walks the call's tiles of it, and the rows of
+    # other calls are never touched
+    in_place = o is not None and o_row0 % bq == 0 and not pad
+    if in_place:
+        tile0 = o_row0 // bq
+        operands += (o,)
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        aliases = {len(operands): 0}  # operand 0 is the positions
+        out_specs = [pl.BlockSpec(
+            (1, bq, d), lambda i, j, t, offs: (i, tile0 + j, 0))]
+        out_shape = [out_struct(o.shape, o.dtype, *operands)]
+    elif finish:
+        out_specs = [qblk]
+        out_shape = [out_struct(
+            (h, np_, d), q.dtype if o is None else o.dtype, *operands)]
+    else:
+        out_specs = [qblk] * 3
+        out_shape = [out_struct((h, np_, d), jnp.float32, *operands)] * 3
     outs = pl.pallas_call(
         functools.partial(_flash_kernel, plan),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -234,20 +287,27 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
             # kv innermost and strictly sequential: the VMEM scratch state
             # carries across the kv steps of one (head, q-tile)
             grid=(h, np_ // bq, plan.steps),
-            in_specs=[qblk, kvblk, kvblk] + [qblk] * len(state),
-            out_specs=[qblk, qblk, qblk],
+            in_specs=in_specs,
+            out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)] * 3,
         ),
-        out_shape=[st, st, st],
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")
         ),
         name=name,
         interpret=interpret,
     )(jnp.asarray([q_pos, k_pos], jnp.int32), *operands)
+    if in_place:
+        return outs[0]
     if pad:
-        outs = [o[:, :n] for o in outs]
-    return tuple(outs)
+        outs = [t[:, :n] for t in outs]
+    if not finish:
+        return tuple(outs)
+    if o is None:
+        return outs[0]
+    return jax.lax.dynamic_update_slice_in_dim(o, outs[0], o_row0, 1)
 
 
 _STATIC = ("scale", "bkv", "q_pos", "k_pos", "causal", "window", "interpret")
@@ -282,7 +342,7 @@ def attn_block_pallas(
                   k_pos, causal, window, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC)
+@functools.partial(jax.jit, static_argnames=_STATIC + ("finish", "o_row0"))
 def attn_fused_pallas(
     q: jax.Array,
     k: jax.Array,
@@ -298,7 +358,10 @@ def attn_fused_pallas(
     causal: bool = False,
     window: Optional[int] = None,
     interpret: Optional[bool] = None,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    finish: bool = False,
+    o: Optional[jax.Array] = None,
+    o_row0: int = 0,
+):
     """Fold a whole K/V range into the online-softmax state in ONE kernel —
     the fused alternative to chaining :func:`attn_block_pallas` per block.
 
@@ -308,10 +371,17 @@ def attn_fused_pallas(
     so the chain is HBM-state-bound at 66.5% MFU while the roofline says
     compute-bound.  Keeping the state in VMEM scratch across the kv grid
     dimension (strictly sequential, pinned "arbitrary") cuts state traffic
-    to one read + one write per q-tile (one write with ``acc=None``).
+    to one read + one write per q-tile (one write with ``acc=None``, none
+    with ``finish``).
 
     Shapes as :func:`attn_block_pallas`, with nkv % bkv == 0.  Under a mask
     each q-tile walks only the K/V tiles that hold a key it can see.
+
+    Returns ``(acc', m', l')``; with ``finish=True`` (and ``acc``, ``m``,
+    ``l`` ``None``) O instead: ``(acc' / l')`` as fresh ``(h, n, d)`` rows
+    in q's dtype, or, handed ``o`` ``(h, N, d)``, ``o`` with rows ``o_row0
+    .. o_row0 + n`` written and every other row as it came (the module's
+    head says how).  Every row has to see a key in the range.
     """
     return _flash("attn_fused", q, k, v, acc, m, l, scale, Q_TILE, bkv, q_pos,
-                  k_pos, causal, window, interpret)
+                  k_pos, causal, window, interpret, finish, o, o_row0)

@@ -200,6 +200,130 @@ def test_folds_that_sit_alike_under_the_mask_are_one_traced_call(kind, kinds):
     assert len({id(c) for c in calls}) == kinds
 
 
+def drive_blocks(graph, plat, order, fused):
+    """The schedule that takes the query blocks' vertices in ``order``,
+    the blocks in ``fused`` on the fused kernel and the others on chains
+    of XLA folds."""
+    rank = {f".q{i}.": r for r, i in enumerate(order)}
+
+    def key(d):
+        name = d.op.name()
+        r = next((r for tag, r in rank.items() if tag in name), -1)
+        if isinstance(d, ChooseOp):
+            block = next(i for i in order if f".q{i}." in name)
+            want = ".fused" if block in fused else (".chain", ".xla")
+            return (r, not d.choice.name().endswith(want))
+        return (r, False)
+
+    st = State(graph)
+    while not st.is_terminal():
+        st = st.apply(min(st.get_decisions(plat), key=key))
+    return st.sequence
+
+
+def test_four_writers_of_one_o_give_the_reference_in_every_order():
+    """A layer's four vertices write one buffer, O, in disjoint rows, with
+    no edge between them: two fused kernels that write their rows in place
+    and two chains whose finalisers put theirs in.  Each order of the four
+    is sound and gives the dense reference's O."""
+    import itertools
+
+    args = replace(ARGS, n_devices=8, q_block=16)  # 64 positions, 4 blocks
+    g, plat, ex, bufs = layer(args, impl_choice=True, fused_choice=True)
+    want = np.asarray(attention(bufs["Q.L0"], bufs["K.L0"], bufs["V.L0"],
+                                True, args.window))
+    verify = ScheduleVerifier(g)
+    seen = set()
+    for order in itertools.permutations(range(4)):
+        seq = drive_blocks(g, plat, order, fused={1, 2})
+        assert verify(seq).ok
+        names = [op.name() for op in seq]
+        writers = [n for n in names
+                   if n.endswith((".fused", "attn_finalize"))]
+        assert [int(n.split(".q")[1][0]) for n in writers] == list(order)
+        seen.add(tuple(writers))
+        out = ex.program(seq)(ex.init_bufs)  # op by op: no program compiled
+        np.testing.assert_allclose(np.asarray(out["O.L0"]), want,
+                                   rtol=2e-5, atol=2e-6)
+    assert len(seen) == 24
+
+
+def test_bf16_fused_vertex_finishes_its_rows_in_o_s_dtype():
+    """A float32 layer's ``fused_bf16`` entry casts Q, K and V for the MXU;
+    the rows it writes are O's own float32, a bfloat16 rounding of the
+    operands away from the reference."""
+    g, plat, ex, bufs = layer(ARGS, impl_choice=True, fused_choice=True)
+    seq = drive(g, plat, (".fused_bf16",))
+    assert sum(op.name().endswith(".fused_bf16") for op in seq) == 3
+    out = ex.program(seq)(ex.init_bufs)
+    assert out["O.L0"].dtype == jnp.float32
+    want = attention(bufs["Q.L0"], bufs["K.L0"], bufs["V.L0"], True,
+                     ARGS.window)
+    gap = np.abs(np.asarray(out["O.L0"]) - np.asarray(want)).max()
+    assert 1e-4 < gap < 3e-2
+
+
+def period_of_four():
+    """A period shaped as the benchmark cell's at toy widths: three window
+    layers and a full one, four query blocks a layer."""
+    full = RingAttnArgs(n_devices=8, seq_local=8, head_dim=8, heads=4,
+                        kv_heads=2, causal=True, q_block=16)
+    win = replace(full, window=WINDOW)
+    layers = [("L0", win), ("L1", win), ("L2", win), ("L3", full)]
+    bufs = {}
+    for tag, a in layers:
+        bufs.update(make_blocked_buffers(a, seed=1, layer=tag)[0])
+    g = period_graph(layers, impl_choice=True, fused_choice=True)
+    plat = Platform.make_n_lanes(2)
+    ex = TraceExecutor(plat, {k: jnp.asarray(v) for k, v in bufs.items()})
+    return g, plat, ex, [tag + "." for tag, _ in layers]
+
+
+@pytest.mark.parametrize("which,finalisers,finishes", [
+    ("start", 0, 16), ("naive", 16, 0)])
+def test_who_finishes_the_rows_of_the_cell_s_two_programs(
+        which, finalisers, finishes):
+    """The climb's start point (every query block on the fused kernel)
+    holds no ``FinalizeAttn``, writes no state buffer and counts 16
+    ``attn.fused_finishes`` a traced body; naive (every block a chain of
+    kernel folds) holds four finalisers a layer and counts none."""
+    from tenzing_tpu.bench.workloads import attn_fused_prefer
+    from tenzing_tpu.core.operation import unbound
+    from tenzing_tpu.models.ring_attention import FinalizeAttn
+    from tenzing_tpu.solve.local import drive as drive_policy, phase_policy
+
+    g, plat, ex, phases = period_of_four()
+    if which == "start":
+        seq, _ = drive_policy(g, plat, phase_policy(plat, phases,
+                                                    attn_fused_prefer))
+    else:
+        seq = drive(g, Platform.make_n_lanes(1), (".chain", ".pallas"))
+    assert ScheduleVerifier(g)(seq).ok
+    fins = [op for op in map(unbound, seq) if isinstance(op, FinalizeAttn)]
+    assert len(fins) == finalisers
+    assert not any(f.fusible() for f in fins)
+    state = {n for n in ex.init_bufs if n.split(".")[0] in (
+        "acc", "m_run", "l_run")}
+    assert len(state) == 12
+    written = {w for op in seq for w in op.writes()}
+    assert bool(written & state) == (which == "naive")
+    reg = MetricsRegistry()
+    prev = set_metrics(reg)
+    try:
+        jaxpr, out = jax.make_jaxpr(ex.program(seq), return_shape=True)(
+            ex.init_bufs)
+    finally:
+        set_metrics(prev)
+    assert reg.counter("attn.fused_finishes").value == finishes
+    # a dict's leaves are in the order of its sorted keys: which buffers
+    # leave the traced body as the very variable they entered it
+    came = dict(zip(sorted(ex.init_bufs), jaxpr.jaxpr.invars))
+    left = dict(zip(sorted(out), jaxpr.jaxpr.outvars))
+    untouched = {n for n in left if left[n] is came[n]}
+    assert {n for n in left if n.startswith("O.")}.isdisjoint(untouched)
+    assert (state <= untouched) == (which == "start")
+
+
 def test_defaults_trace_to_the_jaxpr_they_had():
     """``RingAttnArgs()``'s defaults (one head group, no mask, no query
     blocks) are the shape the module had before PR 33: the program of the
@@ -234,7 +358,8 @@ def test_menus_drop_the_entries_that_coincide_by_dtype():
 def test_plan_at_the_cell_s_blocks():
     """32k tokens in K/V blocks of 2048 and query blocks of 4096: a chain
     has 2 to 16 folds in the full layer and 2 or 3 in a window layer; a
-    period is 32 vertices and four finalisers."""
+    period is 32 vertices, each of which finishes its own rows of O (a
+    fused vertex in the kernel, a chain with a finaliser of its own)."""
     full = RingAttnArgs(n_devices=16, seq_local=2048, heads=32, kv_heads=4,
                         causal=True, q_block=4096, dtype="bfloat16")
     win = replace(full, window=2048)
@@ -246,12 +371,17 @@ def test_plan_at_the_cell_s_blocks():
     seq = drive(g, Platform.make_n_lanes(1), (".fused",))
     names = [op.name() for op in seq]
     assert sum(n.endswith(".fused") for n in names) == 32
-    assert sum(n.endswith("attn_finalize") for n in names) == 4
-    # the layers in the order of the residual stream
-    ends = [names.index(f"L{i}.attn_finalize") for i in range(4)]
-    starts = [min(i for i, n in enumerate(names) if n.startswith(f"L{l}."))
+    assert not any(n.endswith("attn_finalize") for n in names)
+    chains = [op.name() for op in drive(g, Platform.make_n_lanes(1),
+                                        (".chain", ".pallas"))]
+    assert sum(n.endswith("attn_finalize") for n in chains) == 32
+    assert "L3.q7.attn_finalize" in chains
+    # the layers in the order of the residual stream: every vertex of a
+    # layer, its blocks' finalisers included, before any of the next
+    for order in (names, chains):
+        at = [[i for i, n in enumerate(order) if n.startswith(f"L{l}.")]
               for l in range(4)]
-    assert all(e < s for e, s in zip(ends, starts[1:]))
+        assert all(max(a) < min(b) for a, b in zip(at, at[1:]))
 
 
 def test_attention_cost_counts_pairs_under_the_mask():

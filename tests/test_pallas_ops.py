@@ -181,6 +181,69 @@ def test_fused_attention_kernel_runs_with_compiler_params():
     np.testing.assert_allclose(o, p @ np.asarray(v), rtol=1e-5, atol=1e-5)
 
 
+# (first row of the query block in O, its rows): whole tiles of 8 in place
+# (at row 0, inside O, the short last block), then the two cases that land
+# by dynamic_update_slice: a ragged count (its pad rows sliced off) and a
+# block that starts inside a tile
+FINISH_BLOCKS = {"at_row_0": (0, 16), "inside_o": (16, 16),
+                 "last_short_tile": (36, 4), "ragged_rows": (8, 20),
+                 "off_the_tile": (20, 16)}
+
+
+@pytest.mark.parametrize("block", list(FINISH_BLOCKS))
+@pytest.mark.parametrize("window", [None, 12], ids=["full", "window"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_finishing_kernel_is_the_state_then_the_division(
+        dtype, window, block, monkeypatch):
+    """``attn_fused_pallas(finish=True)`` writes, to the last bit, what the
+    kernel that hands on its state followed by ``(acc / l).astype`` gives:
+    as fresh rows, and into its own rows of an O whose other rows keep what
+    they held.  Four query heads over two K/V heads."""
+    import jax
+
+    from tenzing_tpu.ops import attention_pallas
+    from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+
+    monkeypatch.setattr(attention_pallas, "Q_TILE", 8)
+    q0, rows = FINISH_BLOCKS[block]
+    n, d = 40, 8
+    rng = np.random.default_rng(q0 + rows)
+    big_q = jnp.asarray(rng.standard_normal((4, n, d)), dtype)
+    k, v = (jnp.asarray(rng.standard_normal((2, n, d)), dtype)
+            for _ in range(2))
+    q = big_q[:, q0:q0 + rows]
+    mask = dict(bkv=4, q_pos=q0, causal=True, window=window)
+    acc, m, l = attn_fused_pallas(q, k, v, None, None, None, 0.35, **mask)
+    want = (acc / l).astype(dtype)
+    fresh = attn_fused_pallas(q, k, v, None, None, None, 0.35, finish=True,
+                              **mask)
+    assert fresh.dtype == want.dtype and fresh.shape == want.shape
+    assert np.array_equal(np.asarray(fresh, np.float32),
+                          np.asarray(want, np.float32))
+    o = jnp.full((4, n, d), 7.0, dtype)  # the sentinel
+    got = attn_fused_pallas(q, k, v, None, None, None, 0.35, finish=True,
+                            o=o, o_row0=q0, **mask)
+    assert got.dtype == o.dtype
+    assert np.array_equal(
+        np.asarray(got, np.float32),
+        np.asarray(o.at[:, q0:q0 + rows].set(want), np.float32))
+    traced = str(jax.make_jaxpr(lambda o: attn_fused_pallas(
+        q, k, v, None, None, None, 0.35, finish=True, o=o, o_row0=q0,
+        **mask))(o))
+    in_place = block in ("at_row_0", "inside_o", "last_short_tile")
+    assert ("dynamic_update_slice" in traced) != in_place
+
+
+def test_finishing_kernel_refuses_a_state_it_would_not_hand_on():
+    from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+
+    q = jnp.zeros((1, 8, 8))
+    with pytest.raises(ValueError, match="opens their state"):
+        attn_fused_pallas(q, q, q, q, q, q, 1.0, finish=True)
+    with pytest.raises(ValueError, match="finishes its rows"):
+        attn_fused_pallas(q, q, q, None, None, None, 1.0, o=q)
+
+
 def test_halo_and_rdma_modules_build_their_compiler_params():
     """Module-level pltpu.CompilerParams construction: every field the
     kernels pass is one the installed class knows (none is dropped)."""

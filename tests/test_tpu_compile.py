@@ -309,6 +309,101 @@ def test_attention_block(one_chip):
         q, kv, kv, q, q, q, d ** -0.5, interpret=False).compile())
 
 
+# trinity-attn32k.climb: 16 384 tokens, 32 query heads over 4 K/V heads of
+# 128, bfloat16, query blocks of 4096; (first row of the query block, first
+# key of its visible range, keys in it, window) of the full layer's last
+# block and of a window layer's third
+CELL_ATTN = dict(n=16 * 1024, heads=32, kv_heads=4, d=128, rows=4096)
+FINISHING = {"full": (12288, 0, 16384, None),
+             "window": (8192, 6144, 6144, 2048)}
+
+
+@pytest.mark.parametrize("kind", list(FINISHING))
+def test_attention_fused_finishes_its_rows_of_o(one_chip, kind):
+    """The fused kernel that writes O (ISSUE 34) at the attention cell's
+    shapes: Mosaic takes it with O an aliased operand left in place (no
+    fetch of it) and the output's tiles offset by ``q0 // 512``."""
+    from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+
+    c = CELL_ATTN
+    q0, k0, keys, window = FINISHING[kind]
+    bf = jnp.bfloat16
+    q = _sds((c["heads"], c["rows"], c["d"]), bf, one_chip)
+    kv = _sds((c["kv_heads"], keys, c["d"]), bf, one_chip)
+    o = _sds((c["heads"], c["n"], c["d"]), bf, one_chip)
+    compiled = attn_fused_pallas.lower(
+        q, kv, kv, None, None, None, c["d"] ** -0.5, bkv=1024,
+        q_pos=q0 - k0, causal=True, window=window, interpret=False,
+        finish=True, o=o, o_row0=q0).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    call = next(l for l in text.splitlines() if "tpu_custom_call" in l)
+    assert " = bf16[32,16384,128]" in call  # one output: O, no state
+    assert "output_to_operand_aliasing={{}: (4, {})}" in call
+
+
+@pytest.mark.parametrize("which,kernels", [("start", 16), ("naive", 53)])
+def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
+                                                 which, kernels):
+    """The repeat-n program of ``trinity-attn32k.climb``'s start point
+    (every query block on the fused kernel) and of its naive (chains of
+    ``attn_fold`` kernels) as the TPU compiler leaves them.  Inside the
+    start point's ``while`` body a layer's O is the result of its four
+    ``attn_fused`` calls and of nothing else: no copy of it, no
+    concatenate, no division, and no float32 state leaves a kernel.
+    Naive's chains keep their state and finish each block's rows with an
+    update of O in place."""
+    from benchmarks.builders.attn_period import unfused_prefer
+    from tenzing_tpu.bench.workloads import attn_fused_prefer
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.ring_attention import (
+        RingAttnArgs,
+        blocked_buffer_shapes,
+        period_graph,
+    )
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+    from tenzing_tpu.runtime.executor import TraceExecutor
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    # the kernels pick the interpreter by the process's default backend,
+    # which stays ``cpu`` during an AOT compile
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    c = CELL_ATTN
+    layers = [(f"L{i}", RingAttnArgs(
+        n_devices=c["n"] // 2048, seq_local=2048, head_dim=c["d"],
+        dtype="bfloat16", heads=c["heads"], kv_heads=c["kv_heads"],
+        causal=True, window=w, q_block=c["rows"]))
+        for i, w in enumerate((2048, 2048, 2048, None))]
+    graph = period_graph(layers, impl_choice=True, fused_choice=True)
+    bufs = {name: _sds(shape, jnp.dtype(dtype), one_chip)
+            for tag, a in layers
+            for name, (shape, dtype) in blocked_buffer_shapes(a, tag).items()}
+    phases = [tag + "." for tag, _ in layers]
+
+    plat = Platform.make_n_lanes(2 if which == "start" else 1)
+    seq, _ = drive(graph, plat, phase_policy(
+        plat, phases,
+        attn_fused_prefer if which == "start" else unfused_prefer))
+    ex = TraceExecutor(plat, bufs)
+    compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(
+        bufs, _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels
+    whole_o = loop_ops_of_shape(text, "bf16[32,16384,128]")
+    state = loop_ops_of_shape(text, "f32[32,4096,128]")
+    if which == "start":
+        assert len(whole_o) == 16
+        assert all(o.opcode == "custom-call"
+                   and o.name.startswith("attn_fused") for o in whole_o)
+        assert not state
+        assert "divide" not in text
+    else:
+        assert len(whole_o) == 16  # a block's rows put into its layer's O
+        assert all("dynamic-update-slice" in o.fused
+                   or o.opcode == "dynamic-update-slice" for o in whole_o)
+        assert state
+
+
 def test_spmv_ell(one_chip):
     """spmv: the 150000-row local ELL slab (width 26, make_spmv_buffers seed
     0; transposed ``(w, m)`` as the buffers hold it) against the largest x
