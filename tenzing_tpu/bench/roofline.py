@@ -146,6 +146,30 @@ def attention_cost(batch: int, seq: int, head_dim: int, bytes_per_el: int = 4,
     return Cost(flops=flops, hbm_bytes=hbm)
 
 
+def latent_decode_cost(lens, heads: int, rank: int, rope: int, v_dim: int,
+                       layers: int = 1, nope: int = 128,
+                       bytes_per_el: int = 2) -> Cost:
+    """One decode step of latent attention (models/latent_attention.py):
+    one new token for each sequence, sequence b with ``lens[b]`` cached
+    tokens, through ``layers`` layers.  A visible key (``L_b + 1`` a
+    sequence) costs each head ``2 (rank + rope)`` for its score and ``2
+    rank`` for its share of ``o_lat``; the absorb and up-project einsums
+    ``2 nope rank`` and ``2 rank v_dim`` a (sequence, head).  HBM, a floor:
+    every visible key's ``rank + rope`` wide row read once (V is a view of
+    it), the appended rows written, inputs and weights read and ``o``
+    written once.  At DeepSeek-V3's widths a key is 278 528 FLOPs for 1 152
+    bytes, 241.8 FLOP a byte: a v5e's ridge (240.5)."""
+    batch, width = len(lens), rank + rope
+    keys = sum(int(n) + 1 for n in lens)
+    flops = 2.0 * heads * keys * (width + rank) + 2.0 * batch * heads * (
+        nope * rank + rank * v_dim)
+    els = (keys * width + batch * width + batch * heads * (nope + rope)
+           + batch * width + heads * (nope * rank + rank * v_dim)
+           + batch * heads * v_dim)
+    return Cost(flops=layers * flops,
+                hbm_bytes=float(layers * bytes_per_el * els))
+
+
 def moe_cost(tokens: int, d_model: int, d_ff: int, bytes_per_el: int = 4,
              staged: bool = False, n_experts: int = 8) -> Cost:
     """Top-1 routed MoE layer: every token through one gelu MLP —

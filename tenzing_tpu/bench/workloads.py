@@ -31,6 +31,14 @@ The workloads (``DriverRequest.workload`` / the CLI's ``--workload``):
   first fold writing the softmax state, so that an iteration is
   idempotent); this row runs its unmasked single-group shape (8k context,
   8 blocks of 1024), the benchmark's ``trinity-attn32k`` a model's layers.
+* ``mla_decode``: one decode step of latent attention (MLA) over a paged
+  latent cache (``models/latent_attention.py``): per layer an append into
+  each sequence's open page, the absorb einsum, one engine menu a group of
+  sequences (one ``mla_decode`` kernel over the group's whole cache against
+  a split-K chain of ``mla_fold`` links) and the up-projection.  This row
+  runs DeepSeek-V3's widths on eight short sequences (toy widths with
+  ``--smoke``), the benchmark's ``dsv3-mla-decode`` 16 sequences of 8k to
+  128k through four layers.
 * ``moe``: single-chip MoE dispatch/combine pipeline — routed tokens staged
   through async host round-trip DMAs to the resident experts (the
   expert-parallel network-hop analog), searched over order x lane x
@@ -542,6 +550,68 @@ def _attn_incumbents(req, g, wargs, plat):
     return inc
 
 
+# -- mla_decode ---------------------------------------------------------------
+
+# cached tokens a sequence: eight sequences, none a multiple of the page
+_MLA_SMOKE_LENS = (3, 9, 13, 17, 26, 31, 44, 61)
+_MLA_LENS = (1100, 1900, 2700, 3300, 4200, 5100, 6600, 8000)
+
+
+def _mla_dims(req):
+    """The step's sizes as plain numbers (the fingerprint's arithmetic
+    imports no model)."""
+    if req.smoke:
+        return dict(lens=_MLA_SMOKE_LENS, heads=4, rank=16, rope=8, nope=8,
+                    v_dim=8, page=8, groups=4, fold_pages=2, dtype="float32")
+    # DeepSeek-V3's widths on a toy batch (the benchmark's dsv3-mla-decode
+    # runs 16 sequences of 8k to 128k through four layers)
+    return dict(lens=_MLA_LENS, heads=128, rank=512, rope=64, nope=128,
+                v_dim=128, page=512, groups=4, fold_pages=4,
+                dtype="bfloat16")
+
+
+def _mla_args(req):
+    from tenzing_tpu.models.latent_attention import LatentDecodeArgs
+    from tenzing_tpu.models.latent_attention_reference import yarn_scale
+
+    d = _mla_dims(req)
+    return LatentDecodeArgs(scale=yarn_scale(d["nope"], d["rope"]), **d)
+
+
+def _mla_shape(req):
+    d = _mla_dims(req)
+    return {"sequences": len(d["lens"]), "keys": sum(d["lens"]) + len(
+        d["lens"]), "page": d["page"], "heads": d["heads"],
+        "rank": d["rank"], "rope": d["rope"], "groups": d["groups"]}
+
+
+def _mla_parts(req):
+    from tenzing_tpu.models.latent_attention import (
+        decode_graph,
+        make_decode_buffers,
+    )
+
+    a = _mla_args(req)
+    # the kernel menu of a link (XLA gather and einsums against the kernel)
+    # only where a chip compiles it, as the halo's
+    g = decode_graph(a, ("L0",), impl_choice=not req.smoke)
+    return g, make_decode_buffers(a, ("L0",), seed=0), a
+
+
+def build_mla_decode(args):
+    import jax.numpy as jnp
+
+    g, bufs, a = _mla_parts(args)
+    bufs = {k: jnp.asarray(v) for k, v in bufs.items()}
+    return g, bufs, metric_for("mla_decode", args), a
+
+
+def _mla_cost(built):
+    a = built[3]
+    return roofline.latent_decode_cost(a.lens, a.heads, a.rank, a.rope,
+                                       a.v_dim, nope=a.nope)
+
+
 # -- the table ----------------------------------------------------------------
 
 @dataclass
@@ -651,6 +721,12 @@ WORKLOADS: Dict[str, Workload] = {
             4 * 16 if req.smoke else 8 * 1024),
         cost=_attn_cost, incumbents=_attn_incumbents,
         seed_csv="experiments/attn_search_tpu_r[45]*.csv"),
+    "mla_decode": Workload(
+        build=build_mla_decode, graph=_device_free(_mla_parts),
+        shape=_mla_shape,
+        metric=lambda req: "mla_decode_pct50_searched_k%d" % (
+            _mla_shape(req)["keys"]),
+        cost=_mla_cost, phases=lambda: ("L0.",)),
     "moe": Workload(
         build=build_moe, graph=_device_free(_moe_parts), shape=_moe_shape,
         metric=lambda req: "moe_pipe_pct50_searched_t%d" % (

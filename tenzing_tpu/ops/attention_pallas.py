@@ -11,13 +11,16 @@ online-softmax state:
     l'    = l * alpha + rowsum(p)
     acc'  = acc * alpha + p V      (MXU)
 
-One kernel body, two entry points that differ in what they are handed and in
+One kernel body, four entry points that differ in what they are handed and in
 the name the device trace shows:
 
 * :func:`attn_block_pallas` (``attn_fold``): one K/V block, the state read
   from HBM and written back — one link of a per-block chain;
 * :func:`attn_fused_pallas` (``attn_fused``): a whole K/V range, the state
-  in VMEM scratch across it.
+  in VMEM scratch across it;
+* :func:`mla_decode_pallas` (``mla_decode``) and :func:`mla_fold_pallas`
+  (``mla_fold``): the same two over a **paged latent cache** (one decode
+  step of latent attention, models/latent_attention.py), see below.
 
 and three shapes of output:
 
@@ -63,6 +66,31 @@ not -inf, and a masked ``p`` is set to zero, not to ``exp(0)``.
 state instead of reading one: the first fold of a chain, which makes an
 iteration leave the state one iteration leaves.
 
+Paged (:func:`_flash_paged`; what the operands handed decide, no switch):
+
+* **V is a view of K**: no V operand; the second product runs over K's
+  first ``v_dim`` columns (a latent cache row is ``[c ; k_rope]`` and V is
+  ``c``), so the cache is read once.  The state and O are ``v_dim`` wide,
+  Q and K ``d``.
+* **A key limit per leading index**: the leading index is a sequence, its
+  rows (heads) all sit at one position, and ``lens[b]`` keys of it are
+  visible (a scalar-prefetched vector).  The causal comparison by row does
+  not apply; the tile that holds the last visible key masks by column.
+* **K through a block table** (``table[b, j]``, scalar-prefetched): tile j
+  of sequence b is ``pool[table[b, j]]`` while it is sealed (whole, every
+  key visible: no mask built), and ``k_open[b]``, a second K operand, where
+  the last visible key lies (the one page an append writes).  Steps of the
+  rectangular grid past a sequence's last tile hold the index they had:
+  they fetch nothing new and compute nothing.
+* **A page holds its keys as columns**, ``(d, page)``: K^T as it lies.  The
+  TPU runtime lays a bfloat16 array out with its 128-multiple axis minor,
+  so a ``(page, 576)`` page would reach the kernel through a copy of the
+  whole pool on every call; ``(576, page)`` arrives as it is.  The first
+  product is then plain and the second contracts the keys of both operands.
+* The grid's leading axis walks ``rows`` sequences from ``lead0`` of Q,
+  ``lens``, ``table``, ``k_open`` and O, which are the whole batch's: no
+  slice of them is made for a group.
+
 ``interpret=True`` (automatic off-TPU) runs the same kernel in the Pallas
 interpreter for CPU tests.
 """
@@ -98,6 +126,13 @@ class _Plan:
     window: Optional[int]
     init: bool
     finish: bool = False  # write O = acc / l and no state (needs init)
+    # a paged latent cache (``_flash_paged``): V is K's first ``dv`` columns
+    # (no V operand), the leading index is a sequence with its own key limit,
+    # K tiles come through a block table, and row 0 of the grid's leading
+    # axis is sequence ``lead0`` of q, the limits, the table and O
+    dv: Optional[int] = None
+    paged: bool = False
+    lead0: int = 0
 
 
 def visible_tiles(plan: _Plan, q_lo, k_pos, larger=jnp.maximum,
@@ -130,12 +165,19 @@ def computed_pairs(rows: int, keys: int, q_pos: int, k_pos: int,
     return tiles * bq * bkv
 
 
-def _flash_kernel(plan: _Plan, offs, q_ref, k_ref, v_ref, *refs):
+def _flash_kernel(plan: _Plan, offs, *refs):
     """One (head, q-tile, kv-step) grid step: state lives in VMEM scratch
     across the kv dimension (innermost, strictly sequential), so acc/m/l
     touch HBM once in (not at all with ``init``) and once out per q-tile
     (not at all with ``finish``: the tile's last step divides and writes its
-    rows of O)."""
+    rows of O).  Paged: the key limits and the block table follow ``offs``
+    as scalar operands, and K comes as a tile of the sealed pool and the
+    sequence's open page."""
+    if plan.paged:
+        lens, table, q_ref, k_ref, ko_ref, *refs = refs
+        v_ref = None
+    else:
+        q_ref, k_ref, v_ref, *refs = refs
     if plan.finish:
         # an aliased O comes first among the refs, unfetched: never read
         o_out, acc_s, m_s, l_s = refs[-4:]
@@ -156,17 +198,36 @@ def _flash_kernel(plan: _Plan, offs, q_ref, k_ref, v_ref, *refs):
             m_s[...] = m_in[0]
             l_s[...] = l_in[0]
 
-    q_lo = offs[0] + j * plan.bq
-    first, last = visible_tiles(plan, q_lo, offs[1])
-    k_lo = offs[1] + (first + t) * plan.bkv
+    if plan.paged:
+        # the keys sequence b sees, and the tile of this step: the tile
+        # that holds the last visible key is the open page, those before
+        # it are sealed (whole, every key visible)
+        limit = lens[plan.lead0 + pl.program_id(0)]
+        tile = offs[1] // plan.bkv + t
+        k_lo = tile * plan.bkv
+    else:
+        q_lo = offs[0] + j * plan.bq
+        first, last = visible_tiles(plan, q_lo, offs[1])
+        k_lo = offs[1] + (first + t) * plan.bkv
 
-    def fold(edge: bool):
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+    def fold(edge: bool, k_ref=k_ref):
+        if plan.paged:
+            # a page holds its keys as columns, (d, bkv): K^T as it lies,
+            # and V^T its first dv rows
+            q, k, v = q_ref[0], k_ref[0], k_ref[0, :plan.dv, :]
+        else:
+            q, k, v = q_ref[0], k_ref[0], v_ref[0]
         m_old, l_old, acc_old = m_s[...], l_s[...], acc_s[...]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (0 if plan.paged else 1,)), ((), ())),
+            preferred_element_type=jnp.float32
         ) * plan.scale  # (bq, bkv)
-        if edge:
+        if edge and plan.paged:
+            # every row of a sequence sits at one position: a key limit
+            seen = k_lo + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) < limit
+            s = jnp.where(seen, s, NEG)
+        elif edge:
             qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             seen = kpos <= qpos
@@ -182,12 +243,22 @@ def _flash_kernel(plan: _Plan, offs, q_ref, k_ref, v_ref, *refs):
         l_s[...] = l_old * alpha + jnp.broadcast_to(
             jnp.sum(p, axis=1, keepdims=True), l_old.shape
         )
-        acc_s[...] = acc_old * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
+        acc_new = acc_old * alpha
+        if plan.paged:
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            pv = jnp.dot(p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        acc_s[...] = acc_new + pv
         m_s[...] = m_new
 
-    if plan.causal:
+    if plan.paged:
+        open_tile = (limit - 1) // plan.bkv
+        pl.when(tile < open_tile)(lambda: fold(False))
+        pl.when(tile == open_tile)(lambda: fold(True, ko_ref))
+    elif plan.causal:
         live = first + t <= last
         edge = k_lo + plan.bkv - 1 > q_lo
         if plan.window is not None:
@@ -385,3 +456,139 @@ def attn_fused_pallas(
     """
     return _flash("attn_fused", q, k, v, acc, m, l, scale, Q_TILE, bkv, q_pos,
                   k_pos, causal, window, interpret, finish, o, o_row0)
+
+
+# -- a paged latent cache: one decode step ---------------------------------------
+
+
+def paged_tiles(lens, page: int, k_pos: int = 0, span: Optional[int] = None):
+    """Per sequence, the tiles of the key range ``k_pos .. k_pos + span``
+    (to the end without ``span``) that hold a visible key, ``lens`` visible
+    keys each: what a call's grid walks (its extent over keys is the
+    largest) and what the program's ``mla.*`` counters count."""
+    first = k_pos // page
+    out = []
+    for n in lens:
+        last = -(-int(n) // page)  # one past the sequence's open tile
+        if span is not None:
+            last = min(last, first + span // page)
+        out.append(max(0, last - first))
+    return out
+
+
+def _flash_paged(name, q, pool, k_open, lens, table, state, scale, v_dim,
+                 lead0, rows, k_pos, steps, o, interpret):
+    """The kernel body over a paged cache.  ``q`` ``(B, n, d)``: the n rows
+    of every sequence (heads: they sit at one position); ``pool`` ``(pages,
+    d, page)`` sealed pages and ``k_open`` ``(B, d, page)`` each sequence's
+    open page, their keys as columns; ``lens`` ``(B,)`` visible keys;
+    ``table`` ``(B, max_pages)``: tile j of sequence b is ``pool[table[b,
+    j]]`` while ``j < (lens[b] - 1) // page`` and ``k_open[b]`` at that
+    tile, where the last visible key lies.  The grid is ``rows`` sequences
+    from ``lead0`` by ``steps`` tiles from key ``k_pos``; steps past a
+    sequence's last tile fetch nothing new and compute nothing.  V^T is a
+    tile's first ``v_dim`` rows."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    _, n, d = q.shape
+    pages, _, page = pool.shape
+    if k_pos % page:
+        raise ValueError(f"a key range starts at a page: {k_pos} % {page}")
+    if n > Q_TILE:
+        raise ValueError(f"{n} rows a sequence: one query tile holds {Q_TILE}")
+    finish = o is not None
+    init = state is None
+    if finish and not init:
+        raise ValueError("a call that finishes its rows opens their state: "
+                         "it is handed no acc, m and l")
+    plan = _Plan(float(scale), n, page, pages, int(steps), False, None, init,
+                 finish, dv=int(v_dim), paged=True, lead0=int(lead0))
+    max_pages = table.shape[1]
+
+    def seq(i, j, t, *_):
+        return (lead0 + i, 0, 0)
+
+    def sealed(i, j, t, offs, lens, table):
+        # the sealed tile of this step, held at the sequence's last sealed
+        # page once past it (the open page's step and the idle ones fetch
+        # nothing new)
+        b = lead0 + i
+        last = (lens[b] - 1) // page - 1
+        tile = jnp.clip(jnp.minimum(offs[1] // page + t, last), 0,
+                        max_pages - 1)
+        return (table[b, tile], 0, 0)
+
+    stblk = pl.BlockSpec((1, n, v_dim), lambda i, j, t, *_: (i, 0, 0))
+    operands = (q, pool, k_open) + (() if init else tuple(state))
+    in_specs = [pl.BlockSpec((1, n, d), seq),
+                pl.BlockSpec((1, d, page), sealed),
+                pl.BlockSpec((1, d, page), seq)] + [stblk] * (
+                    0 if init else 3)
+    aliases = {}
+    if finish:
+        # O in place, as attn_fused's: aliased, unfetched, the rows of the
+        # other sequences never touched
+        operands += (o,)
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        aliases = {len(operands) + 2: 0}  # three scalar operands lead
+        out_specs = [pl.BlockSpec((1, n, v_dim), seq)]
+        out_shape = [out_struct(o.shape, o.dtype, *operands)]
+    else:
+        out_specs = [stblk] * 3
+        out_shape = [out_struct((rows, n, v_dim), jnp.float32,
+                                *operands)] * 3
+    # two K operands double-buffered, the scores and P of a tile beside them
+    tile_bytes = page * d * pool.dtype.itemsize
+    vmem = min(100 << 20, max(32 << 20, 8 * tile_bytes))
+    outs = pl.pallas_call(
+        functools.partial(_flash_kernel, plan),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(rows, 1, plan.steps),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((n, v_dim), jnp.float32)] * 3,
+        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem,
+        ),
+        name=name,
+        interpret=interpret,
+    )(jnp.asarray([0, k_pos], jnp.int32), lens.astype(jnp.int32),
+      table.astype(jnp.int32), *operands)
+    return outs[0] if finish else tuple(outs)
+
+
+_PAGED_STATIC = ("scale", "v_dim", "lead0", "rows", "steps", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_PAGED_STATIC)
+def mla_decode_pallas(q, pool, k_open, lens, table, o, scale, *, v_dim: int,
+                      lead0: int, rows: int, steps: int,
+                      interpret: Optional[bool] = None):
+    """One decode step's cache read for ``rows`` sequences from ``lead0``,
+    each over its whole cache, in ONE kernel (``mla_decode``): state in
+    VMEM, ``o`` ``(B, n, v_dim)`` returned with those sequences' rows
+    written (float32 ``acc / l``, rounded once) and every other row as it
+    came.  Operands as :func:`_flash_paged`; ``steps`` at least the most
+    tiles a sequence of the group has (:func:`paged_tiles`)."""
+    return _flash_paged("mla_decode", q, pool, k_open, lens, table, None,
+                        scale, v_dim, lead0, rows, 0, steps, o, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=_PAGED_STATIC + ("k_pos",))
+def mla_fold_pallas(q, pool, k_open, lens, table, acc, m, l, scale, *,
+                    v_dim: int, lead0: int, rows: int, k_pos: int,
+                    steps: int, interpret: Optional[bool] = None):
+    """One link of a split-K chain (``mla_fold``): the tiles ``k_pos //
+    page .. + steps`` of every sequence of the group folded into its
+    softmax state ``(rows, n, v_dim)`` float32 through HBM (``None``: from
+    the empty state).  A sequence with no visible key in the range keeps
+    its state.  Returns ``(acc', m', l')``."""
+    state = None if acc is None else (acc, m, l)
+    return _flash_paged("mla_fold", q, pool, k_open, lens, table, state,
+                        scale, v_dim, lead0, rows, k_pos, steps, None,
+                        interpret)

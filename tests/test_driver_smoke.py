@@ -71,6 +71,8 @@ CASES = {
              ["fault"]),
     "attn": (dict(workload="attn", mcts_iters=8),
              "attn_blockwise_pct50_searched_n64", [], ["fault"]),
+    "mla_decode": (dict(workload="mla_decode", mcts_iters=6),
+                   "mla_decode_pct50_searched_k212", [], ["fault"]),
 }
 # two files, so that --dist loadfile gives the runs to two workers: the
 # switches of one workload here, the plain workloads in
@@ -101,6 +103,7 @@ EXTRA = {
     "moe": (["greedy-overlap incumbent: pct50="], "incumbents"),
     "spmv": ([], "incumbents"),
     "attn": ([], "incumbents"),
+    "mla_decode": ([], "incumbents"),
 }
 
 

@@ -1,0 +1,319 @@
+"""One decode step of latent attention over a paged latent cache
+(models/latent_attention.py) against the plain reference
+(models/latent_attention_reference.py), at toy widths on the CPU (Pallas in
+interpret mode).
+
+Eight sequences of unequal length (one shorter than a page, one a page and
+a key, none a multiple of the page) in four groups, pages of 8 tokens laid
+out through a shuffled table, two layers.
+"""
+
+import hashlib
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tenzing_tpu.core.platform import Platform
+from tenzing_tpu.core.state import ChooseOp, State
+from tenzing_tpu.models.latent_attention import (
+    LatentDecodeArgs,
+    MlaEngineChoice,
+    block_table,
+    buffer_shapes,
+    decode_graph,
+    decode_plan,
+    dense_caches,
+    make_decode_buffers,
+)
+from tenzing_tpu.models.latent_attention_reference import (
+    absorbed,
+    published,
+    yarn_scale,
+)
+from tenzing_tpu.obs.metrics import MetricsRegistry, set_metrics
+from tenzing_tpu.ops.attention_pallas import (
+    attn_block_pallas,
+    attn_fused_pallas,
+    paged_tiles,
+)
+from tenzing_tpu.runtime.executor import TraceExecutor
+from tenzing_tpu.verify.soundness import ScheduleVerifier
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LENS = (3, 9, 13, 17, 26, 31, 44, 61)
+ARGS = LatentDecodeArgs(lens=LENS, heads=4, rank=16, rope=8, nope=8, v_dim=8,
+                        scale=yarn_scale(8, 8), page=8, groups=4,
+                        fold_pages=2, dtype="float32")
+LAYERS = ("L0", "L1")
+ENGINES = {"fused": (".fused",), "chain": (".chain", ".pallas"),
+           "xla": (".chain", ".xla")}
+#: the widest row's gap a fault must pass and a sound float32 run stay far
+#: under (the benchmark's limit is for bfloat16 and lies higher)
+ROW_LIMIT = 1e-3
+
+
+def drive(graph, plat, want=()):
+    """The schedule of taking, at every menu, the first entry that ends in
+    one of ``want``, and else the first decision offered."""
+    st = State(graph)
+    while not st.is_terminal():
+        ds = st.get_decisions(plat)
+        pick = None
+        for w in want:
+            pick = pick or next(
+                (d for d in ds if isinstance(d, ChooseOp)
+                 and d.choice.name().endswith(w)), None)
+        st = st.apply(pick or ds[0])
+    return st.sequence
+
+
+def step(args=ARGS, seed=3, table_seed=11, lanes=2):
+    bufs = make_decode_buffers(args, LAYERS, seed, table_seed)
+    g = decode_graph(args, LAYERS, impl_choice=True)
+    plat = Platform.make_n_lanes(lanes)
+    ex = TraceExecutor(plat, {k: jnp.asarray(v) for k, v in bufs.items()})
+    return g, plat, ex, bufs
+
+
+def reference(args, bufs, layer, form=published, **change):
+    """``o`` ``(batch, heads, v_dim)`` of one layer by the plain reference:
+    every sequence's dense cache with its new row put last.  ``change``:
+    ``extra_key`` lets every sequence see the key after its last,
+    ``drop_new`` leaves the new row out."""
+    out = []
+    caches = dense_caches(args, bufs, layer)
+    for b, cache in enumerate(caches):
+        new = np.concatenate([bufs[f"c_new.{layer}"][b],
+                              bufs[f"kr_new.{layer}"][b]])[None]
+        rows = [cache] + ([] if change.get("drop_new") else [new])
+        if change.get("extra_key"):
+            col = args.lens[b] % args.page + 1
+            if col < args.page:  # what the open page holds there
+                rows.append(bufs[f"Copen.{layer}"][b][:, col][None])
+        out.append(form(np.concatenate(rows), bufs[f"q_nope.{layer}"][b],
+                        bufs[f"q_rope.{layer}"][b], bufs[f"W_UK.{layer}"],
+                        bufs[f"W_UV.{layer}"], args.scale))
+    return np.asarray(jnp.stack(out))
+
+
+def widest_row_gap(o, ref):
+    err = np.linalg.norm(o - ref, axis=-1)
+    norm = np.linalg.norm(ref, axis=-1)
+    return float((err / np.maximum(norm, np.median(norm))).max())
+
+
+def test_the_scale_is_the_config_s():
+    assert round(yarn_scale(), 6) == 0.135234
+    assert abs(0.1 * np.log(40) + 1 - 1.36888) < 1e-5
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_absorbed_form_is_the_published_form(seed):
+    """In float32 at ``highest`` the two orders of sums agree to rounding:
+    relative 2e-5 of the largest entry (some 600 float32 sums a value)."""
+    bufs = make_decode_buffers(ARGS, LAYERS, seed, 5)
+    for layer in LAYERS:
+        a = reference(ARGS, bufs, layer, absorbed)
+        p = reference(ARGS, bufs, layer, published)
+        assert np.abs(a - p).max() <= 2e-5 * np.abs(p).max()
+        assert widest_row_gap(a, p) < 2e-5
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_system_matches_the_plain_reference(engine):
+    g, plat, ex, bufs = step()
+    seq = drive(g, plat, ENGINES[engine])
+    assert ScheduleVerifier(g)(seq).ok
+    names = [op.name() for op in seq]
+    assert any(n.endswith(ENGINES[engine][-1]) for n in names)
+    out = ex.run(seq)
+    for layer in LAYERS:
+        want = reference(ARGS, bufs, layer)
+        np.testing.assert_allclose(np.asarray(out[f"o.{layer}"]), want,
+                                   rtol=2e-4, atol=2e-5)
+        assert widest_row_gap(np.asarray(out[f"o.{layer}"]), want) < 1e-4
+        # the appended row, exact, and no other column touched
+        opened = np.array(bufs[f"Copen.{layer}"])
+        for b, n in enumerate(LENS):
+            opened[b, :, n % ARGS.page] = np.concatenate(
+                [bufs[f"c_new.{layer}"][b], bufs[f"kr_new.{layer}"][b]])
+        assert np.array_equal(np.asarray(out[f"Copen.{layer}"]), opened)
+
+
+@pytest.mark.parametrize("fault", ["extra_key", "drop_new", "swapped_row"])
+def test_a_fault_moves_the_widest_row_gap_past_its_limit(fault):
+    """One key past a length let in, the new row left out, two rows of the
+    table swapped: each reads far over what a sound run reads."""
+    g, plat, ex, bufs = step()
+    seq = drive(g, plat, (".fused",))
+    if fault == "swapped_row":
+        table = np.array(bufs["table"])
+        table[[5, 7]] = table[[7, 5]]
+        out = ex.compile(seq)({**ex.init_bufs, "table": jnp.asarray(table)})
+        want = reference(ARGS, bufs, "L0")
+    else:
+        out = ex.run(seq)
+        want = reference(ARGS, bufs, "L0", **{fault: True})
+    sound = widest_row_gap(np.asarray(ex.run(seq)["o.L0"]),
+                           reference(ARGS, bufs, "L0"))
+    assert sound < ROW_LIMIT / 10
+    assert widest_row_gap(np.asarray(out["o.L0"]), want) > 30 * ROW_LIMIT
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_two_iterations_leave_every_buffer_as_one_leaves_it(engine):
+    g, plat, ex, _ = step()
+    seq = drive(g, plat, ENGINES[engine])
+    once = ex.run(seq)
+    twice = ex.compile(seq)(once)
+    for name in once:
+        assert np.array_equal(np.asarray(once[name]),
+                              np.asarray(twice[name])), name
+
+
+@pytest.mark.parametrize("engine", list(ENGINES))
+def test_counters_equal_a_count_from_the_lengths(engine):
+    reg = MetricsRegistry()
+    prev = set_metrics(reg)
+    try:
+        g, plat, ex, _ = step()
+        jax.make_jaxpr(ex.program(drive(g, plat, ENGINES[engine])))(
+            ex.init_bufs)
+        plan = decode_plan(ARGS)
+    finally:
+        set_metrics(prev)
+    count = {n: reg.counter("mla." + n).value for n in (
+        "page_steps", "page_steps_idle", "keys_useful", "keys_computed",
+        "appended_rows")}
+    layers, page = len(LAYERS), ARGS.page
+    tiles = [n // page + 1 for n in LENS]  # sealed pages and the open one
+    assert count["appended_rows"] == layers * len(LENS)
+    assert count["keys_useful"] == layers * sum(n + 1 for n in LENS)
+    assert count["page_steps"] == layers * sum(tiles)
+    if engine == "fused":
+        grid = sum(grp.rows * grp.steps for grp in plan)
+    else:
+        grid = sum(grp.rows * steps for grp in plan for _, steps in grp.links)
+    assert count["page_steps_idle"] == layers * (grid - sum(tiles))
+    computed = grid if engine == "xla" else sum(tiles)
+    assert count["keys_computed"] == layers * computed * page
+
+
+def test_the_grouping_is_one_span_of_the_program_s_tracing():
+    from tenzing_tpu.obs.tracer import Tracer, set_tracer
+
+    tr = Tracer(enabled=True)
+    prev = set_tracer(tr)
+    try:
+        decode_plan(ARGS)
+    finally:
+        set_tracer(prev)
+    (span,) = [s for s in tr.spans() if s.name == "mla.plan"]
+    assert span.attrs == {"groups": 4, "page_tokens": 8, "rows": 2}
+
+
+def test_plan_groups_neighbours_and_cuts_chains_by_pages():
+    plan = decode_plan(ARGS)
+    assert [(g.lead0, g.rows) for g in plan] == [(0, 2), (2, 2), (4, 2),
+                                                 (6, 2)]
+    assert [g.steps for g in plan] == [2, 3, 4, 8]
+    # links of two pages: the longest group has four, the last one a page
+    # short for its shorter sequence
+    assert plan[3].links == ((0, 2), (16, 2), (32, 2), (48, 2))
+    assert plan[0].links == ((0, 2),)
+    assert paged_tiles([62, 45], 8, 48, 16) == [2, 0]
+    with pytest.raises(ValueError, match="sorted"):
+        LatentDecodeArgs(lens=(9, 3), groups=1)
+    menu = MlaEngineChoice(ARGS, plan[1], "L0", True)
+    assert [c.name().rsplit(".", 1)[1] for c in menu.choices()] == [
+        "chain", "fused"]
+
+
+def test_table_is_a_permutation_of_the_pool_and_the_graph_s_size():
+    table = block_table(ARGS, 11)
+    used = np.concatenate([table[b, :n] for b, n in enumerate(ARGS.sealed)])
+    assert sorted(used) == list(range(ARGS.pool_pages))
+    assert not np.array_equal(used, np.arange(ARGS.pool_pages))
+    shapes = buffer_shapes(ARGS, LAYERS)
+    assert shapes["C.L0"] == ((ARGS.pool_pages, 24, 8), "float32")
+    assert shapes["Copen.L1"] == ((8, 24, 8), "float32")
+    g, plat, _, _ = step()
+    start = drive(g, plat, (".fused",))
+    # append, absorb, four groups and the up-projection a layer
+    names = [op.name() for op in start]
+    assert sum(n.startswith(("L0.", "L1.")) for n in names) == 2 * 7
+    assert sum(n.endswith(".mla_read.fused") for n in names) == 2 * 4
+
+
+# -- what the shared kernel body traces for the prefill's entry points ---------
+
+PINNED = {
+    "fold_state":
+        "e3d1bf79917b51ef93843bbb9d99e864b5e621b851482c7fea35f5ba1ee5311f",
+    "fold_init_masked":
+        "55928d7d1cd076badf748e66c87471f73a98836e5e5ddeb42849ae80b7414d03",
+    "fused_state":
+        "4e7811d9e5df514a77e8958ef37f3a2e0af8733f0450b4ce6d0e42d308347de0",
+    "fused_finish_in_place":
+        "016c455ead109a45eaf19c7faa35698c7ff2963cb039ea5c60dccf6dce2f38b0",
+    "fused_finish_fresh":
+        "7f2b60731ad9915af8853dd0b5f897f6a64d0f11517527b7ab61cb5b01a12295",
+}
+
+
+def _prefill_calls():
+    f32 = jnp.float32
+    q, k = jnp.zeros((4, 16, 8), f32), jnp.zeros((2, 32, 8), f32)
+    st = tuple(jnp.zeros((4, 16, 8), f32) for _ in range(3))
+    o = jnp.zeros((4, 64, 8), f32)
+    return {
+        "fold_state": (lambda *a: attn_block_pallas(
+            *a, 0.5, bkv=16, interpret=True), (q, k, k) + st),
+        "fold_init_masked": (lambda q, k, v: attn_block_pallas(
+            q, k, v, None, None, None, 0.5, bkv=16, q_pos=16, causal=True,
+            window=12, interpret=True), (q, k, k)),
+        "fused_state": (lambda *a: attn_fused_pallas(
+            *a, 0.5, 16, interpret=True), (q, k, k) + st),
+        "fused_finish_in_place": (lambda q, k, v, o: attn_fused_pallas(
+            q, k, v, None, None, None, 0.5, 16, q_pos=16, causal=True,
+            interpret=True, finish=True, o=o, o_row0=16), (q, k, k, o)),
+        "fused_finish_fresh": (lambda q, k, v: attn_fused_pallas(
+            q, k, v, None, None, None, 0.5, 16, q_pos=16, causal=True,
+            window=12, interpret=True, finish=True), (q, k, k)),
+    }
+
+
+@pytest.mark.parametrize("call", list(PINNED))
+def test_prefill_entry_points_trace_what_they_traced(call):
+    """``attn_fold`` and ``attn_fused`` share the kernel body the decode
+    step extended: their jaxprs, kernel body included, are the parent
+    commit's (PR 34) to the letter."""
+    f, operands = _prefill_calls()[call]
+    text = str(jax.make_jaxpr(f)(*operands))
+    assert hashlib.sha256(text.encode()).hexdigest() == PINNED[call]
+
+
+def test_benchmark_reference_is_the_model_s_reference():
+    """``benchmarks/references/mla_paged_decode.py`` imports nothing of the
+    program and computes the absorbed form a block of keys at a time
+    through its own reading of the table: held here to the published form
+    of the model's plain reference on the same data."""
+    spec = importlib.util.spec_from_file_location(
+        "mla_paged_decode", os.path.join(
+            REPO, "benchmarks", "references", "mla_paged_decode.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    bufs = make_decode_buffers(ARGS, LAYERS, 4, 9)
+    z = {"lens": LENS, "heads": 4, "rank": 16, "rope": 8, "nope": 8,
+         "v_dim": 8, "page": 8, "scale": ARGS.scale}
+    for layer in LAYERS:
+        got = ref.layer_reference(z, {
+            k.split(".")[0]: jnp.asarray(v) for k, v in bufs.items()
+            if k.endswith("." + layer) or "." not in k})
+        want = reference(ARGS, bufs, layer)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4,
+                                   atol=2e-5)

@@ -258,3 +258,117 @@ def test_halo_and_rdma_modules_build_their_compiler_params():
     known = {f.name for f in dataclasses.fields(pltpu.CompilerParams)}
     assert {"dimension_semantics", "collective_id",
             "has_side_effects"} <= known
+
+
+# -- the kernel body over a paged latent cache (ISSUE 35) ----------------------
+
+PAGED_LENS = [5, 9, 16, 17, 30, 8]  # visible keys: a page is 8
+PAGED_GROUPS = {"first_three": (0, 3), "last_three": (3, 3), "all": (0, 6)}
+
+
+def _paged_case(seed=0, page=8, d=24, dv=16, n=8):
+    """Pools with their pages as columns, a shuffled table, and per
+    sequence the dense float64 answer."""
+    rng = np.random.default_rng(seed)
+    tiles = [-(-x // page) for x in PAGED_LENS]
+    sealed = [t - 1 for t in tiles]
+    pages = sum(sealed) + 2  # two pages no sequence owns
+    perm = rng.permutation(pages)
+    table = np.zeros((len(PAGED_LENS), max(tiles)), np.int32)
+    at = 0
+    for b, cnt in enumerate(sealed):
+        table[b, :cnt] = perm[at:at + cnt]
+        at += cnt
+    pool = rng.standard_normal((pages, d, page)).astype(np.float32)
+    opened = rng.standard_normal((len(PAGED_LENS), d, page)).astype(
+        np.float32)
+    q = rng.standard_normal((len(PAGED_LENS), n, d)).astype(np.float32)
+
+    def want(b, scale):
+        rows = [pool[table[b, j]].T for j in range(sealed[b])] + [opened[b].T]
+        c = np.concatenate(rows)[:PAGED_LENS[b]].astype(np.float64)
+        s = q[b].astype(np.float64) @ c.T * scale
+        p = np.exp(s - s.max(1, keepdims=True))
+        return (p / p.sum(1, keepdims=True)) @ c[:, :dv]
+
+    arrays = tuple(jnp.asarray(x) for x in (
+        q, pool, opened, np.asarray(PAGED_LENS, np.int32), table))
+    return arrays, want, page, dv
+
+
+@pytest.mark.parametrize("group", list(PAGED_GROUPS))
+def test_paged_decode_kernel_reads_each_sequence_through_its_table_row(group):
+    """``mla_decode``: V is K's first ``v_dim`` columns, every sequence
+    stops at its own length, sealed pages come through the table and the
+    last tile from the open pool; the rows of sequences outside the group
+    keep what they held."""
+    from tenzing_tpu.ops.attention_pallas import (
+        mla_decode_pallas,
+        paged_tiles,
+    )
+
+    arrays, want, page, dv = _paged_case()
+    lead0, rows = PAGED_GROUPS[group]
+    steps = max(paged_tiles(PAGED_LENS[lead0:lead0 + rows], page))
+    o = jnp.full((len(PAGED_LENS), 8, dv), 7.0, jnp.float32)
+    got = np.asarray(mla_decode_pallas(
+        *arrays, o, 0.3, v_dim=dv, lead0=lead0, rows=rows, steps=steps))
+    for b in range(len(PAGED_LENS)):
+        if lead0 <= b < lead0 + rows:
+            np.testing.assert_allclose(got[b], want(b, 0.3), rtol=2e-5,
+                                       atol=2e-6)
+        else:
+            assert (got[b] == 7.0).all()
+
+
+@pytest.mark.parametrize("span", [1, 2, 4])
+@pytest.mark.parametrize("group", list(PAGED_GROUPS))
+def test_paged_fold_chain_is_the_fused_kernel(group, span):
+    """``mla_fold`` links of ``span`` pages through the state in HBM, the
+    first opening it: ``acc / l`` at the end is what ``mla_decode`` writes;
+    a link past a sequence's last page leaves its state as it came."""
+    from tenzing_tpu.ops.attention_pallas import (
+        mla_decode_pallas,
+        mla_fold_pallas,
+        paged_tiles,
+    )
+
+    arrays, want, page, dv = _paged_case(seed=1)
+    lead0, rows = PAGED_GROUPS[group]
+    vis = PAGED_LENS[lead0:lead0 + rows]
+    state = (None, None, None)
+    for k_pos in range(0, max(vis), span * page):
+        steps = max(paged_tiles(vis, page, k_pos, span * page))
+        before = state
+        state = mla_fold_pallas(*arrays, *state, 0.3, v_dim=dv, lead0=lead0,
+                                rows=rows, k_pos=k_pos, steps=steps)
+        for i, n_vis in enumerate(vis):
+            if k_pos >= n_vis and before[0] is not None:
+                for new, old in zip(state, before):
+                    assert np.array_equal(np.asarray(new[i]),
+                                          np.asarray(old[i]))
+    chain = np.asarray(state[0] / state[2])
+    o = jnp.zeros((len(PAGED_LENS), 8, dv), jnp.float32)
+    fused = np.asarray(mla_decode_pallas(
+        *arrays, o, 0.3, v_dim=dv, lead0=lead0, rows=rows,
+        steps=max(paged_tiles(vis, page))))
+    for i in range(rows):
+        np.testing.assert_allclose(chain[i], want(lead0 + i, 0.3),
+                                   rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(chain[i], fused[lead0 + i], rtol=2e-6,
+                                   atol=2e-7)
+
+
+def test_paged_kernel_refusals_and_its_tile_count():
+    from tenzing_tpu.ops.attention_pallas import (
+        mla_fold_pallas,
+        paged_tiles,
+    )
+
+    assert paged_tiles(PAGED_LENS, 8) == [1, 2, 2, 3, 4, 1]
+    assert paged_tiles(PAGED_LENS, 8, 16, 16) == [0, 0, 0, 1, 2, 0]
+    assert paged_tiles([8, 9], 8, 8) == [0, 1]
+    arrays, _, _, dv = _paged_case()
+    with pytest.raises(ValueError, match="starts at a page"):
+        mla_fold_pallas(*arrays, None, None, None, 0.3, v_dim=dv, lead0=0,
+                        rows=6, k_pos=4, steps=1)

@@ -404,6 +404,114 @@ def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
         assert state
 
 
+# dsv3-mla-decode.climb: 16 sequences of 8k to 128k cached tokens (the
+# configuration's lengths), 128 heads on a 576-wide latent cache in pages of
+# 2048 keys, held as columns; four layers
+def _mla_cell():
+    import json
+
+    from tenzing_tpu.models.latent_attention import LatentDecodeArgs
+    from tenzing_tpu.models.latent_attention_reference import yarn_scale
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "dsv3-mla-decode.json")) as f:
+        shapes = json.load(f)["shapes"]
+    return LatentDecodeArgs(
+        lens=tuple(sorted(shapes["lens"])), scale=yarn_scale(),
+        page=shapes["page_tokens"], groups=shapes["groups"],
+        fold_pages=shapes["fold_pages"])
+
+
+@pytest.mark.parametrize("form", ["mla_decode", "mla_fold"])
+def test_latent_decode_kernels(one_chip, form):
+    """The paged kernel at the decode cell's shapes, its longest group:
+    Mosaic takes K tiles of ``(576, 2048)`` (the contraction 576 wide, V
+    the first 512 rows), three scalar operands, and O aliased in place;
+    the pools arrive in the runtime's own layout, so the compiled program
+    holds the kernel and no copy of a pool."""
+    from tenzing_tpu.models.latent_attention import decode_plan
+    from tenzing_tpu.ops.attention_pallas import (
+        mla_decode_pallas,
+        mla_fold_pallas,
+    )
+
+    a = _mla_cell()
+    grp = decode_plan(a)[-1]
+    bf = jnp.bfloat16
+    operands = (
+        _sds((a.batch, a.heads, a.width), bf, one_chip),
+        _sds((a.pool_pages, a.width, a.page), bf, one_chip),
+        _sds((a.batch, a.width, a.page), bf, one_chip),
+        _sds((a.batch,), jnp.int32, one_chip),
+        _sds((a.batch, a.max_pages), jnp.int32, one_chip))
+    common = dict(v_dim=a.rank, lead0=grp.lead0, rows=grp.rows,
+                  interpret=False)
+    if form == "mla_decode":
+        o = _sds((a.batch, a.heads, a.rank), bf, one_chip)
+        compiled = mla_decode_pallas.lower(
+            *operands, o, a.scale, steps=grp.steps, **common).compile()
+    else:
+        st = _sds((grp.rows, a.heads, a.rank), jnp.float32, one_chip)
+        k_pos, steps = grp.links[-1]
+        compiled = mla_fold_pallas.lower(
+            *operands, st, st, st, a.scale, k_pos=k_pos, steps=steps,
+            **common).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    assert form in text  # the name the device trace shows
+    pool = f"bf16[{a.pool_pages},{a.width},{a.page}]"
+    moved = [l for l in text.splitlines()
+             if " copy(" in l and l.split(" = ")[1].startswith(pool)]
+    assert not moved
+    # well under a pool: no temporary stands in for one
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("which,kernels", [("start", 16), ("naive", 32)])
+def test_latent_decode_loop_moves_no_pool(one_chip, monkeypatch, which,
+                                          kernels):
+    """The repeat-n program of ``dsv3-mla-decode.climb``'s start point
+    (every group on ``mla_decode``) and of its naive (chains of
+    ``mla_fold``) as the TPU compiler leaves them: inside the ``while``
+    body nothing touches a sealed pool but the kernels, an open pool only
+    takes its 16 one-column updates in place a layer, and the program's
+    temporaries stay far under one pool (0.63 GB)."""
+    from benchmarks.builders.mla_decode import unfused_prefer
+    from tenzing_tpu.bench.workloads import attn_fused_prefer
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.latent_attention import (
+        buffer_shapes,
+        decode_graph,
+    )
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+    from tenzing_tpu.runtime.executor import TraceExecutor
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    a = _mla_cell()
+    tags = [f"L{i}" for i in range(4)]
+    bufs = {name: _sds(shape, jnp.dtype(dtype), one_chip)
+            for name, (shape, dtype) in buffer_shapes(a, tags).items()}
+    graph = decode_graph(a, tags)
+    plat = Platform.make_n_lanes(2 if which == "start" else 1)
+    seq, _ = drive(graph, plat, phase_policy(
+        plat, [t + "." for t in tags],
+        attn_fused_prefer if which == "start" else unfused_prefer))
+    ex = TraceExecutor(plat, bufs)
+    compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(
+        bufs, _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels
+    sealed = loop_ops_of_shape(
+        text, f"bf16[{a.pool_pages},{a.width},{a.page}]")
+    assert not sealed  # read by the kernels, produced by nothing
+    opened = loop_ops_of_shape(text, f"bf16[{a.batch},{a.width},{a.page}]")
+    assert len(opened) == 4 * a.batch
+    assert all(o.opcode == "dynamic-update-slice" for o in opened)
+    assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
+
+
 def test_spmv_ell(one_chip):
     """spmv: the 150000-row local ELL slab (width 26, make_spmv_buffers seed
     0; transposed ``(w, m)`` as the buffers hold it) against the largest x
