@@ -405,6 +405,15 @@ def note_finish() -> None:
     get_metrics().counter("attn.fused_finishes").inc()
 
 
+def note_in_place() -> None:
+    """``attn.operands_in_place``: one fused vertex handed the kernel the
+    layer's Q, K and V as they lie, no row or key sliced out of them first
+    (at trace time, once per traced body, as :func:`note_tiles`)."""
+    from tenzing_tpu.obs.metrics import get_metrics
+
+    get_metrics().counter("attn.operands_in_place").inc()
+
+
 def _all_rows(args: RingAttnArgs) -> QBlock:
     """The one query block of a layer without ``q_block``: every row."""
     return tile_plan(replace(args, q_block=None))[0]
@@ -639,6 +648,20 @@ class FusedBlockAttn(DeviceOp):
     state lives in VMEM scratch across the kv grid dimension instead of
     round-tripping HBM between per-block ops.
 
+    The kernel is handed the layer's whole Q, K and V with the vertex's rows
+    (``q0``, ``rows``) and key range (``k0``, ``keys``): where those are
+    whole tiles of the buffers, as a layer with query blocks cuts them, its
+    index maps find them and nothing is sliced out in HBM first
+    (``attn.operands_in_place``; 1.5 ms of XLA slices an iteration at
+    ``trinity-attn32k``, 0.54 GB of Q read and written: PERF.md, PR 37);
+    where they are not, the call slices.  The vertex takes its ordering
+    token by index (``INDEX_TIE``): the token's zero goes onto the
+    positions the kernel scalar-prefetches, as the halo's window unpack
+    takes its own, so no buffer gets a value-preserving add (on a K that
+    nothing slices any more that add would write a fresh K a vertex).  The
+    ``bf16`` entry casts, which is a copy anyway: of its rows and keys
+    alone, sliced first.
+
     With query blocks (``first``: the vertex opens its own state and covers
     the whole visible range of its rows) the kernel also finishes them: it
     divides ``acc`` by ``l`` at a query tile's last step and writes rows
@@ -661,6 +684,7 @@ class FusedBlockAttn(DeviceOp):
     finishes here: state in, state out, the layer's one finaliser."""
 
     BF16 = False
+    INDEX_TIE = True
 
     def __init__(self, name: str, args: RingAttnArgs,
                  qb: Optional[QBlock] = None, layer: str = "",
@@ -686,25 +710,39 @@ class FusedBlockAttn(DeviceOp):
 
         from tenzing_tpu.ops.attention_pallas import (
             KV_TILE,
+            Q_TILE,
             attn_fused_pallas,
             computed_pairs,
+            taken_whole,
         )
 
         a, n, qb = self._args, self._n, self._qb
         blk = a.seq_local
         k0, keys = qb.blocks[0] * blk, len(qb.blocks) * blk
-        q = _rows_of(bufs[n["Q"]], qb.q0, qb.rows)
-        k = _rows_of(bufs[n["K"]], k0, keys)
-        v = _rows_of(bufs[n["V"]], k0, keys)
-        if self.BF16:
-            bf = jnp.bfloat16
-            q, k, v = q.astype(bf), k.astype(bf), v.astype(bf)
+        tok = ctx.tok_index_zero
+        if tok is None:  # as the halo's Pack: no zero, no happens-before edge
+            raise RuntimeError(
+                f"{self.desc()}: INDEX_TIE op traced without tok_index_zero "
+                "(executor contract violated: the kernel would have no "
+                "happens-before edge)")
         # one K/V block a grid step where the mask skips nothing; under a
         # mask the kernel's own tile, so that less of an edge is computed
         bkv = min(blk, KV_TILE) if a.causal else blk
+        q, k, v = (bufs[n[t]] for t in ("Q", "K", "V"))
+        if self.BF16:
+            # a cast is a copy anyway: of the vertex's rows and keys alone
+            q = _rows_of(q, qb.q0, qb.rows).astype(jnp.bfloat16)
+            k, v = (_rows_of(t, k0, keys).astype(jnp.bfloat16)
+                    for t in (k, v))
+            at = {}
+        else:
+            at = dict(q_row0=qb.q0, rows=qb.rows, k_row0=k0, keys=keys)
+            if taken_whole(q.shape[1], qb.q0, qb.rows, Q_TILE) and (
+                    taken_whole(k.shape[1], k0, keys, bkv)):
+                note_in_place()
         # the positions enter as their difference (AttnStepPallas._update)
         mask = dict(bkv=bkv, q_pos=qb.q0 - k0, causal=a.causal,
-                    window=a.window)
+                    window=a.window, tok=tok, **at)
         note_tiles(a, qb, qb.blocks,
                    computed_pairs(qb.rows, keys, qb.q0, k0, a.causal,
                                   a.window, bkv=bkv),

@@ -39,6 +39,18 @@ and three shapes of output:
   inside a tile) come out fresh and are put in by
   ``dynamic_update_slice_in_dim``.
 
+and what it reads, the same way (``attn_fused_pallas(..., q_row0, rows,
+k_row0, keys)``): handed a layer's whole Q ``(h, N, d)`` and K, V ``(hkv,
+Nk, d)`` with the call's row range and key range, it takes them as they lie
+where those are whole tiles of the buffers (:func:`taken_whole`): the Q
+index map walks tiles ``q_row0 // bq + j``, the K/V index map adds ``k_row0
+// bkv`` to the visible tile it picks (the tile count a query tile's range
+is clamped to stays the range's), and nothing is sliced out in HBM first.
+A range that is no tile multiple is sliced out by the call.  ``tok``, the
+caller's ordering token as an int32 zero, is added onto the scalar-
+prefetched positions: the kernel waits for its scalars, so no operand needs
+a value-preserving add to carry the token.
+
 State tensors m and l are carried broadcast to (h, n, d) — same shape/layout
 as acc — so every in-kernel operand is a clean 2D (n, d) or (n, nkv) tile (no
 lane<->sublane transposes, no last-dim-1 blocks; see ops/spmv_pallas.py for
@@ -150,6 +162,21 @@ def visible_tiles(plan: _Plan, q_lo, k_pos, larger=jnp.maximum,
     return first, last
 
 
+def walk_step(plan: _Plan, first, last, t):
+    """``(walked, live)``: at K/V step ``t`` the query tile that sees tiles
+    ``first .. last`` is on tile ``first + walked``, and folds it if ``live``.  It
+    walks them in its *last* steps: a tile that sees fewer than
+    ``plan.steps`` idles first, on ``first`` (nothing folded, nothing
+    fetched anew).  So a query tile's last step is a fold, and the next
+    tile's Q and first K/V tile, fetched one step ahead, arrive behind a
+    fold and not behind an idle step a sixth as long (PERF.md, PR 37).  The
+    folds keep their order.  No mask: every tile, in order."""
+    if not plan.causal:
+        return t, True
+    idle = plan.steps - (last - first + 1)
+    return jnp.maximum(t - idle, 0), t >= idle
+
+
 def computed_pairs(rows: int, keys: int, q_pos: int, k_pos: int,
                    causal: bool, window: Optional[int], bq: int = Q_TILE,
                    bkv: int = KV_TILE) -> int:
@@ -208,7 +235,8 @@ def _flash_kernel(plan: _Plan, offs, *refs):
     else:
         q_lo = offs[0] + j * plan.bq
         first, last = visible_tiles(plan, q_lo, offs[1])
-        k_lo = offs[1] + (first + t) * plan.bkv
+        walked, live = walk_step(plan, first, last, t)
+        k_lo = offs[1] + (first + walked) * plan.bkv
 
     def fold(edge: bool, k_ref=k_ref):
         if plan.paged:
@@ -259,7 +287,6 @@ def _flash_kernel(plan: _Plan, offs, *refs):
         pl.when(tile < open_tile)(lambda: fold(False))
         pl.when(tile == open_tile)(lambda: fold(True, ko_ref))
     elif plan.causal:
-        live = first + t <= last
         edge = k_lo + plan.bkv - 1 > q_lo
         if plan.window is not None:
             edge = edge | (k_lo <= q_lo + plan.bq - 1 - plan.window)
@@ -279,15 +306,41 @@ def _flash_kernel(plan: _Plan, offs, *refs):
             l_out[0] = l_s[...]
 
 
+def taken_whole(extent: int, row0: int, rows: int, tile: int) -> bool:
+    """Whether a call that reads rows ``row0 .. row0 + rows`` of an operand
+    of ``extent`` rows takes the operand as it lies: the rows are all of
+    it, or whole tiles of it (of ``tile`` rows, or of ``rows`` where that
+    is fewer), which a block index can address."""
+    if (row0, rows) == (0, extent):
+        return True
+    tile = min(rows, tile)
+    return row0 % tile == 0 and rows % tile == 0
+
+
+def _rows_at(x, row0: int, rows: Optional[int], tile: int):
+    """``(operand, first row, rows)`` of a call that reads rows ``row0 ..
+    row0 + rows`` of ``x``'s axis 1 (to the end without ``rows``): ``x``
+    as it lies where :func:`taken_whole`, else the rows sliced out, from
+    row 0."""
+    rows = x.shape[1] - row0 if rows is None else rows
+    if taken_whole(x.shape[1], row0, rows, tile):
+        return x, row0, rows
+    return jax.lax.dynamic_slice_in_dim(x, row0, rows, 1), 0, rows
+
+
 def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
-           window, interpret, finish=False, o=None, o_row0=0):
+           window, interpret, finish=False, o=None, o_row0=0, q_row0=0,
+           rows=None, k_row0=0, keys=None, tok=None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if window is not None and not causal:
         raise ValueError("a window is counted back from the query's own "
                          "position: it needs causal=True")
-    h, n, d = q.shape
-    hkv, nkv = k.shape[0], k.shape[1]
+    q, q_row0, n = _rows_at(q, q_row0, rows, bq)
+    v = _rows_at(v, k_row0, keys, bkv)[0]
+    k, k_row0, nkv = _rows_at(k, k_row0, keys, bkv)
+    h, d = q.shape[0], q.shape[2]
+    hkv = k.shape[0]
     if h % hkv:
         raise ValueError(f"{h} query heads over {hkv} key/value heads")
     group = h // hkv
@@ -319,17 +372,26 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
                  for j in range(np_ // bq)]
         plan = replace(plan, steps=max(1, max(b - a + 1 for a, b in spans)))
 
-    def kv_tile(j, t, offs):
-        if not plan.causal:
-            return t
-        first, last = visible_tiles(plan, offs[0] + j * bq, offs[1])
-        return jnp.clip(jnp.minimum(first + t, last), 0, plan.kv_tiles - 1)
+    # the first tile of the call's rows in Q and of its keys in K and V; an
+    # index map adds it only where it is not 0, so that a call that names
+    # no offset traces what it traced (the pinned jaxprs)
+    q_tile0, k_tile0 = q_row0 // bq, k_row0 // bkv
 
-    qblk = pl.BlockSpec((1, bq, d), lambda i, j, t, offs: (i, j, 0))
+    def kv_tile(j, t, offs):
+        tile = t
+        if plan.causal:
+            first, last = visible_tiles(plan, offs[0] + j * bq, offs[1])
+            tile = jnp.clip(first + walk_step(plan, first, last, t)[0], 0,
+                            plan.kv_tiles - 1)
+        return k_tile0 + tile if k_tile0 else tile
+
+    rowblk = pl.BlockSpec((1, bq, d), lambda i, j, t, offs: (i, j, 0))
+    qblk = rowblk if not q_tile0 else pl.BlockSpec(
+        (1, bq, d), lambda i, j, t, offs: (i, q_tile0 + j, 0))
     kvblk = pl.BlockSpec(
         (1, bkv, d), lambda i, j, t, offs: (i // group, kv_tile(j, t, offs), 0))
     operands = (q, k, v) + state
-    in_specs = [qblk, kvblk, kvblk] + [qblk] * len(state)
+    in_specs = [qblk, kvblk, kvblk] + [rowblk] * len(state)
     aliases = {}
     # O in place where this call's query tiles are tiles of O: O is an
     # aliased operand nobody fetches (every tile written is written whole),
@@ -345,12 +407,17 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
             (1, bq, d), lambda i, j, t, offs: (i, tile0 + j, 0))]
         out_shape = [out_struct(o.shape, o.dtype, *operands)]
     elif finish:
-        out_specs = [qblk]
+        out_specs = [rowblk]
         out_shape = [out_struct(
             (h, np_, d), q.dtype if o is None else o.dtype, *operands)]
     else:
-        out_specs = [qblk] * 3
+        out_specs = [rowblk] * 3
         out_shape = [out_struct((h, np_, d), jnp.float32, *operands)] * 3
+    positions = jnp.asarray([q_pos, k_pos], jnp.int32)
+    if tok is not None:
+        # the caller's ordering token, an int32 zero: the kernel waits for
+        # its scalars, so for the token, and no operand gets an add
+        positions = positions + tok
     outs = pl.pallas_call(
         functools.partial(_flash_kernel, plan),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -369,7 +436,7 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
         ),
         name=name,
         interpret=interpret,
-    )(jnp.asarray([q_pos, k_pos], jnp.int32), *operands)
+    )(positions, *operands)
     if in_place:
         return outs[0]
     if pad:
@@ -413,7 +480,8 @@ def attn_block_pallas(
                   k_pos, causal, window, interpret)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC + ("finish", "o_row0"))
+@functools.partial(jax.jit, static_argnames=_STATIC + (
+    "finish", "o_row0", "q_row0", "rows", "k_row0", "keys"))
 def attn_fused_pallas(
     q: jax.Array,
     k: jax.Array,
@@ -432,6 +500,11 @@ def attn_fused_pallas(
     finish: bool = False,
     o: Optional[jax.Array] = None,
     o_row0: int = 0,
+    q_row0: int = 0,
+    rows: Optional[int] = None,
+    k_row0: int = 0,
+    keys: Optional[int] = None,
+    tok: Optional[jax.Array] = None,
 ):
     """Fold a whole K/V range into the online-softmax state in ONE kernel —
     the fused alternative to chaining :func:`attn_block_pallas` per block.
@@ -446,7 +519,18 @@ def attn_fused_pallas(
     with ``finish``).
 
     Shapes as :func:`attn_block_pallas`, with nkv % bkv == 0.  Under a mask
-    each q-tile walks only the K/V tiles that hold a key it can see.
+    each q-tile walks only the K/V tiles that hold a key it can see, in the
+    last of the grid's K/V steps (:func:`walk_step`).
+
+    ``q_row0``/``rows`` and ``k_row0``/``keys``: the call folds rows
+    ``q_row0 .. q_row0 + rows`` of ``q`` against keys ``k_row0 .. k_row0 +
+    keys`` of ``k``/``v`` (to the end without a count), read from the
+    operands as they lie where the ranges are whole tiles of them and sliced
+    out first where not (the module's head); ``n`` and ``nkv`` above are
+    then the ranges', as are ``q_pos``/``k_pos`` the positions of their
+    first rows, and a handed state has ``rows`` rows.  ``tok``: an int32
+    zero that carries the caller's ordering token onto the prefetched
+    positions.
 
     Returns ``(acc', m', l')``; with ``finish=True`` (and ``acc``, ``m``,
     ``l`` ``None``) O instead: ``(acc' / l')`` as fresh ``(h, n, d)`` rows
@@ -455,7 +539,8 @@ def attn_fused_pallas(
     head says how).  Every row has to see a key in the range.
     """
     return _flash("attn_fused", q, k, v, acc, m, l, scale, Q_TILE, bkv, q_pos,
-                  k_pos, causal, window, interpret, finish, o, o_row0)
+                  k_pos, causal, window, interpret, finish, o, o_row0, q_row0,
+                  rows, k_row0, keys, tok)
 
 
 # -- a paged latent cache: one decode step ---------------------------------------

@@ -221,11 +221,15 @@ def drive_blocks(graph, plat, order, fused):
     return st.sequence
 
 
-def test_four_writers_of_one_o_give_the_reference_in_every_order():
+@pytest.mark.parametrize("fused", [{1, 2}, {0, 1, 2, 3}],
+                         ids=["two_fused", "all_fused"])
+def test_four_writers_of_one_o_give_the_reference_in_every_order(fused):
     """A layer's four vertices write one buffer, O, in disjoint rows, with
     no edge between them: two fused kernels that write their rows in place
-    and two chains whose finalisers put theirs in.  Each order of the four
-    is sound and gives the dense reference's O."""
+    and two chains whose finalisers put theirs in (``all_fused``: the
+    cell's start point, four kernels that read the layer's whole Q, K and
+    V and share no slice).  Each order of the four is sound and gives the
+    dense reference's O."""
     import itertools
 
     args = replace(ARGS, n_devices=8, q_block=16)  # 64 positions, 4 blocks
@@ -235,7 +239,7 @@ def test_four_writers_of_one_o_give_the_reference_in_every_order():
     verify = ScheduleVerifier(g)
     seen = set()
     for order in itertools.permutations(range(4)):
-        seq = drive_blocks(g, plat, order, fused={1, 2})
+        seq = drive_blocks(g, plat, order, fused=fused)
         assert verify(seq).ok
         names = [op.name() for op in seq]
         writers = [n for n in names
@@ -279,17 +283,11 @@ def period_of_four():
     return g, plat, ex, [tag + "." for tag, _ in layers]
 
 
-@pytest.mark.parametrize("which,finalisers,finishes", [
-    ("start", 0, 16), ("naive", 16, 0)])
-def test_who_finishes_the_rows_of_the_cell_s_two_programs(
-        which, finalisers, finishes):
-    """The climb's start point (every query block on the fused kernel)
-    holds no ``FinalizeAttn``, writes no state buffer and counts 16
-    ``attn.fused_finishes`` a traced body; naive (every block a chain of
-    kernel folds) holds four finalisers a layer and counts none."""
+def cell_program(which):
+    """(graph, executor, schedule) of the cell's two programs at toy widths:
+    the climb's ``start`` point (every query block on the fused kernel) or
+    ``naive`` (one lane, every block a chain of kernel folds)."""
     from tenzing_tpu.bench.workloads import attn_fused_prefer
-    from tenzing_tpu.core.operation import unbound
-    from tenzing_tpu.models.ring_attention import FinalizeAttn
     from tenzing_tpu.solve.local import drive as drive_policy, phase_policy
 
     g, plat, ex, phases = period_of_four()
@@ -298,6 +296,21 @@ def test_who_finishes_the_rows_of_the_cell_s_two_programs(
                                                     attn_fused_prefer))
     else:
         seq = drive(g, Platform.make_n_lanes(1), (".chain", ".pallas"))
+    return g, ex, seq
+
+
+@pytest.mark.parametrize("which,finalisers,finishes", [
+    ("start", 0, 16), ("naive", 16, 0)])
+def test_who_finishes_the_rows_of_the_cell_s_two_programs(
+        which, finalisers, finishes):
+    """The climb's start point (every query block on the fused kernel)
+    holds no ``FinalizeAttn``, writes no state buffer and counts 16
+    ``attn.fused_finishes`` a traced body; naive (every block a chain of
+    kernel folds) holds four finalisers a layer and counts none."""
+    from tenzing_tpu.core.operation import unbound
+    from tenzing_tpu.models.ring_attention import FinalizeAttn
+
+    g, ex, seq = cell_program(which)
     assert ScheduleVerifier(g)(seq).ok
     fins = [op for op in map(unbound, seq) if isinstance(op, FinalizeAttn)]
     assert len(fins) == finalisers
@@ -322,6 +335,91 @@ def test_who_finishes_the_rows_of_the_cell_s_two_programs(
     untouched = {n for n in left if left[n] is came[n]}
     assert {n for n in left if n.startswith("O.")}.isdisjoint(untouched)
     assert (state <= untouched) == (which == "start")
+
+
+def _fused_call(args, qb, q, k, v, o, whole, bkv=None, **more):
+    """``attn_fused_pallas`` as :class:`FusedBlockAttn` calls it for query
+    block ``qb``, writing its rows of ``o``: handed the layer's Q, K and V
+    with the block's rows and key range (``whole``), or the rows and keys
+    sliced out first, as before ISSUE 37."""
+    from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+
+    blk = args.seq_local
+    k0, keys = qb.blocks[0] * blk, len(qb.blocks) * blk
+    if whole:
+        at = dict(q_row0=qb.q0, rows=qb.rows, k_row0=k0, keys=keys)
+    else:
+        q = q[:, qb.q0:qb.q0 + qb.rows]
+        k, v, at = k[:, k0:k0 + keys], v[:, k0:k0 + keys], {}
+    return attn_fused_pallas(
+        q, k, v, None, None, None, args.scale, bkv=bkv or blk,
+        q_pos=qb.q0 - k0,
+        causal=True, window=args.window, interpret=True, finish=True, o=o,
+        o_row0=qb.q0, **at, **more)
+
+
+@pytest.mark.parametrize("block", ["first", "middle", "last", "off_tile"])
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_whole_operands_give_the_sliced_call_s_o_bit_for_bit(kind, block):
+    """The fused call handed the layer's whole Q, K and V finds its rows
+    and keys by index map (no ``dynamic_slice`` in what it traces) and
+    writes, to the last bit, the O of the call handed slices, at the
+    cell's rehearsal shape (40 positions, query blocks of 16, K/V blocks of
+    8: the last block has 8 rows).  ``off_tile``: rows 4..20 are no tile
+    of 16 rows of Q, keys 4..20 no tiles of 8 of K and V; the call slices
+    all three out itself and still agrees."""
+    from tenzing_tpu.models.ring_attention import QBlock
+
+    args = KINDS[kind]
+    plan = tile_plan(args)
+    if block == "off_tile":
+        # offsets inside a tile: a block of the plan moved by four rows
+        args, qb = replace(args, seq_local=4), QBlock(None, 4, 16,
+                                                      (1, 2, 3, 4), 0)
+        bkv, slices = 8, 3  # Q, K and V: all fall back
+    else:
+        qb = {"first": plan[0], "middle": plan[1], "last": plan[-1]}[block]
+        bkv, slices = None, 0
+    bufs, _ = make_blocked_buffers(KINDS[kind], seed=5, layer="L0")
+    q, k, v = (jnp.asarray(bufs[t + ".L0"]) for t in "QKV")
+    o = jnp.asarray(np.random.default_rng(6).standard_normal(q.shape),
+                    jnp.float32)
+    tok = jnp.zeros((), jnp.int32)
+    whole = _fused_call(args, qb, q, k, v, o, True, bkv, tok=tok)
+    sliced = _fused_call(args, qb, q, k, v, o, False, bkv)
+    traced = str(jax.make_jaxpr(lambda *a: _fused_call(
+        args, qb, *a, True, bkv, tok=tok))(q, k, v, o))
+    assert traced.count("dynamic_slice") == slices
+    assert np.array_equal(np.asarray(whole), np.asarray(sliced))
+    rows = slice(qb.q0, qb.q0 + qb.rows)
+    assert not np.array_equal(np.asarray(whole)[:, rows],
+                              np.asarray(o)[:, rows])
+    untouched = np.ones(q.shape[1], bool)
+    untouched[rows] = False
+    assert np.array_equal(np.asarray(whole)[:, untouched],
+                          np.asarray(o)[:, untouched])
+
+
+@pytest.mark.parametrize("which,in_place", [("start", 16), ("naive", 0)])
+def test_what_the_cell_s_two_programs_hand_their_kernels(which, in_place):
+    """The climb's start point hands every fused kernel the layer's Q, K
+    and V as they lie: its traced body holds no ``dynamic_slice``, no
+    buffer takes an ordering token by value (the sixteen vertices take
+    theirs by index, onto the positions the kernel prefetches), and
+    ``attn.operands_in_place`` counts 16.  Naive's chains of folds slice
+    as they did and count none."""
+    _, ex, seq = cell_program(which)
+    reg = MetricsRegistry()
+    prev = set_metrics(reg)
+    try:
+        text = str(jax.make_jaxpr(ex.program(seq))(ex.init_bufs))
+    finally:
+        set_metrics(prev)
+    assert reg.counter("attn.operands_in_place").value == in_place
+    assert reg.counter("executor.index_ties").value == in_place
+    assert ("dynamic_slice" in text) == (which == "naive")
+    tied = reg.counter("executor.value_tied_bytes").value
+    assert (tied == 0) == (which == "start")
 
 
 def test_defaults_trace_to_the_jaxpr_they_had():

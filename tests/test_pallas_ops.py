@@ -234,6 +234,38 @@ def test_finishing_kernel_is_the_state_then_the_division(
     assert ("dynamic_update_slice" in traced) != in_place
 
 
+@pytest.mark.parametrize("q_pos", [0, 4], ids=["at_0", "at_4"])
+@pytest.mark.parametrize("window", [None, 12], ids=["full", "window"])
+def test_a_query_tile_idles_first_and_ends_on_a_fold(window, q_pos):
+    """``walk_step``: a query tile that sees fewer K/V tiles than the grid
+    has steps sits on its first tile through the steps it has to spare,
+    then folds ``first .. last`` in order, so its last step is a fold (the
+    next tile's operands are fetched behind it) wherever it sees a key."""
+    from tenzing_tpu.ops.attention_pallas import (
+        _Plan,
+        visible_tiles,
+        walk_step,
+    )
+
+    bq, bkv, tiles = 8, 4, 12
+    spans = [visible_tiles(_Plan(0.0, bq, bkv, tiles, 0, True, window, True),
+                           q_pos + j * bq, 0, max, min) for j in range(3)]
+    steps = max(last - first + 1 for first, last in spans)
+    plan = _Plan(0.0, bq, bkv, tiles, steps, True, window, True)
+    assert len({last - first for first, last in spans}) > 1  # some idle
+    for first, last in spans:
+        walk = [walk_step(plan, first, last, t) for t in range(steps)]
+        spare = steps - (last - first + 1)
+        assert [first + int(w) for w, _ in walk] == (
+            [first] * spare + list(range(first, last + 1)))
+        assert [bool(live) for _, live in walk] == (
+            [False] * spare + [True] * (last - first + 1))
+    # no mask: every tile, in order, every step a fold
+    plain = _Plan(0.0, bq, bkv, tiles, tiles, False, None, True)
+    assert [walk_step(plain, 0, tiles - 1, t) for t in range(tiles)] == [
+        (t, True) for t in range(tiles)]
+
+
 def test_finishing_kernel_refuses_a_state_it_would_not_hand_on():
     from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
 

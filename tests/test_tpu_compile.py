@@ -342,6 +342,32 @@ def test_attention_fused_finishes_its_rows_of_o(one_chip, kind):
     assert "output_to_operand_aliasing={{}: (4, {})}" in call
 
 
+@pytest.mark.parametrize("kind", list(FINISHING))
+def test_attention_fused_takes_whole_operands(one_chip, kind):
+    """The same calls handed the layer's whole Q, K and V (ISSUE 37):
+    Mosaic takes the query tiles offset by ``q0 // 512`` and the K/V tiles
+    by ``k0 // 1024`` in the index maps, the ordering token's zero on the
+    scalar-prefetched positions, and the compiled program holds the kernel
+    and no slice of an operand."""
+    from tenzing_tpu.ops.attention_pallas import attn_fused_pallas
+
+    c = CELL_ATTN
+    q0, k0, keys, window = FINISHING[kind]
+    bf = jnp.bfloat16
+    q = _sds((c["heads"], c["n"], c["d"]), bf, one_chip)
+    kv = _sds((c["kv_heads"], c["n"], c["d"]), bf, one_chip)
+    compiled = attn_fused_pallas.lower(
+        q, kv, kv, None, None, None, c["d"] ** -0.5, bkv=1024,
+        q_pos=q0 - k0, causal=True, window=window, interpret=False,
+        finish=True, o=q, o_row0=q0, q_row0=q0, rows=c["rows"], k_row0=k0,
+        keys=keys, tok=_sds((), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    assert "slice" not in text
+    call = next(l for l in text.splitlines() if "tpu_custom_call" in l)
+    assert "output_to_operand_aliasing={{}: (4, {})}" in call
+
+
 @pytest.mark.parametrize("which,kernels", [("start", 16), ("naive", 53)])
 def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
                                                  which, kernels):
@@ -351,6 +377,8 @@ def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
     start point's ``while`` body a layer's O is the result of its four
     ``attn_fused`` calls and of nothing else: no copy of it, no
     concatenate, no division, and no float32 state leaves a kernel.
+    Nor is a row of Q or a key range of K or V sliced out for a kernel
+    (ISSUE 37): the kernels read the layer's buffers as they lie.
     Naive's chains keep their state and finish each block's rows with an
     update of O in place."""
     from benchmarks.builders.attn_period import unfused_prefer
@@ -397,6 +425,11 @@ def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
                    and o.name.startswith("attn_fused") for o in whole_o)
         assert not state
         assert "divide" not in text
+        # Q's row blocks and the key ranges of K and V a vertex sees
+        assert not loop_ops_of_shape(text, "bf16[32,4096,128]")
+        for keys in (4096, 6144, 8192, 12288):
+            assert not loop_ops_of_shape(text, f"bf16[4,{keys},128]")
+        assert "dynamic-slice" not in text
     else:
         assert len(whole_o) == 16  # a block's rows put into its layer's O
         assert all("dynamic-update-slice" in o.fused
