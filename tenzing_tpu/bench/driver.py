@@ -1431,12 +1431,20 @@ def winner_report(name: str, enabled, needs_device: Optional[str], body,
 
 
 def stepped_analysis(run: _Run, seq, measured_us, cost=None):
-    """obs/attrib's analysis of ``seq`` stepped op by op on the run's
-    executor; ``cost`` joins the roofline's fractions of peak."""
+    """obs/attrib's analysis of ``seq`` on the run's executor; ``cost``
+    joins the roofline's fractions of peak.  On a TPU the timeline is one
+    profiled dispatch of the program that was timed, cut by vertex
+    (``traced_timeline``); elsewhere a profile has no ``XLA Ops`` line
+    and the ops are stepped one by one."""
+    import jax
+
     from tenzing_tpu.obs import attrib as _attrib
 
-    tl = _attrib.stepped_timeline(run.ex, seq,
-                                  repeats=run.args.profile_repeats)
+    if jax.default_backend() == "tpu":
+        tl = _attrib.traced_timeline(run.ex, seq)
+    else:
+        tl = _attrib.stepped_timeline(run.ex, seq,
+                                      repeats=run.args.profile_repeats)
     return _attrib.analyze(seq.vector(), tl, measured_us=measured_us,
                            cost=cost, peaks=run.peaks)
 

@@ -7,7 +7,10 @@ decision**:
 
 * :mod:`~tenzing_tpu.obs.attrib.timeline` — the timed execution mode:
   per-op stepped sub-programs over ``TraceExecutor.op_stepped`` produce an
-  :class:`OpTimeline` of (op, lane, start, duration) records;
+  :class:`OpTimeline` of (op, lane, start, duration) records; where a
+  profile has the device's own operations (a TPU), ``traced_timeline``
+  makes the same records from one profiled dispatch of the real repeat-n
+  program, cut by the executor's vertex scopes;
 * :mod:`~tenzing_tpu.obs.attrib.analysis` — Gantt reconstruction on the
   verifier's happens-before relation, critical path, overlap efficiency,
   dispatch overhead (the MPK baseline number), roofline join;
@@ -15,8 +18,8 @@ decision**:
   (lanes / reorder / sync removal / menu choices), the three-term timing
   decomposition, ``explain.json``, per-lane Perfetto tracks;
 * :mod:`~tenzing_tpu.obs.attrib.xplane` — the reader of a jax.profiler
-  trace: device busy and idle, and each idle gap given to the program's
-  own span (``python -m tenzing_tpu.obs.attrib.xplane <trace dir>``; not
+  trace: device busy and idle, device time by the schedule's vertex, and
+  each idle gap given to the program's own span (``python -m tenzing_tpu.obs.attrib.xplane <trace dir>``; not
   imported here, so that ``-m`` runs it as a script of its own).
 
 Driver surface: ``bench.py --profile-winner`` stamps the ``attrib`` block
@@ -39,6 +42,7 @@ from tenzing_tpu.obs.attrib.timeline import (
     OpTimeline,
     fetch_overhead_us,
     stepped_timeline,
+    traced_timeline,
 )
 
 __all__ = [
@@ -52,5 +56,6 @@ __all__ = [
     "lane_label",
     "stepped_timeline",
     "timeline_trace_events",
+    "traced_timeline",
     "write_explain",
 ]

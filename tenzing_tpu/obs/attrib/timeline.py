@@ -28,8 +28,13 @@ What stepped durations mean — and what they do not:
   covering all member positions (the wait closure cannot cross a program
   boundary — see ``TraceExecutor.op_stepped``).
 
-The xplane capture path (xplane.py) is the multi-chip fallback; it
-attributes by kernel name rather than by schedule position.
+:func:`traced_timeline` is the other source, where the backend's profile
+has an ``XLA Ops`` line (a TPU): ONE profiled dispatch of the *real*
+repeat-n program, its device time cut by the executor's vertex scopes
+(xplane.py ``device_by_vertex``) into the same records.  It times the
+program that was measured, kernels fused and overlapped as XLA left them,
+and runs on a mesh; the stepped mode stays for the backends without that
+line (the CPU), where it is the only per-op clock.
 """
 
 from __future__ import annotations
@@ -90,7 +95,7 @@ class OpTimeline:
 
     records: List[OpRecord] = field(default_factory=list)
     schedule: str = ""  # schedule_id digest (bench/benchmarker.py)
-    source: str = "stepped"  # "stepped" | "xplane" | "synthetic"
+    source: str = "stepped"  # "stepped" | "traced" | "synthetic"
     n_ops: int = 0
     repeats: int = 0
     fetch_overhead_us: float = 0.0
@@ -216,3 +221,64 @@ def stepped_timeline(executor, order, repeats: int = 7) -> OpTimeline:
     return OpTimeline(records=records, schedule=sid, source="stepped",
                       n_ops=len(ops), repeats=repeats,
                       fetch_overhead_us=overhead_us)
+
+
+def traced_timeline(executor, order, n: int = 8,
+                    trace_dir: Optional[str] = None) -> OpTimeline:
+    """One profiled dispatch of ``order``'s real repeat-n program (``n``
+    iterations), the first device's time cut by the executor's vertex
+    scopes into one :class:`OpRecord` a schedule position: a vertex's
+    ``dur_us`` is its ``tie + apply + join`` self time an iteration (a sync
+    op's is 0: what its hook emits is the executor's).  Starts unassigned —
+    run analysis.py over it, as over a stepped timeline.
+
+    The names come from the executable that ran (``compiled_n``): events
+    with no scope stat of their own are named by their instruction.
+    Raises ``RuntimeError`` where the profile has no ``XLA Ops`` line (the
+    CPU backend): use :func:`stepped_timeline` there."""
+    import tempfile
+
+    import jax
+
+    from tenzing_tpu.bench.benchmarker import schedule_id
+    from tenzing_tpu.obs.attrib import hlo, xplane
+    from tenzing_tpu.obs.scopes import SCOPE, vertex_scope
+
+    tr = get_tracer()
+    sid = schedule_id(order)
+    ops = order.vector()
+    with tr.span("attrib.profile", schedule=sid, traced_n=n) as sp:
+        run_n = executor.prepare_n(order)
+        run_n(1)  # its first call, and a warm dispatch path
+        with tempfile.TemporaryDirectory() as tmp:
+            out = trace_dir or tmp
+            jax.profiler.start_trace(out)
+            try:
+                run_n(n)
+            finally:
+                jax.profiler.stop_trace()
+            trace = xplane.load_xplane(out, hlo.scopes_of_text(
+                executor.compiled_n(order).as_text()))
+        if not xplane.device_planes(trace):
+            raise RuntimeError(
+                "traced_timeline: the profile has no XLA Ops line (backend "
+                f"{jax.default_backend()!r}); use stepped_timeline")
+        _, events = xplane.dispatch_events(trace)
+        by_vertex = dict(xplane.device_by_vertex(events)["vertices"])
+        records: List[OpRecord] = []
+        for p in range(len(ops)):
+            name, desc, kind, lane = _record_meta(ops, (p,))
+            parts = by_vertex.get(vertex_scope(ops[p].name())[len(SCOPE):])
+            dur_us = 0.0
+            if kind != "sync":
+                dur_us = max(sum((parts or {}).values()) / n * 1e6,
+                             MIN_DUR_US)
+            records.append(OpRecord(name=name, desc=desc, kind=kind,
+                                    lane=lane, positions=(p,),
+                                    dur_us=dur_us))
+        n_timed = sum(r.kind != "sync" for r in records)
+        sp.set("n_timed", n_timed)
+        get_metrics().counter("attrib.profiles").inc()
+        get_metrics().counter("attrib.steps").inc(n_timed)
+    return OpTimeline(records=records, schedule=sid, source="traced",
+                      n_ops=len(ops), repeats=n, fetch_overhead_us=0.0)

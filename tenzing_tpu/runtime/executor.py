@@ -59,6 +59,7 @@ from tenzing_tpu.core.platform import Platform
 from tenzing_tpu.core.resources import Event, Lane
 from tenzing_tpu.core.sequence import Sequence
 from tenzing_tpu.core.serdes import sequence_to_json_str
+from tenzing_tpu.obs import scopes
 from tenzing_tpu.obs.metrics import get_metrics
 from tenzing_tpu.obs.tracer import get_tracer, short_digest
 
@@ -77,6 +78,8 @@ _LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _COMPILED = "/jax/core/compile/backend_compile_duration"
 _FIRST_CALL_PARTS = ("executor.lower", "executor.xla_compile",
                      "executor.first_run")
+# a compiled program's sizes, as ``memory_analysis()`` names them
+_PROGRAM_SIZES = ("temp", "argument", "output", "alias", "generated_code")
 _first_call_open = threading.local()  # .parts: this thread's open first call
 
 
@@ -120,6 +123,16 @@ def _on_jax_duration(event: str, duration: float, **kw) -> None:
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def _vertex_scope(op):
+    """The ``jax.named_scope`` of a schedule's vertex (obs/scopes.py: the
+    grammar, who reads it, what it costs)."""
+    return jax.named_scope(scopes.vertex_scope(op.name()))
+
+
+def _sync_scope(kind: str):
+    return jax.named_scope(scopes.sync_scope(kind))
 
 
 def _scalarize(leaf) -> Any:
@@ -217,14 +230,26 @@ class TraceContext:
 
     def _tie(self, value, tok):
         """Value unchanged, but consumers now also wait for ``tok``."""
-        return datatie(value, tok)
+        with jax.named_scope("tie"):
+            return datatie(value, tok)
 
     def tie_named(self, name: str, value, tok):
         """Tie, unless ``name`` is host-resident (host-space tensors admit no
         arithmetic; ordering then rests on data dependencies alone)."""
         if name in self.host_space:
             return value
-        return datatie(value, tok)
+        return self._tie(value, tok)
+
+    def trace_op(self, op) -> None:
+        """``op.trace(self)`` inside the vertex's scope: whatever the op
+        puts on the device, through :meth:`_apply_op` or a ``trace`` of its
+        own (ops/comm_ops.py), carries its name.  A sync op is no vertex:
+        its hook below scopes what it emits."""
+        if getattr(op, "is_sync", lambda: False)():
+            op.trace(self)
+        else:
+            with _vertex_scope(op):
+                op.trace(self)
 
     # -- op tracing --------------------------------------------------------
     @staticmethod
@@ -242,7 +267,8 @@ class TraceContext:
         chain the written values back into the token."""
         is_device = isinstance(op, BoundDeviceOp)
         if is_device:
-            tok_in = self._join(self._lane(op.lane()), self._host_tok)
+            with jax.named_scope("tie"):
+                tok_in = self._join(self._lane(op.lane()), self._host_tok)
         else:
             tok_in = self._host_tok
         tok_out = self._apply_op(op, tok_in)
@@ -262,7 +288,9 @@ class TraceContext:
         op can never drop a happens-before edge (it can only add them; the
         cost is overlap the megakernel now owns internally)."""
         lanes = op.lanes()
-        tok_in = self._join(*[self._lane(l) for l in lanes], self._host_tok)
+        with jax.named_scope("tie"):
+            tok_in = self._join(
+                *[self._lane(l) for l in lanes], self._host_tok)
         tok_out = self._apply_op(op, tok_in)
         for l in lanes:
             self._lane_tok[l.id] = tok_out
@@ -294,9 +322,9 @@ class TraceContext:
         view = self.bufs
         reg = get_metrics()
         if getattr(unbound(op), "INDEX_TIE", False):
-            self.tok_index_zero = jnp.where(tok_in != tok_in, 1, 0).astype(
-                jnp.int32
-            )
+            with jax.named_scope("tie"):
+                self.tok_index_zero = jnp.where(
+                    tok_in != tok_in, 1, 0).astype(jnp.int32)
             reg.counter("executor.index_ties").inc()
         else:
             self.tok_index_zero = None  # stale-consumption guard
@@ -306,8 +334,9 @@ class TraceContext:
                 name = min(reads, key=lambda n: (self._approx_nbytes(view[n]), n))
                 reg.counter("executor.value_tied_bytes").inc(
                     self._approx_nbytes(view[name]))
-                view[name] = datatie(view[name], tok_in)
-        out = op.apply(view, self)
+                view[name] = self._tie(view[name], tok_in)
+        with jax.named_scope("apply"):
+            out = op.apply(view, self)
         for name, val in out.items():
             if name not in self.bufs:
                 raise KeyError(
@@ -321,25 +350,54 @@ class TraceContext:
             if name not in self.host_space
             for l in jax.tree_util.tree_leaves(val)
         ]
-        return self._join(tok_in, *[_clean(_scalarize(l)) for l in leaves])
+        with jax.named_scope("join"):
+            return self._join(
+                tok_in, *[_clean(_scalarize(l)) for l in leaves])
 
     # -- sync-op hooks (core/sync_ops.py) ----------------------------------
     def record_event(self, lane: Lane, event: Event) -> None:
-        self._ev_tok[event.id] = self._lane(lane)
+        self._ev_tok[event.id] = self._lane(lane)  # emits nothing
 
     def wait_event(self, lane: Lane, event: Event) -> None:
         ev = self._ev_tok.get(event.id, self._zero)
-        self._lane_tok[lane.id] = self._join(self._lane(lane), ev)
+        with _sync_scope("wait_event"):
+            self._lane_tok[lane.id] = self._join(self._lane(lane), ev)
 
     def sync_event_host(self, event: Event) -> None:
         ev = self._ev_tok.get(event.id, self._zero)
-        self._host_tok = self._join(self._host_tok, ev)
+        with _sync_scope("event_sync"):
+            self._host_tok = self._join(self._host_tok, ev)
 
     def sync_lane_host(self, lane: Lane) -> None:
-        self._host_tok = self._join(self._host_tok, self._lane(lane))
+        with _sync_scope("lane_sync"):
+            self._host_tok = self._join(self._host_tok, self._lane(lane))
 
     def wait_lane(self, waiter: Lane, waitee: Lane) -> None:
-        self._lane_tok[waiter.id] = self._join(self._lane(waiter), self._lane(waitee))
+        with _sync_scope("lane_wait"):
+            self._lane_tok[waiter.id] = self._join(
+                self._lane(waiter), self._lane(waitee))
+
+
+def _compiled_of(jitted, *args):
+    """The ``Compiled`` a jitted callable's call on ``args`` just ran: after
+    that call, lowering the same arguments again finds jax's traced jaxpr,
+    its lowering and its executable where the call left them (under a
+    millisecond, no second compile: tests/test_op_scopes.py holds it to
+    that)."""
+    return jitted.lower(*args).compile()
+
+
+def _fence_of(values) -> Any:
+    """The full-reduction fence: one float32 scalar summed from every leaf
+    of ``values``, under ``tz.fence``."""
+    with jax.named_scope(scopes.SCOPE + scopes.FENCE):
+        fence = jnp.zeros((), jnp.float32)
+        for leaf in jax.tree_util.tree_leaves(list(values)):
+            x = jnp.asarray(leaf)
+            if jnp.issubdtype(x.dtype, jnp.complexfloating):
+                x = jnp.real(x)
+            fence = fence + jnp.sum(x).astype(jnp.float32)
+    return fence
 
 
 def evolve_host_space(names: set, op: OpBase) -> None:
@@ -406,8 +464,8 @@ class TraceExecutor:
             self.compile_secs += secs
 
     @contextmanager
-    def _first_call(self, sched_json: str,
-                    run_n: Optional[int]) -> Iterator[None]:
+    def _first_call(self, sched_json: str, run_n: Optional[int],
+                    repeat_n: bool = True) -> Iterator[Callable]:
         """Round the first call of a newly built program — where jax traces
         and lowers, XLA compiles and loads, and (unless ahead of time:
         ``run_n`` None, span attr ``aot``) the program runs ``run_n``
@@ -417,18 +475,48 @@ class TraceExecutor:
         tracer records, one ``executor.first_call`` span with the parts of
         :class:`_FirstCallParts` as children.  ``schedule`` hashes the
         UNPREFIXED schedule JSON, so it matches the ``bench.benchmark``
-        span's id for the same schedule."""
+        span's id for the same schedule.
+
+        Yields ``note_sizes(compiled_of)``: from the ``Compiled`` the call
+        ended with (``compiled_of()``), it sets the span's ``temp_bytes``, ``argument_bytes``,
+        ``output_bytes``, ``alias_bytes`` and ``generated_code_bytes``
+        (``memory_analysis()``: one device's) and, for a repeat-n program
+        (``repeat_n``), raises the gauge ``executor.program_temp_bytes_max``
+        to its temporaries: the memory the runtime reserves for a program
+        beside its buffers, which no buffer count holds."""
         attrs = {"aot": True} if run_n is None else {}
         t0 = time.perf_counter()
         with get_tracer().span("executor.first_call",
-                               schedule=short_digest(sched_json), **attrs):
+                               schedule=short_digest(sched_json),
+                               **attrs) as span:
+
+            def note_sizes(compiled_of: Callable) -> None:
+                try:
+                    mem = compiled_of().memory_analysis()
+                except Exception as e:  # telemetry never fails a first call
+                    span.set("sizes_error", f"{type(e).__name__}: {e}"[:200])
+                    return
+                if mem is None:  # a backend without the analysis
+                    return
+                for attr in _PROGRAM_SIZES:
+                    span.set(attr + "_bytes",
+                             int(getattr(mem, attr + "_size_in_bytes")))
+                if repeat_n:
+                    self._note_temp_bytes(int(mem.temp_size_in_bytes))
+
             parts = _first_call_open.parts = _FirstCallParts(run_n)
             try:
-                yield
+                yield note_sizes
             finally:
                 _first_call_open.parts = None
                 parts.close()
         self._note_compile(time.perf_counter() - t0)
+
+    def _note_temp_bytes(self, temp_bytes: int) -> None:
+        gauge = get_metrics().gauge("executor.program_temp_bytes_max")
+        with self._stats_lock:
+            if temp_bytes > gauge.value:
+                gauge.set(temp_bytes)
 
     @staticmethod
     def place_host_buffers(bufs: Dict[str, Any], host_names) -> Dict[str, Any]:
@@ -474,7 +562,7 @@ class TraceExecutor:
             host_space=self._initial_host_space(),
         )
         for op in ops:
-            op.trace(tc)
+            tc.trace_op(op)
         _check_inflight_drained(tc)
         return tc.bufs
 
@@ -538,8 +626,11 @@ class TraceExecutor:
         def wrapped(bufs: Dict[str, Any]) -> Dict[str, Any]:
             if state["cold"]:
                 state["cold"] = False
-                with self._first_call(key, run_n=1):
-                    return jitted(bufs)  # its first run: to the call's return
+                with self._first_call(key, run_n=1,
+                                      repeat_n=False) as note_sizes:
+                    out = jitted(bufs)  # its first run: to the call's return
+                    note_sizes(lambda: _compiled_of(jitted, bufs))
+                    return out
             return jitted(bufs)
 
         self._cache[key] = wrapped
@@ -598,8 +689,9 @@ class TraceExecutor:
                 state["first"] = None
                 n_dev = jnp.int32(n)  # its own tiny program, the first time
                 if first == "call":
-                    with self._first_call(sched_json, run_n=n):
+                    with self._first_call(sched_json, run_n=n) as note_sizes:
                         jax.device_get(f(bufs, n_dev)[0])
+                        note_sizes(lambda: _compiled_of(f, bufs, n_dev))
                 else:
                     self._unrun.discard(key)
                     with get_tracer().span("executor.first_run", n=n):
@@ -634,7 +726,7 @@ class TraceExecutor:
                 dict(bufs), axis_names=axis_names, tokens=toks, host_space=host_space0
             )
             for op in ops:
-                op.trace(tc)
+                tc.trace_op(op)
             _check_inflight_drained(tc)
             return (tc.bufs, tc.token_state())
 
@@ -671,20 +763,14 @@ class TraceExecutor:
 
         def stepped(bufs: Dict[str, Any], n) -> Any:
             out = loop(bufs, n)
-            fence = jnp.zeros((), jnp.float32)
-            host_outs = {}
-            for name, val in out.items():
-                if name in host_space_final:
-                    # host-space tensors admit no arithmetic; returning
-                    # them as program outputs keeps a trailing un-fetched
-                    # spill alive (only the fence scalar is device_get)
-                    host_outs[name] = val
-                    continue
-                for leaf in jax.tree_util.tree_leaves(val):
-                    x = jnp.asarray(leaf)
-                    if jnp.issubdtype(x.dtype, jnp.complexfloating):
-                        x = jnp.real(x)
-                    fence = fence + jnp.sum(x).astype(jnp.float32)
+            host_outs = {
+                # host-space tensors admit no arithmetic; returning them
+                # as program outputs keeps a trailing un-fetched spill
+                # alive (only the fence scalar is device_get)
+                name: val for name, val in out.items()
+                if name in host_space_final}
+            fence = _fence_of(
+                val for name, val in out.items() if name not in host_outs)
             return fence, host_outs
 
         return stepped
@@ -720,13 +806,24 @@ class TraceExecutor:
             return False
         stepped = self._stepped_fn(order.vector())
         one = jnp.int32(1)
-        with self._first_call(sched_json, run_n=None):
+        with self._first_call(sched_json, run_n=None) as note_sizes:
             compiled = jax.jit(stepped).lower(self.init_bufs, one).compile()
+            note_sizes(lambda: compiled)
         # first writer wins: a foreground prepare_n racing this insert keeps
         # its own (equivalent) program; both callables answer identically
         if self._cache.setdefault(key, compiled) is compiled:
             self._unrun.add(key)  # compiled, never run (prepare_n)
         return True
+
+    def compiled_n(self, order: Sequence):
+        """The ``Compiled`` of ``order``'s repeat-n program, as
+        :meth:`prepare_n` or :meth:`precompile` left it (a lazily jitted
+        one must have run: :func:`_compiled_of`): its ``as_text()`` names
+        every instruction's owner (obs/attrib/hlo.py)."""
+        f = self._cache["n:" + sequence_to_json_str(order)]
+        if hasattr(f, "as_text"):
+            return f
+        return _compiled_of(f, self.init_bufs, jnp.int32(1))
 
     # -- timed execution mode (the attribution profiler's entry point) ------
     def op_stepped(self, order: Sequence):
@@ -802,16 +899,9 @@ class TraceExecutor:
             tc = TraceContext(dict(bufs), axis_names=axis_names,
                               host_space=set(host_space0))
             for op in group:
-                op.trace(tc)
+                tc.trace_op(op)
             _check_inflight_drained(tc)
-            fence = jnp.zeros((), jnp.float32)
-            for name in fence_names:
-                for leaf in jax.tree_util.tree_leaves(tc.bufs[name]):
-                    x = jnp.asarray(leaf)
-                    if jnp.issubdtype(x.dtype, jnp.complexfloating):
-                        x = jnp.real(x)
-                    fence = fence + jnp.sum(x).astype(jnp.float32)
-            return fence, tc.bufs
+            return _fence_of(tc.bufs[name] for name in fence_names), tc.bufs
 
         return jax.jit(fn)
 

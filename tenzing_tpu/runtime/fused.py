@@ -84,6 +84,7 @@ from tenzing_tpu.core.operation import (
 )
 from tenzing_tpu.core.sequence import Sequence
 from tenzing_tpu.core.sync_ops import EventRecord, SyncOp
+from tenzing_tpu.obs import scopes
 from tenzing_tpu.obs.metrics import get_metrics
 from tenzing_tpu.obs.tracer import get_tracer
 from tenzing_tpu.runtime.executor import TraceExecutor, evolve_host_space
@@ -396,7 +397,9 @@ def _region_call(members: List[BoundDeviceOp], in_names: List[str],
         vals = {n: r[...] for n, r in zip(in_names, ins)}
         ctx = _FusedCtx()
         for op in members:
-            vals.update(op.apply(vals, ctx))
+            # the members' names nest under the region's own (obs/scopes.py)
+            with jax.named_scope(scopes.vertex_scope(op.name())):
+                vals.update(op.apply(vals, ctx))
         for n, r in zip(out_names, outs):
             r[...] = jnp.asarray(vals[n]).astype(r.dtype)
 

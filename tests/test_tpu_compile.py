@@ -368,19 +368,13 @@ def test_attention_fused_takes_whole_operands(one_chip, kind):
     assert "output_to_operand_aliasing={{}: (4, {})}" in call
 
 
-@pytest.mark.parametrize("which,kernels", [("start", 16), ("naive", 53)])
-def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
-                                                 which, kernels):
-    """The repeat-n program of ``trinity-attn32k.climb``'s start point
-    (every query block on the fused kernel) and of its naive (chains of
-    ``attn_fold`` kernels) as the TPU compiler leaves them.  Inside the
-    start point's ``while`` body a layer's O is the result of its four
-    ``attn_fused`` calls and of nothing else: no copy of it, no
-    concatenate, no division, and no float32 state leaves a kernel.
-    Nor is a row of Q or a key range of K or V sliced out for a kernel
-    (ISSUE 37): the kernels read the layer's buffers as they lie.
-    Naive's chains keep their state and finish each block's rows with an
-    update of O in place."""
+_LOOP_TEXTS = {}  # a whole program's compiled text, once a module run
+
+
+def _attention_period_text(one_chip, monkeypatch, which):
+    """The compiled text of the repeat-n program of
+    ``trinity-attn32k.climb``'s start point (every query block on the fused
+    kernel, two lanes) or naive (chains of ``attn_fold`` kernels)."""
     from benchmarks.builders.attn_period import unfused_prefer
     from tenzing_tpu.bench.workloads import attn_fused_prefer
     from tenzing_tpu.core.platform import Platform
@@ -389,10 +383,11 @@ def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
         blocked_buffer_shapes,
         period_graph,
     )
-    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
     from tenzing_tpu.runtime.executor import TraceExecutor
     from tenzing_tpu.solve.local import drive, phase_policy
 
+    if ("attn", which) in _LOOP_TEXTS:
+        return _LOOP_TEXTS["attn", which]
     # the kernels pick the interpreter by the process's default backend,
     # which stays ``cpu`` during an AOT compile
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -415,7 +410,26 @@ def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
     ex = TraceExecutor(plat, bufs)
     compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(
         bufs, _sds((), jnp.int32, one_chip)).compile()
-    text = compiled.as_text()
+    _LOOP_TEXTS["attn", which] = compiled.as_text()
+    return _LOOP_TEXTS["attn", which]
+
+
+@pytest.mark.parametrize("which,kernels", [("start", 16), ("naive", 53)])
+def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
+                                                 which, kernels):
+    """The repeat-n program of ``trinity-attn32k.climb``'s start point
+    (every query block on the fused kernel) and of its naive (chains of
+    ``attn_fold`` kernels) as the TPU compiler leaves them.  Inside the
+    start point's ``while`` body a layer's O is the result of its four
+    ``attn_fused`` calls and of nothing else: no copy of it, no
+    concatenate, no division, and no float32 state leaves a kernel.
+    Nor is a row of Q or a key range of K or V sliced out for a kernel
+    (ISSUE 37): the kernels read the layer's buffers as they lie.
+    Naive's chains keep their state and finish each block's rows with an
+    update of O in place."""
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+
+    text = _attention_period_text(one_chip, monkeypatch, which)
     assert text.count("tpu_custom_call") == kernels
     whole_o = loop_ops_of_shape(text, "bf16[32,16384,128]")
     state = loop_ops_of_shape(text, "f32[32,4096,128]")
@@ -435,6 +449,35 @@ def test_attention_period_loop_writes_o_in_place(one_chip, monkeypatch,
         assert all("dynamic-update-slice" in o.fused
                    or o.opcode == "dynamic-update-slice" for o in whole_o)
         assert state
+
+
+def test_attention_start_point_names_its_sixteen_kernels(one_chip,
+                                                         monkeypatch):
+    """ISSUE 38: in the start point's repeat-n program the 16
+    ``attn_fused`` custom calls carry 16 distinct vertex names, each its
+    vertex's ``apply``, and no instruction of the loop body that moves
+    data is unscoped but the loop's own (its counter and the carry's
+    copies)."""
+    from tenzing_tpu.obs.attrib.hlo import UNSCOPED, loop_ops_by_scope
+
+    ops = loop_ops_by_scope(
+        _attention_period_text(one_chip, monkeypatch, "start"))
+    kernels = [o for o in ops if o.opcode == "custom-call"]
+    assert len(kernels) == 16
+    assert len({o.vertex for o in kernels}) == 16
+    assert all(o.part == "apply" and o.vertex.endswith(".fused")
+               and o.name.startswith("attn_fused") for o in kernels)
+    unscoped = [o for o in ops if o.vertex == UNSCOPED]
+    assert unscoped  # the counter's add at the least
+    # what is nobody's is the loop's: scalars of the counter, or copies
+    assert all(o.bytes <= 8 or o.opcode in ("copy", "copy-start",
+                                            "copy-done") for o in unscoped), [
+        (o.name, o.opcode, o.result) for o in unscoped]
+    # a vertex takes its token by index: scalars under its tie (a lane's
+    # first vertex of a layer has its tie folded into its neighbour's)
+    ties = {o.vertex for o in ops if o.part == "tie"}
+    assert ties <= {o.vertex for o in kernels} and len(ties) >= 12
+    assert all(o.bytes <= 8 for o in ops if o.part in ("tie", "join"))
 
 
 # dsv3-mla-decode.climb: 16 sequences of 8k to 128k cached tokens (the
@@ -828,6 +871,27 @@ PARENT_LOOP = {"naive": (1, 3_131_737_088), "xla": (2, 5_091_660_800),
                "rdma": (0, 3_219_259_392)}
 
 
+def _mesh_halo_loop(topo, which):
+    """``(compiled text, temporaries, args)`` of the repeat-n program of
+    ``halo512-mesh4.mcts`` (448^3 a shard): naive, or every exchange on one
+    engine posted before any is awaited."""
+    from tenzing_tpu.bench.driver import naive_schedule
+    from tenzing_tpu.models.halo import engine_overlap_order
+
+    if ("mesh", which) not in _LOOP_TEXTS:
+        ex, bufs, graph, plat, args = _mesh_halo(topo, 448)
+        seq = (naive_schedule("halo_mesh", graph, None) if which == "naive"
+               else engine_overlap_order(graph, plat, which))
+        n = _sds((), jnp.int32, jax.sharding.NamedSharding(
+            plat.mesh, jax.sharding.PartitionSpec()))
+        compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(
+            bufs, n).compile()
+        _LOOP_TEXTS["mesh", which] = (
+            compiled.as_text(),
+            compiled.memory_analysis().temp_size_in_bytes, args)
+    return _LOOP_TEXTS["mesh", which]
+
+
 @pytest.mark.parametrize("which", ["naive", "xla", "rdma"])
 def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
                                                    which):
@@ -844,17 +908,9 @@ def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
     and the temporaries are not above the parent's."""
     import re
 
-    from tenzing_tpu.bench.driver import naive_schedule
-    from tenzing_tpu.models.halo import engine_overlap_order
     from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
 
-    ex, bufs, graph, plat, args = _mesh_halo(topo, 448)
-    seq = (naive_schedule("halo_mesh", graph, None) if which == "naive"
-           else engine_overlap_order(graph, plat, which))
-    n = _sds((), jnp.int32, jax.sharding.NamedSharding(
-        plat.mesh, jax.sharding.PartitionSpec()))
-    compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(bufs, n).compile()
-    text = compiled.as_text()
+    text, temp, args = _mesh_halo_loop(topo, which)
     grid = "f32[" + ",".join(str(e) for e in args.local_shape()) + "]"
     ops = loop_ops_of_shape(text, grid)
     assert sum(o.opcode == "dynamic-update-slice" for o in ops) == 2
@@ -873,7 +929,70 @@ def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
     assert not [o for o in ops if "add" in o.fused or o.opcode == "add"], ops
     copies, temp_bytes = PARENT_LOOP[which]
     assert sum(o.opcode == "copy" for o in ops) <= copies
-    assert compiled.memory_analysis().temp_size_in_bytes <= temp_bytes
+    assert temp <= temp_bytes
+
+
+@pytest.mark.parametrize("which", ["naive", "xla", "rdma"])
+def test_mesh_halo_loop_names_its_vertices(topo, on_chip_kernels, which):
+    """ISSUE 38: who owns what in the mesh halo's repeat-n loop, read from
+    the compiled text with no chip.  The window unpacks, the x faces'
+    updates, the collective-permutes and the remote-DMA kernels name their
+    vertices.  A pack has no instruction of its own: XLA fuses its slice
+    into the value tie of the exchange that reads it (the fusion goes to
+    the exchange's ``tie`` and lists the pack's ``apply`` as mixed), and the
+    thin faces' relayout copies inherit the exchange's name.  What XLA
+    leaves nameless: the memory-space moves (``copy-start``/``-done``,
+    ``slice-start``/``-done``, its ``ConcatBitcast``) and, in the ``xla``
+    overlap schedule, the two relayout copies of the whole grid (1.12 GB
+    each, the largest operations of the loop) with four face copies."""
+    from tenzing_tpu.obs.attrib.hlo import UNSCOPED, loop_ops_by_scope
+
+    text, _, args = _mesh_halo_loop(topo, which)
+    ops = loop_ops_by_scope(text)
+    engine = "xla" if which == "naive" else which
+    names = [dir_name(d) for d in DIRECTIONS]
+    owner = lambda o: f"{o.vertex}/{o.part}"
+    grid_bytes = 4 * int(np.prod(args.local_shape()))
+
+    windows = [o for o in ops if o.name.startswith("halo_window_unpack")]
+    assert sorted(owner(o) for o in windows) == sorted(
+        f"unpack_{dir_name(d)}/apply" for d in THIN)
+    updates = [o for o in ops if o.opcode == "dynamic-update-slice"]
+    assert sorted(owner(o) for o in updates) == [
+        "unpack_mx/apply", "unpack_px/apply"]
+    # every pack's slice sits in a fusion of its exchange's value tie
+    packed = [o for o in ops if o.opcode == "fusion" and o.bytes > 8
+              and any(m.startswith("pack_") for m in o.mixed)]
+    assert all(o.part == "tie" and o.vertex.startswith("exchange_")
+               and o.vertex.endswith("." + engine) for o in packed)
+    assert sorted(m[len("pack_"):-len("/apply")] for o in packed
+                  for m in o.mixed if m.startswith("pack_")) == sorted(names)
+    assert not [o for o in ops if o.vertex.startswith("pack_")
+                and o.bytes > 8]
+    if engine == "xla":
+        starts = [o for o in ops if o.opcode == "collective-permute-start"]
+        assert sorted(owner(o) for o in starts) == sorted(
+            f"exchange_{n}.xla/apply" for n in names)
+    else:
+        posts = [o for o in ops if o.name.startswith("rdma_shift_post")]
+        waits = [o for o in ops if o.name.startswith("rdma_shift_wait")]
+        assert sorted(owner(o) for o in posts) == sorted(
+            f"exchange_{n}.rdma/apply" for n in names)
+        assert sorted(owner(o) for o in waits) == sorted(
+            f"await_{n}/apply" for n in names)
+    copies = [o for o in ops if o.opcode == "copy" and o.bytes > 8]
+    whole = [o for o in copies if o.bytes == grid_bytes]
+    if which == "xla":
+        assert len(whole) == 2 and {o.vertex for o in whole} == {UNSCOPED}
+    else:
+        assert not whole
+    if which == "naive":  # a thin face's relayout, there and back
+        assert sorted(o.vertex for o in copies) == sorted(
+            2 * [f"exchange_{dir_name(d)}.xla" for d in THIN])
+    nameless = {o.opcode for o in ops
+                if o.vertex == UNSCOPED and o.bytes > 8}
+    assert nameless <= {"copy", "copy-start", "copy-done", "slice-start",
+                        "slice-done", "custom-call"}, nameless
 
 
 # -- the expert layer on four chips --------------------------------------------
