@@ -162,8 +162,8 @@ def main() -> int:
     def program(steps):
         return jax.jit(lambda q, pool, opened, lens, table, o:
                        mla_decode_pallas(q, pool, opened, lens, table, o,
-                                         scale, v_dim=dv, lead0=0, rows=batch,
-                                         steps=steps))
+                                         scale, v_dim=dv, lead0=0,
+                                         tiles=(steps,) * batch))
 
     pages1k = 4 if toy else args.pages
     for page in ((8, 16) if toy else (512, 1024, 2048)):

@@ -34,9 +34,12 @@ Sequences are sorted by length and cut into ``groups`` contiguous groups;
 each group is one :class:`MlaEngineChoice`: one ``mla_decode`` kernel over
 the group's whole range (state in VMEM, writes its rows of ``o_lat`` in
 place) **or** a chain of ``mla_fold`` links over ranges of ``fold_pages``
-pages (state through HBM) that ends in a finaliser of the group's rows.  The
-lengths do not advance: an iteration is the same step again, so n repeats
-leave every buffer as one leaves it.
+pages (state through HBM) that ends in a finaliser of the group's rows.  A
+kernel's grid is the pages its sequences have in its range, one step each
+(:func:`decode_plan` counts them from the static lengths), so a group costs
+what it holds however unequal its sequences are.  The lengths do not
+advance: an iteration is the same step again, so n repeats leave every
+buffer as one leaves it.
 """
 
 from __future__ import annotations
@@ -105,17 +108,32 @@ class LatentDecodeArgs:
 @dataclass(frozen=True)
 class Group:
     """A run of sequences one engine vertex covers, and the links of its
-    split-K chain: ``(k_pos, steps)`` each."""
+    split-K chain: ``(k_pos, tiles)`` each, the link's first key and the
+    pages each sequence has in its range (0 for one that ends before it)."""
 
     index: int
     lead0: int
-    rows: int
-    steps: int  # tiles of its longest sequence: the fused kernel's grid
-    links: Tuple[Tuple[int, int], ...]
+    tiles: Tuple[int, ...]  # pages a sequence: the fused kernel walks them
+    links: Tuple[Tuple[int, Tuple[int, ...]], ...]
+
+    @property
+    def rows(self) -> int:
+        return len(self.tiles)
+
+    @property
+    def steps(self) -> int:
+        """Grid steps of the fused kernel: the pages there are, summed."""
+        return sum(self.tiles)
 
 
 def decode_plan(args: LatentDecodeArgs) -> List[Group]:
-    """The groups of a step (the same for every layer)."""
+    """The groups of a step (the same for every layer): contiguous runs of
+    the sorted sequences, each with the pages its sequences have
+    (``paged_tiles`` of the static lengths), whole and per link of
+    ``fold_pages`` pages.  A kernel's grid is the sum of the pages it is
+    handed, so a group costs what its sequences hold whatever their
+    lengths' spread; only the XLA fold still computes a link's rectangle,
+    sequences by the longest's pages."""
     from tenzing_tpu.obs.tracer import get_tracer
     from tenzing_tpu.ops.attention_pallas import paged_tiles
 
@@ -126,36 +144,37 @@ def decode_plan(args: LatentDecodeArgs) -> List[Group]:
         span = args.fold_pages * args.page
         for g in range(args.groups):
             vis = args.visible[g * rows:(g + 1) * rows]
-            steps = max(paged_tiles(vis, args.page))
+            tiles = tuple(paged_tiles(vis, args.page))
             links = tuple(
-                (k_pos, max(paged_tiles(vis, args.page, k_pos, span)))
-                for k_pos in range(0, steps * args.page, span))
-            plan.append(Group(g, g * rows, rows, steps, links))
+                (k_pos, tuple(paged_tiles(vis, args.page, k_pos, span)))
+                for k_pos in range(0, max(tiles) * args.page, span))
+            plan.append(Group(g, g * rows, tiles, links))
     return plan
 
 
-def note_pages(args: LatentDecodeArgs, grp: Group, k_pos: int, steps: int,
-               whole: bool) -> None:
-    """The program's counters for one traced kernel (or XLA fold, ``whole``:
-    it computes its rectangle whole) of ``grp`` over ``steps`` tiles from
-    key ``k_pos`` (at trace time, once per traced body, as ``attn.*``):
-    ``mla.page_steps`` (grid steps that fetch a page), ``mla.page_steps_idle``
-    (steps of the rectangular grid past a sequence's last page),
-    ``mla.keys_useful`` (visible keys of the range) and ``mla.keys_computed``
-    (keys of the tiles computed, whole)."""
+def note_pages(args: LatentDecodeArgs, grp: Group, k_pos: int,
+               tiles: Tuple[int, ...], whole: bool) -> None:
+    """The program's counters for one traced kernel of ``grp`` over
+    ``tiles`` pages a sequence from key ``k_pos``, or one XLA fold
+    (``whole``: it computes the rectangle, every sequence over the most
+    pages one has), at trace time, once per traced body, as ``attn.*``:
+    ``mla.page_steps`` (steps that fold a page), ``mla.page_steps_idle``
+    (steps of a rectangle past a sequence's last page: 0 for a kernel,
+    whose grid is the pages there are), ``mla.keys_useful`` (visible keys of
+    the range) and ``mla.keys_computed`` (keys of the pages computed,
+    whole)."""
     from tenzing_tpu.obs.metrics import get_metrics
-    from tenzing_tpu.ops.attention_pallas import paged_tiles
 
     reg = get_metrics()
     vis = args.visible[grp.lead0:grp.lead0 + grp.rows]
-    live = sum(paged_tiles(vis, args.page, k_pos, steps * args.page))
-    end = k_pos + steps * args.page
+    live = sum(tiles)
+    grid = grp.rows * max(tiles) if whole else live
+    end = k_pos + max(tiles) * args.page
     reg.counter("mla.page_steps").inc(live)
-    reg.counter("mla.page_steps_idle").inc(grp.rows * steps - live)
+    reg.counter("mla.page_steps_idle").inc(grid - live)
     reg.counter("mla.keys_useful").inc(
         sum(min(n, end) - min(n, k_pos) for n in vis))
-    reg.counter("mla.keys_computed").inc(
-        (grp.rows * steps if whole else live) * args.page)
+    reg.counter("mla.keys_computed").inc(grid * args.page)
 
 
 def _names(layer: str, grp: Optional[Group] = None) -> Dict[str, str]:
@@ -283,20 +302,21 @@ class MlaDecode(DeviceOp):
         from tenzing_tpu.ops.attention_pallas import mla_decode_pallas
 
         a, g, n = self._args, self._grp, self._n
-        note_pages(a, g, 0, g.steps, whole=False)
+        note_pages(a, g, 0, g.tiles, whole=False)
         return {n["o_lat"]: mla_decode_pallas(
             *(bufs[n[k]] for k in _CACHE), bufs[n["o_lat"]], a.scale,
-            v_dim=a.rank, lead0=g.lead0, rows=g.rows, steps=g.steps)}
+            v_dim=a.rank, lead0=g.lead0, tiles=g.tiles)}
 
     def uses_pallas(self) -> bool:
         return True
 
 
 class MlaFold(DeviceOp):
-    """One link of a group's split-K chain: ``steps`` tiles from key
-    ``k_pos`` folded into the group's softmax state (XLA: the pages
-    gathered through the table, the whole rectangle computed).  ``first``
-    opens the state instead of reading it."""
+    """One link of a group's split-K chain: the pages from key ``k_pos``
+    that the link covers folded into the group's softmax state (XLA: the
+    pages gathered through the table, the whole rectangle computed, every
+    sequence over the most pages one has there).  ``first`` opens the state
+    instead of reading it."""
 
     WHOLE = True  # computes its rectangle whole (the counters' keys_computed)
 
@@ -304,7 +324,7 @@ class MlaFold(DeviceOp):
                  link: int, layer: str = "", first: bool = False):
         super().__init__(name)
         self._args, self._grp, self._first = args, grp, first
-        self._k_pos, self._steps = grp.links[link]
+        self._k_pos, self._tiles = grp.links[link]
         self._n = _names(layer, grp)
 
     def reads(self):
@@ -319,7 +339,7 @@ class MlaFold(DeviceOp):
         import jax.numpy as jnp
 
         a, g = self._args, self._grp
-        first, n_t = self._k_pos // a.page, self._steps
+        first, n_t = self._k_pos // a.page, max(self._tiles)
         rows = slice(g.lead0, g.lead0 + g.rows)
         vis = lens[rows]
         tiles = first + jnp.arange(n_t)
@@ -354,14 +374,15 @@ class MlaFold(DeviceOp):
     def apply(self, bufs, ctx):
         n = self._n
         state = None if self._first else tuple(bufs[n[s]] for s in STATE)
-        note_pages(self._args, self._grp, self._k_pos, self._steps,
+        note_pages(self._args, self._grp, self._k_pos, self._tiles,
                    whole=self.WHOLE)
         out = self._update(*(bufs[n[k]] for k in _CACHE), state)
         return dict(zip((n[s] for s in STATE), out))
 
 
 class MlaFoldPallas(MlaFold):
-    """The link as one ``mla_fold`` kernel."""
+    """The link as one ``mla_fold`` kernel: a step a page, and none for a
+    sequence that ends before the link (its state stays where it is)."""
 
     WHOLE = False
 
@@ -371,8 +392,8 @@ class MlaFoldPallas(MlaFold):
         a, g = self._args, self._grp
         return mla_fold_pallas(
             q, pool, opened, lens, table, *(state or (None,) * 3), a.scale,
-            v_dim=a.rank, lead0=g.lead0, rows=g.rows, k_pos=self._k_pos,
-            steps=self._steps)
+            v_dim=a.rank, lead0=g.lead0, k_pos=self._k_pos,
+            tiles=self._tiles)
 
     def uses_pallas(self) -> bool:
         return True

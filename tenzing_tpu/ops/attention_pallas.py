@@ -91,17 +91,28 @@ Paged (:func:`_flash_paged`; what the operands handed decide, no switch):
 * **K through a block table** (``table[b, j]``, scalar-prefetched): tile j
   of sequence b is ``pool[table[b, j]]`` while it is sealed (whole, every
   key visible: no mask built), and ``k_open[b]``, a second K operand, where
-  the last visible key lies (the one page an append writes).  Steps of the
-  rectangular grid past a sequence's last tile hold the index they had:
-  they fetch nothing new and compute nothing.
+  the last visible key lies (the one page an append writes).
+* **The grid is the pages there are**: one axis of (sequence, page) steps,
+  sequence after sequence and a sequence's pages ascending, as many as the
+  call's sequences have pages in its key range (``tiles``, static:
+  :func:`paged_tiles` of the lengths the caller sizes the call by).  Not
+  sequences by the longest's pages: every step folds a page, and the next
+  sequence's Q, open page and first page are always fetched behind a fold.
+  A step finds its sequence and its page by comparing its index with the
+  sequences' first steps, which are immediates of the kernel and of its
+  index maps (:func:`paged_step`): the walk is no operand and no operation
+  of the program around the kernel.  A sequence's state opens at its first
+  step of the call and leaves at its last; a sequence with no page in a
+  link's range has no step, and keeps its state because the handed state
+  *is* the output (aliased, as O is).
 * **A page holds its keys as columns**, ``(d, page)``: K^T as it lies.  The
   TPU runtime lays a bfloat16 array out with its 128-multiple axis minor,
   so a ``(page, 576)`` page would reach the kernel through a copy of the
   whole pool on every call; ``(576, page)`` arrives as it is.  The first
   product is then plain and the second contracts the keys of both operands.
-* The grid's leading axis walks ``rows`` sequences from ``lead0`` of Q,
-  ``lens``, ``table``, ``k_open`` and O, which are the whole batch's: no
-  slice of them is made for a group.
+* The walk's sequences count from ``lead0`` of Q, ``lens``, ``table``,
+  ``k_open`` and O, which are the whole batch's: no slice of them is made
+  for a group.
 
 ``interpret=True`` (automatic off-TPU) runs the same kernel in the Pallas
 interpreter for CPU tests.
@@ -115,6 +126,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -134,17 +146,21 @@ class _Plan:
     bkv: int
     kv_tiles: int  # K/V tiles in the operand
     steps: int     # grid extent over K/V: the most tiles a query tile sees
+                   # (paged: the whole grid, the pages its sequences have)
     causal: bool   # the mask: a window implies it
     window: Optional[int]
     init: bool
     finish: bool = False  # write O = acc / l and no state (needs init)
     # a paged latent cache (``_flash_paged``): V is K's first ``dv`` columns
     # (no V operand), the leading index is a sequence with its own key limit,
-    # K tiles come through a block table, and row 0 of the grid's leading
-    # axis is sequence ``lead0`` of q, the limits, the table and O
+    # K tiles come through a block table, and the grid walks ``tiles[i]``
+    # tiles from ``tile0`` of sequence ``lead0 + i`` of q, the limits, the
+    # table and O, one sequence after another (``steps`` in all)
     dv: Optional[int] = None
     paged: bool = False
     lead0: int = 0
+    tiles: Tuple[int, ...] = ()
+    tile0: int = 0
 
 
 def visible_tiles(plan: _Plan, q_lo, k_pos, larger=jnp.maximum,
@@ -197,11 +213,12 @@ def _flash_kernel(plan: _Plan, offs, *refs):
     across the kv dimension (innermost, strictly sequential), so acc/m/l
     touch HBM once in (not at all with ``init``) and once out per q-tile
     (not at all with ``finish``: the tile's last step divides and writes its
-    rows of O).  Paged: the key limits and the block table follow ``offs``
-    as scalar operands, and K comes as a tile of the sealed pool and the
-    sequence's open page."""
+    rows of O).  Paged: one (sequence, page) step of a one-axis grid; no
+    positions, the scalar operands are the key limits and the block table,
+    and K comes as a tile of the sealed pool and the sequence's open
+    page."""
     if plan.paged:
-        lens, table, q_ref, k_ref, ko_ref, *refs = refs
+        lens, (table, q_ref, k_ref, ko_ref, *refs) = offs, refs
         v_ref = None
     else:
         q_ref, k_ref, v_ref, *refs = refs
@@ -212,9 +229,15 @@ def _flash_kernel(plan: _Plan, offs, *refs):
         acc_out, m_out, l_out, acc_s, m_s, l_s = refs
     else:
         acc_in, m_in, l_in, acc_out, m_out, l_out, acc_s, m_s, l_s = refs
-    j, t = pl.program_id(1), pl.program_id(2)
+    if plan.paged:
+        step = pl.program_id(0)
+        seq, start, count = paged_step(plan.tiles, step)
+        opens = step == start
+    else:
+        j, t = pl.program_id(1), pl.program_id(2)
+        opens = t == 0
 
-    @pl.when(t == 0)
+    @pl.when(opens)
     def _():
         if plan.init:
             acc_s[...] = jnp.zeros_like(acc_s)
@@ -229,8 +252,8 @@ def _flash_kernel(plan: _Plan, offs, *refs):
         # the keys sequence b sees, and the tile of this step: the tile
         # that holds the last visible key is the open page, those before
         # it are sealed (whole, every key visible)
-        limit = lens[plan.lead0 + pl.program_id(0)]
-        tile = offs[1] // plan.bkv + t
+        limit = lens[plan.lead0 + seq]
+        tile = plan.tile0 + (step - start)
         k_lo = tile * plan.bkv
     else:
         q_lo = offs[0] + j * plan.bq
@@ -295,7 +318,8 @@ def _flash_kernel(plan: _Plan, offs, *refs):
     else:
         fold(False)
 
-    @pl.when(t == plan.steps - 1)
+    @pl.when(step == start + count - 1 if plan.paged
+             else t == plan.steps - 1)
     def _():
         if plan.finish:
             # FinalizeAttn's own float32 division, rounded once
@@ -549,8 +573,8 @@ def attn_fused_pallas(
 def paged_tiles(lens, page: int, k_pos: int = 0, span: Optional[int] = None):
     """Per sequence, the tiles of the key range ``k_pos .. k_pos + span``
     (to the end without ``span``) that hold a visible key, ``lens`` visible
-    keys each: what a call's grid walks (its extent over keys is the
-    largest) and what the program's ``mla.*`` counters count."""
+    keys each: what a call's grid walks (its extent is their sum) and what
+    the program's ``mla.*`` counters count."""
     first = k_pos // page
     out = []
     for n in lens:
@@ -561,17 +585,37 @@ def paged_tiles(lens, page: int, k_pos: int = 0, span: Optional[int] = None):
     return out
 
 
+def paged_step(tiles, s, fields: int = 3):
+    """``[sequence, its first step, its steps][:fields]`` of step ``s`` of
+    the grid that walks ``tiles[i]`` (:func:`paged_tiles`) tiles of sequence
+    i, sequence after sequence (a sequence with none has no step): the step
+    is on tile ``s - first step`` of the sequence's in the range.  The
+    sequences' first steps are immediates: a comparison and ``fields``
+    selects a sequence after the first, on the scalar core, in the kernel
+    and in each of its index maps."""
+    rows = [(i, sum(tiles[:i]), n) for i, n in enumerate(tiles) if n]
+    got = [np.int32(v) for v in rows[0][:fields]]
+    for row in rows[1:]:
+        past = jax.lax.ge(s, np.int32(row[1]))
+        got = [jax.lax.select(past, np.int32(v), g)
+               for v, g in zip(row, got)]
+    return got
+
+
 def _flash_paged(name, q, pool, k_open, lens, table, state, scale, v_dim,
-                 lead0, rows, k_pos, steps, o, interpret):
+                 lead0, k_pos, tiles, o, interpret):
     """The kernel body over a paged cache.  ``q`` ``(B, n, d)``: the n rows
     of every sequence (heads: they sit at one position); ``pool`` ``(pages,
     d, page)`` sealed pages and ``k_open`` ``(B, d, page)`` each sequence's
     open page, their keys as columns; ``lens`` ``(B,)`` visible keys;
     ``table`` ``(B, max_pages)``: tile j of sequence b is ``pool[table[b,
     j]]`` while ``j < (lens[b] - 1) // page`` and ``k_open[b]`` at that
-    tile, where the last visible key lies.  The grid is ``rows`` sequences
-    from ``lead0`` by ``steps`` tiles from key ``k_pos``; steps past a
-    sequence's last tile fetch nothing new and compute nothing.  V^T is a
+    tile, where the last visible key lies.  The call covers ``len(tiles)``
+    sequences from ``lead0``, sequence i over its ``tiles[i]`` tiles from
+    key ``k_pos`` (static: ``paged_tiles`` of the lengths ``lens`` holds),
+    and its grid is those ``sum(tiles)`` steps in order (:func:`paged_step`):
+    no step but folds a page.  A handed state is the output, aliased: a
+    sequence with no tile here is never fetched and keeps it.  V^T is a
     tile's first ``v_dim`` rows."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -586,39 +630,50 @@ def _flash_paged(name, q, pool, k_open, lens, table, state, scale, v_dim,
     if finish and not init:
         raise ValueError("a call that finishes its rows opens their state: "
                          "it is handed no acc, m and l")
-    plan = _Plan(float(scale), n, page, pages, int(steps), False, None, init,
-                 finish, dv=int(v_dim), paged=True, lead0=int(lead0))
+    if not any(tiles) or (init and not all(tiles)):
+        raise ValueError(f"tiles {tiles} from key {k_pos}: a call that opens "
+                         "the state gives every sequence a step, and every "
+                         "call some sequence")
+    rows, tile0 = len(tiles), k_pos // page
+    plan = _Plan(float(scale), n, page, pages, sum(tiles), False, None,
+                 init, finish, dv=int(v_dim), paged=True, lead0=int(lead0),
+                 tiles=tiles, tile0=tile0)
     max_pages = table.shape[1]
 
-    def seq(i, j, t, *_):
-        return (lead0 + i, 0, 0)
+    def seq(s, *_):
+        return (lead0 + paged_step(tiles, s, 1)[0], 0, 0)
 
-    def sealed(i, j, t, offs, lens, table):
+    def sealed(s, lens, table):
         # the sealed tile of this step, held at the sequence's last sealed
-        # page once past it (the open page's step and the idle ones fetch
-        # nothing new)
+        # page on its open page's step (which fetches nothing new of the
+        # pool: the next sequence's first page arrives behind that fold)
+        i, start = paged_step(tiles, s, 2)
         b = lead0 + i
         last = (lens[b] - 1) // page - 1
-        tile = jnp.clip(jnp.minimum(offs[1] // page + t, last), 0,
+        tile = jnp.clip(jnp.minimum(tile0 + (s - start), last), 0,
                         max_pages - 1)
         return (table[b, tile], 0, 0)
 
-    stblk = pl.BlockSpec((1, n, v_dim), lambda i, j, t, *_: (i, 0, 0))
+    stblk = pl.BlockSpec((1, n, v_dim),
+                         lambda s, *_: (paged_step(tiles, s, 1)[0], 0, 0))
     operands = (q, pool, k_open) + (() if init else tuple(state))
     in_specs = [pl.BlockSpec((1, n, d), seq),
                 pl.BlockSpec((1, d, page), sealed),
                 pl.BlockSpec((1, d, page), seq)] + [stblk] * (
                     0 if init else 3)
-    aliases = {}
+    scalars = 2  # the limits and the table lead the operands
     if finish:
         # O in place, as attn_fused's: aliased, unfetched, the rows of the
         # other sequences never touched
         operands += (o,)
         in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        aliases = {len(operands) + 2: 0}  # three scalar operands lead
+        aliases = {scalars + len(operands) - 1: 0}
         out_specs = [pl.BlockSpec((1, n, v_dim), seq)]
         out_shape = [out_struct(o.shape, o.dtype, *operands)]
     else:
+        # a handed state in place too: a sequence the walk never visits
+        # (no tile in this range) keeps its rows, unread and unwritten
+        aliases = {} if init else {scalars + 3 + i: i for i in range(3)}
         out_specs = [stblk] * 3
         out_shape = [out_struct((rows, n, v_dim), jnp.float32,
                                 *operands)] * 3
@@ -628,8 +683,10 @@ def _flash_paged(name, q, pool, k_open, lens, table, state, scale, v_dim,
     outs = pl.pallas_call(
         functools.partial(_flash_kernel, plan),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(rows, 1, plan.steps),
+            num_scalar_prefetch=scalars,
+            # strictly sequential: the VMEM scratch state carries across a
+            # sequence's steps
+            grid=(plan.steps,),
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((n, v_dim), jnp.float32)] * 3,
@@ -637,43 +694,47 @@ def _flash_paged(name, q, pool, k_open, lens, table, state, scale, v_dim,
         out_shape=out_shape,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem,
         ),
         name=name,
         interpret=interpret,
-    )(jnp.asarray([0, k_pos], jnp.int32), lens.astype(jnp.int32),
-      table.astype(jnp.int32), *operands)
+    )(lens.astype(jnp.int32), table.astype(jnp.int32), *operands)
     return outs[0] if finish else tuple(outs)
 
 
-_PAGED_STATIC = ("scale", "v_dim", "lead0", "rows", "steps", "interpret")
+_PAGED_STATIC = ("scale", "v_dim", "lead0", "tiles", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_PAGED_STATIC)
 def mla_decode_pallas(q, pool, k_open, lens, table, o, scale, *, v_dim: int,
-                      lead0: int, rows: int, steps: int,
+                      lead0: int, tiles: Tuple[int, ...],
                       interpret: Optional[bool] = None):
-    """One decode step's cache read for ``rows`` sequences from ``lead0``,
-    each over its whole cache, in ONE kernel (``mla_decode``): state in
-    VMEM, ``o`` ``(B, n, v_dim)`` returned with those sequences' rows
-    written (float32 ``acc / l``, rounded once) and every other row as it
-    came.  Operands as :func:`_flash_paged`; ``steps`` at least the most
-    tiles a sequence of the group has (:func:`paged_tiles`)."""
+    """One decode step's cache read for ``len(tiles)`` sequences from
+    ``lead0``, each over its whole cache, in ONE kernel (``mla_decode``):
+    state in VMEM, ``o`` ``(B, n, v_dim)`` returned with those sequences'
+    rows written (float32 ``acc / l``, rounded once) and every other row as
+    it came.  Operands as :func:`_flash_paged`; ``tiles`` the tiles each of
+    the sequences has (:func:`paged_tiles` of its visible keys): the grid is
+    their sum, one step a page."""
     return _flash_paged("mla_decode", q, pool, k_open, lens, table, None,
-                        scale, v_dim, lead0, rows, 0, steps, o, interpret)
+                        scale, v_dim, lead0, 0, tuple(tiles), o, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=_PAGED_STATIC + ("k_pos",))
 def mla_fold_pallas(q, pool, k_open, lens, table, acc, m, l, scale, *,
-                    v_dim: int, lead0: int, rows: int, k_pos: int,
-                    steps: int, interpret: Optional[bool] = None):
-    """One link of a split-K chain (``mla_fold``): the tiles ``k_pos //
-    page .. + steps`` of every sequence of the group folded into its
-    softmax state ``(rows, n, v_dim)`` float32 through HBM (``None``: from
-    the empty state).  A sequence with no visible key in the range keeps
-    its state.  Returns ``(acc', m', l')``."""
+                    v_dim: int, lead0: int, k_pos: int,
+                    tiles: Tuple[int, ...],
+                    interpret: Optional[bool] = None):
+    """One link of a split-K chain (``mla_fold``): of each of ``len(tiles)``
+    sequences from ``lead0`` the ``tiles[i]`` tiles from ``k_pos // page``
+    (:func:`paged_tiles` of the link's key range) folded into its softmax
+    state ``(len(tiles), n, v_dim)`` float32 through HBM (``None``: from the
+    empty state, every sequence with a tile).  The grid is the sum of
+    ``tiles``; a sequence with none has no step, and its rows of the handed
+    state, which is the output in place, come back as they were.  Returns
+    ``(acc', m', l')``."""
     state = None if acc is None else (acc, m, l)
     return _flash_paged("mla_fold", q, pool, k_open, lens, table, state,
-                        scale, v_dim, lead0, rows, k_pos, steps, None,
+                        scale, v_dim, lead0, k_pos, tuple(tiles), None,
                         interpret)

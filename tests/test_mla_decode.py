@@ -8,6 +8,7 @@ a key, none a multiple of the page) in four groups, pages of 8 tokens laid
 out through a shuffled table, two layers.
 """
 
+import dataclasses
 import hashlib
 import importlib.util
 import os
@@ -49,6 +50,16 @@ ARGS = LatentDecodeArgs(lens=LENS, heads=4, rank=16, rope=8, nope=8, v_dim=8,
                         scale=yarn_scale(8, 8), page=8, groups=4,
                         fold_pages=2, dtype="float32")
 LAYERS = ("L0", "L1")
+#: the step's shapes the engines are held to the reference on: the groups of
+#: neighbours above; a group whose sequences differ by twenty times (1, 1, 1
+#: and 8 pages: in the chain's second link three of four have no page); a
+#: group a sequence
+SHAPES = {
+    "neighbours": ARGS,
+    "tenfold": dataclasses.replace(
+        ARGS, lens=(3, 5, 6, 61, 62, 63, 64, 125), groups=2, fold_pages=4),
+    "singles": dataclasses.replace(ARGS, groups=8),
+}
 ENGINES = {"fused": (".fused",), "chain": (".chain", ".pallas"),
            "xla": (".chain", ".xla")}
 #: the widest row's gap a fault must pass and a sound float32 run stay far
@@ -123,23 +134,25 @@ def test_absorbed_form_is_the_published_form(seed):
         assert widest_row_gap(a, p) < 2e-5
 
 
+@pytest.mark.parametrize("shape", list(SHAPES))
 @pytest.mark.parametrize("engine", list(ENGINES))
-def test_system_matches_the_plain_reference(engine):
-    g, plat, ex, bufs = step()
+def test_system_matches_the_plain_reference(engine, shape):
+    args = SHAPES[shape]
+    g, plat, ex, bufs = step(args)
     seq = drive(g, plat, ENGINES[engine])
     assert ScheduleVerifier(g)(seq).ok
     names = [op.name() for op in seq]
     assert any(n.endswith(ENGINES[engine][-1]) for n in names)
     out = ex.run(seq)
     for layer in LAYERS:
-        want = reference(ARGS, bufs, layer)
+        want = reference(args, bufs, layer)
         np.testing.assert_allclose(np.asarray(out[f"o.{layer}"]), want,
                                    rtol=2e-4, atol=2e-5)
         assert widest_row_gap(np.asarray(out[f"o.{layer}"]), want) < 1e-4
         # the appended row, exact, and no other column touched
         opened = np.array(bufs[f"Copen.{layer}"])
-        for b, n in enumerate(LENS):
-            opened[b, :, n % ARGS.page] = np.concatenate(
+        for b, n in enumerate(args.lens):
+            opened[b, :, n % args.page] = np.concatenate(
                 [bufs[f"c_new.{layer}"][b], bufs[f"kr_new.{layer}"][b]])
         assert np.array_equal(np.asarray(out[f"Copen.{layer}"]), opened)
 
@@ -194,13 +207,16 @@ def test_counters_equal_a_count_from_the_lengths(engine):
     assert count["appended_rows"] == layers * len(LENS)
     assert count["keys_useful"] == layers * sum(n + 1 for n in LENS)
     assert count["page_steps"] == layers * sum(tiles)
-    if engine == "fused":
-        grid = sum(grp.rows * grp.steps for grp in plan)
-    else:
-        grid = sum(grp.rows * steps for grp in plan for _, steps in grp.links)
+    # a kernel's grid is the pages there are: only the XLA fold computes a
+    # link's rectangle, every sequence over the most pages one has there
+    assert sum(grp.steps for grp in plan) == sum(tiles)
+    assert all(sum(sum(t) for _, t in grp.links) == grp.steps
+               for grp in plan)
+    grid = sum(tiles) if engine != "xla" else sum(
+        grp.rows * max(t) for grp in plan for _, t in grp.links)
     assert count["page_steps_idle"] == layers * (grid - sum(tiles))
-    computed = grid if engine == "xla" else sum(tiles)
-    assert count["keys_computed"] == layers * computed * page
+    assert count["page_steps_idle"] == {"xla": layers * 4}.get(engine, 0)
+    assert count["keys_computed"] == layers * grid * page
 
 
 def test_the_grouping_is_one_span_of_the_program_s_tracing():
@@ -220,11 +236,15 @@ def test_plan_groups_neighbours_and_cuts_chains_by_pages():
     plan = decode_plan(ARGS)
     assert [(g.lead0, g.rows) for g in plan] == [(0, 2), (2, 2), (4, 2),
                                                  (6, 2)]
-    assert [g.steps for g in plan] == [2, 3, 4, 8]
-    # links of two pages: the longest group has four, the last one a page
-    # short for its shorter sequence
-    assert plan[3].links == ((0, 2), (16, 2), (32, 2), (48, 2))
-    assert plan[0].links == ((0, 2),)
+    # a group's grid: the pages its sequences have, summed (not the
+    # longest's by the rows: 4, 6, 8 and 16)
+    assert [g.tiles for g in plan] == [(1, 2), (2, 3), (4, 4), (6, 8)]
+    assert [g.steps for g in plan] == [3, 5, 8, 14]
+    # links of two pages, each with the pages a sequence has in it: the
+    # longest group has four, and its shorter sequence none in the last
+    assert plan[3].links == ((0, (2, 2)), (16, (2, 2)), (32, (2, 2)),
+                             (48, (0, 2)))
+    assert plan[0].links == ((0, (1, 2)),)
     assert paged_tiles([62, 45], 8, 48, 16) == [2, 0]
     with pytest.raises(ValueError, match="sorted"):
         LatentDecodeArgs(lens=(9, 3), groups=1)

@@ -294,8 +294,11 @@ def test_halo_and_rdma_modules_build_their_compiler_params():
 
 # -- the kernel body over a paged latent cache (ISSUE 35) ----------------------
 
-PAGED_LENS = [5, 9, 16, 17, 30, 8]  # visible keys: a page is 8
-PAGED_GROUPS = {"first_three": (0, 3), "last_three": (3, 3), "all": (0, 6)}
+PAGED_LENS = [5, 9, 16, 17, 30, 8, 3, 85]  # visible keys: a page is 8
+#: (first sequence, sequences): the last two a group of one sequence and a
+#: group whose lengths differ by more than ten times (1, 1 and 11 pages)
+PAGED_GROUPS = {"first_three": (0, 3), "last_three": (3, 3), "all": (0, 8),
+                "one": (4, 1), "tenfold": (5, 3)}
 
 
 def _paged_case(seed=0, page=8, d=24, dv=16, n=8):
@@ -341,10 +344,10 @@ def test_paged_decode_kernel_reads_each_sequence_through_its_table_row(group):
 
     arrays, want, page, dv = _paged_case()
     lead0, rows = PAGED_GROUPS[group]
-    steps = max(paged_tiles(PAGED_LENS[lead0:lead0 + rows], page))
+    tiles = tuple(paged_tiles(PAGED_LENS[lead0:lead0 + rows], page))
     o = jnp.full((len(PAGED_LENS), 8, dv), 7.0, jnp.float32)
     got = np.asarray(mla_decode_pallas(
-        *arrays, o, 0.3, v_dim=dv, lead0=lead0, rows=rows, steps=steps))
+        *arrays, o, 0.3, v_dim=dv, lead0=lead0, tiles=tiles))
     for b in range(len(PAGED_LENS)):
         if lead0 <= b < lead0 + rows:
             np.testing.assert_allclose(got[b], want(b, 0.3), rtol=2e-5,
@@ -370,10 +373,10 @@ def test_paged_fold_chain_is_the_fused_kernel(group, span):
     vis = PAGED_LENS[lead0:lead0 + rows]
     state = (None, None, None)
     for k_pos in range(0, max(vis), span * page):
-        steps = max(paged_tiles(vis, page, k_pos, span * page))
+        tiles = tuple(paged_tiles(vis, page, k_pos, span * page))
         before = state
         state = mla_fold_pallas(*arrays, *state, 0.3, v_dim=dv, lead0=lead0,
-                                rows=rows, k_pos=k_pos, steps=steps)
+                                k_pos=k_pos, tiles=tiles)
         for i, n_vis in enumerate(vis):
             if k_pos >= n_vis and before[0] is not None:
                 for new, old in zip(state, before):
@@ -382,8 +385,8 @@ def test_paged_fold_chain_is_the_fused_kernel(group, span):
     chain = np.asarray(state[0] / state[2])
     o = jnp.zeros((len(PAGED_LENS), 8, dv), jnp.float32)
     fused = np.asarray(mla_decode_pallas(
-        *arrays, o, 0.3, v_dim=dv, lead0=lead0, rows=rows,
-        steps=max(paged_tiles(vis, page))))
+        *arrays, o, 0.3, v_dim=dv, lead0=lead0,
+        tiles=tuple(paged_tiles(vis, page))))
     for i in range(rows):
         np.testing.assert_allclose(chain[i], want(lead0 + i, 0.3),
                                    rtol=2e-5, atol=2e-6)
@@ -397,10 +400,66 @@ def test_paged_kernel_refusals_and_its_tile_count():
         paged_tiles,
     )
 
-    assert paged_tiles(PAGED_LENS, 8) == [1, 2, 2, 3, 4, 1]
-    assert paged_tiles(PAGED_LENS, 8, 16, 16) == [0, 0, 0, 1, 2, 0]
+    assert paged_tiles(PAGED_LENS, 8) == [1, 2, 2, 3, 4, 1, 1, 11]
+    assert paged_tiles(PAGED_LENS, 8, 16, 16) == [0, 0, 0, 1, 2, 0, 0, 2]
     assert paged_tiles([8, 9], 8, 8) == [0, 1]
     arrays, _, _, dv = _paged_case()
     with pytest.raises(ValueError, match="starts at a page"):
         mla_fold_pallas(*arrays, None, None, None, 0.3, v_dim=dv, lead0=0,
-                        rows=6, k_pos=4, steps=1)
+                        k_pos=4, tiles=(1,) * 8)
+    # a grid has a step, and a state that is opened is opened for every row
+    state = (jnp.zeros((3, 8, dv)),) * 3
+    with pytest.raises(ValueError, match="every call some sequence"):
+        mla_fold_pallas(*arrays, *state, 0.3, v_dim=dv, lead0=0, k_pos=24,
+                        tiles=(0, 0, 0))
+    with pytest.raises(ValueError, match="opens the state"):
+        mla_fold_pallas(*arrays, None, None, None, 0.3, v_dim=dv, lead0=2,
+                        k_pos=16, tiles=(0, 1, 2))
+
+
+@pytest.mark.parametrize("case", ["cell_g0", "cell_g1", "cell_g2", "cell_g3",
+                                  "tenfold", "a_link", "one"])
+def test_paged_walk_is_the_pages_there_are(case):
+    """The grid of a paged call, step for step: sequence after sequence,
+    each on the tiles ``paged_tiles`` counts for it, ascending from the
+    range's first; a sequence's first step and its count with every step of
+    it; no step for a sequence with no tile in the range, and none that is
+    not a tile's."""
+    import json
+    import os
+
+    import jax
+
+    from tenzing_tpu.ops.attention_pallas import paged_step, paged_tiles
+
+    # ``dsv3-mla-decode.climb``'s cached lengths: its four groups are runs
+    # of four of the sorted list, its pages 2048 keys
+    with open(os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                           "benchmarks", "configs",
+                           "dsv3-mla-decode.json")) as f:
+        cell = sorted(json.load(f)["shapes"]["lens"])
+    lens, page, k_pos, span, want = {
+        "cell_g0": (cell[0:4], 2048, 0, None, (5, 5, 5, 6)),
+        "cell_g1": (cell[4:8], 2048, 0, None, (6, 7, 8, 9)),
+        "cell_g2": (cell[8:12], 2048, 0, None, (11, 14, 17, 21)),
+        "cell_g3": (cell[12:16], 2048, 0, None, (27, 34, 45, 64)),
+        "tenfold": ((700, 7001, 70001), 128, 0, None, (6, 55, 547)),
+        # g3's third link of 16 pages: the shortest sequence ended before it
+        "a_link": (cell[12:16], 2048, 32 * 2048, 16 * 2048,
+                   (0, 2, 13, 16)),
+        "one": ((30,), 8, 0, None, (4,)),
+    }[case]
+    tiles = paged_tiles([n + 1 for n in lens], page, k_pos, span)
+    assert tuple(tiles) == want
+    steps = [(i, t) for i, n in enumerate(tiles) for t in range(n)]
+    assert len(steps) == sum(tiles)
+    walked = np.asarray(jax.vmap(lambda s: jnp.stack(
+        paged_step(tiles, s)))(jnp.arange(len(steps), dtype=jnp.int32)))
+    starts = np.cumsum(tiles) - np.asarray(tiles)
+    for s, (i, t) in enumerate(steps):
+        assert tuple(walked[s]) == (i, starts[i], tiles[i])
+        assert s - walked[s, 1] == t
+    # an index map that wants the sequence alone gets the same one
+    assert [int(paged_step(tiles, jnp.int32(s), 1)[0])
+            for s in range(0, len(steps), 7)] == [
+                i for i, _ in steps[::7]]

@@ -499,11 +499,16 @@ def _mla_cell():
         fold_pages=shapes["fold_pages"])
 
 
+@pytest.mark.parametrize("group", [0, 3])
 @pytest.mark.parametrize("form", ["mla_decode", "mla_fold"])
-def test_latent_decode_kernels(one_chip, form):
-    """The paged kernel at the decode cell's shapes, its longest group:
-    Mosaic takes K tiles of ``(576, 2048)`` (the contraction 576 wide, V
-    the first 512 rows), three scalar operands, and O aliased in place;
+def test_latent_decode_kernels(one_chip, form, group):
+    """The paged kernel at the decode cell's shapes, its shortest group and
+    its longest (a grid of 21 and of 170 (sequence, page) steps: the pages
+    there are; a link of the chain 21 and 31, g3's third with no step for
+    its shortest sequence): Mosaic takes K tiles of ``(576, 2048)`` (the
+    contraction 576 wide, V the first 512 rows), two scalar operands (the
+    walk is immediates of the index maps), and O or the handed state aliased
+    in place;
     the pools arrive in the runtime's own layout, so the compiled program
     holds the kernel and no copy of a pool."""
     from tenzing_tpu.models.latent_attention import decode_plan
@@ -513,7 +518,8 @@ def test_latent_decode_kernels(one_chip, form):
     )
 
     a = _mla_cell()
-    grp = decode_plan(a)[-1]
+    grp = decode_plan(a)[group]
+    assert grp.tiles == ((5, 5, 5, 6), (27, 34, 45, 64))[group > 0]
     bf = jnp.bfloat16
     operands = (
         _sds((a.batch, a.heads, a.width), bf, one_chip),
@@ -521,17 +527,17 @@ def test_latent_decode_kernels(one_chip, form):
         _sds((a.batch, a.width, a.page), bf, one_chip),
         _sds((a.batch,), jnp.int32, one_chip),
         _sds((a.batch, a.max_pages), jnp.int32, one_chip))
-    common = dict(v_dim=a.rank, lead0=grp.lead0, rows=grp.rows,
-                  interpret=False)
+    common = dict(v_dim=a.rank, lead0=grp.lead0, interpret=False)
     if form == "mla_decode":
         o = _sds((a.batch, a.heads, a.rank), bf, one_chip)
         compiled = mla_decode_pallas.lower(
-            *operands, o, a.scale, steps=grp.steps, **common).compile()
+            *operands, o, a.scale, tiles=grp.tiles, **common).compile()
     else:
         st = _sds((grp.rows, a.heads, a.rank), jnp.float32, one_chip)
-        k_pos, steps = grp.links[-1]
+        k_pos, tiles = grp.links[(0, 2)[group > 0]]
+        assert tiles == ((5, 5, 5, 6), (0, 2, 13, 16))[group > 0]
         compiled = mla_fold_pallas.lower(
-            *operands, st, st, st, a.scale, k_pos=k_pos, steps=steps,
+            *operands, st, st, st, a.scale, k_pos=k_pos, tiles=tiles,
             **common).compile()
     _assert_kernel(compiled)
     text = compiled.as_text()
