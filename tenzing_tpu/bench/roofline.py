@@ -170,6 +170,36 @@ def latent_decode_cost(lens, heads: int, rank: int, rope: int, v_dim: int,
                 hbm_bytes=float(layers * bytes_per_el * els))
 
 
+def sparse_decode_cost(lens, heads: int, rank: int, rope: int, v_dim: int,
+                       index_heads: int, index_dim: int, topk: int,
+                       layers: int = 1, nope: int = 128,
+                       bytes_per_el: int = 2) -> Cost:
+    """One decode step of learned sparse attention
+    (models/sparse_attention.py): an indexer over every visible key (``2
+    index_dim`` an index head and key), an exact top-``topk`` (no
+    floating-point work), and :func:`latent_decode_cost`'s attention over
+    the ``min(topk, L_b + 1)`` selected keys a sequence alone.  HBM, a
+    floor: every visible key's index row and every selected key's latent
+    row read once, both appended rows written, inputs and weights read and
+    ``o`` written once; the scores' trip to the selection, the selection
+    and the gathered tile are not counted.  At DeepSeek-V3.2's widths an
+    indexed key is 16 384 FLOPs for 256 bytes: bound by HBM, four times
+    under the ridge."""
+    batch, width = len(lens), rank + rope
+    keys = sum(int(n) + 1 for n in lens)
+    selected = sum(min(topk, int(n) + 1) for n in lens)
+    flops = (2.0 * index_heads * index_dim * keys
+             + 2.0 * heads * selected * (width + rank)
+             + 2.0 * batch * heads * (nope * rank + rank * v_dim))
+    els = (keys * index_dim + selected * width + batch * (width + index_dim)
+           + batch * heads * (nope + rope) + batch * width
+           + batch * index_heads * index_dim + batch * index_dim
+           + heads * (nope * rank + rank * v_dim) + batch * heads * v_dim)
+    return Cost(flops=layers * flops,
+                hbm_bytes=float(layers * (bytes_per_el * els
+                                          + 4 * batch * index_heads)))
+
+
 def moe_cost(tokens: int, d_model: int, d_ff: int, bytes_per_el: int = 4,
              staged: bool = False, n_experts: int = 8) -> Cost:
     """Top-1 routed MoE layer: every token through one gelu MLP —

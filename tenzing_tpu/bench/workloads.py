@@ -39,6 +39,17 @@ The workloads (``DriverRequest.workload`` / the CLI's ``--workload``):
   runs DeepSeek-V3's widths on eight short sequences (toy widths with
   ``--smoke``), the benchmark's ``dsv3-mla-decode`` 16 sequences of 8k to
   128k through four layers.
+* ``dsa_decode``: one decode step of learned sparse attention (DeepSeek-
+  V3.2's DSA) over a paged index-key cache and a paged latent cache
+  (``models/sparse_attention.py``): per layer the two appends, the absorb
+  einsum, one chain a group of sequences (the ``dsa_index`` kernel over the
+  group's pages of index keys, an exact top-k, a gather of the selected
+  latent rows, ``mla_decode`` over the gathered tile; or, the menu's other
+  entry, one selection for the layer between the indexes and the gathers)
+  and the up-projection.  This row runs DeepSeek-V3.2's
+  widths on ``mla_decode``'s eight short sequences with 1024 selected
+  (toy widths with ``--smoke``), the benchmark's ``dsv32-dsa-decode`` 16
+  sequences of 8k to 128k with the published 2048 through four layers.
 * ``moe``: single-chip MoE dispatch/combine pipeline — routed tokens staged
   through async host round-trip DMAs to the resident experts (the
   expert-parallel network-hop analog), searched over order x lane x
@@ -612,6 +623,59 @@ def _mla_cost(built):
                                        a.v_dim, nope=a.nope)
 
 
+# -- dsa_decode ---------------------------------------------------------------
+
+
+def _dsa_dims(req):
+    """The sparse step's sizes beside the latent step's (``_mla_dims``):
+    index heads and width, keys selected."""
+    if req.smoke:
+        return dict(index_heads=4, index_dim=8, topk=16)
+    # DeepSeek-V3.2's indexer on the toy batch, half its 2048 selected so
+    # that sequences lie on either side of it
+    return dict(index_heads=64, index_dim=128, topk=1024)
+
+
+def _dsa_args(req):
+    from tenzing_tpu.models.sparse_attention import SparseDecodeArgs
+
+    return SparseDecodeArgs(_mla_args(req), **_dsa_dims(req))
+
+
+def _dsa_shape(req):
+    d = _dsa_dims(req)
+    return {**_mla_shape(req), "index_heads": d["index_heads"],
+            "index_dim": d["index_dim"], "topk": d["topk"]}
+
+
+def _dsa_parts(req):
+    from tenzing_tpu.models.sparse_attention import (
+        dsa_graph,
+        make_dsa_buffers,
+    )
+
+    a = _dsa_args(req)
+    # the index's kernel menu only where a chip compiles it, as the halo's
+    g = dsa_graph(a, ("L0",), impl_choice=not req.smoke)
+    return g, make_dsa_buffers(a, ("L0",), seed=0), a
+
+
+def build_dsa_decode(args):
+    import jax.numpy as jnp
+
+    g, bufs, a = _dsa_parts(args)
+    bufs = {k: jnp.asarray(v) for k, v in bufs.items()}
+    return g, bufs, metric_for("dsa_decode", args), a
+
+
+def _dsa_cost(built):
+    a = built[3]
+    lat = a.latent
+    return roofline.sparse_decode_cost(
+        lat.lens, lat.heads, lat.rank, lat.rope, lat.v_dim, a.index_heads,
+        a.index_dim, a.topk, nope=lat.nope)
+
+
 # -- the table ----------------------------------------------------------------
 
 @dataclass
@@ -727,6 +791,12 @@ WORKLOADS: Dict[str, Workload] = {
         metric=lambda req: "mla_decode_pct50_searched_k%d" % (
             _mla_shape(req)["keys"]),
         cost=_mla_cost, phases=lambda: ("L0.",)),
+    "dsa_decode": Workload(
+        build=build_dsa_decode, graph=_device_free(_dsa_parts),
+        shape=_dsa_shape,
+        metric=lambda req: "dsa_decode_pct50_searched_k%d" % (
+            _dsa_shape(req)["keys"]),
+        cost=_dsa_cost, phases=lambda: ("L0.",)),
     "moe": Workload(
         build=build_moe, graph=_device_free(_moe_parts), shape=_moe_shape,
         metric=lambda req: "moe_pipe_pct50_searched_t%d" % (

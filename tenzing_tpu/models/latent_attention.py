@@ -183,7 +183,9 @@ def _names(layer: str, grp: Optional[Group] = None) -> Dict[str, str]:
     (they run one after another)."""
     t = f".{layer}" if layer else ""
     n = {k: k + t for k in ("C", "Copen", "c_new", "kr_new", "q_nope",
-                            "q_rope", "W_UK", "W_UV", "qt", "o_lat", "o")}
+                            "q_rope", "W_UK", "W_UV", "qt", "o_lat", "o",
+                            # a sparse step's (models/sparse_attention.py)
+                            "KI", "KIopen", "kI_new", "qI", "wI", "sel")}
     n.update(lens="lens", table="table")
     if grp is not None:
         n.update({s: f"{s}.g{grp.index}" for s in STATE})
@@ -192,18 +194,26 @@ def _names(layer: str, grp: Optional[Group] = None) -> Dict[str, str]:
 
 class Append(DeviceOp):
     """Row ``L_b`` of every sequence's cache: column ``L_b % page`` of its
-    open page becomes ``[c_new ; k_rope_new]`` (step 1)."""
+    open page becomes ``[c_new ; k_rope_new]`` (step 1).  ``src`` and
+    ``dst`` name another cache's new row and open pages (a sparse step's
+    index keys); ``as_rows``: an open page holds its keys as rows, ``(page,
+    row width)``, the new row zero past its own width; ``counter``: the
+    program's counter the rows are added to."""
 
-    def __init__(self, name: str, args: LatentDecodeArgs, layer: str = ""):
+    def __init__(self, name: str, args: LatentDecodeArgs, layer: str = "",
+                 src=("c_new", "kr_new"), dst: str = "Copen",
+                 as_rows: bool = False, counter: str = "mla.appended_rows"):
         super().__init__(name)
         self._args = args
         self._n = _names(layer)
+        self._src, self._dst = tuple(src), dst
+        self._as_rows, self._counter = as_rows, counter
 
     def reads(self):
-        return [self._n[k] for k in ("c_new", "kr_new", "Copen")]
+        return [self._n[k] for k in self._src + (self._dst,)]
 
     def writes(self):
-        return [self._n["Copen"]]
+        return [self._n[self._dst]]
 
     def apply(self, bufs, ctx):
         import jax.numpy as jnp
@@ -212,17 +222,22 @@ class Append(DeviceOp):
         from tenzing_tpu.obs.metrics import get_metrics
 
         a, n = self._args, self._n
-        opened = bufs[n["Copen"]]
-        new = jnp.concatenate([bufs[n["c_new"]], bufs[n["kr_new"]]],
+        opened = bufs[n[self._dst]]
+        new = jnp.concatenate([bufs[n[k]] for k in self._src],
                               axis=1).astype(opened.dtype)
-        get_metrics().counter("mla.appended_rows").inc(a.batch)
+        get_metrics().counter(self._counter).inc(a.batch)
+        if self._as_rows:
+            new = jnp.pad(new, ((0, 0), (0, opened.shape[2] - new.shape[1])))
         # one update in place a sequence, at a column the lengths fix: a
         # scatter makes the compiler lay the open pages out for the scatter
         # and copy them whole for the kernel, every iteration
         for b, length in enumerate(a.lens):
+            at = length % a.page
             opened = lax.dynamic_update_slice(
-                opened, new[b][None, :, None], (b, 0, length % a.page))
-        return {n["Copen"]: opened}
+                opened, new[b][None, None, :] if self._as_rows
+                else new[b][None, :, None],
+                (b, at, 0) if self._as_rows else (b, 0, at))
+        return {n[self._dst]: opened}
 
 
 class Absorb(DeviceOp):
@@ -287,10 +302,12 @@ class MlaDecode(DeviceOp):
     buffer touched (``FusedBlockAttn``'s finishing form)."""
 
     def __init__(self, name: str, args: LatentDecodeArgs, grp: Group,
-                 layer: str = ""):
+                 layer: str = "", names: Optional[Dict[str, str]] = None):
         super().__init__(name)
         self._args, self._grp = args, grp
-        self._n = _names(layer, grp)
+        # ``names``: buffers that stand in for the cache's (a sparse step
+        # hands its gathered tile as the open pages of a one-page cache)
+        self._n = {**_names(layer, grp), **(names or {})}
 
     def reads(self):
         return [self._n[k] for k in _CACHE + ("o_lat",)]

@@ -114,6 +114,14 @@ Paged (:func:`_flash_paged`; what the operands handed decide, no switch):
   ``k_open`` and O, which are the whole batch's: no slice of them is made
   for a group.
 
+The paged walk also serves a kernel with another body
+(:func:`dsa_index_pallas`, ``dsa_index``; models/sparse_attention.py): the
+scores a sparse selection is made from, ``sum_h w_h relu(q_h . k_j)`` over a
+paged cache of index keys.  The same grid of the pages there are, the same
+scalar operands and index maps (:func:`_paged_maps`), the open page as a
+second K operand, no softmax and no state: a step writes its page of one
+row of scores a sequence, ``NEG`` past the sequence's limit.
+
 ``interpret=True`` (automatic off-TPU) runs the same kernel in the Pallas
 interpreter for CPU tests.
 """
@@ -602,6 +610,28 @@ def paged_step(tiles, s, fields: int = 3):
     return got
 
 
+def _paged_maps(tiles, lead0: int, tile0: int, page: int, max_pages: int):
+    """``(seq, sealed)``: the index maps of the paged walk's operands, for
+    the step's sequence (Q, the open page, O, a state) and for the tile of
+    the sealed pool it is on."""
+
+    def seq(s, *_):
+        return (lead0 + paged_step(tiles, s, 1)[0], 0, 0)
+
+    def sealed(s, lens, table):
+        # the sealed tile of this step, held at the sequence's last sealed
+        # page on its open page's step (which fetches nothing new of the
+        # pool: the next sequence's first page arrives behind that fold)
+        i, start = paged_step(tiles, s, 2)
+        b = lead0 + i
+        last = (lens[b] - 1) // page - 1
+        tile = jnp.clip(jnp.minimum(tile0 + (s - start), last), 0,
+                        max_pages - 1)
+        return (table[b, tile], 0, 0)
+
+    return seq, sealed
+
+
 def _flash_paged(name, q, pool, k_open, lens, table, state, scale, v_dim,
                  lead0, k_pos, tiles, o, interpret):
     """The kernel body over a paged cache.  ``q`` ``(B, n, d)``: the n rows
@@ -638,21 +668,7 @@ def _flash_paged(name, q, pool, k_open, lens, table, state, scale, v_dim,
     plan = _Plan(float(scale), n, page, pages, sum(tiles), False, None,
                  init, finish, dv=int(v_dim), paged=True, lead0=int(lead0),
                  tiles=tiles, tile0=tile0)
-    max_pages = table.shape[1]
-
-    def seq(s, *_):
-        return (lead0 + paged_step(tiles, s, 1)[0], 0, 0)
-
-    def sealed(s, lens, table):
-        # the sealed tile of this step, held at the sequence's last sealed
-        # page on its open page's step (which fetches nothing new of the
-        # pool: the next sequence's first page arrives behind that fold)
-        i, start = paged_step(tiles, s, 2)
-        b = lead0 + i
-        last = (lens[b] - 1) // page - 1
-        tile = jnp.clip(jnp.minimum(tile0 + (s - start), last), 0,
-                        max_pages - 1)
-        return (table[b, tile], 0, 0)
+    seq, sealed = _paged_maps(tiles, lead0, tile0, page, table.shape[1])
 
     stblk = pl.BlockSpec((1, n, v_dim),
                          lambda s, *_: (paged_step(tiles, s, 1)[0], 0, 0))
@@ -738,3 +754,98 @@ def mla_fold_pallas(q, pool, k_open, lens, table, acc, m, l, scale, *,
     return _flash_paged("mla_fold", q, pool, k_open, lens, table, state,
                         scale, v_dim, lead0, k_pos, tuple(tiles), None,
                         interpret)
+
+
+# -- a paged index-key cache: the scores a sparse selection is made from ----------
+
+
+def _index_kernel(plan: _Plan, lens, table, q_ref, w_ref, k_ref, ko_ref, _,
+                  o_ref):
+    """One (sequence, page) step of the paged walk, with no softmax and no
+    state: the page's index scores ``sum_h w_h relu(q_h . k_j)``, products
+    and sums in float32, keys past the sequence's limit written as
+    ``NEG``."""
+    step = pl.program_id(0)
+    seq, start = paged_step(plan.tiles, step, 2)
+    limit = lens[plan.lead0 + seq]
+    tile = plan.tile0 + (step - start)
+    open_tile = (limit - 1) // plan.bkv
+
+    def score(k_ref, edge: bool):
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (heads, page)
+        row = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0][:, :1], axis=0,
+                      keepdims=True)
+        if edge:
+            seen = tile * plan.bkv + jax.lax.broadcasted_iota(
+                jnp.int32, row.shape, 1) < limit
+            row = jnp.where(seen, row, NEG)
+        o_ref[0] = row
+
+    pl.when(tile < open_tile)(lambda: score(k_ref, False))
+    pl.when(tile == open_tile)(lambda: score(ko_ref, True))
+
+
+@functools.partial(jax.jit, static_argnames=("lead0", "tiles", "interpret"))
+def dsa_index_pallas(q, w, pool, k_open, lens, table, scores, *, lead0: int,
+                     tiles: Tuple[int, ...],
+                     interpret: Optional[bool] = None):
+    """The lightning indexer's scores of ``len(tiles)`` sequences from
+    ``lead0`` over their whole index-key cache, in ONE kernel
+    (``dsa_index``) on the paged walk of :func:`_flash_paged`: ``scores[b,
+    0, j] = sum_h w[b, h] relu(q[b, h] . KI[b, j])`` for the keys of the
+    ``tiles[i]`` pages sequence ``lead0 + i`` has (:func:`paged_tiles` of
+    its visible keys), ``NEG`` past its limit inside its open page.
+
+    ``q`` ``(B, heads, d)``; ``w`` ``(B, heads)`` float32; ``pool``
+    ``(pages, d, page)`` sealed pages of index keys and ``k_open`` ``(B, d,
+    page)`` the open ones, keys as columns; ``lens`` ``(B,)`` visible keys,
+    ``table`` ``(B, max_pages)``; ``scores`` ``(B, 1, max_pages * page)``
+    float32, returned with those sequences' pages written and everything
+    else as it came (aliased, never fetched: a page no step visits keeps
+    what it held, so a reader masks by the lengths too).  The grid is the
+    sum of ``tiles``: one step a page, no step that scores none."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    tiles = tuple(tiles)
+    _, heads, d = q.shape
+    pages, _, page = pool.shape
+    if not all(tiles):
+        raise ValueError(f"tiles {tiles}: every sequence of the call has a "
+                         "visible key, so a page")
+    if scores.shape != (q.shape[0], 1, table.shape[1] * page):
+        raise ValueError(f"scores {scores.shape}: one row a sequence over "
+                         f"{table.shape[1]} pages of {page}")
+    plan = _Plan(1.0, heads, page, pages, sum(tiles), False, None, True,
+                 paged=True, lead0=int(lead0), tiles=tiles)
+    seq, sealed = _paged_maps(tiles, lead0, 0, page, table.shape[1])
+
+    def out(s, *_):
+        i, start = paged_step(tiles, s, 2)
+        return (lead0 + i, 0, s - start)
+
+    # a head's weight along a row of lanes: the kernel takes its column
+    wide = jnp.broadcast_to(w.astype(jnp.float32)[:, :, None],
+                            w.shape + (128,))
+    operands = (q, wide, pool, k_open, scores)
+    scalars = 2
+    return pl.pallas_call(
+        functools.partial(_index_kernel, plan),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=scalars,
+            grid=(plan.steps,),
+            in_specs=[pl.BlockSpec((1, heads, d), seq),
+                      pl.BlockSpec((1, heads, 128), seq),
+                      pl.BlockSpec((1, d, page), sealed),
+                      pl.BlockSpec((1, d, page), seq),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, page), out),
+        ),
+        out_shape=out_struct(scores.shape, scores.dtype, *operands),
+        input_output_aliases={scalars + len(operands) - 1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="dsa_index",
+        interpret=interpret,
+    )(lens.astype(jnp.int32), table.astype(jnp.int32), *operands)

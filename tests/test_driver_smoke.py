@@ -73,6 +73,8 @@ CASES = {
              "attn_blockwise_pct50_searched_n64", [], ["fault"]),
     "mla_decode": (dict(workload="mla_decode", mcts_iters=6),
                    "mla_decode_pct50_searched_k212", [], ["fault"]),
+    "dsa_decode": (dict(workload="dsa_decode", mcts_iters=6),
+                   "dsa_decode_pct50_searched_k212", [], ["fault"]),
 }
 # two files, so that --dist loadfile gives the runs to two workers: the
 # switches of one workload here, the plain workloads in
@@ -104,6 +106,7 @@ EXTRA = {
     "spmv": ([], "incumbents"),
     "attn": ([], "incumbents"),
     "mla_decode": ([], "incumbents"),
+    "dsa_decode": ([], "incumbents"),
 }
 
 

@@ -39,6 +39,8 @@ from tenzing_tpu.obs.metrics import MetricsRegistry, set_metrics
 from tenzing_tpu.ops.attention_pallas import (
     attn_block_pallas,
     attn_fused_pallas,
+    mla_decode_pallas,
+    mla_fold_pallas,
     paged_tiles,
 )
 from tenzing_tpu.runtime.executor import TraceExecutor
@@ -282,6 +284,12 @@ PINNED = {
         "708cd4a19ccf362ea5265d7ca77732e667f6dc8565af9797f29ec9de5792e924",
     "fused_finish_fresh":
         "ce8b40bf3950e1bed2ccf68354349dc4ddeea9627464d54ba06764882404ffc5",
+    # the paged two, pinned at PR 40 from PR 39's tree, before the walk's
+    # index maps were shared with ``dsa_index``
+    "paged_decode":
+        "3dcbd4bec305eedb541e64013e342a41468700659488dbde91511626bb6e7e23",
+    "paged_fold_state":
+        "6a0ff4948724a4d22aea3ae3f4d2f4e59efe2f80ee3790151295c6b4b6eecbec",
 }
 
 
@@ -290,7 +298,19 @@ def _prefill_calls():
     q, k = jnp.zeros((4, 16, 8), f32), jnp.zeros((2, 32, 8), f32)
     st = tuple(jnp.zeros((4, 16, 8), f32) for _ in range(3))
     o = jnp.zeros((4, 64, 8), f32)
+    pq = jnp.zeros((4, 4, 24), f32)
+    pool, opened = jnp.zeros((6, 24, 8), f32), jnp.zeros((4, 24, 8), f32)
+    lens = jnp.asarray([4, 10, 18, 27], jnp.int32)
+    table = jnp.zeros((4, 4), jnp.int32)
+    po = jnp.zeros((4, 4, 16), f32)
+    pst = tuple(jnp.zeros((2, 4, 16), f32) for _ in range(3))
     return {
+        "paged_decode": (lambda *a: mla_decode_pallas(
+            *a, 0.5, v_dim=16, lead0=2, tiles=(3, 4), interpret=True),
+            (pq, pool, opened, lens, table, po)),
+        "paged_fold_state": (lambda *a: mla_fold_pallas(
+            *a, 0.5, v_dim=16, lead0=2, k_pos=16, tiles=(1, 2),
+            interpret=True), (pq, pool, opened, lens, table) + pst),
         "fold_state": (lambda *a: attn_block_pallas(
             *a, 0.5, bkv=16, interpret=True), (q, k, k) + st),
         "fold_init_masked": (lambda q, k, v: attn_block_pallas(
@@ -310,7 +330,9 @@ def _prefill_calls():
 @pytest.mark.parametrize("call", list(PINNED))
 def test_prefill_entry_points_trace_what_they_traced(call):
     """``attn_fold`` and ``attn_fused`` share the kernel body the decode
-    step extended: their jaxprs, kernel body included, are pinned.  The two
+    step extended, ``mla_decode`` and ``mla_fold`` the paged walk the
+    sparse step's ``dsa_index`` took over: their jaxprs, kernel body
+    included, are pinned.  The two
     unmasked calls are PR 34's to the letter; the three masked ones were
     pinned again at PR 37, which moved a query tile's idle K/V steps in
     front of its folds (``walk_step``: same folds, same order, O to the

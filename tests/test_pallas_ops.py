@@ -417,6 +417,61 @@ def test_paged_kernel_refusals_and_its_tile_count():
                         k_pos=16, tiles=(0, 1, 2))
 
 
+# -- the paged walk without the softmax: a sparse selection's scores (ISSUE 40) --
+
+@pytest.mark.parametrize("group", list(PAGED_GROUPS))
+def test_index_kernel_scores_each_sequence_through_its_table_row(group):
+    """``dsa_index``: ``sum_h w_h relu(q_h . k_j)`` for every visible key of
+    the group's sequences, sealed pages through the table and the last from
+    the open pool, ``NEG`` past a length inside its open page; pages past a
+    sequence's last and the rows of sequences outside the group keep what
+    they held."""
+    from tenzing_tpu.ops.attention_pallas import (
+        NEG,
+        dsa_index_pallas,
+        paged_tiles,
+    )
+
+    (q, pool, opened, lens, table), _, page, _ = _paged_case(seed=2)
+    w = jnp.asarray(np.random.default_rng(3).standard_normal(
+        q.shape[:2]).astype(np.float32))
+    lead0, rows = PAGED_GROUPS[group]
+    tiles = tuple(paged_tiles(PAGED_LENS[lead0:lead0 + rows], page))
+    held = jnp.full((len(PAGED_LENS), 1, table.shape[1] * page), 7.0,
+                    jnp.float32)
+    got = np.asarray(dsa_index_pallas(q, w, pool, opened, lens, table, held,
+                                      lead0=lead0, tiles=tiles))
+    q, w, pool, opened, table = (np.asarray(x, np.float64) if x.dtype !=
+                                 jnp.int32 else np.asarray(x)
+                                 for x in (q, w, pool, opened, table))
+    for b, n in enumerate(PAGED_LENS):
+        if not lead0 <= b < lead0 + rows:
+            assert (got[b] == 7.0).all()
+            continue
+        sealed = -(-n // page) - 1
+        keys = np.concatenate([pool[table[b, j]].T for j in range(sealed)]
+                              + [opened[b].T])
+        want = (np.maximum(q[b] @ keys.T, 0.0) * w[b][:, None]).sum(0)
+        np.testing.assert_allclose(got[b, 0, :n], want[:n], rtol=2e-5,
+                                   atol=2e-6)
+        assert (got[b, 0, n:(sealed + 1) * page] == NEG).all()
+        assert (got[b, 0, (sealed + 1) * page:] == 7.0).all()
+
+
+def test_index_kernel_refusals():
+    from tenzing_tpu.ops.attention_pallas import dsa_index_pallas
+
+    (q, pool, opened, lens, table), _, page, _ = _paged_case()
+    w = jnp.ones(q.shape[:2], jnp.float32)
+    scores = jnp.zeros((len(PAGED_LENS), 1, table.shape[1] * page))
+    with pytest.raises(ValueError, match="a visible key, so a page"):
+        dsa_index_pallas(q, w, pool, opened, lens, table, scores, lead0=0,
+                         tiles=(1, 0, 2))
+    with pytest.raises(ValueError, match="one row a sequence"):
+        dsa_index_pallas(q, w, pool, opened, lens, table, scores[:, 0],
+                         lead0=0, tiles=(1, 2, 2))
+
+
 @pytest.mark.parametrize("case", ["cell_g0", "cell_g1", "cell_g2", "cell_g3",
                                   "tenfold", "a_link", "one"])
 def test_paged_walk_is_the_pages_there_are(case):

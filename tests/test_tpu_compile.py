@@ -594,6 +594,127 @@ def test_latent_decode_loop_moves_no_pool(one_chip, monkeypatch, which,
     assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
 
 
+# dsv32-dsa-decode.climb: dsv3-mla-decode's sixteen sequences; 64 index heads
+# over a paged index-key cache of 128-wide keys, the 2048 largest scores a
+# sequence, their latent rows gathered from row-major pools of 640-wide rows
+def _dsa_cell():
+    import json
+
+    from tenzing_tpu.models.latent_attention import LatentDecodeArgs
+    from tenzing_tpu.models.latent_attention_reference import yarn_scale
+    from tenzing_tpu.models.sparse_attention import SparseDecodeArgs
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "dsv32-dsa-decode.json")) as f:
+        config = json.load(f)
+    shapes = config["shapes"]
+    return SparseDecodeArgs(
+        LatentDecodeArgs(lens=tuple(sorted(shapes["lens"])),
+                         scale=yarn_scale(), page=shapes["page_tokens"],
+                         groups=shapes["groups"]),
+        index_heads=config["index_n_heads"],
+        index_dim=config["index_head_dim"], topk=shapes["index_topk"])
+
+
+@pytest.mark.parametrize("group", [0, 1])
+def test_sparse_decode_kernels(one_chip, group):
+    """The sparse cell's two kernels at its shapes, its shorter group and
+    its longer: ``dsa_index`` on the paged walk (a grid of 51 and of 233
+    steps, K tiles of ``(128, 2048)``, a ``(1, 1, 2048)`` block of the one
+    row of scores a sequence, aliased in place) and ``mla_decode`` over the
+    gathered tiles as it stands (a step a sequence, the tiles as open pages
+    and as the one sealed block nobody walks).  Neither moves a pool."""
+    from tenzing_tpu.models.sparse_attention import dsa_plan
+    from tenzing_tpu.ops.attention_pallas import (
+        dsa_index_pallas,
+        mla_decode_pallas,
+    )
+
+    args = _dsa_cell()
+    a = args.latent
+    grp, tile = dsa_plan(args)[group]
+    assert grp.tiles == ((5, 5, 5, 6, 6, 7, 8, 9),
+                         (11, 14, 17, 21, 27, 34, 45, 64))[group]
+    assert tile.tiles == (1,) * 8 and args.picked == (2048,) * 16
+    bf = jnp.bfloat16
+    index = dsa_index_pallas.lower(
+        _sds((a.batch, args.index_heads, args.index_dim), bf, one_chip),
+        _sds((a.batch, args.index_heads), jnp.float32, one_chip),
+        _sds((a.pool_pages, args.index_dim, a.page), bf, one_chip),
+        _sds((a.batch, args.index_dim, a.page), bf, one_chip),
+        _sds((a.batch,), jnp.int32, one_chip),
+        _sds((a.batch, a.max_pages), jnp.int32, one_chip),
+        _sds((a.batch, 1, a.max_pages * a.page), jnp.float32, one_chip),
+        lead0=grp.lead0, tiles=grp.tiles, interpret=False).compile()
+    tiles = _sds((a.batch, a.width, args.topk), bf, one_chip)
+    read = mla_decode_pallas.lower(
+        _sds((a.batch, a.heads, a.width), bf, one_chip), tiles, tiles,
+        _sds((a.batch,), jnp.int32, one_chip),
+        _sds((a.batch, 1), jnp.int32, one_chip),
+        _sds((a.batch, a.heads, a.rank), bf, one_chip), a.scale,
+        v_dim=a.rank, lead0=tile.lead0, tiles=tile.tiles,
+        interpret=False).compile()
+    for compiled, name in ((index, "dsa_index"), (read, "mla_decode")):
+        _assert_kernel(compiled)
+        text = compiled.as_text()
+        assert name in text  # the name the device trace shows
+        big = (f"bf16[{a.pool_pages},", f"bf16[{a.batch},{a.width},",
+               f"bf16[{a.batch},{args.index_dim},{a.page}]")
+        moved = [l for l in text.splitlines() if " copy(" in l
+                 and l.split(" = ")[1].startswith(big)]
+        assert not moved  # no pool, no tile (what is not donated here, is)
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_sparse_decode_loop_moves_no_pool(one_chip, monkeypatch):
+    """The repeat-n program of ``dsv32-dsa-decode.climb``'s start point as
+    the TPU compiler leaves it: 8 ``dsa_index`` and 8 ``mla_decode``;
+    inside the ``while`` body nothing touches a sealed pool of either cache
+    but the index kernels and the gathers (a row-major pool is gathered
+    from as it lies: no copy of it), an open pool only takes its 16
+    one-row or one-column updates in place a layer, and the program's
+    temporaries stay under one latent pool (0.70 GB)."""
+    from benchmarks.builders.dsa_decode import start_prefer
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models.sparse_attention import buffer_shapes, dsa_graph
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+    from tenzing_tpu.runtime.executor import TraceExecutor
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = _dsa_cell()
+    a = args.latent
+    tags = [f"L{i}" for i in range(4)]
+    bufs = {name: _sds(shape, jnp.dtype(dtype), one_chip)
+            for name, (shape, dtype) in buffer_shapes(args, tags).items()}
+    graph = dsa_graph(args, tags)
+    plat = Platform.make_n_lanes(2)
+    seq, _ = drive(graph, plat, phase_policy(
+        plat, [t + "." for t in tags], start_prefer))
+    ex = TraceExecutor(plat, bufs)
+    compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(
+        bufs, _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 16
+    for sealed in (f"bf16[{a.pool_pages},{a.page},{args.row}]",
+                   f"bf16[{a.pool_pages},{args.index_dim},{a.page}]"):
+        assert not loop_ops_of_shape(text, sealed)  # produced by nothing
+    opened = loop_ops_of_shape(text, f"bf16[{a.batch},{a.page},{args.row}]")
+    assert sum(o.opcode == "dynamic-update-slice"
+               for o in opened) == 4 * a.batch
+    # and at most a move a layer between the compiler's memory spaces (the
+    # gathers read the open pages, 42 MB, from its fast memory)
+    moves = [o.opcode for o in opened if o.opcode != "dynamic-update-slice"]
+    assert set(moves) <= {"copy-start", "copy-done", "custom-call"}
+    assert moves.count("copy-start") <= 4
+    keys = [o for o in loop_ops_of_shape(
+        text, f"bf16[{a.batch},{args.index_dim},{a.page}]")
+        if o.opcode == "dynamic-update-slice"]
+    assert len(keys) == 4 * a.batch
+    assert compiled.memory_analysis().temp_size_in_bytes < 700 << 20
+
+
 def test_spmv_ell(one_chip):
     """spmv: the 150000-row local ELL slab (width 26, make_spmv_buffers seed
     0; transposed ``(w, m)`` as the buffers hold it) against the largest x
