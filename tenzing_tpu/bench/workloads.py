@@ -43,9 +43,9 @@ The workloads (``DriverRequest.workload`` / the CLI's ``--workload``):
   V3.2's DSA) over a paged index-key cache and a paged latent cache
   (``models/sparse_attention.py``): per layer the two appends, the absorb
   einsum, one chain a group of sequences (the ``dsa_index`` kernel over the
-  group's pages of index keys, an exact top-k, a gather of the selected
-  latent rows, ``mla_decode`` over the gathered tile; or, the menu's other
-  entry, one selection for the layer between the indexes and the gathers)
+  group's pages of index keys, an exact top-k, and the ``mla_decode_rows``
+  kernel over the selected latent rows, gathered as rows; or, the menu's
+  other entry, one selection for the layer between the indexes and the reads)
   and the up-projection.  This row runs DeepSeek-V3.2's
   widths on ``mla_decode``'s eight short sequences with 1024 selected
   (toy widths with ``--smoke``), the benchmark's ``dsv32-dsa-decode`` 16

@@ -302,12 +302,10 @@ class MlaDecode(DeviceOp):
     buffer touched (``FusedBlockAttn``'s finishing form)."""
 
     def __init__(self, name: str, args: LatentDecodeArgs, grp: Group,
-                 layer: str = "", names: Optional[Dict[str, str]] = None):
+                 layer: str = ""):
         super().__init__(name)
         self._args, self._grp = args, grp
-        # ``names``: buffers that stand in for the cache's (a sparse step
-        # hands its gathered tile as the open pages of a one-page cache)
-        self._n = {**_names(layer, grp), **(names or {})}
+        self._n = _names(layer, grp)
 
     def reads(self):
         return [self._n[k] for k in _CACHE + ("o_lat",)]

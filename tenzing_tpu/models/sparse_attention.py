@@ -16,7 +16,7 @@ index_dim)``, ``wI`` ``(B, index_heads)`` float32 beside the latent step's):
 3. *Select*: ``S_b`` = the positions of the ``min(topk, L_b + 1)`` largest
    ``I[b, .]``, exact; equal scores go to the lower position.
 4. *Absorb*, as the latent step's.
-5. *Gather and read*: softmax of ``scale qt[b, h] . C[b, j, :]`` over ``j in
+5. *Read*: softmax of ``scale qt[b, h] . C[b, j, :]`` over ``j in
    S_b`` only, ``o_lat = sum_{j in S_b} p C[b, j, :rank]``.
 6. *Up-project*, as the latent step's.
 
@@ -39,13 +39,20 @@ read only, one open page a sequence: ``latent_attention``'s reasons):
   the dense read wanted has no reader here.
 
 Per group of sequences (``decode_plan``'s groups) a layer runs the chain
-``index -> select -> gather -> read``: the scores of the group's pages into
-its rows of ``I`` (shared by the layers), the selection into its rows of
-``sel.<l>``, the picked rows gathered, transposed and put into its rows of
-``G`` ``(B, width, topk)`` (shared), and ``mla_decode`` over ``G`` as it
-stands: a sequence's gathered latents are one open page of ``topk`` keys
-with no sealed page behind it (``picked`` holds the key limits, ``min(topk,
-L_b + 1)``, ``tile_table`` a column of zeros).
+``index -> select -> read``: the scores of the group's pages into its rows
+of ``I`` (shared by the layers), the selection into its rows of ``sel.<l>``,
+and the read (:class:`DsaRead`): the picked rows of the sealed pool by one
+gather, **as rows**, and one ``mla_decode_rows`` kernel over them, a step a
+sequence, which writes the group's rows of ``o_lat`` in place.  ``(keys,
+row)`` is the layout attention wants for K anyway (Q K^T contracts the minor
+axis of both, P V is a plain product), and the pool already holds it: the
+``(width, topk)`` column tile ``mla_decode`` takes, its buffer ``G``, the
+transposes into it and the second gather (a slot in the open page read from
+``Copen`` too, and a ``where`` over both) were there only because the dense
+kernel was reused, and are gone (PR 41).  The kernel is handed the open pages
+and takes those slots' rows from them itself, a few a sequence.
+:func:`gather_rows` stays as the plain statement of which row a position is:
+the tests hold the read to it and to ``mla_decode`` over its tile.
 
 Menus: how far a selection reaches (:class:`SparseReadsChoice`: one a
 group, each a link of its group's chain, handed the group's rectangle of
@@ -71,7 +78,6 @@ from tenzing_tpu.models.latent_attention import (
     Append,
     Group,
     LatentDecodeArgs,
-    MlaDecode,
     UpProject,
     _names,
     block_table,
@@ -100,8 +106,10 @@ class SparseDecodeArgs:
 
     @property
     def tile(self) -> LatentDecodeArgs:
-        """The gathered tiles as a cache of their own: every sequence one
-        open page of ``topk`` keys, ``picked`` of them visible."""
+        """The selected tokens as a cache of their own: every sequence one
+        open page of ``topk`` keys, ``picked`` of them visible (what
+        ``mla_decode`` over :func:`gather_rows`' tile walks: the plain
+        statement the read is held to)."""
         return replace(self.latent, lens=tuple(n - 1 for n in self.picked),
                        page=self.topk, fold_pages=1)
 
@@ -109,7 +117,8 @@ class SparseDecodeArgs:
 def dsa_plan(args: SparseDecodeArgs) -> List[Tuple[Group, Group]]:
     """Per group of a step ``(cache, tile)``: the group over the paged
     caches (the index walks its pages, the selection is handed its
-    rectangle) and over the gathered tiles (one step a sequence)."""
+    rectangle) and over its selected tokens as :attr:`SparseDecodeArgs.tile`
+    (one step a sequence)."""
     from tenzing_tpu.obs.tracer import get_tracer
 
     a = args.latent
@@ -251,6 +260,19 @@ def gather_rows(pool, opened, table, lens, sel, page: int, width: int):
     return jnp.swapaxes(got, 1, 2)
 
 
+def sealed_rows(pool, table, sel, page: int):
+    """``(rows, k, row)``: for every position of ``sel`` ``(rows, k)`` the
+    sealed pool's row, whole and as a row: one gather.  A position in a
+    sequence's open page or past its last page reads through its table
+    slot all the same (clipped to the table): some row of the pool, which
+    the reader replaces from the open page or leaves out."""
+    import jax.numpy as jnp
+
+    page_id = jnp.take_along_axis(
+        table, jnp.clip(sel // page, 0, table.shape[1] - 1), axis=1)
+    return pool.reshape(-1, pool.shape[2])[page_id * page + sel % page]
+
+
 # -- the vertices of a group's chain ---------------------------------------------
 
 _INDEX = ("qI", "wI", "KI", "KIopen", "lens", "table")
@@ -375,40 +397,51 @@ class DsaSelect(DeviceOp):
             bufs[n["sel"]], select_chunks(rect, args.topk), g.lead0, 0)}
 
 
-class DsaGather(DeviceOp):
-    """A group's picked latent rows, as columns, into its rows of ``G``
-    (:func:`gather_rows`: XLA's gather from the row-major pools)."""
+class DsaRead(DeviceOp):
+    """A group's attention over its selected tokens, into its rows of
+    ``o_lat`` in place: the positions' rows of the sealed pool by one gather
+    (:func:`sealed_rows`), as rows, and one ``mla_decode_rows`` kernel over
+    them, which takes the rows of the slots in a sequence's open page from
+    ``Copen`` itself and leaves the slots from ``picked`` on out."""
+
+    _READS = ("qt", "sel", "C", "Copen", "lens", "table", "picked", "o_lat")
 
     def __init__(self, name: str, args: SparseDecodeArgs, grp: Group,
                  layer: str = ""):
         super().__init__(name)
         self._args, self._grp = args, grp
-        self._n = {**_names(layer), "G": "G"}
+        self._n = {**_names(layer), "picked": "picked"}
 
     def reads(self):
-        return [self._n[k] for k in ("sel", "C", "Copen", "lens", "table",
-                                     "G")]
+        return [self._n[k] for k in self._READS]
 
     def writes(self):
-        return [self._n["G"]]
+        return [self._n["o_lat"]]
 
     def apply(self, bufs, ctx):
-        from jax import lax
+        import jax.numpy as jnp
 
-        a, g, n = self._args.latent, self._grp, self._n
+        from tenzing_tpu.ops.attention_pallas import mla_decode_rows_pallas
+
+        args, a, g, n = self._args, self._args.latent, self._grp, self._n
         rows = slice(g.lead0, g.lead0 + g.rows)
-        tile = gather_rows(bufs[n["C"]], bufs[n["Copen"]][rows],
-                           bufs[n["table"]][rows], bufs[n["lens"]][rows],
-                           bufs[n["sel"]][rows], a.page, a.width)
-        _count("rows_gathered", g.rows * self._args.topk)
-        return {n["G"]: lax.dynamic_update_slice_in_dim(
-            bufs[n["G"]], tile.astype(bufs[n["G"]].dtype), g.lead0, 0)}
+        sel = bufs[n["sel"]]
+        got = sealed_rows(bufs[n["C"]], bufs[n["table"]][rows], sel[rows],
+                          a.page)
+        _count("rows_gathered", g.rows * args.topk)
+        # the kernel issues no DMA of its own (Mosaic takes no slice of the
+        # pool finer than its tile of 8 rows): the rows come by XLA's
+        # gather, through HBM once, whole lanes wide
+        _count("row_dmas", 0)
+        _count("tile_bytes_via_hbm", g.rows * args.topk * args.row
+               * jnp.dtype(a.dtype).itemsize)
+        return {n["o_lat"]: mla_decode_rows_pallas(
+            bufs[n["qt"]], got, bufs[n["Copen"]], sel, bufs[n["lens"]],
+            bufs[n["picked"]], bufs[n["o_lat"]], a.scale, v_dim=a.rank,
+            lead0=g.lead0)}
 
-
-#: the buffers that stand in for ``mla_decode``'s cache over the gathered
-#: tiles: no sealed page (its operand is the tiles themselves, one block of
-#: them fetched once), the tiles as the open pages, ``picked`` the limits
-TILE_NAMES = {"C": "G", "Copen": "G", "lens": "picked", "table": "tile_table"}
+    def uses_pallas(self) -> bool:
+        return True
 
 
 def whole_batch(plan) -> Group:
@@ -419,9 +452,9 @@ def whole_batch(plan) -> Group:
 
 class SparseReads(CompoundOp):
     """A layer's sparse reads as one expandable vertex: a group's ``index ->
-    select -> gather -> read`` chains side by side, or (``by_layer``) the
-    groups' indexes, one selection over the layer's every sequence, then
-    the groups' ``gather -> read``."""
+    select -> read`` chains side by side, or (``by_layer``) the groups'
+    indexes, one selection over the layer's every sequence, then the
+    groups' reads."""
 
     def __init__(self, name: str, args: SparseDecodeArgs, plan, layer: str,
                  impl_choice: bool, by_layer: bool):
@@ -437,15 +470,14 @@ class SparseReads(CompoundOp):
         g = Graph()
         over_all = self._by_layer and DsaSelect(
             pre + "dsa_select", args, whole_batch(self._plan), tag)
-        for grp, tile in self._plan:
+        for grp, _ in self._plan:
             at = f"{pre}g{grp.index}."
             where = (args, grp, tag)
             chain = [
                 (DsaIndexChoice if self._impl_choice else DsaIndexPallas)(
                     at + "dsa_index", *where),
                 over_all or DsaSelect(at + "dsa_select", *where),
-                DsaGather(at + "dsa_gather", *where),
-                MlaDecode(at + "dsa_read", args.tile, tile, tag, TILE_NAMES)]
+                DsaRead(at + "dsa_read", *where)]
             g.start_then(chain[0])
             for x, y in zip(chain, chain[1:]):
                 g.then(x, y)
@@ -475,8 +507,8 @@ def dsa_graph(args: SparseDecodeArgs, layers, impl_choice: bool = False
     """The step's layers one after another, as the residual stream orders
     them.  In a layer the two appends and the absorb come first, side by
     side, then the sparse reads (:class:`SparseReadsChoice`: the groups'
-    ``index -> select -> gather -> read`` chains side by side, or one
-    selection for the layer between the indexes and the gathers), then the
+    ``index -> select -> read`` chains side by side, or one selection for
+    the layer between the indexes and the reads), then the
     up-projection.  ``impl_choice``: a menu of the index's implementations
     too."""
     plan = dsa_plan(args)
@@ -510,9 +542,8 @@ def buffer_shapes(args: SparseDecodeArgs, layers) -> Dict[str, tuple]:
     a, dt = args.latent, args.latent.dtype
     b, h, w = a.batch, a.heads, a.width
     out = {"lens": ((b,), "int32"), "table": ((b, a.max_pages), "int32"),
-           "picked": ((b,), "int32"), "tile_table": ((b, 1), "int32"),
-           "I": ((b, 1, a.max_pages * a.page), "float32"),
-           "G": ((b, w, args.topk), dt)}
+           "picked": ((b,), "int32"),
+           "I": ((b, 1, a.max_pages * a.page), "float32")}
     for tag in layers:
         n = _names(tag)
         out.update({

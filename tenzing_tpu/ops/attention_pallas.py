@@ -22,6 +22,9 @@ the name the device trace shows:
   (``mla_fold``): the same two over a **paged latent cache** (one decode
   step of latent attention, models/latent_attention.py), see below.
 
+(Two more kernels live here with bodies of their own: ``dsa_index`` on the
+paged walk and ``mla_decode_rows``, both models/sparse_attention.py's.)
+
 and three shapes of output:
 
 * state in, state out (``acc``, ``m``, ``l`` handed): three float32
@@ -121,6 +124,17 @@ paged cache of index keys.  The same grid of the pages there are, the same
 scalar operands and index maps (:func:`_paged_maps`), the open page as a
 second K operand, no softmax and no state: a step writes its page of one
 row of scores a sequence, ``NEG`` past the sequence's limit.
+
+A sparse step's attention over its selected tokens is a kernel of its own
+(:func:`mla_decode_rows_pallas`, ``mla_decode_rows``) and shares nothing
+with the walk: the dense read wants whole pages as columns through an index
+map and a running softmax over many pages; the sparse read wants
+token-granular rows and one softmax over a resident tile.  Its keys stay
+rows, ``(keys, row)``, as the sparse step's pool holds them, and arrive
+gathered; the kernel fetches none of the pool itself, because Mosaic takes
+no slice of a tiled HBM operand finer than its tile (8 rows of the pool's
+``(8,128)(2,1)`` bfloat16 layout: a one-row DMA does not compile;
+``tests/test_tpu_compile.py``).
 
 ``interpret=True`` (automatic off-TPU) runs the same kernel in the Pallas
 interpreter for CPU tests.
@@ -849,3 +863,154 @@ def dsa_index_pallas(q, w, pool, k_open, lens, table, scores, *, lead0: int,
         name="dsa_index",
         interpret=interpret,
     )(lens.astype(jnp.int32), table.astype(jnp.int32), *operands)
+
+
+# -- selected latent rows: attention over a gathered tile of rows --------------------
+
+
+def open_span(sel, lens, page: int):
+    """``(2, rows)`` int32: per sequence the slots ``first .. last + 1`` of
+    ``sel`` ``(rows, k)`` whose position lies in the sequence's open page
+    (the page of its last visible key, ``lens`` visible keys; ``0, 0`` where
+    none does).  An ascending selection's open-page slots are exactly that
+    run; in any other order the run holds them all."""
+    is_open = sel // page == ((lens - 1) // page)[:, None]
+    slots = jnp.arange(sel.shape[1], dtype=jnp.int32)[None, :]
+    first = jnp.min(jnp.where(is_open, slots, sel.shape[1]), axis=1)
+    last = jnp.max(jnp.where(is_open, slots + 1, 0), axis=1)
+    return jnp.stack([jnp.minimum(first, last), last]).astype(jnp.int32)
+
+
+def _rows_kernel(scale: float, dv: int, lead0: int, page: int, lens, picked,
+                 span, sel, q_ref, rows_ref, ko_ref, _, o_ref, rows_s, q_s):
+    """One sequence a step: its ``k`` gathered rows ``(k, row)`` into
+    scratch, the slots whose position lies in its open page overwritten
+    with that page's rows (the gather read the sealed pool for every
+    slot), then one softmax over the ``picked`` first slots and ``O = P .
+    rows[:, :dv]``: ``mla_decode``'s roundings at ``mla_decode``'s places
+    (float32 scores and sums, P in the cache's dtype, ``acc / l`` rounded
+    once), with no running state: every key is in VMEM at once."""
+    i = pl.program_id(0)
+    b = lead0 + i
+    d = q_ref.shape[2]
+
+    @pl.when(i == 0)
+    def _():
+        # Q padded to a row's width: the tail of a row is zero too
+        q_s[...] = jnp.zeros_like(q_s)
+
+    q_s[:, :d] = q_ref[0]
+    # tokens a 32-bit sublane row holds: a 16-bit token is half of one, and
+    # is moved as a word, by shifts (the scratch and the open page as words)
+    packed = 4 // rows_ref.dtype.itemsize
+    if packed == 1:
+        rows_s[...], src = rows_ref[0], ko_ref
+    else:
+        rows_s[...] = pltpu.bitcast(rows_ref[0], jnp.uint32)
+        src = ko_ref.bitcast(jnp.uint32)
+        bits = 32 // packed
+        ones = jnp.uint32((1 << bits) - 1)
+    open_slot = (lens[b] - 1) // page
+
+    def patch(j, carry):
+        at = sel[b, j]
+
+        @pl.when(at // page == open_slot)
+        def _():
+            r = at % page
+            word = src[0, pl.ds(r // packed, 1), :]
+            if packed > 1:
+                half = (word >> (bits * (r % packed)).astype(jnp.uint32)
+                        ) & ones
+                up = (bits * (j % packed)).astype(jnp.uint32)
+                word = (rows_s[pl.ds(j // packed, 1), :] & ~(ones << up)) | (
+                    half << up)
+            rows_s[pl.ds(j // packed, 1), :] = word
+
+        return carry
+
+    jax.lax.fori_loop(span[0, i], span[1, i], patch, 0)
+
+    rows = rows_s[...]
+    if packed > 1:
+        rows = pltpu.bitcast(rows, rows_ref.dtype)
+    s = jax.lax.dot_general(q_s[...], rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    seen = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < picked[b]
+    s = jnp.where(seen, s, NEG)
+    m = jnp.max(s, axis=1, keepdims=True)
+    p = jnp.where(seen, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=1, keepdims=True)
+    acc = jnp.dot(p.astype(rows.dtype), rows[:, :dv],
+                  preferred_element_type=jnp.float32)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "v_dim", "lead0",
+                                             "interpret"))
+def mla_decode_rows_pallas(q, rows, k_open, sel, lens, picked, o, scale, *,
+                           v_dim: int, lead0: int,
+                           interpret: Optional[bool] = None):
+    """Latent attention of ``rows.shape[0]`` sequences from ``lead0`` over
+    their selected tokens alone, in ONE kernel (``mla_decode_rows``): a
+    step a sequence, its ``k`` rows resident, one softmax, ``o`` ``(B, n,
+    v_dim)`` returned with those sequences' rows written and every other
+    row as it came (aliased, never fetched).
+
+    ``q`` ``(B, n, d)``; ``rows`` ``(R, k, row)``, ``row >= d`` whole lanes
+    and a token's tail zero: slot j of sequence ``lead0 + i`` holds the
+    sealed pool's row for position ``sel[lead0 + i, j]`` (read through the
+    table, whatever the position: :func:`~tenzing_tpu.models.
+    sparse_attention.sealed_rows`); ``k_open`` ``(B, page, row)`` the open
+    pages, a token a row: a slot whose position lies in the sequence's open
+    page (``lens`` ``(B,)`` visible keys) takes its row from there, inside
+    the kernel, so the rows arrive by one gather and not two.  ``sel``
+    ``(B, k)`` int32 reaches the scalar core whole; ``picked`` ``(B,)`` the
+    key limits: slots from ``picked[b]`` on are left out.  Keys stay rows,
+    ``(k, row)``: the first product contracts the minor axis of both
+    operands, the second is plain, and nothing is transposed."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    _, n, d = q.shape
+    count, k, row = rows.shape
+    page = k_open.shape[1]
+    if row < d or k_open.shape[2] != row:
+        raise ValueError(f"rows of {row} and open pages of "
+                         f"{k_open.shape[2]} for queries of {d}")
+    packed = 4 // rows.dtype.itemsize
+    if k % packed or page % packed:
+        raise ValueError(f"{k} keys of pages of {page}: {packed} tokens to "
+                         "a 32-bit row")
+    at = slice(lead0, lead0 + count)
+    span = open_span(sel[at], lens[at], page)
+
+    def seq(i, *_):
+        return (lead0 + i, 0, 0)
+
+    operands = (q, rows, k_open, o)
+    scalars = 4  # the limits, the open slots and the positions lead
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, float(scale), int(v_dim), int(lead0),
+                          page),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=scalars,
+            grid=(count,),
+            in_specs=[pl.BlockSpec((1, n, d), seq),
+                      pl.BlockSpec((1, k, row), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec((1, page, row), seq),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, n, v_dim), seq),
+            scratch_shapes=[
+                pltpu.VMEM((k, row), rows.dtype) if packed == 1
+                else pltpu.VMEM((k // packed, row), jnp.uint32),
+                pltpu.VMEM((n, row), q.dtype)],
+        ),
+        out_shape=out_struct(o.shape, o.dtype, *operands),
+        input_output_aliases={scalars + len(operands) - 1: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 << 20),
+        name="mla_decode_rows",
+        interpret=interpret,
+    )(lens.astype(jnp.int32), picked.astype(jnp.int32), span,
+      sel.astype(jnp.int32), *operands)

@@ -46,6 +46,7 @@ from tenzing_tpu.models.sparse_attention import (
     whole_batch,
 )
 from tenzing_tpu.obs.metrics import MetricsRegistry, set_metrics
+from tenzing_tpu.ops import attention_pallas
 from tenzing_tpu.runtime.executor import TraceExecutor
 from tenzing_tpu.verify.soundness import ScheduleVerifier
 
@@ -271,10 +272,14 @@ def test_a_fault_moves_the_widest_row_gap_past_its_limit(fault, monkeypatch):
         out = ex.compile(seq)({**ex.init_bufs, "table": jnp.asarray(table)})
         want, ref_sel = reference(ARGS, bufs, "L0")
     elif fault == "gather_off_by_one":
-        real = sa.gather_rows
-        monkeypatch.setattr(sa, "gather_rows", lambda pool, opened, table,
-                            lens, sel, *size: real(pool, opened, table, lens,
-                                                   sel + 1, *size))
+        # where the read's vertex hands the kernel its rows and positions
+        real, kernel = sa.sealed_rows, attention_pallas.mla_decode_rows_pallas
+        monkeypatch.setattr(sa, "sealed_rows", lambda pool, table, sel,
+                            page: real(pool, table, sel + 1, page))
+        monkeypatch.setattr(
+            attention_pallas, "mla_decode_rows_pallas",
+            lambda q, rows, opened, sel, *rest, **kw: kernel(
+                q, rows, opened, sel + 1, *rest, **kw))
         _, _, shifted, _ = step()
         out = shifted.run(seq)
         want, ref_sel = reference(ARGS, bufs, "L0",
@@ -302,6 +307,178 @@ def test_two_iterations_leave_every_buffer_as_one_leaves_it(menu):
                               np.asarray(twice[name])), name
 
 
+# -- the read's kernel against the plain statement ----------------------------------
+
+def selection(args, where: str, rng):
+    """``(B, topk)`` positions, ascending a sequence, the visible ones
+    first and then positions past the length: ``mixed`` any of a sequence's
+    visible keys, ``open`` as many of its open page's as there are and the
+    rest from the last sealed pages, ``sealed`` none of its open page's
+    (a sequence with fewer sealed keys than it picks takes its first)."""
+    a = args.latent
+    sel = np.zeros((a.batch, args.topk), np.int32)
+    for b, (vis, n) in enumerate(zip(a.visible, args.picked)):
+        base = (vis - 1) // a.page * a.page
+        if where == "mixed":
+            own = rng.choice(vis, n, replace=False)
+        elif where == "open":
+            own = np.arange(vis)[-n:]
+        else:
+            own = rng.choice(base, n, replace=False) if base >= n else (
+                np.arange(vis)[:n])
+        sel[b, :n] = np.sort(own)
+        sel[b, n:] = vis + np.arange(args.topk - n)
+    return sel
+
+
+def plain_read(args, bufs, sel, grp, tile, o_lat, layer="L0"):
+    """The parent's two vertices: :func:`gather_rows` into the column tile
+    ``G`` and ``mla_decode`` over it as one open page a sequence."""
+    a = args.latent
+    rows = slice(grp.lead0, grp.lead0 + grp.rows)
+    cols = sa.gather_rows(bufs[f"C.{layer}"], bufs[f"Copen.{layer}"][rows],
+                          bufs["table"][rows], bufs["lens"][rows], sel[rows],
+                          a.page, a.width)
+    g = jax.lax.dynamic_update_slice_in_dim(
+        jnp.zeros((a.batch, a.width, args.topk), cols.dtype), cols,
+        grp.lead0, 0)
+    return attention_pallas.mla_decode_pallas(
+        bufs[f"qt.{layer}"], g, g, bufs["picked"],
+        jnp.zeros((a.batch, 1), jnp.int32), o_lat, a.scale, v_dim=a.rank,
+        lead0=tile.lead0, tiles=tile.tiles)
+
+
+def rows_read(args, bufs, sel, grp, o_lat, layer="L0"):
+    a = args.latent
+    rows = slice(grp.lead0, grp.lead0 + grp.rows)
+    return attention_pallas.mla_decode_rows_pallas(
+        bufs[f"qt.{layer}"],
+        sa.sealed_rows(bufs[f"C.{layer}"], bufs["table"][rows], sel[rows],
+                       a.page),
+        bufs[f"Copen.{layer}"], sel, bufs["lens"], bufs["picked"], o_lat,
+        a.scale, v_dim=a.rank, lead0=grp.lead0)
+
+
+#: dtype x where the selection lies x the table: a permuted table and the
+#: identity (``table_seed`` None: page j of the pool is the j-th sealed page)
+READS = [(dtype, where, table_seed)
+         for dtype in ("float32", "bfloat16")
+         for where in ("mixed", "open", "sealed")
+         for table_seed in (11, None)]
+
+
+@pytest.mark.parametrize("dtype,where,table_seed", READS)
+def test_rows_kernel_reads_what_gather_and_mla_decode_read(dtype, where,
+                                                           table_seed):
+    """``mla_decode_rows`` over :func:`sealed_rows` against
+    :func:`gather_rows` + ``mla_decode`` over the tile, at the rehearse
+    sizes, group by group (three of the four do not start at sequence 0;
+    three sequences see fewer keys than ``topk``, and their slots past
+    their length, which ``picked`` leaves out, read some row of the pool
+    through a clipped table slot in both).  The arithmetic is
+    ``mla_decode``'s at ``mla_decode``'s places: the rows written are
+    equal to the last bit, and every other row of ``o_lat`` stays."""
+    args = dataclasses.replace(ARGS, latent=dataclasses.replace(
+        LATENT, dtype=dtype))
+    a = args.latent
+    rng = np.random.default_rng(7)
+    bufs = make_dsa_buffers(args, LAYERS, 3, table_seed or 0)
+    if table_seed is None:
+        table = np.zeros_like(bufs["table"])
+        at = 0
+        for b, n in enumerate(a.sealed):
+            table[b, :n] = at + np.arange(n)
+            at += n
+        bufs["table"] = table
+    bufs = {k: jnp.asarray(v) for k, v in bufs.items()}
+    dt = jnp.dtype(dtype)
+    bufs["qt.L0"] = jnp.asarray(
+        rng.standard_normal((a.batch, a.heads, a.width)), dt)
+    sel = jnp.asarray(selection(args, where, rng))
+    in_open = np.asarray(sel) // a.page == (
+        (np.asarray(a.visible) - 1) // a.page)[:, None]
+    seen = np.arange(args.topk)[None, :] < np.asarray(args.picked)[:, None]
+    if where == "open":
+        assert (in_open & seen).sum(axis=1).tolist() == [
+            min(n, (v - 1) % a.page + 1)
+            for n, v in zip(args.picked, a.visible)]
+    if where == "sealed":
+        assert not (in_open & seen)[3:].any()  # those with 16 sealed keys
+    before = jnp.asarray(rng.standard_normal((a.batch, a.heads, a.rank)), dt)
+    for grp, tile in dsa_plan(args):
+        want = plain_read(args, bufs, sel, grp, tile, before)
+        got = rows_read(args, bufs, sel, grp, before)
+        assert np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(want, np.float32)), grp.index
+        rows = slice(grp.lead0, grp.lead0 + grp.rows)
+        assert not np.array_equal(np.asarray(got[rows], np.float32),
+                                  np.asarray(before[rows], np.float32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_rows_kernel_takes_a_selection_in_any_order(dtype):
+    """``open_span`` is the run of slots that holds every open-page slot,
+    whatever the order: a shuffled selection reads the same set of rows,
+    so the same O to the tolerance of another order of float32 sums."""
+    args = dataclasses.replace(ARGS, latent=dataclasses.replace(
+        LATENT, dtype=dtype))
+    a = args.latent
+    rng = np.random.default_rng(8)
+    bufs = {k: jnp.asarray(v) for k, v in make_dsa_buffers(
+        args, LAYERS, 3, 11).items()}
+    bufs["qt.L0"] = jnp.asarray(
+        rng.standard_normal((a.batch, a.heads, a.width)), jnp.dtype(dtype))
+    sel = selection(args, "mixed", rng)
+    mixed = sel.copy()
+    for b, n in enumerate(args.picked):
+        mixed[b, :n] = rng.permutation(sel[b, :n])
+    span = np.asarray(attention_pallas.open_span(
+        jnp.asarray(mixed), bufs["lens"], a.page))
+    in_open = mixed // a.page == ((np.asarray(a.visible) - 1) // a.page)[
+        :, None]
+    for b in range(a.batch):
+        at = np.flatnonzero(in_open[b])
+        assert (span[0, b], span[1, b]) == (
+            (at[0], at[-1] + 1) if len(at) else (0, 0))
+    zero = jnp.zeros((a.batch, a.heads, a.rank), jnp.dtype(dtype))
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == "bfloat16" else dict(
+        rtol=2e-5, atol=2e-6)
+    for grp, _ in dsa_plan(args):
+        np.testing.assert_allclose(
+            np.asarray(rows_read(args, bufs, jnp.asarray(mixed), grp, zero),
+                       np.float32),
+            np.asarray(rows_read(args, bufs, jnp.asarray(sel), grp, zero),
+                       np.float32), **tol)
+
+
+#: the read kernel's call, kernel body included, as PR 41 traced it (beside
+#: ``test_mla_decode.PINNED``, which holds the kernels it stands next to):
+#: rows of 32 bits, moved as they are, and of 16, moved as halves of words
+PINNED_ROWS = {
+    "float32":
+        "5a02d9a2d25f4df3e8be322e3083d69f50784bf59ab4b03d3e4a8f40fb4f2e1e",
+    "bfloat16":
+        "bafa5c39553d1dbb57e8cc4a841793ae051799f9042015123520742675f2566f",
+}
+
+
+@pytest.mark.parametrize("dtype", list(PINNED_ROWS))
+def test_rows_kernel_traces_what_it_traced(dtype):
+    import hashlib
+
+    dt = jnp.dtype(dtype)
+    operands = (jnp.zeros((4, 4, 24), dt), jnp.zeros((2, 16, 128), dt),
+                jnp.zeros((4, 8, 128), dt), jnp.zeros((4, 16), jnp.int32),
+                jnp.asarray([4, 10, 18, 27], jnp.int32),
+                jnp.asarray([4, 10, 16, 16], jnp.int32),
+                jnp.zeros((4, 4, 16), dt))
+    text = str(jax.make_jaxpr(
+        lambda *a: attention_pallas.mla_decode_rows_pallas(
+            *a, 0.5, v_dim=16, lead0=2, interpret=True))(*operands))
+    assert "mla_decode_rows" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == PINNED_ROWS[dtype]
+
+
 # -- tracing ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("menu", list(MENUS))
@@ -316,7 +493,8 @@ def test_counters_equal_a_count_from_the_lengths(menu):
         set_metrics(prev)
     count = {n: reg.counter("dsa." + n).value for n in (
         "keys_indexed", "keys_indexed_computed", "select_candidates",
-        "select_candidates_padded", "rows_gathered", "appended_rows")}
+        "select_candidates_padded", "rows_gathered", "appended_rows",
+        "row_dmas", "tile_bytes_via_hbm")}
     layers, page, k = len(LAYERS), LATENT.page, ARGS.topk
     visible = sum(n + 1 for n in LENS)
     tiles = [n // page + 1 for n in LENS]
@@ -335,12 +513,14 @@ def test_counters_equal_a_count_from_the_lengths(menu):
     assert sum(grp.rows * candidates(ARGS, grp) for grp in handed) == (
         512 if menu.endswith("layer") else 272)
     assert count["rows_gathered"] == layers * len(LENS) * k
-    # the attention over the gathered tiles, by the dense step's counters:
-    # a step a sequence, the selected keys of a tile of ``topk``
-    assert reg.counter("mla.page_steps").value == layers * len(LENS)
-    assert reg.counter("mla.page_steps_idle").value == 0
-    assert reg.counter("mla.keys_useful").value == layers * sum(ARGS.picked)
-    assert reg.counter("mla.keys_computed").value == layers * len(LENS) * k
+    # how the rows reach the read: by XLA's one gather, through HBM once as
+    # whole rows (float32 here), and by no DMA of the kernel's own
+    assert count["row_dmas"] == 0
+    assert count["tile_bytes_via_hbm"] == count["rows_gathered"] * 128 * 4
+    # the read is no paged walk: none of the dense step's counters moves
+    for name in ("page_steps", "page_steps_idle", "keys_useful",
+                 "keys_computed"):
+        assert reg.counter("mla." + name).value == 0
 
 
 def test_the_plan_is_one_span_of_the_program_s_tracing():
@@ -376,29 +556,30 @@ def test_buffers_and_the_graph_s_size():
     assert shapes["KIopen.L1"] == ((8, 8, 8), "float32")
     assert shapes["sel.L0"] == ((8, 16), "int32")
     assert shapes["I"] == ((8, 1, 64), "float32")
-    assert shapes["G"] == ((8, 24, 16), "float32")
+    assert "G" not in shapes and "tile_table" not in shapes
+    assert shapes["picked"] == ((8,), "int32")
     assert shapes["wI.L0"] == ((8, 4), "float32")
     bufs = make_dsa_buffers(ARGS, LAYERS, 0, 11)
     assert not bufs["C.L0"][..., 24:].any() and bufs["C.L0"][..., :24].all()
     assert list(bufs["picked"]) == list(ARGS.picked)
     g, plat, _, _ = step()
     names = [op.name() for op in drive(g, plat, (".pallas", ".by_group"))]
-    # two appends, absorb, four chains of four and the up-projection a layer
-    assert sum(n.startswith(("L0.", "L1.")) for n in names) == 2 * 20
+    # two appends, absorb, four chains of three and the up-projection a layer
+    assert sum(n.startswith(("L0.", "L1.")) for n in names) == 2 * 16
     for kind, n in (("dsa_index.pallas", 8), ("dsa_select", 8),
-                    ("dsa_gather", 8), ("dsa_read", 8), ("index_append", 2)):
+                    ("dsa_gather", 0), ("dsa_read", 8), ("index_append", 2)):
         assert sum(x.endswith(kind) for x in names) == n, kind
     # one selection a layer: it waits for every group's index, and every
-    # gather for it
+    # read for it
     seq = drive(g, plat, (".pallas", ".by_layer"))
     assert ScheduleVerifier(g)(seq).ok
     names = [op.name() for op in seq]
-    assert sum(n.startswith(("L0.", "L1.")) for n in names) == 2 * 17
+    assert sum(n.startswith(("L0.", "L1.")) for n in names) == 2 * 13
     assert [n for n in names if n.endswith("dsa_select")] == [
         "L0.by_layer.dsa_select", "L1.by_layer.dsa_select"]
     at = names.index("L0.by_layer.dsa_select")
     assert all(names.index(f"L0.by_layer.g{i}.dsa_index.pallas") < at
-               < names.index(f"L0.by_layer.g{i}.dsa_gather")
+               < names.index(f"L0.by_layer.g{i}.dsa_read")
                for i in range(4))
     plan = dsa_plan(ARGS)
     menu = SparseReadsChoice("L0.dsa_reads", ARGS, plan, "L0")

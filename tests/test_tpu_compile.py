@@ -622,21 +622,22 @@ def test_sparse_decode_kernels(one_chip, group):
     """The sparse cell's two kernels at its shapes, its shorter group and
     its longer: ``dsa_index`` on the paged walk (a grid of 51 and of 233
     steps, K tiles of ``(128, 2048)``, a ``(1, 1, 2048)`` block of the one
-    row of scores a sequence, aliased in place) and ``mla_decode`` over the
-    gathered tiles as it stands (a step a sequence, the tiles as open pages
-    and as the one sealed block nobody walks).  Neither moves a pool."""
+    row of scores a sequence, aliased in place) and ``mla_decode_rows`` over
+    the group's gathered rows (a step a sequence: its ``(2048, 640)`` rows
+    and its open page as blocks, the positions whole on the scalar core,
+    the open page's slots moved as 32-bit words).  Neither moves a pool."""
     from tenzing_tpu.models.sparse_attention import dsa_plan
     from tenzing_tpu.ops.attention_pallas import (
         dsa_index_pallas,
-        mla_decode_pallas,
+        mla_decode_rows_pallas,
     )
 
     args = _dsa_cell()
     a = args.latent
-    grp, tile = dsa_plan(args)[group]
+    grp, _ = dsa_plan(args)[group]
     assert grp.tiles == ((5, 5, 5, 6, 6, 7, 8, 9),
                          (11, 14, 17, 21, 27, 34, 45, 64))[group]
-    assert tile.tiles == (1,) * 8 and args.picked == (2048,) * 16
+    assert args.picked == (2048,) * 16
     bf = jnp.bfloat16
     index = dsa_index_pallas.lower(
         _sds((a.batch, args.index_heads, args.index_dim), bf, one_chip),
@@ -647,34 +648,71 @@ def test_sparse_decode_kernels(one_chip, group):
         _sds((a.batch, a.max_pages), jnp.int32, one_chip),
         _sds((a.batch, 1, a.max_pages * a.page), jnp.float32, one_chip),
         lead0=grp.lead0, tiles=grp.tiles, interpret=False).compile()
-    tiles = _sds((a.batch, a.width, args.topk), bf, one_chip)
-    read = mla_decode_pallas.lower(
-        _sds((a.batch, a.heads, a.width), bf, one_chip), tiles, tiles,
+    read = mla_decode_rows_pallas.lower(
+        _sds((a.batch, a.heads, a.width), bf, one_chip),
+        _sds((grp.rows, args.topk, args.row), bf, one_chip),
+        _sds((a.batch, a.page, args.row), bf, one_chip),
+        _sds((a.batch, args.topk), jnp.int32, one_chip),
         _sds((a.batch,), jnp.int32, one_chip),
-        _sds((a.batch, 1), jnp.int32, one_chip),
+        _sds((a.batch,), jnp.int32, one_chip),
         _sds((a.batch, a.heads, a.rank), bf, one_chip), a.scale,
-        v_dim=a.rank, lead0=tile.lead0, tiles=tile.tiles,
-        interpret=False).compile()
-    for compiled, name in ((index, "dsa_index"), (read, "mla_decode")):
+        v_dim=a.rank, lead0=grp.lead0, interpret=False).compile()
+    for compiled, name in ((index, "dsa_index"), (read, "mla_decode_rows")):
         _assert_kernel(compiled)
         text = compiled.as_text()
         assert name in text  # the name the device trace shows
-        big = (f"bf16[{a.pool_pages},", f"bf16[{a.batch},{a.width},",
+        big = (f"bf16[{a.pool_pages},", f"bf16[{grp.rows},{args.topk},",
                f"bf16[{a.batch},{args.index_dim},{a.page}]")
         moved = [l for l in text.splitlines() if " copy(" in l
                  and l.split(" = ")[1].startswith(big)]
-        assert not moved  # no pool, no tile (what is not donated here, is)
+        assert not moved  # no pool, no rows (what is not donated here, is)
         assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_one_row_of_the_pool_is_no_dma(one_chip):
+    """Why the read's rows come by XLA's gather (PERF.md section 6, PR 41):
+    Mosaic takes no slice of a tiled HBM operand finer than its tile, and
+    the latent pool arrives tiled ``(8,128)(2,1)``, so a DMA of one
+    token's row does not compile, and the finest, 8 rows, does.  When this
+    fails, a kernel can fetch its selected rows itself (ROADMAP S7c)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def fetch(rows: int):
+        def kernel(at, pool, o_ref, got, sem):
+            first = pl.multiple_of(at[0] // rows * rows, rows)
+            copy = pltpu.make_async_copy(pool.at[0, pl.ds(first, rows), :],
+                                         got, sem)
+            copy.start()
+            copy.wait()
+            o_ref[...] = got[...]
+
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((rows, 640), lambda i, at: (0, 0)),
+                scratch_shapes=[pltpu.VMEM((rows, 640), jnp.bfloat16),
+                                pltpu.SemaphoreType.DMA]),
+            out_shape=jax.ShapeDtypeStruct((rows, 640), jnp.bfloat16))
+
+    operands = (_sds((1,), jnp.int32, one_chip),
+                _sds((4, 2048, 640), jnp.bfloat16, one_chip))
+    _assert_kernel(jax.jit(fetch(8)).lower(*operands).compile())
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(fetch(1)).lower(*operands).compile()
 
 
 def test_sparse_decode_loop_moves_no_pool(one_chip, monkeypatch):
     """The repeat-n program of ``dsv32-dsa-decode.climb``'s start point as
-    the TPU compiler leaves it: 8 ``dsa_index`` and 8 ``mla_decode``;
+    the TPU compiler leaves it: 8 ``dsa_index`` and 8 ``mla_decode_rows``;
     inside the ``while`` body nothing touches a sealed pool of either cache
     but the index kernels and the gathers (a row-major pool is gathered
-    from as it lies: no copy of it), an open pool only takes its 16
-    one-row or one-column updates in place a layer, and the program's
-    temporaries stay under one latent pool (0.70 GB)."""
+    from as it lies: no copy of it), one gather a read (8) and no
+    transposed tile, an open pool only takes its 16 one-row or one-column
+    updates in place a layer, and the program's temporaries stay under one
+    latent pool (0.70 GB)."""
     from benchmarks.builders.dsa_decode import start_prefer
     from tenzing_tpu.core.platform import Platform
     from tenzing_tpu.models.sparse_attention import buffer_shapes, dsa_graph
@@ -697,14 +735,19 @@ def test_sparse_decode_loop_moves_no_pool(one_chip, monkeypatch):
         bufs, _sds((), jnp.int32, one_chip)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 16
+    assert text.count("mla_decode_rows") >= 8 and "dsa_index" in text
+    rows = loop_ops_of_shape(
+        text, f"bf16[{a.batch // a.groups * args.topk},{args.row}]")
+    assert sum(o.opcode == "fusion" for o in rows) == 8  # the gathers
+    assert not loop_ops_of_shape(
+        text, f"bf16[{a.batch},{a.width},{args.topk}]")  # no column tile
     for sealed in (f"bf16[{a.pool_pages},{a.page},{args.row}]",
                    f"bf16[{a.pool_pages},{args.index_dim},{a.page}]"):
         assert not loop_ops_of_shape(text, sealed)  # produced by nothing
     opened = loop_ops_of_shape(text, f"bf16[{a.batch},{a.page},{args.row}]")
     assert sum(o.opcode == "dynamic-update-slice"
                for o in opened) == 4 * a.batch
-    # and at most a move a layer between the compiler's memory spaces (the
-    # gathers read the open pages, 42 MB, from its fast memory)
+    # and at most a move a layer between the compiler's memory spaces
     moves = [o.opcode for o in opened if o.opcode != "dynamic-update-slice"]
     assert set(moves) <= {"copy-start", "copy-done", "custom-call"}
     assert moves.count("copy-start") <= 4
