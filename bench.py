@@ -55,7 +55,7 @@ def build_arg_parser() -> argparse.ArgumentParser:
     agree, so a new flag cannot silently miss the library API."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true", help="tiny CPU config")
-    ap.add_argument("--workload", choices=("halo", "spmv", "attn", "mla_decode", "dsa_decode", "moe"),
+    ap.add_argument("--workload", choices=("halo", "spmv", "attn", "mla_decode", "dsa_decode", "kda_decode", "moe"),
                     default="halo")
     ap.add_argument("--moe-tokens", type=int, default=8192,
                     help="total tokens (moe)")

@@ -200,6 +200,29 @@ def sparse_decode_cost(lens, heads: int, rank: int, rope: int, v_dim: int,
                                           + 4 * batch * index_heads)))
 
 
+def kda_decode_cost(batch: int, heads: int, d: int, taps: int,
+                    layers: int = 1, bytes_per_el: int = 2) -> Cost:
+    """One decode step of KDA layers (models/delta_attention.py): one new
+    token for each of ``batch`` sequences through ``layers`` layers.  A
+    (sequence, head) costs ``7 d^2`` float32 operations on its ``(d, d)``
+    state (the decay ``d^2``, ``k^T S'``, the rank-one update and the
+    read-out ``2 d^2`` each) and ``2 taps 3 d`` for the convolution step;
+    none of it is MXU work.  HBM, a floor: the state read once and written
+    once in float32, the convolution window read and written, the inputs
+    (``x``, ``f``, ``b``, ``go``) read and ``o`` written once, the
+    parameters once a layer.  At Kimi-Linear's widths a (sequence, head) is
+    114 688 operations for 131 072 bytes of state: bound by HBM whatever
+    the batch."""
+    state = 2 * 4 * d * d
+    window = 2 * (taps - 1) * 3 * d * bytes_per_el
+    rows = (3 * d + 2 * d + 1 + d) * bytes_per_el
+    params = heads * (taps * 3 * d * bytes_per_el + 4 * d + 4) + 4 * d
+    return Cost(
+        flops=float(layers * batch * heads * (7 * d * d + 2 * taps * 3 * d)),
+        hbm_bytes=float(layers * (batch * heads * (state + window + rows)
+                                  + params)))
+
+
 def moe_cost(tokens: int, d_model: int, d_ff: int, bytes_per_el: int = 4,
              staged: bool = False, n_experts: int = 8) -> Cost:
     """Top-1 routed MoE layer: every token through one gelu MLP —

@@ -50,6 +50,14 @@ The workloads (``DriverRequest.workload`` / the CLI's ``--workload``):
   widths on ``mla_decode``'s eight short sequences with 1024 selected
   (toy widths with ``--smoke``), the benchmark's ``dsv32-dsa-decode`` 16
   sequences of 8k to 128k with the published 2048 through four layers.
+* ``kda_decode``: one decode step of one period of a linear-attention
+  hybrid (Kimi-Linear; ``models/delta_attention.py``): three KDA layers on
+  a recurrent state (one engine menu a group of sequences: one fused
+  ``kda_step`` kernel that reads the state once and writes it once against
+  the chain of four XLA vertices), then ``mla_decode``'s latent-attention
+  layer at 32 heads.  This row runs Kimi-Linear's widths on ``mla_decode``'s
+  eight short sequences (toy widths with ``--smoke``), the benchmark's
+  ``kimi-linear-kda-decode`` 128 sequences of 1k to 131k.
 * ``moe``: single-chip MoE dispatch/combine pipeline — routed tokens staged
   through async host round-trip DMAs to the resident experts (the
   expert-parallel network-hop analog), searched over order x lane x
@@ -676,6 +684,71 @@ def _dsa_cost(built):
         a.index_dim, a.topk, nope=lat.nope)
 
 
+# -- kda_decode ---------------------------------------------------------------
+
+_KDA_PATTERN = (("kda", "L0"), ("kda", "L1"), ("kda", "L2"), ("mla", "L3"))
+
+
+def _kda_dims(req):
+    """The KDA layers' sizes beside the latent layer's (``_mla_dims``, at
+    Kimi-Linear's 32 heads and plain ``192^(-1/2)`` scale)."""
+    if req.smoke:
+        return dict(heads=2, d=16, taps=4, groups=2, dtype="float32")
+    return dict(heads=32, d=128, taps=4, groups=2, dtype="bfloat16")
+
+
+def _kda_args(req):
+    from tenzing_tpu.models.delta_attention import DeltaDecodeArgs
+    from tenzing_tpu.models.latent_attention import LatentDecodeArgs
+
+    m = _mla_dims(req)
+    if not req.smoke:
+        m["heads"] = 32
+    mla = LatentDecodeArgs(scale=(m["nope"] + m["rope"]) ** -0.5, **m)
+    return DeltaDecodeArgs(batch=mla.batch, **_kda_dims(req)), mla
+
+
+def _kda_shape(req):
+    d = _kda_dims(req)
+    return {**_mla_shape(req), "kda_layers": 3, "kda_heads": d["heads"],
+            "kda_dim": d["d"], "kda_groups": d["groups"]}
+
+
+def _kda_parts(req):
+    from tenzing_tpu.models.delta_attention import (
+        hybrid_decode_graph,
+        make_kda_buffers,
+    )
+    from tenzing_tpu.models.latent_attention import make_decode_buffers
+
+    kda, mla = _kda_args(req)
+    g = hybrid_decode_graph(kda, mla, _KDA_PATTERN,
+                            impl_choice=not req.smoke)
+    bufs = make_kda_buffers(
+        kda, [t for k, t in _KDA_PATTERN if k == "kda"], seed=0)
+    bufs.update(make_decode_buffers(
+        mla, [t for k, t in _KDA_PATTERN if k == "mla"], seed=0))
+    return g, bufs, (kda, mla)
+
+
+def build_kda_decode(args):
+    import jax.numpy as jnp
+
+    g, bufs, a = _kda_parts(args)
+    bufs = {k: jnp.asarray(v) for k, v in bufs.items()}
+    return g, bufs, metric_for("kda_decode", args), a
+
+
+def _kda_cost(built):
+    kda, mla = built[3]
+    state = roofline.kda_decode_cost(kda.batch, kda.heads, kda.d, kda.taps,
+                                     layers=3)
+    cache = roofline.latent_decode_cost(mla.lens, mla.heads, mla.rank,
+                                        mla.rope, mla.v_dim, nope=mla.nope)
+    return roofline.Cost(flops=state.flops + cache.flops,
+                         hbm_bytes=state.hbm_bytes + cache.hbm_bytes)
+
+
 # -- the table ----------------------------------------------------------------
 
 @dataclass
@@ -797,6 +870,13 @@ WORKLOADS: Dict[str, Workload] = {
         metric=lambda req: "dsa_decode_pct50_searched_k%d" % (
             _dsa_shape(req)["keys"]),
         cost=_dsa_cost, phases=lambda: ("L0.",)),
+    "kda_decode": Workload(
+        build=build_kda_decode, graph=_device_free(_kda_parts),
+        shape=_kda_shape,
+        metric=lambda req: "kda_decode_pct50_searched_k%d" % (
+            _kda_shape(req)["keys"]),
+        cost=_kda_cost, phases=lambda: tuple(
+            f"{t}." for _, t in _KDA_PATTERN)),
     "moe": Workload(
         build=build_moe, graph=_device_free(_moe_parts), shape=_moe_shape,
         metric=lambda req: "moe_pipe_pct50_searched_t%d" % (

@@ -496,31 +496,39 @@ class MlaEngineChoice(ChoiceOp):
                 MlaDecode(self.name() + ".fused", *self._where)]
 
 
+def add_layer(g: Graph, args: LatentDecodeArgs, plan: List[Group], tag: str,
+              after=(), impl_choice: bool = False) -> OpBase:
+    """One layer's vertices: the append and the absorb first, side by side
+    (each behind every vertex of ``after``; none: behind the graph's start),
+    then the groups' engine menus, side by side, then the up-projection,
+    which is returned."""
+    pre = f"{tag}." if tag else ""
+    heads = [Append(pre + "append", args, tag),
+             Absorb(pre + "absorb", args, tag)]
+    up = UpProject(pre + "up_project", args, tag)
+    for h in heads:
+        if not after:
+            g.start_then(h)
+        for prev in after:
+            g.then(prev, h)
+    for grp in plan:
+        read = MlaEngineChoice(args, grp, tag, impl_choice)
+        for h in heads:
+            g.then(h, read)
+        g.then(read, up)
+    return up
+
+
 def decode_graph(args: LatentDecodeArgs, layers, impl_choice: bool = False
                  ) -> Graph:
     """The step's layers one after another, as the residual stream orders
-    them (layer l+1 starts when layer l's ``o`` is final).  In a layer the
-    append and the absorb come first, side by side, then the groups' engine
-    menus, side by side, then the up-projection."""
+    them (layer l+1 starts when layer l's ``o`` is final)."""
     plan = decode_plan(args)
     g = Graph()
     last = None
     for tag in layers:
-        pre = f"{tag}." if tag else ""
-        heads = [Append(pre + "append", args, tag),
-                 Absorb(pre + "absorb", args, tag)]
-        up = UpProject(pre + "up_project", args, tag)
-        for h in heads:
-            if last is None:
-                g.start_then(h)
-            else:
-                g.then(last, h)
-        for grp in plan:
-            read = MlaEngineChoice(args, grp, tag, impl_choice)
-            for h in heads:
-                g.then(h, read)
-            g.then(read, up)
-        last = up
+        last = add_layer(g, args, plan, tag, [last] if last else (),
+                         impl_choice)
     g.then_finish(last)
     return g
 

@@ -99,7 +99,7 @@ def main(argv: Optional[List[str]] = None) -> int:
 
     def request_flags(p):
         p.add_argument("--workload",
-                       choices=("halo", "spmv", "attn", "mla_decode", "dsa_decode", "moe"),
+                       choices=("halo", "spmv", "attn", "mla_decode", "dsa_decode", "kda_decode", "moe"),
                        default="halo")
         p.add_argument("--smoke", action="store_true",
                        help="the tiny CPU config's fingerprint")

@@ -93,7 +93,7 @@ def test_search_lanes_default_rule():
 
 def test_builders_cover_all_workloads():
     assert set(BUILDERS) == {"halo", "spmv", "attn", "mla_decode", "dsa_decode",
-                             "moe"}
+                             "kda_decode", "moe"}
 
 
 def test_graph_for_is_device_free():

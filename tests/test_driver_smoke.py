@@ -75,6 +75,8 @@ CASES = {
                    "mla_decode_pct50_searched_k212", [], ["fault"]),
     "dsa_decode": (dict(workload="dsa_decode", mcts_iters=6),
                    "dsa_decode_pct50_searched_k212", [], ["fault"]),
+    "kda_decode": (dict(workload="kda_decode", mcts_iters=6),
+                   "kda_decode_pct50_searched_k212", [], ["fault"]),
 }
 # two files, so that --dist loadfile gives the runs to two workers: the
 # switches of one workload here, the plain workloads in
@@ -107,6 +109,7 @@ EXTRA = {
     "attn": ([], "incumbents"),
     "mla_decode": ([], "incumbents"),
     "dsa_decode": ([], "incumbents"),
+    "kda_decode": ([], "incumbents"),
 }
 
 

@@ -9,7 +9,8 @@ from test_driver_smoke import check_smoke_verdict
 
 
 @pytest.mark.needs_pinned_host
-@pytest.mark.parametrize("case", ["halo", "spmv", "attn", "mla_decode", "dsa_decode", "moe",
+@pytest.mark.parametrize("case", ["halo", "spmv", "attn", "mla_decode",
+                                  "dsa_decode", "kda_decode", "moe",
                                   "synth"])
 def test_smoke_workload_verdict_is_pinned(case, capfd):
     check_smoke_verdict(case, capfd)

@@ -26,7 +26,8 @@ from tenzing_tpu.ops.comm_ops import AwaitTransfer, CommStart, MultiAwait
 from tenzing_tpu.runtime.executor import TraceExecutor
 
 DATA = Path(__file__).parent / "data"
-TOYS = ("halo", "spmv", "moe", "attn", "mla_decode", "dsa_decode")
+TOYS = ("halo", "spmv", "moe", "attn", "mla_decode", "dsa_decode",
+        "kda_decode")
 OWN_TRACE = (CommStart, AwaitTransfer, MultiAwait)  # ops/comm_ops.py
 
 
@@ -37,7 +38,7 @@ def _is_sync(op) -> bool:
 @pytest.fixture(scope="module")
 def toys():
     """``{workload: (executor, graph, naive, lowered text, compiled text)}``
-    of the six smoke graphs, each built and compiled once."""
+    of the seven smoke graphs, each built and compiled once."""
     built = {}
 
     def get(wl):

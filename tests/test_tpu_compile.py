@@ -594,6 +594,111 @@ def test_latent_decode_loop_moves_no_pool(one_chip, monkeypatch, which,
     assert compiled.memory_analysis().temp_size_in_bytes < 400 << 20
 
 
+# kimi-linear-kda-decode.climb: 128 sequences (the configuration's lengths),
+# three KDA layers of 32 heads on a 128 x 128 float32 state and one latent
+# layer of 32 heads on dsv3-mla-decode's cache layout
+def _kda_cell():
+    import json
+
+    from tenzing_tpu.models.delta_attention import DeltaDecodeArgs
+    from tenzing_tpu.models.latent_attention import LatentDecodeArgs
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmarks", "configs",
+                           "kimi-linear-kda-decode.json")) as f:
+        config = json.load(f)
+    shapes, lin = config["shapes"], config["linear_attn_config"]
+    lens = tuple(sorted(shapes["lens"]))
+    kda = DeltaDecodeArgs(
+        batch=len(lens), heads=lin["num_heads"], d=lin["head_dim"],
+        taps=lin["short_conv_kernel_size"], groups=shapes["kda_groups"],
+        eps=config["rms_norm_eps"])
+    mla = LatentDecodeArgs(
+        lens=lens, heads=config["num_attention_heads"], scale=192 ** -0.5,
+        page=shapes["page_tokens"], groups=shapes["groups"],
+        fold_pages=shapes["fold_pages"])
+    pattern = [(k, f"L{i}") for i, k in enumerate(config["pattern"])]
+    return kda, mla, pattern
+
+
+def test_kda_step_kernel(one_chip):
+    """``kda_step`` at the cell's shapes, its second group: Mosaic takes a
+    grid step of one sequence's 32 states (2 MB in, 2 MB out, one (128,
+    128) transpose for the heads' columns, the walk over the heads
+    unrolled), ``Snew``, ``Cvnew`` and ``o`` aliased in place."""
+    from tenzing_tpu.models.delta_attention import KdaFused, buffer_shapes
+    from tenzing_tpu.ops.kda_pallas import kda_step_pallas
+
+    kda, _, _ = _kda_cell()
+    shapes = buffer_shapes(kda, ("L0",))
+    operands = [_sds(*shapes[f"{k}.L0"][:1], jnp.dtype(shapes[f"{k}.L0"][1]),
+                     one_chip) for k in KdaFused.READS + KdaFused.WRITES]
+    assert operands[9].shape == (kda.batch, 32, 128, 128)
+    compiled = kda_step_pallas.lower(
+        *operands, lead0=kda.rows, rows=kda.rows, eps=kda.eps,
+        interpret=False).compile()
+    _assert_kernel(compiled)
+    assert "kda_step" in compiled.as_text()  # the name the trace shows
+    # no temporary stands in for a state
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("which", ["start", "naive"])
+def test_hybrid_decode_loop_moves_each_state_once(one_chip, monkeypatch,
+                                                  which):
+    """The repeat-n program of ``kimi-linear-kda-decode.climb``'s start
+    point (every (layer, group) on its fused kernel) and of its naive (the
+    XLA chains) as the TPU compiler leaves them: inside the ``while`` body
+    the start point touches a layer's ``(128, 32, 128, 128)`` state with its
+    ``kda_step`` kernels alone (no copy, no slice), the latent layer's
+    sealed pool with nothing but ``mla_decode``, and the whole program fits
+    the chip beside its arguments."""
+    from benchmarks.builders.kda_decode import unfused_prefer
+    from tenzing_tpu.bench.workloads import attn_fused_prefer
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models import delta_attention, latent_attention
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+    from tenzing_tpu.runtime.executor import TraceExecutor
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kda, mla, pattern = _kda_cell()
+    tags = {kind: [t for k, t in pattern if k == kind]
+            for kind in ("kda", "mla")}
+    shapes = {**delta_attention.buffer_shapes(kda, tags["kda"]),
+              **latent_attention.buffer_shapes(mla, tags["mla"])}
+    bufs = {name: _sds(shape, jnp.dtype(dtype), one_chip)
+            for name, (shape, dtype) in shapes.items()}
+    graph = delta_attention.hybrid_decode_graph(kda, mla, pattern)
+    plat = Platform.make_n_lanes(2 if which == "start" else 1)
+    seq, _ = drive(graph, plat, phase_policy(
+        plat, [t + "." for _, t in pattern],
+        attn_fused_prefer if which == "start" else unfused_prefer))
+    ex = TraceExecutor(plat, bufs)
+    compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(
+        bufs, _sds((), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    state = loop_ops_of_shape(
+        text, f"f32[{kda.batch},{kda.heads},{kda.d},{kda.d}]")
+    sealed = loop_ops_of_shape(
+        text, f"bf16[{mla.pool_pages},{mla.width},{mla.page}]")
+    assert not sealed
+    mem = compiled.memory_analysis()
+    if which == "start":
+        assert text.count("tpu_custom_call") == 3 * kda.groups + mla.groups
+        assert len(state) == 3 * kda.groups
+        assert all(o.opcode == "custom-call" for o in state), [
+            (o.name, o.opcode) for o in state]
+        assert mem.temp_size_in_bytes < 1 << 30
+    else:
+        # the chain writes its group's rows of Snew in place
+        assert all(o.opcode in ("fusion", "dynamic-update-slice")
+                   for o in state), [(o.name, o.opcode) for o in state]
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes)
+    assert total < HBM_BYTES
+
+
 # dsv32-dsa-decode.climb: dsv3-mla-decode's sixteen sequences; 64 index heads
 # over a paged index-key cache of 128-wide keys, the 2048 largest scores a
 # sequence, their latent rows gathered from row-major pools of 640-wide rows

@@ -14,7 +14,8 @@ from tenzing_tpu.bench import driver, workloads
 from tenzing_tpu.bench.driver import DriverRequest
 from tenzing_tpu.bench.workloads import WORKLOADS, DriverConfigError
 
-NAMES = ["halo", "spmv", "attn", "mla_decode", "dsa_decode", "moe"]
+NAMES = ["halo", "spmv", "attn", "mla_decode", "dsa_decode", "kda_decode",
+         "moe"]
 
 
 def test_the_table_is_the_set_of_workloads():
@@ -41,6 +42,9 @@ def test_the_table_is_the_set_of_workloads():
     (dict(workload="dsa_decode", smoke=True),
      "dsa_decode_pct50_searched_k212"),
     (dict(workload="dsa_decode"), "dsa_decode_pct50_searched_k32908"),
+    (dict(workload="kda_decode", smoke=True),
+     "kda_decode_pct50_searched_k212"),
+    (dict(workload="kda_decode"), "kda_decode_pct50_searched_k32908"),
     (dict(workload="moe", smoke=True), "moe_pipe_pct50_searched_t32"),
     (dict(workload="moe"), "moe_pipe_pct50_searched_t8192"),
     (dict(workload="moe", moe_tokens=4096), "moe_pipe_pct50_searched_t4096"),
@@ -67,7 +71,8 @@ def test_an_unknown_name_is_a_config_error(lookup):
 
 @pytest.mark.parametrize("name,lanes", [("halo", 8), ("spmv", 2),
                                         ("attn", 2), ("mla_decode", 2),
-                                        ("dsa_decode", 2), ("moe", 2)])
+                                        ("dsa_decode", 2), ("kda_decode", 2),
+                                        ("moe", 2)])
 def test_lane_rule_per_row(name, lanes):
     assert workloads.search_lanes(DriverRequest(workload=name)) == lanes
     assert workloads.search_lanes(
@@ -115,7 +120,8 @@ def test_a_name_without_a_row_takes_the_generic_naive():
 @pytest.mark.needs_pinned_host
 @pytest.mark.parametrize("name,labels", [
     ("halo", ["greedy-overlap"]), ("spmv", []), ("attn", []),
-    ("mla_decode", []), ("dsa_decode", []), ("moe", ["greedy-overlap"])])
+    ("mla_decode", []), ("dsa_decode", []), ("kda_decode", []),
+    ("moe", ["greedy-overlap"])])
 def test_smoke_incumbents_per_row(name, labels):
     from tenzing_tpu.core.platform import Platform
     from tenzing_tpu.verify import ScheduleVerifier
@@ -191,6 +197,7 @@ def test_a_recorded_schedule_seeds_the_first_climb():
     ("attn", "experiments/attn_search_tpu_r[45]*.csv"),
     ("mla_decode", ""),
     ("dsa_decode", ""),
+    ("kda_decode", ""),
     ("moe", "experiments/moe_search_tpu_r[45]*.csv")])
 def test_recorded_database_default_per_row(name, glob):
     assert WORKLOADS[name].seed_csv == glob
