@@ -456,6 +456,11 @@ class CachingBenchmarker:
         total = self.hits + self.misses
         return self.hits / total if total else 0.0
 
+    def has(self, order: Sequence, opts: Optional[BenchOpts] = None) -> bool:
+        """Whether ``benchmark(order, opts)`` would be answered from the
+        cache (a probe: it counts as neither hit nor miss)."""
+        return self._key(order, opts) in self._cache
+
     def benchmark(self, order: Sequence, opts: Optional[BenchOpts] = None) -> BenchResult:
         key = self._key(order, opts)
         hit = key in self._cache

@@ -700,14 +700,28 @@ def assemble_stack(executor, graph, req: DriverRequest, surrogate,
         injector = FaultInjectingBenchmarker(
             emp, inner_specs, hang_secs=req.inject_hang_secs)
         measured_stack = injector
+    ckpt = SearchCheckpoint(req.checkpoint) if req.checkpoint else None
     prefetcher = None
-    if req.prefetch_compiles > 0 and req.resume:
+    if req.resume:
         # a resumed run answers journaled measurements without touching the
         # executor (the PR 3 "0 compiles" provenance); background hints
         # would compile programs the journal already answers — keep the
-        # resume contract and skip the pipeline
+        # resume contract and skip the pipeline.  What stays of it is its
+        # width: the tree search draws ``workers`` rollouts ahead
+        # (MctsOpts.prefetch), and only a search that draws what the
+        # interrupted one drew finds its rollouts in the journal.  So the
+        # solvers get a prefetcher of the recorded width, outside the
+        # measured stack, with nothing to compile
+        width = _recorded_lookahead(ckpt, req)
         sys.stderr.write("prefetch: disabled under --resume (journaled "
-                         "answers never compile)\n")
+                         "answers never compile); the tree search keeps "
+                         f"the checkpoint's lookahead of {width}\n")
+        if width > 0:
+            from tenzing_tpu.bench.pipeline import PrefetchingBenchmarker
+
+            prefetcher = PrefetchingBenchmarker(
+                measured_stack, executor=_NothingToCompile(), workers=width)
+            scope.on_exit(prefetcher.close)
     elif req.prefetch_compiles > 0:
         from tenzing_tpu.bench.pipeline import PrefetchingBenchmarker
 
@@ -724,7 +738,6 @@ def assemble_stack(executor, graph, req: DriverRequest, surrogate,
         # only AFTER the queue empties (~3.4 s per pending compile), while
         # close() cancels pending first.  Idempotent; SIGINT has the trap.
         scope.on_exit(prefetcher.close)
-    ckpt = SearchCheckpoint(req.checkpoint) if req.checkpoint else None
     quar = Quarantine(ckpt.quarantine_path if ckpt else None,
                       log=lambda m: sys.stderr.write(m + "\n"))
     if len(quar):
@@ -746,17 +759,49 @@ def assemble_stack(executor, graph, req: DriverRequest, surrogate,
     bench = CachingBenchmarker(
         JournalingBenchmarker(guarded, ckpt) if ckpt else guarded)
     if ckpt is not None:
-        _open_checkpoint(ckpt, bench, graph, req, scope)
+        _open_checkpoint(ckpt, bench, graph, req, scope,
+                         lookahead=getattr(prefetcher, "workers", 0))
     return Stack(emp=emp, injector=injector, prefetcher=prefetcher,
                  resilient=resilient, corrupt_injector=corrupt_injector,
                  quarantine=quar, checkpoint=ckpt, bench=bench,
                  verifier=verifier)
 
 
+class _NothingToCompile:
+    """The executor of a resumed run's prefetcher: every program counts as
+    compiled, so no hint is ever issued."""
+
+    @staticmethod
+    def is_compiled(order) -> bool:
+        return True
+
+    @staticmethod
+    def precompile(order) -> bool:
+        return False
+
+
+def _recorded_lookahead(ckpt, req: DriverRequest) -> int:
+    """How far ahead the interrupted run's tree search drew: the width its
+    checkpoint recorded, 0 for a checkpoint from before widths were
+    recorded (those searches drew one rollout at a time), and this run's
+    ``--prefetch-compiles`` where there is no snapshot to resume from."""
+    prior = None
+    if ckpt is not None:
+        try:
+            prior = ckpt.load_state()
+        except Exception:  # corrupt snapshot: _open_checkpoint reports it
+            pass
+    if prior is None:
+        return max(0, req.prefetch_compiles)
+    return int(prior.get("lookahead", 0))
+
+
 def _open_checkpoint(ckpt, bench, graph, args: DriverRequest,
-                     scope: _RunScope) -> None:
+                     scope: _RunScope, lookahead: int = 0) -> None:
     """Check ``--checkpoint``'s recorded config against this run's, restore
-    its journal under ``--resume``, and register its final snapshots."""
+    its journal under ``--resume``, and register its final snapshots.
+    ``lookahead``: the width this run's tree search draws ahead with,
+    recorded for the run that resumes it."""
     config = {"workload": args.workload,
               "metric": metric_for(args.workload, args),
               "smoke": bool(args.smoke), "seed_topk": args.seed_topk}
@@ -790,7 +835,7 @@ def _open_checkpoint(ckpt, bench, graph, args: DriverRequest,
         sys.stderr.write(
             f"resume: {restored} recorded measurement(s) restored — "
             "already-measured schedules will not touch the device\n")
-    ckpt.save_state(config=config, inject=want_inject)
+    ckpt.save_state(config=config, inject=want_inject, lookahead=lookahead)
 
     # final snapshots: the journal and quarantine are already on disk
     # (appended/rewritten as each measurement landed), so these only

@@ -32,8 +32,13 @@ Fault discipline — background threads NEVER touch the control plane:
   failure is consumed by the raise).
 * hints are *advisory*: they consume no solver RNG, touch no platform state
   (``provision_events`` is foreground-only bookkeeping), and a full queue
-  drops excess hints rather than blocking — with prefetch disabled (or every
-  hint dropped) behavior is bit-identical to today's.
+  drops excess hints rather than blocking — for DFS and the hill climb,
+  prefetch disabled (or every hint dropped) is bit-identical to prefetch on.
+  The tree search reads one thing off the prefetcher, its ``workers``: it
+  draws that many rollouts ahead of the one it measures so as to have real
+  hints to give (``solve/mcts/mcts.py``), so there the search depends on the
+  pool's width and on nothing its threads do: compiles that do nothing,
+  fail or finish at any other time give the same search.
 
 Observability (docs/performance.md): ``pipeline.prefetch.issued`` /
 ``hits`` / ``wasted`` / ``failed`` / ``surfaced`` / ``dropped`` counters, a

@@ -7,12 +7,19 @@ to all hosts, provision events, benchmark on every host, backprop (rank 0),
 periodic graphviz tree dump with decaying cadence (mcts.hpp:52-127,302-309),
 phase counters (counters.hpp), stop when the root is fully visited
 (mcts.hpp:194-201) — broadcast via the control plane's stop protocol.
+
+Beyond the reference: given a compile prefetcher (``MctsOpts.prefetch``),
+rank 0 draws its next rollouts before the last one is measured — a short
+queue of drawn, hinted, unmeasured schedules, each a pending visit on its
+path (``node.py``) — so that their first calls run behind the measurement in
+hand.  Rollouts are measured in the order drawn.
 """
 
 from __future__ import annotations
 
 import random as _random
 import sys
+from collections import deque
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple, Type
 
@@ -29,6 +36,7 @@ from tenzing_tpu.core.schedule import remove_redundant_syncs
 from tenzing_tpu.core.sequence import Sequence, canonical_key
 from tenzing_tpu.core.serdes import sequence_from_json, sequence_to_json
 from tenzing_tpu.core.state import State
+from tenzing_tpu.obs.metrics import get_metrics
 from tenzing_tpu.obs.progress import get_reporter
 from tenzing_tpu.obs.tracer import get_tracer
 from tenzing_tpu.parallel.control_plane import ControlPlane, default_control_plane
@@ -82,15 +90,25 @@ class MctsOpts:
     # negative-cached) and a ``verify.unsound`` event lands in the trace.
     # Deterministic and device-free, so identical on every rank.
     verify: Optional[object] = None
-    # compile prefetcher (bench.pipeline.PrefetchingBenchmarker): candidate
-    # hints — the seed queue up front, speculative completions of the
-    # expanded node's unplayed children per iteration, the confirm queue
-    # before the sequential confirm loop — start background AOT compiles
-    # while the foreground measurement runs.  Hints are advisory and consume
-    # no search RNG: None (the default) is bit-identical to prefetch-off.
+    # compile prefetcher (bench.pipeline.PrefetchingBenchmarker): the search
+    # hints it the seed queue up front, every rollout as it is drawn, and
+    # the confirm queue before the sequential confirm loop, and the hinted
+    # first calls run in the background behind the measurement in hand.
+    # With a prefetcher rank 0 draws ahead: before it measures a rollout it
+    # has drawn (and hinted) that one and ``prefetch.workers`` more, each
+    # drawn against the tree as it stands with the unmeasured ones counted
+    # as pending visits (node.py).  So a prefetcher is NOT bit-identical to
+    # prefetch-off here, as it is for DFS and the hill climb: the search is
+    # bit-identical to the same search against a prefetcher of the same
+    # ``workers`` whose compiles do nothing, or finish at any other time.
+    # What the prefetcher does never reaches the search, only its width
+    # does: a resumed search has to be given the width it had (the driver
+    # records it in the checkpoint), and a drawn rollout that the caching
+    # layer already answers is not hinted.  Every strategy supports it: a
+    # pending, unmeasured child scores a neutral 0.0 (Node.select).  None
+    # (the default), or a prefetcher without ``workers``, draws each
+    # rollout after the last one's backprop: the reference's loop.
     prefetch: Optional[object] = None
-    # how many speculative child completions to hint per iteration
-    prefetch_rollouts: int = 2
     # disjoint fleet sharding ``(k, n)`` (search/fleet.py): restrict the
     # search to the k-th of n slices of the root's top-level children —
     # the enumeration is deterministic (Node.ensure_children sorts by
@@ -148,6 +166,18 @@ class MctsResult:
         return min(self.sims, key=lambda s: s.result.pct10)
 
 
+@dataclass
+class _Drawn:
+    """A rollout drawn and not yet measured: an entry of rank 0's queue."""
+
+    endpoint: Node
+    order: Sequence
+    grew: list  # ``(node, children it had)`` as drawn, for ``take_back``
+    n_grown: int  # nodes it added to the tree
+    seeded: bool
+    selected: Optional[str]  # the expanded child's decision, for the trace
+
+
 def _dump_cadence(it: int) -> bool:
     """Decaying dump cadence (reference mcts.hpp:302-309): every iteration up to
     10, then every 10th up to 100, then every 100th."""
@@ -158,7 +188,7 @@ def _dump_cadence(it: int) -> bool:
     return it % 100 == 0
 
 
-def _materialize_seed(root: Node, path) -> tuple:
+def _materialize_seed(root: Node, path, grew: Optional[list] = None) -> tuple:
     """Walk ``path`` (a decision list from ``solve.local.drive``) down the
     tree, creating ONLY the matching child per step (siblings are left for
     ``ensure_children`` to fill lazily when UCT actually visits the node — a
@@ -167,7 +197,7 @@ def _materialize_seed(root: Node, path) -> tuple:
     terminal state reached by applying the FULL path).  Decisions match by
     content key — the same mechanism the hill-climb's neighbor replay uses —
     so a path recorded on an independent State chain of the same graph lands
-    on the same tree nodes."""
+    on the same tree nodes.  ``grew`` as in ``Node.ensure_children``."""
     node, st = root, root.state
     matched = True
     for d in path:
@@ -182,6 +212,8 @@ def _materialize_seed(root: Node, path) -> tuple:
                 # pre-create just this child; expanded_ stays False so the
                 # node's remaining decisions enumerate on first real visit
                 nxt = Node(st, node.strategy, d, node)
+                if grew is not None:
+                    grew.append((node, len(node.children)))
                 node.children.append(nxt)
             if nxt is None:
                 matched = False
@@ -208,33 +240,6 @@ def _seed_orders(graph: Graph, seeds, limit: int) -> list:
         if st.is_terminal():
             orders.append(st.sequence)
     return orders
-
-
-def _speculative_completions(node: Node, platform, prng, k: int,
-                             skip: Optional[Node] = None) -> list:
-    """Up to ``k`` plausible future rollouts for the compile prefetcher:
-    complete the unplayed children of the just-expanded node to terminal
-    schedules on THROWAWAY States with a forked RNG.
-
-    Strictly side-effect-free with respect to the search: the tree is never
-    touched (no ensure_children, no node creation), the search RNG is never
-    consumed, and the (possibly stateful — bench.py's phase_policy carries a
-    lane round-robin) rollout policy is never called — uniform-random
-    completion only.  Misses are the prefetcher's ``wasted`` counter's job
-    to account, not a correctness concern."""
-    hints = []
-    kids = [c for c in node.children
-            if c.n_ == 0 and c is not skip] or [node]
-    for child in kids[:k]:
-        st = child.state
-        while not st.is_terminal():
-            ds = st.get_decisions(platform)
-            if not ds:
-                break
-            st = st.apply(prng.choice(ds))
-        if st.is_terminal():
-            hints.append(remove_redundant_syncs(st.sequence))
-    return hints
 
 
 def prune_to_subtree(root: Node, platform, subtree: Tuple[int, int]) -> None:
@@ -306,6 +311,15 @@ def explore(
             opts.checkpoint.save_state(
                 mcts={"n_sims": len(result.sims), "interrupted": True})
 
+    reg = get_metrics()
+    root: Optional[Node] = None
+    # rank 0's lookahead: rollouts drawn, hinted and not yet measured, oldest
+    # first, each a pending visit on its path.  Its width is the
+    # prefetcher's: the rollout in hand and one more for every worker that
+    # could be compiling behind it
+    queue: deque = deque()
+    ahead = (getattr(opts.prefetch, "workers", 0)
+             if opts.prefetch is not None else 0)
     trap.register_handler(dump_partial)
     # manual enter/exit (not `with`): the finally below must set the
     # run-total attrs on every exit path, including the mid-block return
@@ -326,66 +340,113 @@ def explore(
             opts.prefetch.prefetch(_seed_orders(
                 graph, seeds, getattr(opts.prefetch, "depth", 8)))
         failed_keys: set = set()  # negative cache for uncompilable schedules
+        ropts = opts.screen_opts if opts.screen_opts is not None else (
+            opts.bench_opts)
+
+        def answered(order: Sequence) -> bool:
+            """No first call lies ahead of ``order``: the caching layer (its
+            journal-restored entries included) holds the result of an
+            equivalent schedule.  Decides what is hinted, never what is
+            drawn."""
+            return (isinstance(benchmarker, CachingBenchmarker)
+                    and benchmarker.has(order, ropts))
+
+        def draw(grew: list) -> Optional[_Drawn]:
+            """The next rollout against the tree as it stands, seed paths
+            first; None when no further one can be drawn.  ``grew`` lists
+            what it adds to the tree, also where it raises."""
+            assert root is not None
+            selected = None
+            path = next(seed_iter, None)
+            if path is not None:
+                with counters.phase("SEED"):
+                    endpoint, st = _materialize_seed(root, path, grew)
+                    if not st.is_terminal():  # defensive: complete
+                        _, order = endpoint.get_rollout(
+                            platform, rng,
+                            policy=opts.rollout_policy,
+                            policy_eps=opts.rollout_eps,
+                        )
+                    else:
+                        # benchmarked AS RECORDED (no redundant-sync
+                        # cleanup): the cache key matches the incumbent's
+                        # measurement exactly when the rollout opts do
+                        # (with a multi-fidelity screen floor the seed is
+                        # instead re-measured cheaply at that floor)
+                        order = st.sequence
+            elif root.closed():
+                return None
+            else:
+                with counters.phase("SELECT"):
+                    leaf = root.select(ctx, platform, rng, grew)
+                with counters.phase("EXPAND"):
+                    child = leaf.expand(platform, rng, grew)
+                with counters.phase("ROLLOUT"):
+                    endpoint, order = child.get_rollout(
+                        platform, rng, opts.expand_rollout,
+                        policy=opts.rollout_policy,
+                        policy_eps=opts.rollout_eps, grew=grew,
+                    )
+                with counters.phase("REDUNDANT_SYNC"):
+                    order = remove_redundant_syncs(order)
+                if tr.enabled and child.decision is not None:
+                    selected = child.decision.desc()
+            endpoint.mark_pending()
+            return _Drawn(endpoint, order, grew,
+                          sum(len(n.children) - had for n, had in grew),
+                          path is not None, selected)
+
+        def settle(res: BenchResult) -> None:
+            """Rank 0: the head of the queue is measured (or refused and
+            given its penalty): its pending visits become real ones."""
+            with counters.phase("BACKPROP"):
+                queue.popleft().endpoint.backprop(ctx, res, pending=True)
+
+        def measured_size() -> int:
+            """The tree without what the queued draws added to it."""
+            return root.size() - sum(d.n_grown for d in queue)
+
         for it in range(opts.n_iters):
             # per-iteration span (ISSUE 1): which node/path was selected,
             # the rolled-out schedule's hash, the measured time and the tree
             # size — the phase spans (mcts.phase.*) nest inside it
             with tr.span("mcts.iter", it=it) as it_sp:
-                stop = False
                 order: Optional[Sequence] = None
-                endpoint: Optional[Node] = None
                 if cp.rank() == 0:
-                    assert root is not None
-                    path = next(seed_iter, None)
-                    if path is not None:
-                        it_sp.set("seeded", True)
-                        with counters.phase("SEED"):
-                            endpoint, st = _materialize_seed(root, path)
-                            if not st.is_terminal():  # defensive: complete
-                                _, order = endpoint.get_rollout(
-                                    platform, rng,
-                                    policy=opts.rollout_policy,
-                                    policy_eps=opts.rollout_eps,
-                                )
-                            else:
-                                # benchmarked AS RECORDED (no redundant-sync
-                                # cleanup): the cache key matches the incumbent's
-                                # measurement exactly when the rollout opts do
-                                # (with a multi-fidelity screen floor the seed is
-                                # instead re-measured cheaply at that floor)
-                                order = st.sequence
-                    elif root.fully_visited_:
-                        stop = True
-                    else:
-                        with counters.phase("SELECT"):
-                            leaf = root.select(ctx, platform, rng)
-                        with counters.phase("EXPAND"):
-                            child = leaf.expand(platform, rng)
-                        with counters.phase("ROLLOUT"):
-                            endpoint, order = child.get_rollout(
-                                platform, rng, opts.expand_rollout,
-                                policy=opts.rollout_policy,
-                                policy_eps=opts.rollout_eps,
-                            )
-                        with counters.phase("REDUNDANT_SYNC"):
-                            order = remove_redundant_syncs(order)
-                        if opts.prefetch is not None:
-                            # expansion-children lookahead: speculative
-                            # completions of the leaf's other unplayed
-                            # children compile in the background while this
-                            # rollout measures (forked RNG, throwaway
-                            # States — the search itself is untouched)
-                            opts.prefetch.prefetch(_speculative_completions(
-                                leaf, platform,
-                                _random.Random(
-                                    f"prefetch:{opts.seed}:{it}"),
-                                opts.prefetch_rollouts, skip=child))
-                        if tr.enabled and child.decision is not None:
-                            it_sp.set("selected", child.decision.desc())
+                    fresh = []
+                    while (len(queue) <= ahead
+                           and it + len(queue) < opts.n_iters):
+                        grew: list = []
+                        try:
+                            drawn = draw(grew)
+                        except BaseException:
+                            Node.ungrow(grew)  # a failed draw leaves no nodes
+                            raise
+                        if drawn is None:
+                            break
+                        if queue:
+                            reg.counter("mcts.lookahead.drawn").inc()
+                        queue.append(drawn)
+                        if (opts.prefetch is not None
+                                and not answered(drawn.order)):
+                            fresh.append(drawn.order)
+                    if fresh:
+                        # their first calls start now, behind the
+                        # measurements ahead of them in the queue
+                        opts.prefetch.prefetch(fresh)
+                    if queue:
+                        head = queue[0]
+                        order = head.order
+                        reg.gauge("mcts.lookahead.pending").set(
+                            len(queue) - 1)
+                        if head.seeded:
+                            it_sp.set("seeded", True)
+                        if head.selected is not None:
+                            it_sp.set("selected", head.selected)
                 # stop-flag + schedule broadcast (mcts.hpp:129-152,244)
                 with counters.phase("BCAST"):
-                    stop = cp.bcast_json(stop)
-                    if stop:
+                    # stop: the tree has no rollout left, drawn or to draw
+                    if cp.bcast_json(cp.rank() == 0 and order is None):
                         break
                     payload = cp.bcast_json(
                         sequence_to_json(order) if cp.rank() == 0 else None
@@ -401,8 +462,6 @@ def explore(
                 key = canonical_key(order)
                 if tr.enabled:
                     it_sp.set("schedule", schedule_id(order))
-                ropts = opts.screen_opts if opts.screen_opts is not None else (
-                    opts.bench_opts)
                 res: Optional[BenchResult] = None
                 if key not in failed_keys and opts.verify is not None:
                     verdict = opts.verify(order)
@@ -453,10 +512,8 @@ def explore(
                     worst = max(
                         (s.result.pct50 for s in result.sims), default=1.0
                     )
-                    pen = BenchResult.from_times([2.0 * worst])
                     if cp.rank() == 0:
-                        with counters.phase("BACKPROP"):
-                            endpoint.backprop(ctx, pen)
+                        settle(BenchResult.from_times([2.0 * worst]))
                     continue
                 fidelity = ("screen" if opts.screen_opts is not None
                             else "full")
@@ -467,10 +524,9 @@ def explore(
                     order=order, result=res, fidelity=fidelity,
                 ))
                 if cp.rank() == 0:
-                    with counters.phase("BACKPROP"):
-                        endpoint.backprop(ctx, res)
+                    settle(res)
                     if tr.enabled:
-                        it_sp.set("tree_size", root.size())
+                        it_sp.set("tree_size", measured_size())
                     if opts.dump_tree and _dump_cadence(it):
                         path = f"{opts.dump_tree_prefix}_{it:06d}.dot"
                         with open(path, "w") as f:
@@ -483,7 +539,7 @@ def explore(
                         # checkpoint only needs the generative cursor
                         opts.checkpoint.save_state(
                             mcts={"it": it, "n_sims": len(result.sims),
-                                  "tree_size": root.size()})
+                                  "tree_size": measured_size()})
         # multi-fidelity confirm: the top-k distinct screened schedules are
         # re-measured at the full bench_opts floor so the solver's official
         # output carries final-fidelity numbers (the CachingBenchmarker key
@@ -540,12 +596,21 @@ def explore(
                         continue
                 result.sims.append(
                     SimResult(order=order, result=res, fidelity="full"))
-        if cp.rank() == 0 and root is not None:
-            result.tree_size = root.size()
         if opts.dump_csv_path and cp.rank() == 0:
             result.dump_csv(opts.dump_csv_path)
         return result
     finally:
+        # whatever ended the loop (the iterations, a closed tree, the
+        # harness's deadline or any other exception out of a measurement):
+        # rollouts still queued were never measured, so their pending visits
+        # and their nodes go, newest first, and the tree that is counted
+        # describes measured rollouts alone
+        reg.counter("mcts.lookahead.dropped").inc(len(queue))
+        while queue:
+            dropped = queue.pop()
+            dropped.endpoint.take_back(dropped.grew)
+        if root is not None:
+            result.tree_size = root.size()
         explore_sp.set("n_sims", len(result.sims))
         explore_sp.set("tree_size", result.tree_size)
         explore_ctx.__exit__(None, None, None)

@@ -460,15 +460,18 @@ def test_chaos_with_prefetch_matches_prefetch_off(tmp_path, tracer,
                                                   registry, corpus):
     """ISSUE 5 chaos acceptance: seeded fault injection with the async
     compile pipeline enabled must (a) produce bit-identical search results
-    to prefetch-off, (b) classify background compile errors through the
-    fault taxonomy and quarantine deterministic ones exactly once, and
-    (c) leak no pipeline threads."""
+    to prefetch-off (for the tree search, which draws ahead by the
+    prefetcher's ``workers``: to the same search against a prefetcher of
+    that width whose executor compiles nothing, so that every compile
+    failure is the foreground's), (b) classify background compile errors
+    through the fault taxonomy and quarantine deterministic ones exactly
+    once, and (c) leak no pipeline threads."""
     import threading
 
     from tenzing_tpu.bench.benchmarker import schedule_id
     from tenzing_tpu.bench.pipeline import PrefetchingBenchmarker
 
-    from tests.test_pipeline_bench import FakeExecutor
+    from tests.test_pipeline_bench import FakeExecutor, NullExecutor
 
     rows, terminals = corpus
     plat = Platform.make_n_lanes(2)
@@ -499,24 +502,28 @@ def test_chaos_with_prefetch_matches_prefetch_off(tmp_path, tracer,
                     f"failed to compile (chaos {schedule_id(order)})")
             return self.inner.benchmark(order, opts)
 
-    def run(qdir, prefetcher):
+    def run_off():
         inject = FaultInjectingBenchmarker(mk_db(rows), CHAOS_SPECS,
                                            hang_secs=2.5)
         counting = CountingInner(CompileGate(inject))
-        quar = Quarantine(str(tmp_path / qdir / "quarantine.json"))
-        resilient = ResilientBenchmarker(
-            prefetcher if prefetcher is not None else counting,
-            timeout_secs=1.0, policy=_fast_policy(), quarantine=quar,
-            sleep=lambda s: None)
-        bench = CachingBenchmarker(resilient)
-        mcts = explore(_graph(), plat, bench,
-                       MctsOpts(n_iters=30, seed=3,
-                                prefetch=prefetcher))
-        dfs = dfs_explore(_graph(), plat, bench,
-                          DfsOpts(max_seqs=10_000, prefetch=prefetcher))
+        quar = Quarantine(str(tmp_path / "off" / "quarantine.json"))
+        # the tree search's "off" is the lookahead's width and no compiles;
+        # DFS's is no hints at all (unhinted, the layer passes through)
+        null = PrefetchingBenchmarker(counting, executor=NullExecutor(),
+                                      workers=2)
+        try:
+            bench = CachingBenchmarker(ResilientBenchmarker(
+                null, timeout_secs=1.0, policy=_fast_policy(),
+                quarantine=quar, sleep=lambda s: None))
+            mcts = explore(_graph(), plat, bench,
+                           MctsOpts(n_iters=30, seed=3, prefetch=null))
+            dfs = dfs_explore(_graph(), plat, bench,
+                              DfsOpts(max_seqs=10_000))
+        finally:
+            null.close()
         return mcts, dfs, counting, quar
 
-    off_mcts, off_dfs, off_count, off_quar = run("off", None)
+    off_mcts, off_dfs, off_count, off_quar = run_off()
 
     ex = FakeExecutor(fail=lambda o: RuntimeError(
         f"failed to compile (chaos {schedule_id(o)})")
