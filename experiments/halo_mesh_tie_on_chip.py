@@ -28,8 +28,8 @@ engine-overlap schedules (``engine_overlap_order``, ``xla`` and ``rdma``):
   and every operation inside its ``while`` body whose result is a shard's
   whole grid (``obs/attrib/hlo.py``);
 * the program's counters ``executor.index_ties``,
-  ``executor.value_tied_bytes`` and ``halo.window_unpacks`` for one traced
-  body;
+  ``executor.value_tied_bytes``, ``halo.window_unpacks`` and
+  ``halo.window_packs`` for one traced body;
 * a profiled dispatch: the first device's milliseconds an iteration by
   operation, and of them the loop's writes into the grid by the face each
   writes (``writes_ms``: x, y, z; a ``dynamic-update-slice`` or the window
@@ -42,6 +42,14 @@ schedule's repeat-n program at N cells a shard for the attached chips from
 shapes alone and reports its temporaries (what decides whether the source's
 512^3 fits).  One process; not part of a benchmark run.  Writes
 ``chiprun_out/halo_mesh_tie[.<label>].json``.
+
+ISSUE 44's step 1, one chip (``--pack``): how a thin face leaves a shard's
+``(3, 454, 454, 454)`` grid, each form alone in a ``fori_loop`` whose next
+start waits for the face before it (:func:`pack_section`):
+
+    chiprun -- python experiments/halo_mesh_tie_on_chip.py --pack
+
+Its readings are kept in ``experiments/halo_mesh_pack_step0.json``.
 """
 
 import argparse
@@ -120,6 +128,132 @@ def device_ms_by_op(run_n, n: int, top: int = 24) -> list:
     return [[k, v / 1e6 / n] for k, v in ranked[:top]]
 
 
+def _window_padded(u, starts, sizes, tok_zero):
+    """ISSUE 44's first form of the window pack, kept here for its reading
+    alone: the face leaves the kernel as ``(nq, sx, sy, sz)``, which the
+    default layout pads 3 -> 128 lanes when z is the thin axis."""
+    import jax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from tenzing_tpu.ops.halo_pallas import _shell_block
+
+    nq, sx, sy, sz = sizes
+    _, x0, y0, z0 = starts
+    _, _, Y, Z = u.shape
+    WH, by, yl = _shell_block(y0, sy, Y, 8)
+    WW, bz, zl = _shell_block(z0, sz, Z, 128)
+
+    def kernel(tok_ref, u_ref, f_ref):
+        f_ref[...] = u_ref[:, yl:yl + sy, zl:zl + sz]
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(sx,),
+            in_specs=[pl.BlockSpec(
+                (nq, None, WH, WW),
+                lambda i, t: (0, x0 + i + t[0], by, bz))],
+            out_specs=pl.BlockSpec((nq, None, sy, sz),
+                                   lambda i, t: (0, i, 0, 0))),
+        out_shape=jax.ShapeDtypeStruct((nq, sx, sy, sz), u.dtype),
+        name="halo_window_pack_padded",
+    )(tok_zero.reshape(1), u)
+
+
+def pack_section(cells: int, seed: int, rehearse: bool) -> dict:
+    """ISSUE 44, step 1: a thin face's pack alone, ms a face.
+
+    Forms, each as the next thing the program does with the face would see
+    it (a value tie, then XLA's choice of layout for a loop carry):
+    ``slice`` = ``Pack``'s ``dynamic_slice`` at the token's zero, ``padded``
+    = :func:`_window_padded`, ``window`` = ``ops/halo_pallas.py``
+    ``pack_face_window``; ``+tie`` adds the executor's value tie onto the
+    face, as ``PermuteStart`` and ``RdmaShiftStart`` take their token.  By
+    the slope of a ``fori_loop`` between ``n`` and ``5n`` repeats, the
+    token's zero of a repeat drawn from the face before it, and by one
+    profiled dispatch (the first device's ms a repeat by operation).  Each
+    form's face is compared with ``lax.dynamic_slice`` to the bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tenzing_tpu.models.halo import (
+        DIRECTIONS,
+        HaloArgs,
+        _face_axis,
+        _face_slices,
+        dir_name,
+    )
+    from tenzing_tpu.ops.halo_pallas import _interpret, pack_face_window
+    from tenzing_tpu.runtime.executor import datatie
+
+    hargs = HaloArgs(nq=3, lx=cells, ly=cells, lz=cells, radius=3)
+    u = jax.random.uniform(jax.random.PRNGKey(seed % (2**31)),
+                           hargs.local_shape(), jnp.float32)
+    n0 = 2 if rehearse else 8
+    out = {"cells_per_shard": cells, "n": [n0, 5 * n0], "faces": {}}
+
+    def forms(d):
+        starts, sizes = _face_slices(hargs, d, "pack")
+        axis = _face_axis(d)
+
+        def slice_(u, z):
+            at = tuple(s + z if i == axis else s
+                       for i, s in enumerate(starts))
+            return jax.lax.dynamic_slice(u, at, sizes)
+
+        def padded(u, z):
+            return _window_padded(u, tuple(starts), tuple(sizes), z)
+
+        def window(u, z):
+            return pack_face_window(u, tuple(starts), tuple(sizes), z,
+                                    interpret=_interpret())
+
+        def tied(f):
+            return lambda u, z: datatie(f(u, z), z.astype(jnp.float32))
+
+        made = {"slice+tie": tied(slice_), "window": window,
+                "window+tie": tied(window)}
+        if not rehearse:  # the interpreter is slow, and this form is A/B only
+            made.update({"padded": padded, "padded+tie": tied(padded)})
+        return made, jax.lax.dynamic_slice(u, starts, sizes)
+
+    for d in [d for d in DIRECTIONS if d[0] == 0]:
+        made, want = forms(d)
+        row = out["faces"][dir_name(d)] = {}
+        for label, f in made.items():
+            def loop(u, n, f=f):
+                def body(_, face):
+                    x = face[0, 0, 0, 0]
+                    z = jnp.where(x != x, 1, 0).astype(jnp.int32)
+                    return f(u, z)
+
+                return jax.lax.fori_loop(0, n, body, jnp.zeros_like(want))
+
+            run = jax.jit(loop)
+            face = jax.block_until_ready(run(u, jnp.int32(1)))
+            same = bool(jnp.array_equal(face, want))
+
+            def timed(n):
+                best = float("inf")
+                for _ in range(3):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(run(u, jnp.int32(n)))
+                    best = min(best, time.perf_counter() - t0)
+                return best
+
+            slope = (timed(5 * n0) - timed(n0)) / (4 * n0) * 1e3
+            by_op = device_ms_by_op(
+                lambda n: jax.block_until_ready(run(u, jnp.int32(n))),
+                5 * n0, top=6)
+            row[label] = {"ms": slope, "bit_equal": same,
+                          "device_ms_by_op": by_op}
+            print(f"pack {dir_name(d)} {label}: {json.dumps(row[label])}",
+                  flush=True)
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="halo512-mesh4.mcts")
@@ -133,10 +267,23 @@ def main() -> int:
                     help="the checkout whose program is measured")
     ap.add_argument("--label", default=None)
     ap.add_argument("--compare", nargs=2, metavar=("PARENT", "CHANGE"))
+    ap.add_argument("--pack", action="store_true",
+                    help="ISSUE 44's step 1 alone: the pack forms, one chip")
     args = ap.parse_args()
     if args.compare:
         return compare(*args.compare)
     sys.path.insert(0, os.path.abspath(args.root))
+    if args.pack:
+        report = pack_section(args.cells or (16 if args.rehearse_cpu else 448),
+                              args.seed, args.rehearse_cpu)
+        os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(HERE, "chiprun_out",
+                               "halo_mesh_pack.json"), "w") as f:
+            json.dump(report, f, indent=1)
+        bad = [f"{d}/{k}" for d, row in report["faces"].items()
+               for k, r in row.items() if not r["bit_equal"]]
+        print(json.dumps({"not_bit_equal": bad}))
+        return 1 if bad else 0
     import jax
     import jax.numpy as jnp
 
@@ -180,7 +327,7 @@ def main() -> int:
     def counters():
         return tuple(reg.counter(name).value for name in (
             "executor.index_ties", "executor.value_tied_bytes",
-            "halo.window_unpacks"))
+            "halo.window_unpacks", "halo.window_packs"))
 
     for label in [s for s in args.schedules.split(",") if s]:
         order = orders[label]
@@ -192,7 +339,7 @@ def main() -> int:
         mem = compiled.memory_analysis()
         row = report["schedules"][label] = {
             "index_ties": ties[0], "value_tied_bytes": ties[1],
-            "window_unpacks": ties[2],
+            "window_unpacks": ties[2], "window_packs": ties[3],
             "temp_gb": mem.temp_size_in_bytes / 1e9,
             "argument_gb": mem.argument_size_in_bytes / 1e9,
             "grid_ops_in_loop": grid_ops(compiled, local)}

@@ -13,11 +13,11 @@ mesh ``("x", "y", "z")``; per direction the DAG is
 Pack(slice of the interior edge) -> post (host-posted transfer along the
 face's mesh axis, periodic: ``PermuteStart`` ICI collective-permute or
 ``RdmaShiftStart`` per-neighbor remote DMA) -> AwaitTransfer (the reference's
-Wait) -> Unpack(the ghost-shell write).  The packs and the x faces' unpacks
-are XLA slice ops (contiguous copies the compiler fuses; the reference needs
-hand-written CUDA kernels for exactly this); the y and z faces' unpacks, thin
-along the grid's sublane and lane axes, are an aliased window kernel
-(:class:`Unpack`).  The six directions are
+Wait) -> Unpack(the ghost-shell write).  The x faces' packs and unpacks are
+XLA slice ops (contiguous copies the compiler fuses; the reference needs
+hand-written CUDA kernels for exactly this); the y and z faces, thin along
+the grid's sublane and lane axes, leave and enter the grid through a pair of
+window kernels (:class:`Pack`, :class:`Unpack`).  The six directions are
 independent in the graph and the post and wait are separate vertices, so the
 solver searches how exchanges overlap each other and how much work hides
 between each post and its wait — the reference's post-all-before-wait-any
@@ -119,14 +119,43 @@ def _face_slices(args: HaloArgs, d: Tuple[int, int, int], which: str):
     return starts, sizes
 
 
+def _index_zero(op, ctx):
+    """``ctx.tok_index_zero`` for an INDEX_TIE op.  It MUST come from the
+    executor contract: a missing/None value means the op would trace with
+    no ordering edge at all, so fail loudly."""
+    z = ctx.tok_index_zero
+    if z is None:
+        raise RuntimeError(
+            f"{op.desc()}: INDEX_TIE op traced without tok_index_zero "
+            "(executor contract violated — the op would have no "
+            "happens-before edge)"
+        )
+    return z
+
+
 class Pack(DeviceOp):
-    """Slice the interior edge for one direction (reference Pack,
+    """Cut the interior edge for one direction (reference Pack,
     ops_halo_exchange.hpp:97-141, kernels ops_halo_exchange.cu:519-573).
 
-    INDEX_TIE: the op takes its ordering token into the slice's START index
-    (``ctx.tok_index_zero``, an int32 zero that depends on the token), not
-    as a value-preserving add on its read.  The happens-before is the same
-    (the slice cannot start before the token) and the face bit-identical,
+    Which read depends on what the op can see, the face's thin axis in the
+    grid ``(q, x, y, z)``, as for :class:`Unpack`:
+
+    * x (axis 1, a leading dimension): ``lax.dynamic_slice``, whole tiles,
+      which XLA fuses into the exchange that reads the face (0.03-0.05 ms);
+    * y or z (axis 2 or 3, the grid's sublane or lane axis): the window
+      kernel ``ops/halo_pallas.py`` ``pack_face_window``, which says why
+      (XLA's strided z slices were 4.1 of the mesh cell's 9.44 ms, and its
+      fused y slices the reason the ``xla`` overlap program relayouted the
+      whole grid) and why a z face leaves it transposed.  The op then
+      counts as a Pallas op (``uses_pallas``), and the program's counter
+      ``halo.window_packs`` says, per traced body, how many faces went this
+      way.
+
+    INDEX_TIE, either way: the op takes its ordering token into the read's
+    START index (``ctx.tok_index_zero``, an int32 zero that depends on the
+    token; for the kernel a scalar-prefetch operand on its x block index),
+    not as a value-preserving add on its read.  The happens-before is the
+    same (the read cannot start before the token) and the face bit-identical,
     but the read is the whole grid and six packs read it: a value-add makes
     six live versions of the grid, each a full pass over it.  Measured on a
     v5e: the one-chip flagship (2.07 GB grid) paid 21 ms an iteration in
@@ -134,13 +163,19 @@ class Pack(DeviceOp):
     longer be done in place; the mesh cell ``halo512-mesh4.mcts`` (1.27 GB a
     shard), value-tied until PR 29, read 36.5 ms an iteration and 10.2 GB of
     temporaries for its overlap schedule, 20.8 ms and 5.1 GB since (its
-    updates were in place before and after: PERF.md, PR 29).  The zero
-    goes onto the DIRECTION axis, where ``start < dim - size`` keeps the
-    dynamic-slice clamp non-degenerate: on a full-extent axis the clamp is
-    provably 0 and XLA folds the edge away (probed: the compiled program had
-    static slices and no token edge).  Subclasses that need static starts
-    (the Pallas kernels of ops/halo_pallas.py) set ``INDEX_TIE = False`` and
-    get the executor's value-tied read."""
+    updates were in place before and after: PERF.md, PR 29).  In the slice
+    the zero goes onto the DIRECTION axis, where ``start < dim - size``
+    keeps the dynamic-slice clamp non-degenerate: on a full-extent axis the
+    clamp is provably 0 and XLA folds the edge away (probed: the compiled
+    program had static slices and no token edge).
+
+    ``halo_pipeline.PackFlat`` (the one-chip twin: a tile-padded grid and a
+    dense flat staging buffer for a host round trip, where XLA's slice
+    already reads 0.55 ms a z face) keeps :meth:`_xla_slice` for every
+    face; the two needs conflict and the class tells them apart.  Its
+    subclasses that need static starts (the Pallas menu of
+    ops/halo_pallas.py) set ``INDEX_TIE = False`` and get the executor's
+    value-tied read."""
 
     INDEX_TIE = True
 
@@ -154,25 +189,35 @@ class Pack(DeviceOp):
     def writes(self):
         return [f"buf_{dir_name(self._d)}"]
 
-    def apply(self, bufs, ctx):
+    def uses_pallas(self) -> bool:
+        return _face_axis(self._d) >= 2
+
+    def _xla_slice(self, bufs, ctx):
+        """The face as one ``lax.dynamic_slice``, the token's zero on the
+        direction axis' start."""
         import jax.lax as lax
 
         starts, sizes = _face_slices(self._args, self._d, "pack")
-        # MUST come from the executor contract — a missing/None value means
-        # the op would trace with no ordering edge at all, so fail loudly
-        z = ctx.tok_index_zero
-        if z is None:
-            raise RuntimeError(
-                f"{self.desc()}: INDEX_TIE op traced without tok_index_zero "
-                "(executor contract violated — the pack would have no "
-                "happens-before edge)"
-            )
+        z = _index_zero(self, ctx)
         axis = _face_axis(self._d)
         starts = tuple(
             s + z if i == axis else s for i, s in enumerate(starts)
         )
-        sl = lax.dynamic_slice(bufs["U"], starts, sizes)
-        return {f"buf_{dir_name(self._d)}": sl}
+        return lax.dynamic_slice(bufs["U"], starts, sizes)
+
+    def apply(self, bufs, ctx):
+        name = f"buf_{dir_name(self._d)}"
+        if not self.uses_pallas():
+            return {name: self._xla_slice(bufs, ctx)}
+        from tenzing_tpu.obs.metrics import get_metrics
+        from tenzing_tpu.ops.halo_pallas import _interpret, pack_face_window
+
+        starts, sizes = _face_slices(self._args, self._d, "pack")
+        z = _index_zero(self, ctx)
+        get_metrics().counter("halo.window_packs").inc()
+        return {name: pack_face_window(
+            bufs["U"], tuple(starts), tuple(sizes), z,
+            interpret=_interpret())}
 
 
 def _dir_axis_sign(d: Tuple[int, int, int]) -> Tuple[str, int]:
@@ -326,13 +371,7 @@ class Unpack(DeviceOp):
         from tenzing_tpu.obs.metrics import get_metrics
         from tenzing_tpu.ops.halo_pallas import _interpret, unpack_face_window
 
-        z = ctx.tok_index_zero
-        if z is None:  # as Pack: no zero, no happens-before edge
-            raise RuntimeError(
-                f"{self.desc()}: INDEX_TIE op traced without tok_index_zero "
-                "(executor contract violated — the unpack would have no "
-                "happens-before edge)"
-            )
+        z = _index_zero(self, ctx)
         get_metrics().counter("halo.window_unpacks").inc()
         return {"U": unpack_face_window(
             bufs["U"], face, tuple(starts), z, interpret=_interpret())}

@@ -95,11 +95,18 @@ class PackFlat(Pack):
     reliable for — which is also what the reference does with its staging
     buffers (contiguous pack buffers, ops_halo_exchange.hpp:97-186).
     The slice itself, and how it takes its ordering token (INDEX_TIE), are
-    the base class's: see :class:`tenzing_tpu.models.halo.Pack`."""
+    the base class's ``_xla_slice``, for every face: the mesh halo's window
+    kernel for y and z faces (``Pack.apply``) writes a padded 4-D face for a
+    collective on an unpadded grid, this class a dense flat buffer for a
+    host round trip from a tile-padded one, where XLA's slice is fast.  See
+    :class:`tenzing_tpu.models.halo.Pack`."""
+
+    def uses_pallas(self) -> bool:
+        return False
 
     def apply(self, bufs, ctx):
-        ((name, face),) = super().apply(bufs, ctx).items()
-        return {name: flatten_face(face, face.shape)}
+        face = self._xla_slice(bufs, ctx)
+        return {f"buf_{dir_name(self._d)}": flatten_face(face, face.shape)}
 
 
 class UnpackRecv(Unpack):

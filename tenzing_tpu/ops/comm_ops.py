@@ -76,6 +76,10 @@ class CommStart(CpuOp):
     """
 
     DST_SPACE = "device"
+    # True: the op takes the post point's token by index, a kernel operand
+    # (``ctx.tok_index_zero``: ops/rdma.py RdmaShiftStart), and its reads
+    # get no value-preserving add
+    INDEX_TIE = False
 
     def __init__(self, name: str, src: str, dst: str):
         super().__init__(name)
@@ -99,10 +103,13 @@ class CommStart(CpuOp):
 
     def trace(self, tc) -> None:
         view = dict(tc.bufs)
-        for name in self.reads():
-            # host-space reads skip the tie inside tie_named; their post
-            # ordering then rests on the destination-side tie below
-            view[name] = tc.tie_named(name, view[name], tc._host_tok)
+        if self.INDEX_TIE:
+            tc.tie_by_index(tc._host_tok)
+        else:
+            for name in self.reads():
+                # host-space reads skip the tie inside tie_named; their post
+                # ordering then rests on the destination-side tie below
+                view[name] = tc.tie_named(name, view[name], tc._host_tok)
         out = self.apply(view, tc)
         for name, val in out.items():
             if name not in tc.bufs:

@@ -38,9 +38,13 @@ the one-chip flagship's ``(3, 518, 520, 640)``).  The mesh exchange
 own ``(3, 454, 454, 454)``, where Mosaic refuses every manual window; its
 ``Unpack`` writes the y and z ghost shells with a fifth kernel,
 :func:`unpack_face_window`, which pipelines by ``BlockSpec`` and takes its
-ordering token as a scalar-prefetch operand.  That function's docstring is
-the one account of both (the unpadded-grid window and the scalar-prefetch
-tie); it is no menu entry: ``Unpack`` picks it by the face's thin axis.
+ordering token as a scalar-prefetch operand, and its ``Pack`` reads the y
+and z edges with that kernel's mirror, :func:`pack_face_window`.
+``unpack_face_window``'s docstring is the one account of the unpadded-grid
+window and the scalar-prefetch tie, ``pack_face_window``'s of why a
+lane-thin face leaves its kernel transposed.  Neither is a menu entry:
+``Unpack`` and ``Pack`` pick them by the face's thin axis, and the one-chip
+menu's ``PackFlat`` keeps XLA's slice.
 
 Off-TPU the kernels run in the Pallas interpreter (``interpret=True``), same
 code path as the repo's other Pallas kernels.
@@ -515,7 +519,7 @@ def unpack_face_flat_pallas(
     )(u, flat)
 
 
-# -- the window write on an unpadded grid (models/halo.py ``Unpack``) ----------
+# -- the window write and read on an unpadded grid (models/halo.py) ------------
 
 
 def _shell_block(a0: int, n: int, extent: int, tile: int) -> Tuple[int, int, int]:
@@ -597,6 +601,69 @@ def unpack_face_window(
         name="halo_window_unpack",
         interpret=interpret,
     )(tok_zero.reshape(1), u, face)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("starts", "sizes", "interpret")
+)
+def pack_face_window(
+    u: jax.Array, starts: Tuple[int, ...], sizes: Tuple[int, ...],
+    tok_zero: jax.Array, interpret: bool = False
+) -> jax.Array:
+    """face[:, i] = u[:, x0+i, y0:y0+sy, z0:z0+sz] on a grid that is NOT
+    tile-padded: the mirror of :func:`unpack_face_window`, built from the
+    same parts (``_shell_block``'s block of each thin axis, one x row and
+    every q a grid step, the token's zero by scalar prefetch on the x block
+    index; the grid is only read, so no alias).
+
+    Why a kernel: XLA has no instruction for a thin slice.  It fuses the
+    strided read into whatever consumes the face, the exchange's value tie
+    (1.8-2.3 ms a z face at 448^3 a shard, 3.35 for the pair: PERF.md
+    section 5, PR 38), and where several packs fuse it relayouts the whole
+    1.27 GB grid to feed them.  What a pack has to read is the tile column
+    (z) or tile row (y) that holds the edge; a Pallas consumer also pins
+    the grid's default layout.
+
+    A lane-thin (z) face leaves the kernel TRANSPOSED, ``(nq, sx, sz,
+    sy)``, and is handed on through ``swapaxes``: in the default layout a
+    ``(nq, sx, sy, 3)`` float32 face is padded 3 -> 128 lanes (308 MB for
+    7 MB of cells), and every pass over it (the write, the exchange's value
+    tie, the relayout to the collective-permute's thin-major layout) costs
+    0.4-0.8 ms.  The transposed face is 11 MB, the ``swapaxes`` a bitcast
+    to a layout of XLA's choosing, and tie and relayout run on that.  The
+    body turns each q's ``(sy, 128)`` block on the XLU and keeps the ``sz``
+    rows that are the edge."""
+    nq, sx, sy, sz = sizes
+    _, x0, y0, z0 = starts
+    _, _, Y, Z = u.shape
+    WH, by, yl = _shell_block(y0, sy, Y, sublane_tile(u.dtype.itemsize))
+    WW, bz, zl = _shell_block(z0, sz, Z, 128)
+    lane_thin = sz < sy
+
+    def kernel(tok_ref, u_ref, f_ref):
+        if lane_thin:
+            for q in range(nq):
+                f_ref[q] = u_ref[q, yl : yl + sy, :].T[zl : zl + sz, :]
+        else:
+            f_ref[...] = u_ref[:, yl : yl + sy, zl : zl + sz]
+
+    row = (sz, sy) if lane_thin else (sy, sz)
+    face = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(sx,),
+            in_specs=[pl.BlockSpec(
+                (nq, None, WH, WW),
+                lambda i, tok_ref: (0, x0 + i + tok_ref[0], by, bz))],
+            out_specs=pl.BlockSpec(
+                (nq, None) + row, lambda i, tok_ref: (0, i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((nq, sx) + row, u.dtype),
+        name="halo_window_pack",
+        interpret=interpret,
+    )(tok_zero.reshape(1), u)
+    return jnp.swapaxes(face, 2, 3) if lane_thin else face
 
 
 # -- ops + choice menu ------------------------------------------------------------

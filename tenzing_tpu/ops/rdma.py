@@ -158,9 +158,12 @@ def rdma_copy_fused_local(x: jax.Array, interpret: Optional[bool] = None) -> jax
 # -- split start/wait (TPU hardware): semaphores as kernel outputs ----------
 
 
-def _shift_post_kernel(axes, axis, shift, x_ref, send_ref, recv_ref, y_ref):
+def _shift_post_kernel(axes, axis, shift, tok_ref, x_ref, send_ref, recv_ref,
+                       y_ref):
     """Post half of the mesh neighbor shift: neighbor barrier, then
-    ``rdma.start()`` — returns with the DMA in flight (MPI_Isend)."""
+    ``rdma.start()`` — returns with the DMA in flight (MPI_Isend).
+    ``tok_ref`` is the ordering token's zero: an operand the call waits for
+    and the body never reads."""
     fwd, bwd, id_type, n = _mesh_ids(axes, axis, shift)
     if n > 1:
         barrier = pltpu.get_barrier_semaphore()
@@ -191,11 +194,21 @@ def rdma_shift_post(
     axis: Optional[str],
     shift: int,
     collective_id: int = 0,
+    tok_zero: Optional[jax.Array] = None,
 ):
     """Post the mesh neighbor shift; returns (send_sem, recv_sem, y) with the
     remote DMA in flight — the MPI_Isend half of the reference's split
     (ops_mpi.hpp:17-146).  TPU only: the interpreter cannot materialize
-    semaphore outputs (probed on v5e; see module docstring)."""
+    semaphore outputs (probed on v5e; see module docstring).
+
+    ``tok_zero`` (``ctx.tok_index_zero``) is the ordering token by index,
+    as ``ops/halo_pallas.py`` ``unpack_face_window`` takes it: a scalar
+    operand in SMEM that the kernel call cannot start without, so ``x``
+    gets no value-preserving add.  ``x`` is a Pallas operand in the padded
+    default layout, and on a lane-thin halo face that add was a pass of its
+    own over 0.6 GB (0.92 ms a z face at 448^3 a shard: PERF.md, PR 44)."""
+    if tok_zero is None:
+        tok_zero = jnp.zeros((), jnp.int32)
     kern = functools.partial(_shift_post_kernel, tuple(axes), axis, shift)
     needs_barrier = axis is not None and axes and jax.lax.axis_size(axis) > 1
     params = (
@@ -205,7 +218,8 @@ def rdma_shift_post(
     )
     return pl.pallas_call(
         kern,
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=(
             pl.BlockSpec(memory_space=pltpu.SEMAPHORE),
             pl.BlockSpec(memory_space=pltpu.SEMAPHORE),
@@ -218,7 +232,7 @@ def rdma_shift_post(
         ),
         compiler_params=params,
         name="rdma_shift_post",
-    )(x)
+    )(tok_zero.reshape(1), x)
 
 
 def rdma_shift_wait(
@@ -305,6 +319,9 @@ class RdmaShiftStart(CommStart):
     unsupported, so the op degrades to the fused start+wait kernel and the
     await falls back to the ordinary data dependency."""
 
+    # the post takes its token by index (``rdma_shift_post``'s ``tok_zero``)
+    INDEX_TIE = True
+
     def __init__(self, name: str, src: str, dst: str, axis: str,
                  shift: int = 1, collective_id: int = 0):
         super().__init__(name, src, dst)
@@ -316,14 +333,24 @@ class RdmaShiftStart(CommStart):
         axes = tuple(getattr(ctx, "axis_names", ()) or ())
         x = bufs[self._src]
         axis = self._axis if axes else None
+        z = ctx.tok_index_zero
+        if z is None:  # as models/halo.py Pack: no zero, no ordering edge
+            raise RuntimeError(
+                f"{self.desc()}: INDEX_TIE op traced without tok_index_zero "
+                "(executor contract violated — the post would have no "
+                "happens-before edge)"
+            )
         if _interpret():
+            # the interpreter drops an operand its body never reads: there
+            # the token stays a value-preserving add on the source
             return {
                 self._dst: rdma_shift_fused(
-                    x, axes, axis, self._shift, collective_id=self._cid,
+                    x + z.astype(x.dtype), axes, axis, self._shift,
+                    collective_id=self._cid,
                 )
             }
         send, recv, y = rdma_shift_post(
-            x, axes, axis, self._shift, collective_id=self._cid
+            x, axes, axis, self._shift, collective_id=self._cid, tok_zero=z
         )
         inflight = getattr(ctx, "inflight", None)
         if inflight is not None:

@@ -240,6 +240,14 @@ class TraceContext:
             return value
         return self._tie(value, tok)
 
+    def tie_by_index(self, tok) -> None:
+        """Hand the op about to be applied its token as
+        ``tok_index_zero``, an int32 zero that depends on ``tok``, and
+        count it (``executor.index_ties``)."""
+        with jax.named_scope("tie"):
+            self.tok_index_zero = jnp.where(tok != tok, 1, 0).astype(jnp.int32)
+        get_metrics().counter("executor.index_ties").inc()
+
     def trace_op(self, op) -> None:
         """``op.trace(self)`` inside the vertex's scope: whatever the op
         puts on the device, through :meth:`_apply_op` or a ``trace`` of its
@@ -322,10 +330,7 @@ class TraceContext:
         view = self.bufs
         reg = get_metrics()
         if getattr(unbound(op), "INDEX_TIE", False):
-            with jax.named_scope("tie"):
-                self.tok_index_zero = jnp.where(
-                    tok_in != tok_in, 1, 0).astype(jnp.int32)
-            reg.counter("executor.index_ties").inc()
+            self.tie_by_index(tok_in)
         else:
             self.tok_index_zero = None  # stale-consumption guard
             reads = [n for n in op.reads() if n not in self.host_space]

@@ -22,7 +22,8 @@ Covered, at the shapes ``python bench.py`` builds without ``--smoke``:
   pinned_host staging buffers and the 2 GB grid carried through ``fori_loop``;
 * the models/halo.py mesh exchange on the four described chips, both
   transfer engines (XLA collective-permute, remote DMA with barriers), and
-  its window unpack on the cell's unpadded ``(3, 454, 454, 454)`` shard.
+  its window unpack and window pack on the cell's unpadded ``(3, 454, 454,
+  454)`` shard.
 
 During such a compile ``jax.default_backend()`` is still ``cpu``, so the
 kernels' ``_interpret()`` would pick the interpreter: the tests (never an
@@ -1124,6 +1125,26 @@ def test_window_unpack_on_the_unpadded_grid(one_chip, d):
 
 
 @pytest.mark.parametrize("d", THIN, ids=[dir_name(d) for d in THIN])
+def test_window_pack_on_the_unpadded_grid(one_chip, d):
+    """``pack_face_window`` on the same shard: Mosaic takes the read-only
+    blocks and, for a z face, the turn of each ``(448, 128)`` block on the
+    XLU.  A z face leaves the kernel as ``(3, 448, 3, 448)``, 4 sublanes
+    for its 3 and not 128 lanes, and reaches the builder's shape by a
+    bitcast: nothing of the padded ``f32[3,448,448,3]{3,2,1,0}`` is made."""
+    from tenzing_tpu.ops.halo_pallas import pack_face_window
+
+    starts, sizes = _face_slices(MESH_CELL, d, "pack")
+    compiled = jax.jit(
+        lambda u, z: pack_face_window(u, tuple(starts), tuple(sizes), z)
+    ).lower(_sds(MESH_CELL.local_shape(), jnp.float32, one_chip),
+            _sds((), jnp.int32, one_chip)).compile()
+    _assert_kernel(compiled)
+    text = compiled.as_text()
+    assert "f32[3,448,3,448]{3,2,1,0:T(4,128)" in text
+    assert "f32[3,448,448,3]{3,2,1,0" not in text
+
+
+@pytest.mark.parametrize("d", THIN, ids=[dir_name(d) for d in THIN])
 def test_manual_window_dma_is_refused_on_the_unpadded_grid(one_chip, d):
     """... and refuses the one-chip twin's kernel there: every slice of a
     manual DMA window, a whole axis included, has to be a multiple of the
@@ -1139,11 +1160,13 @@ def test_manual_window_dma_is_refused_on_the_unpadded_grid(one_chip, d):
                 _sds(sizes, jnp.float32, one_chip)).compile()
 
 
-# the parent's (89ae733) repeat-n programs at 448^3, compiled for the same
-# described chips before the edit: whole-grid ``copy`` operations in the
-# ``while`` body, and temporaries a chip in bytes
-PARENT_LOOP = {"naive": (1, 3_131_737_088), "xla": (2, 5_091_660_800),
-               "rdma": (0, 3_219_259_392)}
+# the repeat-n programs at 448^3 as PR 44 left them, compiled for the same
+# described chips: whole-grid ``copy`` operations in the ``while`` body, and
+# temporaries a chip in bytes (its parent, 74bc99f: naive 0 and 2_596_547_584,
+# xla 2 and 5_091_209_216, rdma 0 and 3_203_034_624; the remote-DMA posts took
+# their tokens by value there, an add on each face they send)
+LOOP_AT_PR44 = {"naive": (0, 2_002_004_992), "xla": (0, 2_002_940_416),
+                "rdma": (0, 2_585_990_144)}
 
 
 def _mesh_halo_loop(topo, which):
@@ -1175,12 +1198,16 @@ def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
     onto a shard's whole grid (up to PR 28 the six packs' ordering tokens
     did, a pass over 1.27 GB each).  Since PR 32 the only
     ``dynamic-update-slice`` of the grid are the two x faces': the y and z
-    shells are written by four ``halo_window_unpack`` kernels, each handed
-    its token's zero as an operand that is not a constant.  The body copies
-    the whole grid no more often than the parent's program of the same
-    schedule did (layout assignment's relayouts for the thin y and z
-    *packs*, which are the compiler's and no ordering edge: PERF.md, PR 29),
-    and the temporaries are not above the parent's."""
+    shells are written by four ``halo_window_unpack`` kernels.  Since PR 44
+    the only ``dynamic-slice`` of it are the two x faces' too: the y and z
+    edges are read by four ``halo_window_pack`` kernels, and no fusion
+    anywhere slices a y or z face out of the grid.  Each of the eight
+    kernels, and each remote-DMA post, is handed its token's zero as an
+    operand that is not a constant.  Every consumer of the grid that cares about its layout is
+    now a Pallas one, so the body copies the whole grid in no schedule
+    (the ``xla`` overlap program did twice, layout assignment's relayouts
+    for its fused thin *packs*: PERF.md, PR 29 and PR 44), and the
+    temporaries are not above what PR 44 read."""
     import re
 
     from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
@@ -1189,20 +1216,25 @@ def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
     grid = "f32[" + ",".join(str(e) for e in args.local_shape()) + "]"
     ops = loop_ops_of_shape(text, grid)
     assert sum(o.opcode == "dynamic-update-slice" for o in ops) == 2
-    for d in THIN:  # no update of a y- or z-face shape is left anywhere
-        face = "f32[" + ",".join(
-            str(e) for e in _face_slices(args, d, "unpack")[1]) + "]"
+    for d in THIN:  # no update or slice of a y- or z-face shape is left
+        sizes = _face_slices(args, d, "unpack")[1]
+        face = "f32[" + ",".join(str(e) for e in sizes) + "]"
         assert not re.search(
             r"dynamic-update-slice\([^)]*" + re.escape(face), text), face
+        assert "dynamic_slice_sizes={" + ",".join(
+            str(e) for e in sizes) + "}" not in text, face
     kernels = [o for o in ops if o.name.startswith("halo_window_unpack")]
     assert len(kernels) == 4 and all(o.opcode == "custom-call"
                                      for o in kernels)
-    for o in kernels:
-        first = re.search(re.escape(o.name) + r" = .*? custom-call\(%?([\w.\-]+)",
+    packs = sorted(set(re.findall(r"%(halo_window_pack[\w.\-]*) = ", text)))
+    posts = sorted(set(re.findall(r"%(rdma_shift_post[\w.\-]*) = ", text)))
+    assert len(packs) == 4 and len(posts) == (6 if which == "rdma" else 0)
+    for name in [o.name for o in kernels] + packs + posts:
+        first = re.search(re.escape(name) + r" = .*? custom-call\(%?([\w.\-]+)",
                           text).group(1)
-        assert not first.startswith("constant"), (o.name, first)
+        assert not first.startswith("constant"), (name, first)
     assert not [o for o in ops if "add" in o.fused or o.opcode == "add"], ops
-    copies, temp_bytes = PARENT_LOOP[which]
+    copies, temp_bytes = LOOP_AT_PR44[which]
     assert sum(o.opcode == "copy" for o in ops) <= copies
     assert temp <= temp_bytes
 
@@ -1212,38 +1244,61 @@ def test_mesh_halo_loop_names_its_vertices(topo, on_chip_kernels, which):
     """ISSUE 38: who owns what in the mesh halo's repeat-n loop, read from
     the compiled text with no chip.  The window unpacks, the x faces'
     updates, the collective-permutes and the remote-DMA kernels name their
-    vertices.  A pack has no instruction of its own: XLA fuses its slice
-    into the value tie of the exchange that reads it (the fusion goes to
-    the exchange's ``tie`` and lists the pack's ``apply`` as mixed), and the
-    thin faces' relayout copies inherit the exchange's name.  What XLA
-    leaves nameless: the memory-space moves (``copy-start``/``-done``,
-    ``slice-start``/``-done``, its ``ConcatBitcast``) and, in the ``xla``
-    overlap schedule, the two relayout copies of the whole grid (1.12 GB
-    each, the largest operations of the loop) with four face copies."""
+    vertices, and since PR 44 so do the y and z packs: each owns one
+    ``halo_window_pack`` call (and, before a remote DMA, the z face's
+    relayout to the padded layout that kernel wants).  Before a
+    collective-permute an x pack still has no instruction of its own: XLA
+    fuses its slice into the value tie of the exchange that reads it (the
+    fusion goes to the exchange's ``tie`` and lists the pack's ``apply`` as
+    mixed); a remote-DMA post has no value tie (PR 44: its token is a
+    kernel operand), so there the two x slices are one fusion of the
+    packs' own.  No y or z pack is mixed into anything.  The thin faces' relayout copies around a
+    collective-permute inherit the exchange's name.  What XLA leaves
+    nameless: the memory-space moves (``copy-start``/``-done``,
+    ``slice-start``/``-done``, its ``ConcatBitcast``) and a few face
+    copies; no copy of the whole grid is left in any schedule."""
     from tenzing_tpu.obs.attrib.hlo import UNSCOPED, loop_ops_by_scope
 
     text, _, args = _mesh_halo_loop(topo, which)
     ops = loop_ops_by_scope(text)
     engine = "xla" if which == "naive" else which
     names = [dir_name(d) for d in DIRECTIONS]
+    thin = [dir_name(d) for d in THIN]
     owner = lambda o: f"{o.vertex}/{o.part}"
     grid_bytes = 4 * int(np.prod(args.local_shape()))
 
     windows = [o for o in ops if o.name.startswith("halo_window_unpack")]
     assert sorted(owner(o) for o in windows) == sorted(
-        f"unpack_{dir_name(d)}/apply" for d in THIN)
+        f"unpack_{n}/apply" for n in thin)
     updates = [o for o in ops if o.opcode == "dynamic-update-slice"]
     assert sorted(owner(o) for o in updates) == [
         "unpack_mx/apply", "unpack_px/apply"]
-    # every pack's slice sits in a fusion of its exchange's value tie
+    # a y or z pack is one kernel call of its own ...
+    kernels = [o for o in ops if o.name.startswith("halo_window_pack")]
+    assert sorted(owner(o) for o in kernels) == sorted(
+        f"pack_{n}/apply" for n in thin)
+    own = [o for o in ops if o.vertex.startswith("pack_") and o.bytes > 8
+           and o not in kernels]
     packed = [o for o in ops if o.opcode == "fusion" and o.bytes > 8
               and any(m.startswith("pack_") for m in o.mixed)]
-    assert all(o.part == "tie" and o.vertex.startswith("exchange_")
-               and o.vertex.endswith("." + engine) for o in packed)
-    assert sorted(m[len("pack_"):-len("/apply")] for o in packed
-                  for m in o.mixed if m.startswith("pack_")) == sorted(names)
-    assert not [o for o in ops if o.vertex.startswith("pack_")
-                and o.bytes > 8]
+    if engine == "xla":
+        # ... and an x pack's slice sits in a fusion of its exchange's
+        # value tie
+        assert not own, own
+        assert all(o.part == "tie" and o.vertex.startswith("exchange_")
+                   and o.vertex.endswith("x.xla") for o in packed)
+        assert sorted(m[len("pack_"):-len("/apply")] for o in packed
+                      for m in o.mixed if m.startswith("pack_")) == [
+                          "mx", "px"]
+    else:
+        # ... a remote-DMA post takes its token by index, so the x packs'
+        # slices are a fusion of their own, and a z face is relayouted to
+        # the padded layout the post's kernel wants
+        assert sorted((o.opcode, o.vertex) for o in own) == [
+            ("copy", "pack_mz"), ("copy", "pack_pz"), ("fusion", "pack_px")]
+        assert [list(o.mixed) for o in packed] == [["pack_mx/apply"]]
+        assert not [o for o in ops if o.vertex.startswith("exchange_")
+                    and o.part == "tie" and o.bytes > 8]
     if engine == "xla":
         starts = [o for o in ops if o.opcode == "collective-permute-start"]
         assert sorted(owner(o) for o in starts) == sorted(
@@ -1256,14 +1311,10 @@ def test_mesh_halo_loop_names_its_vertices(topo, on_chip_kernels, which):
         assert sorted(owner(o) for o in waits) == sorted(
             f"await_{n}/apply" for n in names)
     copies = [o for o in ops if o.opcode == "copy" and o.bytes > 8]
-    whole = [o for o in copies if o.bytes == grid_bytes]
-    if which == "xla":
-        assert len(whole) == 2 and {o.vertex for o in whole} == {UNSCOPED}
-    else:
-        assert not whole
+    assert not [o for o in copies if o.bytes == grid_bytes]
     if which == "naive":  # a thin face's relayout, there and back
         assert sorted(o.vertex for o in copies) == sorted(
-            2 * [f"exchange_{dir_name(d)}.xla" for d in THIN])
+            2 * [f"exchange_{n}.xla" for n in thin])
     nameless = {o.opcode for o in ops
                 if o.vertex == UNSCOPED and o.bytes > 8}
     assert nameless <= {"copy", "copy-start", "copy-done", "slice-start",
