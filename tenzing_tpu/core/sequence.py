@@ -156,10 +156,9 @@ def canonical_key(seq: Sequence) -> tuple:
     i-th distinct lane of one to the i-th distinct lane of the other (at each
     first use, injectivity in both directions forces fresh->fresh), so a
     bijection exists exactly when the first-use-relabeled streams coincide.
-    This is the O(1)-lookup replacement for pairwise bijection scans (the
-    same canonicalization the native core's canonical_key uses,
-    native/src/core.cpp) — ``get_equivalence`` remains the semantic ground
-    truth and the cross-check test asserts agreement.
+    This is the O(1)-lookup replacement for pairwise bijection scans —
+    ``get_equivalence`` remains the semantic ground truth and the
+    cross-check test asserts agreement.
 
     Memoized on the sequence (``Sequence.cached``): the solvers' dedup
     loops, the benchmark cache, the verifier cache, and the journal all key

@@ -182,9 +182,7 @@ def get_unique_sequences(
 ) -> List[State]:
     """Like :func:`get_all_sequences`, but terminals are deduplicated under
     resource bijection *as they are found* and ``max_seqs`` counts unique
-    terminals — the same cap semantics as the native core
-    (native/src/core.cpp enumerate_sequences), so ``TENZING_TPU_NATIVE=0``
-    and ``=1`` see the same capped terminal set for the same budget."""
+    terminals."""
     return _dfs_terminals(graph, platform, max_seqs, dedup_terminals=True,
                           counters=counters)
 
@@ -203,7 +201,7 @@ def expand_all(graph: Graph) -> Graph:
 def structural_variants(graph: Graph) -> List[Graph]:
     """All graphs reachable by compound expansion and choice substitution —
     the structural (graph-surgery) half of the decision space, taken eagerly so
-    the order x lane half can run in the native core."""
+    ``enumerate_schedules`` can share its budget fairly between them."""
     graph = expand_all(graph)
     choices = [v for v in graph.vertices() if isinstance(v, ChoiceOp)]
     if not choices:
@@ -220,14 +218,10 @@ def enumerate_schedules(graph: Graph, platform, max_seqs: int = 15000,
 
     Structural decisions (compound expansion, implementation choices) are
     resolved eagerly into graph variants; each variant's order x lane space is
-    enumerated by the native (C++) core when available, else by the Python
-    path.  The ``max_seqs`` budget is fair-shared across variants (a huge first
-    variant must not starve the others out of the search entirely); unused
-    share flows to later variants.  Both paths count *deduplicated* terminals
-    against the cap (same semantics either way; cross-checked in
-    tests/test_native.py)."""
-    from tenzing_tpu.native import bridge
-
+    walked by :func:`get_unique_sequences`.  The ``max_seqs`` budget is
+    fair-shared across variants (a huge first variant must not starve the
+    others out of the search entirely); unused share flows to later variants.
+    *Deduplicated* terminals count against the cap."""
     reporter = get_reporter()
     tr = get_tracer()
     variants = structural_variants(graph)
@@ -243,25 +237,18 @@ def enumerate_schedules(graph: Graph, platform, max_seqs: int = 15000,
             break
         share = -(-remaining // (len(variants) - k))  # ceil fair share
         with tr.span("dfs.enumerate_variant", variant=k, share=share) as sp:
-            # the native core enumerates (and dedups) opaquely — its whole
-            # wall is SELECT; the Python fallback self-attributes per node
-            c = counters if counters is not None else Counters(
-                mirror_global=False)
-            with c.phase("SELECT", span=False):
-                nat = bridge.try_enumerate(g, platform, share,
-                                           dedup_terminals=True)
-            if nat is None:
-                nat = get_unique_sequences(g, platform, share,
-                                           counters=counters)
-            sp.set("n_terminals", len(nat))
-        truncated = len(nat) >= share
+            # the walk attributes itself per node: SELECT and DEDUP
+            found = get_unique_sequences(g, platform, share,
+                                         counters=counters)
+            sp.set("n_terminals", len(found))
+        truncated = len(found) >= share
         if truncated and k + 1 < len(variants):
             reporter.warn(
                 f"tenzing-tpu: dfs variant {k} truncated at its fair share "
                 f"({share} schedules)",
                 variant=k, share=share,
             )
-        out.extend(nat)
+        out.extend(found)
     return out
 
 

@@ -21,8 +21,7 @@ reference's.
 
 Simplification vs the reference: each node stores its full SDP ``State``
 (graph + sequence) rather than reconstructing the state from the root path —
-clone surgery shares op objects so snapshots are cheap; the C++ core will restore
-the path-reconstruction optimization if profiles demand it.
+clone surgery shares op objects so snapshots are cheap.
 """
 
 from __future__ import annotations
@@ -33,15 +32,6 @@ from typing import List, Optional, Tuple
 
 from tenzing_tpu.core.sequence import Sequence
 from tenzing_tpu.core.state import Decision, ExecuteOp, State
-
-
-def _decisions(state: State, platform) -> List[Decision]:
-    """Native-accelerated decision enumeration with Python fallback (the two
-    agree exactly; see tests/test_native.py)."""
-    from tenzing_tpu.native import bridge
-
-    nat = bridge.try_decisions(state, platform)
-    return nat if nat is not None else state.get_decisions(platform)
 
 
 class Node:
@@ -84,7 +74,7 @@ class Node:
         have = {c.decision.key() for c in self.children if c.decision is not None}
         if grew is not None:
             grew.append((self, len(self.children)))
-        for d in _decisions(self.state, platform):
+        for d in self.state.get_decisions(platform):
             if d.key() not in have:
                 self.children.append(Node(self.state.apply(d), self.strategy, d, self))
         self.expanded_ = True
@@ -216,14 +206,13 @@ class Node:
                     node = rng.choice(node.children)
             return node, node.state.sequence
         if policy is None:
-            from tenzing_tpu.native import bridge
-
-            nat = bridge.try_rollout(self.state, platform, rng.getrandbits(63))
-            if nat is not None:
-                return self, nat
+            # a uniform playout opens with one draw that nothing reads: it
+            # is part of the stream a seed stands for, which journals replay
+            # against and tests pin (tests/test_search_core.py)
+            rng.getrandbits(63)
         state = self.state
         while not state.is_terminal():
-            ds = _decisions(state, platform)
+            ds = state.get_decisions(platform)
             if not ds:
                 break
             if policy is not None and rng.random() >= policy_eps:

@@ -312,10 +312,9 @@ class _FakeBench:
         return BenchResult.from_times([t, t, t])
 
 
-def test_dfs_explore_emits_counters_and_spans(tracer, registry, monkeypatch):
+def test_dfs_explore_emits_counters_and_spans(tracer, registry):
     from tenzing_tpu.solve.dfs import DfsOpts, explore
 
-    monkeypatch.setenv("TENZING_TPU_NATIVE", "0")  # force the Python walk
     res = explore(_tiny_graph(), _FakePlatform(1), _FakeBench(),
                   DfsOpts(max_seqs=4))
     assert res.sims

@@ -484,41 +484,25 @@ def test_solvers_bit_identical_prefetch_on_vs_off(corpus, registry):
     assert not _prefetch_threads()
 
 
-# what the parent commit's ``explore(..., MctsOpts(n_iters=24, seed=3))``
-# measured on the corpus, in order (PR 43; ``schedule_id`` of every sim), by
-# playout engine: the native core's rollout draws otherwise than Python's
-PARENT_SIMS = {
-    "off": (["e1067b0eecb0", "ce95dd513ed8", "588e350ccbd3", "5fbab9f8c71b",
-             "e6ebdcb535a1", "f00e27cde505", "545d468c874e", "4fe6c3a3b6d7",
-             "a3fec232ca6c", "be2440a2ba39", "2540d4c8ec85", "732fef6f0a15",
-             "e6ebdcb535a1", "a1e315818944", "eef3f8becb09", "531c1d626e9e",
-             "4875b1dfb836", "eeeae76a08d7", "e7e68b3df9d6", "a55e451e07ea",
-             "b54353c03314", "eeede6e6ccf2", "f00e27cde505", "e0ea00bf0748"],
-            30),
-    "auto": (["f1ea563ee8ca", "d6ff9cb0853c", "de47203e1108", "3bf4dcf68398",
-              "1fcab1b12227", "5a03019a2da3", "1c2644803a34", "cd0b46e79ce9",
-              "e6ebdcb535a1", "d9569ebd61a4", "516c1df91de4", "1c2644803a34",
-              "d87de288901f", "c6af9ba8804b", "a55e451e07ea", "4194d4d0cba3",
-              "e7e68b3df9d6", "5a03019a2da3", "72e66b87d34b", "27ab98678821",
-              "22cac5da1a83", "7064529f5338", "4786b2b8de3b", "d3c1cb0c7a2d"],
-             32),
-}
+# what ``explore(..., MctsOpts(n_iters=24, seed=3))`` measured on the corpus
+# at PR 43's parent commit, in order (``schedule_id`` of every sim), and the
+# tree it left
+PARENT_SIMS = (["e1067b0eecb0", "ce95dd513ed8", "588e350ccbd3", "5fbab9f8c71b",
+                "e6ebdcb535a1", "f00e27cde505", "545d468c874e", "4fe6c3a3b6d7",
+                "a3fec232ca6c", "be2440a2ba39", "2540d4c8ec85", "732fef6f0a15",
+                "e6ebdcb535a1", "a1e315818944", "eef3f8becb09", "531c1d626e9e",
+                "4875b1dfb836", "eeeae76a08d7", "e7e68b3df9d6", "a55e451e07ea",
+                "b54353c03314", "eeede6e6ccf2", "f00e27cde505", "e0ea00bf0748"],
+               30)
 
 
-@pytest.mark.parametrize("native", ["off", "auto"])
-def test_mcts_without_prefetcher_is_the_parents_search(corpus, registry,
-                                                       monkeypatch, native):
+def test_mcts_without_prefetcher_is_the_parents_search(corpus, registry):
     """No prefetcher, no lookahead: rollout for rollout the search the
     parent commit ran, and the same tree."""
-    from tenzing_tpu.native import bridge
-
-    monkeypatch.setenv("TENZING_TPU_NATIVE", native)
-    if native == "auto" and bridge._load() is None:
-        pytest.skip("the native core is not built here")
     rows, _ = corpus
     res = explore(_graph(), Platform.make_n_lanes(2), mk_db(rows),
                   MctsOpts(n_iters=24, seed=3))
-    sims, tree_size = PARENT_SIMS[native]
+    sims, tree_size = PARENT_SIMS
     assert [schedule_id(s.order) for s in res.sims] == sims
     assert res.tree_size == tree_size
 
