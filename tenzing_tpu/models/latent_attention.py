@@ -40,6 +40,14 @@ kernel's grid is the pages its sequences have in its range, one step each
 what it holds however unequal its sequences are.  The lengths do not
 advance: an iteration is the same step again, so n repeats leave every
 buffer as one leaves it.
+
+**On a mesh** (``shards`` > 1: :func:`buffer_shapes`, :func:`mesh_specs`,
+:func:`block_table`, :func:`make_decode_buffers`) the step is data-parallel
+by sequence: every shard holds ``args.lens`` sequences of its own (the same
+list of lengths on each), its own pools, and a block table into its own
+sealed pool; every buffer but the two absorbed weight matrices is cut along
+its leading axis, and the vertices above, traced under ``shard_map``, see a
+shard's part at exactly the one-chip shapes.
 """
 
 from __future__ import annotations
@@ -501,15 +509,19 @@ def add_layer(g: Graph, args: LatentDecodeArgs, plan: List[Group], tag: str,
     """One layer's vertices: the append and the absorb first, side by side
     (each behind every vertex of ``after``; none: behind the graph's start),
     then the groups' engine menus, side by side, then the up-projection,
-    which is returned."""
+    which is returned.  ``after`` may be a mapping ``{"append": [...],
+    "absorb": [...]}`` where vertices of the graph produce the new row and
+    the query (``models/shortcut_moe.py``): each head then waits for its
+    own producer only."""
     pre = f"{tag}." if tag else ""
     heads = [Append(pre + "append", args, tag),
              Absorb(pre + "absorb", args, tag)]
     up = UpProject(pre + "up_project", args, tag)
-    for h in heads:
-        if not after:
+    for h, kind in zip(heads, ("append", "absorb")):
+        before = after.get(kind, ()) if isinstance(after, dict) else after
+        if not before:
             g.start_then(h)
-        for prev in after:
+        for prev in before:
             g.then(prev, h)
     for grp in plan:
         read = MlaEngineChoice(args, grp, tag, impl_choice)
@@ -533,8 +545,32 @@ def decode_graph(args: LatentDecodeArgs, layers, impl_choice: bool = False
     return g
 
 
-def buffer_shapes(args: LatentDecodeArgs, layers) -> Dict[str, tuple]:
-    """``{name: (shape, dtype)}`` of the step's buffers."""
+_EVERYWHERE = ("W_UK", "W_UV")  # what a mesh holds whole on every shard
+
+
+def mesh_specs(args: LatentDecodeArgs, layers, axis: str) -> Dict[str, object]:
+    """Partition spec of every buffer of :func:`buffer_shapes` on a mesh
+    whose ``axis`` cuts the sequences: the leading axis of each, but the two
+    absorbed weight matrices, which every shard holds whole."""
+    from jax.sharding import PartitionSpec as P
+
+    whole = {_names(tag)[k] for tag in layers for k in _EVERYWHERE}
+    return {name: P() if name in whole
+            else P(axis, *([None] * (len(shape) - 1)))
+            for name, (shape, _) in buffer_shapes(args, layers).items()}
+
+
+def buffer_shapes(args: LatentDecodeArgs, layers, shards: int = 1
+                  ) -> Dict[str, tuple]:
+    """``{name: (shape, dtype)}`` of the step's buffers; with ``shards`` the
+    global shapes on a mesh (:func:`mesh_specs`): ``shards`` times the
+    sequences, pools and tables, one shard's after another."""
+    if shards > 1:
+        whole = {_names(tag)[k] for tag in layers for k in _EVERYWHERE}
+        return {name: (shape if name in whole
+                       else (shards * shape[0],) + tuple(shape[1:]), dtype)
+                for name, (shape, dtype) in
+                buffer_shapes(args, layers).items()}
     a, dt = args, args.dtype
     b, h, w = a.batch, a.heads, a.width
     out = {"lens": ((b,), "int32"), "table": ((b, a.max_pages), "int32")}
@@ -556,11 +592,17 @@ def buffer_shapes(args: LatentDecodeArgs, layers) -> Dict[str, tuple]:
     return out
 
 
-def block_table(args: LatentDecodeArgs, seed: int) -> np.ndarray:
+def block_table(args: LatentDecodeArgs, seed: int, shards: int = 1
+                ) -> np.ndarray:
     """``(batch, max_pages)``: sequence b's j-th sealed page is page
     ``table[b, j]`` of the pool, the sealed pages of all sequences laid out
     as a random permutation of the pool (a cache after many allocations);
-    slots past a sequence's sealed pages hold 0."""
+    slots past a sequence's sealed pages hold 0.  With ``shards``: one such
+    table a shard, each into its own pool (another permutation each), one
+    after another."""
+    if shards > 1:
+        return np.concatenate([block_table(args, seed + i)
+                               for i in range(shards)])
     perm = np.random.default_rng(seed).permutation(args.pool_pages)
     table = np.zeros((args.batch, args.max_pages), np.int32)
     at = 0
@@ -571,10 +613,12 @@ def block_table(args: LatentDecodeArgs, seed: int) -> np.ndarray:
 
 
 def make_decode_buffers(args: LatentDecodeArgs, layers, seed: int = 0,
-                        table_seed: int = 0) -> Dict[str, np.ndarray]:
+                        table_seed: int = 0, shards: int = 1
+                        ) -> Dict[str, np.ndarray]:
     """Host buffers of a step at a small size (tests and smoke): the inputs
     standard normal (``W_UK`` over ``sqrt(nope)``, ``W_UV`` over
-    ``sqrt(rank)``), the outputs and the state zero."""
+    ``sqrt(rank)``), the outputs and the state zero.  With ``shards`` the
+    global arrays of a mesh (:func:`buffer_shapes`)."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(seed)
@@ -582,15 +626,15 @@ def make_decode_buffers(args: LatentDecodeArgs, layers, seed: int = 0,
     drawn = ("C", "Copen", "c_new", "kr_new", "q_nope", "q_rope",
              "W_UK", "W_UV")
     bufs = {}
-    for name, (shape, dtype) in buffer_shapes(args, layers).items():
+    for name, (shape, dtype) in buffer_shapes(args, layers, shards).items():
         kind = name.split(".")[0]
         if kind in drawn:
             x = rng.standard_normal(shape) * scaled.get(kind, 1.0)
         else:
             x = np.full(shape, NEG if kind == "m_run" else 0.0)
         bufs[name] = x.astype(jnp.dtype(dtype))
-    bufs["lens"] = np.asarray(args.visible, np.int32)
-    bufs["table"] = block_table(args, table_seed)
+    bufs["lens"] = np.tile(np.asarray(args.visible, np.int32), shards)
+    bufs["table"] = block_table(args, table_seed, shards)
     return bufs
 
 

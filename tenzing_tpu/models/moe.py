@@ -20,13 +20,27 @@ Design:
   pair to the common capacity, exactly like the irregular SpMV's
   width-padded lists — there is no ragged all-to-all on ICI.  A shard holds
   ``experts_per_shard`` experts (a grouped product over the experts held).
-* **Two scoring rules, one layer** (:class:`MoEArgs`).  ``"softmax"`` (the
-  default): ``disp_w`` is the selected experts' softmax probability, fixed
-  at set-up.  ``"sigmoid"`` (the deepseek_v3 rule, ``scoring_func: sigmoid``
-  / ``norm_topk_prob`` / ``routed_scaling_factor``): ``disp_w`` is computed
-  **in the iteration** by ``gate_c`` — scores ``sigmoid(x W_g)``, the
-  selected ones normalised to sum 1 and scaled — a chain that depends on
-  neither all-to-all, like the shared expert ``shared_c`` (``shared_ff``).
+* **Two scoring rules, one layer** (:class:`MoEArgs`): ``scoring`` says how
+  a score is made, ``gate_in_iteration`` whether the timed iteration makes
+  it.  ``"softmax"``: a selected expert's weight is its softmax probability
+  over all the router's outputs times ``routed_scale``, not renormalised
+  (the top-1 default, fixed at set-up; LongCat-Flash's rule with
+  ``routed_scale=6`` in the iteration).  ``"sigmoid"`` (the deepseek_v3
+  rule, ``scoring_func: sigmoid`` / ``norm_topk_prob`` /
+  ``routed_scaling_factor``): scores ``sigmoid(x W_g)``, the selected ones
+  normalised to sum 1 and scaled.  In the iteration ``disp_w`` is computed
+  by ``gate_c``, a chain that depends on neither all-to-all, like the shared
+  expert ``shared_c`` (``shared_ff``).
+* **Zero-compute experts** (``zero_experts``, LongCat-Flash's
+  ``zero_expert_num`` of type identity): the router has ``n_experts +
+  zero_experts`` outputs and the selection runs over all of them; a pick at
+  or above ``n_experts`` is an identity expert: it holds no slot, crosses no
+  chip and adds ``w . x`` on the token's own shard (``gate_c`` sums such
+  picks' weights into ``zero_w_c``, the combine adds the term).  A token so
+  has between 0 and ``top_k`` slots.
+* **A layer among several** (:class:`LayerNames`): a tag before every
+  buffer's and vertex's name, and the names of the input and output
+  buffers, which are then another layer's.
 * **The data plane is schedulable.**  Tokens are split into ``n_chunks``
   microbatch chunks; each chunk is an independent chain
 
@@ -49,7 +63,7 @@ Numerics are checked against a dense host evaluation of the routed layer
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -68,13 +82,41 @@ PHASES = ("start", "pack", "a2a_disp", "gate", "shared", "await_disp", "ffn",
           "a2a_comb", "await_comb", "combine", "moe_concat", "finish")
 
 
+class LayerNames:
+    """Names of one layer's buffers and vertices where a graph holds
+    several: ``tag`` goes before each (``"B0.moe"``: ``B0.moe.W1``,
+    ``B0.moe.pack_0``), and the layer's input and output are the buffers
+    ``x`` and ``y``, whatever vertex of the graph writes or reads them.  The
+    defaults are the layer alone: ``X``, ``Y``, no tag."""
+
+    def __init__(self, tag: str = "", x: str = "X", y: str = "Y"):
+        self.tag, self.x, self.y = tag, x, y
+        self._pre = f"{tag}." if tag else ""
+
+    def buf(self, base: str) -> str:
+        return {"X": self.x, "Y": self.y}.get(base, self._pre + base)
+
+    def op(self, base: str) -> str:
+        return self._pre + base
+
+
+ALONE = LayerNames()
+
+
 @dataclass(frozen=True)
 class MoEArgs:
     """One expert-parallel layer.  The defaults are the one-expert-a-shard,
     top-1 softmax, gelu layer; ``experts_per_shard=16, top_k=6, gated=True,
     shared_ff=2816, scoring="sigmoid", routed_scale=2.446,
     capacity_factor=1.5`` is Moonlight-16B-A3B's (deepseek_v3) over four
-    shards."""
+    shards; ``experts_per_shard=64, top_k=12, gated=True, scoring="softmax",
+    gate_in_iteration=True, routed_scale=6, zero_experts=128`` is
+    LongCat-Flash-Lite's.
+
+    A **zero expert** (``zero_experts`` of them, the router's outputs at and
+    above ``n_experts``) computes nothing: selected, it returns the token
+    itself times the pick's weight.  It is selected like any other, holds
+    no slot in the exchange and lives on no shard."""
 
     n_ep: int  # expert-parallel shards
     tokens_per_shard: int = 16
@@ -89,15 +131,27 @@ class MoEArgs:
     # slots per (source shard, expert, chunk) = ceil(factor * mean load);
     # 0: the largest load routed (the data decide the shapes)
     capacity_factor: float = 0.0
-    scoring: str = "softmax"  # "softmax": weights fixed at set-up;
-    # "sigmoid": normalised, scaled, computed in the iteration (gate_c)
+    # how a selected expert's weight is made.  "softmax": its probability
+    # over all the router's outputs, times routed_scale, not renormalised;
+    # "sigmoid": sigmoid scores, the selected normalised to sum 1, scaled
+    scoring: str = "softmax"
     routed_scale: float = 1.0
+    zero_experts: int = 0  # identity experts behind the n_experts real ones
+    # whether the timed iteration makes the weights (gate_c) or set-up fixes
+    # them; None: the iteration for "sigmoid", set-up for "softmax"
+    gate_in_iteration: Optional[bool] = None
 
     def __post_init__(self):
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown scoring {self.scoring!r}")
-        if not 1 <= self.top_k <= self.n_experts:
-            raise ValueError(f"top_k {self.top_k} of {self.n_experts} experts")
+        if not 1 <= self.top_k <= self.n_router:
+            raise ValueError(f"top_k {self.top_k} of {self.n_router} experts")
+        if self.gate_in_iteration is None:
+            object.__setattr__(self, "gate_in_iteration",
+                               self.scoring == "sigmoid")
+        if self.zero_experts and not self.gate_in_iteration:
+            raise ValueError("zero experts' weights are made in the "
+                             "iteration (gate_in_iteration)")
 
     @property
     def chunk_tokens(self) -> int:
@@ -109,15 +163,18 @@ class MoEArgs:
         return self.n_ep * self.experts_per_shard
 
     @property
-    def gate_in_iteration(self) -> bool:
-        return self.scoring == "sigmoid"
+    def n_router(self) -> int:
+        """Outputs of the router: the real experts, then the zero ones."""
+        return self.n_experts + self.zero_experts
 
     def fixed_capacity(self):
         """Slots per (source shard, expert, chunk) where the configuration
-        fixes them (``capacity_factor``), else ``None``."""
+        fixes them (``capacity_factor``), else ``None``.  The mean load is
+        the real picks': a balanced router sends ``n_experts`` of
+        ``n_router`` picks to a real expert."""
         if not self.capacity_factor:
             return None
-        mean = self.chunk_tokens * self.top_k / self.n_experts
+        mean = self.chunk_tokens * self.top_k / self.n_router
         return int(np.ceil(self.capacity_factor * mean))
 
 
@@ -156,13 +213,23 @@ def select_experts(args: MoEArgs, x, wg, bias):
 
 
 def gate_weights(args: MoEArgs, xc, wg, topk):
-    """The iteration half of the sigmoid router: ``(Tc, top_k)`` float32
-    combine weights of the selected experts ``topk`` — their scores
+    """The iteration half of the router: ``(Tc, top_k)`` float32 combine
+    weights of the selected experts ``topk``.  Sigmoid scoring: their scores
     ``sigmoid(xc wg)``, normalised to sum 1 (``norm_topk_prob``; the
-    model's ``+ 1e-20``) and scaled (``routed_scaling_factor``)."""
+    model's ``+ 1e-20``) and scaled (``routed_scaling_factor``).  Softmax
+    scoring: their probabilities over all the router's outputs, scaled and
+    not renormalised; a float32 router's product is taken at the highest
+    precision (the default would round both sides to bfloat16)."""
     import jax
     import jax.numpy as jnp
 
+    if args.scoring == "softmax":
+        exact = wg.dtype == jnp.float32
+        logits = jnp.dot(xc.astype(wg.dtype), wg,
+                         precision=jax.lax.Precision.HIGHEST if exact
+                         else None, preferred_element_type=jnp.float32)
+        return args.routed_scale * jnp.take_along_axis(
+            jax.nn.softmax(logits, axis=1), topk, axis=1)
     s = jax.nn.sigmoid(jnp.dot(xc, wg, preferred_element_type=jnp.float32))
     picked = jnp.take_along_axis(s, topk, axis=1)
     return args.routed_scale * picked / (
@@ -195,7 +262,9 @@ def slot_tables(sel, args: MoEArgs, cap: int) -> Dict[str, object]:
     * ``topk_c`` (Tc, top_k): the selection itself.
 
     A selection beyond ``cap`` finds no slot (counted by the caller from
-    :func:`expert_loads`; the builders refuse it)."""
+    :func:`expert_loads`; the builders refuse it).  Neither does a zero
+    expert's pick (an id at or above ``n_experts``): its ``comb_idx`` points
+    at the table's last slot and the combine masks it by ``topk_c``."""
     import jax.numpy as jnp
 
     k, tc, n_e = args.top_k, args.chunk_tokens, args.n_experts
@@ -205,11 +274,14 @@ def slot_tables(sel, args: MoEArgs, cap: int) -> Dict[str, object]:
         topk = sel[c * tc:(c + 1) * tc]
         e = topk.reshape(-1)
         onehot = (e[:, None] == jnp.arange(n_e, dtype=e.dtype)[None, :])
+        held = jnp.minimum(e, n_e - 1) if args.zero_experts else e
         rank = jnp.take_along_axis(
-            jnp.cumsum(onehot.astype(jnp.int32), axis=0), e[:, None],
+            jnp.cumsum(onehot.astype(jnp.int32), axis=0), held[:, None],
             axis=1)[:, 0] - 1
         # beyond capacity: an index past the table, which the scatter drops
         slot = jnp.where(rank < cap, e * cap + rank, n_e * cap)
+        if args.zero_experts:
+            slot = jnp.where(e < n_e, slot, n_e * cap)
         shape = (1, args.n_ep, args.experts_per_shard * cap)
         out[f"disp_idx_{c}"] = jnp.zeros((n_e * cap,), jnp.int32).at[
             slot].set(a // k, mode="drop").reshape(shape)
@@ -253,15 +325,18 @@ def _mlp(args: MoEArgs, x, w1, w3, w2, grouped: bool = False):
 class _ChunkOp(DeviceOp):
     """A device op of chunk ``c``'s chain."""
 
-    def __init__(self, name: str, c: int, args: MoEArgs):
+    def __init__(self, name: str, c: int, args: MoEArgs,
+                 names: LayerNames = ALONE):
         super().__init__(name)
         self._c = c
         self._args = args
+        self._names = names
+        self._b = names.buf
 
     def _tokens(self, bufs):
         """The chunk's local tokens ``(Tc, d)``."""
         tc_ = self._args.chunk_tokens
-        return bufs["X"][self._c * tc_ : (self._c + 1) * tc_]
+        return bufs[self._b("X")][self._c * tc_ : (self._c + 1) * tc_]
 
 
 class DispatchPack(_ChunkOp):
@@ -270,35 +345,48 @@ class DispatchPack(_ChunkOp):
     for the Ialltoallv send buffer, ops_spmv.cuh:194-215)."""
 
     def reads(self):
-        return ["X", f"disp_idx_{self._c}"]
+        return [self._b("X"), self._b(f"disp_idx_{self._c}")]
 
     def writes(self):
-        return [f"send_disp_{self._c}"]
+        return [self._b(f"send_disp_{self._c}")]
 
     def apply(self, bufs, ctx):
         xc = self._tokens(bufs)  # (Tc, d)
-        idx = bufs[f"disp_idx_{self._c}"][0]  # (n_ep, E_l*C)
-        return {f"send_disp_{self._c}": xc[idx]}  # (n_ep, E_l*C, d)
+        idx = bufs[self._b(f"disp_idx_{self._c}")][0]  # (n_ep, E_l*C)
+        return {self._b(f"send_disp_{self._c}"): xc[idx]}  # (n_ep, E_l*C, d)
 
 
 class GateWeights(_ChunkOp):
-    """Chunk ``c``'s combine weights, computed in the iteration (sigmoid
-    scoring): the score product ``x W_g`` over all experts, the selected
-    scores normalised and scaled, laid out slot by slot.  Depends on
-    neither all-to-all: the search may run it while a dispatch is in
-    flight."""
+    """Chunk ``c``'s combine weights, computed in the iteration: the score
+    product ``x W_g`` over all the router's outputs, the selected experts'
+    weights (:func:`gate_weights`) laid out slot by slot and, where the
+    layer has zero experts, each token's sum over its zero picks
+    (``zero_w_c``, float32: the combine adds that much of the token
+    itself).  Depends on neither all-to-all: the search may run it while a
+    dispatch is in flight."""
 
     def reads(self):
-        return ["X", "Wg", f"topk_{self._c}", f"slot_tk_{self._c}"]
+        b = self._b
+        return [b("X"), b("Wg"), b(f"topk_{self._c}"),
+                b(f"slot_tk_{self._c}")]
 
     def writes(self):
-        return [f"disp_w_{self._c}"]
+        return [self._b(f"disp_w_{self._c}")] + (
+            [self._b(f"zero_w_{self._c}")] if self._args.zero_experts else [])
 
     def apply(self, bufs, ctx):
-        w_tk = gate_weights(self._args, self._tokens(bufs), bufs["Wg"],
-                            bufs[f"topk_{self._c}"])
-        return {f"disp_w_{self._c}": slot_weights(
-            w_tk, bufs[f"slot_tk_{self._c}"])}
+        import jax.numpy as jnp
+
+        b, a = self._b, self._args
+        topk = bufs[b(f"topk_{self._c}")]
+        w_tk = gate_weights(a, self._tokens(bufs), bufs[b("Wg")], topk)
+        out = {b(f"disp_w_{self._c}"): slot_weights(
+            w_tk, bufs[b(f"slot_tk_{self._c}")])}
+        if a.zero_experts:
+            out[b(f"zero_w_{self._c}")] = jnp.sum(
+                jnp.where(topk >= a.n_experts, w_tk, 0.0), axis=1,
+                keepdims=True)
+        return out
 
 
 class SharedExpert(_ChunkOp):
@@ -306,15 +394,19 @@ class SharedExpert(_ChunkOp):
     exchange): the MLP of the experts' form at width ``shared_ff``."""
 
     def reads(self):
-        return ["X", "Ws1", "Ws2"] + (["Ws3"] if self._args.gated else [])
+        b = self._b
+        return [b("X"), b("Ws1"), b("Ws2")] + (
+            [b("Ws3")] if self._args.gated else [])
 
     def writes(self):
-        return [f"shared_out_{self._c}"]
+        return [self._b(f"shared_out_{self._c}")]
 
     def apply(self, bufs, ctx):
+        b = self._b
         xc = self._tokens(bufs)
-        y = _mlp(self._args, xc, bufs["Ws1"], bufs.get("Ws3"), bufs["Ws2"])
-        return {f"shared_out_{self._c}": y.astype(xc.dtype)}
+        y = _mlp(self._args, xc, bufs[b("Ws1")], bufs.get(b("Ws3")),
+                 bufs[b("Ws2")])
+        return {b(f"shared_out_{self._c}"): y.astype(xc.dtype)}
 
 
 class ExpertFFN(_ChunkOp):
@@ -324,11 +416,12 @@ class ExpertFFN(_ChunkOp):
     0."""
 
     def reads(self):
-        return [f"recv_disp_{self._c}", "W1", "W2"] + (
-            ["W3"] if self._args.gated else [])
+        b = self._b
+        return [b(f"recv_disp_{self._c}"), b("W1"), b("W2")] + (
+            [b("W3")] if self._args.gated else [])
 
     def writes(self):
-        return [f"ffn_out_{self._c}"]
+        return [self._b(f"ffn_out_{self._c}")]
 
     def _experts(self, x, w1, w3, w2):
         """``x`` (rows, E_l, cap, d) through expert ``e``'s weights for each
@@ -338,14 +431,15 @@ class ExpertFFN(_ChunkOp):
     def _ffn(self, bufs, x):
         """``x`` (rows, E_l*cap, d), rows by source shard, to the same shape."""
         rows, slots, d = x.shape
-        e_l = bufs["W1"].shape[0]  # this shard's experts
-        y = self._experts(x.reshape(rows, e_l, slots // e_l, d), bufs["W1"],
-                          bufs.get("W3"), bufs["W2"])
+        b = self._b
+        e_l = bufs[b("W1")].shape[0]  # this shard's experts
+        y = self._experts(x.reshape(rows, e_l, slots // e_l, d),
+                          bufs[b("W1")], bufs.get(b("W3")), bufs[b("W2")])
         return y.astype(x.dtype).reshape(rows, slots, d)
 
     def apply(self, bufs, ctx):
-        x = bufs[f"recv_disp_{self._c}"]  # (n_ep, E_l*C, d)
-        return {f"ffn_out_{self._c}": self._ffn(bufs, x)}
+        x = bufs[self._b(f"recv_disp_{self._c}")]  # (n_ep, E_l*C, d)
+        return {self._b(f"ffn_out_{self._c}"): self._ffn(bufs, x)}
 
     # -- op-chunking protocol (core/chunking.py, T3): the expert MLP splits
     # over the source-shard rows of the received slot table (the token
@@ -366,7 +460,7 @@ class ExpertFFN(_ChunkOp):
         if n < 1 or e % n:
             raise ValueError(f"{e} slot-table rows do not split {n} ways")
         return [ExpertFFNPartial(f"{self.name()}.c{n}p{j}", self._c,
-                                 self._args, j, n)
+                                 self._args, j, n, self._names)
                 for j in range(n)]
 
 
@@ -377,20 +471,21 @@ class ExpertFFNPartial(ExpertFFN):
     the update chain, so other ops interleave between the partials)."""
 
     def __init__(self, name: str, c: int, args: MoEArgs, part: int,
-                 n_parts: int):
-        super().__init__(name, c, args)
+                 n_parts: int, names: LayerNames = ALONE):
+        super().__init__(name, c, args, names)
         self._part, self._n_parts = part, n_parts
 
     def chunkable(self) -> bool:
         return False  # a partial never re-splits
 
     def reads(self):
-        return super().reads() + [f"ffn_out_{self._c}"]
+        return super().reads() + [self._b(f"ffn_out_{self._c}")]
 
     def apply(self, bufs, ctx):
         from jax import lax
 
-        x = bufs[f"recv_disp_{self._c}"]  # (n_ep, E_l*C, d)
+        out = self._b(f"ffn_out_{self._c}")
+        x = bufs[self._b(f"recv_disp_{self._c}")]  # (n_ep, E_l*C, d)
         n = x.shape[0]
         if n % self._n_parts:
             # chunk validity was checked against the build-time n_ep —
@@ -400,8 +495,7 @@ class ExpertFFNPartial(ExpertFFN):
                 f"{self._n_parts} ways")
         lo = self._part * (n // self._n_parts)
         y = self._ffn(bufs, x[lo : lo + n // self._n_parts])
-        return {f"ffn_out_{self._c}": lax.dynamic_update_slice_in_dim(
-            bufs[f"ffn_out_{self._c}"], y, lo, 0)}
+        return {out: lax.dynamic_update_slice_in_dim(bufs[out], y, lo, 0)}
 
 
 class ExpertFFNPallas(ExpertFFN):
@@ -456,10 +550,12 @@ class ExpertFFNChoice(ChoiceOp):
     ``chunk_counts`` is given — core/chunking.py)."""
 
     def __init__(self, name: str, c: int, args: MoEArgs,
-                 chunk_counts=(), chunk_est=None):
+                 chunk_counts=(), chunk_est=None,
+                 names: LayerNames = ALONE):
         super().__init__(name)
         self._c = c
         self._args = args
+        self._names = names
         self._chunks = tuple(int(n) for n in chunk_counts if int(n) > 1)
         self._chunk_est = dict(chunk_est or {})
         if chunk_counts:
@@ -471,12 +567,13 @@ class ExpertFFNChoice(ChoiceOp):
     def choices(self) -> List[OpBase]:
         from tenzing_tpu.core.chunking import ChunkedOp
 
+        where = (self._c, self._args, self._names)
         out: List[OpBase] = [
-            ExpertFFN(self.name() + ".xla", self._c, self._args),
-            ExpertFFNPallas(self.name() + ".pallas", self._c, self._args),
+            ExpertFFN(self.name() + ".xla", *where),
+            ExpertFFNPallas(self.name() + ".pallas", *where),
         ]
         out += [
-            ChunkedOp(ExpertFFN(self.name() + ".xla", self._c, self._args),
+            ChunkedOp(ExpertFFN(self.name() + ".xla", *where),
                       n, est_hidden_us=self._chunk_est.get(n))
             for n in self._chunks
         ]
@@ -486,11 +583,13 @@ class ExpertFFNChoice(ChoiceOp):
 # -- synthesized all-to-all (collectives/synth.py) --------------------------
 
 
-def moe_synth_plans(args: MoEArgs, c: int, site: str, cap: int = None):
+def moe_synth_plans(args: MoEArgs, c: int, site: str, cap: int = None,
+                    names: LayerNames = ALONE):
     """Ring all-to-all instantiations for chunk ``c``'s dispatch or combine
     exchange (``site`` in ``{"disp", "comb"}``): n-1 single-hop rotations
     replace the fused ``AllToAllStart``, each await free to interleave.
-    ``cap`` is the capacity (slot-table width); the graph-time default
+    ``cap`` is the capacity (slots per expert: a peer's row of the table is
+    ``experts_per_shard * cap`` wide); the graph-time default
     ``chunk_tokens`` is its upper bound (pricing only — the buffer builder
     passes the routed capacity)."""
     from tenzing_tpu.collectives.synth import plan_ring_all_to_all
@@ -501,61 +600,72 @@ def moe_synth_plans(args: MoEArgs, c: int, site: str, cap: int = None):
     src = f"send_disp_{c}" if site == "disp" else f"ffn_out_{c}"
     dst = f"recv_disp_{c}" if site == "disp" else f"recv_comb_{c}"
     return [plan_ring_all_to_all(
-        f"a2a_{site}_{c}", src, dst, AXIS, args.n_ep,
-        (cap, args.d_model), itemsize=np.dtype(args.dtype).itemsize)]
+        names.op(f"a2a_{site}_{c}"), names.buf(src), names.buf(dst), AXIS,
+        args.n_ep, (args.experts_per_shard * cap, args.d_model),
+        itemsize=np.dtype(args.dtype).itemsize)]
 
 
 class CombineScatter(_ChunkOp):
     """Bring the returned expert outputs back into token order: each token
     gathers the slots its ``top_k`` experts answered in and sums them,
     scaled by the slots' combine weights, in float32, on top of the shared
-    expert's output where the layer has one."""
+    expert's output where the layer has one.  A zero expert's pick answers
+    in no slot: its weight is masked out of the sum (``topk_c``), and the
+    token itself, times the sum of such picks' weights (``zero_w_c``), is
+    added on this shard: the identity experts' term."""
 
     def reads(self):
-        c = self._c
-        return [f"recv_comb_{c}", f"comb_idx_{c}", f"disp_w_{c}"] + (
-            [f"shared_out_{c}"] if self._args.shared_ff else [])
+        c, b, a = self._c, self._b, self._args
+        return ([b(f"recv_comb_{c}"), b(f"comb_idx_{c}"), b(f"disp_w_{c}")]
+                + ([b(f"shared_out_{c}")] if a.shared_ff else [])
+                + ([b("X"), b(f"topk_{c}"), b(f"zero_w_{c}")]
+                   if a.zero_experts else []))
 
     def writes(self):
-        return [f"Y_{self._c}"]
+        return [self._b(f"Y_{self._c}")]
 
     def apply(self, bufs, ctx):
         import jax.numpy as jnp
 
-        c = self._c
-        vals = bufs[f"recv_comb_{c}"]  # (n_ep, E_l*C, d) rows by expert shard
+        c, b, a = self._c, self._b, self._args
+        vals = bufs[b(f"recv_comb_{c}")]  # (n_ep, E_l*C, d) by expert shard
         d = vals.shape[-1]
         vals = vals.reshape(-1, d)
-        idx = bufs[f"comb_idx_{c}"]  # (Tc, top_k) flat slots
-        w = bufs[f"disp_w_{c}"].reshape(-1)[idx].astype(jnp.float32)
-        if self._args.shared_ff:
-            y = bufs[f"shared_out_{c}"].astype(jnp.float32)
+        idx = bufs[b(f"comb_idx_{c}")]  # (Tc, top_k) flat slots
+        w = bufs[b(f"disp_w_{c}")].reshape(-1)[idx].astype(jnp.float32)
+        if a.shared_ff:
+            y = bufs[b(f"shared_out_{c}")].astype(jnp.float32)
         else:
-            y = jnp.zeros((self._args.chunk_tokens, d), jnp.float32)
-        for k in range(self._args.top_k):  # a fixed order of sums
+            y = jnp.zeros((a.chunk_tokens, d), jnp.float32)
+        if a.zero_experts:
+            w = jnp.where(bufs[b(f"topk_{c}")] < a.n_experts, w, 0.0)
+            y = y + bufs[b(f"zero_w_{c}")] * self._tokens(bufs).astype(
+                jnp.float32)
+        for k in range(a.top_k):  # a fixed order of sums
             y = y + w[:, k, None] * vals[idx[:, k]].astype(jnp.float32)
-        return {f"Y_{c}": y.astype(vals.dtype)}
+        return {b(f"Y_{c}"): y.astype(vals.dtype)}
 
 
 class ConcatChunks(DeviceOp):
     """Stitch the per-chunk outputs back into the token-order output."""
 
-    def __init__(self, name: str, args: MoEArgs):
+    def __init__(self, name: str, args: MoEArgs, names: LayerNames = ALONE):
         super().__init__(name)
         self._args = args
+        self._b = names.buf
 
     def reads(self):
-        return [f"Y_{c}" for c in range(self._args.n_chunks)]
+        return [self._b(f"Y_{c}") for c in range(self._args.n_chunks)]
 
     def writes(self):
-        return ["Y"]
+        return [self._b("Y")]
 
     def apply(self, bufs, ctx):
         import jax.numpy as jnp
 
         return {
-            "Y": jnp.concatenate(
-                [bufs[f"Y_{c}"] for c in range(self._args.n_chunks)], axis=0
+            self._b("Y"): jnp.concatenate(
+                [bufs[name] for name in self.reads()], axis=0
             )
         }
 
@@ -572,14 +682,16 @@ class MoELayer(CompoundOp):
     ``synth=True`` puts synthesized ring all-to-all decompositions
     (collectives/synth.py) next to each chunk's fused dispatch/combine
     exchange in one ChooseOp; ``synth_relax`` keeps analytically-dominated
-    instantiations searchable."""
+    instantiations searchable.  ``names`` (:class:`LayerNames`) makes it one
+    layer of several in a graph."""
 
     def __init__(self, args: MoEArgs, name: str = "moe",
                  impl_choice: bool = False, chunk: bool = False,
                  chunk_relax: bool = False, synth: bool = False,
-                 synth_relax: bool = False):
+                 synth_relax: bool = False, names: LayerNames = ALONE):
         super().__init__(name)
         self._args = args
+        self._names = names
         self._impl_choice = impl_choice
         self._chunk = chunk
         self._chunk_relax = chunk_relax
@@ -591,26 +703,29 @@ class MoELayer(CompoundOp):
 
     def graph(self) -> Graph:
         g = Graph()
-        cat = ConcatChunks("moe_concat", self._args)
+        nm = self._names
+        cat = ConcatChunks(nm.op("moe_concat"), self._args, nm)
         counts, est = ((), None)
         if self._chunk:
             counts, est = ffn_chunk_menu(self._args,
                                          relax=self._chunk_relax)
         if self._impl_choice:
-            mk = lambda name, c_, a_: ExpertFFNChoice(
-                name, c_, a_, chunk_counts=counts, chunk_est=est)
+            mk = lambda name, c_, a_, nm_: ExpertFFNChoice(
+                name, c_, a_, chunk_counts=counts, chunk_est=est, names=nm_)
         elif any(int(n) > 1 for n in counts):
             from tenzing_tpu.core.chunking import ChunkChoice, chunk_variants
 
-            def mk(name, c_, a_):
-                op = ExpertFFN(name, c_, a_)
+            def mk(name, c_, a_, nm_):
+                op = ExpertFFN(name, c_, a_, nm_)
                 return ChunkChoice(op, chunk_variants(op, counts, est))
         else:
             mk = ExpertFFN
 
-        def a2a(base, src, dst, prev, nxt):
+        def a2a(site, src, dst, prev, nxt):
+            base = nm.op(f"a2a_{site}_{c}")
+            src, dst = nm.buf(src), nm.buf(dst)
             start = AllToAllStart(base, src, dst, AXIS, split_axis=0)
-            await_ = AwaitTransfer(f"await_{base[4:]}", dst)
+            await_ = AwaitTransfer(nm.op(f"await_{site}_{c}"), dst)
             if self._synth and self._args.n_ep >= 2:
                 from tenzing_tpu.collectives.synth import (
                     FixedCollective, SynthCollectiveChoice, sketch_menu)
@@ -619,9 +734,8 @@ class MoELayer(CompoundOp):
                 a = self._args
                 cap = a.chunk_tokens  # capacity upper bound for pricing
                 bpe = np.dtype(a.dtype).itemsize
-                site = "disp" if "disp" in base else "comb"
                 variants, menu = sketch_menu(
-                    moe_synth_plans(a, c, site),
+                    moe_synth_plans(a, c, site, names=nm),
                     mesh_topology({AXIS: a.n_ep}, host=False),
                     fixed_bytes=float(a.n_ep * cap * a.d_model * bpe),
                     relax=self._synth_relax, collective="all_to_all")
@@ -637,22 +751,21 @@ class MoELayer(CompoundOp):
             g.then(await_, nxt)
 
         for c in range(self._args.n_chunks):
-            pack = DispatchPack(f"pack_{c}", c, self._args)
-            ffn = mk(f"ffn_{c}", c, self._args)
-            scat = CombineScatter(f"combine_{c}", c, self._args)
+            pack = DispatchPack(nm.op(f"pack_{c}"), c, self._args, nm)
+            ffn = mk(nm.op(f"ffn_{c}"), c, self._args, nm)
+            scat = CombineScatter(nm.op(f"combine_{c}"), c, self._args, nm)
             g.start_then(pack)
-            a2a(f"a2a_disp_{c}", f"send_disp_{c}", f"recv_disp_{c}",
-                pack, ffn)
-            a2a(f"a2a_comb_{c}", f"ffn_out_{c}", f"recv_comb_{c}",
-                ffn, scat)
+            a2a("disp", f"send_disp_{c}", f"recv_disp_{c}", pack, ffn)
+            a2a("comb", f"ffn_out_{c}", f"recv_comb_{c}", ffn, scat)
             # the chains that cross no chip: free to run under either
             # exchange of any chunk
             if self._args.gate_in_iteration:
-                gate = GateWeights(f"gate_{c}", c, self._args)
+                gate = GateWeights(nm.op(f"gate_{c}"), c, self._args, nm)
                 g.start_then(gate)
                 g.then(gate, scat)
             if self._args.shared_ff:
-                shared = SharedExpert(f"shared_{c}", c, self._args)
+                shared = SharedExpert(nm.op(f"shared_{c}"), c, self._args,
+                                      nm)
                 g.start_then(shared)
                 g.then(shared, scat)
             g.then(scat, cat)
@@ -660,13 +773,21 @@ class MoELayer(CompoundOp):
         return g
 
 
-def buffer_layout(args: MoEArgs, cap: int = 1) -> Dict[str, tuple]:
+def buffer_layout(args: MoEArgs, cap: int = 1, names: LayerNames = ALONE,
+                  router_dtype: Optional[str] = None) -> Dict[str, tuple]:
     """``{name: (global shape, dtype, partition spec)}`` of every buffer of
     the layer on the ``("ep",)`` mesh at ``cap`` slots per (source shard,
     expert, chunk): tokens, slot tables and exchange buffers by shard,
-    expert weights by expert, the router and the shared expert replicated.
-    Data and tables are made at set-up; what the iteration writes starts at
-    zero."""
+    expert weights by expert, the router (``n_router`` columns, the zero
+    experts' last; ``router_dtype``, the layer's where not given) and the
+    shared expert replicated.  Data and tables are made at set-up; what the
+    iteration writes starts at zero."""
+    return {names.buf(base): v for base, v in _layout(
+        args, cap, router_dtype or args.dtype).items()}
+
+
+def _layout(args: MoEArgs, cap: int, router_dtype: str) -> Dict[str, tuple]:
+    """:func:`buffer_layout` under the layer's plain names."""
     from jax.sharding import PartitionSpec as P
 
     n, d, t = args.n_ep, args.d_model, args.tokens_per_shard
@@ -684,7 +805,7 @@ def buffer_layout(args: MoEArgs, cap: int = 1) -> Dict[str, tuple]:
         if args.shared_ff:
             out["Ws3"] = out["Ws1"]
     if args.gate_in_iteration:
-        out["Wg"] = ((d, n_e), dt, everywhere)
+        out["Wg"] = ((d, args.n_router), router_dtype, everywhere)
     for c in range(args.n_chunks):
         for nm in ("send_disp", "recv_disp", "ffn_out", "recv_comb"):
             out[f"{nm}_{c}"] = ((n * n, slots, d), dt, stacked)
@@ -697,14 +818,18 @@ def buffer_layout(args: MoEArgs, cap: int = 1) -> Dict[str, tuple]:
         if args.gate_in_iteration:
             out[f"slot_tk_{c}"] = ((n, n, slots), "int32", stacked)
             out[f"topk_{c}"] = ((n * tc, k), "int32", rows)
+        if args.zero_experts:
+            out[f"zero_w_{c}"] = ((n * tc, 1), "float32", rows)
         if args.shared_ff:
             out[f"shared_out_{c}"] = ((n * tc, d), dt, rows)
     return out
 
 
-def layer_specs(args: MoEArgs) -> Dict[str, object]:
+def layer_specs(args: MoEArgs, names: LayerNames = ALONE
+                ) -> Dict[str, object]:
     """Partition spec of every buffer of the layer (:func:`buffer_layout`)."""
-    return {name: spec for name, (_, _, spec) in buffer_layout(args).items()}
+    return {name: spec
+            for name, (_, _, spec) in buffer_layout(args, 1, names).items()}
 
 
 def note_routing(args: MoEArgs, cap: int, loads) -> int:
@@ -712,13 +837,19 @@ def note_routing(args: MoEArgs, cap: int, loads) -> int:
     ``(..., n_experts)`` of every (shard, chunk): ``moe.capacity_slots``
     (slots the exchange carries), ``moe.routed_slots`` (slots that hold a
     token), ``moe.max_expert_load`` (largest load of one (shard, expert,
-    chunk)), ``moe.dropped_slots`` (selections beyond capacity).  Returns
-    the last, and refuses a layer that drops: no token may be."""
+    chunk)), ``moe.dropped_slots`` (selections beyond capacity) and, for a
+    layer with zero experts, ``moe.zero_picks`` (selections that need no
+    slot: every token makes ``top_k``, and the loads count the real ones).
+    Returns the dropped, and refuses a layer that drops: no token may be."""
     from tenzing_tpu.obs.metrics import get_metrics
 
     loads = np.asarray(loads).reshape(-1, args.n_experts)
     dropped = int(np.maximum(loads - cap, 0).sum())
     reg = get_metrics()
+    if args.zero_experts:
+        picks = (loads.shape[0] // args.n_chunks) * args.tokens_per_shard \
+            * args.top_k
+        reg.counter("moe.zero_picks").inc(picks - int(loads.sum()))
     for name, n in (("capacity_slots", loads.size * cap),
                     ("routed_slots", int(loads.sum()) - dropped),
                     ("max_expert_load", int(loads.max())),
@@ -732,15 +863,23 @@ def note_routing(args: MoEArgs, cap: int, loads) -> int:
     return dropped
 
 
-def mesh_moe_buffers(args: MoEArgs, mesh, data: Dict[str, object]):
-    """``(buffers, specs)`` for the sigmoid-scored layer on ``mesh``
-    (``("ep",)``) from ``data`` that already lie there under
-    :func:`layer_specs` (``X``, the expert weights, ``Wg``, the shared
+def mesh_moe_buffers(args: MoEArgs, mesh, data: Dict[str, object],
+                     names: LayerNames = ALONE, route_on=None):
+    """``(buffers, specs)`` for a layer whose weights are made in the
+    iteration on ``mesh`` (``("ep",)``) from ``data`` that already lie there
+    under :func:`layer_specs` (``X``, the expert weights, ``Wg``, the shared
     expert; ``gate_bias`` replicated, zeros if absent): the set-up
     negotiation and the zeroed work buffers, every shard's made on its own
     device — nothing of the global size passes through the host.  Needs a
     fixed capacity (``capacity_factor``): the shapes are then the same for
-    every seed.  Raises where a selection finds no slot."""
+    every seed.  Raises where a selection finds no slot.
+
+    The selection is taken from ``route_on`` (rows by shard as ``X``; ``X``
+    itself where not given): a layer whose input another vertex writes in
+    the iteration has no ``X`` at set-up, and is handed the float32
+    forward's.  ``Wg`` has ``n_router`` columns; a zero expert (a column at
+    or above ``n_experts``, see :class:`MoEArgs`) is selected like any
+    other and given no slot."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -750,28 +889,34 @@ def mesh_moe_buffers(args: MoEArgs, mesh, data: Dict[str, object]):
     cap = args.fixed_capacity()
     if cap is None or not args.gate_in_iteration:
         raise ValueError("a layer made on the mesh needs capacity_factor and "
-                         "sigmoid scoring (make_moe_buffers makes the rest)")
-    specs = layer_specs(args)
-    bias = data.get("gate_bias")
+                         "its weights made in the iteration "
+                         "(make_moe_buffers makes the rest)")
+    b = names.buf
+    wg = data[b("Wg")]
+    layout = buffer_layout(args, cap, names, router_dtype=str(wg.dtype))
+    specs = {name: spec for name, (_, _, spec) in layout.items()}
+    bias = data.get(b("gate_bias"))
     if bias is None:
-        bias = jnp.zeros((args.n_experts,), jnp.float32)
+        bias = jnp.zeros((args.n_router,), jnp.float32)
+    if route_on is None:
+        route_on = data[b("X")]
 
     def route(x, wg, b):
         sel = select_experts(args, x, wg, b)
         return slot_tables(sel, args, cap), expert_loads(sel, args)[None]
 
     with get_tracer().span("moe.route", n_ep=args.n_ep, capacity=cap):
-        table_specs = {f"{nm}_{c}": specs[f"{nm}_{c}"]
+        table_specs = {f"{nm}_{c}": specs[b(f"{nm}_{c}")]
                        for c in range(args.n_chunks)
                        for nm in ("disp_idx", "slot_tk", "comb_idx", "topk")}
         tables, loads = jax.jit(jax.shard_map(
-            route, mesh=mesh, in_specs=(specs["X"], P(), P()),
+            route, mesh=mesh, in_specs=(specs[b("X")], P(), P()),
             out_specs=(table_specs, P(AXIS, None, None))))(
-                data["X"], data["Wg"], bias)
+                route_on, wg, bias)
         note_routing(args, cap, jax.device_get(loads))
     bufs = {k: data[k] for k in specs if k in data}
-    bufs.update(tables)
-    for name, (shape, dtype, spec) in buffer_layout(args, cap).items():
+    bufs.update({b(k): v for k, v in tables.items()})
+    for name, (shape, dtype, spec) in layout.items():
         if name not in bufs:  # what the iteration writes
             bufs[name] = jnp.zeros(shape, dtype,
                                    device=NamedSharding(mesh, spec))

@@ -447,9 +447,13 @@ class TraceExecutor:
     partition specs, and comm ops may use collectives over the mesh axes.
     """
 
-    def __init__(self, platform: Platform, init_bufs: Dict[str, Any]):
+    def __init__(self, platform: Platform, init_bufs: Dict[str, Any],
+                 one_shot_as_loop: bool = False):
         self.platform = platform
         self.init_bufs = dict(init_bufs)
+        # the one-shot program (compile / run) as the repeat-n loop run
+        # once: see compile()
+        self._one_shot_as_loop = one_shot_as_loop
         self._cache: Dict[str, Callable] = {}
         # "n:" keys that precompile() put there and nobody has run yet
         self._unrun: set = set()
@@ -619,13 +623,29 @@ class TraceExecutor:
 
         The FIRST invocation of the returned callable — where jax.jit
         actually traces and XLA-compiles — goes through
-        :meth:`_first_call`; steady-state calls pay one branch."""
+        :meth:`_first_call`; steady-state calls pay one branch.
+
+        ``one_shot_as_loop`` (the constructor's): the program is the
+        repeat-n loop of :meth:`prepare_n` run once (:meth:`_looped_fn`, the
+        repeat count an argument), handing back the carry.  XLA fuses a
+        straight-line program otherwise than a loop's body (a division
+        merged with the update that stores it, a convert in a fusion of its
+        own), so where bfloat16 stations follow one another through a dozen
+        products the two round a value in a million apart, which is nothing
+        to a reference and everything to a comparison bit for bit
+        (``longcat-lite-scmoe-decode``, PERF.md PR 46); the loop run once
+        shares the timed program's body."""
         key = sequence_to_json_str(order)
         if key in self._cache:
             return self._cache[key]
         with get_tracer().span("executor.build", schedule=short_digest(key),
                                n_ops=len(order.vector())):
-            jitted = jax.jit(self._build(order))
+            if self._one_shot_as_loop:
+                jitted = jax.jit(self._looped_fn(order.vector()))
+                once = (jnp.int32(1),)  # the loop's repeat count
+            else:
+                jitted = jax.jit(self._build(order))
+                once = ()
         state = {"cold": True}
 
         def wrapped(bufs: Dict[str, Any]) -> Dict[str, Any]:
@@ -633,10 +653,11 @@ class TraceExecutor:
                 state["cold"] = False
                 with self._first_call(key, run_n=1,
                                       repeat_n=False) as note_sizes:
-                    out = jitted(bufs)  # its first run: to the call's return
-                    note_sizes(lambda: _compiled_of(jitted, bufs))
+                    # its first run: to the call's return
+                    out = jitted(bufs, *once)
+                    note_sizes(lambda: _compiled_of(jitted, bufs, *once))
                     return out
-            return jitted(bufs)
+            return jitted(bufs, *once)
 
         self._cache[key] = wrapped
         return wrapped
@@ -714,7 +735,15 @@ class TraceExecutor:
 
         return run_n
 
-    def _stepped_fn(self, ops: List[OpBase]) -> Callable:
+    def _looped_fn(self, ops: List[OpBase]) -> Callable:
+        """The repeat-n loop of :meth:`_stepped_fn` itself, ``(bufs, n) ->
+        bufs``: the same ``fori_loop`` body under the same ``shard_map``,
+        handing back the carry where the timed program reduces it to its
+        fence."""
+        return self._stepped_fn(ops, return_buffers=True)
+
+    def _stepped_fn(self, ops: List[OpBase],
+                    return_buffers: bool = False) -> Callable:
         """The (unjitted) repeat-n program ``stepped(bufs, n) -> (fence,
         host_outs)`` shared by :meth:`prepare_n` (lazy jit) and
         :meth:`precompile` (AOT): the fori_loop sample body carrying the
@@ -765,6 +794,9 @@ class TraceExecutor:
                 out_specs=specs,
                 **kw,
             )
+
+        if return_buffers:
+            return loop
 
         def stepped(bufs: Dict[str, Any], n) -> Any:
             out = loop(bufs, n)

@@ -1367,3 +1367,102 @@ def test_moonlight_expert_layer(topo):
     assert 1.5e9 < m.argument_size_in_bytes < 1.8e9
     assert 3 * m.argument_size_in_bytes + m.temp_size_in_bytes < (
         HBM_BYTES - 3e9)
+
+
+# -- the shortcut-connected decode step on four chips ---------------------------
+
+SCMOE_CONFIG = "benchmarks/configs/longcat-lite-scmoe-decode.json"
+
+
+def _scmoe_cell(topo):
+    """``(args, mesh, shapes with shardings, specs, graph)`` of
+    ``longcat-lite-scmoe-decode.climb`` on the four described chips, from
+    the configuration's file (the ring exchanges' staging buffers too)."""
+    import json
+    from pathlib import Path
+
+    from jax.sharding import Mesh, NamedSharding
+
+    from tenzing_tpu.models import shortcut_moe
+    from tenzing_tpu.models.latent_attention import LatentDecodeArgs
+    from tenzing_tpu.models.moe import MoEArgs
+
+    cfg = json.loads((Path(__file__).resolve().parent.parent
+                      / SCMOE_CONFIG).read_text())
+    s = cfg["shapes"]
+    mla = LatentDecodeArgs(
+        lens=tuple(s["lens"]), heads=cfg["num_attention_heads"],
+        rank=cfg["kv_lora_rank"], rope=cfg["qk_rope_head_dim"],
+        nope=cfg["qk_nope_head_dim"], v_dim=cfg["v_head_dim"],
+        scale=0.10923, page=s["page_tokens"], groups=s["groups"],
+        fold_pages=s["fold_pages"], dtype=s["dtype"])
+    moe = MoEArgs(
+        n_ep=s["ranks"], tokens_per_shard=len(s["lens"]),
+        d_model=cfg["hidden_size"], d_ff=cfg["expert_ffn_hidden_size"],
+        n_chunks=1, dtype=s["dtype"],
+        experts_per_shard=s["experts_per_shard"], top_k=cfg["moe_topk"],
+        gated=True, capacity_factor=s["capacity_factor"], scoring="softmax",
+        routed_scale=cfg["routed_scaling_factor"],
+        zero_experts=cfg["zero_expert_num"], gate_in_iteration=True)
+    args = shortcut_moe.ScMoEArgs(
+        mla=mla, moe=moe, blocks=cfg["layers"], q_rank=cfg["q_lora_rank"],
+        ffn=cfg["ffn_hidden_size"])
+    cap = moe.fixed_capacity()
+    assert cap == 16
+    mesh = Mesh(np.array(topo.devices), ("ep",))
+    layout = {**shortcut_moe.data_layout(args, cap),
+              **shortcut_moe.ring_staging_layout(args, cap)}
+    specs = {name: spec for name, (_, _, spec) in layout.items()}
+    bufs = {name: _sds(shape, jnp.dtype(dt), NamedSharding(mesh, spec))
+            for name, (shape, dt, spec) in layout.items()}
+    graph = shortcut_moe.scmoe_decode_graph(args, synth=True,
+                                            synth_relax=True)
+    return args, mesh, bufs, specs, graph
+
+
+@pytest.mark.parametrize("which", ["start", "naive", "ring"])
+def test_scmoe_decode_step_on_four_chips(topo, monkeypatch, which):
+    """``models/shortcut_moe.py`` at LongCat-Flash-Lite's published widths
+    over the four described chips (what ``longcat-lite-scmoe-decode.climb``
+    runs): the repeat-n program of the start point (the shortcut discipline,
+    every latent group on ``mla_decode``), of naive (written order, chains
+    of ``mla_fold``) and of the start point on the ring exchanges compiles
+    as ONE ``shard_map`` program that holds the paged Mosaic kernels, the
+    XLA products and the exchange together, and leaves room for the three
+    sets of buffers ``correct`` keeps beside it."""
+    import sys
+    from pathlib import Path
+
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models import shortcut_moe
+    from tenzing_tpu.runtime.executor import TraceExecutor
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+    from benchmarks.builders.scmoe_decode import NAIVE, START, prefer_of
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args, mesh, bufs, specs, graph = _scmoe_cell(topo)
+    lanes = 1 if which == "naive" else 2
+    plat = Platform.make_n_lanes(lanes, mesh=mesh, specs=specs)
+    order = shortcut_moe.WRITTEN if which == "naive" else \
+        shortcut_moe.SHORTCUT
+    prefer = {"start": START, "naive": NAIVE,
+              "ring": (".ring.c1",) + START}[which]
+    seq, _ = drive(graph, plat, phase_policy(
+        plat, shortcut_moe.phases(args, order), prefer_of(prefer)))
+    stepped = TraceExecutor(plat, bufs)._stepped_fn(seq.vector())
+    compiled = jax.jit(stepped).lower(
+        bufs, jax.ShapeDtypeStruct((), jnp.int32)).compile()
+    text = compiled.as_text()
+    exchanges = 2 * args.blocks
+    if which == "ring":
+        assert " all-to-all(" not in text
+        assert text.count(" collective-permute-start(") == 3 * exchanges
+    else:
+        assert text.count(" all-to-all(") == exchanges
+    kernels = text.count("tpu_custom_call")
+    assert kernels >= 2 * args.blocks * args.mla.groups
+    m = compiled.memory_analysis()  # bytes on each device
+    assert 4.2e9 < m.argument_size_in_bytes < 5.0e9
+    assert 3 * m.argument_size_in_bytes + m.temp_size_in_bytes < HBM_BYTES
