@@ -50,6 +50,14 @@ start waits for the face before it (:func:`pack_section`):
     chiprun -- python experiments/halo_mesh_tie_on_chip.py --pack
 
 Its readings are kept in ``experiments/halo_mesh_pack_step0.json``.
+
+ISSUE 47's step 1, one chip (``--unpack``): how a z face enters that grid,
+each form alone in a ``fori_loop`` that carries the grid
+(:func:`unpack_section`):
+
+    chiprun -- python experiments/halo_mesh_tie_on_chip.py --unpack
+
+Its readings are kept in ``experiments/halo_mesh_unpack_step1.json``.
 """
 
 import argparse
@@ -75,7 +83,9 @@ def grid_ops(compiled, local_shape) -> list:
 def grid_writes(compiled, local_shape) -> dict:
     """{operation: thin axis of the face it writes} for the writes into a
     shard's grid inside the loop: a ``dynamic-update-slice``'s update or a
-    kernel's last operand, looked up by name for its shape."""
+    kernel's last operand, looked up by name for its shape.  A z face that
+    enters its kernel turned (PR 47: ``(nq, sx, sz, sy)``) has a y face's
+    shape and reads as y here."""
     import re
 
     from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
@@ -126,6 +136,23 @@ def device_ms_by_op(run_n, n: int, top: int = 24) -> list:
         ops[head] = ops.get(head, 0) + (b - a)
     ranked = sorted(ops.items(), key=lambda kv: -kv[1])
     return [[k, v / 1e6 / n] for k, v in ranked[:top]]
+
+
+def slope_and_ops(call, n0: int) -> dict:
+    """``call(n)`` runs a form's loop of ``n`` repeats to its end: ms a
+    repeat by the slope between ``n0`` and ``5 * n0`` (the best of three
+    each), and one profiled dispatch's ms a repeat by operation."""
+    def timed(n):
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            call(n)
+            best = min(best, time.perf_counter() - t0)
+        return best
+
+    slope = (timed(5 * n0) - timed(n0)) / (4 * n0) * 1e3
+    return {"ms": slope, "device_ms_by_op": device_ms_by_op(
+        call, 5 * n0, top=6)}
 
 
 def _window_padded(u, starts, sizes, tok_zero):
@@ -235,22 +262,102 @@ def pack_section(cells: int, seed: int, rehearse: bool) -> dict:
             face = jax.block_until_ready(run(u, jnp.int32(1)))
             same = bool(jnp.array_equal(face, want))
 
-            def timed(n):
-                best = float("inf")
-                for _ in range(3):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(run(u, jnp.int32(n)))
-                    best = min(best, time.perf_counter() - t0)
-                return best
-
-            slope = (timed(5 * n0) - timed(n0)) / (4 * n0) * 1e3
-            by_op = device_ms_by_op(
-                lambda n: jax.block_until_ready(run(u, jnp.int32(n))),
-                5 * n0, top=6)
-            row[label] = {"ms": slope, "bit_equal": same,
-                          "device_ms_by_op": by_op}
+            row[label] = {"bit_equal": same, **slope_and_ops(
+                lambda n: jax.block_until_ready(run(u, jnp.int32(n))), n0)}
             print(f"pack {dir_name(d)} {label}: {json.dumps(row[label])}",
                   flush=True)
+    return out
+
+
+def unpack_section(cells: int, seed: int, rehearse: bool) -> dict:
+    """ISSUE 47, step 1: a z face's unpack alone, ms a face.
+
+    The grid is the ``fori_loop``'s carry (the kernel is aliased, so it
+    stays in place) and the token's zero of a repeat is drawn from the grid
+    the repeat before left.  Forms:
+
+    * ``padded``: ``unpack_face_window`` on the shell's own ``(nq, sx, sy,
+      sz)``, the face resident in that (default, 3 -> 128 lanes) layout:
+      the parent's kernel alone;
+    * ``turned``: the committed kernel on a resident ``(nq, sx, sz, sy)``;
+    * ``exchange+padded`` and ``exchange+turned``: the face of every repeat
+      is a collective-permute's result (one device sending to itself, the
+      zero added onto what it sends, as ``PermuteStart`` ties its token), so
+      that XLA lays it out as it does in the cell's program and relayouts it
+      inside the loop for whichever operand the kernel pins: the parent's
+      whole path from the exchange into the shell, and the change's.
+
+    By the slope of the loop between ``n`` and ``5n`` repeats and by one
+    profiled dispatch; each form's grid is compared with
+    ``lax.dynamic_update_slice``'s to the bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from tenzing_tpu.models.halo import (
+        DIRECTIONS,
+        HaloArgs,
+        _face_slices,
+        dir_name,
+    )
+    from tenzing_tpu.ops.halo_pallas import _interpret, unpack_face_window
+
+    hargs = HaloArgs(nq=3, lx=cells, ly=cells, lz=cells, radius=3)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
+    k_u, k_f = jax.random.split(jax.random.PRNGKey(seed % (2**31)))
+    u = jax.random.uniform(k_u, hargs.local_shape(), jnp.float32)
+    n0 = 2 if rehearse else 8
+    out = {"cells_per_shard": cells, "n": [n0, 5 * n0], "faces": {}}
+
+    for d in [d for d in DIRECTIONS if d[2] != 0]:
+        starts, sizes = _face_slices(hargs, d, "unpack")
+        starts = tuple(starts)
+        shell = jax.random.uniform(k_f, sizes, jnp.float32) + 2.0
+        want = jax.lax.dynamic_update_slice(u, shell, starts)
+
+        def window(u, face, z, turned):
+            return unpack_face_window(u, face, starts, z, turned=turned,
+                                      interpret=_interpret())
+
+        def exchanged(f, z):
+            return jax.shard_map(
+                lambda a: jax.lax.ppermute(a, "x", [(0, 0)]), mesh=mesh,
+                in_specs=P(), out_specs=P(), check_vma=False,
+            )(f + z.astype(f.dtype))
+
+        forms = {
+            "padded": (shell, lambda u, f, z: window(u, f, z, False)),
+            "turned": (jnp.swapaxes(shell, 2, 3),
+                       lambda u, f, z: window(u, f, z, True)),
+            "exchange+padded": (
+                shell, lambda u, f, z: window(u, exchanged(f, z), z, False)),
+            "exchange+turned": (
+                shell, lambda u, f, z: window(
+                    u, jnp.swapaxes(exchanged(f, z), 2, 3), z, True)),
+        }
+        row = out["faces"][dir_name(d)] = {}
+        for label, (face, f) in forms.items():
+            def loop(u, face, n, f=f):
+                def body(_, u):
+                    x = u[0, 0, 0, 0]
+                    return f(u, face, jnp.where(x != x, 1, 0).astype(
+                        jnp.int32))
+
+                return jax.lax.fori_loop(0, n, body, u)
+
+            run = jax.jit(loop)
+            face = jax.block_until_ready(face)
+            got = jax.block_until_ready(run(u, face, jnp.int32(1)))
+            same = bool(jnp.array_equal(got, want))
+            del got
+
+            row[label] = {"bit_equal": same, **slope_and_ops(
+                lambda n: jax.block_until_ready(run(u, face, jnp.int32(n))),
+                n0)}
+            print(f"unpack {dir_name(d)} {label}: {json.dumps(row[label])}",
+                  flush=True)
+        del want
     return out
 
 
@@ -269,16 +376,20 @@ def main() -> int:
     ap.add_argument("--compare", nargs=2, metavar=("PARENT", "CHANGE"))
     ap.add_argument("--pack", action="store_true",
                     help="ISSUE 44's step 1 alone: the pack forms, one chip")
+    ap.add_argument("--unpack", action="store_true",
+                    help="ISSUE 47's step 1 alone: a z face's unpack forms, "
+                    "one chip")
     args = ap.parse_args()
     if args.compare:
         return compare(*args.compare)
     sys.path.insert(0, os.path.abspath(args.root))
-    if args.pack:
-        report = pack_section(args.cells or (16 if args.rehearse_cpu else 448),
-                              args.seed, args.rehearse_cpu)
+    if args.pack or args.unpack:
+        section = pack_section if args.pack else unpack_section
+        report = section(args.cells or (16 if args.rehearse_cpu else 448),
+                         args.seed, args.rehearse_cpu)
         os.makedirs(os.path.join(HERE, "chiprun_out"), exist_ok=True)
-        with open(os.path.join(HERE, "chiprun_out",
-                               "halo_mesh_pack.json"), "w") as f:
+        name = "halo_mesh_pack.json" if args.pack else "halo_mesh_unpack.json"
+        with open(os.path.join(HERE, "chiprun_out", name), "w") as f:
             json.dump(report, f, indent=1)
         bad = [f"{d}/{k}" for d, row in report["faces"].items()
                for k, r in row.items() if not r["bit_equal"]]
@@ -327,7 +438,8 @@ def main() -> int:
     def counters():
         return tuple(reg.counter(name).value for name in (
             "executor.index_ties", "executor.value_tied_bytes",
-            "halo.window_unpacks", "halo.window_packs"))
+            "halo.window_unpacks", "halo.window_packs",
+            "halo.window_unpacks_turned"))
 
     for label in [s for s in args.schedules.split(",") if s]:
         order = orders[label]
@@ -340,6 +452,7 @@ def main() -> int:
         row = report["schedules"][label] = {
             "index_ties": ties[0], "value_tied_bytes": ties[1],
             "window_unpacks": ties[2], "window_packs": ties[3],
+            "window_unpacks_turned": ties[4],
             "temp_gb": mem.temp_size_in_bytes / 1e9,
             "argument_gb": mem.argument_size_in_bytes / 1e9,
             "grid_ops_in_loop": grid_ops(compiled, local)}
@@ -409,7 +522,8 @@ def compare(parent_json: str, change_json: str) -> int:
                                     c["timed_fence_gap"]],
                 "compared": c["compared"]}
         print(json.dumps(line), flush=True)
-        if (writes["z"][1] > GO_Z_WRITES_MS
+        # y and z together: a turned z face reads as y (grid_writes)
+        if (writes["y"][1] + writes["z"][1] > GO_Z_WRITES_MS
                 or any(a > b for a, b in zip(passes(c), passes(p)))
                 or c["temp_gb"] > p["temp_gb"]):
             no_go.append(label)

@@ -338,7 +338,19 @@ class Unpack(DeviceOp):
       index by scalar prefetch).  The op then counts as a Pallas op
       (``uses_pallas``: ``shard_map`` without ``check_vma``, left out of
       fused regions), and the program's counter ``halo.window_unpacks``
-      says, per traced body, how many faces went this way.
+      says, per traced body, how many faces went this way;
+    * z (the lane axis, ``sz < sy`` as ``Pack`` tells a lane-thin face): the
+      face goes to that kernel TURNED, ``swapaxes(face, 2, 3)``, the form
+      ``Pack`` emits.  The buffer ``recv_<d>`` keeps the builder's shape;
+      the ``swapaxes`` is a bitcast to a layout of XLA's choosing, so the
+      collective-permute's thin-major result is relayouted to an 11 MB
+      operand and not to the shell's own ``(nq, sx, sy, 3)``, which the
+      default layout pads to 308 MB (0.47 ms of relayout a face and 0.43 of
+      the kernel's 1.40 at 448^3 a shard: PERF.md, PR 47).  A remote DMA
+      delivers its face padded; XLA then reads it into the turned form
+      (0.41 ms) and the face costs what it did, 2.41 -> 2.37 ms from the
+      wait into the shell, so no schedule keeps the padded operand.
+      ``halo.window_unpacks_turned`` counts these (2 a mesh body).
 
     Subclasses with a write of their own (``halo_pipeline.UnpackRecv`` and
     the kernel menu of ops/halo_pallas.py) override ``apply`` and declare
@@ -363,6 +375,7 @@ class Unpack(DeviceOp):
 
     def apply(self, bufs, ctx):
         import jax.lax as lax
+        import jax.numpy as jnp
 
         starts, _ = _face_slices(self._args, self._d, "unpack")
         face = bufs[f"recv_{dir_name(self._d)}"]
@@ -373,8 +386,13 @@ class Unpack(DeviceOp):
 
         z = _index_zero(self, ctx)
         get_metrics().counter("halo.window_unpacks").inc()
+        turned = face.shape[3] < face.shape[2]  # lane-thin, as Pack's rule
+        if turned:
+            get_metrics().counter("halo.window_unpacks_turned").inc()
+            face = jnp.swapaxes(face, 2, 3)
         return {"U": unpack_face_window(
-            bufs["U"], face, tuple(starts), z, interpret=_interpret())}
+            bufs["U"], face, tuple(starts), z, turned=turned,
+            interpret=_interpret())}
 
 
 class HaloExchange(CompoundOp):

@@ -42,7 +42,10 @@ ordering token as a scalar-prefetch operand, and its ``Pack`` reads the y
 and z edges with that kernel's mirror, :func:`pack_face_window`.
 ``unpack_face_window``'s docstring is the one account of the unpadded-grid
 window and the scalar-prefetch tie, ``pack_face_window``'s of why a
-lane-thin face leaves its kernel transposed.  Neither is a menu entry:
+lane-thin (z) face leaves its kernel transposed; since PR 47 it enters
+``unpack_face_window`` the same way and is turned in VMEM, so between the
+two kernels a z face is 11 MB whatever XLA does with it, never the 308 MB
+of the shell's own shape in the default layout.  Neither is a menu entry:
 ``Unpack`` and ``Pack`` pick them by the face's thin axis, and the one-chip
 menu's ``PackFlat`` keeps XLA's slice.
 
@@ -536,10 +539,11 @@ def _shell_block(a0: int, n: int, extent: int, tile: int) -> Tuple[int, int, int
     return (extent, 0, a0) if w >= extent else (w, a0 // w, a0 % w)
 
 
-@functools.partial(jax.jit, static_argnames=("starts", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("starts", "turned", "interpret"))
 def unpack_face_window(
     u: jax.Array, face: jax.Array, starts: Tuple[int, ...],
-    tok_zero: jax.Array, interpret: bool = False
+    tok_zero: jax.Array, turned: bool = False, interpret: bool = False
 ) -> jax.Array:
     """u[:, x0+i, y0:y0+sy, z0:z0+sz] = face[:, i], in place, on a grid that
     is NOT tile-padded (the mesh cell's ``(3, 454, 454, 454)`` a shard): the
@@ -571,16 +575,39 @@ def unpack_face_window(
     index map adds to the x block index.  The kernel cannot start before it
     is there, and no buffer gets a value-preserving add: on the received
     face that add would be a Pallas consumer's operand, materialised in the
-    padded default layout (0.6 GB of traffic for a 7 MB z face)."""
+    padded default layout (0.6 GB of traffic for a 7 MB z face).
+
+    A lane-thin (z) face comes TURNED (``turned``: ``face`` is ``(nq, sx,
+    sz, sy)``, the form :func:`pack_face_window` emits, which says why): as
+    this kernel's operand the shell's own ``(nq, sx, sy, 3)`` is pinned to
+    the default layout, 3 -> 128 lanes, 308 MB that XLA writes once (the
+    relayout from the collective-permute's thin-major result) for the
+    kernel to read once.  The turned face is 11 MB.  The body lays each q's
+    ``(sz, sy)`` row into rows ``[zl, zl + sz)`` of a ``(WW, sy)`` scratch,
+    turns that once on the XLU and selects its lanes ``[zl, zl + sz)`` into
+    the window block; what else the scratch holds is never selected.  A
+    sublane-thin (y) face, and a z face handed over as the shell's shape,
+    take the plain merge."""
     nq, sx, sy, sz = face.shape
+    if turned:
+        sy, sz = sz, sy
     _, x0, y0, z0 = starts
     _, _, Y, Z = u.shape
     WH, by, yl = _shell_block(y0, sy, Y, sublane_tile(u.dtype.itemsize))
     WW, bz, zl = _shell_block(z0, sz, Z, 128)
 
-    def kernel(tok_ref, u_ref, f_ref, o_ref):
+    def kernel(tok_ref, u_ref, f_ref, o_ref, *scratch):
         o_ref[...] = u_ref[...]
-        o_ref[:, yl : yl + sy, zl : zl + sz] = f_ref[...]
+        if not turned:
+            o_ref[:, yl : yl + sy, zl : zl + sz] = f_ref[...]
+            return
+        (rows,) = scratch
+        lane = jax.lax.broadcasted_iota(jnp.int32, (sy, WW), 1)
+        shell = (lane >= zl) & (lane < zl + sz)
+        for q in range(nq):
+            rows[zl : zl + sz, :] = f_ref[q]
+            o_ref[q, yl : yl + sy, :] = jnp.where(
+                shell, rows[...].T, u_ref[q, yl : yl + sy, :])
 
     def window(i, tok_ref):
         return (0, x0 + i + tok_ref[0], by, bz)
@@ -592,9 +619,12 @@ def unpack_face_window(
             grid=(sx,),
             in_specs=[
                 pl.BlockSpec((nq, None, WH, WW), window),
-                pl.BlockSpec((nq, None, sy, sz), lambda i, tok_ref: (0, i, 0, 0)),
+                pl.BlockSpec((nq, None) + face.shape[2:],
+                             lambda i, tok_ref: (0, i, 0, 0)),
             ],
             out_specs=pl.BlockSpec((nq, None, WH, WW), window),
+            scratch_shapes=(
+                [pltpu.VMEM((WW, sy), u.dtype)] if turned else []),
         ),
         out_shape=jax.ShapeDtypeStruct(u.shape, u.dtype),
         input_output_aliases={1: 0},  # operand 0 is the token's zero

@@ -86,7 +86,7 @@ def _counters():
     reg = get_metrics()
     return tuple(reg.counter(name).value for name in (
         "executor.index_ties", "executor.value_tied_bytes",
-        "halo.window_unpacks"))
+        "halo.window_unpacks", "halo.window_unpacks_turned"))
 
 
 @pytest.mark.needs_shard_map
@@ -108,15 +108,16 @@ def test_counters_read_ten_index_ties_and_the_x_faces(which):
     the window kernel writes), sixteen where the six exchanges are
     remote-DMA posts (since PR 44 the token is an operand of their kernel),
     and the value-tied reads are the two received x faces, neither of them
-    the grid."""
+    the grid.  Of the four window unpacks the two z faces' take their face
+    turned (PR 47)."""
     ex, seq, _ = _setup(which)
     before = _counters()
     _lowered_repeat_n(ex, seq)
-    ties, tied_bytes, window_unpacks = (
+    ties, tied_bytes, window_unpacks, turned = (
         b - a for a, b in zip(before, _counters()))
     assert ties == (16 if which == "rdma" else 10)
     assert tied_bytes == X_FACE_BYTES
-    assert window_unpacks == 4
+    assert (window_unpacks, turned) == (4, 2)
 
 
 @pytest.mark.needs_shard_map
@@ -130,7 +131,7 @@ def test_a_value_tied_pack_shows_in_lowering_and_counter(monkeypatch):
     ex, seq, want = _setup("xla")
     before = _counters()
     text = _lowered_repeat_n(ex, seq)
-    ties, tied_bytes, _ = (b - a for a, b in zip(before, _counters()))
+    ties, tied_bytes, _, _ = (b - a for a, b in zip(before, _counters()))
     grid_bytes = int(np.prod(ARGS.local_shape())) * 4
     assert _grid_adds(text) == 6
     assert ties == 4  # the y and z unpacks

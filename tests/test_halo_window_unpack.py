@@ -11,6 +11,11 @@ operand.  Here the kernel and the op; the mesh program end to end, its
 counters and its token edges are in tests/test_halo_index_tie.py.  CPU, the
 Pallas interpreter, toy shards: what is checked is values and the traced
 program, never a time.
+
+Since ISSUE 47 a z face reaches the kernel TURNED, ``(nq, sx, sz, sy)``, the
+form ``pack_face_window`` emits, and the body turns it in VMEM: the padded
+``(nq, sx, sy, 3)`` operand (308 MB for 7 MB of cells at the cell's size) is
+no operand of the kernel any more.
 """
 
 from types import SimpleNamespace
@@ -65,19 +70,28 @@ def test_shell_block(a0, n, extent, tile, want):
     assert w == extent or w % tile == 0
 
 
+# every thin face as the shell's own shape, and the z faces turned as well
+FORMS = [(d, False) for d in THIN] + [(d, True) for d in THIN if d[2] != 0]
+FORM_IDS = [dir_name(d) + ("-turned" if t else "") for d, t in FORMS]
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("d", THIN, ids=THIN_IDS)
-def test_window_unpack_is_dynamic_update_slice_to_the_bit(d, dtype):
-    """Low and high side, y and z, a 4-byte and a 2-byte grid, no extent
-    aligned: the kernel's grid is ``lax.dynamic_update_slice``'s bit for
-    bit, and every cell outside the face is the cell that went in."""
+@pytest.mark.parametrize("d,turned", FORMS, ids=FORM_IDS)
+def test_window_unpack_is_dynamic_update_slice_to_the_bit(d, turned, dtype):
+    """Low and high side, y and z, a z face as the shell's shape and turned
+    (``(nq, sx, sz, sy)``, the form ``Unpack`` hands over), a 4-byte and a
+    2-byte grid, no extent aligned: the kernel's grid is
+    ``lax.dynamic_update_slice``'s bit for bit, and every cell outside the
+    face is the cell that went in."""
     rng = np.random.default_rng(11)
     u = jnp.asarray(rng.random(UNALIGNED.local_shape(), dtype=np.float32)
                     ).astype(dtype)
     starts, sizes = _face_slices(UNALIGNED, d, "unpack")
     face = (jnp.asarray(rng.random(sizes, dtype=np.float32)) + 2.0
             ).astype(dtype)
-    got = unpack_face_window(u, face, tuple(starts), _zero(), interpret=True)
+    got = unpack_face_window(
+        u, jnp.swapaxes(face, 2, 3) if turned else face, tuple(starts),
+        _zero(), turned=turned, interpret=True)
     want = jax.lax.dynamic_update_slice(u, face, starts)
     assert got.dtype == u.dtype and got.shape == u.shape
     np.testing.assert_array_equal(np.asarray(got, np.float32),
@@ -91,35 +105,63 @@ def test_window_unpack_is_dynamic_update_slice_to_the_bit(d, dtype):
                                   np.asarray(face, np.float32))
 
 
+@pytest.mark.parametrize("d,turned", FORMS, ids=FORM_IDS)
+def test_only_a_turned_face_is_turned(d, turned):
+    """The kernel's body by what it traces to: a face in the shell's own
+    shape (every y face; the parent's jaxpr, sha for sha, at the cell's
+    size) is merged by one store, nothing is turned and the kernel has no
+    scratch; a turned z face costs one ``transpose`` a q, out of the one
+    VMEM scratch its rows are laid into."""
+    starts, sizes = _face_slices(UNALIGNED, d, "unpack")
+    if turned:
+        sizes = (sizes[0], sizes[1], sizes[3], sizes[2])
+    text = str(jax.make_jaxpr(
+        lambda u, f, z: unpack_face_window(u, f, tuple(starts), z,
+                                           turned=turned, interpret=False)
+    )(jnp.zeros(UNALIGNED.local_shape(), jnp.float32),
+      jnp.zeros(sizes, jnp.float32), _zero()))
+    assert (text.count(" transpose["), text.count("Ref<vmem>")) == (
+        (UNALIGNED.nq, 1) if turned else (0, 0))
+
+
 @pytest.mark.parametrize("d", DIRECTIONS, ids=DIR_IDS)
 def test_unpack_adapts_to_the_thin_axis(d, monkeypatch):
     """x faces keep ``dynamic_update_slice`` on a value-tied read; y and z
     faces declare an index tie, count as Pallas ops and call the kernel
-    with the token's zero.  The result is the same grid either way."""
+    with the token's zero: a y face as it is, a z face turned (``(nq, sx,
+    sz, sy)``, ``turned=True``), which ``halo.window_unpacks_turned``
+    counts.  The result is the same grid either way."""
     op = Unpack(UNALIGNED, d)
     windowed = d[0] == 0
+    turned = d[2] != 0
     assert bool(op.INDEX_TIE) is windowed
     assert op.uses_pallas() is windowed
     calls = []
     real = halo_pallas.unpack_face_window
     monkeypatch.setattr(
         halo_pallas, "unpack_face_window",
-        lambda *a, **k: calls.append(a[3]) or real(*a, **k))
+        lambda *a, **k: calls.append((a[1].shape, a[3], k["turned"]))
+        or real(*a, **k))
     rng = np.random.default_rng(2)
     u = jnp.asarray(rng.random(UNALIGNED.local_shape(), dtype=np.float32))
     starts, sizes = _face_slices(UNALIGNED, d, "unpack")
     face = jnp.asarray(rng.random(sizes, dtype=np.float32))
     zero = _zero()
     ctx = SimpleNamespace(tok_index_zero=zero if windowed else None)
-    before = get_metrics().counter("halo.window_unpacks").value
+    reg = get_metrics()
+    before = (reg.counter("halo.window_unpacks").value,
+              reg.counter("halo.window_unpacks_turned").value)
     out = op.apply({"U": u, f"recv_{dir_name(d)}": face}, ctx)["U"]
     np.testing.assert_array_equal(
         np.asarray(out),
         np.asarray(jax.lax.dynamic_update_slice(u, face, starts)))
-    assert len(calls) == int(windowed)
-    assert all(z is zero for z in calls)
-    assert (get_metrics().counter("halo.window_unpacks").value - before
-            == int(windowed))
+    nq, sx, sy, sz = sizes
+    assert calls == ([((nq, sx, sz, sy) if turned else (nq, sx, sy, sz),
+                       zero, turned)] if windowed else [])
+    assert all(z is zero for _, z, _ in calls)
+    assert (reg.counter("halo.window_unpacks").value - before[0],
+            reg.counter("halo.window_unpacks_turned").value - before[1]
+            ) == (int(windowed), int(turned))
 
 
 @pytest.mark.parametrize("d", THIN, ids=THIN_IDS)
@@ -140,8 +182,8 @@ def test_window_unpack_traced_outside_the_contract_raises(d):
 def _counters():
     reg = get_metrics()
     return tuple(reg.counter(name).value for name in (
-        "halo.window_unpacks", "executor.index_ties",
-        "executor.value_tied_bytes"))
+        "halo.window_unpacks", "halo.window_unpacks_turned",
+        "executor.index_ties", "executor.value_tied_bytes"))
 
 
 # the parent's (89ae733) value-tied bytes for this body, read before the edit
@@ -151,8 +193,9 @@ ONE_CHIP_VALUE_TIED_BYTES = 3072
 @pytest.mark.needs_pinned_host
 def test_one_chip_body_counts_no_window_unpack():
     """``halo512.climb``'s graph (``halo_pipeline``: ``UnpackRecv`` and the
-    kernel menu) never reaches ``Unpack.apply``: no window unpack, the six
-    packs' index ties, and the value-tied bytes the parent read."""
+    kernel menu) never reaches ``Unpack.apply``: no window unpack, turned
+    or not, the six packs' index ties, and the value-tied bytes the parent
+    read."""
     from tenzing_tpu.models.halo_pipeline import (
         host_buffer_names,
         make_pipeline_buffers,
@@ -168,7 +211,7 @@ def test_one_chip_body_counts_no_window_unpack():
     before = _counters()
     jax.jit(ex._stepped_fn(seq.vector())).lower(ex.init_bufs, jnp.int32(1))
     assert tuple(b - a for a, b in zip(before, _counters())) == (
-        0, 6, ONE_CHIP_VALUE_TIED_BYTES)
+        0, 0, 6, ONE_CHIP_VALUE_TIED_BYTES)
 
 
 # -- subclasses with a write of their own trace as before ----------------------

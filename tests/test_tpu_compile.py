@@ -1108,20 +1108,38 @@ MESH_CELL = HaloArgs(nq=3, lx=448, ly=448, lz=448, radius=3)
 THIN = [d for d in DIRECTIONS if d[0] == 0]
 
 
-@pytest.mark.parametrize("d", THIN, ids=[dir_name(d) for d in THIN])
-def test_window_unpack_on_the_unpadded_grid(one_chip, d):
+# a thin face as the shell's own shape, and a z face turned as ``Unpack``
+# hands it over since PR 47
+UNPACK_FORMS = [(d, False) for d in THIN] + [
+    (d, True) for d in THIN if d[2] != 0]
+
+
+@pytest.mark.parametrize("d,turned", UNPACK_FORMS, ids=[
+    dir_name(d) + ("-turned" if t else "") for d, t in UNPACK_FORMS])
+def test_window_unpack_on_the_unpadded_grid(one_chip, d, turned):
     """``unpack_face_window`` on ``halo512-mesh4``'s shard, ``(3, 454, 454,
     454)`` as the cell allocates it: Mosaic takes the blocks that run past
-    the grid's end (sublanes [448, 456), lanes [384, 512) of 454) ..."""
+    the grid's end (sublanes [448, 456), lanes [384, 512) of 454) and, for
+    a z face that comes turned as ``(3, 448, 3, 448)``, the turn of a
+    ``(128, 448)`` scratch on the XLU and the select under a lane mask.
+    The turned face is the kernel's operand at 4 sublanes for its 3:
+    nothing of the padded ``f32[3,448,448,3]{3,2,1,0}`` is made ..."""
     from tenzing_tpu.ops.halo_pallas import unpack_face_window
 
     starts, sizes = _face_slices(MESH_CELL, d, "unpack")
+    if turned:
+        sizes = (sizes[0], sizes[1], sizes[3], sizes[2])
     compiled = jax.jit(
-        lambda u, f, z: unpack_face_window(u, f, tuple(starts), z)
+        lambda u, f, z: unpack_face_window(u, f, tuple(starts), z,
+                                           turned=turned)
     ).lower(_sds(MESH_CELL.local_shape(), jnp.float32, one_chip),
             _sds(sizes, jnp.float32, one_chip),
             _sds((), jnp.int32, one_chip)).compile()
     _assert_kernel(compiled)
+    if turned:
+        text = compiled.as_text()
+        assert "f32[3,448,3,448]{3,2,1,0:T(4,128)" in text
+        assert "f32[3,448,448,3]" not in text
 
 
 @pytest.mark.parametrize("d", THIN, ids=[dir_name(d) for d in THIN])
@@ -1160,13 +1178,19 @@ def test_manual_window_dma_is_refused_on_the_unpadded_grid(one_chip, d):
                 _sds(sizes, jnp.float32, one_chip)).compile()
 
 
-# the repeat-n programs at 448^3 as PR 44 left them, compiled for the same
+# the repeat-n programs at 448^3 as PR 47 leaves them, compiled for the same
 # described chips: whole-grid ``copy`` operations in the ``while`` body, and
-# temporaries a chip in bytes (its parent, 74bc99f: naive 0 and 2_596_547_584,
-# xla 2 and 5_091_209_216, rdma 0 and 3_203_034_624; the remote-DMA posts took
-# their tokens by value there, an add on each face they send)
-LOOP_AT_PR44 = {"naive": (0, 2_002_004_992), "xla": (0, 2_002_940_416),
-                "rdma": (0, 2_585_990_144)}
+# temporaries a chip in bytes (its parent, 877d6fa, as PR 44 left them: naive
+# 0 and 2_002_004_992, xla 0 and 2_002_940_416, rdma 0 and 2_585_990_144; the
+# two padded z faces a collective-permute's result was relayouted to, 308 MB
+# each, are gone; the remote DMA still delivers its face padded, and the 11 MB
+# turned copies of the two beside it are the 193_536 bytes rdma is up: on the
+# chip that program read 7.693 -> 7.635 ms an iteration, so it keeps the turned
+# form too: PERF.md, PR 47)
+LOOP_AT_PR47 = {"naive": (0, 1_385_507_840), "xla": (0, 1_385_540_096),
+                "rdma": (0, 2_586_183_680)}
+# a received z face as the builder shapes it, z minor: 3 -> 128 lanes, 308 MB
+PADDED_Z_FACE = r"f32\[3,448,448,3\]\{3,"
 
 
 def _mesh_halo_loop(topo, which):
@@ -1206,11 +1230,15 @@ def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
     operand that is not a constant.  Every consumer of the grid that cares about its layout is
     now a Pallas one, so the body copies the whole grid in no schedule
     (the ``xla`` overlap program did twice, layout assignment's relayouts
-    for its fused thin *packs*: PERF.md, PR 29 and PR 44), and the
-    temporaries are not above what PR 44 read."""
+    for its fused thin *packs*: PERF.md, PR 29 and PR 44).  Since PR 47 a
+    z face enters its unpack turned, so where the exchange is a
+    collective-permute no instruction of the body produces or consumes a
+    received z face in the padded z-minor layout (the parent relayouted
+    each to it for the kernel to read once); a remote DMA still delivers
+    one.  The temporaries are not above what PR 47 read."""
     import re
 
-    from tenzing_tpu.obs.attrib.hlo import loop_ops_of_shape
+    from tenzing_tpu.obs.attrib.hlo import computations, loop_ops_of_shape
 
     text, temp, args = _mesh_halo_loop(topo, which)
     grid = "f32[" + ",".join(str(e) for e in args.local_shape()) + "]"
@@ -1234,7 +1262,16 @@ def test_mesh_halo_loop_adds_nothing_onto_the_grid(topo, on_chip_kernels,
                           text).group(1)
         assert not first.startswith("constant"), (name, first)
     assert not [o for o in ops if "add" in o.fused or o.opcode == "add"], ops
-    copies, temp_bytes = LOOP_AT_PR44[which]
+    comps = computations(text)
+    padded = []
+    for body in re.findall(r"\swhile\(.*\bbody=%?([\w.\-]+)", text):
+        for line in comps[body]:
+            called = re.search(r"calls=%?([\w.\-]+)", line)
+            padded += [ln for ln in [line] + (
+                comps[called.group(1)] if called else [])
+                if re.search(PADDED_Z_FACE, ln)]
+    assert bool(padded) is (which == "rdma"), padded[:2]
+    copies, temp_bytes = LOOP_AT_PR47[which]
     assert sum(o.opcode == "copy" for o in ops) <= copies
     assert temp <= temp_bytes
 
@@ -1252,8 +1289,14 @@ def test_mesh_halo_loop_names_its_vertices(topo, on_chip_kernels, which):
     fusion goes to the exchange's ``tie`` and lists the pack's ``apply`` as
     mixed); a remote-DMA post has no value tie (PR 44: its token is a
     kernel operand), so there the two x slices are one fusion of the
-    packs' own.  No y or z pack is mixed into anything.  The thin faces' relayout copies around a
-    collective-permute inherit the exchange's name.  What XLA leaves
+    packs' own.  No y or z pack is mixed into anything.  The thin faces'
+    relayout copies around a collective-permute inherit the exchange's
+    name: a y face's there and back, a z face's there only since PR 47,
+    because its way back is now the small relayout to the turned form the
+    unpack's kernel takes (``(3, 448, 3, 448)``, 11 MB), a fusion of the
+    unpack's own that lists the exchange as mixed; after a remote DMA,
+    which delivers the face padded, that relayout is a ``copy`` under the
+    await's name.  What XLA leaves
     nameless: the memory-space moves (``copy-start``/``-done``,
     ``slice-start``/``-done``, its ``ConcatBitcast``) and a few face
     copies; no copy of the whole grid is left in any schedule."""
@@ -1312,9 +1355,25 @@ def test_mesh_halo_loop_names_its_vertices(topo, on_chip_kernels, which):
             f"await_{n}/apply" for n in names)
     copies = [o for o in ops if o.opcode == "copy" and o.bytes > 8]
     assert not [o for o in copies if o.bytes == grid_bytes]
-    if which == "naive":  # a thin face's relayout, there and back
+    z_thin = [n for n in thin if n.endswith("z")]
+    turns = [o for o in ops if o.bytes > 8 and o.opcode != "custom-call"
+             and o.vertex in [f"{v}_{n}" for n in z_thin
+                              for v in ("unpack", "await")]]
+    if engine == "xla":  # the z faces' small relayout is the unpacks' own
+        assert sorted((o.opcode, owner(o), o.mixed) for o in turns) == sorted(
+            ("fusion", f"unpack_{n}/apply", (f"exchange_{n}.xla/apply",))
+            for n in z_thin)
+        assert all("f32[3,448,3,448]{3,2,1,0:T(4,128)" in o.result
+                   for o in turns)
+    else:
+        assert sorted((o.opcode, owner(o)) for o in turns) == sorted(
+            ("copy", f"await_{n}/apply") for n in z_thin)
+        assert all("f32[3,448,448,3]{2,3,1,0:T(4,128)" in o.result
+                   for o in turns)
+    if which == "naive":  # a y face's relayout there and back, a z face's there
         assert sorted(o.vertex for o in copies) == sorted(
-            2 * [f"exchange_{n}.xla" for n in thin])
+            [f"exchange_{n}.xla" for n in thin]
+            + [f"exchange_{n}.xla" for n in thin if n.endswith("y")])
     nameless = {o.opcode for o in ops
                 if o.vertex == UNSCOPED and o.bytes > 8}
     assert nameless <= {"copy", "copy-start", "copy-done", "slice-start",
