@@ -83,18 +83,26 @@ class DriverConfigError(ValueError):
 # the measured per-face aliased-unpack recipe (the r5 discovery, see
 # experiments/MENU_INCUMBENT2.json / MENU_INCUMBENT3.json): the ghost-shell
 # write must lower IN PLACE (a non-aliased write copies the 2.07 GB grid,
-# ~5 ms) and these are the aliased Pallas kernels per face axis.  ONE
-# definition — the greedy incumbents and the climb seeds must refine the
-# same recipe.
-ALIAS_UNPACK = {"x": ".pallas", "y": ".pallasf", "z": ".pallasb"}
+# ~5 ms) and these are the aliased Pallas kernels per face axis, first
+# choice first.  A z face takes the window kernel that reads and writes one
+# tile column of the grid (PR 48; ``.pallasb``, the r5 choice, where the
+# menu has no ``.window``: a z face that is not lane-thin).  ONE definition
+# — the greedy incumbents and the climb seeds must refine the same recipe.
+ALIAS_UNPACK = {"x": (".pallas",), "y": (".pallasf",),
+                "z": (".window", ".pallasb")}
+
+
+def _first_choice(choices, suffixes):
+    """The menu entry with the first of ``suffixes`` the menu has, or None."""
+    return next((c for want in suffixes for c in choices
+                 if c.endswith(want)), None)
 
 
 def alias_unpack_choice(op_name, choices):
     """The aliased kernel for an ``unpack_*`` op from the menu, or None when
     it is off-menu — the one lookup both the greedy seeding and the climb
     disciplines share."""
-    want = ALIAS_UNPACK[op_name[-1]]
-    return next((c for c in choices if c.endswith(want)), None)
+    return _first_choice(choices, ALIAS_UNPACK[op_name[-1]])
 
 
 def generic_xla_prefer(op_name, choices):
@@ -106,15 +114,17 @@ def generic_xla_prefer(op_name, choices):
 def halo_alias_prefer(op_name, choices):
     """The halo climb policy: all-rdma + the aliased-unpack kernel map (the
     measured r5 recipe — in-place ghost-shell writes per face,
-    MENU_INCUMBENT2/3).  Module-level so a fleet worker process can rebuild
-    it by name from the job spec (search/fleet.py resolve_prefer)."""
+    MENU_INCUMBENT2/3) + the window pack where the menu has one (a z face:
+    it feeds ``unpack_*.window`` the turned face as the kernel wrote it),
+    XLA's slice elsewhere.  Module-level so a fleet worker process can
+    rebuild it by name from the job spec (search/fleet.py resolve_prefer)."""
     if op_name.startswith("xfer_"):
-        return next((c for c in choices if c.endswith(".rdma")), None)
+        return _first_choice(choices, (".rdma",))
     if op_name.startswith("unpack_"):
         hit = alias_unpack_choice(op_name, choices)
         if hit is not None:
             return hit
-    return next((c for c in choices if c.endswith(".xla")), None)
+    return _first_choice(choices, (".window", ".xla"))
 
 
 def moe_bf16_prefer(op_name, choices):
@@ -253,17 +263,15 @@ def _halo_incumbents(req, g, hargs, plat):
     _dirs = [_dn(d) for d in _DIRS]
 
     def mk_prefer(engine):
+        if engine == "alias":
+            return halo_alias_prefer
+
         def prefer(op_name, choices):
             if op_name.startswith("xfer_"):
                 i = _dirs.index(op_name.split("_", 1)[1])
-                want = {"host": ".host", "rdma": ".rdma",
-                        "alias": ".rdma"}.get(
+                want = {"host": ".host", "rdma": ".rdma"}.get(
                     engine, ".rdma" if i % 2 == 0 else ".host")
                 return next((c for c in choices if c.endswith(want)), None)
-            if engine == "alias" and op_name.startswith("unpack_"):
-                hit = alias_unpack_choice(op_name, choices)
-                if hit is not None:
-                    return hit
             return next((c for c in choices if c.endswith(".xla")), None)
 
         return prefer

@@ -170,12 +170,13 @@ class Pack(DeviceOp):
     program had static slices and no token edge).
 
     ``halo_pipeline.PackFlat`` (the one-chip twin: a tile-padded grid and a
-    dense flat staging buffer for a host round trip, where XLA's slice
-    already reads 0.55 ms a z face) keeps :meth:`_xla_slice` for every
-    face; the two needs conflict and the class tells them apart.  Its
-    subclasses that need static starts (the Pallas menu of
-    ops/halo_pallas.py) set ``INDEX_TIE = False`` and get the executor's
-    value-tied read."""
+    dense flat staging buffer for a host round trip; naive's pack and the
+    menu's ``.xla`` entry) keeps :meth:`_xla_slice` for every face.  Of its
+    subclasses, the menu of ops/halo_pallas.py, ``PackWindow`` reads a z
+    face with the same window kernel as this class and takes its token the
+    same way (PR 48: XLA's z slices were 4.1 of that cell's 10 ms); those
+    that need static starts (the window-DMA kernels) set ``INDEX_TIE =
+    False`` and get the executor's value-tied read."""
 
     INDEX_TIE = True
 
@@ -354,7 +355,9 @@ class Unpack(DeviceOp):
 
     Subclasses with a write of their own (``halo_pipeline.UnpackRecv`` and
     the kernel menu of ops/halo_pallas.py) override ``apply`` and declare
-    ``INDEX_TIE = False``: they keep the executor's value-tied read."""
+    ``INDEX_TIE = False``: they keep the executor's value-tied read.  The
+    menu's ``UnpackWindow`` (PR 48) is the exception: the same kernel as
+    here on the turned face its staging buffer holds, the token by index."""
 
     def __init__(self, args: HaloArgs, d: Tuple[int, int, int]):
         super().__init__(f"unpack_{dir_name(d)}")

@@ -56,6 +56,7 @@ from tenzing_tpu.models.halo import (
     HaloArgs,
     Pack,
     Unpack,
+    _face_axis,
     _face_slices,
     dir_name,
     sublane_tile,
@@ -85,6 +86,42 @@ def unflatten_face(flat, sizes):
     return flat.reshape(-1)[:n].reshape(tuple(sizes))
 
 
+def staged_sizes(d, sizes) -> Tuple[int, ...]:
+    """The extents a halo face of ``sizes`` (the shell's own ``(nq, sx, sy,
+    sz)``) has in its staging buffer, the ONE order every pack entry of
+    direction ``d`` writes and every unpack entry of it reads (the search
+    picks the two independently).  A lane-thin face, a z face with ``sz <
+    sy`` (the rule ``Pack`` and ``Unpack`` tell one by), is staged TURNED,
+    ``(nq, sx, sz, sy)``: the form ``pack_face_window`` writes and
+    ``unpack_face_window`` reads (ops/halo_pallas.py), so between the menu's
+    ``.window`` entries no XLA fusion ever sees a ``(.., sy, 3)`` face, which
+    the default layout pads 3 -> 128 lanes.  Every other face is staged as
+    it is."""
+    nq, sx, sy, sz = sizes
+    turned = _face_axis(d) == 3 and sz < sy
+    return (nq, sx, sz, sy) if turned else (nq, sx, sy, sz)
+
+
+def stage_face(face, d):
+    """A halo face in the shell's own shape -> direction ``d``'s (rows, 128)
+    staging buffer, in :func:`staged_sizes`' order."""
+    import jax.numpy as jnp
+
+    if staged_sizes(d, face.shape) != tuple(face.shape):
+        face = jnp.swapaxes(face, 2, 3)
+    return flatten_face(face, face.shape)
+
+
+def unstage_face(flat, d, sizes):
+    """Direction ``d``'s staging buffer -> the face in the shell's own
+    ``sizes`` (the inverse of :func:`stage_face`)."""
+    import jax.numpy as jnp
+
+    staged = staged_sizes(d, sizes)
+    face = unflatten_face(flat, staged)
+    return face if staged == tuple(sizes) else jnp.swapaxes(face, 2, 3)
+
+
 class PackFlat(Pack):
     """Pack that emits the face as a 128-lane-flattened (rows, 128) staging
     buffer.  Probed on both the CPU backend and TPU v5e: spilling a 4D face
@@ -95,10 +132,11 @@ class PackFlat(Pack):
     reliable for — which is also what the reference does with its staging
     buffers (contiguous pack buffers, ops_halo_exchange.hpp:97-186).
     The slice itself, and how it takes its ordering token (INDEX_TIE), are
-    the base class's ``_xla_slice``, for every face: the mesh halo's window
-    kernel for y and z faces (``Pack.apply``) writes a padded 4-D face for a
-    collective on an unpadded grid, this class a dense flat buffer for a
-    host round trip from a tile-padded one, where XLA's slice is fast.  See
+    the base class's ``_xla_slice``, for every face; the order the face has
+    in the buffer is :func:`stage_face`'s, a z face turned.  This is naive's
+    pack and the menu's ``.xla`` entry; the menu's ``.window`` entry
+    (ops/halo_pallas.py ``PackWindow``) reads a z face with the mesh halo's
+    window kernel and stages what the kernel wrote.  See
     :class:`tenzing_tpu.models.halo.Pack`."""
 
     def uses_pallas(self) -> bool:
@@ -106,27 +144,35 @@ class PackFlat(Pack):
 
     def apply(self, bufs, ctx):
         face = self._xla_slice(bufs, ctx)
-        return {f"buf_{dir_name(self._d)}": flatten_face(face, face.shape)}
+        return {f"buf_{dir_name(self._d)}": stage_face(face, self._d)}
 
 
 class UnpackRecv(Unpack):
-    """Unpack reading the fetched (round-tripped) flat staging buffer: reshape
-    back to the face extents, then one ``dynamic_update_slice`` into the
-    ghost shell whatever the face (the grid is tile-padded here, and the
-    kernels are the menu's: ops/halo_pallas.py ``UnpackChoice``), on the
-    executor's value-tied read."""
+    """Unpack reading the fetched (round-tripped) flat staging buffer:
+    :func:`unstage_face` back to the face extents, then one
+    ``dynamic_update_slice`` into the ghost shell whatever the face, on the
+    executor's value-tied read.  This is naive's unpack and the menu's
+    ``.xla`` entry; the kernels are the menu's (ops/halo_pallas.py
+    ``UnpackChoice``: the aliased window-DMA kernels on the tile-padded
+    grid and, for a z face, ``UnpackWindow``, which hands the staged face
+    to the mesh halo's window kernel as it is)."""
 
     INDEX_TIE = False
 
     def uses_pallas(self) -> bool:
         return False
 
+    def _face(self, bufs):
+        """(ghost-shell starts, the received face in the shell's shape)."""
+        starts, _ = _face_slices(self._args, self._d, "unpack")
+        _, sizes = _face_slices(self._args, self._d, "pack")
+        return starts, unstage_face(
+            bufs[f"recv_{dir_name(self._d)}"], self._d, sizes)
+
     def apply(self, bufs, ctx):
         import jax.lax as lax
 
-        starts, _ = _face_slices(self._args, self._d, "unpack")
-        _, sizes = _face_slices(self._args, self._d, "pack")
-        face = unflatten_face(bufs[f"recv_{dir_name(self._d)}"], sizes)
+        starts, face = self._face(bufs)
         return {"U": lax.dynamic_update_slice(bufs["U"], face, starts)}
 
 

@@ -45,9 +45,12 @@ window and the scalar-prefetch tie, ``pack_face_window``'s of why a
 lane-thin (z) face leaves its kernel transposed; since PR 47 it enters
 ``unpack_face_window`` the same way and is turned in VMEM, so between the
 two kernels a z face is 11 MB whatever XLA does with it, never the 308 MB
-of the shell's own shape in the default layout.  Neither is a menu entry:
-``Unpack`` and ``Pack`` pick them by the face's thin axis, and the one-chip
-menu's ``PackFlat`` keeps XLA's slice.
+of the shell's own shape in the default layout.  On the mesh ``Unpack`` and
+``Pack`` pick them by the face's thin axis.  On the one-chip menu they are
+the z faces' ``.window`` entries (``PackWindow``, ``UnpackWindow``, PR 48):
+a z face crosses its flat staging buffer turned, whichever entry wrote it
+(``halo_pipeline.staged_sizes``), so the pair hands it from kernel to
+kernel through two reshapes and the older entries through a ``swapaxes``.
 
 Off-TPU the kernels run in the Pallas interpreter (``interpret=True``), same
 code path as the repo's other Pallas kernels.
@@ -69,6 +72,7 @@ from tenzing_tpu.core.operation import ChoiceOp, OpBase
 from tenzing_tpu.models.halo import (
     HaloArgs,
     _face_slices,
+    _index_zero,
     dir_name,
     sublane_tile,
 )
@@ -76,8 +80,11 @@ from tenzing_tpu.models.halo_pipeline import (
     PackFlat,
     UnpackRecv,
     flatten_face,
+    stage_face,
+    staged_sizes,
     unflatten_face,
 )
+from tenzing_tpu.obs.metrics import get_metrics
 
 
 def _interpret() -> bool:
@@ -634,11 +641,11 @@ def unpack_face_window(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("starts", "sizes", "interpret")
+    jax.jit, static_argnames=("starts", "sizes", "turned", "interpret")
 )
 def pack_face_window(
     u: jax.Array, starts: Tuple[int, ...], sizes: Tuple[int, ...],
-    tok_zero: jax.Array, interpret: bool = False
+    tok_zero: jax.Array, turned: bool = False, interpret: bool = False
 ) -> jax.Array:
     """face[:, i] = u[:, x0+i, y0:y0+sy, z0:z0+sz] on a grid that is NOT
     tile-padded: the mirror of :func:`unpack_face_window`, built from the
@@ -662,7 +669,10 @@ def pack_face_window(
     0.4-0.8 ms.  The transposed face is 11 MB, the ``swapaxes`` a bitcast
     to a layout of XLA's choosing, and tie and relayout run on that.  The
     body turns each q's ``(sy, 128)`` block on the XLU and keeps the ``sz``
-    rows that are the edge."""
+    rows that are the edge.  With ``turned`` the lane-thin face is handed
+    on as the kernel wrote it, ``(nq, sx, sz, sy)``, the operand
+    ``unpack_face_window(..., turned=True)`` takes (the one-chip menu's
+    ``PackWindow`` stages it so)."""
     nq, sx, sy, sz = sizes
     _, x0, y0, z0 = starts
     _, _, Y, Z = u.shape
@@ -693,7 +703,7 @@ def pack_face_window(
         name="halo_window_pack",
         interpret=interpret,
     )(tok_zero.reshape(1), u)
-    return jnp.swapaxes(face, 2, 3) if lane_thin else face
+    return jnp.swapaxes(face, 2, 3) if lane_thin and not turned else face
 
 
 # -- ops + choice menu ------------------------------------------------------------
@@ -717,7 +727,7 @@ class PackPallas(PackFlat):
         out = pack_face_pallas(
             bufs["U"], tuple(starts), tuple(sizes), interpret=_interpret()
         )
-        return {f"buf_{dir_name(self._d)}": flatten_face(out, sizes)}
+        return {f"buf_{dir_name(self._d)}": stage_face(out, self._d)}
 
     def uses_pallas(self) -> bool:
         return True
@@ -766,7 +776,7 @@ class PackPallasB(PackFlat):
         out = pack_face_pallas_batched(
             bufs["U"], tuple(starts), tuple(sizes), interpret=_interpret()
         )
-        return {f"buf_{dir_name(self._d)}": flatten_face(out, sizes)}
+        return {f"buf_{dir_name(self._d)}": stage_face(out, self._d)}
 
     def uses_pallas(self) -> bool:
         return True
@@ -780,9 +790,7 @@ class UnpackPallas(UnpackRecv):
         self._name = f"unpack_{dir_name(d)}.pallas"
 
     def apply(self, bufs, ctx):
-        starts, _ = _face_slices(self._args, self._d, "unpack")
-        _, sizes = _face_slices(self._args, self._d, "pack")
-        face = unflatten_face(bufs[f"recv_{dir_name(self._d)}"], sizes)
+        starts, face = self._face(bufs)
         out = unpack_face_pallas(
             bufs["U"], face, tuple(starts), interpret=_interpret()
         )
@@ -792,14 +800,25 @@ class UnpackPallas(UnpackRecv):
         return True
 
 
+def _window_ok(args: HaloArgs, d) -> bool:
+    """Whether the ``.window`` pair is on direction ``d``'s menus: where the
+    face is staged turned (``halo_pipeline.staged_sizes``: a lane-thin z
+    face), the one form both window kernels share."""
+    _, sizes = _face_slices(args, d, "pack")
+    return staged_sizes(d, sizes) != tuple(sizes)
+
+
 def _flat_ok(args: HaloArgs, d) -> bool:
     """Whether the direct-flat kernels apply: the face's trailing dim must be
     lane-aligned (sz % 128 == 0) — that makes every block row-aligned in the
     (rows, 128) staging buffer AND keeps the in-kernel relayout a
     sublane-merge Mosaic can lower (probed on v5e: a 3-wide trailing dim —
-    z-faces — fails in the Mosaic relayout pass)."""
+    z-faces — fails in the Mosaic relayout pass).  The flat kernels write
+    and read the face's own order, so a face that is staged turned (a z
+    face a whole lane tile thick and thinner than y, which nobody runs) is
+    not theirs either."""
     _, sizes = _face_slices(args, d, "pack")
-    return sizes[3] % 128 == 0
+    return sizes[3] % 128 == 0 and not _window_ok(args, d)
 
 
 class PackPallasF(PackFlat):
@@ -858,12 +877,69 @@ class UnpackPallasB(UnpackRecv):
         self._name = f"unpack_{dir_name(d)}.pallasb"
 
     def apply(self, bufs, ctx):
-        starts, _ = _face_slices(self._args, self._d, "unpack")
-        _, sizes = _face_slices(self._args, self._d, "pack")
-        face = unflatten_face(bufs[f"recv_{dir_name(self._d)}"], sizes)
+        starts, face = self._face(bufs)
         out = unpack_face_pallas_batched(
             bufs["U"], face, tuple(starts), interpret=_interpret()
         )
+        return {"U": out}
+
+    def uses_pallas(self) -> bool:
+        return True
+
+
+class PackWindow(PackFlat):
+    """Pack a lane-thin (z) face with the mesh halo's window kernel
+    (:func:`pack_face_window`: one 128-lane tile column of the grid read
+    once) and stage the TURNED face the kernel wrote: ``(nq, sx, sz, sy)``
+    is whole rows of 128 lanes at the flagship's size, so the staging buffer
+    is a reshape of it.  ``INDEX_TIE`` stays on (inherited): the token is
+    the kernel's scalar-prefetch operand and the 2.07 GB grid is only read,
+    which is what sets this entry apart from the value-tied Pallas packs
+    above (a full pass over the grid and a new version of it a pack)."""
+
+    def __init__(self, args: HaloArgs, d):
+        super().__init__(args, d)
+        self._name = f"pack_{dir_name(d)}.window"
+
+    def apply(self, bufs, ctx):
+        starts, sizes = _face_slices(self._args, self._d, "pack")
+        z = _index_zero(self, ctx)
+        get_metrics().counter("halo.window_packs").inc()
+        out = pack_face_window(
+            bufs["U"], tuple(starts), tuple(sizes), z, turned=True,
+            interpret=_interpret())
+        return {f"buf_{dir_name(self._d)}": flatten_face(out, out.shape)}
+
+    def uses_pallas(self) -> bool:
+        return True
+
+
+class UnpackWindow(UnpackRecv):
+    """Unpack a lane-thin (z) face with the mesh halo's aliased window
+    kernel (:func:`unpack_face_window`, ``turned=True``: the tile column
+    that holds the shell read and written once, the face turned in VMEM).
+    The staged face is the kernel's operand as it is, a reshape of
+    ``recv_<d>``; the token goes in by index, the kernel's scalar-prefetch
+    operand, so the received face gets no value-add."""
+
+    INDEX_TIE = True
+
+    def __init__(self, args: HaloArgs, d):
+        super().__init__(args, d)
+        self._name = f"unpack_{dir_name(d)}.window"
+
+    def apply(self, bufs, ctx):
+        starts, _ = _face_slices(self._args, self._d, "unpack")
+        _, sizes = _face_slices(self._args, self._d, "pack")
+        z = _index_zero(self, ctx)
+        reg = get_metrics()
+        reg.counter("halo.window_unpacks").inc()
+        reg.counter("halo.window_unpacks_turned").inc()
+        face = unflatten_face(bufs[f"recv_{dir_name(self._d)}"],
+                              staged_sizes(self._d, sizes))
+        out = unpack_face_window(
+            bufs["U"], face, tuple(starts), z, turned=True,
+            interpret=_interpret())
         return {"U": out}
 
     def uses_pallas(self) -> bool:
@@ -886,6 +962,8 @@ class PackChoice(ChoiceOp):
             menu.append(PackPallasB(self._args, self._d))
         if _flat_ok(self._args, self._d):
             menu.append(PackPallasF(self._args, self._d))
+        if _window_ok(self._args, self._d):
+            menu.append(PackWindow(self._args, self._d))
         return menu
 
 
@@ -902,4 +980,6 @@ class UnpackChoice(ChoiceOp):
             menu.append(UnpackPallasB(self._args, self._d))
         if _flat_ok(self._args, self._d):
             menu.append(UnpackPallasF(self._args, self._d))
+        if _window_ok(self._args, self._d):
+            menu.append(UnpackWindow(self._args, self._d))
         return menu

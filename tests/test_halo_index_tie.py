@@ -27,7 +27,11 @@ from tenzing_tpu.models.halo import (
     engine_overlap_order,
     make_halo_buffers,
 )
-from tenzing_tpu.models.halo_pipeline import PackFlat, flatten_face
+from tenzing_tpu.models.halo_pipeline import (
+    PackFlat,
+    flatten_face,
+    stage_face,
+)
 from tenzing_tpu.obs.metrics import get_metrics
 from tenzing_tpu.runtime.executor import TraceExecutor
 
@@ -235,19 +239,24 @@ def test_pack_traced_outside_the_contract_raises(cls):
 
 
 def _packflat_up_to_pr28(args, d, bufs, ctx):
-    """``PackFlat.apply`` as it stood before the slice moved to ``Pack``."""
+    """``PackFlat.apply`` as it stood before the slice moved to ``Pack``;
+    since ISSUE 48 a lane-thin z face is turned before it is flattened
+    (the one staging order of its direction)."""
     starts, sizes = _face_slices(args, d, "pack")
     z = ctx.tok_index_zero
     axis = 1 + [i for i, v in enumerate(d) if v != 0][0]
     starts = tuple(s + z if i == axis else s for i, s in enumerate(starts))
     sl = jax.lax.dynamic_slice(bufs["U"], starts, sizes)
-    return {f"buf_{dir_name(d)}": flatten_face(sl, sizes)}
+    if d[2]:
+        sl = jnp.swapaxes(sl, 2, 3)
+    return {f"buf_{dir_name(d)}": flatten_face(sl, sl.shape)}
 
 
 @pytest.mark.parametrize("d", DIRECTIONS, ids=DIR_IDS)
 def test_packflat_traces_as_before(d):
     """(d) the one-chip flagship's pack is the program it was: the same
-    jaxpr, equation for equation (``halo512.climb`` runs it)."""
+    jaxpr, equation for equation (``halo512.climb``'s naive runs it), a z
+    face with the staging order's one ``swapaxes`` before the flatten."""
     u = jnp.zeros(ARGS.local_shape(), jnp.float32)
     z = jnp.zeros((), jnp.int32)
 
@@ -264,8 +273,8 @@ def test_packflat_traces_as_before(d):
 
 @pytest.mark.parametrize("d", DIRECTIONS, ids=DIR_IDS)
 def test_pack_and_packflat_share_one_slice(d):
-    """The mesh pack's face is the flat pack's before flattening, to the
-    bit, at a token's zero as at a plain one."""
+    """The mesh pack's face is the flat pack's before staging, to the bit,
+    at a token's zero as at a plain one."""
     rng = np.random.default_rng(5)
     u = jnp.asarray(rng.random(ARGS.local_shape(), dtype=np.float32))
     ctx = SimpleNamespace(tok_index_zero=jnp.zeros((), jnp.int32))
@@ -273,7 +282,7 @@ def test_pack_and_packflat_share_one_slice(d):
     face = Pack(ARGS, d).apply({"U": u}, ctx)[name]
     flat = PackFlat(ARGS, d).apply({"U": u}, ctx)[name]
     _, sizes = _face_slices(ARGS, d, "pack")
-    np.testing.assert_array_equal(np.asarray(flatten_face(face, sizes)),
+    np.testing.assert_array_equal(np.asarray(stage_face(face, d)),
                                   np.asarray(flat))
     starts, _ = _face_slices(ARGS, d, "pack")
     want = np.asarray(u)[tuple(slice(s, s + n)

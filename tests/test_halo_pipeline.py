@@ -244,10 +244,16 @@ def test_batched_variant_on_menu_only_when_it_differs():
     assert _face_bx(args, (0, 1, 0)) > 1
     assert _face_bx(args, (0, 0, 1)) > 1
     # x: xla + pallas + pallasf (bx=1 keeps pallasb off); y: all four;
-    # z: xla + pallas + pallasb (lane gate keeps pallasf off)
+    # z: xla + pallas + pallasb (lane gate keeps pallasf off) + window
+    # (ISSUE 48: a lane-thin face's own pair, behind the older entries)
     assert len(PackChoice(args, (1, 0, 0)).choices()) == 3
     assert len(PackChoice(args, (0, 1, 0)).choices()) == 4
-    assert len(UnpackChoice(args, (0, 0, 1)).choices()) == 3
+    assert [c.name() for c in UnpackChoice(args, (0, 0, 1)).choices()] == [
+        "unpack_pz.xla", "unpack_pz.pallas", "unpack_pz.pallasb",
+        "unpack_pz.window"]
+    assert [c.name() for c in PackChoice(args, (0, 0, -1)).choices()] == [
+        "pack_mz.xla", "pack_mz.pallas", "pack_mz.pallasb",
+        "pack_mz.window"]
 
 
 @pytest.mark.needs_pinned_host

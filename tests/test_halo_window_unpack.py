@@ -16,6 +16,11 @@ Since ISSUE 47 a z face reaches the kernel TURNED, ``(nq, sx, sz, sy)``, the
 form ``pack_face_window`` emits, and the body turns it in VMEM: the padded
 ``(nq, sx, sy, 3)`` operand (308 MB for 7 MB of cells at the cell's size) is
 no operand of the kernel any more.
+
+Since ISSUE 48 the one-chip twin's z faces have the kernel on their menu
+(``unpack_<d>.window``, on the turned face its staging buffer holds:
+tests/test_halo_window_pack.py has the pair, the menus and the start
+point's counters); its older unpacks read a z face through one ``swapaxes``.
 """
 
 from types import SimpleNamespace
@@ -192,10 +197,11 @@ ONE_CHIP_VALUE_TIED_BYTES = 3072
 
 @pytest.mark.needs_pinned_host
 def test_one_chip_body_counts_no_window_unpack():
-    """``halo512.climb``'s graph (``halo_pipeline``: ``UnpackRecv`` and the
-    kernel menu) never reaches ``Unpack.apply``: no window unpack, turned
-    or not, the six packs' index ties, and the value-tied bytes the parent
-    read."""
+    """Naive of ``halo512.climb``'s graph (``halo_pipeline``: ``UnpackRecv``,
+    no menu) never reaches ``Unpack.apply``: no window unpack, turned or
+    not, the six packs' index ties, and the value-tied bytes the parent
+    read.  (The menu's start point counts two, turned:
+    tests/test_halo_window_pack.py.)"""
     from tenzing_tpu.models.halo_pipeline import (
         host_buffer_names,
         make_pipeline_buffers,
@@ -217,11 +223,20 @@ def test_one_chip_body_counts_no_window_unpack():
 # -- subclasses with a write of their own trace as before ----------------------
 
 
+def _unstaged(recv, d, sizes):
+    """The staging order spelled out: every face unflattened as it was up
+    to PR 47, a z face from its turned extents and turned back."""
+    if not d[2]:
+        return unflatten_face(recv, sizes)
+    nq, sx, sy, sz = sizes
+    return jnp.swapaxes(unflatten_face(recv, (nq, sx, sz, sy)), 2, 3)
+
+
 def _unpackrecv_up_to_pr31(args, d, bufs):
     """``UnpackRecv.apply`` as it stands since PR 9: unflatten, one update."""
     starts, _ = _face_slices(args, d, "unpack")
     _, sizes = _face_slices(args, d, "pack")
-    face = unflatten_face(bufs[f"recv_{dir_name(d)}"], sizes)
+    face = _unstaged(bufs[f"recv_{dir_name(d)}"], d, sizes)
     return {"U": jax.lax.dynamic_update_slice(bufs["U"], face, starts)}
 
 
@@ -233,7 +248,7 @@ def _kernel_up_to_pr31(kernel, flat: bool):
         if flat:
             return {"U": kernel(bufs["U"], recv, tuple(starts), tuple(sizes),
                                 interpret=True)}
-        return {"U": kernel(bufs["U"], unflatten_face(recv, sizes),
+        return {"U": kernel(bufs["U"], _unstaged(recv, d, sizes),
                             tuple(starts), interpret=True)}
 
     return then
@@ -254,17 +269,24 @@ SUBCLASSES = [
 ]
 
 
-@pytest.mark.parametrize("name,cls,then,pallas", SUBCLASSES,
-                         ids=[s[0] for s in SUBCLASSES])
-def test_subclasses_with_their_own_write_trace_as_before(name, cls, then,
-                                                         pallas):
-    """The one-chip flagship's unpacks (``halo512.climb`` runs them) are
-    the programs they were: the same jaxpr, equation for equation, on a y
-    face (which ``Unpack`` itself now hands to the window kernel), on the
-    executor's value-tied read and with ``uses_pallas`` as it was."""
+# a y face each, and a z face where the entry is on its menu
+ON_FACES = [(s, d) for s in SUBCLASSES for d in [(0, 1, 0), (0, 0, -1)]
+            if not (s[0] == "UnpackPallasF" and d[2])]
+
+
+@pytest.mark.parametrize(
+    "sub,d", ON_FACES, ids=[
+        s[0] + ("" if d[1] else "-" + dir_name(d)) for s, d in ON_FACES])
+def test_subclasses_with_their_own_write_trace_as_before(sub, d):
+    """The one-chip flagship's older unpacks (``halo512.climb``'s menus hold
+    them) are the programs they were: the same jaxpr, equation for
+    equation, on a y face (which ``Unpack`` itself now hands to the window
+    kernel), on the executor's value-tied read and with ``uses_pallas`` as
+    it was; on a z face with the staging order's one ``swapaxes`` between
+    the unflatten it always was and the write it always was (ISSUE 48)."""
     from tenzing_tpu.models.halo_pipeline import _flat_rows, _padded_shape
 
-    d = (0, 1, 0)
+    name, cls, then, pallas = sub
     op = cls()(MENU_ARGS, d)
     assert not op.INDEX_TIE
     assert op.uses_pallas() is pallas
@@ -279,5 +301,6 @@ def test_subclasses_with_their_own_write_trace_as_before(name, cls, then,
     def before(u, recv):
         return then(MENU_ARGS, d, {"U": u, f"recv_{dir_name(d)}": recv})
 
-    assert str(jax.make_jaxpr(now)(u, recv)) == str(
-        jax.make_jaxpr(before)(u, recv))
+    text = str(jax.make_jaxpr(now)(u, recv))
+    assert text == str(jax.make_jaxpr(before)(u, recv))
+    assert text.count(" transpose[") == (1 if d[2] else 0)

@@ -10,7 +10,8 @@ or times, and a compile that passes is not a chip run.
 Covered, at the shapes ``python bench.py`` builds without ``--smoke``:
 
 * every halo kernel of ops/halo_pallas.py on every face it is on the menu
-  for (window 6+6, batched 4+4, flat 4+4), grid ``(3, 518, 520, 640)`` f32;
+  for (window 6+6, batched 4+4, flat 4+4, the z faces' ``.window`` pair
+  2+2), grid ``(3, 518, 520, 640)`` f32;
 * the fused and the split (semaphore-output) rdma copy of ops/rdma.py;
 * the moe/attention/spmv kernels;
 * the SpMV column sweep at the source's 150 000 rows and the whole naive
@@ -19,7 +20,8 @@ Covered, at the shapes ``python bench.py`` builds without ``--smoke``:
 * two whole halo schedule programs (the naive baseline and the
   ``greedy-alias-6l`` incumbent), both as the single-shot program the
   integrity gate runs and as the repeat-n benchmark program, with the
-  pinned_host staging buffers and the 2 GB grid carried through ``fori_loop``;
+  pinned_host staging buffers and the 2 GB grid carried through ``fori_loop``,
+  and who owns what in the incumbent's loop;
 * the models/halo.py mesh exchange on the four described chips, both
   transfer engines (XLA collective-permute, remote DMA with barriers), and
   its window unpack and window pack on the cell's unpadded ``(3, 454, 454,
@@ -225,7 +227,8 @@ def test_flat_unpack(one_chip, d):
 
 def test_menus_offer_exactly_the_compiled_kernels():
     """The cases above are the flagship menus: 6+6 window, 4+4 batched,
-    4+4 flat — a menu that grows a kernel must grow a compile case."""
+    4+4 flat — a menu that grows a kernel must grow a compile case (the z
+    faces' 2+2 ``.window`` entries: ``test_window_pair_on_the_padded_grid``)."""
     from tenzing_tpu.ops.halo_pallas import PackChoice, UnpackChoice
 
     def suffixes(choice_cls):
@@ -234,7 +237,7 @@ def test_menus_offer_exactly_the_compiled_kernels():
                       for c in choice_cls(FLAGSHIP, d).choices())
 
     want = sorted(["xla"] * 6 + ["pallas"] * 6 + ["pallasb"] * 4
-                  + ["pallasf"] * 4)
+                  + ["pallasf"] * 4 + ["window"] * 2)
     assert suffixes(PackChoice) == want
     assert suffixes(UnpackChoice) == want
 
@@ -1020,7 +1023,8 @@ def _halo_schedule(which):
         plat = Platform.make_n_lanes(1)
         return plat, naive_order(FLAGSHIP, plat)
     # greedy-alias-6l, as bench/driver.py builds it: all-rdma transfers,
-    # aliased Pallas unpacks, on the kernel + engine choice graph
+    # aliased Pallas unpacks, the z faces through the window pair, on the
+    # kernel + engine choice graph
     plat = Platform.make_n_lanes(6)
     g = build_graph(FLAGSHIP, impl_choice=True, xfer_choice=True)
     seq, _ = drive(g, plat, phase_policy(plat, HALO_PHASES,
@@ -1028,12 +1032,13 @@ def _halo_schedule(which):
     return plat, seq
 
 
-@pytest.mark.parametrize("which,n_kernels", [("naive", 0), ("alias", 18)])
+@pytest.mark.parametrize("which,n_kernels", [("naive", 0), ("alias", 20)])
 def test_whole_halo_program(topo, one_chip, on_chip_kernels, which,
                             n_kernels):
     """One whole schedule at 512^3: the single-shot program and the repeat-n
     benchmark program compile, keep their kernels (alias: 6 aliased unpacks +
-    6 rdma posts + 6 rdma waits) and fit the chip's memory."""
+    6 rdma posts + 6 rdma waits + the z faces' 2 window packs, ISSUE 48) and
+    fit the chip's memory."""
     from jax.sharding import SingleDeviceSharding
 
     host = SingleDeviceSharding(topo.devices[0], memory_kind="pinned_host")
@@ -1048,6 +1053,83 @@ def test_whole_halo_program(topo, one_chip, on_chip_kernels, which,
         assert (m.argument_size_in_bytes + m.output_size_in_bytes
                 + m.temp_size_in_bytes) < HBM_BYTES
         assert m.host_output_size_in_bytes > 0  # the staging set is host's
+
+
+Z_FACES = [d for d in DIRECTIONS if d[2] != 0]
+
+
+@pytest.mark.parametrize("d", Z_FACES, ids=[dir_name(d) for d in Z_FACES])
+def test_window_pair_on_the_padded_grid(one_chip, on_chip_kernels, d):
+    """ISSUE 48: the one-chip menu's ``pack_<d>.window`` and
+    ``unpack_<d>.window`` on the flagship's tile-padded ``(3, 518, 520,
+    640)``.  Mosaic takes both window kernels there as they stand (a block
+    of ``(3, ., 520, 128)``: the whole y axis, tile column 0 or 4 of 5).
+    The face between kernel and staging buffer is ``(3, 512, 3, 512)`` at 4
+    sublanes for its 3, a reshape of the ``(18432, 128)`` buffer: nothing
+    of the padded ``f32[3,512,512,3]`` is made on either side."""
+    from types import SimpleNamespace
+
+    from tenzing_tpu.ops.halo_pallas import PackWindow, UnpackWindow
+
+    name = dir_name(d)
+    ctx = lambda z: SimpleNamespace(tok_index_zero=z)
+    flat = _sds((18432, 128), jnp.float32, one_chip)
+    zero = _sds((), jnp.int32, one_chip)
+    packed = jax.jit(lambda u, z: PackWindow(FLAGSHIP, d).apply(
+        {"U": u}, ctx(z))).lower(_grid(one_chip), zero).compile()
+    unpacked = jax.jit(
+        lambda u, r, z: UnpackWindow(FLAGSHIP, d).apply(
+            {"U": u, f"recv_{name}": r}, ctx(z)),
+        donate_argnums=0).lower(_grid(one_chip), flat, zero).compile()
+    for compiled in (packed, unpacked):
+        _assert_kernel(compiled)
+        text = compiled.as_text()
+        assert "f32[3,512,3,512]{3,2,1,0:T(4,128)" in text
+        assert "f32[3,512,512,3]" not in text
+    assert "f32[18432,128]" in packed.as_text()
+    # in place: the grid is the only large buffer the unpack's program holds
+    assert unpacked.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_start_point_loop_owns_its_z_faces(topo, one_chip, on_chip_kernels):
+    """ISSUE 48, step 0: the repeat-n loop of the climb's start point at
+    512^3, by the executor's scopes.  Each z pack is one
+    ``halo_window_pack`` and one reshape of its own, each z unpack one
+    reshape and one ``halo_window_unpack`` aliased on the grid, every other
+    instruction of theirs a scalar (the index tie, the join).  Nothing in
+    the body but the six aliased unpack kernels produces a grid; no
+    instruction of it is a ``copy`` of one, an add onto one, or makes a
+    z-minor ``(.., 512, 3)`` face (the parent's body made four, 403 MB
+    each)."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    from tenzing_tpu.obs.attrib.hlo import loop_ops_by_scope
+    from tenzing_tpu.runtime.executor import TraceExecutor
+
+    host = SingleDeviceSharding(topo.devices[0], memory_kind="pinned_host")
+    shapes = _pipeline_shapes(FLAGSHIP)
+    bufs = {k: _sds(s, jnp.float32, host if is_host else one_chip)
+            for k, (s, is_host) in shapes.items()}
+    host_names = {k for k, (_, is_host) in shapes.items() if is_host}
+    plat, seq = _halo_schedule("alias")
+    *_, compiled = _both_programs(plat, seq, bufs, host_names, one_chip)
+    ops = loop_ops_by_scope(compiled.as_text())
+    large = [o for o in ops if o.bytes >= 2**20]
+    for d in Z_FACES:
+        n = dir_name(d)
+        assert [(o.opcode, o.name.split(".")[0]) for o in large
+                if o.vertex == f"pack_{n}.window"] == [
+            ("custom-call", "halo_window_pack"), ("reshape", "reshape")]
+        assert [(o.opcode, o.name.split(".")[0]) for o in large
+                if o.vertex == f"unpack_{n}.window"] == [
+            ("reshape", "reshape"), ("custom-call", "halo_window_unpack")]
+    grids = [o for o in ops if "f32[3,518,520,640]" in o.result]
+    assert [o.opcode for o in grids] == ["custom-call"] * 6
+    assert sorted(o.vertex for o in grids) == sorted(
+        op.name() for op in seq.vector() if op.name().startswith("unpack_"))
+    assert not [o for o in ops if re.search(r",512,3\]", o.result)]
 
 
 # -- the mesh exchange on four chips ------------------------------------------

@@ -167,6 +167,62 @@ def test_full_size_climbs_per_row():
     assert WORKLOADS["spmv"].phases() == ("",)
 
 
+# what ``halo_alias_prefer`` picks, op by op: every transfer on the chip's DMA
+# engine, the aliased unpack kernel of each face axis, a z face through the
+# window pair (ISSUE 48), XLA's slice for the x and y packs
+ALIAS_RECIPE = {
+    "pack_px": ".xla", "pack_mx": ".xla", "pack_py": ".xla",
+    "pack_my": ".xla", "pack_pz": ".window", "pack_mz": ".window",
+    "unpack_px": ".pallas", "unpack_mx": ".pallas",
+    "unpack_py": ".pallasf", "unpack_my": ".pallasf",
+    "unpack_pz": ".window", "unpack_mz": ".window",
+    **{f"xfer_{s}{a}": ".rdma" for s in "pm" for a in "xyz"}}
+
+
+def _halo_menus(hargs):
+    from tenzing_tpu.models.halo_pipeline import build_graph
+
+    g = build_graph(hargs, impl_choice=True, xfer_choice=True)
+    return {op.name(): [c.name() for c in op.choices()]
+            for op in g.vertices() if hasattr(op, "choices")}
+
+
+@pytest.mark.parametrize("op_name", sorted(ALIAS_RECIPE))
+def test_halo_alias_prefer_per_op(op_name):
+    """The climb's start point on the flagship's menus, one case an op."""
+    from tenzing_tpu.models.halo import HaloArgs
+
+    menus = _halo_menus(HaloArgs(nq=3, lx=512, ly=512, lz=512, radius=3))
+    assert sorted(menus) == sorted(ALIAS_RECIPE)
+    assert workloads.halo_alias_prefer(op_name, menus[op_name]) == (
+        op_name + ALIAS_RECIPE[op_name])
+
+
+def test_halo_alias_prefer_without_the_window_pair():
+    """A grid whose z face is not lane-thin (thicker than y is long) has no
+    ``.window`` entry: the z faces fall back to the recipe up to PR 47
+    (``.xla`` packs, ``.pallasb`` unpacks where the batched kernel is on
+    the menu, else XLA's), everything else as on the flagship."""
+    from tenzing_tpu.models.halo import HaloArgs
+
+    menus = _halo_menus(HaloArgs(nq=3, lx=64, ly=2, lz=512, radius=3))
+    assert not any(c.endswith(".window") for m in menus.values() for c in m)
+    for op_name, menu in menus.items():
+        want = ALIAS_RECIPE[op_name]
+        if op_name[-1] == "z" and not op_name.startswith("xfer_"):
+            want = ".pallasb" if (
+                op_name.startswith("unpack_")
+                and op_name + ".pallasb" in menu) else ".xla"
+        elif op_name + want not in menu:
+            want = ".xla"  # a y menu without the flat kernel
+        assert workloads.halo_alias_prefer(op_name, menu) == op_name + want
+    assert workloads.halo_alias_prefer(
+        "unpack_pz", ["unpack_pz.xla", "unpack_pz.pallas",
+                      "unpack_pz.pallasb"]) == "unpack_pz.pallasb"
+    assert workloads.alias_unpack_choice(
+        "unpack_pz", ["unpack_pz.xla"]) is None
+
+
 def test_a_recorded_schedule_seeds_the_first_climb():
     """With a recorded winner the first climb replicates its menu choices on
     its lane count, with a third (halo) or half (moe) of the budget."""
