@@ -47,6 +47,14 @@ group, no mask, ``q_block=None``: every query in one chain whose state comes
 from the buffers, as the ring's does) are the shape this module had before
 it met a model, on the same code path: there the state is handed on, and
 one :class:`FinalizeAttn` ends the layer.
+
+Packed prompts (``segments``: the first row of each prompt of a packed
+batch, ascending from 0; needs ``causal``): a row sees the keys of its own
+prompt at or before it.  :func:`visible_pairs`, :func:`mask_crosses` and so
+:func:`tile_plan` count it: a K/V block that lies wholly in other prompts
+than a query block's is not in the graph, and a block a prompt's start
+crosses is masked.  The kernels take the starts beside the positions
+(scalar-prefetched; ops/attention_pallas.py).
 """
 
 from __future__ import annotations
@@ -76,8 +84,18 @@ class RingAttnArgs:
     # rows of a query block (BlockedAttention); None: every query in one chain
     # whose state is read from the buffers
     q_block: Optional[int] = None
+    # the first row of each prompt of a packed batch (ascending, the first
+    # 0); None: one prompt
+    segments: Optional[Tuple[int, ...]] = None
 
     def __post_init__(self):
+        if self.segments is not None:
+            starts = tuple(int(s) for s in self.segments)
+            if not self.causal or not starts or starts[0] != 0 or any(
+                    b <= a for a, b in zip(starts, starts[1:])):
+                raise ValueError(f"segments {starts}: ascending first rows "
+                                 "from 0, under causal=True")
+            object.__setattr__(self, "segments", starts)
         if self.heads % self.kv_heads:
             raise ValueError(f"{self.heads} query heads do not group over "
                              f"{self.kv_heads} key/value heads")
@@ -152,6 +170,10 @@ class AttnStep(DeviceOp):
             seen = kpos <= qpos
             if a.window is not None:
                 seen = seen & (kpos > qpos - a.window)
+            if a.segments:
+                from tenzing_tpu.ops.attention_pallas import segment_start
+
+                seen = seen & (kpos >= segment_start(a.segments, qpos))
             s_ = jnp.where(seen, s_, NEG)
         m_blk = jnp.max(s_, axis=2, keepdims=True)  # (b, n, 1)
         m_new = jnp.maximum(m, jnp.broadcast_to(m_blk, m.shape))
@@ -204,10 +226,12 @@ class AttnStepPallas(AttnStep):
         # have 53 folds of 9 kinds: PERF.md, PR 33)
         masked = a.causal and mask_crosses(a, q_pos, q.shape[1], k_pos,
                                            k.shape[1])
+        packed = {"segments": tuple(s - k_pos for s in a.segments)} if (
+            masked and a.segments) else {}
         return attn_block_pallas(
             q, k, v, *(state or (None,) * 3), a.scale,
             q_pos=q_pos - k_pos if masked else 0, causal=masked,
-            window=a.window if masked else None)
+            window=a.window if masked else None, **packed)
 
     def uses_pallas(self) -> bool:
         return True
@@ -342,7 +366,16 @@ def mask_crosses(args: RingAttnArgs, q0: int, rows: int, k0: int,
         return False
     if k0 + keys - 1 > q0:
         return True
+    if args.segments and k0 < _segment_starts(args, q0 + rows - 1):
+        return True  # some row's prompt starts beyond the first key
     return args.window is not None and k0 <= q0 + rows - 1 - args.window
+
+
+def _segment_starts(args: RingAttnArgs, rows):
+    """The first row of the prompt of each of ``rows`` (ints or an array)."""
+    from tenzing_tpu.ops.attention_pallas import segment_start
+
+    return segment_start(args.segments, np.asarray(rows), np.where)
 
 
 def visible_pairs(args: RingAttnArgs, q0: int, rows: int, k0: int,
@@ -351,6 +384,8 @@ def visible_pairs(args: RingAttnArgs, q0: int, rows: int, k0: int,
     i = np.arange(q0, q0 + rows, dtype=np.int64)
     hi = np.minimum(i, k0 + keys - 1) if args.causal else k0 + keys - 1
     lo = k0 if args.window is None else np.maximum(i - args.window + 1, k0)
+    if args.segments:
+        lo = np.maximum(lo, _segment_starts(args, i))
     return int(np.maximum(hi - lo + 1, 0).sum())
 
 
@@ -554,7 +589,8 @@ class BlockAttnStepPallas(BlockAttnStep):
         from tenzing_tpu.ops.attention_pallas import computed_pairs
 
         a, qb = self._args, self._qb
-        return computed_pairs(qb.rows, keys, qb.q0, k0, a.causal, a.window)
+        return computed_pairs(qb.rows, keys, qb.q0, k0, a.causal, a.window,
+                              segments=a.segments)
 
     def uses_pallas(self) -> bool:
         return True
@@ -743,9 +779,11 @@ class FusedBlockAttn(DeviceOp):
         # the positions enter as their difference (AttnStepPallas._update)
         mask = dict(bkv=bkv, q_pos=qb.q0 - k0, causal=a.causal,
                     window=a.window, tok=tok, **at)
+        if a.segments:  # in the positions' coordinates
+            mask["segments"] = tuple(s - k0 for s in a.segments)
         note_tiles(a, qb, qb.blocks,
                    computed_pairs(qb.rows, keys, qb.q0, k0, a.causal,
-                                  a.window, bkv=bkv),
+                                  a.window, bkv=bkv, segments=a.segments),
                    self._first)
         if self._first:
             note_finish()

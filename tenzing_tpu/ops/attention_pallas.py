@@ -77,6 +77,16 @@ diagonal or the window's edge crosses build the iota comparison.  A row
 whose every key in a tile is masked stays finite: ``m`` starts at -1e30,
 not -inf, and a masked ``p`` is set to zero, not to ``exp(0)``.
 
+Packed prompts (``segments``, with ``causal``): the rows are several
+prompts one after another, and a row sees the keys of its own prompt alone,
+from the prompt's first row (its *segment start*) to its own.  The starts
+are scalar-prefetched behind the two positions, in their coordinates; a
+row's start is the largest that is not beyond it (a few compares and
+selects, on the scalar core for a tile's first and last row, on a column of
+the tile for the mask).  A query tile walks from the tile that holds its
+first row's start, and a tile is an edge tile too where some row's start
+lies beyond its first key.
+
 ``acc``/``m``/``l`` handed as ``None`` starts from the empty
 state instead of reading one: the first fold of a chain, which makes an
 iteration leave the state one iteration leaves.
@@ -183,10 +193,25 @@ class _Plan:
     lead0: int = 0
     tiles: Tuple[int, ...] = ()
     tile0: int = 0
+    segs: int = 0  # segment starts prefetched behind the two positions
+
+
+def segment_start(starts, pos, pick=jnp.where):
+    """The largest of the ascending ``starts`` that is not beyond ``pos``
+    (the first where none is): the first row of ``pos``'s prompt.  On
+    traced values, or on Python ints with ``pick=pick_int``."""
+    out = starts[0]
+    for s in starts[1:]:
+        out = pick(pos >= s, s, out)
+    return out
+
+
+def pick_int(cond, a, b):
+    return a if cond else b
 
 
 def visible_tiles(plan: _Plan, q_lo, k_pos, larger=jnp.maximum,
-                  smaller=jnp.minimum):
+                  smaller=jnp.minimum, starts=(), pick=jnp.where):
     """``(first, last)`` K/V tile of the operand that holds a key visible to
     the query tile whose first row is at position ``q_lo`` (``last < first``
     where there is none).  On traced scalars inside the kernel and its index
@@ -195,6 +220,10 @@ def visible_tiles(plan: _Plan, q_lo, k_pos, larger=jnp.maximum,
     first, last = 0, plan.kv_tiles - 1
     if plan.window is not None:
         first = larger(q_lo - plan.window + 1 - k_pos, 0) // plan.bkv
+    if len(starts):
+        # nothing before the prompt of the tile's first row
+        first = larger(first, larger(
+            segment_start(starts, q_lo, pick) - k_pos, 0) // plan.bkv)
     if plan.causal:
         last = smaller((q_lo + plan.bq - 1 - k_pos) // plan.bkv, last)
     return first, last
@@ -217,15 +246,17 @@ def walk_step(plan: _Plan, first, last, t):
 
 def computed_pairs(rows: int, keys: int, q_pos: int, k_pos: int,
                    causal: bool, window: Optional[int], bq: int = Q_TILE,
-                   bkv: int = KV_TILE) -> int:
+                   bkv: int = KV_TILE, segments=None) -> int:
     """(query, key) pairs a call of the kernel computes for one head, masked
     ones included: its (query tile, K/V tile) steps that hold a visible key,
     whole (the program's ``attn.pairs_computed``)."""
     bq, bkv = min(rows, bq), min(bkv, keys)
     plan = _Plan(0.0, bq, bkv, keys // bkv, 0, causal, window, False)
+    starts = tuple(segments or ())
     tiles = 0
     for j in range(-(-rows // bq)):
-        first, last = visible_tiles(plan, q_pos + j * bq, k_pos, max, min)
+        first, last = visible_tiles(plan, q_pos + j * bq, k_pos, max, min,
+                                    starts, pick_int)
         tiles += max(0, last - first + 1)
     return tiles * bq * bkv
 
@@ -279,7 +310,8 @@ def _flash_kernel(plan: _Plan, offs, *refs):
         k_lo = tile * plan.bkv
     else:
         q_lo = offs[0] + j * plan.bq
-        first, last = visible_tiles(plan, q_lo, offs[1])
+        starts = [offs[2 + i] for i in range(plan.segs)]
+        first, last = visible_tiles(plan, q_lo, offs[1], starts=starts)
         walked, live = walk_step(plan, first, last, t)
         k_lo = offs[1] + (first + walked) * plan.bkv
 
@@ -306,6 +338,11 @@ def _flash_kernel(plan: _Plan, offs, *refs):
             seen = kpos <= qpos
             if plan.window is not None:
                 seen = seen & (kpos > qpos - plan.window)
+            if plan.segs:
+                # no key before the row's own prompt
+                rows_at = q_lo + jax.lax.broadcasted_iota(
+                    jnp.int32, (s.shape[0], 1), 0)
+                seen = seen & (kpos >= segment_start(starts, rows_at))
             s = jnp.where(seen, s, NEG)
         m_blk = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_old, jnp.broadcast_to(m_blk, m_old.shape))
@@ -335,6 +372,9 @@ def _flash_kernel(plan: _Plan, offs, *refs):
         edge = k_lo + plan.bkv - 1 > q_lo
         if plan.window is not None:
             edge = edge | (k_lo <= q_lo + plan.bq - 1 - plan.window)
+        if plan.segs:
+            edge = edge | (k_lo < segment_start(starts,
+                                                q_lo + plan.bq - 1))
         pl.when(live & edge)(lambda: fold(True))
         pl.when(live & jnp.logical_not(edge))(lambda: fold(False))
     else:
@@ -376,12 +416,16 @@ def _rows_at(x, row0: int, rows: Optional[int], tile: int):
 
 def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
            window, interpret, finish=False, o=None, o_row0=0, q_row0=0,
-           rows=None, k_row0=0, keys=None, tok=None):
+           rows=None, k_row0=0, keys=None, tok=None, segments=None):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if window is not None and not causal:
         raise ValueError("a window is counted back from the query's own "
                          "position: it needs causal=True")
+    segments = tuple(int(s) for s in segments or ())
+    if segments and not causal:
+        raise ValueError("packed prompts are masked by position: "
+                         "segments need causal=True")
     q, q_row0, n = _rows_at(q, q_row0, rows, bq)
     v = _rows_at(v, k_row0, keys, bkv)[0]
     k, k_row0, nkv = _rows_at(k, k_row0, keys, bkv)
@@ -412,9 +456,11 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
         q = jnp.pad(q, padw)
         state = tuple(jnp.pad(t, padw) for t in state)
     plan = _Plan(float(scale), bq, bkv, nkv // bkv, nkv // bkv, bool(causal),
-                 None if window is None else int(window), init, bool(finish))
+                 None if window is None else int(window), init, bool(finish),
+                 segs=len(segments))
     if plan.causal:
-        spans = [visible_tiles(plan, q_pos + j * bq, k_pos, max, min)
+        spans = [visible_tiles(plan, q_pos + j * bq, k_pos, max, min,
+                               segments, pick_int)
                  for j in range(np_ // bq)]
         plan = replace(plan, steps=max(1, max(b - a + 1 for a, b in spans)))
 
@@ -426,7 +472,9 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
     def kv_tile(j, t, offs):
         tile = t
         if plan.causal:
-            first, last = visible_tiles(plan, offs[0] + j * bq, offs[1])
+            first, last = visible_tiles(
+                plan, offs[0] + j * bq, offs[1],
+                starts=[offs[2 + i] for i in range(plan.segs)])
             tile = jnp.clip(first + walk_step(plan, first, last, t)[0], 0,
                             plan.kv_tiles - 1)
         return k_tile0 + tile if k_tile0 else tile
@@ -459,7 +507,7 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
     else:
         out_specs = [rowblk] * 3
         out_shape = [out_struct((h, np_, d), jnp.float32, *operands)] * 3
-    positions = jnp.asarray([q_pos, k_pos], jnp.int32)
+    positions = jnp.asarray((q_pos, k_pos) + segments, jnp.int32)
     if tok is not None:
         # the caller's ordering token, an int32 zero: the kernel waits for
         # its scalars, so for the token, and no operand gets an add
@@ -494,7 +542,8 @@ def _flash(name, q, k, v, acc, m, l, scale, bq, bkv, q_pos, k_pos, causal,
     return jax.lax.dynamic_update_slice_in_dim(o, outs[0], o_row0, 1)
 
 
-_STATIC = ("scale", "bkv", "q_pos", "k_pos", "causal", "window", "interpret")
+_STATIC = ("scale", "bkv", "q_pos", "k_pos", "causal", "window", "interpret",
+           "segments")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -513,6 +562,7 @@ def attn_block_pallas(
     causal: bool = False,
     window: Optional[int] = None,
     interpret: Optional[bool] = None,
+    segments: Optional[Tuple[int, ...]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fold one K/V block into the online-softmax state; returns (acc', m', l').
 
@@ -520,10 +570,11 @@ def attn_block_pallas(
     (h, n, d) float32 with m/l broadcast along the last axis, or all three
     ``None`` to start from the empty state.  ``q_pos``/``k_pos``: the
     positions of q's and k's first rows, for the mask.  A block of more than
-    ``bkv`` rows is walked ``bkv`` at a time.
+    ``bkv`` rows is walked ``bkv`` at a time.  ``segments``: the first rows
+    of packed prompts, ascending, in the positions' coordinates.
     """
     return _flash("attn_fold", q, k, v, acc, m, l, scale, Q_TILE, bkv, q_pos,
-                  k_pos, causal, window, interpret)
+                  k_pos, causal, window, interpret, segments=segments)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + (
@@ -551,6 +602,7 @@ def attn_fused_pallas(
     k_row0: int = 0,
     keys: Optional[int] = None,
     tok: Optional[jax.Array] = None,
+    segments: Optional[Tuple[int, ...]] = None,
 ):
     """Fold a whole K/V range into the online-softmax state in ONE kernel —
     the fused alternative to chaining :func:`attn_block_pallas` per block.
@@ -586,7 +638,7 @@ def attn_fused_pallas(
     """
     return _flash("attn_fused", q, k, v, acc, m, l, scale, Q_TILE, bkv, q_pos,
                   k_pos, causal, window, interpret, finish, o, o_row0, q_row0,
-                  rows, k_row0, keys, tok)
+                  rows, k_row0, keys, tok, segments)
 
 
 # -- a paged latent cache: one decode step ---------------------------------------

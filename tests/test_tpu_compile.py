@@ -503,18 +503,10 @@ def _mla_cell():
         fold_pages=shapes["fold_pages"])
 
 
-@pytest.mark.parametrize("group", [0, 3])
-@pytest.mark.parametrize("form", ["mla_decode", "mla_fold"])
-def test_latent_decode_kernels(one_chip, form, group):
-    """The paged kernel at the decode cell's shapes, its shortest group and
-    its longest (a grid of 21 and of 170 (sequence, page) steps: the pages
-    there are; a link of the chain 21 and 31, g3's third with no step for
-    its shortest sequence): Mosaic takes K tiles of ``(576, 2048)`` (the
-    contraction 576 wide, V the first 512 rows), two scalar operands (the
-    walk is immediates of the index maps), and O or the handed state aliased
-    in place;
-    the pools arrive in the runtime's own layout, so the compiled program
-    holds the kernel and no copy of a pool."""
+def _latent_decode_compiled(one_chip, form, group):
+    """``(LatentDecodeArgs, compiled)``: the paged kernel ``form`` at the
+    decode cell's shapes, its group ``group`` (``mla_fold``: the group's
+    first link, g3's third)."""
     from tenzing_tpu.models.latent_attention import decode_plan
     from tenzing_tpu.ops.attention_pallas import (
         mla_decode_pallas,
@@ -543,6 +535,22 @@ def test_latent_decode_kernels(one_chip, form, group):
         compiled = mla_fold_pallas.lower(
             *operands, st, st, st, a.scale, k_pos=k_pos, tiles=tiles,
             **common).compile()
+    return a, compiled
+
+
+@pytest.mark.parametrize("group", [0, 3])
+@pytest.mark.parametrize("form", ["mla_decode", "mla_fold"])
+def test_latent_decode_kernels(one_chip, form, group):
+    """The paged kernel at the decode cell's shapes, its shortest group and
+    its longest (a grid of 21 and of 170 (sequence, page) steps: the pages
+    there are; a link of the chain 21 and 31, g3's third with no step for
+    its shortest sequence): Mosaic takes K tiles of ``(576, 2048)`` (the
+    contraction 576 wide, V the first 512 rows), two scalar operands (the
+    walk is immediates of the index maps), and O or the handed state aliased
+    in place;
+    the pools arrive in the runtime's own layout, so the compiled program
+    holds the kernel and no copy of a pool."""
+    a, compiled = _latent_decode_compiled(one_chip, form, group)
     _assert_kernel(compiled)
     text = compiled.as_text()
     assert form in text  # the name the device trace shows
@@ -1607,3 +1615,168 @@ def test_scmoe_decode_step_on_four_chips(topo, monkeypatch, which):
     m = compiled.memory_analysis()  # bytes on each device
     assert 4.2e9 < m.argument_size_in_bytes < 5.0e9
     assert 3 * m.argument_size_in_bytes + m.temp_size_in_bytes < HBM_BYTES
+
+
+# -- the mixers of a Mamba-2 hybrid's period on one chip ----------------------------
+
+MIXERS_CONFIG = "benchmarks/configs/nemotron3-nano-mixers-prefill.json"
+
+
+def _mixers_cell():
+    """``(Mamba2Args, RingAttnArgs, pattern, phases)`` of
+    ``nemotron3-nano-mixers-prefill.climb`` at the published widths, as its
+    builder makes them."""
+    import json
+
+    from benchmarks.builders.mixers_prefill import step_args
+    from benchmarks.harness.cell import load_module
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, MIXERS_CONFIG)) as f:
+        config = json.load(f)
+    ref = load_module("references", config["reference"])
+    z = ref.sizes(config)
+    return (*step_args(z), z["pattern"],
+            [f"{t}." for _, t in ref.tags(config)])
+
+
+def test_ssd_scan_kernel(one_chip):
+    """``ssd_scan`` at the cell's shapes (16 384 packed tokens, twelve
+    prompts, 64 heads of 64 on a 64 x 128 state, 8 groups, chunks of 128):
+    Mosaic takes a grid step of one group's eight heads with a prompt
+    boundary inside the chunk (masks from the prefetched ids, no
+    immediate), the two transposes a step, the resident block of final
+    states."""
+    from tenzing_tpu.ops.ssd_pallas import ssd_chunk_scan
+
+    m = _mixers_cell()[0]
+    assert (m.tokens, m.prompts, m.chunks, m.boundary_chunks) == (
+        16384, 12, 128, 8)
+    compiled = ssd_chunk_scan.lower(
+        _sds((m.tokens, m.conv_width), jnp.bfloat16, one_chip),
+        _sds((m.tokens, m.heads), jnp.float32, one_chip),
+        _sds((m.heads,), jnp.float32, one_chip),
+        _sds((m.heads,), jnp.float32, one_chip),
+        _sds((m.tokens,), jnp.int32, one_chip),
+        _sds((m.prompts,), jnp.int32, one_chip),
+        **m.dims, interpret=False).compile()
+    _assert_kernel(compiled)
+    assert "ssd_scan" in compiled.as_text()  # the name the trace shows
+    # x, B and C are read where they lie: no slice of the convolved rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("which", ["start", "naive"])
+def test_mixers_prefill_step_fits_the_chip(one_chip, monkeypatch, which):
+    """The repeat-n program of the cell's start point (three ``ssd_scan``
+    and a packed ``attn_fused`` a query block) and of its naive (the XLA
+    chains, the ``attn_fold`` chains) at the published widths: both compile
+    for the described chip, and three sets of the step's buffers (the
+    run's, the probe's, a one-shot program's outputs) fit beside the loop's
+    temporaries and beside the one-shot program's own."""
+    import re
+
+    from benchmarks.builders.mixers_prefill import unfused_prefer
+    from tenzing_tpu.bench.workloads import attn_fused_prefer
+    from tenzing_tpu.core.platform import Platform
+    from tenzing_tpu.models import mixers_prefill
+    from tenzing_tpu.runtime.executor import TraceExecutor
+    from tenzing_tpu.solve.local import drive, phase_policy
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mamba, attn, pattern, phases = _mixers_cell()
+    bufs = {name: _sds(shape, jnp.dtype(dtype), one_chip)
+            for name, (shape, dtype) in mixers_prefill.buffer_shapes(
+                mamba, attn, pattern).items()}
+    graph = mixers_prefill.mixers_prefill_graph(mamba, attn, pattern)
+    plat = Platform.make_n_lanes(2 if which == "start" else 1)
+    seq, _ = drive(graph, plat, phase_policy(
+        plat, phases,
+        attn_fused_prefer if which == "start" else unfused_prefer))
+    ex, n = TraceExecutor(plat, bufs), _sds((), jnp.int32, one_chip)
+    compiled = jax.jit(ex._stepped_fn(seq.vector())).lower(bufs, n).compile()
+    text = compiled.as_text()
+    scans = len(re.findall(r"ssd_scan\.\d+ = ", text))
+    if which == "start":
+        blocks = mamba.tokens // attn.q_block
+        assert scans == 3
+        assert text.count("tpu_custom_call") == 3 + blocks
+    else:
+        assert scans == 0 and re.search(r"attn_fold\.\d+ = ", text)
+    mem = compiled.memory_analysis()
+    print(which, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+    assert 3.5e9 < mem.argument_size_in_bytes < 4.0e9
+    assert 3 * mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    # the cell's one-shot program is that loop run once, handing back its
+    # carry: the run's buffers, the probe's, its outputs, its temporaries
+    once = jax.jit(ex._looped_fn(seq.vector())).lower(
+        bufs, n).compile().memory_analysis()
+    assert (2 * once.argument_size_in_bytes + once.output_size_in_bytes
+            + once.temp_size_in_bytes) < HBM_BYTES
+
+
+# -- the attention cell's programs, as they were -------------------------------------
+
+#: sha256 (first 20 hex digits) of the compiled repeat-n programs of
+#: ``trinity-attn32k.climb``'s start point and naive without what names a
+#: source file or line (:func:`_bare_digest`), read from the commit before
+#: ISSUE 50 and from the change alike: the attention's ``segments`` left a
+#: one-prompt caller's program as it was to the byte.  A change that means
+#: to move one of them reads the new digest off the failing assertion.
+TRINITY_PROGRAMS = {
+    "start": "06ede39a3e5ae5227213",
+    "naive": "eb8ea28be98344f8a5cd",
+}
+
+
+def _bare_digest(text: str) -> str:
+    """sha256 prefix of a compiled text without what names a source file,
+    line or stack frame."""
+    import hashlib
+    import re
+
+    bare = re.sub(r", metadata=\{[^}]*\}", "", text)
+    bare = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n|^\d+ (\"|\{).*\n", "", bare, flags=re.M)
+    bare = re.sub(r",? ?stack_frame_id=\d+", "", bare)
+    return hashlib.sha256(bare.encode()).hexdigest()[:20]
+
+
+@pytest.mark.parametrize("which", list(TRINITY_PROGRAMS))
+def test_trinity_s_programs_are_as_they_were(one_chip, monkeypatch, which):
+    # a Mosaic kernel's serialized body carries its Python frames, and a
+    # line that moved would read as another kernel: lowered with none
+    prev = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    _LOOP_TEXTS.pop(("attn", which), None)
+    try:
+        text = _attention_period_text(one_chip, monkeypatch, which)
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", prev)
+        _LOOP_TEXTS.pop(("attn", which), None)
+    assert _bare_digest(text) == TRINITY_PROGRAMS[which]
+
+
+#: the same of ``dsv3-mla-decode.climb``'s two paged kernels at its shortest
+#: group: ``models/latent_attention.py`` and ``models/sparse_attention.py``
+#: build their plans from ``ops/attention_pallas.py``'s ``_Plan``, which
+#: ISSUE 50 gave a field (``segs``, 0 for them), so the four decode cells
+#: run code of a changed file; read from the commit before and from the
+#: change alike.
+DECODE_KERNELS = {
+    "mla_decode": "5f3cb0d62013a5b333bb",
+    "mla_fold": "3af7d34bcff93333f76b",
+}
+
+
+@pytest.mark.parametrize("form", list(DECODE_KERNELS))
+def test_decode_kernels_are_as_they_were(one_chip, form):
+    prev = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    # another test's trace of the same call would hand back its frames
+    jax.clear_caches()
+    try:
+        _, compiled = _latent_decode_compiled(one_chip, form, 0)
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", prev)
+    assert _bare_digest(compiled.as_text()) == DECODE_KERNELS[form]
